@@ -1,6 +1,6 @@
 """``fleet.elastic`` — preemption-proof elastic training.
 
-The supervisor loop over the pieces PRs 4/6/7/8 built: subprocess
+The supervisor loop over the pieces PRs 4/6/7/8 built: in-process
 device preflight with a deadline (:mod:`.preflight`), a supervised
 step loop under the stall watchdog + cluster health plane with
 failure classification and elastic restore on the surviving topology
@@ -14,9 +14,10 @@ from __future__ import annotations
 
 from . import chaos
 from .chaos import RankKilled, TornCheckpoint
-from .preflight import (DEFAULT_PROBE_CODE, PREFLIGHT_COMPILE_ERROR,
+from .preflight import (PREFLIGHT_COMPILE_ERROR,
                         PREFLIGHT_INIT_TIMEOUT, PREFLIGHT_OK,
-                        PreflightVerdict, preflight_device)
+                        PreflightVerdict, default_probe,
+                        preflight_device)
 from .supervisor import (FAILURE_POISON, FAILURE_TOPOLOGY,
                          FAILURE_TRANSIENT, DeadRankDetected,
                          ElasticSupervisor, ElasticTerminated,
@@ -28,7 +29,7 @@ __all__ = [
     "ElasticSupervisor", "SupervisorResult", "Topology",
     "ElasticTerminated", "PreflightError", "StallDetected",
     "DeadRankDetected", "RankKilled", "TornCheckpoint",
-    "preflight_device", "PreflightVerdict", "DEFAULT_PROBE_CODE",
+    "preflight_device", "PreflightVerdict", "default_probe",
     "PREFLIGHT_OK", "PREFLIGHT_INIT_TIMEOUT", "PREFLIGHT_COMPILE_ERROR",
     "classify_failure", "is_device_failure", "dead_ranks_from_cluster",
     "FAILURE_TRANSIENT", "FAILURE_TOPOLOGY", "FAILURE_POISON", "chaos",

@@ -22,7 +22,7 @@ continuously by tests and the ``bench.py`` chaos leg:
   so the health plane dead-lists a live process — the
   dead-rank-detection path.
 - ``preflight_init_timeout`` (no params): one preflight probe reports
-  ``init_timeout`` without spawning the subprocess — the r04/r05
+  ``init_timeout`` without running the probe — the
   "device init did not complete" failure on demand.
 - ``kill_prefill_replica`` (params ``replica``): the disaggregated
   serving router (``serving/disagg.py``) hard-stops the named prefill

@@ -1,37 +1,37 @@
-"""Device preflight with a deadline: probe the backend in a subprocess.
+"""Device preflight with a deadline: probe the backend IN-PROCESS.
 
-BENCH r04/r05 died because the *first in-process* ``jax.devices()``
-call wedged ("device init did not complete within 240s") — once a
-backend hangs inside your own process there is nothing left to
-supervise with.  The probe therefore runs in a CHILD process under
-``subprocess`` timeout: a tiny jit dispatch (`import jax` + compile +
-execute one add) that exercises init, compile, and dispatch, while the
-parent — the supervisor — can never be hung by it.
+A chip belongs to one process at a time.  Every caller of the preflight
+(the elastic supervisor before each attempt, the disagg autoscaler from a
+live server, bench.py) either already holds the chip or is about to, so a
+probe in a CHILD process could never load the TPU library and would read
+every healthy device as dead.  The probe therefore runs in the calling
+process, on a daemon thread under a deadline: a tiny jit dispatch
+(compile + execute one add) that exercises init, compile and dispatch.
+A backend that wedges leaves that thread hung, but the caller gets its
+verdict at the deadline and stays free to report and exit.
 
 The verdict is structured, not a string soup:
 
-- ``ok``            probe printed its sentinel; ``platform`` is set.
-- ``init_timeout``  the child exceeded ``FLAGS_elastic_preflight_timeout_s``.
-- ``compile_error`` the child exited nonzero (or produced no sentinel);
-  ``diag`` carries the stderr tail.
+- ``ok``            the probe returned; ``platform`` is set.
+- ``init_timeout``  the probe exceeded ``FLAGS_elastic_preflight_timeout_s``.
+- ``compile_error`` the probe raised; ``diag`` carries the exception.
 
 Failures retry with exponential backoff (``FLAGS_elastic_backoff_s *
-2^k``) up to ``attempts`` — a transiently-held chip (an orphaned worker
-still being reaped) recovers without burning the supervisor's restart
-budget.  Every attempt lands in the flight recorder
-(``elastic/preflight``) and the ``elastic_preflight_*`` metric family.
+2^k``) up to ``attempts`` — a transiently-held chip recovers without
+burning the supervisor's restart budget.  Every attempt lands in the
+flight recorder (``elastic/preflight``) and the ``elastic_preflight_*``
+metric family.
 """
 from __future__ import annotations
 
-import subprocess
-import sys
+import threading
 import time
 from typing import Callable, Optional
 
 from ....framework import flags as _flags
 from . import chaos as _chaos
 
-__all__ = ["PreflightVerdict", "preflight_device", "DEFAULT_PROBE_CODE",
+__all__ = ["PreflightVerdict", "preflight_device", "default_probe",
            "PREFLIGHT_OK", "PREFLIGHT_INIT_TIMEOUT",
            "PREFLIGHT_COMPILE_ERROR"]
 
@@ -39,15 +39,15 @@ PREFLIGHT_OK = "ok"
 PREFLIGHT_INIT_TIMEOUT = "init_timeout"
 PREFLIGHT_COMPILE_ERROR = "compile_error"
 
-# init + compile + dispatch in one child; the sentinel keeps parsing
-# robust against libraries that chat on stdout during import
-DEFAULT_PROBE_CODE = (
-    "import jax\n"
-    "import jax.numpy as jnp\n"
-    "x = jax.jit(lambda v: v + 1)(jnp.zeros((8,), jnp.float32))\n"
-    "x.block_until_ready()\n"
-    "print('PREFLIGHT_OK', jax.devices()[0].platform)\n"
-)
+def default_probe() -> str:
+    """init + compile + dispatch on this process's own backend; returns
+    the platform jax reports."""
+    import jax
+    import jax.numpy as jnp
+
+    jax.jit(lambda v: v + 1)(jnp.zeros((8,), jnp.float32)) \
+        .block_until_ready()
+    return jax.devices()[0].platform
 
 
 class PreflightVerdict:
@@ -77,36 +77,41 @@ class PreflightVerdict:
                 f"platform={self.platform!r}, attempts={self.attempts})")
 
 
-def _one_probe(probe_code: str, timeout_s: float) -> PreflightVerdict:
+def _one_probe(probe: Callable[[], str],
+               timeout_s: float) -> PreflightVerdict:
     f = _chaos.take("preflight_init_timeout")
     if f is not None:
         return PreflightVerdict(
             PREFLIGHT_INIT_TIMEOUT,
             diag=f"chaos: injected preflight init timeout ({timeout_s}s)")
-    try:
-        r = subprocess.run([sys.executable, "-c", probe_code],
-                           capture_output=True, text=True,
-                           timeout=timeout_s)
-    except subprocess.TimeoutExpired:
+    box: dict = {}
+
+    def work():
+        try:
+            box["platform"] = probe()
+        except Exception as e:  # noqa: BLE001 - becomes the verdict
+            box["error"] = f"{type(e).__name__}: {e}"
+
+    t = threading.Thread(target=work, daemon=True,
+                         name="elastic-preflight")
+    t.start()
+    t.join(timeout_s)
+    if t.is_alive():
         return PreflightVerdict(
             PREFLIGHT_INIT_TIMEOUT,
             diag=f"device init did not complete within {timeout_s}s")
-    for line in reversed((r.stdout or "").splitlines()):
-        if line.startswith("PREFLIGHT_OK"):
-            parts = line.split()
-            return PreflightVerdict(
-                PREFLIGHT_OK,
-                platform=parts[1] if len(parts) > 1 else "unknown")
-    diag = (r.stderr or r.stdout or "no output").strip()[-2000:]
-    return PreflightVerdict(
-        PREFLIGHT_COMPILE_ERROR,
-        diag=f"probe exited {r.returncode}: {diag}")
+    if "platform" not in box:
+        return PreflightVerdict(
+            PREFLIGHT_COMPILE_ERROR,
+            diag=f"probe raised {box.get('error', 'and left no result')}"
+            [-2000:])
+    return PreflightVerdict(PREFLIGHT_OK, platform=str(box["platform"]))
 
 
 def preflight_device(attempts: int = 2,
                      timeout_s: Optional[float] = None,
                      backoff_s: Optional[float] = None,
-                     probe_code: Optional[str] = None,
+                     probe: Optional[Callable[[], str]] = None,
                      sleep_fn: Callable[[float], None] = time.sleep
                      ) -> PreflightVerdict:
     """Probe the device up to ``attempts`` times with exponential
@@ -122,15 +127,15 @@ def preflight_device(attempts: int = 2,
                       if timeout_s is None else timeout_s)
     backoff_s = float(_flags.flag("elastic_backoff_s")
                       if backoff_s is None else backoff_s)
-    code = probe_code or DEFAULT_PROBE_CODE
+    probe = probe or default_probe
     attempts = max(int(attempts), 1)
     t0 = time.perf_counter()
     v = PreflightVerdict(PREFLIGHT_COMPILE_ERROR, diag="no attempts made",
                          attempts=0)
     for i in range(attempts):
         try:
-            v = _one_probe(code, timeout_s)
-        except Exception as e:  # noqa: BLE001 - subprocess machinery broke
+            v = _one_probe(probe, timeout_s)
+        except Exception as e:  # noqa: BLE001 - the thread could not start
             v = PreflightVerdict(
                 PREFLIGHT_COMPILE_ERROR,
                 diag=f"probe could not run: {type(e).__name__}: {e}")

@@ -12,8 +12,9 @@ loop and, when a device or rank disappears, classifies the failure,
 dumps a postmortem bundle, and restarts — rebuilding on the surviving
 topology when the world shrank — instead of dying:
 
-1. **Preflight with a deadline** (:mod:`.preflight`): a subprocess-
-   isolated probe so a wedged backend can never hang the supervisor.
+1. **Preflight with a deadline** (:mod:`.preflight`): an in-process
+   probe on a daemon thread, so a wedged backend cannot keep the
+   supervisor from reporting (a child could not open a held chip).
 2. **Supervised step loop**: steps run inside an in-flight window the
    PR 6 :class:`~paddle_tpu.observe.health.StallWatchdog` samples (a
    supervisor-local progress feed — one counter pair + the current
@@ -45,9 +46,8 @@ wrapped as a stateless step function.
 
 Honest limitation: this is in-process supervision — a host thread
 wedged *forever* inside a device call can be diagnosed (watchdog →
-bundle) but not preempted from the same process.  That is exactly why
-preflight is subprocess-isolated, and why multi-host deployments run
-one supervised process per rank (the launcher restarts processes; this
+bundle) but not preempted from the same process.  That is why
+multi-host deployments run one supervised process per rank (the launcher restarts processes; this
 loop restarts *topologies*).
 """
 from __future__ import annotations
@@ -266,7 +266,7 @@ class ElasticSupervisor:
                  preflight: bool = True,
                  preflight_attempts: int = 2,
                  preflight_timeout_s: Optional[float] = None,
-                 preflight_probe_code: Optional[str] = None,
+                 preflight_probe: Optional[Callable[[], str]] = None,
                  watchdog_timeout_s: float = 0.0,
                  cluster_fn: Optional[Callable[[], dict]] = None,
                  cluster_url: Optional[str] = None,
@@ -283,7 +283,7 @@ class ElasticSupervisor:
         self.preflight = bool(preflight)
         self.preflight_attempts = int(preflight_attempts)
         self.preflight_timeout_s = preflight_timeout_s
-        self.preflight_probe_code = preflight_probe_code
+        self.preflight_probe = preflight_probe
         self.watchdog_timeout_s = float(watchdog_timeout_s)
         if cluster_fn is None and cluster_url:
             url_fn = dead_ranks_from_cluster(cluster_url)
@@ -451,7 +451,7 @@ class ElasticSupervisor:
                         attempts=self.preflight_attempts,
                         timeout_s=self.preflight_timeout_s,
                         backoff_s=self.backoff_s,
-                        probe_code=self.preflight_probe_code,
+                        probe=self.preflight_probe,
                         sleep_fn=self.sleep_fn)
                     result.preflight_retries += max(v.attempts - 1, 0)
                     if not v.ok:

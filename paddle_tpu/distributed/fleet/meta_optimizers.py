@@ -178,7 +178,7 @@ class RecomputeMetaOptimizer(MetaOptimizerBase):
                  no_grad_set=None):
         from ...framework.passes import (LAYER_SCAN_ATTR,
                                          LAYER_SCAN_POLICY_ATTR)
-        from ...framework.jax_compat import REMAT_POLICIES
+        from ...ops.layer_scan import REMAT_POLICIES
 
         cfg = self.user_strategy.recompute_configs
         ckpts = list(cfg.get("checkpoints", []))

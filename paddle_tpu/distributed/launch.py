@@ -11,6 +11,7 @@ jax.distributed rendezvous).
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import signal
 import subprocess
@@ -29,6 +30,16 @@ def _parse_args(argv=None):
     p.add_argument("training_script", type=str)
     p.add_argument("training_script_args", nargs=argparse.REMAINDER)
     return p.parse_args(argv)
+
+
+def tpu_present() -> bool:
+    """Does this host have TPU chips the trainers would open?  Asked
+    of the device files, not of jax: a launcher that touched jax would
+    hold the chip its child needs.  A job that selected the CPU
+    (``JAX_PLATFORMS=cpu``, loopback testing) opens none."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return False
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
 
 
 def start_local_trainers(nproc, coordinator, script, script_args, log_dir=None,
@@ -102,6 +113,14 @@ def launch(argv=None):
                 "would claim node rank 0 and the rendezvous fails")
     else:
         me = ips[0]
+    if args.nproc_per_node > 1 and tpu_present():
+        raise SystemExit(
+            f"--nproc_per_node {args.nproc_per_node} on a TPU host: a chip "
+            "belongs to one process at a time and every trainer would open "
+            "all of them (the second one fails or hangs). One process "
+            "drives all local chips: launch one trainer per host and let "
+            "init_parallel_env() build the mesh over its chips, or set "
+            "JAX_PLATFORMS=cpu for a loopback test")
     node_rank = ips.index(me)
     coordinator = f"{ips[0]}:{args.coordinator_port}"
     total = len(ips) * args.nproc_per_node

@@ -41,19 +41,8 @@ def init_parallel_env(mesh_shape: Optional[Sequence[int]] = None,
             eps = os.environ.get("PADDLE_TRAINER_ENDPOINTS", "")
             coord = eps.split(",")[0] if eps else None
         rank = int(os.environ.get("PADDLE_TRAINER_ID", "0") or 0)
-        from ..framework.jax_compat import config_value, update_config
-
-        if config_value("jax_cpu_collectives_implementation", "") is None:
-            # XLA CPU needs an explicit cross-process collectives impl;
-            # without it multi-process psum SILENTLY stays process-local
-            # (each rank reduces only its own devices).  Setting it here
-            # is safe for TPU backends (only consulted when the CPU
-            # client is created) but must happen BEFORE any backend
-            # exists, hence before jax.distributed.initialize.  Guarded
-            # accessor: jax versions WITHOUT the config entry pick gloo
-            # by default (or read the env var), so absence is a no-op,
-            # not an AttributeError.
-            update_config("jax_cpu_collectives_implementation", "gloo")
+        # cross-process CPU collectives (the localhost-cluster tests) ride
+        # jax's default jax_cpu_collectives_implementation, gloo
         jax.distributed.initialize(coordinator_address=coord,
                                    num_processes=nproc, process_id=rank)
 

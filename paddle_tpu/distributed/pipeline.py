@@ -674,9 +674,10 @@ def _emit_stage_scan(ctx, run, lower_one, policy):
     the op semantics byte-for-byte: each iteration lowers exactly the
     template ops the unrolled trace would, with the same key chain)."""
     import jax.numpy as jnp
+    from jax import lax
 
-    from ..framework import jax_compat as _jc
     from ..framework.lowering import LoweringContext
+    from ..ops.layer_scan import wrap_checkpoint
 
     plan = run["plan"]
     env = ctx.env
@@ -713,9 +714,9 @@ def _emit_stage_scan(ctx, run, lower_one, policy):
             return (new_key,) + nc, ys
         return nc, ys
 
-    body = _jc.wrap_checkpoint(body, policy or "")
+    body = wrap_checkpoint(body, policy or "")
     init_carry = ((ctx.rng_key,) + init) if has_key else init
-    final, ys_stacks = _jc.scan(body, init_carry,
+    final, ys_stacks = lax.scan(body, init_carry,
                                 xs_stacks if xs_stacks else None,
                                 length=plan.M)
     if has_key:
@@ -749,7 +750,7 @@ def build_pipeline_fn(program, mesh, feed_names, state_mut, state_const,
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ..framework.jax_compat import shard_map
+    from jax import shard_map
 
     from ..framework.lowering import (PSEUDO_OPS, LoweringContext,
                                       get_lowering)

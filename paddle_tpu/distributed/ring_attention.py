@@ -45,8 +45,7 @@ def ring_attention(q, k, v, axis_name="sp", sm_scale=None, causal=False,
     b, h, s_local, d = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    from ..framework.jax_compat import axis_size
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     r = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % p) for i in range(p)]
 
@@ -104,7 +103,7 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", sm_scale=None,
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ..framework.jax_compat import shard_map
+    from jax import shard_map
 
     key = (id(mesh), axis_name, sm_scale, causal, bias is not None)
     fn = _SHARDED_CACHE.get(key)

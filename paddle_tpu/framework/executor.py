@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import collections
 import logging
+import os
 import threading
 import weakref
 from dataclasses import dataclass, field
@@ -466,52 +467,40 @@ def close_all() -> int:
     return n
 
 
-_threefry_partitionable_applied = False
+# the one default location of jax's persistent compilation cache: a
+# fixed path inside the checkout (the path is part of the cache key, so
+# a directory that moves never hits); .gitignore lists it
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_compile_cache")
+_compile_cache_applied = False
 
 
-def _maybe_enable_partitionable_threefry():
-    """Switch jax to the partitionable threefry implementation (the
-    modern default upstream).  The legacy implementation generates
-    DIFFERENT bits when XLA shards the consumer of a random op — a
-    dropout mask inside the tensor-parallel GSPMD executable would
-    silently differ from the same program's replicated run (repro:
-    bernoulli under jit with a dp-sharded consumer output), breaking
-    the tp-vs-oracle loss-parity contract.  Partitionable threefry's
-    bit-stream is sharding-invariant, so every path — single-device,
-    shard_map dp, GSPMD tp — draws identical values for identical
-    keys.  Applied process-wide at the first Executor construction:
-    consistency REQUIRES one mode everywhere."""
-    global _threefry_partitionable_applied
-
-    if _threefry_partitionable_applied:
-        return
-    from .jax_compat import update_config
-
-    if update_config("jax_threefry_partitionable", True):
-        _threefry_partitionable_applied = True
-
-
-_compile_cache_dir_applied: Optional[str] = None
+def default_compile_cache_dir(environ, backend: str) -> Optional[str]:
+    """The directory this program points jax's persistent compilation
+    cache at, or None where it sets nothing: the cache is placed from
+    OUTSIDE when ``JAX_COMPILATION_CACHE_DIR`` is in the environment
+    (jax has already taken it), and a process that selected the CPU
+    keeps none (XLA:CPU reloads buy nothing and warn about machine
+    features)."""
+    if "JAX_COMPILATION_CACHE_DIR" in environ or backend == "cpu":
+        return None
+    return COMPILE_CACHE_DIR
 
 
 def _maybe_enable_compile_cache():
-    """FLAGS_compile_cache_dir -> jax persistent compilation cache
-    (guarded via jax_compat: a jax without the knob is a silent no-op).
-    Re-checked per Executor construction so setting the flag after
-    import still takes effect."""
-    global _compile_cache_dir_applied
+    """Applied once, when the first Executor (and so the first serving
+    engine) is built - never at import."""
+    global _compile_cache_applied
 
-    from . import flags
-
-    d = flags.flag("compile_cache_dir")
-    if not d or d == _compile_cache_dir_applied:
+    if _compile_cache_applied:
         return
-    from ..monitor import stat_add
-    from .jax_compat import update_config
+    _compile_cache_applied = True
+    import jax
 
-    if update_config("jax_compilation_cache_dir", d):
-        _compile_cache_dir_applied = d
-        stat_add("executor_compile_cache_dir_set")
+    d = default_compile_cache_dir(os.environ, jax.default_backend())
+    if d is not None:
+        jax.config.update("jax_compilation_cache_dir", d)
 
 
 def _block_written(program, block_idx: int) -> set:
@@ -703,7 +692,6 @@ class Executor:
         self._window = _InflightWindow()
         _LIVE_EXECUTORS.add(self)
         _maybe_enable_compile_cache()
-        _maybe_enable_partitionable_threefry()
         # flight recorder + health plane (observe/): the run-metadata
         # event fires once per process, executor creation is a
         # lifecycle event, and FLAGS_stall_timeout_s > 0 arms the stall
@@ -1847,7 +1835,7 @@ class Executor:
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from .jax_compat import shard_map
+        from jax import shard_map
 
         axis_names = tuple(mesh.axis_names)
         dp_axis = "dp" if "dp" in axis_names else axis_names[0]

@@ -140,10 +140,13 @@ define_flag("ckpt_fsync", True,
 define_flag("ckpt_verify_restore", True,
             "verify the SHA-256 of every shard against the manifest "
             "before restoring (off: existence+size checks only)")
-define_flag("device_peak_tflops", 275.0,
-            "per-chip peak TFLOP/s used by the MFU estimate "
-            "(observe/step_stats.py); default is TPU v4/v5e-class bf16 "
-            "peak — set to your part's number for honest utilization")
+define_flag("device_peak_tflops", 0.0,
+            "per-chip peak TFLOP/s the MFU estimate divides by "
+            "(observe/step_stats.py).  0 = unset: the live device's "
+            "published bf16 peak from observe/device_peaks.py "
+            "(keyed by device_kind; \"TPU v5 lite\" = 197).  A device "
+            "that is not in that table has no MFU (null in summaries, "
+            "an error from mfu_estimate) - never another chip's number")
 define_flag("max_inflight_steps", 2,
             "pipelined step dispatch (framework/executor.py): Executor."
             "run returns a lazy StepHandle and up to this many steps may "
@@ -234,22 +237,13 @@ define_flag("layer_scan_policy", "",
             "'dots_saveable', or 'save_anything' (= jax "
             "everything_saveable) — extends the program-level "
             "recompute_barrier support to XLA remat choices per scanned "
-            "block.  A jax without checkpoint_policies degrades to "
-            "plain jax.checkpoint (counter remat_policy_unavailable)",
+            "block",
             affects_lowering=True)
 define_flag("layer_scan_unroll", 1,
             "lax.scan unroll= factor for layer_scan regions (>1 trades "
             "compile time back for per-step dispatch overhead on very "
-            "cheap bodies); dropped silently on a jax whose lax.scan "
-            "lacks the knob",
+            "cheap bodies)",
             affects_lowering=True)
-define_flag("compile_cache_dir", "",
-            "persistent XLA compilation cache directory (sets jax's "
-            "jax_compilation_cache_dir through framework/jax_compat.py "
-            "when the installed jax has the knob): restarted jobs reuse "
-            "compiled executables instead of re-tracing + re-compiling; "
-            "empty = disabled.  Applied when an Executor is constructed; "
-            "counted once as executor_compile_cache_dir_set")
 define_flag("decode_slots", 8,
             "decode engine (paddle_tpu.serving.decode): fixed slot-batch "
             "capacity of one DecodeEngine replica — the number of "
@@ -333,9 +327,7 @@ define_flag("weight_quant", "",
             "matmul-family weights to a compact carrier + per-output-"
             "channel scales lowered through the dequant-fused "
             "ops/quant_ops.dequant_matmul kernel.  '' = off; 'int8' = "
-            "symmetric int8; 'fp8_e4m3' = float8 e4m3 where the "
-            "installed jax has the dtype (probed via jax_compat, falls "
-            "back to int8 with quant_fp8_unavailable counted).  "
+            "symmetric int8; 'fp8_e4m3' = float8 e4m3 (jnp.float8_e4m3fn).  "
             "Per-program override: slim.quantization.mark_weight_quant",
             affects_lowering=True)
 define_flag("elastic_max_restarts", 3,
@@ -347,13 +339,11 @@ define_flag("elastic_max_restarts", 3,
             "flagship rounds share the same budget for device-failure "
             "retries")
 define_flag("elastic_preflight_timeout_s", 240.0,
-            "deadline for ONE subprocess-isolated device preflight "
-            "probe (fleet.elastic.preflight_device: import jax + a "
-            "tiny jit dispatch in a CHILD process, so a wedged backend "
-            "can never hang the supervisor itself); this is the BENCH "
-            "r04/r05 'device init did not complete within 240s' bound, "
-            "now a structured init_timeout verdict retried with "
-            "backoff instead of a zeroed round")
+            "deadline for ONE device preflight probe "
+            "(fleet.elastic.preflight_device: a tiny jit dispatch on a "
+            "daemon thread of the calling process, which holds or is "
+            "about to hold the chip); past it the caller gets a "
+            "structured init_timeout verdict, retried with backoff")
 define_flag("elastic_backoff_s", 10.0,
             "base backoff between elastic restart/preflight attempts; "
             "attempt k sleeps backoff * 2^(k-1) — exponential, so a "

@@ -334,11 +334,10 @@ class BenchmarkCallback(Callback):
                 out["examples_per_sec"] = round(
                     self.batch_size * self._steps / self._time, 3)
             if self.flops_per_step:
-                from ..framework import flags as _flags
+                from ..observe.device_peaks import peak_tflops
 
-                peak = self.peak_tflops if self.peak_tflops is not None \
-                    else float(_flags.flag("device_peak_tflops"))
-                if peak > 0.0:
+                peak = peak_tflops(self.peak_tflops)
+                if peak is not None:
                     mfu = observe.mfu_estimate(
                         self.flops_per_step, self._time / self._steps,
                         peak)

@@ -425,20 +425,11 @@ def _worker_loop(wid, n_workers, dataset, collate, init_fn, task_q,
     holding queue/file state."""
     import os
     import queue as _q
-    import sys
 
-    # Never touch the accelerator from a worker.  The env pin from the
-    # parent covers normal jax installs; site hooks that force the
-    # platform list post-import (overriding JAX_PLATFORMS) need the
-    # live config pinned too — without this, any stray jax.devices()
-    # in user dataset code would initialize the device backend from
-    # every worker.
+    # Never touch the accelerator from a worker: the chip belongs to the
+    # parent, and a stray jax.devices() in user dataset code must find
+    # the CPU only.  The parent pins JAX_PLATFORMS around spawn (below).
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    if "jax" in sys.modules:
-        try:
-            sys.modules["jax"].config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
     global _worker_info
     _worker_info = WorkerInfo(wid, n_workers, dataset)
     if init_fn is not None:

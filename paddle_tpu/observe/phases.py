@@ -54,6 +54,7 @@ from typing import Dict, List, Optional
 
 from ..framework import flags as _flags
 from ..monitor import stat_add, stat_set
+from . import device_peaks as _peaks
 
 __all__ = ["PhasePlan", "PhaseEngine", "phase_engine", "build_phase_plan",
            "collective_inventory", "on_step_drained", "phases_report",
@@ -257,7 +258,7 @@ class PhasePlan:
         self._recost()
 
     def _recost(self) -> None:
-        peak = float(_flags.flag("device_peak_tflops") or 0.0) * 1e12
+        peak = (_peaks.peak_tflops() or 0.0) * 1e12
         bw = float(_flags.flag("phase_interconnect_gbps") or 0.0) * 1e9
         self.compute_s = (self.flops_per_step / peak) if peak > 0 else 0.0
         budget = self.compute_s

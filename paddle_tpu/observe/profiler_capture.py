@@ -21,9 +21,9 @@ human looks.
   ``capture_s / continuous_s``) into a 2-deep rotating directory set —
   the always-on-fleet profiling mode, without bundles.
 
-Capability-guarded like the AOT stages: ``jax_compat.profiler_start``
-probes the installed jax, a backend that cannot trace counts
-``prof_trace_unavailable`` and the phase snapshot still lands.  Trace
+A trace that cannot start (another ``jax.profiler`` trace is already
+live in the process) counts ``prof_trace_unavailable`` and the phase
+snapshot still lands.  Trace
 directories are summarized best-effort (file count/bytes + event count
 where the chrome-trace JSON is readable) — parsing failures degrade to
 the raw listing, never to a lost capture.
@@ -46,6 +46,27 @@ __all__ = ["CaptureEngine", "capture_engine", "on_step_drained",
 
 BASELINE_WINDOW = 64   # rolling step-time samples behind the median
 BASELINE_WARMUP = 8    # steps before the trigger may fire
+
+
+def _start_trace(log_dir: str) -> bool:
+    """Begin a ``jax.profiler`` trace; False when one is already live
+    (the profiler allows one per process)."""
+    import jax
+
+    try:
+        jax.profiler.start_trace(log_dir)
+    except RuntimeError:
+        return False
+    return True
+
+
+def _stop_trace() -> None:
+    import jax
+
+    try:
+        jax.profiler.stop_trace()
+    except RuntimeError:  # the other owner stopped it first
+        pass
 
 
 def _burning_slo() -> Optional[str]:
@@ -173,7 +194,6 @@ class CaptureEngine:
         t.start()
 
     def _capture(self, trigger: str) -> None:
-        from ..framework import jax_compat
         from . import flight as _flight
         from . import health as _health
 
@@ -185,7 +205,7 @@ class CaptureEngine:
         started = False
         try:
             os.makedirs(trace_dir, exist_ok=True)
-            started = jax_compat.profiler_start(trace_dir)
+            started = _start_trace(trace_dir)
         except OSError:
             pass
         if not started:
@@ -195,7 +215,7 @@ class CaptureEngine:
         if started:
             # the bound: stop no matter what after capture_s
             time.sleep(capture_s)
-            jax_compat.profiler_stop()
+            _stop_trace()
         profiler = parse_trace_dir(trace_dir) if started else \
             {"unavailable": True}
         try:
@@ -243,7 +263,6 @@ class CaptureEngine:
         return True
 
     def _continuous_loop(self, period: float) -> None:
-        from ..framework import jax_compat
         from . import flight as _flight
 
         base = _flags.flag("postmortem_dir") or "postmortem"
@@ -263,11 +282,11 @@ class CaptureEngine:
                 os.makedirs(trace_dir, exist_ok=True)
             except OSError:
                 continue
-            if not jax_compat.profiler_start(trace_dir):
+            if not _start_trace(trace_dir):
                 stat_add("prof_trace_unavailable")
                 continue
             time.sleep(capture_s)
-            jax_compat.profiler_stop()
+            _stop_trace()
             stat_add("prof_continuous_captures")
             _flight.record("prof/continuous_window",
                            **parse_trace_dir(trace_dir))

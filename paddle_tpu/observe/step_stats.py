@@ -33,7 +33,7 @@ from __future__ import annotations
 import threading
 from typing import Dict, Optional
 
-from ..framework import flags as _flags
+from . import device_peaks as _peaks
 from .histogram import histogram, stat_time
 
 __all__ = ["STEP_TIME_HISTOGRAM", "StepTimer", "step_timer",
@@ -45,13 +45,17 @@ STEP_TIME_HISTOGRAM = "step_time_seconds"
 def mfu_estimate(flops_per_step: float, step_time_s: float,
                  peak_tflops: Optional[float] = None) -> float:
     """Model FLOPs utilization: achieved / peak.  ``peak_tflops``
-    defaults to ``FLAGS_device_peak_tflops``."""
+    defaults to ``FLAGS_device_peak_tflops``, and that to the live
+    device's row in ``observe/device_peaks.py``; with neither there is
+    nothing to divide by and asking is an error."""
     if step_time_s <= 0.0 or flops_per_step <= 0.0:
         return 0.0
-    peak = peak_tflops if peak_tflops is not None \
-        else float(_flags.flag("device_peak_tflops"))
-    if peak <= 0.0:
-        return 0.0
+    peak = _peaks.peak_tflops(peak_tflops)
+    if peak is None:
+        raise ValueError(
+            "no peak FLOP/s for this device: it is not in "
+            "observe/device_peaks.py and FLAGS_device_peak_tflops is "
+            "unset; an MFU needs one of the two")
     return (flops_per_step / step_time_s) / (peak * 1e12)
 
 
@@ -145,16 +149,15 @@ class StepTimer:
             out["allreduce_bytes_per_step"] = ar_bytes // steps
             if flops:
                 out["flops_per_step"] = int(flops / steps)
-                peak = peak_tflops if peak_tflops is not None \
-                    else float(_flags.flag("device_peak_tflops"))
-                if peak > 0.0:
+                peak = _peaks.peak_tflops(peak_tflops)
+                if peak is not None:
                     # significant digits, not decimal places: a toy
                     # model's 1e-6 MFU must not round to a dead zero
                     out["mfu"] = float(
                         f"{mfu_estimate(flops / steps, et / steps, peak):.4g}")
                 else:
-                    # FLAGS_device_peak_tflops unset/zero: there is no
-                    # denominator — null, not a misleading 0.0
+                    # no flag and no table row for this device: there
+                    # is no denominator — null, not a misleading 0.0
                     out["mfu"] = None
         return out
 

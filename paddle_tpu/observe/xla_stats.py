@@ -14,9 +14,9 @@ rebuilt on what jax actually exposes:
   histogram, executable size + HLO module stats as ``/metrics`` gauges,
   an ``executor/compile_done`` flight event with the duration, and an
   optional optimized-HLO dump (``FLAGS_hlo_dump_dir``).
-- **HBM accounting** — ``compiled.memory_analysis()`` (guarded through
-  ``framework/jax_compat.py``; per-chip under SPMD, since the analyzed
-  module is the partitioned per-device program) becomes a footprint
+- **HBM accounting** — ``compiled.memory_analysis()`` (per-chip under
+  SPMD, since the analyzed module is the partitioned per-device
+  program) becomes a footprint
   breakdown (arguments / outputs / temporaries / generated code), and
   the :class:`~..framework.passes.TPShardingPlan` + scope var sizes
   join into a top-N per-var attribution table — the thing that says
@@ -44,7 +44,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..framework import flags as _flags
-from ..framework import jax_compat as _jc
 from . import flight as _flight
 from .histogram import stat_time
 
@@ -104,25 +103,22 @@ def _mb(nbytes) -> float:
 
 
 # ---------------------------------------------------------------------------
-# compiled-executable readings (all capability-guarded via jax_compat)
+# compiled-executable readings
 # ---------------------------------------------------------------------------
 
 
 def memory_breakdown(compiled) -> Optional[Dict[str, int]]:
     """Per-chip footprint breakdown from ``compiled.memory_analysis()``
-    or None when this jax cannot say.  ``total_bytes`` is the predicted
+    or None where the backend reports none.  ``total_bytes`` is the predicted
     live-at-once HBM need: arguments + outputs + temporaries +
     generated code, minus the aliased (donated-in-place) bytes that
     would otherwise count twice."""
-    m = _jc.compiled_memory_stats(compiled)
+    m = compiled.memory_analysis()
     if m is None:
         return None
 
     def _get(attr):
-        try:
-            return max(int(getattr(m, attr, 0) or 0), 0)
-        except (TypeError, ValueError):
-            return 0
+        return max(int(getattr(m, attr) or 0), 0)
 
     args = _get("argument_size_in_bytes")
     outs = _get("output_size_in_bytes")
@@ -142,14 +138,8 @@ def memory_breakdown(compiled) -> Optional[Dict[str, int]]:
 def cost_flops(compiled) -> Optional[float]:
     """FLOPs of one executable call per ``compiled.cost_analysis()``
     (per-chip under SPMD), or None when unavailable."""
-    c = _jc.compiled_cost_analysis(compiled)
-    if not c:
-        return None
-    f = c.get("flops")
-    try:
-        f = float(f)
-    except (TypeError, ValueError):
-        return None
+    c = compiled.cost_analysis()
+    f = float((c or {}).get("flops") or 0.0)
     return f if f > 0.0 else None
 
 
@@ -223,7 +213,12 @@ def format_attribution(rows: Sequence[dict], limit: Optional[int] = None
 def device_memory_stats(device=None) -> Optional[dict]:
     """Live ``device.memory_stats()`` as a plain dict, or None where
     the backend has none (CPU)."""
-    return _jc.device_memory_stats(device)
+    if device is None:
+        import jax
+
+        device = jax.local_devices()[0]
+    ms = device.memory_stats()
+    return dict(ms) if ms else None
 
 
 def device_hbm_capacity(device=None) -> Optional[int]:
@@ -408,7 +403,8 @@ def on_compile(compiled, *, fingerprint: str = "", seconds: float = 0.0,
     if breakdown:
         exec_size = breakdown["generated_code_bytes"]
     if exec_size <= 0:
-        exec_size = _jc.executable_code_bytes(compiled)
+        exec_size = int(compiled.runtime_executable()
+                        .size_of_generated_code_in_bytes())
     # the optimized-HLO text is rendered ONLY when something needs it —
     # a dump dir, or a backend that reports no code size (the text
     # length is then the honest proxy for "how big did this program
@@ -418,7 +414,7 @@ def on_compile(compiled, *, fingerprint: str = "", seconds: float = 0.0,
     # exists to observe.
     hlo_text = None
     if exec_size <= 0 or _flags.flag("hlo_dump_dir"):
-        hlo_text = _jc.compiled_text(compiled)
+        hlo_text = compiled.as_text()
     if exec_size <= 0 and hlo_text:
         exec_size = len(hlo_text)
         rec["executable_size_is_hlo_text"] = True

@@ -160,8 +160,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, m_scr,
         o_ref[0] = (acc_scr[...] / safe).astype(o_ref.dtype)
         # per-row softmax statistic the backward recompute needs:
         # lse = m + log(l); fully-masked rows pin to -inf
-        lse = jnp.where(l == 0.0, _NEG_INF, m_scr[:, :1] + jnp.log(safe))
-        lse_ref[0] = lse[:, 0]
+        lse_ref[0] = jnp.where(l == 0.0, _NEG_INF,
+                               m_scr[:, :1] + jnp.log(safe))
 
 
 def _fwd_call(q, k, v, bias, sm_scale, causal, block_q, block_k,
@@ -190,11 +190,14 @@ def _fwd_call(q, k, v, bias, sm_scale, causal, block_q, block_k,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qb, kb: (bh, qb, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, qb, kb: (bh, qb)),
+            # row stats keep a trailing unit dim: a (1, block_q) block of
+            # a (BH, Sq) array is not a legal Mosaic tile (last two block
+            # dims must divide by (8, 128) or equal the array's)
+            pl.BlockSpec((1, block_q, 1), lambda bh, qb, kb: (bh, qb, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
@@ -234,10 +237,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         do = do_ref[0].astype(jnp.float32)
         s = _tile_scores(q, k, bias_ref, bias_mode, qb, kb, sm_scale,
                          causal, block_q, block_k)
-        p = jnp.exp(s - lse_ref[0][:, None])             # (BQ, BK)
+        p = jnp.exp(s - lse_ref[0])                      # (BQ, BK)
         dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * sm_scale
+        ds = p * (dp - delta_ref[0]) * sm_scale
         dq_scr[...] += lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -270,14 +273,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         do = do_ref[0].astype(jnp.float32)
         s = _tile_scores(q, k, bias_ref, bias_mode, qb, kb, sm_scale,
                          causal, block_q, block_k)
-        p = jnp.exp(s - lse_ref[0][:, None])             # (BQ, BK)
+        p = jnp.exp(s - lse_ref[0])                      # (BQ, BK)
         # dv += p^T do  — contract the q dim without materializing p^T
         dv_scr[...] += lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * sm_scale
+        ds = p * (dp - delta_ref[0]) * sm_scale
         dk_scr[...] += lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -301,11 +304,11 @@ def _bwd_call(q, k, v, bias, out, lse, do, sm_scale, causal, block_q,
     kf = k.reshape(bh, sk, d)
     vf = v.reshape(bh, sk, d)
     dof = do.reshape(bh, sq, d)
-    lsef = lse.reshape(bh, sq)
+    lsef = lse.reshape(bh, sq, 1)
     # delta_i = do_i . o_i — one O(N*D) pass in plain jnp, shared by
     # both kernels (the canonical flash backward precompute)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(bh, sq)
+                    axis=-1).reshape(bh, sq, 1)
 
     bias_arr = bias if bias is not None else jnp.zeros((1, 1, 1, 1),
                                                        q.dtype)
@@ -323,8 +326,8 @@ def _bwd_call(q, k, v, bias, out, lse, do, sm_scale, causal, block_q,
             pl.BlockSpec((1, block_k, d), lambda g, qb, kb: (g, kb, 0)),
             bias_spec_q,
             pl.BlockSpec((1, block_q, d), lambda g, qb, kb: (g, qb, 0)),
-            pl.BlockSpec((1, block_q), lambda g, qb, kb: (g, qb)),
-            pl.BlockSpec((1, block_q), lambda g, qb, kb: (g, qb)),
+            pl.BlockSpec((1, block_q, 1), lambda g, qb, kb: (g, qb, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda g, qb, kb: (g, qb, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d),
                                lambda g, qb, kb: (g, qb, 0)),
@@ -347,8 +350,8 @@ def _bwd_call(q, k, v, bias, out, lse, do, sm_scale, causal, block_q,
             pl.BlockSpec((1, block_k, d), lambda g, kb, qb: (g, kb, 0)),
             bias_spec_k,
             pl.BlockSpec((1, block_q, d), lambda g, kb, qb: (g, qb, 0)),
-            pl.BlockSpec((1, block_q), lambda g, kb, qb: (g, qb)),
-            pl.BlockSpec((1, block_q), lambda g, kb, qb: (g, qb)),
+            pl.BlockSpec((1, block_q, 1), lambda g, kb, qb: (g, qb, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda g, kb, qb: (g, qb, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda g, kb, qb: (g, kb, 0)),
@@ -427,8 +430,9 @@ def flash_attention(q, k, v, mask=None, *, sm_scale=None, causal=False,
 
     ``use_pallas``: True forces the Pallas kernels (``interpret=True``
     runs them on CPU for tests), False forces the jnp reference, None
-    picks Pallas on TPU at kernel-aligned shapes and the reference
-    everywhere else — the CPU/tier-1 default stays pure jnp.
+    picks Pallas on TPU and the reference on the CPU; a TPU shape the
+    tiling cannot cover takes the reference and counts
+    ``flash_attention_refused_shape``.
     Differentiable in q/k/v via the custom VJP (tiled recompute
     backward); the mask is treated as a constant."""
     if q.ndim != 4:
@@ -440,8 +444,12 @@ def flash_attention(q, k, v, mask=None, *, sm_scale=None, causal=False,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if use_pallas is None:
-        use_pallas = (jax.default_backend() == "tpu"
-                      and _shape_ok(sq, sk, d))
+        use_pallas = jax.default_backend() == "tpu"
+        if use_pallas and not _shape_ok(sq, sk, d):
+            from ..monitor import stat_add
+
+            stat_add("flash_attention_refused_shape")  # never in silence
+            use_pallas = False
     if not use_pallas:
         return flash_attention_ref(q, k, v, mask, sm_scale=sm_scale,
                                    causal=causal)

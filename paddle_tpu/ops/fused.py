@@ -69,13 +69,19 @@ def _flash_engaged(b, h, sq, sk, d):
     'always' engages at any aligned shape (A/B testing, memory-bound
     configs the heuristic misses); 'never' forces the plain path."""
     mode = _flash_mode()
-    if mode == "never" or not _shape_ok(sq, sk, d):
+    if mode == "never":
         return False
     if not (_FORCE_INTERPRET or jax.default_backend() == "tpu"):
         return False
-    if mode == "always":
-        return True
-    return 4 * b * h * sq * sk > (2 << 30)
+    wanted = mode == "always" or 4 * b * h * sq * sk > (2 << 30)
+    if wanted and not _shape_ok(sq, sk, d):
+        # the plain path stands in for a kernel the flag asked for:
+        # never in silence (a smoke or a bench reads this counter)
+        from ..monitor import stat_add
+
+        stat_add("flash_attention_refused_shape")
+        return False
+    return wanted
 
 
 @register_lower("fused_multihead_attention")

@@ -8,10 +8,8 @@ per-layer outputs come back as stacked ys.  The RNG key threads through
 the carry so the split chain is BITWISE the one the unrolled program
 would draw (iteration k performs exactly the splits unrolled layer k
 performed, in the same order).  The body is optionally wrapped in
-``jax.checkpoint`` under the pass's remat policy
-(framework/jax_compat.py guarded accessors; a jax without
-``checkpoint_policies`` degrades to plain checkpoint and counts
-``remat_policy_unavailable``).
+``jax.checkpoint`` under the pass's remat policy (``wrap_checkpoint``
+below maps the framework's policy names onto ``jax.checkpoint_policies``).
 
 ``layer_index`` — materializes one per-layer member out of a stacked
 carrier for the few consumers the pass left unrolled (an edge layer a
@@ -19,11 +17,47 @@ trimmed run excluded, a fetch of a mid-stack activation).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+from jax import lax
 
-from ..framework import jax_compat as _jc
 from ..framework.lowering import (LoweringContext, apply_tp_constraints,
                                   get_lowering, register_lower)
+
+# framework-facing policy names -> jax.checkpoint_policies attr names
+# ("save_anything" is this framework's spelling of "do not recompute
+# anything the body produced" == everything_saveable)
+_CHECKPOINT_POLICY_NAMES = {
+    "nothing_saveable": "nothing_saveable",
+    "dots_saveable": "dots_saveable",
+    "checkpoint_dots": "dots_saveable",  # historical jax alias
+    "save_anything": "everything_saveable",
+    "everything_saveable": "everything_saveable",
+    "dots_with_no_batch_dims_saveable": "dots_with_no_batch_dims_saveable",
+}
+
+REMAT_POLICIES = tuple(_CHECKPOINT_POLICY_NAMES)
+
+
+def checkpoint_policy(name):
+    """The ``jax.checkpoint_policies`` callable behind a framework
+    policy name; None for the empty name (no remat wrap)."""
+    if not name:
+        return None
+    if name not in _CHECKPOINT_POLICY_NAMES:
+        raise ValueError(f"unknown remat policy {name!r}; expected one of "
+                         f"{sorted(REMAT_POLICIES)}")
+    return getattr(jax.checkpoint_policies, _CHECKPOINT_POLICY_NAMES[name])
+
+
+def wrap_checkpoint(fn, policy_name: str = ""):
+    """``jax.checkpoint(fn, policy=<resolved>)``.  With ``policy_name``
+    empty the wrap is skipped entirely — primal values are
+    bitwise-identical either way, so the un-wrapped body stays the
+    cheapest default."""
+    if not policy_name:
+        return fn
+    return jax.checkpoint(fn, policy=checkpoint_policy(policy_name))
 
 
 def _ints(op, name):
@@ -116,12 +150,12 @@ def _layer_scan(ctx: LoweringContext, op):
             return (new_key,) + new_carry, ys
         return new_carry, ys
 
-    body = _jc.wrap_checkpoint(body, str(op.attr("remat_policy", "") or ""))
+    body = wrap_checkpoint(body, str(op.attr("remat_policy", "") or ""))
     init_carry = ((ctx.rng_key,) + init) if has_key else init
-    final_carry, ys_stacks = _jc.scan(
+    final_carry, ys_stacks = lax.scan(
         body, init_carry, tuple(xs_vals) if xs_vals else None,
         length=n_layers,
-        unroll=int(flags.flag("layer_scan_unroll") or 1))
+        unroll=max(int(flags.flag("layer_scan_unroll") or 1), 1))
 
     if has_key:
         new_key, final_vals = final_carry[0], final_carry[1:]

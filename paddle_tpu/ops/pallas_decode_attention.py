@@ -25,11 +25,14 @@ page table (O(S * max_seq) materialization) and do masked attention.
 It is also the CPU-backend default so tier-1 stays green without
 Mosaic; ``interpret=True`` runs the real kernel on CPU for tests.
 
-``paged_chunk_attention`` generalizes the kernel to R query rows per
-slot with per-row causal lengths over one shared page table — the
-attention shape of chunked/suffix prefill and speculative verification
+``paged_chunk_attention`` is the kernel itself: R query rows per slot
+with per-row causal lengths over one shared page table — the attention
+shape of chunked/suffix prefill and speculative verification
 (serving/decode.py), where shared and partially-filled pages need no
-special casing beyond the mask.
+special casing beyond the mask.  One-token decode is that kernel at
+R=1: Mosaic has no matmul for a bare (H, D) query with batch H and
+contraction D, so the query keeps its row dimension even when it is 1
+(tests/test_tpu_compile.py compiles both shapes for the v5e).
 """
 from __future__ import annotations
 
@@ -68,119 +71,6 @@ def decode_attention_reference(q, k, v, lengths, *, sm_scale=None):
     return out.astype(q.dtype)
 
 
-def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                   sm_scale, page, n_pages, quantized=False):
-    import jax.experimental.pallas as pl
-
-    if quantized:
-        # int8 pages ride with their per-page scale planes; the
-        # dequant happens HERE, on the tile already in VMEM — the f32
-        # K/V never exists in HBM (the dequant-fused contract)
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-        ks_ref = vs_ref = None
-
-    s_idx = pl.program_id(0)
-    p_idx = pl.program_id(1)
-
-    @pl.when(p_idx == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[s_idx]
-    # pages wholly past the live length contribute nothing — skip the
-    # compute (the DMA still landed, clamped to a valid pool index)
-    @pl.when(p_idx * page < length)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)              # (H, D)
-        k = k_ref[0].astype(jnp.float32)              # (page, H, D)
-        v = v_ref[0].astype(jnp.float32)
-        if ks_ref is not None:
-            k = k * ks_ref[0].astype(jnp.float32)[..., None]
-            v = v * vs_ref[0].astype(jnp.float32)[..., None]
-        # scores per head over this page's positions: (H, page)
-        s = lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * sm_scale
-        pos = p_idx * page + lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, _NEG_INF)
-
-        m_prev = m_scr[:, :1]                          # (H, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                         # (H, page)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
-            p, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(p_idx == n_pages - 1)
-    def _flush():
-        l = l_scr[:, :1]
-        o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(
-            o_ref.dtype)
-
-
-def _paged_call(q, k_pages, v_pages, page_table, lengths, sm_scale,
-                interpret, k_scales=None, v_scales=None):
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_slots, h, d = q.shape
-    pps = page_table.shape[1]
-    page = k_pages.shape[1]
-    flat_table = page_table.reshape(-1).astype(jnp.int32)
-    quantized = k_scales is not None
-
-    in_specs = [
-        pl.BlockSpec((1, h, d), lambda s, p, pt, ln: (s, 0, 0)),
-        # THE paged-attention move: the K/V block index is read out
-        # of the prefetched page table, so each grid step DMAs one
-        # pool page — no gather materialization
-        pl.BlockSpec((1, page, h, d),
-                     lambda s, p, pt, ln: (pt[s * pps + p], 0, 0, 0)),
-        pl.BlockSpec((1, page, h, d),
-                     lambda s, p, pt, ln: (pt[s * pps + p], 0, 0, 0)),
-    ]
-    operands = [q, k_pages, v_pages]
-    if quantized:
-        # the scale planes ride the same page-id indexing as the pages
-        in_specs += [
-            pl.BlockSpec((1, page, h),
-                         lambda s, p, pt, ln: (pt[s * pps + p], 0, 0)),
-            pl.BlockSpec((1, page, h),
-                         lambda s, p, pt, ln: (pt[s * pps + p], 0, 0)),
-        ]
-        operands += [k_scales, v_scales]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # (flat page table, lengths)
-        grid=(n_slots, pps),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, d), lambda s, p, pt, ln: (s, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((h, _LANES), jnp.float32),   # running denom
-            pltpu.VMEM((h, d), jnp.float32),        # output accumulator
-        ],
-    )
-    kern = functools.partial(_decode_kernel, sm_scale=sm_scale,
-                             page=page, n_pages=pps,
-                             quantized=quantized)
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_slots, h, d), q.dtype),
-        interpret=interpret,
-    )(flat_table, lengths.astype(jnp.int32), *operands)
-
-
 def _gather_dequant(pages, scales, page_table):
     """Reference-path page gather: [S, pps*page, H, D] at full width,
     dequantized inline when a scale pool rides along."""
@@ -216,9 +106,12 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         use_pallas = "always" if jax.default_backend() == "tpu" \
             else "never"
     if use_pallas == "always":
-        return _paged_call(q, k_pages, v_pages, page_table, lengths,
-                           float(sm_scale), interpret,
-                           k_scales=k_scales, v_scales=v_scales)
+        # one query row per slot IS the chunk kernel at R=1: Mosaic has
+        # no matmul for a bare (H, D) left operand with batch H and
+        # contraction D (no free row dimension), so the row axis stays
+        return _chunk_call(q[:, None], k_pages, v_pages, page_table,
+                           lengths[:, None], float(sm_scale), interpret,
+                           k_scales=k_scales, v_scales=v_scales)[:, 0]
     # reference: gather the page table to full width, then mask
     k = _gather_dequant(k_pages, k_scales, page_table)
     v = _gather_dequant(v_pages, v_scales, page_table)

@@ -228,21 +228,11 @@ WEIGHT_QUANT_MODES = ("int8", "fp8_e4m3")
 
 
 def resolve_quant_mode(mode: str) -> str:
-    """Validate a weight-quant mode string, degrading ``fp8_e4m3`` to
-    ``int8`` (counted as ``quant_fp8_unavailable``) when the installed
-    jax lacks the dtype."""
+    """Validate a weight-quant mode string."""
     if mode not in WEIGHT_QUANT_MODES:
         raise ValueError(
             f"unknown weight-quant mode {mode!r}; expected one of "
             f"{WEIGHT_QUANT_MODES}")
-    if mode == "fp8_e4m3":
-        from ..framework import jax_compat
-
-        if jax_compat.float8_e4m3_dtype() is None:
-            from ..monitor import stat_add
-
-            stat_add("quant_fp8_unavailable")
-            return "int8"
     return mode
 
 
@@ -265,10 +255,8 @@ def quantize_weight(w, axis: int, mode: str = "int8"):
         q = jnp.clip(jnp.round(scaled), -INT8_QMAX, INT8_QMAX) \
             .astype(jnp.int8)
     else:
-        from ..framework import jax_compat
-
-        fp8 = jax_compat.float8_e4m3_dtype()
-        q = jnp.clip(scaled, -FP8_E4M3_MAX, FP8_E4M3_MAX).astype(fp8)
+        q = jnp.clip(scaled, -FP8_E4M3_MAX, FP8_E4M3_MAX) \
+            .astype(jnp.float8_e4m3fn)
     return q, scale.astype(jnp.float32)
 
 
@@ -306,10 +294,8 @@ def quantize_weight_stacked(w, axis: int, mode: str = "int8"):
         q = jnp.clip(jnp.round(scaled), -INT8_QMAX, INT8_QMAX) \
             .astype(jnp.int8)
     else:
-        from ..framework import jax_compat
-
-        fp8 = jax_compat.float8_e4m3_dtype()
-        q = jnp.clip(scaled, -FP8_E4M3_MAX, FP8_E4M3_MAX).astype(fp8)
+        q = jnp.clip(scaled, -FP8_E4M3_MAX, FP8_E4M3_MAX) \
+            .astype(jnp.float8_e4m3fn)
     return q, scale.astype(jnp.float32)
 
 
@@ -342,15 +328,30 @@ def _dequant_matmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_scr, *, n_k):
         o_ref[...] = acc_scr[...].astype(o_ref.dtype)
 
 
+def _tile(n, whole_up_to, candidates):
+    """A legal Mosaic block extent for a dim of size ``n``: the whole
+    dim when small, else the largest candidate that divides it."""
+    if n <= whole_up_to:
+        return n
+    return next((c for c in candidates if n % c == 0), None)
+
+
+def _dequant_tiles(m, k, n):
+    """(bm, bk, bn) or None where no aligned tiling covers the shape
+    (e.g. a vocab head whose width is not a multiple of 128)."""
+    tiles = (_tile(m, 256, (256, 128, 64, 32, 16, 8)),
+             _tile(k, 512, (512, 256, 128)),
+             _tile(n, 256, (256, 128)))
+    return None if None in tiles else tiles
+
+
 def _dequant_matmul_call(x, qw, scale, out_dtype, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     m, k = x.shape
     _, n = qw.shape
-    bm = min(m, 256)
-    bk = min(k, 512)
-    bn = min(n, 256)
+    bm, bk, bn = _dequant_tiles(m, k, n)
     grid = (m // bm, n // bn, k // bk)
     kern = functools.partial(_dequant_matmul_kernel, n_k=grid[2])
     return pl.pallas_call(
@@ -384,10 +385,7 @@ def dequant_matmul(x, qw, scale, *, use_pallas="auto", interpret=False,
         use_pallas = "always" if jax.default_backend() == "tpu" \
             else "never"
     if use_pallas == "always":
-        m, k = x.shape
-        n = qw.shape[1]
-        if m % min(m, 256) == 0 and k % min(k, 512) == 0 \
-                and n % min(n, 256) == 0:
+        if _dequant_tiles(*x.shape, qw.shape[1]) is not None:
             return _dequant_matmul_call(x, qw, scale, out_dtype,
                                         interpret)
         from ..monitor import stat_add

@@ -615,24 +615,32 @@ class DecodeEngine:
                     f" is shorter than max_seq_len ({c.max_seq_len})")
         self._scope = Scope()
         self._exe = Executor(place)
-        self._cache = PagedKVCache(
-            CacheConfig(model.num_layers, model.num_heads, model.head_dim,
-                        c.slots, c.max_seq_len, c.page_size,
-                        num_pages=c.num_pages, dtype=c.cache_dtype,
-                        quantized=c.kv_quant),
-            self._scope, prefix_cache=c.prefix_cache)
+        # an explicit ``place`` PINS this replica: weights and page
+        # pools are committed to its device, and every jitted step
+        # follows its committed operands there (DecodeServer/
+        # DisaggServer hand replica i local device i mod n).  place=None
+        # leaves everything uncommitted on the default device, which is
+        # also what mesh-sharded (expert-parallel) weights need
+        self._device = self._exe.place.jax_device()
+        self._pinned = place is not None
+        with jax.default_device(self._device):
+            self._cache = PagedKVCache(
+                CacheConfig(model.num_layers, model.num_heads,
+                            model.head_dim, c.slots, c.max_seq_len,
+                            c.page_size, num_pages=c.num_pages,
+                            dtype=c.cache_dtype, quantized=c.kv_quant),
+                self._scope, prefix_cache=c.prefix_cache)
         # per-request timeline hook: claim/CoW/register/evict events
         # from the cache land on the owning request's trace
         self._cache.on_event = self._on_cache_event
         self._admitting = None  # request whose claim() is in flight
-        self.weights = jax.tree_util.tree_map(jax.numpy.asarray, weights)
+        self.weights = self._commit(weights)
         # persistent-state tuples every jitted step threads (the scale
         # pools join them under FLAGS_decode_kv_quant)
         self._state_vars = self._cache.state_var_names()
         self._draft_state_vars = ()
         if draft_model is not None:
-            self.draft_weights = jax.tree_util.tree_map(
-                jax.numpy.asarray, draft_weights)
+            self.draft_weights = self._commit(draft_weights)
             cc = self._cache.config
             dshape = (draft_model.num_layers, cc.num_pages, cc.page_size,
                       draft_model.num_heads, draft_model.head_dim)
@@ -654,6 +662,8 @@ class DecodeEngine:
                 # cover the draft pools too (same page ids)
                 self._cache.scale_vars += [DRAFT_K_SCALES_VAR,
                                            DRAFT_V_SCALES_VAR]
+        for nm in self._state_vars + self._draft_state_vars:
+            self._scope.set_var(nm, self._commit(self._scope.get_var(nm)))
         self._buckets = BucketSpec(
             (1,), prefill_bucket_grid(c.max_seq_len, c.page_size))
         self._step_fn = self._build_step_fn(model)
@@ -678,6 +688,27 @@ class DecodeEngine:
         self._prefill_chunk_count = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
+
+    def _commit(self, tree):
+        """Device arrays for ``tree``; on a pinned replica every leaf
+        that is not already spread over a mesh is committed to the
+        replica's device."""
+        import jax
+        import jax.numpy as jnp
+
+        def leaf(x):
+            if self._pinned and not (
+                    isinstance(x, jax.Array)
+                    and len(x.sharding.device_set) > 1):
+                return jax.device_put(x, self._device)
+            return jnp.asarray(x)
+
+        return jax.tree_util.tree_map(leaf, tree)
+
+    @property
+    def device(self):
+        """The jax device this replica's weights and page pools live on."""
+        return self._device
 
     @property
     def spec_enabled(self) -> bool:
@@ -1407,6 +1438,12 @@ class DecodeEngine:
 
     def _finish_slot(self, slot: int, error=None):
         st = self._slots[slot]
+        if error is None and self._abort:
+            # stop(drain=False) landed while this slot's dispatch was in
+            # flight: an aborted replica completes nothing, exactly as
+            # _loop fails every live slot it still finds (the disagg
+            # router re-dispatches the leg to a survivor)
+            error = ServerClosedError("engine stopped mid-generation")
         if error is None and st.req.extract_kv \
                 and st.phase == "decode":
             # export BEFORE _finish: the handoff thread wakes on the
@@ -1812,17 +1849,12 @@ class DecodeEngine:
         if normal:
             self._run_step(normal)
 
-    def _run_step(self, live_idx):
+    def _step_args(self, live_idx):
+        """Everything one joint decode step takes after its state
+        tuple: the weights plus per-slot feeds (dead slots zeroed)."""
         import jax.numpy as jnp
 
-        c = self._cache.config
-        s = c.num_slots
-        # copy-on-write any shared page this step would write (a
-        # borrowed partial tail at its first divergent token)
-        for i in live_idx:
-            if not self._slots[i].write_trash_once:
-                self._perform_cow(i, self._cache.plan_cow(
-                    i, [int(self._cache.lengths[i])]))
+        s = self._cache.config.num_slots
         tokens = np.zeros((s,), np.int32)
         positions = np.zeros((s,), np.int32)
         live = np.zeros((s,), bool)
@@ -1850,19 +1882,43 @@ class DecodeEngine:
             top_k[i] = st.req.top_k
             top_p[i] = st.req.top_p
             base_keys[i] = np.asarray(st.base_key)
+        return (self.weights, jnp.asarray(tokens),
+                jnp.asarray(positions), jnp.asarray(live),
+                jnp.asarray(self._cache.page_table),
+                jnp.asarray(write_page), jnp.asarray(write_off),
+                jnp.asarray(base_keys), jnp.asarray(counters),
+                jnp.asarray(temp), jnp.asarray(top_k),
+                jnp.asarray(top_p))
+
+    def lower_step(self, sharding=None):
+        """The joint decode step lowered at this engine's own shapes;
+        nothing runs.  ``.as_text()`` shows whether the Pallas kernel is
+        in it (``tpu_custom_call``), ``.compile()`` asks the compiler.
+        ``sharding`` re-targets every operand (e.g. to one device of a
+        described, unattached topology) by lowering from shapes."""
+        import jax
+
+        args = (tuple(self._scope.get_var(n) for n in self._state_vars),
+                *self._step_args(()))
+        if sharding is not None:
+            args = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=sharding), args)
+        return self._step_fn.lower(*args)
+
+    def _run_step(self, live_idx):
+        # copy-on-write any shared page this step would write (a
+        # borrowed partial tail at its first divergent token)
+        for i in live_idx:
+            if not self._slots[i].write_trash_once:
+                self._perform_cow(i, self._cache.plan_cow(
+                    i, [int(self._cache.lengths[i])]))
+        args = self._step_args(live_idx)
         t0 = time.monotonic()
         try:
             with otrace.span("serving/decode_step", live=len(live_idx)):
                 nxt, logits = self._exe.run_persistent(
-                    self._step_fn, self._state_vars,
-                    args=(self.weights, jnp.asarray(tokens),
-                          jnp.asarray(positions), jnp.asarray(live),
-                          jnp.asarray(self._cache.page_table),
-                          jnp.asarray(write_page),
-                          jnp.asarray(write_off),
-                          jnp.asarray(base_keys), jnp.asarray(counters),
-                          jnp.asarray(temp), jnp.asarray(top_k),
-                          jnp.asarray(top_p)),
+                    self._step_fn, self._state_vars, args=args,
                     scope=self._scope)
                 nxt = np.asarray(nxt)  # THE per-step sync point
         except Exception as e:  # noqa: BLE001 — fail the batch loudly,

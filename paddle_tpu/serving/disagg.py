@@ -245,16 +245,22 @@ class DisaggServer:
                  autoscale: bool = False,
                  autoscaler_kw: Optional[dict] = None):
         from .decode import DecodeConfig, DecodeEngine
+        from .server import replica_places
 
         self.config = config or DecodeConfig()
         self.disagg = disagg or DisaggConfig()
         d = self.disagg
         total = d.prefill_replicas + d.decode_replicas
+        # an explicit ``place`` keeps every replica there; otherwise the
+        # prefill and decode sets spread over the local chips, and a
+        # handoff is a chip-to-chip page copy (kv_cache.install_pages)
+        places = [place] * total if place is not None \
+            else replica_places(total, model)
         self._replicas: List[_Replica] = []
         for i in range(total):
             role = "prefill" if i < d.prefill_replicas else "decode"
-            eng = DecodeEngine(model, weights, self.config, place=place,
-                               name=f"disagg-{i}")
+            eng = DecodeEngine(model, weights, self.config,
+                               place=places[i], name=f"disagg-{i}")
             self._replicas.append(_Replica(i, eng, role))
         self._lock = threading.Lock()
         self._seq = 0  # router-level seed counter: both legs of one
@@ -420,15 +426,15 @@ class DisaggServer:
 
     @staticmethod
     def _same_backend(export: KVPageExport, engine) -> bool:
-        """True when the payload's buffers already live on the
-        destination engine's device (a pool-slice device copy is then
-        a no-transport scatter)."""
+        """True when the payload's buffers live on the destination
+        engine's platform: install_pages then moves them chip-to-chip
+        (``jax.device_put``), no host round trip."""
         try:
             from .kv_cache import K_PAGES_VAR
 
             src = next(iter(export.arrays.values())).devices()
             dst = engine._scope.get_var(K_PAGES_VAR).devices()
-            return src == dst
+            return {d.platform for d in src} == {d.platform for d in dst}
         except Exception:  # noqa: BLE001 — unknown topology: bounce
             return False
 
@@ -523,7 +529,7 @@ class Autoscaler:
     queue depth), ``preflight`` (the elastic supervisor's device
     probe), ``clock``/``sleep`` — so tests pin the policy without real
     traffic; the defaults read the live SLO plane and run the real
-    subprocess preflight."""
+    in-process preflight."""
 
     def __init__(self, server: DisaggServer,
                  burn_fn: Optional[Callable[[], float]] = None,

@@ -670,6 +670,7 @@ class PagedKVCache:
         ``migrate_pages_total`` / ``migrate_bytes_total`` /
         ``migrate_seconds``.  Engine-thread-only, like every pool
         mutation."""
+        import jax
         import jax.numpy as jnp
 
         t0 = time.monotonic()
@@ -697,6 +698,12 @@ class PagedKVCache:
                 raise ValueError(
                     f"migration payload {name} shape "
                     f"{tuple(arr.shape)} != expected {want}")
+            # the migration itself: a placed destination pool (a pinned
+            # replica's, or one a mesh-sharded step has written) pulls
+            # the payload to its own chips, device-to-device; a pool
+            # that is not committed anywhere yet follows the payload
+            if pool.committed:
+                arr = jax.device_put(arr, pool.sharding)
             self.scope.set_var(
                 name, pool.at[:, idx].set(jnp.asarray(arr, pool.dtype)))
         for pid in dst:

@@ -240,16 +240,30 @@ def least_loaded_order(engines):
     return [engines[i] for i in order]
 
 
+def replica_places(n: int, model=None):
+    """One process drives every local chip: replica ``i`` is pinned to
+    local device ``i mod n_devices`` (its weights copy, page pools and
+    steps all live there), a single replica included.  Only a model
+    whose weights are spread over a mesh (expert-parallel serving)
+    stays unpinned: its arrays follow the mesh."""
+    from ..framework.place import TPUPlace, accelerator_devices
+
+    if getattr(model, "moe_mesh", None) is not None:
+        return [None] * n
+    n_dev = len(accelerator_devices())
+    return [TPUPlace(i % n_dev) for i in range(n)]
+
+
 class DecodeServer:
     """N replicated decode engines (serving/decode.py) behind ONE
     admission point with least-loaded dispatch — the generative
     counterpart of ``Server``.
 
     Every replica is a full ``DecodeEngine``: its own Executor, slot
-    batch, and paged KV cache, all fed from the shared (read-only)
-    weight arrays.  ``submit`` routes each request to the replica with
-    the most free slots (ties: shortest queue), falling back across
-    replicas when one's queue is full.  Per-request sampling is keyed
+    batch, paged KV cache and weights copy, pinned to its own local
+    device (:func:`replica_places`).  ``submit`` routes each request
+    to the replica with the most free slots (ties: shortest queue),
+    falling back across replicas when one's queue is full.  Per-request sampling is keyed
     by the request's own seed, so WHICH replica serves a request never
     changes its tokens (tests/test_decode_engine.py pins 2-replica parity).
 
@@ -267,10 +281,10 @@ class DecodeServer:
             raise ValueError("replicas must be >= 1")
         self._config = config or DecodeConfig()
         self._engines = [
-            DecodeEngine(model, weights, self._config,
+            DecodeEngine(model, weights, self._config, place=place,
                          name=f"replica-{i}", draft_model=draft_model,
                          draft_weights=draft_weights)
-            for i in range(replicas)
+            for i, place in enumerate(replica_places(replicas, model))
         ]
         self._http_port = http_port
         self._kv = None

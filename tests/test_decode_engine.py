@@ -363,6 +363,38 @@ def test_stop_without_drain_cancels(model_and_weights):
         r1.result(timeout=5)
 
 
+def test_stop_without_drain_fails_the_dispatch_in_flight(
+        model_and_weights):
+    """An aborted engine completes nothing: a request whose last
+    dispatch is already running when stop(drain=False) lands fails like
+    the slots the loop still finds live, whatever the timing (the disagg
+    router's kill-and-redispatch rests on this)."""
+    import threading
+
+    eng = make_engine(model_and_weights, slots=1).start()
+    real = eng._exe.run_persistent
+    entered, release = threading.Event(), threading.Event()
+
+    def held(*a, **kw):
+        entered.set()
+        assert release.wait(60)
+        return real(*a, **kw)
+
+    eng._exe.run_persistent = held
+    # one new token: the prefill dispatch is also the request's last
+    req = eng.submit([1, 2, 3], max_new_tokens=1)
+    assert entered.wait(60)
+    stopper = threading.Thread(target=eng.stop, kwargs={"drain": False})
+    stopper.start()
+    while not eng._abort:
+        time.sleep(0.001)
+    release.set()
+    with pytest.raises(serving.ServerClosedError):
+        req.result(timeout=60)
+    stopper.join(60)
+    assert not stopper.is_alive()
+
+
 # -- sampling determinism (satellite) -------------------------------------
 
 

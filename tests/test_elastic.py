@@ -40,52 +40,57 @@ def _chaos_and_postmortem(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# preflight: subprocess isolation + structured verdicts
+# preflight: in-process probe under a deadline + structured verdicts
 # ---------------------------------------------------------------------------
 
 
 class TestPreflight:
     def test_ok_probe_reports_platform(self):
         v = elastic.preflight_device(
-            attempts=1, timeout_s=30,
-            probe_code="print('PREFLIGHT_OK cpu')")
+            attempts=1, timeout_s=30, probe=lambda: "cpu")
         assert v.ok and v.verdict == "ok"
         assert v.platform == "cpu" and v.attempts == 1
         assert v.to_dict()["verdict"] == "ok"
 
     def test_init_timeout_bounded_with_exponential_backoff(self):
-        """A child that never finishes init cannot hang the caller:
+        """A probe that never finishes init cannot hang the caller:
         the deadline converts it to a structured init_timeout, and
         retries back off exponentially."""
+        import threading
+
         sleeps = []
-        v = elastic.preflight_device(
-            attempts=3, timeout_s=0.3, backoff_s=0.5,
-            probe_code="import time; time.sleep(60)",
-            sleep_fn=sleeps.append)
+        release = threading.Event()
+        try:
+            v = elastic.preflight_device(
+                attempts=3, timeout_s=0.3, backoff_s=0.5,
+                probe=lambda: release.wait(60), sleep_fn=sleeps.append)
+        finally:
+            release.set()  # let the three wedged probe threads go
         assert not v.ok and v.verdict == "init_timeout"
         assert v.attempts == 3
         assert sleeps == [0.5, 1.0]  # backoff * 2^k, no sleep after last
         assert "did not complete" in v.diag
 
-    def test_compile_error_carries_stderr_diag(self):
+    @staticmethod
+    def _kaboom():
+        raise RuntimeError("XLA kaboom")
+
+    def test_compile_error_carries_the_exception(self):
         v = elastic.preflight_device(
-            attempts=1, timeout_s=30,
-            probe_code="import sys; sys.stderr.write('XLA kaboom'); "
-                       "sys.exit(3)")
+            attempts=1, timeout_s=30, probe=self._kaboom)
         assert v.verdict == "compile_error" and not v.ok
-        assert "kaboom" in v.diag and "3" in v.diag
+        assert "kaboom" in v.diag and "RuntimeError" in v.diag
 
     def test_chaos_injected_timeout_then_recovers(self):
         """The r04/r05 failure on demand: one injected init-timeout,
-        then the retry succeeds — no subprocess spawned for the
+        then the retry succeeds — the probe does not run for the
         injected attempt."""
         chaos.inject("preflight_init_timeout", count=1)
         sleeps = []
         before = stat_get("elastic_preflight_init_timeout")
         v = elastic.preflight_device(
             attempts=2, timeout_s=5, backoff_s=0.1,
-            probe_code="print('PREFLIGHT_OK cpu')",
-            sleep_fn=sleeps.append)
+            probe=lambda: "cpu", sleep_fn=sleeps.append)
         assert v.ok and v.attempts == 2 and sleeps == [0.1]
         assert stat_get("elastic_preflight_init_timeout") == before + 1
         assert chaos.armed() == []  # consumed

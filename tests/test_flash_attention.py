@@ -38,12 +38,6 @@ from paddle_tpu.optimizer import MomentumOptimizer
 from paddle_tpu.param_attr import ParamAttr
 from paddle_tpu.ops import flash_attention as fa
 
-from conftest import jax_capability
-
-needs_pallas = pytest.mark.skipif(
-    not jax_capability("pallas_interpret"),
-    reason="no usable Pallas interpret mode on this jax")
-
 
 @pytest.fixture(autouse=True)
 def _flag_reset():
@@ -70,7 +64,6 @@ def _mask(rs, kind, B=1, H=2, S=256):
 # -- kernel vs jnp reference (interpret mode) -----------------------------
 
 
-@needs_pallas
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("mask_kind", ["none", "key", "full"])
 def test_forward_parity_vs_ref(causal, mask_kind):
@@ -86,7 +79,6 @@ def test_forward_parity_vs_ref(causal, mask_kind):
                                atol=1e-6, rtol=1e-5)
 
 
-@needs_pallas
 @pytest.mark.parametrize("causal", [False, True])
 def test_grad_parity_vs_ref(causal):
     """q/k/v cotangents through the tiled recompute backward match
@@ -109,7 +101,6 @@ def test_grad_parity_vs_ref(causal):
             err_msg=f"d{name} diverged from the reference vjp")
 
 
-@needs_pallas
 def test_mask_is_a_constant():
     """The fused op treats the additive mask as a constant: its
     cotangent is exactly zero (the pass refuses learnable masks for

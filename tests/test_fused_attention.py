@@ -81,3 +81,26 @@ def test_bert_builder_fused_matches_unfused():
     assert n1 < n2
     assert any(op.type == "fused_multihead_attention"
                for op in m1.global_block.ops)
+
+
+def test_flag_wanted_kernel_refused_by_shape_is_counted(monkeypatch):
+    """FLAGS_flash_attention=always at a shape the tiling cannot cover:
+    the plain path stands in, and the stand-in is COUNTED (on the chip a
+    silent reference would hide the kernel from every measurement)."""
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.ops import fused
+
+    monkeypatch.setattr(fused, "_FORCE_INTERPRET", True)
+    pt.set_flags({"FLAGS_flash_attention": "always"})
+    try:
+        before = stat_get("flash_attention_refused_shape")
+        assert fused._flash_engaged(2, 12, 128, 128, 64)
+        assert stat_get("flash_attention_refused_shape") == before
+        assert not fused._flash_engaged(2, 12, 100, 100, 64)
+        assert stat_get("flash_attention_refused_shape") == before + 1
+        pt.set_flags({"FLAGS_flash_attention": "auto"})
+        # auto below its memory threshold never wanted the kernel
+        assert not fused._flash_engaged(2, 12, 100, 100, 64)
+        assert stat_get("flash_attention_refused_shape") == before + 1
+    finally:
+        pt.set_flags({"FLAGS_flash_attention": "auto"})

@@ -599,45 +599,36 @@ class TestFuseBucketAccounting:
         assert coalesce_groups(p) == [["g0", "g1"]]
 
 
-class TestCompat:
-    def test_remat_policy_unavailable_degrades(self, monkeypatch):
-        """A jax without checkpoint_policies degrades to plain
-        jax.checkpoint and counts remat_policy_unavailable."""
-        import jax as jax_mod
-
-        from paddle_tpu.framework import jax_compat
-
-        monkeypatch.delattr(jax_mod, "checkpoint_policies", raising=False)
-        stat_reset("remat_policy_unavailable")
-
-        def f(c, x):
-            return c, x
-
-        wrapped = jax_compat.wrap_checkpoint(f, "dots_saveable")
-        assert wrapped is not f
-        assert stat_get("remat_policy_unavailable") == 1
-
+class TestRematPolicy:
     def test_policy_name_resolution(self):
-        from paddle_tpu.framework import jax_compat
+        import jax
 
-        assert jax_compat.checkpoint_policy("") is None
-        for name in jax_compat.REMAT_POLICIES:
-            # on this jax every mapped policy resolves; the accessor
-            # never raises either way
-            jax_compat.checkpoint_policy(name)
+        from paddle_tpu.ops import layer_scan
 
-    def test_scan_unroll_kwarg_guard(self):
+        assert layer_scan.checkpoint_policy("") is None
+        for name in layer_scan.REMAT_POLICIES:
+            assert callable(layer_scan.checkpoint_policy(name))
+        assert layer_scan.checkpoint_policy("save_anything") \
+            is jax.checkpoint_policies.everything_saveable
+        with pytest.raises(ValueError, match="unknown remat policy"):
+            layer_scan.checkpoint_policy("no_such_policy")
+
+    def test_wrap_checkpoint_keeps_values_and_skips_when_unnamed(self):
+        import jax
         import jax.numpy as jnp
 
-        from paddle_tpu.framework import jax_compat
+        from paddle_tpu.ops import layer_scan
 
         def body(c, x):
-            return c + x, c
+            return c + jnp.sin(x), c
 
-        final, ys = jax_compat.scan(body, jnp.float32(0.0),
-                                    jnp.arange(4, dtype="float32"),
-                                    length=4, unroll=2)
-        assert float(final) == 6.0
+        assert layer_scan.wrap_checkpoint(body, "") is body
+        wrapped = layer_scan.wrap_checkpoint(body, "dots_saveable")
+        assert wrapped is not body
+        xs = jnp.arange(4, dtype="float32")
+        g = lambda f: jax.grad(  # noqa: E731
+            lambda c: jax.lax.scan(f, c, xs, unroll=2)[0])(jnp.float32(0.5))
+        assert float(g(wrapped)) == float(g(body))
 
 
 class TestStackedCkptHostValue:

@@ -13,7 +13,6 @@ import socket
 import sys
 
 import numpy as np
-import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import layers
@@ -25,22 +24,6 @@ from paddle_tpu.distributed.launch import (
 from paddle_tpu.framework.program import Program, program_guard
 
 TRAINER = os.path.join(os.path.dirname(__file__), "dist_trainer.py")
-
-# capability probe (tests/conftest.py jax_capability, backed by
-# framework/jax_compat.py): jax versions without the
-# jax_cpu_collectives_implementation config have NO cross-process CPU
-# collectives — the XLA CPU client rejects multiprocess computations
-# outright ("Multiprocess computations aren't implemented on the CPU
-# backend"), so the localhost federation these tests ride cannot exist.
-# Before the guarded accessor this surfaced as an AttributeError inside
-# init_parallel_env; now it is an explicit environment skip.
-from conftest import jax_capability  # noqa: E402
-
-if not jax_capability("cpu_collectives"):
-    pytest.skip(
-        "installed jax has no CPU cross-process collectives backend "
-        "(jax_cpu_collectives_implementation config absent)",
-        allow_module_level=True)
 
 
 def _free_port():

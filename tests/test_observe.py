@@ -410,6 +410,30 @@ class TestExecutorTelemetry:
         assert observe.mfu_estimate(0.0, 0.1, peak_tflops=100.0) == 0.0
         assert observe.mfu_estimate(1e12, 0.0, peak_tflops=100.0) == 0.0
 
+    def test_peak_comes_from_the_device_table_not_a_default(self):
+        """FLAGS_device_peak_tflops unset: the live device's published
+        peak from the one table; a device that is not in it (the CPU
+        here) has NO peak - null in summaries, an error when asked."""
+        from types import SimpleNamespace
+
+        from paddle_tpu.observe import device_peaks
+
+        v5e = device_peaks.device_peak(
+            SimpleNamespace(device_kind="TPU v5 lite"))
+        assert (v5e["bf16_tflops"], v5e["hbm_gbps"], v5e["hbm_gb"]) \
+            == (197.0, 819.0, 16.0) and "TPU v5e" in v5e["source"]
+        pt.set_flags({"FLAGS_device_peak_tflops": 0.0})
+        assert device_peaks.device_peak() is None  # cpu: not in the table
+        assert device_peaks.peak_tflops() is None
+        with pytest.raises(ValueError, match="device_peaks"):
+            observe.mfu_estimate(1e12, 0.1)
+        pt.set_flags({"FLAGS_device_peak_tflops": 123.0})
+        try:
+            assert device_peaks.peak_tflops() == 123.0  # the flag wins
+            assert device_peaks.peak_tflops(50.0) == 50.0  # an argument more
+        finally:
+            pt.set_flags({"FLAGS_device_peak_tflops": 0.0})
+
 
 # ---------------------------------------------------------------------------
 # serving lifecycle + hapi callback

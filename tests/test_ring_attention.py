@@ -57,7 +57,7 @@ def test_causal_matches_full_attention(mesh):
 def test_backward_matches_full_attention(mesh):
     """jax.vjp through the ring (ppermute transposes to a reverse ring)
     must equal the dense-attention gradient."""
-    from paddle_tpu.framework.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.distributed.ring_attention import ring_attention
@@ -88,7 +88,7 @@ def test_backward_matches_full_attention(mesh):
 def test_fused_op_uses_ring_under_sp(mesh):
     """The fused_multihead_attention lowering routes to the ring when the
     executor runs inside an 'sp' shard_map."""
-    from paddle_tpu.framework.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.framework.lowering import LOWERINGS, LoweringContext

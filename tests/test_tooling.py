@@ -41,8 +41,24 @@ def test_op_spec_counts_grads():
 
 
 # ---------------------------------------------------------------------------
-# tools/bench_diff.py against the checked-in bench rounds
+# tools/bench_diff.py against round records of the driver's shape
 # ---------------------------------------------------------------------------
+
+
+def _round(path, n, parsed):
+    """One bench-round record as the driver used to keep them: a wrapper
+    (round number, command, exit code, output tail) around the parsed
+    JSON line of bench.py."""
+    import json
+
+    path.write_text(json.dumps({
+        "n": n, "cmd": "python bench.py", "rc": 0,
+        "tail": json.dumps(parsed) + "\n", "parsed": parsed}))
+    return str(path)
+
+
+_METRIC = {"metric": "resnet50_bf16_images_per_sec",
+           "unit": "images/sec/chip"}
 
 
 def _bench_diff(*args):
@@ -53,21 +69,33 @@ def _bench_diff(*args):
         capture_output=True, text=True, env=env, cwd=ROOT)
 
 
-def test_bench_diff_clean_rounds_improvement():
-    """r02 -> r03 is the PR-3 throughput jump: both rounds clean, no
-    regression, exit 0, and the improvement is flagged."""
-    p = _bench_diff("BENCH_r02.json", "BENCH_r03.json")
+def test_bench_diff_clean_rounds_improvement(tmp_path):
+    """A clean improving pair of wrapped rounds: no regression, exit 0,
+    and the improvement is flagged."""
+    a = _round(tmp_path / "r02.json", 2,
+               dict(_METRIC, value=1889.2, vs_baseline=0.724))
+    b = _round(tmp_path / "r03.json", 3,
+               dict(_METRIC, value=2686.7, vs_baseline=1.029,
+                    resnet50_images_per_sec=2686.7,
+                    bert_base_tokens_per_sec=155564.6))
+    p = _bench_diff(a, b)
     assert p.returncode == 0, p.stderr
     assert "no regressions past threshold" in p.stdout
     assert "improved" in p.stdout
     assert "caveat" not in p.stdout
 
 
-def test_bench_diff_broken_round_is_advisory_not_a_failure():
-    """r05 is the dead-device round (preflight timeout, every metric
-    zeroed): the -100% 'regression' must be downgraded to advisory —
-    exit 0 — with the caveat printed."""
-    p = _bench_diff("BENCH_r03.json", "BENCH_r05.json")
+def test_bench_diff_broken_round_is_advisory_not_a_failure(tmp_path):
+    """A dead-device round (preflight timeout, every metric zeroed, an
+    ``error`` key): the -100% 'regression' must be downgraded to
+    advisory — exit 0 — with the caveat printed."""
+    a = _round(tmp_path / "r03.json", 3,
+               dict(_METRIC, value=2686.7, vs_baseline=1.029))
+    b = _round(tmp_path / "r05.json", 5,
+               dict(_METRIC, value=0.0, vs_baseline=0.0,
+                    error="device preflight failed: device init did not "
+                          "complete within 240s"))
+    p = _bench_diff(a, b)
     assert p.returncode == 0, p.stderr
     assert "caveat [B]" in p.stdout
     assert "ADVISORY" in p.stdout
@@ -103,3 +131,191 @@ def test_bench_diff_threshold_is_respected(tmp_path):
                        "0.10").returncode == 0
     assert _bench_diff(str(a), str(b), "--threshold",
                        "0.05").returncode == 1
+
+
+# ---------------------------------------------------------------------------
+# one process for each chip; a compile cache that is placed from outside
+# ---------------------------------------------------------------------------
+
+
+def _tiny_lm():
+    import jax
+
+    from paddle_tpu.serving.decode import TransformerLM
+
+    model = TransformerLM(vocab_size=50, d_model=16, num_layers=1,
+                          num_heads=2, max_seq_len=64)
+    return model, model.init_weights(jax.random.PRNGKey(0))
+
+
+def test_decode_server_replicas_land_on_distinct_devices():
+    """DecodeServer(replicas=4) on (virtual) devices uses four of them:
+    each replica's weights AND page pools are committed to its own
+    device, and a request served there computes there."""
+    import jax
+
+    from paddle_tpu.serving import DecodeConfig, DecodeServer
+    from paddle_tpu.serving.kv_cache import K_PAGES_VAR
+
+    model, weights = _tiny_lm()
+    srv = DecodeServer(model, weights,
+                       DecodeConfig(max_seq_len=64, slots=2), replicas=4)
+    want = jax.devices()[:4]
+    assert [e.device for e in srv.replicas] == want
+    for eng, dev in zip(srv.replicas, want):
+        assert eng.weights["tok_emb"].devices() == {dev}
+        assert eng._scope.get_var(K_PAGES_VAR).devices() == {dev}
+    with srv:
+        outs = [eng.submit([3, 5, 7], max_new_tokens=3).result(timeout=120)
+                for eng in srv.replicas]
+    assert all(o == outs[0] for o in outs)  # same tokens on every chip
+    for eng, dev in zip(srv.replicas, want):  # state stayed where it was
+        assert eng._scope.get_var(K_PAGES_VAR).devices() == {dev}
+    # more replicas than devices wrap around; a single replica is
+    # pinned like any other (one path, the one the chip smoke serves on)
+    from paddle_tpu.serving.server import replica_places
+
+    n = len(jax.devices())
+    assert [p.device_id for p in replica_places(n + 1)] == \
+        list(range(n)) + [0]
+    assert [p.device_id for p in replica_places(1)] == [0]
+    one = DecodeServer(model, weights, DecodeConfig(max_seq_len=64, slots=2))
+    assert one.replicas[0]._scope.get_var(K_PAGES_VAR).committed
+
+
+def test_disagg_server_spreads_prefill_and_decode_sets():
+    """The disaggregated router's replicas get a device each, and a
+    handoff between two of them is a device-to-device page copy that
+    leaves the tokens bitwise those of a single engine."""
+    import jax
+
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+    from paddle_tpu.serving.disagg import DisaggConfig, DisaggServer
+
+    model, weights = _tiny_lm()
+    cfg = DecodeConfig(max_seq_len=64, slots=2)
+    srv = DisaggServer(model, weights, config=cfg, disagg=DisaggConfig(
+        prefill_replicas=1, decode_replicas=1))
+    assert [r.engine.device for r in srv.replicas] == jax.devices()[:2]
+    prompt = list(range(1, 20))
+    with srv:
+        got = srv.submit(prompt, max_new_tokens=4, temperature=1.0,
+                         seed=7).result(timeout=120)
+    with DecodeEngine(model, weights, cfg) as eng:
+        want = eng.submit(prompt, max_new_tokens=4, temperature=1.0,
+                          seed=7).result(timeout=120)
+    assert got == want
+
+
+def test_disagg_handoff_over_expert_parallel_mesh():
+    """Expert-parallel weights live on a mesh, so their replicas stay
+    unpinned and a KV handoff must not commit the destination's pools to
+    one device: the first handoff lands in a pool that is committed
+    nowhere, the second in one the mesh-sharded step has written."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+    from paddle_tpu.serving.decode import TransformerLM, shard_moe_weights
+    from paddle_tpu.serving.disagg import DisaggConfig, DisaggServer
+    from paddle_tpu.serving.server import replica_places
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("ep",))
+    model = TransformerLM(vocab_size=61, d_model=32, num_layers=2,
+                          num_heads=2, max_seq_len=64, moe_experts=4,
+                          moe_mesh=mesh)
+    weights = shard_moe_weights(
+        model.init_weights(jax.random.PRNGKey(0)), mesh)
+    assert replica_places(2, model) == [None, None]
+    cfg = DecodeConfig(max_seq_len=64, slots=2)
+    prompts = [list(range(1, 20)), list(range(3, 30))]
+    srv = DisaggServer(model, weights, config=cfg, disagg=DisaggConfig(
+        prefill_replicas=1, decode_replicas=1))
+    with srv:
+        got = [srv.submit(p, max_new_tokens=4).result(timeout=120)
+               for p in prompts]
+    with DecodeEngine(model, weights, cfg) as eng:
+        want = [eng.submit(p, max_new_tokens=4).result(timeout=120)
+                for p in prompts]
+    assert got == want
+
+
+def test_compile_cache_is_placed_from_outside():
+    """JAX_COMPILATION_CACHE_DIR in the environment: the program sets no
+    directory at all.  Unset: one fixed path inside the checkout (never
+    a temporary name, a pid or a time), on the chip only."""
+    from paddle_tpu.framework import executor
+
+    d = executor.default_compile_cache_dir
+    assert d({"JAX_COMPILATION_CACHE_DIR": "/elsewhere"}, "tpu") is None
+    assert d({}, "cpu") is None  # a process that selected the CPU
+    path = d({}, "tpu")
+    assert path == executor.COMPILE_CACHE_DIR == d({}, "tpu")
+    assert path == os.path.join(ROOT, ".jax_compile_cache")
+    ignored = open(os.path.join(ROOT, ".gitignore")).read().split()
+    assert ".jax_compile_cache/" in ignored
+
+
+def test_environment_cache_dir_is_left_untouched(tmp_path):
+    """A fresh process with the variable set: building an Executor
+    leaves jax's cache directory exactly what the environment said, and
+    importing paddle_tpu creates nothing."""
+    code = (
+        "import os, jax, paddle_tpu as pt\n"
+        "assert not os.path.exists(os.environ['JAX_COMPILATION_CACHE_DIR'])\n"
+        "pt.Executor(pt.CPUPlace())\n"
+        "print('DIR', jax.config.jax_compilation_cache_dir)\n")
+    target = str(tmp_path / "from_outside")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=target,
+               PYTHONPATH=ROOT)
+    p = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
+                       capture_output=True, text=True)
+    assert p.returncode == 0, p.stderr
+    assert f"DIR {target}" in p.stdout
+
+
+def test_import_initialises_no_backend_and_creates_nothing(tmp_path):
+    """`import paddle_tpu` touches no device, describes no topology and
+    creates no directory (the six-worker test run depends on it: only
+    one process at a time may load the TPU library)."""
+    code = (
+        "import os, sys\n"
+        "before = set(os.listdir('.'))\n"
+        "import paddle_tpu\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge._backends, xla_bridge._backends\n"
+        "assert not any('libtpu' in m for m in sys.modules), 'libtpu'\n"
+        "assert set(os.listdir('.')) == before\n"
+        "print('CLEAN')\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       cwd=str(tmp_path), capture_output=True, text=True)
+    assert p.returncode == 0 and "CLEAN" in p.stdout, p.stderr
+
+
+def test_launcher_refuses_several_trainers_on_a_tpu_host(monkeypatch):
+    """One process drives all local chips: N trainers that would each
+    open every chip are refused with the reason, not left to hang."""
+    import pytest
+
+    from paddle_tpu.distributed import launch
+
+    monkeypatch.setattr(launch, "tpu_present", lambda: True)
+    with pytest.raises(SystemExit, match="one process at a time"):
+        launch.launch(["--nproc_per_node", "2", "train.py"])
+    # a job that selected the CPU opens no chip, whatever the host has
+    monkeypatch.undo()
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert launch.tpu_present() is False
+
+
+def test_bench_refuses_to_measure_without_a_tpu():
+    """bench.py on a process that selected the CPU: non-zero exit, no
+    JSON record on stdout - a CPU number is never a device number."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    p = subprocess.run([sys.executable, "bench.py"], env=env, cwd=ROOT,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0
+    assert "needs a TPU" in p.stderr and "{" not in p.stdout

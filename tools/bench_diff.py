@@ -1,9 +1,6 @@
-"""Compare two BENCH_*.json rounds and flag perf regressions.
+"""Compare two bench-round JSON records and flag perf regressions.
 
-The driver keeps one JSON per bench round (``BENCH_r01.json``..); until
-now comparing rounds meant eyeballing. This CLI diffs any two:
-
-    python -m tools.bench_diff BENCH_r03.json BENCH_r05.json
+    python -m tools.bench_diff A.json B.json
     python -m tools.bench_diff A.json B.json --threshold 0.10 --json
 
 Input handling (pure stdlib, no framework import):
