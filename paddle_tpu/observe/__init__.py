@@ -5,9 +5,12 @@ The reference ships a full observability stack (CUPTI ``DeviceTracer``
 counters); the TPU-native port previously covered only the thin ends.
 This package is the middle:
 
-- ``tracer``     — host-side span ring buffer (``FLAGS_enable_tracer``),
-  fed by the Executor phases, graph passes, collective lowerings, the
-  serving batch lifecycle, and every ``profiler.RecordEvent``.
+- ``tracer``     — the one span API, fed by the Executor phases, graph
+  passes, collective lowerings, the serving batch lifecycle, the decode
+  engine's loop phases, and every ``profiler.RecordEvent``.  Each span
+  is a ``jax.profiler.TraceAnnotation`` (an event of whatever profiler
+  trace is running, on the device trace's clock) and, under
+  ``FLAGS_enable_tracer``, a record of the host-side ring buffer.
 - ``timeline``   — Chrome trace-event JSON export of that buffer
   (Perfetto/chrome://tracing), plus a
   ``python -m paddle_tpu.observe.timeline`` CLI.

@@ -67,11 +67,17 @@ RULES = (
          "Decode-engine request latency (submit to terminal)",
          exact=True),
     Rule("decode_prefill_seconds", "histogram", "serving",
-         "Prefill dispatch time per request/chunk", exact=True),
+         "One prefill dispatch from its arguments through the sync: "
+         "until the sampled token is on the host (per request, chunk "
+         "or ragged dispatch; a chunk that samples no token has no "
+         "sync and ends with its dispatch)", exact=True),
     Rule("decode_step_seconds", "histogram", "serving",
-         "One batched decode step", exact=True),
+         "One batched decode step, dispatch + sync (a speculative "
+         "round: draft burst + verify)", exact=True),
     Rule("ttft_seconds", "histogram", "slo",
-         "Time to first token (SLO input)", exact=True),
+         "Time to first token (SLO input): submit until the prefill's "
+         "sampled token has been read back and is handed to the caller, "
+         "on every prefill path", exact=True),
     Rule("tpot_seconds", "histogram", "slo",
          "Time per output token (SLO input)", exact=True),
     Rule("emb_lookup_seconds", "histogram", "embedding",
@@ -148,6 +154,10 @@ RULES = (
     Rule("emb_", "gauge", "embedding",
          "Sharded-embedding traffic and placement stats"),
     # -- serving ---------------------------------------------------------
+    Rule("decode_h2d_", "gauge", "serving",
+         "Host arrays the engine's argument builders hand to the device "
+         "(`_uploads`: count, `_bytes`), counted where `_step_args` and "
+         "the prefill builders hand them over; the weights are not in it"),
     Rule("decode_", "gauge", "serving",
          "Decode-engine lifecycle, paging, speculation, goodput"),
     Rule("serving_", "gauge", "serving",

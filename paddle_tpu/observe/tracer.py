@@ -11,9 +11,19 @@ gated by ``FLAGS_enable_tracer``, and exportable at any moment as
 Chrome trace-event JSON (``observe/timeline.py``) without restarting or
 re-running anything.
 
+One span API, two sinks.  ``span()``/``begin()``/``end()`` ALSO open a
+``jax.profiler.TraceAnnotation`` of the same name and attributes,
+unconditionally: while any ``jax.profiler`` session runs (the
+benchmark's ``--trace 1`` window, ``profiler.start_profiler``,
+``observe/profiler_capture``) every span site lands on the host plane of
+that trace, on the device trace's clock, its attributes as the event's
+stats; with no session open the annotation is a sub-microsecond no-op.
+``FLAGS_enable_tracer`` gates the ring buffer only.
+
 Design constraints:
-- **Disabled cost ~ zero**: ``span()`` with the flag off is one dict
-  lookup and a shared no-op context manager — no allocation, no lock.
+- **Disabled cost ~ zero**: ``span()`` with the flag off and no
+  profiler session is one flag lookup and one un-recorded annotation
+  (about a microsecond) — no clock read, no lock.
 - **Enabled cost is bounded**: finished spans land in a
   ``deque(maxlen=capacity)`` (old spans fall off; a long-lived server
   cannot leak), two ``perf_counter`` calls + one lock per span.
@@ -27,6 +37,8 @@ import os
 import threading
 import time
 from typing import Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from ..framework import flags as _flags
 
@@ -78,30 +90,19 @@ class Tracer:
         return st
 
     def begin(self, name: Optional[str], args: Optional[dict] = None) -> None:
-        """``name=None`` pushes a DISCARD sentinel: the matching end()
-        pops it without recording.  The module-level begin() pushes it
-        when the tracer is disabled, so a begin/end pair stays balanced
-        even if ``FLAGS_enable_tracer`` flips between the two calls."""
-        if name is None:
-            self._stack().append((None, 0.0, None))
-            return
         self._stack().append((name, time.perf_counter() - _EPOCH, args))
 
     def end(self) -> None:
         st = self._stack()
         if not st:  # unbalanced end(): drop silently (never raise in
             return  # instrumentation paths)
-        if st[-1][0] is None:  # disabled-begin sentinel
-            st.pop()
-            return
         t1 = time.perf_counter() - _EPOCH
         name, t0, args = st.pop()
         th = threading.current_thread()
-        # sentinels are invisible to nesting: depth/parent only count
-        # real open spans
-        depth = sum(1 for e in st if e[0] is not None)
-        parent = next((e[0] for e in reversed(st) if e[0] is not None),
-                      None)
+        # only spans begun with the buffer on are on this stack, so a
+        # span begun while it was off is invisible to depth and parent
+        depth = len(st)
+        parent = st[-1][0] if st else None
         rec = SpanRecord(name, t0, t1, th.ident or 0, th.name, depth,
                          parent, args)
         with self._lock:
@@ -113,7 +114,7 @@ class Tracer:
         """Attach/extend args on the INNERMOST open span of this thread
         (e.g. byte counts known only after the span body ran)."""
         st = self._stack()
-        if not st or st[-1][0] is None:  # no open span / sentinel
+        if not st:  # no open span
             return
         name, t0, args = st[-1]
         merged = dict(args or {})
@@ -158,7 +159,7 @@ def disable() -> None:
 
 
 class _Span:
-    """Context manager for one live span (only built when enabled)."""
+    """Context manager over one begin()/end() pair."""
 
     __slots__ = ("_name", "_args")
 
@@ -167,16 +168,16 @@ class _Span:
         self._args = args
 
     def __enter__(self):
-        _TRACER.begin(self._name, self._args or None)
+        _begin(self._name, self._args)
         return self
 
     def __exit__(self, *exc):
-        _TRACER.end()
+        end()
         return False
 
 
 class _NullSpan:
-    """Shared no-op: the disabled path allocates nothing."""
+    """Shared no-op context manager (see ``NULL_SPAN``)."""
 
     __slots__ = ()
 
@@ -195,32 +196,64 @@ _NULL = _NullSpan()
 NULL_SPAN = _NULL
 
 
+# per-thread LIFO of the open spans: (profiler annotation, whether the
+# span is also in the ring buffer).  begin() decides both sinks once, so
+# a pair stays balanced when FLAGS_enable_tracer flips between the calls
+_local = threading.local()
+
+
+def _open_spans() -> list:
+    try:
+        return _local.spans
+    except AttributeError:
+        st = _local.spans = []
+        return st
+
+
 def span(name: str, **attrs):
-    """``with observe.span("executor/run", bytes=n):`` — no-op unless
-    ``FLAGS_enable_tracer`` is set."""
-    if not _flags.flag("enable_tracer"):
-        return _NULL
+    """``with observe.span("executor/run", bytes=n):`` — an event of
+    the profiler's trace while a ``jax.profiler`` session runs, a
+    ring-buffer record while ``FLAGS_enable_tracer`` is set."""
     return _Span(name, attrs)
 
 
 def begin(name: str, **attrs) -> None:
-    """Explicit begin/end pair (``RecordEvent`` dual-feed path).  The
-    caller must guarantee LIFO order per thread.  Gated by
-    ``FLAGS_enable_tracer`` like ``span()`` — a disabled begin pushes
-    only a discard sentinel so the pair stays balanced across flag
-    flips."""
-    if _flags.flag("enable_tracer"):
+    """Explicit begin/end pair (what ``span()`` and
+    ``profiler.RecordEvent`` are built on).  The caller must guarantee
+    LIFO order per thread.  The profiler annotation always opens; the
+    ring-buffer record is gated by ``FLAGS_enable_tracer``, read here
+    and not again at end()."""
+    _begin(name, attrs)
+
+
+def _begin(name: str, attrs: dict) -> None:
+    annotation = _Annotation(name, **attrs)
+    annotation.__enter__()
+    in_ring = bool(_flags.flag("enable_tracer"))
+    if in_ring:
         _TRACER.begin(name, attrs or None)
-    else:
-        _TRACER.begin(None)
+    _open_spans().append((annotation, in_ring))
 
 
 def end() -> None:
-    _TRACER.end()
+    st = _open_spans()
+    if not st:  # unbalanced end(): drop silently (never raise in
+        return  # instrumentation paths)
+    annotation, in_ring = st.pop()
+    if in_ring:
+        _TRACER.end()
+    annotation.__exit__(None, None, None)
 
 
 def set_span_args(**kwargs) -> None:
-    if _flags.flag("enable_tracer"):
+    """Attach args known only after the span body ran (byte counts) to
+    the innermost open span of this thread, in both sinks."""
+    st = _open_spans()
+    if not st:
+        return
+    annotation, in_ring = st[-1]
+    annotation.set_metadata(**kwargs)
+    if in_ring:
         _TRACER.set_args(**kwargs)
 
 

@@ -45,6 +45,10 @@ from jax import lax
 
 _NEG_INF = -1e30
 _LANES = 128  # TPU vector lane width; row stats broadcast across lanes
+# the Pallas call's own name: a profiler trace names the kernel's device
+# event "%paged_attention.<n> = ... custom-call(...)" (still a
+# tpu_custom_call), so it can be told apart from any other kernel
+KERNEL_NAME = "paged_attention"
 
 
 def decode_attention_reference(q, k, v, lengths, *, sm_scale=None):
@@ -237,6 +241,7 @@ def _chunk_call(q, k_pages, v_pages, page_table, row_lengths, sm_scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_slots, n_rows, h, d), q.dtype),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(flat_table, flat_lengths, *operands)
 
 

@@ -7,13 +7,10 @@ TPU-native redesign: instead of CUPTI device tracing + a custom
 profiler.proto, capture goes through ``jax.profiler`` — the trace contains
 every XLA executable launch and on-device op, viewable in
 TensorBoard/Perfetto (replaces tools/timeline.py's chrome://tracing dump).
-``RecordEvent`` maps to ``jax.profiler.TraceAnnotation`` so user-code
-phases appear on the host timeline alongside device ops — and
-dual-feeds the always-on in-process span tracer
-(``paddle_tpu.observe``): the TraceAnnotation path lights up when an
-XLA capture is live, the ring-buffer span whenever
-``FLAGS_enable_tracer`` is set, so one annotation serves both the
-heavyweight capture and the exportable host timeline.
+``RecordEvent`` is a span of ``paddle_tpu.observe``'s tracer, the one
+span API: it appears on the host timeline alongside device ops when an
+XLA capture is live (the tracer opens the ``TraceAnnotation``), and in
+the ring buffer whenever ``FLAGS_enable_tracer`` is set — once in each.
 """
 from __future__ import annotations
 
@@ -32,21 +29,16 @@ class RecordEvent:
 
     Usable as a context manager, via explicit begin()/end(), or as a
     function decorator (``@RecordEvent("serving/batch")`` wraps every
-    call of the function in its own span).  Shows up as a named span on
-    the profiler timeline when a capture is active AND in the observe
-    tracer's ring buffer when ``FLAGS_enable_tracer`` is set; costs
-    ~nothing when neither is running.
+    call of the function in its own span).  A name over
+    ``observe.begin()/end()``: shows up as a named span on the profiler
+    timeline when a capture is active AND in the observe tracer's ring
+    buffer when ``FLAGS_enable_tracer`` is set; costs ~nothing when
+    neither is running.  The open-span stacks are the tracer's, per
+    thread, so one instance may be shared across threads or re-entered.
     """
 
     def __init__(self, name: str):
-        import threading
-
         self.name = name
-        # per-THREAD LIFO of live annotations: one RecordEvent instance
-        # may be shared across threads or re-entered (explicit
-        # begin()/end() API) without corrupting the tracer's span stack
-        # or leaking a TraceAnnotation
-        self._local = threading.local()
 
     def __call__(self, fn):
         import functools
@@ -58,26 +50,10 @@ class RecordEvent:
 
         return wrapped
 
-    def _entries(self):
-        st = getattr(self._local, "entries", None)
-        if st is None:
-            st = self._local.entries = []
-        return st
-
     def begin(self):
-        import jax
-
-        # tracer begin/end are balance-safe across FLAGS_enable_tracer
-        # flips (disabled begin pushes a discard sentinel)
         _otracer.begin(self.name)
-        ann = jax.profiler.TraceAnnotation(self.name)
-        ann.__enter__()
-        self._entries().append(ann)
 
     def end(self):
-        entries = self._entries()
-        if entries:
-            entries.pop().__exit__(None, None, None)
         _otracer.end()
 
     def __enter__(self):

@@ -69,7 +69,17 @@ Observability: ``decode_*`` counters/gauges (``decode_cache_hit_rate``,
 ``decode_shared_pages``, ``decode_cow_copies``, ``spec_accept_rate``,
 ``prefill_chunks``, ...) plus ``ttft_seconds`` / ``tpot_seconds`` /
 ``decode_step_seconds`` histograms — all on ``/metrics`` wherever a
-fleet KV HTTP server runs.
+fleet KV HTTP server runs.  One iteration of the engine thread is a row
+of leaf spans that follow one another (``observe/tracer.py``: events of
+a running ``jax.profiler`` trace, ring-buffer records under
+``FLAGS_enable_tracer``): ``serving/lock_wait`` | ``serving/admit`` (or
+``serving/idle_wait``) | per prefill ``serving/prefill_args`` |
+``prefill_dispatch`` | ``prefill_sync`` | ``prefill_deliver`` |
+``serving/reap`` | per decode round ``serving/step_cow`` | ``step_args``
+| ``step_dispatch`` | ``step_sync`` | ``step_deliver``.  No span encloses the iteration, so a
+device gap is named by the phase the host was in.  The ``*_args`` spans
+carry the host arrays their builder handed to the device (``uploads``,
+``upload_bytes``; counters ``decode_h2d_uploads`` / ``decode_h2d_bytes``).
 """
 from __future__ import annotations
 
@@ -92,6 +102,41 @@ from .buckets import (BucketSpec, DeadlineExceededError, QueueFullError,
 from . import kv_cache
 from .kv_cache import (CacheConfig, PagedKVCache, K_PAGES_VAR,
                        V_PAGES_VAR, K_SCALES_VAR, V_SCALES_VAR)
+
+
+
+class _Uploads:
+    """Counts the host arrays one dispatch's argument builder hands to
+    the device, at the sites that hand them over."""
+
+    __slots__ = ("n", "nbytes")
+
+    def __init__(self):
+        self.n = self.nbytes = 0
+
+    def __call__(self, a):
+        """``jnp.asarray(a)`` of a host array: one upload."""
+        import jax.numpy as jnp
+
+        return jnp.asarray(self.host(a))
+
+    def host(self, a):
+        """A host value left as it is for the dispatch to upload."""
+        self.n += 1
+        self.nbytes += a.nbytes
+        return a
+
+    def record(self):
+        """Into the counters and onto the open ``*_args`` span."""
+        stat_add("decode_h2d_uploads", self.n)
+        stat_add("decode_h2d_bytes", self.nbytes)
+        otrace.set_span_args(uploads=self.n, upload_bytes=self.nbytes)
+
+
+def _rid(req):
+    """The request's id, as ``submit`` minted it (spans' ``req``)."""
+    return req.trace.trace_id if req.trace is not None else ""
+
 
 DRAFT_K_PAGES_VAR = "__decode_draft_k_pages__"
 DRAFT_V_PAGES_VAR = "__decode_draft_v_pages__"
@@ -688,6 +733,9 @@ class DecodeEngine:
         self._prefill_chunk_count = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
+        # decode rounds dispatched by THIS engine (joint steps and
+        # speculative rounds): the step spans' ``step``
+        self._decode_steps = 0
 
     def _commit(self, tree):
         """Device arrays for ``tree``; on a pinned replica every leaf
@@ -738,17 +786,22 @@ class DecodeEngine:
     # -- jitted step builders --------------------------------------------
     def _attend(self, q, k_pages, v_pages, k_scales, v_scales, layer,
                 page_table, lengths):
-        from ..ops.pallas_decode_attention import paged_decode_attention
+        import jax
+
+        from ..ops.pallas_decode_attention import (KERNEL_NAME,
+                                                   paged_decode_attention)
 
         # all backend dispatch (auto/always/never, Pallas vs the
         # gather+mask reference) lives in ONE place: the op itself —
-        # including the quantized dequant-inline paths
-        return paged_decode_attention(
-            q, k_pages[layer], v_pages[layer], page_table, lengths,
-            use_pallas=self.config.use_pallas,
-            interpret=self.config.interpret,
-            k_scales=None if k_scales is None else k_scales[layer],
-            v_scales=None if v_scales is None else v_scales[layer])
+        # including the quantized dequant-inline paths.  The scope is
+        # metadata only: it names the call's device ops in a trace
+        with jax.named_scope(KERNEL_NAME):
+            return paged_decode_attention(
+                q, k_pages[layer], v_pages[layer], page_table, lengths,
+                use_pallas=self.config.use_pallas,
+                interpret=self.config.interpret,
+                k_scales=None if k_scales is None else k_scales[layer],
+                v_scales=None if v_scales is None else v_scales[layer])
 
     def _token_step_body(self, model, weights, k_pages, v_pages,
                          k_scales, v_scales, tokens, positions,
@@ -786,6 +839,10 @@ class DecodeEngine:
 
         qz = self.config.kv_quant
 
+        # the scopes on the jitted bodies are metadata only: a trace's
+        # device ops read "jit(step)/decode_step/...".  The functions
+        # keep their names (the programs are jit_step, jit_prefill, ...)
+        @jax.named_scope("decode_step")
         def step(state, weights, tokens, positions, live, page_table,
                  write_page, write_off, base_keys, counters, temp, top_k,
                  top_p):
@@ -815,6 +872,7 @@ class DecodeEngine:
         cdt = cc.dtype
         qz = cc.quantized if quantized is None else bool(quantized)
 
+        @jax.named_scope("prefill_full")
         def prefill(state, weights, tokens, length, pages, base_key,
                     temp, top_k, top_p):
             k_pages, v_pages, k_scales, v_scales = _split_state(state, qz)
@@ -876,12 +934,14 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..ops.pallas_decode_attention import paged_chunk_attention
+        from ..ops.pallas_decode_attention import (KERNEL_NAME,
+                                                   paged_chunk_attention)
         from ..ops.sampling_ops import greedy_sample, sample_tokens
 
         R, S = n_rows, n_slots
         qz = self._cache.config.quantized
 
+        @jax.named_scope("prefill_rows")
         def rows_fn(state, weights, tokens, start, last_row, page_table,
                     write_page, write_off, base_keys, counters, temp,
                     top_k, top_p):
@@ -904,12 +964,16 @@ class DecodeEngine:
                 v_pages, v_scales = kv_cache.write_token_layer(
                     v_pages, v_scales, l, v.reshape(flat),
                     write_page.reshape(-1), write_off.reshape(-1))
-                ctx = paged_chunk_attention(
-                    q, k_pages[l], v_pages[l], page_table, row_lengths,
-                    use_pallas=self.config.use_pallas,
-                    interpret=self.config.interpret,
-                    k_scales=None if k_scales is None else k_scales[l],
-                    v_scales=None if v_scales is None else v_scales[l])
+                with jax.named_scope(KERNEL_NAME):
+                    ctx = paged_chunk_attention(
+                        q, k_pages[l], v_pages[l], page_table,
+                        row_lengths,
+                        use_pallas=self.config.use_pallas,
+                        interpret=self.config.interpret,
+                        k_scales=None if k_scales is None
+                        else k_scales[l],
+                        v_scales=None if v_scales is None
+                        else v_scales[l])
                 x = x + model._attn_out(lw, ctx)
                 x = x + model._mlp(
                     lw, model._ln(x, lw["ln2_g"], lw["ln2_b"]))
@@ -1479,24 +1543,36 @@ class DecodeEngine:
 
     def _loop(self):
         while True:
-            with self._cond:
+            # ``with self._cond:`` with the wait for the lock (callers
+            # hold it while they submit) as a phase of its own, so that
+            # the iteration is spanned end to end
+            with otrace.span("serving/lock_wait"):
+                self._cond.acquire()
+            try:
                 if self._abort:
                     for i, st in enumerate(self._slots):
                         if st is not None:
                             self._finish_slot(i, ServerClosedError(
                                 "engine stopped mid-generation"))
                     return
-                self._reap_queue_locked()
-                admitted = self._admit_locked()
+                with otrace.span("serving/admit"):
+                    self._reap_queue_locked()
+                    admitted = self._admit_locked()
+                    otrace.set_span_args(admitted=len(admitted),
+                                         queued=len(self._queue))
                 if not admitted and not self.live_slots:
                     if self._closing and not self._queue:
                         return
                     # short cap keeps queued deadlines (and a pages-
                     # blocked head) honest while idle
-                    self._cond.wait(0.05 if self._queue else None)
+                    with otrace.span("serving/idle_wait"):
+                        self._cond.wait(0.05 if self._queue else None)
                     continue
+            finally:
+                self._cond.release()
             self._service_prefills()
-            self._reap_live()
+            with otrace.span("serving/reap"):
+                self._reap_live()
             self._run_decode_round()
 
     # -- device work: prefill ---------------------------------------------
@@ -1537,46 +1613,53 @@ class DecodeEngine:
         """The whole-prompt prefill fast path (no cache hit, chunking
         off): page-wholesale K/V writes + locally-built full-width
         attention, one dispatch."""
-        import jax.numpy as jnp
-
         st = self._slots[slot]
         req = st.req
         try:
             t_pad = self._buckets.seq_bucket(len(req.prompt))
-            tokens = np.zeros((t_pad,), np.int32)
-            tokens[:len(req.prompt)] = req.prompt
-            args = lambda w: (w, jnp.asarray(tokens),  # noqa: E731
-                              np.int32(len(req.prompt)),
-                              jnp.asarray(self._cache.page_table[slot]),
-                              st.base_key,
-                              np.float32(req.temperature),
-                              np.int32(req.top_k),
-                              np.float32(req.top_p))
+            attrs = {"slot": slot, "bucket": t_pad, "req": _rid(req)}
             t0 = time.monotonic()
-            with otrace.span("serving/decode_prefill", slot=slot,
-                             bucket=t_pad):
+            with otrace.span("serving/prefill_args", **attrs):
+                up = _Uploads()
+                tokens = np.zeros((t_pad,), np.int32)
+                tokens[:len(req.prompt)] = req.prompt
+                args = lambda w: (  # noqa: E731
+                    w, up(tokens), up.host(np.int32(len(req.prompt))),
+                    up(self._cache.page_table[slot]), st.base_key,
+                    up.host(np.float32(req.temperature)),
+                    up.host(np.int32(req.top_k)),
+                    up.host(np.float32(req.top_p)))
+                target_args = args(self.weights)
+                draft_args = args(self.draft_weights) if st.spec else None
+                up.record()
+            with otrace.span("serving/prefill_dispatch", **attrs):
                 tok, last = self._exe.run_persistent(
                     self._prefill_fn(t_pad), self._state_vars,
-                    args=args(self.weights), scope=self._scope)
+                    args=target_args, scope=self._scope)
                 if st.spec:
                     # mirror the prefill into the draft's pools (same
                     # page ids) so proposals can read the prompt
                     self._exe.run_persistent(
                         self._prefill_fn(t_pad, "draft"),
                         self._draft_state_vars,
-                        args=args(self.draft_weights), scope=self._scope)
-            stat_time("decode_prefill_seconds", time.monotonic() - t0)
-            self._tev(req, "prefill", slot=slot, bucket=t_pad,
-                      tokens=len(req.prompt),
-                      dur_ms=round((time.monotonic() - t0) * 1e3, 3))
-            stat_add("decode_prefills")
-            record_pad_waste(len(req.prompt), t_pad)
-            st.prefill_pos = len(req.prompt)
-            st.phase = "decode"
-            self._cache.lengths[slot] = len(req.prompt)
-            if req.record_logits:
-                req.logits_trace.append(np.asarray(last))
-            self._deliver(slot, int(np.asarray(tok)))
+                        args=draft_args, scope=self._scope)
+            with otrace.span("serving/prefill_sync", **attrs):
+                tok = int(np.asarray(tok))  # the prefill's sync point
+            # through the sync: the time until the token is on the host
+            dur = time.monotonic() - t0
+            stat_time("decode_prefill_seconds", dur)
+            with otrace.span("serving/prefill_deliver", **attrs):
+                self._tev(req, "prefill", slot=slot, bucket=t_pad,
+                          tokens=len(req.prompt),
+                          dur_ms=round(dur * 1e3, 3))
+                stat_add("decode_prefills")
+                record_pad_waste(len(req.prompt), t_pad)
+                st.prefill_pos = len(req.prompt)
+                st.phase = "decode"
+                self._cache.lengths[slot] = len(req.prompt)
+                if req.record_logits:
+                    req.logits_trace.append(np.asarray(last))
+                self._deliver(slot, tok)
         except Exception as e:  # noqa: BLE001 — fault isolation per req
             stat_add("decode_prefill_errors")
             self._finish_slot(slot, e)
@@ -1588,8 +1671,6 @@ class DecodeEngine:
         gathers the already-present pages for positions below the
         cursor, so the chunk's logits stay bitwise-equal to a full
         prefill.  The FINAL chunk samples the request's first token."""
-        import jax.numpy as jnp
-
         st = self._slots[slot]
         req = st.req
         cc = self._cache.config
@@ -1598,57 +1679,68 @@ class DecodeEngine:
             start = st.prefill_pos
             n_live = min(rows, n - start)
             final = start + n_live >= n
-            tokens = np.zeros((1, rows), np.int32)
-            tokens[0, :n_live] = req.prompt[start:start + n_live]
-            write_page = np.zeros((1, rows), np.int32)
-            write_off = np.zeros((1, rows), np.int32)
-            for r in range(n_live):
-                pos = start + r
-                write_page[0, r] = self._cache.page_table[slot][
-                    pos // cc.page_size]
-                write_off[0, r] = pos % cc.page_size
+            attrs = {"slot": slot, "bucket": rows, "start": start,
+                     "req": _rid(req)}
             t0 = time.monotonic()
-            args = lambda w: (w, jnp.asarray(tokens),  # noqa: E731
-                              np.asarray([start], np.int32),
-                              np.asarray([min(n - 1 - start, rows - 1)],
-                                         np.int32),
-                              jnp.asarray(
-                                  self._cache.page_table[slot:slot + 1]),
-                              jnp.asarray(write_page),
-                              jnp.asarray(write_off),
-                              jnp.asarray(
-                                  np.asarray(st.base_key)[None]),
-                              np.zeros((1,), np.int32),
-                              np.asarray([req.temperature], np.float32),
-                              np.asarray([req.top_k], np.int32),
-                              np.asarray([req.top_p], np.float32))
-            with otrace.span("serving/decode_prefill_chunk", slot=slot,
-                             start=start, rows=rows):
+            with otrace.span("serving/prefill_args", **attrs):
+                up = _Uploads()
+                tokens = np.zeros((1, rows), np.int32)
+                tokens[0, :n_live] = req.prompt[start:start + n_live]
+                write_page = np.zeros((1, rows), np.int32)
+                write_off = np.zeros((1, rows), np.int32)
+                for r in range(n_live):
+                    pos = start + r
+                    write_page[0, r] = self._cache.page_table[slot][
+                        pos // cc.page_size]
+                    write_off[0, r] = pos % cc.page_size
+                args = lambda w: (  # noqa: E731
+                    w, up(tokens),
+                    up.host(np.asarray([start], np.int32)),
+                    up.host(np.asarray([min(n - 1 - start, rows - 1)],
+                                       np.int32)),
+                    up(self._cache.page_table[slot:slot + 1]),
+                    up(write_page), up(write_off),
+                    up(np.asarray(st.base_key)[None]),
+                    up.host(np.zeros((1,), np.int32)),
+                    up.host(np.asarray([req.temperature], np.float32)),
+                    up.host(np.asarray([req.top_k], np.int32)),
+                    up.host(np.asarray([req.top_p], np.float32)))
+                target_args = args(self.weights)
+                draft_args = args(self.draft_weights) if st.spec else None
+                up.record()
+            with otrace.span("serving/prefill_dispatch", **attrs):
                 tok, _greedy, logits = self._exe.run_persistent(
                     self._rows_fn(rows, 1), self._state_vars,
-                    args=args(self.weights), scope=self._scope)
+                    args=target_args, scope=self._scope)
                 if st.spec:
                     self._exe.run_persistent(
                         self._rows_fn(rows, 1, "draft"),
                         self._draft_state_vars,
-                        args=args(self.draft_weights), scope=self._scope)
-            stat_time("decode_prefill_seconds", time.monotonic() - t0)
-            stat_add("prefill_chunks")
-            record_pad_waste(n_live, rows)
-            self._prefill_chunk_count += 1
-            st.chunks += 1
-            self._tev(req, "prefill_chunk", slot=slot, start=start,
-                      rows=rows, live=n_live, final=final,
-                      dur_ms=round((time.monotonic() - t0) * 1e3, 3))
-            st.prefill_pos += n_live
+                        args=draft_args, scope=self._scope)
             if final:
-                stat_add("decode_prefills")
-                st.phase = "decode"
-                self._cache.lengths[slot] = n
-                if req.record_logits:
-                    req.logits_trace.append(
-                        np.asarray(logits)[0, n - 1 - start].copy())
-                self._deliver(slot, int(np.asarray(tok)[0]))
+                # only the chunk that samples is read back: an earlier
+                # chunk's observation ends with its dispatch
+                with otrace.span("serving/prefill_sync", **attrs):
+                    tok = int(np.asarray(tok)[0])
+            dur = time.monotonic() - t0
+            stat_time("decode_prefill_seconds", dur)
+            with otrace.span("serving/prefill_deliver", **attrs):
+                stat_add("prefill_chunks")
+                record_pad_waste(n_live, rows)
+                self._prefill_chunk_count += 1
+                st.chunks += 1
+                self._tev(req, "prefill_chunk", slot=slot, start=start,
+                          rows=rows, live=n_live, final=final,
+                          dur_ms=round(dur * 1e3, 3))
+                st.prefill_pos += n_live
+                if final:
+                    stat_add("decode_prefills")
+                    st.phase = "decode"
+                    self._cache.lengths[slot] = n
+                    if req.record_logits:
+                        req.logits_trace.append(
+                            np.asarray(logits)[0, n - 1 - start].copy())
+                    self._deliver(slot, tok)
         except Exception as e:  # noqa: BLE001 — fault isolation per req
             stat_add("decode_prefill_errors")
             self._finish_slot(slot, e)
@@ -1668,8 +1760,6 @@ class DecodeEngine:
         to the padded chunk path by the same chunk-equivalence
         contract; dead lanes write to the trash page (page 0) and are
         ignored.  One fixed lane count -> ONE extra executable."""
-        import jax.numpy as jnp
-
         L = self.config.ragged_prefill_rows
         cc = self._cache.config
         per_slot_cap = self.config.prefill_chunk_pages * cc.page_size
@@ -1703,83 +1793,96 @@ class DecodeEngine:
         self._prefill_rr = (picks[-1][0] + 1) % self.config.slots
         live = L - lanes_left
 
-        tokens = np.zeros((L, 1), np.int32)
-        start = np.zeros((L,), np.int32)
-        page_table = np.zeros((L,) + self._cache.page_table[0].shape,
-                              np.int32)
-        write_page = np.zeros((L, 1), np.int32)
-        write_off = np.zeros((L, 1), np.int32)
-        key0 = np.asarray(self._slots[picks[0][0]].base_key)
-        base_keys = np.zeros((L,) + key0.shape, key0.dtype)
-        temp = np.zeros((L,), np.float32)
-        top_k = np.zeros((L,), np.int32)
-        top_p = np.ones((L,), np.float32)
-        lane = 0
-        spec_any = False
-        for i, s, t in picks:
-            st = self._slots[i]
-            req = st.req
-            spec_any = spec_any or st.spec
-            for j in range(t):
-                pos = s + j
-                tokens[lane, 0] = req.prompt[pos]
-                start[lane] = pos
-                page_table[lane] = self._cache.page_table[i]
-                write_page[lane, 0] = self._cache.page_table[i][
-                    pos // cc.page_size]
-                write_off[lane, 0] = pos % cc.page_size
-                base_keys[lane] = np.asarray(st.base_key)
-                temp[lane] = req.temperature
-                top_k[lane] = req.top_k
-                top_p[lane] = req.top_p
-                lane += 1
+        attrs = {"lanes": L, "live": live, "slots": len(picks),
+                 "req": ",".join(_rid(self._slots[i].req)
+                                 for i, _s, _t in picks)}
         try:
             t0 = time.monotonic()
-            args = lambda w: (w, jnp.asarray(tokens),  # noqa: E731
-                              jnp.asarray(start),
-                              np.zeros((L,), np.int32),
-                              jnp.asarray(page_table),
-                              jnp.asarray(write_page),
-                              jnp.asarray(write_off),
-                              jnp.asarray(base_keys),
-                              np.zeros((L,), np.int32),
-                              jnp.asarray(temp), jnp.asarray(top_k),
-                              jnp.asarray(top_p))
-            with otrace.span("serving/decode_prefill_ragged", lanes=L,
-                             live=live, slots=len(picks)):
+            with otrace.span("serving/prefill_args", **attrs):
+                up = _Uploads()
+                tokens = np.zeros((L, 1), np.int32)
+                start = np.zeros((L,), np.int32)
+                page_table = np.zeros(
+                    (L,) + self._cache.page_table[0].shape, np.int32)
+                write_page = np.zeros((L, 1), np.int32)
+                write_off = np.zeros((L, 1), np.int32)
+                key0 = np.asarray(self._slots[picks[0][0]].base_key)
+                base_keys = np.zeros((L,) + key0.shape, key0.dtype)
+                temp = np.zeros((L,), np.float32)
+                top_k = np.zeros((L,), np.int32)
+                top_p = np.ones((L,), np.float32)
+                lane = 0
+                spec_any = any_final = False
+                for i, s, t in picks:
+                    st = self._slots[i]
+                    req = st.req
+                    spec_any = spec_any or st.spec
+                    any_final = any_final or s + t >= len(req.prompt)
+                    for j in range(t):
+                        pos = s + j
+                        tokens[lane, 0] = req.prompt[pos]
+                        start[lane] = pos
+                        page_table[lane] = self._cache.page_table[i]
+                        write_page[lane, 0] = self._cache.page_table[i][
+                            pos // cc.page_size]
+                        write_off[lane, 0] = pos % cc.page_size
+                        base_keys[lane] = np.asarray(st.base_key)
+                        temp[lane] = req.temperature
+                        top_k[lane] = req.top_k
+                        top_p[lane] = req.top_p
+                        lane += 1
+                args = lambda w: (  # noqa: E731
+                    w, up(tokens), up(start),
+                    up.host(np.zeros((L,), np.int32)), up(page_table),
+                    up(write_page), up(write_off), up(base_keys),
+                    up.host(np.zeros((L,), np.int32)),
+                    up(temp), up(top_k), up(top_p))
+                target_args = args(self.weights)
+                draft_args = args(self.draft_weights) if spec_any \
+                    else None
+                up.record()
+            with otrace.span("serving/prefill_dispatch", **attrs):
                 tok, _greedy, logits = self._exe.run_persistent(
                     self._rows_fn(1, L), self._state_vars,
-                    args=args(self.weights), scope=self._scope)
+                    args=target_args, scope=self._scope)
                 if spec_any:
                     self._exe.run_persistent(
                         self._rows_fn(1, L, "draft"),
                         self._draft_state_vars,
-                        args=args(self.draft_weights), scope=self._scope)
-            stat_time("decode_prefill_seconds", time.monotonic() - t0)
-            stat_add("prefill_chunks")
-            stat_add("decode_ragged_dispatches")
-            record_pad_waste(live, L)
-            self._prefill_chunk_count += 1
-            dur = round((time.monotonic() - t0) * 1e3, 3)
-            lane = 0
-            for i, s, t in picks:
-                st = self._slots[i]
-                req = st.req
-                lane += t
-                n = len(req.prompt)
-                final = s + t >= n
-                st.chunks += 1
-                self._tev(req, "prefill_chunk", slot=i, start=s, rows=t,
-                          live=t, final=final, ragged=True, dur_ms=dur)
-                st.prefill_pos += t
-                if final:
-                    stat_add("decode_prefills")
-                    st.phase = "decode"
-                    self._cache.lengths[i] = n
-                    if req.record_logits:
-                        req.logits_trace.append(
-                            np.asarray(logits)[lane - 1, 0].copy())
-                    self._deliver(i, int(np.asarray(tok)[lane - 1]))
+                        args=draft_args, scope=self._scope)
+            if any_final:
+                # only a dispatch that samples some request's first
+                # token is read back
+                with otrace.span("serving/prefill_sync", **attrs):
+                    tok = np.asarray(tok)
+            dur = time.monotonic() - t0
+            stat_time("decode_prefill_seconds", dur)
+            with otrace.span("serving/prefill_deliver", **attrs):
+                stat_add("prefill_chunks")
+                stat_add("decode_ragged_dispatches")
+                record_pad_waste(live, L)
+                self._prefill_chunk_count += 1
+                dur_ms = round(dur * 1e3, 3)
+                lane = 0
+                for i, s, t in picks:
+                    st = self._slots[i]
+                    req = st.req
+                    lane += t
+                    n = len(req.prompt)
+                    final = s + t >= n
+                    st.chunks += 1
+                    self._tev(req, "prefill_chunk", slot=i, start=s,
+                              rows=t, live=t, final=final, ragged=True,
+                              dur_ms=dur_ms)
+                    st.prefill_pos += t
+                    if final:
+                        stat_add("decode_prefills")
+                        st.phase = "decode"
+                        self._cache.lengths[i] = n
+                        if req.record_logits:
+                            req.logits_trace.append(
+                                np.asarray(logits)[lane - 1, 0].copy())
+                        self._deliver(i, int(tok[lane - 1]))
         except Exception as e:  # noqa: BLE001 — the packed dispatch is
             # shared: fail every packed request, not just one
             stat_add("decode_prefill_errors")
@@ -1849,11 +1952,10 @@ class DecodeEngine:
         if normal:
             self._run_step(normal)
 
-    def _step_args(self, live_idx):
+    def _step_args(self, live_idx, up):
         """Everything one joint decode step takes after its state
-        tuple: the weights plus per-slot feeds (dead slots zeroed)."""
-        import jax.numpy as jnp
-
+        tuple: the weights plus per-slot feeds (dead slots zeroed),
+        each handed to the device through ``up`` (an ``_Uploads``)."""
         s = self._cache.config.num_slots
         tokens = np.zeros((s,), np.int32)
         positions = np.zeros((s,), np.int32)
@@ -1882,13 +1984,10 @@ class DecodeEngine:
             top_k[i] = st.req.top_k
             top_p[i] = st.req.top_p
             base_keys[i] = np.asarray(st.base_key)
-        return (self.weights, jnp.asarray(tokens),
-                jnp.asarray(positions), jnp.asarray(live),
-                jnp.asarray(self._cache.page_table),
-                jnp.asarray(write_page), jnp.asarray(write_off),
-                jnp.asarray(base_keys), jnp.asarray(counters),
-                jnp.asarray(temp), jnp.asarray(top_k),
-                jnp.asarray(top_p))
+        return (self.weights, up(tokens), up(positions), up(live),
+                up(self._cache.page_table), up(write_page),
+                up(write_off), up(base_keys), up(counters), up(temp),
+                up(top_k), up(top_p))
 
     def lower_step(self, sharding=None):
         """The joint decode step lowered at this engine's own shapes;
@@ -1899,7 +1998,7 @@ class DecodeEngine:
         import jax
 
         args = (tuple(self._scope.get_var(n) for n in self._state_vars),
-                *self._step_args(()))
+                *self._step_args((), _Uploads()))
         if sharding is not None:
             args = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
@@ -1907,19 +2006,25 @@ class DecodeEngine:
         return self._step_fn.lower(*args)
 
     def _run_step(self, live_idx):
+        attrs = {"step": self._decode_steps, "live": len(live_idx)}
         # copy-on-write any shared page this step would write (a
         # borrowed partial tail at its first divergent token)
-        for i in live_idx:
-            if not self._slots[i].write_trash_once:
-                self._perform_cow(i, self._cache.plan_cow(
-                    i, [int(self._cache.lengths[i])]))
-        args = self._step_args(live_idx)
+        with otrace.span("serving/step_cow", **attrs):
+            for i in live_idx:
+                if not self._slots[i].write_trash_once:
+                    self._perform_cow(i, self._cache.plan_cow(
+                        i, [int(self._cache.lengths[i])]))
+        with otrace.span("serving/step_args", **attrs):
+            up = _Uploads()
+            args = self._step_args(live_idx, up)
+            up.record()
         t0 = time.monotonic()
         try:
-            with otrace.span("serving/decode_step", live=len(live_idx)):
+            with otrace.span("serving/step_dispatch", **attrs):
                 nxt, logits = self._exe.run_persistent(
                     self._step_fn, self._state_vars, args=args,
                     scope=self._scope)
+            with otrace.span("serving/step_sync", **attrs):
                 nxt = np.asarray(nxt)  # THE per-step sync point
         except Exception as e:  # noqa: BLE001 — fail the batch loudly,
             # free every slot, keep the consumer thread alive
@@ -1928,20 +2033,22 @@ class DecodeEngine:
                 self._finish_slot(i, e)
             return
         stat_time("decode_step_seconds", time.monotonic() - t0)
-        logits_np = None
-        for i in live_idx:
-            st = self._slots[i]
-            st.write_trash_once = False
-            if st.spec:
-                st.draft_lag += 1  # target-only write: draft is stale
-            self._cache.lengths[i] += 1
-            if st.req.record_logits:
-                if logits_np is None:
-                    logits_np = np.asarray(logits)
-                st.req.logits_trace.append(logits_np[i].copy())
-            self._deliver(i, int(nxt[i]))
-        stat_set("decode_slot_occupancy", self.live_slots)
-        stat_add("decode_steps")
+        self._decode_steps += 1
+        with otrace.span("serving/step_deliver", **attrs):
+            logits_np = None
+            for i in live_idx:
+                st = self._slots[i]
+                st.write_trash_once = False
+                if st.spec:
+                    st.draft_lag += 1  # target-only write: draft stale
+                self._cache.lengths[i] += 1
+                if st.req.record_logits:
+                    if logits_np is None:
+                        logits_np = np.asarray(logits)
+                    st.req.logits_trace.append(logits_np[i].copy())
+                self._deliver(i, int(nxt[i]))
+            stat_set("decode_slot_occupancy", self.live_slots)
+            stat_add("decode_steps")
 
     def _run_spec(self, spec_idx):
         """One speculative round for the greedy slots: a k-token draft
@@ -1950,47 +2057,54 @@ class DecodeEngine:
         its position — bitwise-identical to non-speculative greedy
         decode; proposals only decide how many tokens this round
         yields (1..k+1)."""
-        import jax.numpy as jnp
-
         c = self._cache.config
         s = c.num_slots
         k = self.config.spec_k
         rows = k + 1
         k_live = {}
-        for i in spec_idx:
-            st = self._slots[i]
-            rem = st.req.max_new_tokens - st.n_generated
-            k_live[i] = min(k, rem - 1)
-            # CoW the pages this round's window writes (skip the
-            # trash-aimed first position on the cache-hit path)
-            n = int(self._cache.lengths[i])
-            lo = n + (1 if st.write_trash_once else 0)
-            self._perform_cow(i, self._cache.plan_cow(
-                i, range(lo, n + k_live[i] + 1)))
-        tok0 = np.zeros((s,), np.int32)
-        start = np.zeros((s,), np.int32)
-        live = np.zeros((s,), bool)
-        trash_first = np.zeros((s,), bool)
-        for i in spec_idx:
-            st = self._slots[i]
-            tok0[i] = st.last_token
-            start[i] = self._cache.lengths[i]
-            live[i] = True
-            trash_first[i] = st.write_trash_once
+        attrs = {"step": self._decode_steps, "live": len(spec_idx),
+                 "k": k}
+        with otrace.span("serving/step_cow", **attrs):
+            for i in spec_idx:
+                st = self._slots[i]
+                rem = st.req.max_new_tokens - st.n_generated
+                k_live[i] = min(k, rem - 1)
+                # CoW the pages this round's window writes (skip the
+                # trash-aimed first position on the cache-hit path)
+                n = int(self._cache.lengths[i])
+                lo = n + (1 if st.write_trash_once else 0)
+                self._perform_cow(i, self._cache.plan_cow(
+                    i, range(lo, n + k_live[i] + 1)))
+        with otrace.span("serving/step_args", **attrs):
+            up = _Uploads()
+            tok0 = np.zeros((s,), np.int32)
+            start = np.zeros((s,), np.int32)
+            live = np.zeros((s,), bool)
+            trash_first = np.zeros((s,), bool)
+            for i in spec_idx:
+                st = self._slots[i]
+                tok0[i] = st.last_token
+                start[i] = self._cache.lengths[i]
+                live[i] = True
+                trash_first[i] = st.write_trash_once
+            propose_args = (self.draft_weights, up(tok0), up(start),
+                            up(live), up(trash_first),
+                            up(self._cache.page_table))
+            up.record()
         t0 = time.monotonic()
         try:
             if self._propose_fn is None:
                 self._propose_fn = self._build_propose_fn(k)
-            with otrace.span("serving/decode_spec", live=len(spec_idx),
-                             k=k):
+            # the round is two dispatches, each with its own phases:
+            # the draft burst, then the target's verify of its proposals
+            with otrace.span("serving/step_dispatch", **attrs):
                 (props,) = self._exe.run_persistent(
                     self._propose_fn, self._draft_state_vars,
-                    args=(self.draft_weights, jnp.asarray(tok0),
-                          jnp.asarray(start), jnp.asarray(live),
-                          jnp.asarray(trash_first),
-                          jnp.asarray(self._cache.page_table)),
-                    scope=self._scope)
+                    args=propose_args, scope=self._scope)
+            with otrace.span("serving/step_sync", **attrs):
                 props = np.asarray(props)            # [S, k+1]
+            with otrace.span("serving/step_args", **attrs):
+                up = _Uploads()
                 tokens = np.zeros((s, rows), np.int32)
                 write_page = np.zeros((s, rows), np.int32)
                 write_off = np.zeros((s, rows), np.int32)
@@ -2004,20 +2118,22 @@ class DecodeEngine:
                         write_page[i, r] = self._cache.page_table[i][
                             pos // c.page_size]
                         write_off[i, r] = pos % c.page_size
+                verify_args = (
+                    self.weights, up(tokens), up(start),
+                    up.host(np.zeros((s,), np.int32)),
+                    up(self._cache.page_table), up(write_page),
+                    up(write_off),
+                    up.host(np.zeros((s, 2), np.uint32)),
+                    up.host(np.zeros((s,), np.int32)),
+                    up.host(np.zeros((s,), np.float32)),
+                    up.host(np.zeros((s,), np.int32)),
+                    up.host(np.ones((s,), np.float32)))
+                up.record()
+            with otrace.span("serving/step_dispatch", **attrs):
                 _tok, greedy, logits = self._exe.run_persistent(
                     self._rows_fn(rows, s), self._state_vars,
-                    args=(self.weights, jnp.asarray(tokens),
-                          jnp.asarray(start),
-                          np.zeros((s,), np.int32),
-                          jnp.asarray(self._cache.page_table),
-                          jnp.asarray(write_page),
-                          jnp.asarray(write_off),
-                          np.zeros((s, 2), np.uint32),
-                          np.zeros((s,), np.int32),
-                          np.zeros((s,), np.float32),
-                          np.zeros((s,), np.int32),
-                          np.ones((s,), np.float32)),
-                    scope=self._scope)
+                    args=verify_args, scope=self._scope)
+            with otrace.span("serving/step_sync", **attrs):
                 greedy = np.asarray(greedy)          # [S, k+1]
         except Exception as e:  # noqa: BLE001 — batch fault isolation
             stat_add("decode_step_errors")
@@ -2026,27 +2142,31 @@ class DecodeEngine:
                     self._finish_slot(i, e)
             return
         stat_time("decode_step_seconds", time.monotonic() - t0)
+        self._decode_steps += 1
         logits_np = None
         proposed = accepted = 0
-        for i in spec_idx:
-            st = self._slots[i]
-            a = 0
-            while a < k_live[i] and int(props[i, a]) == int(greedy[i, a]):
-                a += 1
-            proposed += k_live[i]
-            accepted += a
-            self._tev(st.req, "spec_round", slot=i,
-                      proposed=k_live[i], accepted=a)
-            st.write_trash_once = False
-            for j in range(a + 1):
-                self._cache.lengths[i] += 1
-                if st.req.record_logits:
-                    if logits_np is None:
-                        logits_np = np.asarray(logits)
-                    st.req.logits_trace.append(logits_np[i, j].copy())
-                self._deliver(i, int(greedy[i, j]))
-                if self._slots[i] is None:
-                    break  # finished (EOS/budget) mid-emission
+        with otrace.span("serving/step_deliver", **attrs):
+            for i in spec_idx:
+                st = self._slots[i]
+                a = 0
+                while a < k_live[i] and \
+                        int(props[i, a]) == int(greedy[i, a]):
+                    a += 1
+                proposed += k_live[i]
+                accepted += a
+                self._tev(st.req, "spec_round", slot=i,
+                          proposed=k_live[i], accepted=a)
+                st.write_trash_once = False
+                for j in range(a + 1):
+                    self._cache.lengths[i] += 1
+                    if st.req.record_logits:
+                        if logits_np is None:
+                            logits_np = np.asarray(logits)
+                        st.req.logits_trace.append(
+                            logits_np[i, j].copy())
+                    self._deliver(i, int(greedy[i, j]))
+                    if self._slots[i] is None:
+                        break  # finished (EOS/budget) mid-emission
         self._spec_proposed += proposed
         self._spec_accepted += accepted
         stat_add("decode_spec_proposed", proposed)

@@ -211,21 +211,22 @@ class TestTracer:
         assert tids["t0"] != tids["t1"]
 
     def test_explicit_begin_end_respects_flag_and_stays_balanced(self):
-        """Module-level begin()/end() are gated like span(); a begin
-        made while disabled leaves only a discard sentinel, so nesting
-        stays correct even when the flag flips mid-pair."""
+        """The ring buffer of module-level begin()/end() is gated like
+        span()'s; a begin made while disabled decides so for its end()
+        too, so nesting stays correct even when the flag flips
+        mid-pair."""
         observe.clear()
         observe.disable()
         observe.begin("off")
         observe.end()
         assert observe.snapshot() == []
-        observe.begin("off2")  # disabled: sentinel only
+        observe.begin("off2")  # disabled: not in the buffer
         observe.enable()
         try:
-            with observe.span("live"):  # nested "under" the sentinel
+            with observe.span("live"):  # nested "under" it
                 pass
         finally:
-            observe.end()  # pops the sentinel, records nothing
+            observe.end()  # closes "off2", records nothing
             observe.disable()
         recs = observe.snapshot()
         assert [r.name for r in recs] == ["live"]

@@ -192,5 +192,18 @@ def test_gpt2_width_decode_step_compiles(one_chip, kv_quant):
         jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
     eng = DecodeEngine(model, weights, DecodeConfig(
         max_seq_len=1024, use_pallas="always", kv_quant=kv_quant))
-    text = eng.lower_step(sharding=one_chip).compile().as_text()
+    lowered = eng.lower_step(sharding=one_chip)
+    text = lowered.compile().as_text()
     assert text.count("tpu_custom_call") == model.num_layers
+    # each call carries the kernel's own name, which is how a trace
+    # tells it from any other tpu_custom_call: the instruction is
+    # "%paged_attention[.n] = ... custom-call(...)" under the step's scope
+    from paddle_tpu.ops.pallas_decode_attention import KERNEL_NAME
+
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == model.num_layers
+    assert all(ln.lstrip().lstrip("%").startswith(KERNEL_NAME)
+               for ln in calls), calls[0][:200]
+    assert f"jit(step)/decode_step/{KERNEL_NAME}/" in lowered.as_text(
+        debug_info=True)
