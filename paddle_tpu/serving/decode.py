@@ -675,6 +675,12 @@ class DecodeEngine:
                             c.page_size, num_pages=c.num_pages,
                             dtype=c.cache_dtype, quantized=c.kv_quant),
                 self._scope, prefix_cache=c.prefix_cache)
+        # whether the pools' one layout is also an unpadded one (the
+        # tile rule in serving/kv_cache.py): the counter that says the
+        # lane-dense representation engaged for this model's shape
+        stat_set("decode_kv_lane_dense",
+                 1 if self._cache.config.lane_dense else 0)
+        stat_set("decode_kv_pool_row_lanes", self._cache.config.row_lanes)
         # per-request timeline hook: claim/CoW/register/evict events
         # from the cache land on the owning request's trace
         self._cache.on_event = self._on_cache_event
@@ -687,16 +693,17 @@ class DecodeEngine:
         if draft_model is not None:
             self.draft_weights = self._commit(draft_weights)
             cc = self._cache.config
-            dshape = (draft_model.num_layers, cc.num_pages, cc.page_size,
-                      draft_model.num_heads, draft_model.head_dim)
+            dshape = cc.pool_shape(
+                draft_model.num_layers,
+                draft_model.num_heads * draft_model.head_dim)
             self._scope.set_var(DRAFT_K_PAGES_VAR,
                                 jnp.zeros(dshape, cc.store_dtype))
             self._scope.set_var(DRAFT_V_PAGES_VAR,
                                 jnp.zeros(dshape, cc.store_dtype))
             self._draft_state_vars = _DRAFT_VARS
             if cc.quantized:
-                dsshape = (draft_model.num_layers, cc.num_pages,
-                           cc.page_size, draft_model.num_heads)
+                dsshape = cc.pool_shape(draft_model.num_layers,
+                                        draft_model.num_heads)
                 for nm in (DRAFT_K_SCALES_VAR, DRAFT_V_SCALES_VAR):
                     self._scope.set_var(
                         nm, jnp.full(dsshape, kv_cache.SCALE_EPS,
@@ -797,11 +804,10 @@ class DecodeEngine:
         # metadata only: it names the call's device ops in a trace
         with jax.named_scope(KERNEL_NAME):
             return paged_decode_attention(
-                q, k_pages[layer], v_pages[layer], page_table, lengths,
+                q, k_pages, v_pages, page_table, lengths, layer=layer,
                 use_pallas=self.config.use_pallas,
                 interpret=self.config.interpret,
-                k_scales=None if k_scales is None else k_scales[layer],
-                v_scales=None if v_scales is None else v_scales[layer])
+                k_scales=k_scales, v_scales=v_scales)
 
     def _token_step_body(self, model, weights, k_pages, v_pages,
                          k_scales, v_scales, tokens, positions,
@@ -966,14 +972,10 @@ class DecodeEngine:
                     write_page.reshape(-1), write_off.reshape(-1))
                 with jax.named_scope(KERNEL_NAME):
                     ctx = paged_chunk_attention(
-                        q, k_pages[l], v_pages[l], page_table,
-                        row_lengths,
-                        use_pallas=self.config.use_pallas,
+                        q, k_pages, v_pages, page_table, row_lengths,
+                        layer=l, use_pallas=self.config.use_pallas,
                         interpret=self.config.interpret,
-                        k_scales=None if k_scales is None
-                        else k_scales[l],
-                        v_scales=None if v_scales is None
-                        else v_scales[l])
+                        k_scales=k_scales, v_scales=v_scales)
                 x = x + model._attn_out(lw, ctx)
                 x = x + model._mlp(
                     lw, model._ln(x, lw["ln2_g"], lw["ln2_b"]))
@@ -1033,12 +1035,17 @@ class DecodeEngine:
     def _build_cow_fn(self):
         """Copy page ``src`` onto page ``dst`` across EVERY pool (all
         layers; target K/V + draft K/V when present) — the device half
-        of copy-on-write."""
+        of copy-on-write.  One page is sliced out and updated into the
+        donated pool in place: nothing pool-sized moves."""
         import jax
+        from jax import lax
 
         def cow(state, src, dst):
-            return ((), tuple(pool.at[:, dst].set(pool[:, src])
-                              for pool in state))
+            return ((), tuple(
+                lax.dynamic_update_slice_in_dim(
+                    pool, lax.dynamic_slice_in_dim(pool, src, 1, axis=1),
+                    dst, axis=1)
+                for pool in state))
 
         return jax.jit(cow, donate_argnums=(0,))
 
@@ -2211,10 +2218,9 @@ class DecodeEngine:
         arr = np.zeros((t_pad,), np.int32)
         arr[:len(tokens)] = tokens
         cc = self._cache.config
-        shape = (cc.num_layers, cc.num_pages, cc.page_size, cc.num_heads,
-                 cc.head_dim)
+        shape = cc.pool_shape()
         if qz:
-            sshape = shape[:-1]
+            sshape = cc.pool_shape(row_lanes=cc.num_heads)
             scratch = (jnp.zeros(shape, jnp.int8),
                        jnp.zeros(shape, jnp.int8),
                        jnp.full(sshape, kv_cache.SCALE_EPS,

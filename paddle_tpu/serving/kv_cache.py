@@ -4,9 +4,10 @@
 Layout (the vLLM PagedAttention idea, TPU-native): all keys/values for
 every serving slot live in TWO device arrays of fixed-size pages
 
-    k_pages, v_pages : [num_layers, num_pages, page_size, heads, head_dim]
+    k_pages, v_pages : [num_layers, num_pages, page_size, heads * head_dim]
 
-and each slot owns an ordered list of page ids (its *page table*).  A
+(a position's heads folded into ONE lane-dense row; see "Device layout"
+below) and each slot owns an ordered list of page ids (its *page table*).  A
 slot's logical sequence position ``t`` maps to page ``table[t // page]``
 offset ``t % page``.  Pages are allocated from a host-side free list at
 admission and returned when their REFCOUNT drops to zero — a finished
@@ -54,8 +55,27 @@ draft model's page pools (serving/decode.py) are indexed by the SAME
 page ids, so sharing, reservation, and CoW cover them for free (the
 engine's CoW copy spans every pool).
 
+**Device layout** (the tile rule).  The chip lays an array out in
+(8, 128) tiles over its two trailing dimensions, and layout assignment
+follows the array's SHAPE: a pool whose trailing dimensions fill those
+tiles exactly (``heads * head_dim`` a multiple of 128, ``page_size`` a
+multiple of 8), written at the third-from-last axis (the offset), is
+row-major from allocation to the end of the process — every serving
+program takes it and returns it in that one layout, and a token's K/V
+lands by an in-place scatter.  A trailing ``[..., heads, 64]`` instead
+was re-laid-out on entry to and exit from every program (four
+whole-pool copies a decode step at GPT-2 widths) and padded 2x in
+lanes.  So a position is stored as ONE row of ``heads * head_dim``
+lanes: ``[R, H, D] -> [R, H*D]`` is a free row-major reshape on write,
+and readers reshape back (`ops/pallas_decode_attention.py` reads heads
+straight out of lanes).  Axes 0-2 (layer, page id, offset) are what all
+host bookkeeping, export/install and copy-on-write index; they never
+see the fold.  A shape that cannot be lane-dense still works, only
+padded: ``CacheConfig.lane_dense`` says which.
+
 **Quantized storage** (``FLAGS_decode_kv_quant``): pages are stored
-int8 beside parallel scale pools ``[layers, pages, page_size, heads]``
+int8 (same folded rows) beside parallel scale pools ``[layers, pages,
+page_size, heads]``
 (one float32 scale per head per position-in-page; see
 :class:`CacheConfig` for why the scale granularity is the page's
 positions rather than one scalar per page).  Writes quantize in the
@@ -127,6 +147,13 @@ class KVPageExport:
 class CacheConfig:
     """Geometry of the paged cache (everything static / compile-time).
 
+    A pool is ``[num_layers, num_pages, page_size, row_lanes]`` with
+    ``row_lanes = num_heads * head_dim``: one position's heads folded
+    into one row (the module header's tile rule).  ``lane_dense`` says
+    whether that row and the page fill the chip's (8, 128) tiles
+    exactly, i.e. whether the pool keeps one unpadded layout through
+    every program.
+
     ``quantized=True`` (``FLAGS_decode_kv_quant``) stores pages as int8
     with a parallel per-page scale pool: one float32 scale per head per
     position-in-page (a ``[page_size, heads]`` scale plane per page,
@@ -172,6 +199,26 @@ class CacheConfig:
         self.store_dtype = np.dtype(np.int8) if self.quantized \
             else self.dtype
         self.scale_dtype = np.dtype(np.float32)
+
+    @property
+    def row_lanes(self) -> int:
+        """Width of one stored position: every head's ``head_dim``
+        values side by side."""
+        return self.num_heads * self.head_dim
+
+    @property
+    def lane_dense(self) -> bool:
+        """Whether ``(page_size, row_lanes)`` fills the chip's (8, 128)
+        tiles exactly (the module header's tile rule)."""
+        return self.row_lanes % 128 == 0 and self.page_size % 8 == 0
+
+    def pool_shape(self, num_layers: Optional[int] = None,
+                   row_lanes: Optional[int] = None) -> Tuple[int, ...]:
+        """Shape of one page pool; the draft model's pools share the
+        page ids and differ in depth and row width only."""
+        return (self.num_layers if num_layers is None else num_layers,
+                self.num_pages, self.page_size,
+                self.row_lanes if row_lanes is None else row_lanes)
 
     def pages_for(self, seq_len: int) -> int:
         return max(1, math.ceil(int(seq_len) / self.page_size))
@@ -438,8 +485,7 @@ class PagedKVCache:
         # reserved CoW target for a borrowed partial page (at most one)
         self._cow_spare: List[List[int]] = [[] for _ in range(c.num_slots)]
         self._refs = [0] * c.num_pages
-        shape = (c.num_layers, c.num_pages, c.page_size, c.num_heads,
-                 c.head_dim)
+        shape = c.pool_shape()
         scope.set_var(K_PAGES_VAR, jnp.zeros(shape, c.store_dtype))
         scope.set_var(V_PAGES_VAR, jnp.zeros(shape, c.store_dtype))
         # quantized mode: parallel per-page scale pools (one scale per
@@ -456,8 +502,7 @@ class PagedKVCache:
         # its slot releases — debug_check audits exactly that.
         self._migrated_in: Dict[int, int] = {}
         if c.quantized:
-            sshape = (c.num_layers, c.num_pages, c.page_size,
-                      c.num_heads)
+            sshape = c.pool_shape(row_lanes=c.num_heads)
             scope.set_var(K_SCALES_VAR,
                           jnp.full(sshape, SCALE_EPS, c.scale_dtype))
             scope.set_var(V_SCALES_VAR,
@@ -846,20 +891,28 @@ class PagedKVCache:
 
 # -- pure jit-side helpers (operate on the page arrays functionally) ------
 
+def _fold_heads(val):
+    """[..., H, D] -> [..., H*D]: a position's heads side by side in
+    one pool row (row-major, so free)."""
+    return val.reshape(val.shape[:-2] + (val.shape[-2] * val.shape[-1],))
+
+
 def scatter_token_layer(pages, layer: int, val, page_id, offset):
-    """Write one new position per row: val [R, H, D] lands at
-    (layer, page_id[r], offset[r]) — dead rows pass page 0 (trash)."""
+    """Write one new position per row: val [R, H, D] lands as the row
+    [R, H*D] at (layer, page_id[r], offset[r]) of pages [L, P, page,
+    H*D] — dead rows pass page 0 (trash).  Indexing the three leading
+    axes of a lane-dense pool is what lets the chip scatter in place."""
     return pages.at[layer, page_id, offset].set(
-        val.astype(pages.dtype))
+        _fold_heads(val).astype(pages.dtype))
 
 
 def scatter_prompt_layer(pages, layer: int, val, page_ids):
     """Write a whole prompt's positions for one slot: val
     [n_pages*page, H, D] (padded to a page multiple) is stored page-
-    wholesale into ``page_ids`` [n_pages]."""
+    wholesale, as [n_pages, page, H*D], into ``page_ids`` [n_pages]."""
     n = page_ids.shape[0]
     page = pages.shape[2]
-    v = val.reshape(n, page, val.shape[1], val.shape[2])
+    v = _fold_heads(val).reshape(n, page, -1)
     return pages.at[layer, page_ids].set(v.astype(pages.dtype))
 
 
@@ -895,12 +948,14 @@ def dequantize_kv(q, scale, dtype):
 def write_token_layer(pages, scales, layer: int, val, page_id, offset):
     """Quantization-aware :func:`scatter_token_layer`: returns
     ``(pages, scales)``.  ``scales=None`` is the unquantized path
-    (pages store ``val`` directly, scales pass through)."""
+    (pages store ``val`` directly, scales pass through); otherwise the
+    int8 row [R, H*D] and its scale row [R, H] land at the same
+    (layer, page, offset) of their pools."""
     if scales is None:
         return scatter_token_layer(pages, layer, val, page_id,
                                    offset), None
     q, s = quantize_kv(val)
-    return (pages.at[layer, page_id, offset].set(q),
+    return (scatter_token_layer(pages, layer, q, page_id, offset),
             scales.at[layer, page_id, offset].set(
                 s.astype(scales.dtype)))
 
@@ -912,9 +967,8 @@ def write_prompt_layer(pages, scales, layer: int, val, page_ids):
     the per-row chunked path writing the same values."""
     if scales is None:
         return scatter_prompt_layer(pages, layer, val, page_ids), None
+    q, s = quantize_kv(val)
     n = page_ids.shape[0]
-    page = pages.shape[2]
-    v = val.reshape(n, page, val.shape[1], val.shape[2])
-    q, s = quantize_kv(v)
-    return (pages.at[layer, page_ids].set(q),
-            scales.at[layer, page_ids].set(s.astype(scales.dtype)))
+    return (scatter_prompt_layer(pages, layer, q, page_ids),
+            scales.at[layer, page_ids].set(
+                s.reshape(n, -1, s.shape[-1]).astype(scales.dtype)))

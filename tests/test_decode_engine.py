@@ -73,6 +73,32 @@ def test_cache_config_validation():
     assert c.cache_bytes() == 2 * 2 * 33 * 8 * 2 * 16 * 4
 
 
+@pytest.mark.parametrize("heads,head_dim,page,dense", [
+    (2, 16, 8, 0),      # a toy row: 32 lanes of a tile's 128
+    (2, 64, 8, 1),      # two heads of 64 fill one lane tile
+    (12, 64, 16, 1),    # GPT-2 small
+    (2, 64, 4, 0),      # the row fills the lanes, the page not the rows
+])
+def test_engine_build_says_whether_the_pools_are_lane_dense(
+        heads, head_dim, page, dense):
+    """The counter that says whether the one-layout representation is
+    also an unpadded one for this model's shape, set at engine build."""
+    import jax
+
+    model = TransformerLM(vocab_size=VOCAB, d_model=heads * head_dim,
+                          num_layers=1, num_heads=heads, max_seq_len=32)
+    weights = jax.tree_util.tree_map(
+        lambda s: np.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
+    eng = DecodeEngine(model, weights, DecodeConfig(
+        slots=2, max_seq_len=32, page_size=page))
+    assert stat_get("decode_kv_lane_dense") == dense
+    assert stat_get("decode_kv_pool_row_lanes") == heads * head_dim
+    k_pool, _ = eng._cache.arrays()
+    assert k_pool.shape == (1, 2 * (32 // page) + 1, page,
+                            heads * head_dim)
+
+
 def test_prefill_bucket_grid():
     assert prefill_bucket_grid(64, 8) == (8, 16, 32, 64)
     assert prefill_bucket_grid(48, 16) == (16, 32, 48)
@@ -90,17 +116,117 @@ def test_paged_attention_pallas_interpret_matches_reference():
     rs = np.random.RandomState(0)
     s, h, d, pool, page, pps = 4, 2, 16, 9, 8, 4
     q = jnp.asarray(rs.randn(s, h, d).astype("f4"))
-    kp = jnp.asarray(rs.randn(pool, page, h, d).astype("f4"))
-    vp = jnp.asarray(rs.randn(pool, page, h, d).astype("f4"))
+    # the stacked pools [L, P, page, H*D]; layer 1 of 2 is read
+    kp = jnp.asarray(rs.randn(2, pool, page, h * d).astype("f4"))
+    vp = jnp.asarray(rs.randn(2, pool, page, h * d).astype("f4"))
     table = jnp.asarray(rs.randint(1, pool, (s, pps)).astype("i4"))
     # edge lengths: page-boundary, partial page, full table, one token
     lengths = jnp.asarray(np.array([8, 17, 32, 1], "i4"))
-    ref = paged_decode_attention(q, kp, vp, table, lengths,
+    ref = paged_decode_attention(q, kp, vp, table, lengths, layer=1,
                                  use_pallas="never")
-    pal = paged_decode_attention(q, kp, vp, table, lengths,
+    pal = paged_decode_attention(q, kp, vp, table, lengths, layer=1,
                                  use_pallas="always", interpret=True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(pal),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("rows", [1, 4])
+@pytest.mark.parametrize("h,d", [(16, 64), (12, 64), (4, 16), (8, 128)])
+def test_paged_kernel_reads_heads_out_of_lanes(h, d, rows, quantized):
+    """The kernel in interpret mode against the plain masked-softmax
+    reference on the heads-major K/V, at GPT-2-medium's and -small's
+    head shapes, a toy row narrower than a lane tile and head_dim 128,
+    one query row (decode) and four (a chunk), over ragged lengths:
+    nothing, one token, a page boundary - 1 / on it / + 1, the full
+    table.  The pools are stacked and lane-folded as the cache stores
+    them, and a middle layer is read."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_decode_attention import (
+        decode_attention_reference, paged_chunk_attention)
+    from paddle_tpu.serving.kv_cache import dequantize_kv, quantize_kv
+
+    rs = np.random.RandomState(h * d + rows)
+    layers, layer, pool, page, pps = 3, 1, 14, 16, 4
+    base = np.array([0, 1, page - 1, page, page + 1, pps * page], "i4")
+    s = len(base)
+    # row r of a slot attends r more positions, up to the full table
+    row_lengths = np.minimum(base[:, None] + np.arange(rows, dtype="i4"),
+                             pps * page)
+    table = rs.randint(1, pool, (s, pps)).astype("i4")
+    q = jnp.asarray(rs.randn(s, rows, h, d).astype("f4"))
+    kv = [jnp.asarray(rs.randn(layers, pool, page, h, d).astype("f4"))
+          for _ in range(2)]
+    scales = [None, None]
+    if quantized:
+        (kv[0], scales[0]), (kv[1], scales[1]) = map(quantize_kv, kv)
+    pal = paged_chunk_attention(
+        q, *(x.reshape(layers, pool, page, h * d) for x in kv),
+        jnp.asarray(table), jnp.asarray(row_lengths), layer=layer,
+        use_pallas="always", interpret=True, k_scales=scales[0],
+        v_scales=scales[1])
+    if quantized:
+        kv = [dequantize_kv(x, sc, jnp.float32)
+              for x, sc in zip(kv, scales)]
+    # the reference, one query row at a time over its slot's pages
+    full = [np.asarray(x)[layer][table].reshape(s, pps * page, h, d)
+            for x in kv]
+    for r in range(rows):
+        ref = decode_attention_reference(
+            q[:, r], jnp.asarray(full[0]), jnp.asarray(full[1]),
+            jnp.asarray(row_lengths[:, r]))
+        live = row_lengths[:, r] > 0     # a row of nothing is unspecified
+        np.testing.assert_allclose(np.asarray(pal)[live, r],
+                                   np.asarray(ref)[live],
+                                   rtol=1e-5, atol=1e-5)
+    assert np.isfinite(np.asarray(pal)).all()
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_folded_pool_holds_the_bytes_of_the_heads_major_pool(quantized):
+    """After the same token and prompt writes, the lane-folded pool
+    reshaped back to [..., H, D] holds byte for byte what the old
+    [L, P, page, H, D] pool held (and the scale planes are equal)."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving import kv_cache as kvc
+
+    rs = np.random.RandomState(3)
+    c = CacheConfig(2, 4, 16, num_slots=2, max_seq_len=32, page_size=8,
+                    num_pages=7, quantized=quantized)
+    assert c.pool_shape() == (2, 7, 8, 64) and not c.lane_dense
+    old_shape = (2, 7, 8, 4, 16)
+    new = jnp.zeros(c.pool_shape(), c.store_dtype)
+    old = jnp.zeros(old_shape, c.store_dtype)
+    sc_new = sc_old = None
+    if quantized:
+        sc_new = sc_old = jnp.full(c.pool_shape(row_lanes=4),
+                                   kvc.SCALE_EPS, jnp.float32)
+
+    def stored(val):
+        return kvc.quantize_kv(val) if quantized else (val, None)
+
+    # a prompt of two pages into pages 5 and 2 of layer 1 ...
+    prompt = jnp.asarray(rs.randn(16, 4, 16).astype("f4"))
+    ids = jnp.asarray([5, 2], jnp.int32)
+    new, sc_new = kvc.write_prompt_layer(new, sc_new, 1, prompt, ids)
+    pv, ps = stored(prompt.reshape(2, 8, 4, 16))
+    old = old.at[1, ids].set(pv.astype(old.dtype))
+    # ... then three tokens, one of them aimed at the trash page
+    tok = jnp.asarray(rs.randn(3, 4, 16).astype("f4"))
+    pid = jnp.asarray([2, 0, 6], jnp.int32)
+    off = jnp.asarray([7, 0, 3], jnp.int32)
+    new, sc_new = kvc.write_token_layer(new, sc_new, 0, tok, pid, off)
+    tv, ts = stored(tok)
+    old = old.at[0, pid, off].set(tv.astype(old.dtype))
+    if quantized:
+        sc_old = sc_old.at[1, ids].set(ps).at[0, pid, off].set(ts)
+        np.testing.assert_array_equal(np.asarray(sc_new),
+                                      np.asarray(sc_old))
+    assert np.asarray(new).reshape(old_shape).tobytes() \
+        == np.asarray(old).tobytes()
+    assert np.asarray(new).any()
 
 
 # -- THE oracle: cached decode == full recompute, bitwise -----------------

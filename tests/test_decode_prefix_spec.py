@@ -431,8 +431,9 @@ def test_paged_chunk_attention_pallas_interpret_matches_reference():
     rs = np.random.RandomState(0)
     s, r, h, d, pool, page, pps = 3, 5, 2, 16, 9, 8, 4
     q = jnp.asarray(rs.randn(s, r, h, d).astype("f4"))
-    kp = jnp.asarray(rs.randn(pool, page, h, d).astype("f4"))
-    vp = jnp.asarray(rs.randn(pool, page, h, d).astype("f4"))
+    # the stacked pools [L, P, page, H*D] (one layer here)
+    kp = jnp.asarray(rs.randn(1, pool, page, h * d).astype("f4"))
+    vp = jnp.asarray(rs.randn(1, pool, page, h * d).astype("f4"))
     table = jnp.asarray(rs.randint(1, pool, (s, pps)).astype("i4"))
     # starts at a mid-page offset, zero, and near the table's end
     starts = np.array([7, 0, 27], "i4")

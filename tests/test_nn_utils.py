@@ -79,8 +79,13 @@ def test_weight_norm_negative_dim_counts_from_back():
 
 
 def test_spectral_norm_unit_top_singular_value():
+    # a fixed weight: 20 power iterations reach 1e-3 only when the top
+    # two singular values are apart, which a random init (the global
+    # key, so whatever ran before on this worker) does not promise
+    w = np.random.RandomState(0).randn(6, 5).astype("f4")
     with dygraph.guard():
-        lyr = nn.Linear(6, 5)
+        lyr = nn.Linear(6, 5, weight_attr=pt.ParamAttr(
+            initializer=nn.initializer.Assign(w)))
         nn.utils.spectral_norm(lyr, n_power_iterations=20)
         x = pt.to_tensor(np.eye(6, dtype="f4"))
         lyr(x)  # trigger hook; layer.weight now normalized

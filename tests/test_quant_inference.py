@@ -462,10 +462,14 @@ def test_kv_quant_pallas_interpret_matches_reference():
 
     rs = np.random.RandomState(0)
     s, h, d, pool, page, pps = 3, 2, 16, 9, 8, 4
-    kq, ks = quantize_kv(jnp.asarray(rs.randn(pool, page, h, d)
+    # quantized per head, stored as the stacked pools hold it: int8
+    # rows [L, P, page, H*D] beside scale planes [L, P, page, H]
+    kq, ks = quantize_kv(jnp.asarray(rs.randn(1, pool, page, h, d)
                                      .astype("f4")))
-    vq, vs = quantize_kv(jnp.asarray(rs.randn(pool, page, h, d)
+    vq, vs = quantize_kv(jnp.asarray(rs.randn(1, pool, page, h, d)
                                      .astype("f4")))
+    kq = kq.reshape(1, pool, page, h * d)
+    vq = vq.reshape(1, pool, page, h * d)
     table = jnp.asarray(rs.randint(1, pool, (s, pps)).astype("i4"))
     q = jnp.asarray(rs.randn(s, h, d).astype("f4"))
     lengths = jnp.asarray(np.array([5, 17, 32], "i4"))

@@ -5,7 +5,8 @@ DESCRIBED, not attached (``v5e:2x2``).  Interpret mode cannot see what it
 refuses: a matmul with no free row dimension, a block that is not a legal
 (8, 128) tile, too much VMEM.  These cases pin every Pallas kernel of the
 serving and training main paths at real widths, plus the whole
-GPT-2-width decode step.
+GPT-2-width decode step, and that the K/V page pools keep one device
+layout through the step, the prefill and copy-on-write.
 
 This is the ONLY file that describes the chip, and it does so inside a
 module-scoped, non-autouse fixture: nothing chip-related runs at import,
@@ -18,6 +19,7 @@ without a chip).
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -65,23 +67,28 @@ def _compile(one_chip, fn, *shapes):
 SLOTS, PPS = 8, 64                      # one replica's slot batch
 
 
+LAYERS = 2                              # the pools are stacked
+
+
 def _paged_shapes(h, d, page, rows, quantized):
     """(q, k_pages, v_pages, page_table, lengths[, k_scales, v_scales])
-    for the paged kernels; ``rows=0`` is the one-token decode shape."""
+    for the paged kernels, the pools stacked and lane-folded as the
+    cache stores them; ``rows=0`` is the one-token decode shape."""
     pool = SLOTS * PPS
     q = (SLOTS, rows, h, d) if rows else (SLOTS, h, d)
     ln = (SLOTS, rows) if rows else (SLOTS,)
-    kv = ((pool, page, h, d), jnp.int8 if quantized else jnp.float32)
+    kv = ((LAYERS, pool, page, h * d),
+          jnp.int8 if quantized else jnp.float32)
     out = [(q, jnp.float32), kv, kv, ((SLOTS, PPS), jnp.int32),
            (ln, jnp.int32)]
     if quantized:
-        out += [((pool, page, h), jnp.float32)] * 2
+        out += [((LAYERS, pool, page, h), jnp.float32)] * 2
     return out
 
 
 def _paged(op, q, k, v, pt, ln, ks=None, vs=None):
-    return op(q, k, v, pt, ln, use_pallas="always", k_scales=ks,
-              v_scales=vs)
+    return op(q, k, v, pt, ln, layer=LAYERS - 1, use_pallas="always",
+              k_scales=ks, v_scales=vs)
 
 
 @pytest.mark.parametrize("quantized", [False, True],
@@ -176,22 +183,31 @@ def test_dequant_matmul_compiles(one_chip, m, k, n):
     assert "tpu_custom_call" in text  # tiled, not the reference
 
 
+def _gpt2_width_engine(num_heads, kv_quant, vocab_size=50257, **cfg):
+    """An engine at GPT-2's head shape (``num_heads`` x 64), depth cut
+    to 2 layers, weights zero: only shapes matter to a compile.  'auto'
+    reads the live backend (the CPU here), so the engine is steered to
+    the kernel itself."""
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+    from paddle_tpu.serving.decode import TransformerLM
+
+    model = TransformerLM(vocab_size=vocab_size, d_model=num_heads * 64,
+                          num_layers=2, num_heads=num_heads,
+                          ffn_dim=4 * num_heads * 64, max_seq_len=1024)
+    weights = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
+    return DecodeEngine(model, weights, DecodeConfig(
+        max_seq_len=1024, use_pallas="always", kv_quant=kv_quant, **cfg))
+
+
 @pytest.mark.parametrize("kv_quant", [False, True], ids=["plain", "int8kv"])
 def test_gpt2_width_decode_step_compiles(one_chip, kv_quant):
     """The engine's whole joint decode step at GPT-2-small width (depth
     cut to 2 layers): the kernel must be IN the step the compiler
-    accepted.  'auto' reads the live backend (the CPU here), so the test
-    steers the engine to the kernel itself."""
-    from paddle_tpu.serving import DecodeConfig, DecodeEngine
-    from paddle_tpu.serving.decode import TransformerLM
-
-    model = TransformerLM(vocab_size=50257, d_model=768, num_layers=2,
-                          num_heads=12, ffn_dim=3072, max_seq_len=1024)
-    weights = jax.tree_util.tree_map(
-        lambda s: jnp.zeros(s.shape, s.dtype),
-        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
-    eng = DecodeEngine(model, weights, DecodeConfig(
-        max_seq_len=1024, use_pallas="always", kv_quant=kv_quant))
+    accepted."""
+    eng = _gpt2_width_engine(12, kv_quant)
+    model = eng.model
     lowered = eng.lower_step(sharding=one_chip)
     text = lowered.compile().as_text()
     assert text.count("tpu_custom_call") == model.num_layers
@@ -207,3 +223,123 @@ def test_gpt2_width_decode_step_compiles(one_chip, kv_quant):
                for ln in calls), calls[0][:200]
     assert f"jit(step)/decode_step/{KERNEL_NAME}/" in lowered.as_text(
         debug_info=True)
+
+
+# -- the K/V pools keep ONE layout through every serving program ----------
+#
+# A CPU run can show what is IN a compiled program, never a time: these
+# cases are the standing proof that no program re-lays a pool out, slices
+# a layer out of it or copies it, and that a token's K/V lands in place.
+
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%?(?P<name>[\w.\-]+) = (?P<type>.+?) "
+    r"(?P<op>[a-z][\w\-]*)\(")
+_ARRAY = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def _computation(text, header):
+    """The lines of the computation whose first line starts with
+    ``header``, up to its closing brace."""
+    lines = text.splitlines()
+    start = next(i for i, ln in enumerate(lines) if ln.startswith(header))
+    end = next(i for i in range(start, len(lines))
+               if lines[i].startswith("}"))
+    return lines[start + 1:end]
+
+
+def _assert_pools_stay_put(compiled, pools, relaid=()):
+    """``pools``: the shapes of the state tuple, which is the program's
+    FIRST parameters and LAST results.  Shapes in ``relaid`` are known
+    NOT to keep their layout inside the program (they are still aliased
+    and enter and leave alike)."""
+    text = compiled.as_text()
+    n = len(pools)
+    ins = jax.tree_util.tree_leaves(compiled.input_formats)
+    outs = jax.tree_util.tree_leaves(compiled.output_formats)
+    # every state output is the donated state input, updated in place
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    pairs = {(int(o), int(i)) for o, i in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", alias[1])}
+    want = {(len(outs) - n + i, i) for i in range(n)}
+    assert want <= pairs, (sorted(want), sorted(pairs))
+    # ... and arrives and leaves in the same device layout
+    for i in range(n):
+        assert ins[i].layout == outs[len(outs) - n + i].layout, (
+            i, ins[i].layout, outs[len(outs) - n + i].layout)
+    # nothing pool-sized or layer-of-pool-sized is materialised (the
+    # ENTRY computation's results; a fusion's inner instructions live
+    # in registers and VMEM) except by the scatter or
+    # dynamic-update-slice that writes the pool in place
+    strict = [s for s in pools if s not in relaid]
+    sized = {tuple(s) for s in strict} | {tuple(s[1:]) for s in strict}
+    offenders, writers = [], 0
+    for line in _computation(text, "ENTRY "):
+        m = _INSTR.match(line)
+        if not m or m["op"] in ("parameter", "get-tuple-element", "tuple",
+                                "bitcast"):
+            continue
+        dims = {tuple(int(d) for d in a.split(",") if d)
+                for a in _ARRAY.findall(m["type"])}
+        if not {d[1:] if d[:1] == (1,) else d for d in dims} & sized:
+            continue
+        called = re.search(r"calls=(%[\w.\-]+)", line)
+        root = next(ln for ln in _computation(text, called[1] + " ")
+                    if "ROOT " in ln) if called else line
+        if " scatter(" in root or " dynamic-update-slice(" in root:
+            writers += 1
+        else:
+            offenders.append(f"{m['name']} = {m['type'][:80]} {m['op']}")
+    assert not offenders, offenders
+    assert writers, "no in-place write of the pools"
+
+
+def _on_chip(tree, one_chip):
+    """Shapes of ``tree`` placed on the described chip: a program is
+    lowered from them, nothing runs."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
+                                       sharding=one_chip), tree)
+
+
+def _lower_program(eng, program, one_chip):
+    """One of the engine's pool-taking programs lowered at its own
+    shapes; returns it with the shapes of its state tuple."""
+    cc = eng._cache.config
+    state = tuple(eng._scope.get_var(n) for n in eng._state_vars)
+    i32, f32 = jnp.int32, jnp.float32
+    if program == "step":
+        lowered = eng.lower_step(sharding=one_chip)
+    elif program == "prefill":        # one whole-prompt bucket
+        t_pad = 128
+        args = (state, eng.weights, jnp.zeros((t_pad,), i32), i32(100),
+                jnp.zeros((cc.pages_per_slot,), i32),
+                jax.random.PRNGKey(0), f32(0.0), i32(0), f32(1.0))
+        lowered = eng._prefill_fn(t_pad).lower(*_on_chip(args, one_chip))
+    else:                             # copy-on-write of one page
+        lowered = eng._build_cow_fn().lower(
+            *_on_chip((state, i32(1), i32(2)), one_chip))
+    return lowered, [tuple(v.shape) for v in state]
+
+
+@pytest.mark.parametrize("program", ["step", "prefill", "cow"])
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["plain", "int8kv"])
+@pytest.mark.parametrize("heads", [16, 12], ids=["16x64", "12x64"])
+def test_pools_keep_one_layout_through_program(one_chip, heads, kv_quant,
+                                               program):
+    """GPT-2-medium's and GPT-2-small's head shapes, f32 and int8 pools:
+    the compiled step, whole-prompt prefill and copy-on-write hold no
+    pool- or layer-sized copy, slice or transposition."""
+    # 32 slots and their default 2,049 pages: a pool of a few MB is
+    # prefetched whole into fast memory (seen up to 24 MB), which would
+    # prove nothing.  The vocabulary is cut: it only costs compile time
+    eng = _gpt2_width_engine(heads, kv_quant, vocab_size=512, slots=32)
+    cc = eng._cache.config
+    assert cc.lane_dense
+    lowered, pools = _lower_program(eng, program, one_chip)
+    # the int8 pools' scale planes [L, P, page, H] cannot fill the
+    # tiles (H lanes of 128): they enter in a transposed layout and
+    # the step and the prefill re-lay them out around their writes.
+    # A standing finding, not a contract: drop the exemption with it
+    relaid = (cc.pool_shape(row_lanes=cc.num_heads),) \
+        if kv_quant and program != "cow" else ()
+    _assert_pools_stay_put(lowered.compile(), pools, relaid)
