@@ -158,6 +158,13 @@ RULES = (
          "Host arrays the engine's argument builders hand to the device "
          "(`_uploads`: count, `_bytes`), counted where `_step_args` and "
          "the prefill builders hand them over; the weights are not in it"),
+    Rule("decode_attn_blocks_", "gauge", "serving",
+         "Blocks of page-table entries the paged-attention kernel meets "
+         "a layer, added once a joint decode step from the lengths the "
+         "engine holds: `_live` the blocks its loop runs (those holding "
+         "a position some row attends; one for a dead slot), `_walked` "
+         "every block of every slot's table, which a fixed grid would "
+         "walk.  Their ratio is the share of the table that is work"),
     Rule("decode_", "gauge", "serving",
          "Decode-engine lifecycle, paging, speculation, goodput"),
     Rule("serving_", "gauge", "serving",

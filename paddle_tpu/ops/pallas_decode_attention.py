@@ -8,7 +8,7 @@ ONE query token that must attend over its own live history:
 
     q          : [S, H, D]            one token per slot
     k/v_pages  : [L, P, page, H*D]    the STACKED page pools, all layers
-    layer      : int (static)         which layer of the pools to read
+    layer      : int                  which layer of the pools to read
     page_table : [S, pps]  int32      slot -> ordered page ids
     lengths    : [S]       int32      live positions per slot
     k/v_scales : [L, P, page, H]      int8 pools only (per head, per row)
@@ -20,18 +20,43 @@ position's heads folded into lanes, ``H*D`` wide, exactly as
 fills those tiles exactly the pool has ONE device layout from
 allocation on, and no program that takes it re-lays it out (a
 trailing head_dim of 64 cost GPT-2 four whole-pool copies a step and
-2x lane padding).  The kernel takes the stacked pool itself and puts
-``layer`` into the BlockSpec's index map: handing it ``pool[layer]``
-made XLA materialise a layer-sized slice per layer per step.
+2x lane padding).  The kernel takes the stacked pool itself and
+indexes ``layer`` in its own copies: handing it ``pool[layer]`` made
+XLA materialise a layer-sized slice per layer per step.  ``layer`` is
+an operand, not a constant of the kernel, so a model's layers share
+ONE traced and lowered kernel (a program's set-up pays for the body
+once, not once a layer).
 
-The Pallas kernel iterates grid (slot, page) with the page table and
-lengths as SCALAR-PREFETCH operands: the page id is known before the
-body runs, so each (slot, page) step DMAs exactly one page of K and V
-from the pool — HBM traffic is O(sum(live pages)), never
-O(S * max_seq).  Pages at or past the slot's length are skipped
-entirely (`pl.when`), and the partial page at the tail is masked by
-position.  Online softmax (running max / denominator in VMEM scratch)
-accumulates across pages exactly like the prefill flash kernel.
+**The walk: live pages, a block of them at a time.**  One grid step is
+one SLOT; the page table, the row lengths, each slot's widest row and
+``layer`` are SCALAR-PREFETCH operands, and the pools stay in HBM.
+Inside a slot the kernel loops over its LIVE blocks only — a block is
+``ppb`` consecutive page-table entries — so a step's cost follows the
+positions attended, never ``S * max_seq``: a dead block costs nothing,
+not even a skipped grid step.  ``ppb`` is read from the shapes
+(`pages_per_block`): the largest count with ``ppb * page <= 128``
+positions, one full lane tile of scores, whose K and V blocks fit the
+fast-memory budget twice over (eight 16-token pages at GPT-2's 1,024
+f32 lanes and at 2,048 bf16 lanes, 2 MB; a page of 128 positions is a
+block by itself).  The scores of a block are ONE ``(hb*R, ppb*page)``
+tile a stack: one masked online-softmax update (running max /
+denominator in VMEM scratch, as in the prefill flash kernel) and one
+rescale of the accumulator a block, not a page.
+
+Only live pages move, by the kernel's own async copies into one of two
+block buffers a pool: while a block is computed the next one — this
+slot's, or the NEXT slot's first — is in flight, so no slot starts on
+an exposed copy.  Start and wait walk the same range of live entries,
+so every copy is waited for exactly once; a dead table entry is never
+read.  **The hazard that follows**: a dead page of a live block was
+never copied, its buffer holds whatever was there, and although its
+scores are masked (``p == 0``), ``0 * NaN`` is NaN.  So V is zeroed by
+position before ``p @ v`` (tests poison dead pages and fresh buffers
+with NaN).  The int8 pools' f32 scale planes ``[L, P, page, H]`` are the
+exception to "the pools stay put": the chip's compiler cuts no page out
+of a plane H lanes wide in HBM, so a slot's scales are gathered by its
+page table outside the kernel (1/D of the pages' bytes) and arrive as
+one block a slot.
 
 Heads are read out of lanes without a reshape: the query rows of
 ``hb`` heads are stacked block-diagonally (row ``h*R + r`` holds query
@@ -139,6 +164,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
 # -- the kernel: R query rows per slot (decode is R=1) --------------------
 
 _MAX_STACK_ROWS = 32  # query rows one matmul carries (hb heads x R rows)
+# both buffers of both pools' blocks, in fast memory beside the body's
+# f32 working tiles (a fraction of the 16 MB the chip scopes to a kernel)
+_BLOCK_VMEM_BYTES = 4 << 20
 
 
 def _stack_heads(num_heads, head_dim, n_rows):
@@ -157,70 +185,132 @@ def _stack_heads(num_heads, head_dim, n_rows):
     return max(fit) if fit else min(legal)
 
 
-def _chunk_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                  sm_scale, page, n_pages, n_rows, head_dim,
-                  quantized=False):
-    """The decode kernel generalized to R query rows per slot (a
-    prefill chunk or a speculative t0+draft window).  Row r of slot s
-    attends positions ``t < len_ref[s*R + r]`` — per-row causal masks
-    over one shared page table, so shared and partially-filled pages
-    need no special casing beyond the mask.
+def pages_per_block(page, pps, row_lanes, itemsize):
+    """How many consecutive page-table entries one block covers.
 
-    Refs: q/o (1, R, H*D); k/v (page, H*D) — one page of ONE layer of
-    the stacked pool; scales (page, H).  Scratch, per stack of ``hb``
-    heads: the block-diagonal query (hb*R, hb*D), running max and
-    denominator (hb*R, 128), accumulator (hb*R, hb*D)."""
+    The largest count whose positions fill at most one 128-lane tile of
+    scores (``ppb * page <= 128``) and whose K and V blocks, double
+    buffered, fit ``_BLOCK_VMEM_BYTES``; never more than the table
+    holds, never less than 1 (a page of 128 positions or more is a
+    block by itself).  ``pps`` need not be a multiple: the last block's
+    missing entries are dead like any other."""
+    by_tile = _LANES // page
+    by_vmem = _BLOCK_VMEM_BYTES // (2 * 2 * page * row_lanes * itemsize)
+    return max(1, min(by_tile, by_vmem, pps))
+
+
+def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, q_ref, k_hbm,
+                  v_hbm, *rest, sm_scale, page, pps, ppb, n_slots, n_rows,
+                  head_dim, quantized=False):
+    """One grid step is one SLOT: R query rows (a prefill chunk, a
+    speculative t0+draft window, or decode's one) over the slot's live
+    blocks of ``ppb`` page-table entries.  Row r of slot s attends
+    positions ``t < len_ref[s*R + r]`` — per-row causal masks over one
+    shared page table, so shared and partially-filled pages need no
+    special casing beyond the mask.
+
+    Refs: q/o (1, R, H*D) blocks; k/v the whole stacked pools, left in
+    HBM; int8 pools' scales (1, positions, H), the slot's own, gathered
+    by the caller.  Scratch, per stack of ``hb`` heads: the
+    block-diagonal query (hb*R, hb*D), running max and denominator
+    (hb*R, 128), accumulator (hb*R, hb*D); then the two block buffers
+    (2, ppb, page, H*D) a pool, one DMA semaphore a buffer, and in SMEM
+    which buffer holds the block that is computed next."""
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     if quantized:
-        ks_ref, vs_ref, o_ref, qbd_scr, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, qbd_scr, m_scr, l_scr, acc_scr = rest
-        ks_ref = vs_ref = None
+        ks_ref, vs_ref, *rest = rest
+    o_ref, qbd_scr, m_scr, l_scr, acc_scr, k_buf, v_buf, sem, cur = rest
+    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
 
     s_idx = pl.program_id(0)
-    p_idx = pl.program_id(1)
+    layer = layer_ref[0]
     n_stacks, rows, width = acc_scr.shape
     hb = rows // n_rows                 # heads a stack
+    block = ppb * page                  # positions a block
     # head (within its stack) that owns each lane of a stack
     lane_head = lax.broadcasted_iota(jnp.int32, (1, width), 1) // head_dim
 
-    @pl.when(p_idx == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        q = q_ref[0].astype(jnp.float32) * sm_scale        # (R, H*D)
-        for j in range(n_stacks):
-            qj = q[:, j * width:(j + 1) * width]
-            for h in range(hb):
-                qbd_scr[j, h * n_rows:(h + 1) * n_rows, :] = jnp.where(
-                    lane_head == h, qj, 0.0)
+    def block_dma(s, b, buf, start):
+        """Start, or wait for, the copies of block ``b`` of slot ``s``
+        into buffer ``buf``.  Only LIVE pages move, and both sides
+        walk the same range, so every started copy is waited for
+        exactly once."""
+        first = b * ppb
 
-    # the widest row bounds whether this page matters at all — taken
-    # over ALL rows, so the contract holds for arbitrary (not just
-    # ascending) per-row lengths
+        def _page(entry, carry):
+            # a wait only needs the copy's shape: no table read
+            pid = pt_ref[s * pps + entry] if start else 0
+            for hbm, vmem in pools:
+                copy = pltpu.make_async_copy(
+                    hbm.at[layer, pid], vmem.at[buf, entry - first],
+                    sem.at[buf])
+                copy.start() if start else copy.wait()
+            return carry
+
+        n_live = pl.cdiv(slot_len_ref[s], page)
+        lax.fori_loop(first, jnp.minimum(first + ppb, n_live), _page, 0)
+
+    @pl.when(s_idx == 0)
+    def _first():
+        cur[0] = 0
+        block_dma(0, 0, 0, start=True)
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    q = q_ref[0].astype(jnp.float32) * sm_scale            # (R, H*D)
+    for j in range(n_stacks):
+        qj = q[:, j * width:(j + 1) * width]
+        for h in range(hb):
+            qbd_scr[j, h * n_rows:(h + 1) * n_rows, :] = jnp.where(
+                lane_head == h, qj, 0.0)
+
     lens = [len_ref[s_idx * n_rows + r] for r in range(n_rows)]
-    max_len = functools.reduce(jnp.maximum, lens)
+    max_len = slot_len_ref[s_idx]
+    # every slot walks at least one block, so the hand-over of the
+    # prefetch below never skips a slot; a dead slot's one block moves
+    # no page and masks every position
+    n_blocks = jnp.maximum(pl.cdiv(max_len, block), 1)
+    # stacked row h*R + r carries query row r: its causal length
+    query_row = lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % n_rows
+    row_len = jnp.zeros((rows, 1), jnp.int32) + lens[0]
+    for r in range(1, n_rows):
+        row_len = jnp.where(query_row == r, lens[r], row_len)
 
-    @pl.when(p_idx * page < max_len)
-    def _compute():
-        # stacked row h*R + r carries query row r: its causal length
-        query_row = lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % n_rows
-        row_len = jnp.zeros((rows, 1), jnp.int32) + lens[0]
-        for r in range(1, n_rows):
-            row_len = jnp.where(query_row == r, lens[r], row_len)
-        pos = p_idx * page + lax.broadcasted_iota(
-            jnp.int32, (rows, page), 1)
-        live = pos < row_len                               # (rows, page)
+    def _block(b, carry):
+        buf = cur[0]
+        # the next block (this slot's, or the NEXT slot's first) is in
+        # flight while this one is computed
+        last = b + 1 == n_blocks
+        nxt_s = jnp.where(last, s_idx + 1, s_idx)
+        nxt_b = jnp.where(last, 0, b + 1)
+        pl.when(nxt_s < n_slots)(
+            lambda: block_dma(nxt_s, nxt_b, 1 - buf, start=True))
+        block_dma(s_idx, b, buf, start=False)
+        cur[0] = 1 - buf
+
+        pos = b * block + lax.broadcasted_iota(jnp.int32, (rows, block), 1)
+        live = pos < row_len                               # (rows, block)
+        # a dead page of a live block was never copied: whatever the
+        # buffer held there (NaN included) must not reach ``p @ v``
+        # through ``0 * v``, so V is zeroed by position (K's scores
+        # are replaced by the mask)
+        v_live = b * block + lax.broadcasted_iota(
+            jnp.int32, (block, 1), 0) < max_len
         for j in range(n_stacks):
             lanes = slice(j * width, (j + 1) * width)
-            k = k_ref[:, lanes].astype(jnp.float32)        # (page, width)
-            v = v_ref[:, lanes].astype(jnp.float32)
-            if ks_ref is not None:  # dequant-fused: int8 tile * VMEM scale
-                k = k * _scale_lanes(ks_ref, j, hb, lane_head)
-                v = v * _scale_lanes(vs_ref, j, hb, lane_head)
-            # every stacked head's scores in one matmul: (rows, page)
+            k = k_buf[buf, :, :, lanes].astype(jnp.float32) \
+                .reshape(block, width)
+            v = v_buf[buf, :, :, lanes].astype(jnp.float32) \
+                .reshape(block, width)
+            if quantized:  # dequant-fused: int8 tile * VMEM scale
+                at = pl.ds(pl.multiple_of(b * block, block), block)
+                k = k * _scale_lanes(ks_ref, at, j, hb, lane_head)
+                v = v * _scale_lanes(vs_ref, at, j, hb, lane_head)
+            v = jnp.where(v_live, v, 0.0)
+            # every stacked head's scores in one matmul: (rows, block)
             s = lax.dot_general(
                 qbd_scr[j], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -229,7 +319,7 @@ def _chunk_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
             m_new = jnp.maximum(m_prev,
                                 jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)                         # (rows, page)
+            p = jnp.exp(s - m_new)                         # (rows, block)
             l_new = alpha * l_scr[j, :, :1] \
                 + jnp.sum(p, axis=1, keepdims=True)
             # p @ v at full width: head h's context is in ITS lanes of
@@ -239,32 +329,37 @@ def _chunk_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
                 preferred_element_type=jnp.float32)        # (rows, width)
             m_scr[j] = jnp.broadcast_to(m_new, m_scr.shape[1:])
             l_scr[j] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+        return carry
 
-    @pl.when(p_idx == n_pages - 1)
-    def _flush():
-        for j in range(n_stacks):
-            out = jnp.zeros((n_rows, width), jnp.float32)
-            for h in range(hb):
-                blk = slice(h * n_rows, (h + 1) * n_rows)
-                l = l_scr[j, blk, :1]
-                out = jnp.where(
-                    lane_head == h,
-                    acc_scr[j, blk, :] / jnp.where(l == 0.0, 1.0, l), out)
-            o_ref[0, :, j * width:(j + 1) * width] = out.astype(o_ref.dtype)
+    lax.fori_loop(0, n_blocks, _block, 0)
+
+    for j in range(n_stacks):
+        out = jnp.zeros((n_rows, width), jnp.float32)
+        for h in range(hb):
+            blk = slice(h * n_rows, (h + 1) * n_rows)
+            l = l_scr[j, blk, :1]
+            out = jnp.where(
+                lane_head == h,
+                acc_scr[j, blk, :] / jnp.where(l == 0.0, 1.0, l), out)
+        o_ref[0, :, j * width:(j + 1) * width] = out.astype(o_ref.dtype)
 
 
-def _scale_lanes(scale_ref, stack, hb, lane_head):
-    """(page, hb*D) dequant factors for one stack: each head's
+def _scale_lanes(scale_ref, at, stack, hb, lane_head):
+    """(block, hb*D) dequant factors for one stack: each head's
     per-position scale column spread over that head's lanes."""
-    sc = scale_ref[:, stack * hb:(stack + 1) * hb].astype(jnp.float32)
+    sc = scale_ref[0, at, stack * hb:(stack + 1) * hb].astype(jnp.float32)
     out = jnp.zeros((sc.shape[0], lane_head.shape[1]), jnp.float32)
     for h in range(hb):
         out = jnp.where(lane_head == h, sc[:, h:h + 1], out)
     return out
 
 
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
 def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
-                sm_scale, interpret, k_scales=None, v_scales=None):
+                k_scales=None, v_scales=None, *, sm_scale, interpret):
+    """The ``pallas_call``.  ``layer`` is an OPERAND (int32 scalar), so
+    a model's layers share one traced and lowered kernel: a program
+    pays for the body once, not once a layer."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -276,47 +371,60 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
             f"pool rows are {hd} lanes wide but q has {h} heads of {d}")
     hb = _stack_heads(h, d, n_rows)
     n_stacks, rows, width = h // hb, hb * n_rows, hb * d
-    layer = int(layer)
-    flat_table = page_table.reshape(-1).astype(jnp.int32)
-    flat_lengths = row_lengths.reshape(-1).astype(jnp.int32)
+    ppb = pages_per_block(page, pps, hd, k_pages.dtype.itemsize)
     quantized = k_scales is not None
+    row_lengths = row_lengths.astype(jnp.int32)
+    prefetch = [layer.reshape(1), page_table.reshape(-1).astype(jnp.int32),
+                row_lengths.reshape(-1), row_lengths.max(axis=1)]
 
-    # the stacked pool is the operand; layer and page id are block
-    # indices, so one page of one layer is all that ever moves
-    def page_spec(lanes):
-        return pl.BlockSpec(
-            (None, None, page, lanes),
-            lambda s, p, pt, ln: (layer, pt[s * pps + p], 0, 0))
+    def slot_block(*shape):
+        return pl.BlockSpec((1, *shape), lambda s, *_: (s, 0, 0))
 
-    row_spec = pl.BlockSpec((1, n_rows, hd),
-                            lambda s, p, pt, ln: (s, 0, 0))
-    in_specs = [row_spec, page_spec(hd), page_spec(hd)]
+    # the stacked pools stay in HBM; the kernel copies the live pages
+    # of one layer itself, so nothing else of a pool ever moves
+    pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [slot_block(n_rows, hd), pool_spec, pool_spec]
     operands = [q.reshape(n_slots, n_rows, hd), k_pages, v_pages]
     if quantized:
-        in_specs += [page_spec(h), page_spec(h)]
-        operands += [k_scales, v_scales]
+        # the chip's compiler cuts no page out of a plane H lanes wide
+        # in HBM, so a slot's scales are gathered by its table out here
+        # (1/D of the pages' bytes) and ride in as one block a slot,
+        # padded to whole blocks of positions
+        table = jnp.pad(page_table, ((0, 0), (0, -pps % ppb)))
+        positions = table.shape[1] * page
+        in_specs += [slot_block(positions, h)] * 2
+        operands += [sc[layer, table].reshape(n_slots, positions, h)
+                     for sc in (k_scales, v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # (flat page table, flat row lengths)
-        grid=(n_slots, pps),
+        # (layer, flat page table, flat row lengths, widest row a slot)
+        num_scalar_prefetch=len(prefetch),
+        grid=(n_slots,),
         in_specs=in_specs,
-        out_specs=row_spec,
+        out_specs=slot_block(n_rows, hd),
         scratch_shapes=[
             pltpu.VMEM((n_stacks, rows, width), jnp.float32),   # query
             pltpu.VMEM((n_stacks, rows, _LANES), jnp.float32),  # max
             pltpu.VMEM((n_stacks, rows, _LANES), jnp.float32),  # denom
             pltpu.VMEM((n_stacks, rows, width), jnp.float32),   # acc
+            pltpu.VMEM((2, ppb, page, hd), k_pages.dtype),      # K blocks
+            pltpu.VMEM((2, ppb, page, hd), v_pages.dtype),      # V blocks
+            pltpu.SemaphoreType.DMA((2,)),     # one a buffer, K and V
+            pltpu.SMEM((1,), jnp.int32),       # the buffer computed next
         ],
     )
-    kern = functools.partial(_chunk_kernel, sm_scale=sm_scale,
-                             page=page, n_pages=pps, n_rows=n_rows,
-                             head_dim=d, quantized=quantized)
+    kern = functools.partial(_chunk_kernel, sm_scale=sm_scale, page=page,
+                             pps=pps, ppb=ppb, n_slots=n_slots,
+                             n_rows=n_rows, head_dim=d, quantized=quantized)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_slots, n_rows, hd), q.dtype),
+        # a slot hands its successor's first block over in flight
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=KERNEL_NAME,
-    )(flat_table, flat_lengths, *operands)
+    )(*prefetch, *operands)
     return out.reshape(q.shape)
 
 
@@ -346,9 +454,9 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
         use_pallas = "always" if jax.default_backend() == "tpu" \
             else "never"
     if use_pallas == "always":
-        return _chunk_call(q, k_pages, v_pages, layer, page_table,
-                           row_lengths, float(sm_scale), interpret,
-                           k_scales=k_scales, v_scales=v_scales)
+        return _chunk_call(q, k_pages, v_pages, jnp.int32(layer),
+                           page_table, row_lengths, k_scales, v_scales,
+                           sm_scale=float(sm_scale), interpret=interpret)
     s, r, h = q.shape[:3]
     k = _gather_dequant(k_pages, k_scales, layer, page_table, h)
     v = _gather_dequant(v_pages, v_scales, layer, page_table, h)

@@ -57,7 +57,8 @@ latency to every new arrival.  This engine is the TPU-native fix:
 
 Attention reads the page pool through
 ``ops/pallas_decode_attention.py``: the Pallas kernels on TPU (page
-table as scalar-prefetch operands — one page DMA per grid step), the
+table as scalar-prefetch operands — a slot's live pages copied a block
+of them at a time), the
 pure-jnp gather+mask reference on CPU so tier-1 stays green.  Every
 path — prefill, chunked prefill, decode, speculative verify — shares
 ONE masked-softmax formulation at one width, which is what makes
@@ -681,6 +682,17 @@ class DecodeEngine:
         stat_set("decode_kv_lane_dense",
                  1 if self._cache.config.lane_dense else 0)
         stat_set("decode_kv_pool_row_lanes", self._cache.config.row_lanes)
+        # positions one block of the paged-attention kernel covers at
+        # this shape (the op reads the same rule from the same shapes),
+        # and the blocks of all the slots' tables
+        from ..ops.pallas_decode_attention import pages_per_block
+
+        cc = self._cache.config
+        self._attn_block = cc.page_size * pages_per_block(
+            cc.page_size, cc.pages_per_slot, cc.row_lanes,
+            cc.store_dtype.itemsize)
+        self._attn_table_blocks = cc.num_slots * -(
+            -cc.max_seq_len // self._attn_block)
         # per-request timeline hook: claim/CoW/register/evict events
         # from the cache land on the owning request's trace
         self._cache.on_event = self._on_cache_event
@@ -1991,6 +2003,13 @@ class DecodeEngine:
             top_k[i] = st.req.top_k
             top_p[i] = st.req.top_p
             base_keys[i] = np.asarray(st.base_key)
+        if live_idx:
+            # how much of the table the kernel's walk is this step, a
+            # layer: the blocks holding a position any slot attends (a
+            # dead slot's one included) against every block there is
+            stat_add("decode_attn_blocks_live",
+                     int((positions // self._attn_block + 1).sum()))
+            stat_add("decode_attn_blocks_walked", self._attn_table_blocks)
         return (self.weights, up(tokens), up(positions), up(live),
                 up(self._cache.page_table), up(write_page),
                 up(write_off), up(base_keys), up(counters), up(temp),

@@ -183,6 +183,144 @@ def test_paged_kernel_reads_heads_out_of_lanes(h, d, rows, quantized):
     assert np.isfinite(np.asarray(pal)).all()
 
 
+def block_edge_case(rows, pool, pps=64, h=4, d=32, seed=0):
+    """The paged kernel's inputs at its block's edges, and what it must
+    return.  One batch: lengths 1, a page, a page + 1, a block - 1, a
+    block, a block + 1, 448 and the full table (as far as ``pps``
+    reaches), and a slot whose every row attends nothing.  Row r of a
+    slot is causal: the last row attends the slot's length, the ones
+    before it one position fewer each.  Every DEAD table entry names
+    page 0, which is poisoned: NaN through K and V (through the scale
+    planes of int8 pools), the way an uninitialised or trash page may
+    read.  Returns (args, kwargs, want, live): the op's operands, the
+    reference on the unpoisoned pools and which rows attend
+    anything.  ``chip_smoke``-style callers run it on the chip too."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_decode_attention import paged_chunk_attention
+    from paddle_tpu.serving.kv_cache import quantize_kv
+
+    rs = np.random.RandomState(seed + rows)
+    layers, layer, page = 2, 1, 16
+    width = pps * page
+    base = np.array([n for n in (1, 16, 17, 127, 128, 129, 448, width)
+                     if n <= width] + [0], "i4")
+    s = len(base)
+    row_lengths = np.maximum(
+        base[:, None] - np.arange(rows - 1, -1, -1, dtype="i4"), 0)
+    n_pages = -(-base // page)
+    n_pool = 1 + int(n_pages.sum())
+    table = np.zeros((s, pps), "i4")                  # dead: page 0
+    ids = rs.permutation(np.arange(1, n_pool))
+    at = 0
+    for i, n in enumerate(n_pages):
+        table[i, :n] = ids[at:at + n]
+        at += n
+    q = jnp.asarray(rs.randn(s, rows, h, d).astype("f4"))
+    kv = [rs.randn(layers, n_pool, page, h, d).astype("f4")
+          for _ in range(2)]
+    clean, poisoned = [], []
+    for x in kv:
+        scale = None
+        x = jnp.asarray(x)
+        if pool == "int8":
+            x, scale = quantize_kv(x)
+        else:
+            x = x.astype(pool)
+        x = x.reshape(layers, n_pool, page, h * d)
+        clean.append((x, scale))
+        if scale is None:
+            poisoned.append((x.at[:, 0].set(jnp.nan), None))
+        else:
+            poisoned.append((x, scale.at[:, 0].set(jnp.nan)))
+
+    def operands(pools):
+        (k, ks), (v, vs) = pools
+        return ((q, k, v, jnp.asarray(table), jnp.asarray(row_lengths)),
+                dict(layer=layer, k_scales=ks, v_scales=vs))
+
+    args, kwargs = operands(clean)
+    want = paged_chunk_attention(*args, use_pallas="never", **kwargs)
+    args, kwargs = operands(poisoned)
+    return args, kwargs, np.asarray(want), row_lengths > 0
+
+
+@pytest.mark.parametrize("pool", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("rows", [1, 5, 16])
+def test_paged_kernel_at_its_blocks_edges(rows, pool):
+    """Eight pages a block: lengths on both sides of a page's and a
+    block's edge, the longest request and the full table in one batch,
+    a slot of nothing, and dead table entries that name a NaN page —
+    the outputs are finite and the reference's."""
+    from paddle_tpu.ops.pallas_decode_attention import paged_chunk_attention
+
+    args, kwargs, want, live = block_edge_case(rows, pool)
+    got = np.asarray(paged_chunk_attention(
+        *args, use_pallas="always", interpret=True, **kwargs))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+
+
+def test_paged_kernel_ragged_last_block_and_nan_scratch():
+    """A table of 12 entries is a block of 8 and a block of 4: the
+    entries the last block lacks are dead like any other.  Run under
+    the TPU interpreter, whose fresh buffers read NaN, so a page that
+    was never copied shows if it reaches the output."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from paddle_tpu.ops.pallas_decode_attention import (
+        pages_per_block, paged_chunk_attention)
+
+    args, kwargs, want, live = block_edge_case(5, "float32", pps=12)
+    assert pages_per_block(16, 12, 128, 4) == 8
+    got = np.asarray(paged_chunk_attention(
+        *args, use_pallas="always",
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan"),
+        **kwargs))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("page,pps,lanes,itemsize,want", [
+    (16, 64, 1024, 4, 8),     # GPT-2-medium f32: a full lane tile of scores
+    (16, 64, 2048, 2, 8),     # 16 x 128 heads in bf16
+    (128, 8, 768, 4, 1),      # a page IS a block
+    (16, 12, 1024, 4, 8),     # ppb need not divide pps
+    (16, 4, 1024, 4, 4),      # never more than the table holds
+    (16, 64, 8192, 4, 2),     # VMEM-bound: 2 buffers x (K + V) <= 4 MiB
+    (16, 64, 32768, 4, 1),    # ... and never less than one page
+], ids=["gpt2_medium", "bf16_2048", "page128", "ragged", "short_table",
+        "vmem_bound", "vmem_floor"])
+def test_pages_per_block_is_read_from_the_shapes(page, pps, lanes, itemsize,
+                                                 want):
+    from paddle_tpu.ops.pallas_decode_attention import pages_per_block
+
+    assert pages_per_block(page, pps, lanes, itemsize) == want
+
+
+def test_engine_counts_the_attention_blocks_a_step_meets(model_and_weights):
+    """``decode_attn_blocks_live`` / ``_walked``: once a joint step, a
+    layer's blocks that hold an attended position (a dead slot's one
+    included) against every block of every slot's table — here blocks
+    of 8 pages of 16, two a slot, and a request that grows across the
+    first block's edge while the other slot stays dead."""
+    eng = make_engine(model_and_weights, max_seq_len=256, page_size=16,
+                      max_new_tokens=16).start()
+    names = ("decode_attn_blocks_live", "decode_attn_blocks_walked",
+             "decode_steps")
+    before = [stat_get(n) for n in names]
+    try:
+        assert len(eng.generate(list(range(1, 41)) * 3,
+                                max_new_tokens=16)) == 16
+    finally:
+        eng.stop()
+    live, walked, steps = (stat_get(n) - b for n, b in zip(names, before))
+    assert steps == 15                 # the first token is the prefill's
+    assert walked == steps * 2 * 2
+    # the step at position p attends p + 1: 120 .. 134 cross 128
+    assert live == sum(p // 128 + 1 for p in range(120, 135)) + steps
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
 def test_folded_pool_holds_the_bytes_of_the_heads_major_pool(quantized):
     """After the same token and prompt writes, the lane-folded pool

@@ -70,15 +70,14 @@ SLOTS, PPS = 8, 64                      # one replica's slot batch
 LAYERS = 2                              # the pools are stacked
 
 
-def _paged_shapes(h, d, page, rows, quantized):
+def _paged_shapes(h, d, page, rows, quantized, dtype=jnp.float32):
     """(q, k_pages, v_pages, page_table, lengths[, k_scales, v_scales])
     for the paged kernels, the pools stacked and lane-folded as the
     cache stores them; ``rows=0`` is the one-token decode shape."""
     pool = SLOTS * PPS
     q = (SLOTS, rows, h, d) if rows else (SLOTS, h, d)
     ln = (SLOTS, rows) if rows else (SLOTS,)
-    kv = ((LAYERS, pool, page, h * d),
-          jnp.int8 if quantized else jnp.float32)
+    kv = ((LAYERS, pool, page, h * d), jnp.int8 if quantized else dtype)
     out = [(q, jnp.float32), kv, kv, ((SLOTS, PPS), jnp.int32),
            (ln, jnp.int32)]
     if quantized:
@@ -112,6 +111,18 @@ def test_paged_chunk_attention_compiles(one_chip, h, d, rows, quantized):
     text = _compile(one_chip,
                     functools.partial(_paged, pda.paged_chunk_attention),
                     *_paged_shapes(h, d, 16, rows, quantized))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", [0, 16], ids=["decode", "chunk16"])
+def test_paged_attention_compiles_at_2048_bf16_lanes(one_chip, rows):
+    """16 heads of 128 in bfloat16 (an OLMoE-shaped row, 2,048 lanes):
+    eight pages a block are 2 MB of block buffers, as at GPT-2's f32
+    rows."""
+    assert pda.pages_per_block(16, PPS, 16 * 128, 2) == 8
+    op = pda.paged_chunk_attention if rows else pda.paged_decode_attention
+    text = _compile(one_chip, functools.partial(_paged, op),
+                    *_paged_shapes(16, 128, 16, rows, False, jnp.bfloat16))
     assert "tpu_custom_call" in text
 
 
@@ -223,6 +234,34 @@ def test_gpt2_width_decode_step_compiles(one_chip, kv_quant):
                for ln in calls), calls[0][:200]
     assert f"jit(step)/decode_step/{KERNEL_NAME}/" in lowered.as_text(
         debug_info=True)
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+def test_gpt2_width_step_walks_blocks_of_eight_pages():
+    """The benchmark's step (32 slots, 16 x 64 heads, f32 pools of
+    16-token pages): every layer's kernel runs one grid step a SLOT and
+    holds two buffers of EIGHT pages a pool, so a silent fall-back to a
+    page a block fails here.  Traced, not compiled: needs no chip."""
+    from paddle_tpu.serving.decode import _Uploads
+
+    eng = _gpt2_width_engine(16, False, vocab_size=512, slots=32)
+    args = (tuple(eng._scope.get_var(n) for n in eng._state_vars),
+            *eng._step_args((), _Uploads()))
+    calls = list(_pallas_calls(eng._step_fn.trace(*args).jaxpr.jaxpr))
+    assert len(calls) == eng.model.num_layers
+    for eqn in calls:
+        grid = eqn.params["grid_mapping"]
+        assert grid.grid == (32,)
+        buffers = [a.shape for a in grid.scratch_avals
+                   if len(a.shape) == 4]
+        assert buffers == [(2, 8, 16, 1024)] * 2, buffers
 
 
 # -- the K/V pools keep ONE layout through every serving program ----------
