@@ -220,23 +220,23 @@ def train_phase(name, program, feed, batch):
 
 
 def reference_forward(model, weights, tokens, n_live):
-    """The model's plain jax.numpy forward over the WHOLE sequence: no
-    cache, no pages, no kernel - causal softmax attention written out.
-    ``tokens`` is padded to a fixed length; returns position
-    ``n_live-1``'s logits."""
+    """The model's own forward over the WHOLE sequence with a plain
+    jax.numpy ``attend``: no cache, no pages, no kernel - causal
+    softmax attention written out.  ``tokens`` is padded to a fixed
+    length; returns position ``n_live-1``'s logits."""
     import jax
     import jax.numpy as jnp
 
     t = tokens.shape[0]
-    x = model._embed(weights, tokens, jnp.arange(t))
     causal = jnp.tril(jnp.ones((t, t), bool))
-    for lw in weights["layers"]:
-        q, k, v = model._qkv(lw, model._ln(x, lw["ln1_g"], lw["ln1_b"]))
+
+    def attend(_layer, q, k, v, cache):
         s = jnp.einsum("qhd,khd->hqk", q, k) / np.sqrt(model.head_dim)
         p = jax.nn.softmax(jnp.where(causal[None], s, -1e30), axis=-1)
-        x = x + model._attn_out(lw, jnp.einsum("hqk,khd->qhd", p, v))
-        x = x + model._mlp(lw, model._ln(x, lw["ln2_g"], lw["ln2_b"]))
-    return model._head(weights, x[n_live - 1])
+        return jnp.einsum("hqk,khd->qhd", p, v), cache
+
+    logits, _ = model.forward(weights, tokens, jnp.arange(t), None, attend)
+    return logits[n_live - 1]
 
 
 def reference_greedy(model, weights, prompts, n_new):

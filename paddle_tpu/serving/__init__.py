@@ -11,8 +11,9 @@ deployment story, rebuilt TPU-native over the compile-once Predictor:
 - ``Server`` (server.py) AOT-warms every bucket at start, serves
   ``/stats`` + ``/health`` over the fleet KV HTTP server, and drains
   gracefully on stop;
-- the GENERATIVE path (decode.py + kv_cache.py): ``DecodeEngine``
-  runs autoregressive decode over a fixed slot batch with a paged,
+- the GENERATIVE path (decode.py + kv_cache.py; the served model is
+  transformer_lm.py): ``DecodeEngine`` runs autoregressive decode
+  over a fixed slot batch with a paged,
   device-resident KV cache (Pallas paged-attention kernels on TPU),
   continuous batching at step boundaries, streaming token replies,
   and deadline reaping mid-decode; prefix-cache page sharing
@@ -40,6 +41,8 @@ from .decode import (  # noqa: F401
     DecodeEngine,
     DecodeRequest,
     TransformerLM,
+    quantize_moe_weights,
+    shard_moe_weights,
 )
 from .disagg import (  # noqa: F401
     Autoscaler,
@@ -71,5 +74,6 @@ __all__ = [
     "QueueFullError", "RequestAbandonedError", "RequestBase",
     "RequestTooLargeError", "Server", "ServerClosedError",
     "ServingConfig", "ServingError", "TransformerLM",
-    "least_loaded_order", "prefill_bucket_grid",
+    "least_loaded_order", "prefill_bucket_grid", "quantize_moe_weights",
+    "shard_moe_weights",
 ]

@@ -85,7 +85,6 @@ carry the host arrays their builder handed to the device (``uploads``,
 from __future__ import annotations
 
 import collections
-import math
 import queue as _queue
 import threading
 import time
@@ -101,9 +100,10 @@ from .buckets import (BucketSpec, DeadlineExceededError, QueueFullError,
                       RequestTooLargeError, ServerClosedError,
                       prefill_bucket_grid, record_pad_waste)
 from . import kv_cache
-from .kv_cache import (CacheConfig, PagedKVCache, K_PAGES_VAR,
-                       V_PAGES_VAR, K_SCALES_VAR, V_SCALES_VAR)
-
+from .kv_cache import CacheConfig, PagedKVCache
+# the reference model lives beside the engine; its names stay importable here
+from .transformer_lm import (TransformerLM, quantize_moe_weights,  # noqa: F401
+                             shard_moe_weights)
 
 
 class _Uploads:
@@ -151,246 +151,15 @@ _DRAFT_VARS = (DRAFT_K_PAGES_VAR, DRAFT_V_PAGES_VAR)
 _DONE = object()  # stream sentinel
 
 
-def _split_state(state, quantized):
+def _split_state(state):
     """Persistent-state tuple -> (k_pages, v_pages, k_scales,
     v_scales); the scale pools exist only under FLAGS_decode_kv_quant."""
-    if quantized:
-        kp, vp, ks, vs = state
-        return kp, vp, ks, vs
-    kp, vp = state
-    return kp, vp, None, None
+    kp, vp, *scales = state
+    return (kp, vp, *(scales or (None, None)))
 
 
-def _join_state(kp, vp, ks, vs, quantized):
-    return (kp, vp, ks, vs) if quantized else (kp, vp)
-
-
-# ---------------------------------------------------------------------------
-# model
-
-
-class TransformerLM:
-    """A decoder-only transformer sized by constructor args — the
-    engine's reference model (bench, tests, demos).  Any model works
-    with the engine if it exposes this class's surface: ``num_layers``
-    / ``num_heads`` / ``head_dim`` / ``vocab_size`` plus the pure
-    per-row pieces below, which prefill and decode COMPOSE IDENTICALLY
-    so cached decode stays bitwise-comparable to a full recompute
-    (layer norm, QKV/out projections, MLP are all row-independent)."""
-
-    def __init__(self, vocab_size: int, d_model: int = 64,
-                 num_layers: int = 2, num_heads: int = 2,
-                 ffn_dim: Optional[int] = None, max_seq_len: int = 256,
-                 moe_experts: int = 0, moe_top_k: int = 2,
-                 moe_capacity_factor: float = 0.0, moe_mesh=None):
-        self.vocab_size = int(vocab_size)
-        self.d_model = int(d_model)
-        self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
-        if d_model % num_heads:
-            raise ValueError("d_model must divide by num_heads")
-        self.head_dim = self.d_model // self.num_heads
-        self.ffn_dim = int(ffn_dim) if ffn_dim else 4 * self.d_model
-        self.max_seq_len = int(max_seq_len)
-        # MoE FFN (ops/moe_ops.moe_ffn_ref): moe_experts > 0 replaces
-        # the dense MLP with a top-k routed expert FFN.  The default
-        # capacity factor 0.0 means DROPLESS (cap = E/K * S*K/E = S):
-        # with no drops the routed output is row-independent
-        # MATHEMATICALLY, so cached decode agrees with a prefill
-        # recompute to float tolerance — but not bitwise: the dispatch
-        # buffer's capacity tracks the row count, and XLA's reduction
-        # strategy is shape-dependent (~1 ulp).  A finite factor
-        # additionally reintroduces batch-dependent drops (fine for
-        # training, wrong for the serving oracle).
-        # ``moe_mesh`` with an 'ep' axis turns on expert-parallel
-        # decode: the stacked expert weights live P('ep', ...) and the
-        # dispatch/combine all-to-alls materialize around the FFN.
-        self.moe_experts = int(moe_experts)
-        self.moe_top_k = int(moe_top_k)
-        self.moe_capacity_factor = float(moe_capacity_factor)
-        self.moe_mesh = moe_mesh
-        if self.moe_experts:
-            if self.moe_top_k > self.moe_experts:
-                raise ValueError(
-                    f"moe_top_k={moe_top_k} exceeds "
-                    f"moe_experts={moe_experts}")
-            if moe_mesh is not None and "ep" not in getattr(
-                    moe_mesh, "axis_names", ()):
-                raise ValueError(
-                    "moe_mesh needs an 'ep' axis for expert-parallel "
-                    "decode; build one with init_parallel_env("
-                    "mesh_shape=(dp, ep), axis_names=('dp', 'ep'))")
-
-    def init_weights(self, key):
-        import jax
-        import jax.numpy as jnp
-
-        dm, f, v = self.d_model, self.ffn_dim, self.vocab_size
-        n_per_layer = 7 if self.moe_experts else 6
-        keys = jax.random.split(key, 3 + self.num_layers * n_per_layer)
-
-        def dense(k, shape, scale=None):
-            scale = scale if scale is not None else 1.0 / math.sqrt(shape[0])
-            return (jax.random.normal(k, shape) * scale).astype(jnp.float32)
-
-        w = {
-            "tok_emb": dense(keys[0], (v, dm), 0.02),
-            "pos_emb": dense(keys[1], (self.max_seq_len, dm), 0.02),
-            "lm_head": dense(keys[2], (dm, v)),
-            "lnf_g": jnp.ones((dm,), jnp.float32),
-            "lnf_b": jnp.zeros((dm,), jnp.float32),
-            "layers": [],
-        }
-        for i in range(self.num_layers):
-            k = keys[3 + i * n_per_layer: 3 + (i + 1) * n_per_layer]
-            lw = {
-                "ln1_g": jnp.ones((dm,), jnp.float32),
-                "ln1_b": jnp.zeros((dm,), jnp.float32),
-                "wq": dense(k[0], (dm, dm)),
-                "wk": dense(k[1], (dm, dm)),
-                "wv": dense(k[2], (dm, dm)),
-                "wo": dense(k[3], (dm, dm)),
-                "ln2_g": jnp.ones((dm,), jnp.float32),
-                "ln2_b": jnp.zeros((dm,), jnp.float32),
-            }
-            if self.moe_experts:
-                e = self.moe_experts
-                lw["gate"] = dense(k[4], (dm, e), 0.02)
-                lw["moe_w1"] = dense(k[5], (e, dm, f))
-                lw["moe_b1"] = jnp.zeros((e, f), jnp.float32)
-                lw["moe_w2"] = dense(k[6], (e, f, dm),
-                                     1.0 / math.sqrt(f))
-                lw["moe_b2"] = jnp.zeros((e, dm), jnp.float32)
-            else:
-                lw["w1"] = dense(k[4], (dm, f))
-                lw["w2"] = dense(k[5], (f, dm))
-            w["layers"].append(lw)
-        return w
-
-    # -- pure per-row pieces (shared verbatim by prefill and decode) ------
-    @staticmethod
-    def _ln(x, g, b):
-        import jax.numpy as jnp
-
-        mu = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-        return (x - mu) / jnp.sqrt(var + 1e-5) * g + b
-
-    def _embed(self, w, tokens, positions):
-        return w["tok_emb"][tokens] + w["pos_emb"][positions]
-
-    def _qkv(self, lw, h):
-        n, d = self.num_heads, self.head_dim
-        q = (h @ lw["wq"]).reshape(*h.shape[:-1], n, d)
-        k = (h @ lw["wk"]).reshape(*h.shape[:-1], n, d)
-        v = (h @ lw["wv"]).reshape(*h.shape[:-1], n, d)
-        return q, k, v
-
-    def _attn_out(self, lw, ctx):
-        return ctx.reshape(*ctx.shape[:-2], self.d_model) @ lw["wo"]
-
-    def _mlp(self, lw, h):
-        import jax
-
-        if self.moe_experts:
-            return self._moe_mlp(lw, h)
-        return jax.nn.gelu(h @ lw["w1"]) @ lw["w2"]
-
-    def _moe_mlp(self, lw, h):
-        """Routed expert FFN, dropless by default (see __init__).
-        Quantized expert carriers (``quantize_moe_weights``) dequantize
-        per expert at the einsum's doorstep; a ``moe_mesh`` with an
-        'ep' axis adds the GSPMD constraints that make the dispatch and
-        combine all-to-alls real."""
-        from ..ops.moe_ops import _dequant_stacked, moe_ffn_ref
-
-        if "moe_w1_q" in lw:
-            w1 = _dequant_stacked(lw["moe_w1_q"], lw["moe_w1_scale"])
-            w2 = _dequant_stacked(lw["moe_w2_q"], lw["moe_w2_scale"])
-        else:
-            w1, w2 = lw["moe_w1"], lw["moe_w2"]
-        cf = self.moe_capacity_factor or (
-            self.moe_experts / self.moe_top_k)
-        out, _aux, _load, _chunked = moe_ffn_ref(
-            h, lw["gate"], w1, lw["moe_b1"], w2, lw["moe_b2"],
-            num_experts=self.moe_experts, top_k=self.moe_top_k,
-            capacity_factor=cf, mesh=self.moe_mesh,
-            ep=self.moe_mesh is not None)
-        return out.astype(h.dtype)
-
-    def _head(self, w, x):
-        return self._ln(x, w["lnf_g"], w["lnf_b"]) @ w["lm_head"]
-
-
-def quantize_moe_weights(weights, mode: str = "int8"):
-    """Post-training quantization of a TransformerLM weight dict's
-    stacked expert tensors — the serving twin of the
-    PostTrainingWeightQuantPass moe_ffn branch (slim/quantization.py):
-    every layer's ``moe_w1``/``moe_w2`` becomes an int8 (or fp8)
-    carrier plus a per-expert ``[E, out]`` scale
-    (ops/quant_ops.quantize_weight_stacked), which ``_moe_mlp``
-    dequantizes at the expert einsum's doorstep.  Gate, biases, and
-    everything dense stay full precision (they're a rounding error of
-    the byte footprint).  Returns a NEW dict; the original is
-    untouched (it stays the full-precision oracle)."""
-    from ..ops.quant_ops import quantize_weight_stacked
-
-    out = dict(weights)
-    layers = []
-    n_quantized = 0
-    for lw in weights["layers"]:
-        lw = dict(lw)
-        if "moe_w1" in lw:
-            for nm in ("moe_w1", "moe_w2"):
-                q, s = quantize_weight_stacked(lw.pop(nm), 2, mode)
-                lw[nm + "_q"] = q
-                lw[nm + "_scale"] = s
-                n_quantized += 1
-        layers.append(lw)
-    if not n_quantized:
-        raise ValueError(
-            "quantize_moe_weights found no stacked expert weights; "
-            "build the model with moe_experts > 0")
-    out["layers"] = layers
-    stat_add("serving_moe_weights_quantized", n_quantized)
-    return out
-
-
-def shard_moe_weights(weights, mesh):
-    """Place a TransformerLM weight dict's stacked expert tensors (raw
-    or quantized carriers+scales alike) ``P('ep', ...)`` on ``mesh`` so
-    each chip holds only its 1/ep slice of the experts — the serving
-    counterpart of the ShardingPropagationPass 'ep' seed.  Everything
-    else replicates.  Returns a NEW dict of device-resident arrays."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    if "ep" not in getattr(mesh, "axis_names", ()):
-        raise ValueError(
-            "shard_moe_weights needs a mesh with an 'ep' axis; build "
-            "one with init_parallel_env(mesh_shape=(dp, ep), "
-            "axis_names=('dp', 'ep'))")
-    ep = int(mesh.shape["ep"])
-
-    def put(val, spec):
-        return jax.device_put(val, NamedSharding(mesh, spec))
-
-    rep = PartitionSpec()
-    out = {k: put(v, rep) for k, v in weights.items() if k != "layers"}
-    layers = []
-    for lw in weights["layers"]:
-        placed = {}
-        for nm, val in lw.items():
-            stacked = nm.startswith("moe_w") and val.ndim >= 2 \
-                or nm in ("moe_b1", "moe_b2")
-            if stacked and int(val.shape[0]) % ep == 0:
-                placed[nm] = put(val, PartitionSpec(
-                    "ep", *([None] * (val.ndim - 1))))
-            else:
-                placed[nm] = put(val, rep)
-        layers.append(placed)
-    out["layers"] = layers
-    return out
+def _join_state(pools):
+    return tuple(p for p in pools if p is not None)
 
 
 # ---------------------------------------------------------------------------
@@ -619,6 +388,19 @@ class DecodeEngine:
     one-shot group mode (a new group only starts when EVERY slot is
     free) — the static-batching baseline bench.py's A/B uses.
 
+    What the engine reads off ``model`` is the whole contract
+    (``serving/transformer_lm.py`` is the reference): ``num_layers``,
+    ``num_heads``, ``head_dim``, ``vocab_size``, ``max_seq_len`` and
+    ``forward(weights, tokens, positions, cache, attend) -> (logits,
+    cache)`` for any leading shape of ``tokens``, which calls
+    ``attend(layer, q, k, v, cache) -> (ctx, cache)`` once a layer, in
+    order, with ``[..., H, D]`` rows.  ``weights`` is the caller's
+    pytree (``init_weights`` is for callers).  The engine supplies
+    ``attend`` — where K/V reach the pools and what is attended: one
+    token a slot, a whole prompt, R rows a slot — and nothing else
+    about the model; bitwise parity of cached decode with a recompute
+    needs a ``forward`` whose other operations are row-independent.
+
     ``draft_model``/``draft_weights`` arm speculative decoding (with
     ``spec_k > 0``): the draft's page pools are indexed by the SAME
     page ids as the target's, so prefix sharing, reservation
@@ -803,59 +585,60 @@ class DecodeEngine:
                       **attrs)
 
     # -- jitted step builders --------------------------------------------
-    def _attend(self, q, k_pages, v_pages, k_scales, v_scales, layer,
-                page_table, lengths):
+    def _paged_attend(self, attention, page_table, lengths, write_page,
+                      write_off):
+        """The ``attend`` of the programs that extend sequences THROUGH
+        the pools: a layer's K/V rows written at explicit (page, offset)
+        coords, then each query row attending its slot's page table up
+        to its own length.  ``attention``: ``paged_decode_attention``
+        for ``[S]`` rows (the token step), ``paged_chunk_attention`` for
+        ``[S, R]`` (the multi-row step).  Quantized pools (scales not
+        None) write int8 + scales; attention dequantizes inline."""
         import jax
 
-        from ..ops.pallas_decode_attention import (KERNEL_NAME,
-                                                   paged_decode_attention)
+        from ..ops.pallas_decode_attention import KERNEL_NAME
 
-        # all backend dispatch (auto/always/never, Pallas vs the
-        # gather+mask reference) lives in ONE place: the op itself —
-        # including the quantized dequant-inline paths.  The scope is
-        # metadata only: it names the call's device ops in a trace
-        with jax.named_scope(KERNEL_NAME):
-            return paged_decode_attention(
-                q, k_pages, v_pages, page_table, lengths, layer=layer,
-                use_pallas=self.config.use_pallas,
-                interpret=self.config.interpret,
-                k_scales=k_scales, v_scales=v_scales)
-
-    def _token_step_body(self, model, weights, k_pages, v_pages,
-                         k_scales, v_scales, tokens, positions,
-                         page_table, write_page, write_off):
-        """One single-token step of ``model`` over the page pools:
-        embed -> per-layer (write K/V at (write_page, write_off),
-        attend over the slot's live history) -> logits.  Shared
-        VERBATIM by the target step and the draft proposal burst so
-        both read the cache through the one formulation.  Quantized
-        pools (scales not None) write int8 + per-position scales and
-        attention dequantizes inline."""
-        x = model._embed(weights, tokens, positions)       # [S, Dm]
-        lengths = positions + 1  # the token written THIS step included
-        for l in range(model.num_layers):
-            lw = weights["layers"][l]
-            h = model._ln(x, lw["ln1_g"], lw["ln1_b"])
-            q, k, v = model._qkv(lw, h)                    # [S, H, D]
+        def attend(l, q, k, v, pools):
+            k_pages, v_pages, k_scales, v_scales = pools
+            flat = (-1,) + k.shape[-2:]                     # [rows, H, D]
             k_pages, k_scales = kv_cache.write_token_layer(
-                k_pages, k_scales, l, k, write_page, write_off)
+                k_pages, k_scales, l, k.reshape(flat),
+                write_page.reshape(-1), write_off.reshape(-1))
             v_pages, v_scales = kv_cache.write_token_layer(
-                v_pages, v_scales, l, v, write_page, write_off)
-            ctx = self._attend(q, k_pages, v_pages, k_scales, v_scales,
-                               l, page_table, lengths)
-            x = x + model._attn_out(lw, ctx)
-            x = x + model._mlp(
-                lw, model._ln(x, lw["ln2_g"], lw["ln2_b"]))
-        logits = model._head(weights, x)                   # [S, V]
-        return logits, k_pages, v_pages, k_scales, v_scales
+                v_pages, v_scales, l, v.reshape(flat),
+                write_page.reshape(-1), write_off.reshape(-1))
+            # all backend dispatch (auto/always/never, Pallas vs the
+            # gather+mask reference) lives in ONE place: the op itself —
+            # including the quantized dequant-inline paths.  The scope
+            # is metadata only: it names the call's device ops in a trace
+            with jax.named_scope(KERNEL_NAME):
+                ctx = attention(
+                    q, k_pages, v_pages, page_table, lengths, layer=l,
+                    use_pallas=self.config.use_pallas,
+                    interpret=self.config.interpret,
+                    k_scales=k_scales, v_scales=v_scales)
+            return ctx, (k_pages, v_pages, k_scales, v_scales)
+
+        return attend
+
+    def _token_step_body(self, model, weights, pools, tokens, positions,
+                         page_table, write_page, write_off):
+        """One single-token step of ``model`` over ``pools``, the token
+        written THIS step attended with the slot's history.  Shared
+        VERBATIM by the target step and the draft proposal burst so both
+        read the cache through one formulation.  -> (logits, pools)."""
+        from ..ops.pallas_decode_attention import paged_decode_attention
+
+        return model.forward(
+            weights, tokens, positions, pools, self._paged_attend(
+                paged_decode_attention, page_table, positions + 1,
+                write_page, write_off))
 
     def _build_step_fn(self, model):
         import jax
         import jax.numpy as jnp
 
         from ..ops.sampling_ops import sample_tokens
-
-        qz = self.config.kv_quant
 
         # the scopes on the jitted bodies are metadata only: a trace's
         # device ops read "jit(step)/decode_step/...".  The functions
@@ -864,19 +647,17 @@ class DecodeEngine:
         def step(state, weights, tokens, positions, live, page_table,
                  write_page, write_off, base_keys, counters, temp, top_k,
                  top_p):
-            kp, vp, ks, vs = _split_state(state, qz)
-            logits, kp, vp, ks, vs = self._token_step_body(
-                model, weights, kp, vp, ks, vs, tokens, positions,
-                page_table, write_page, write_off)
+            logits, pools = self._token_step_body(
+                model, weights, _split_state(state), tokens,
+                positions, page_table, write_page, write_off)
             keys = jax.vmap(jax.random.fold_in)(base_keys, counters)
             nxt = sample_tokens(keys, logits, temp, top_k, top_p)
             nxt = jnp.where(live, nxt, 0)
-            return (nxt, logits), _join_state(kp, vp, ks, vs, qz)
+            return (nxt, logits), _join_state(pools)
 
         return jax.jit(step, donate_argnums=(0,))
 
-    def _build_prefill_fn(self, t_pad: int, model,
-                          quantized: Optional[bool] = None):
+    def _build_prefill_fn(self, t_pad: int, model, qz: bool):
         import jax
         import jax.numpy as jnp
 
@@ -888,19 +669,16 @@ class DecodeEngine:
         t_max = cc.max_seq_len
         n_bp = t_pad // cc.page_size
         cdt = cc.dtype
-        qz = cc.quantized if quantized is None else bool(quantized)
 
         @jax.named_scope("prefill_full")
         def prefill(state, weights, tokens, length, pages, base_key,
                     temp, top_k, top_p):
-            k_pages, v_pages, k_scales, v_scales = _split_state(state, qz)
             positions = jnp.arange(t_pad, dtype=jnp.int32)
-            x = model._embed(weights, tokens, positions)    # [T_pad, Dm]
             row_lengths = positions + 1
-            for l in range(model.num_layers):
-                lw = weights["layers"][l]
-                h = model._ln(x, lw["ln1_g"], lw["ln1_b"])
-                q, k, v = model._qkv(lw, h)                 # [T_pad, H, D]
+            shape = (t_max, model.num_heads, model.head_dim)
+
+            def attend(l, q, k, v, pools):                  # [T_pad, H, D]
+                k_pages, v_pages, k_scales, v_scales = pools
                 k_pages, k_scales = kv_cache.write_prompt_layer(
                     k_pages, k_scales, l, k, pages[:n_bp])
                 v_pages, v_scales = kv_cache.write_prompt_layer(
@@ -919,28 +697,27 @@ class DecodeEngine:
                     vl = kv_cache.dequantize_kv(vq, vsc, cdt)
                 else:
                     kl, vl = k.astype(cdt), v.astype(cdt)
-                shape = (t_max, model.num_heads, model.head_dim)
                 kf = jnp.zeros(shape, cdt).at[:t_pad].set(kl)
                 vf = jnp.zeros(shape, cdt).at[:t_pad].set(vl)
                 ctx = decode_attention_reference(
                     q, jnp.broadcast_to(kf[None], (t_pad,) + shape),
                     jnp.broadcast_to(vf[None], (t_pad,) + shape),
                     row_lengths)
-                x = x + model._attn_out(lw, ctx)
-                x = x + model._mlp(
-                    lw, model._ln(x, lw["ln2_g"], lw["ln2_b"]))
-            logits = model._head(weights, x)                # [T_pad, V]
+                return ctx, (k_pages, v_pages, k_scales, v_scales)
+
+            logits, pools = model.forward(                  # [T_pad, V]
+                weights, tokens, positions, _split_state(state),
+                attend)
             last = jax.lax.dynamic_index_in_dim(
                 logits, length - 1, 0, keepdims=False)
             key0 = jax.random.fold_in(base_key, 0)
             tok = sample_tokens(key0[None], last[None], temp[None],
                                 top_k[None], top_p[None])[0]
-            return (tok, last), _join_state(k_pages, v_pages, k_scales,
-                                            v_scales, qz)
+            return (tok, last), _join_state(pools)
 
         return jax.jit(prefill, donate_argnums=(0,))
 
-    def _build_rows_fn(self, n_rows: int, n_slots: int, model):
+    def _build_rows_fn(self, n_rows: int, model):
         """Multi-row step: R query rows per slot written at explicit
         (page, offset) coords, attending over the slot's page table
         with per-row causal lengths.  ONE executable family serves
@@ -952,53 +729,29 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..ops.pallas_decode_attention import (KERNEL_NAME,
-                                                   paged_chunk_attention)
+        from ..ops.pallas_decode_attention import paged_chunk_attention
         from ..ops.sampling_ops import greedy_sample, sample_tokens
-
-        R, S = n_rows, n_slots
-        qz = self._cache.config.quantized
 
         @jax.named_scope("prefill_rows")
         def rows_fn(state, weights, tokens, start, last_row, page_table,
                     write_page, write_off, base_keys, counters, temp,
                     top_k, top_p):
-            k_pages, v_pages, k_scales, v_scales = _split_state(state, qz)
             positions = start[:, None] \
-                + jnp.arange(R, dtype=jnp.int32)[None, :]   # [S, R]
+                + jnp.arange(n_rows, dtype=jnp.int32)[None, :]  # [S, R]
             # clip keeps padded/dead rows inside the positional table;
             # live rows are in range by the reservation accounting
-            pos_c = jnp.clip(positions, 0, model.max_seq_len - 1)
-            x = model._embed(weights, tokens, pos_c)        # [S, R, Dm]
-            row_lengths = positions + 1
-            for l in range(model.num_layers):
-                lw = weights["layers"][l]
-                h = model._ln(x, lw["ln1_g"], lw["ln1_b"])
-                q, k, v = model._qkv(lw, h)                 # [S, R, H, D]
-                flat = (S * R, model.num_heads, model.head_dim)
-                k_pages, k_scales = kv_cache.write_token_layer(
-                    k_pages, k_scales, l, k.reshape(flat),
-                    write_page.reshape(-1), write_off.reshape(-1))
-                v_pages, v_scales = kv_cache.write_token_layer(
-                    v_pages, v_scales, l, v.reshape(flat),
-                    write_page.reshape(-1), write_off.reshape(-1))
-                with jax.named_scope(KERNEL_NAME):
-                    ctx = paged_chunk_attention(
-                        q, k_pages, v_pages, page_table, row_lengths,
-                        layer=l, use_pallas=self.config.use_pallas,
-                        interpret=self.config.interpret,
-                        k_scales=k_scales, v_scales=v_scales)
-                x = x + model._attn_out(lw, ctx)
-                x = x + model._mlp(
-                    lw, model._ln(x, lw["ln2_g"], lw["ln2_b"]))
-            logits = model._head(weights, x)                # [S, R, V]
+            logits, pools = model.forward(                  # [S, R, V]
+                weights, tokens,
+                jnp.clip(positions, 0, model.max_seq_len - 1),
+                _split_state(state), self._paged_attend(
+                    paged_chunk_attention, page_table, positions + 1,
+                    write_page, write_off))
             greedy = greedy_sample(logits)                  # [S, R]
             last = jnp.take_along_axis(
                 logits, last_row[:, None, None], axis=1)[:, 0]  # [S, V]
             keys = jax.vmap(jax.random.fold_in)(base_keys, counters)
             tok = sample_tokens(keys, last, temp, top_k, top_p)
-            return (tok, greedy, logits), _join_state(
-                k_pages, v_pages, k_scales, v_scales, qz)
+            return (tok, greedy, logits), _join_state(pools)
 
         return jax.jit(rows_fn, donate_argnums=(0,))
 
@@ -1017,11 +770,10 @@ class DecodeEngine:
         cc = self._cache.config
         p = cc.page_size
         pps = cc.pages_per_slot
-        qz = cc.quantized
 
         def propose(state, weights, tok0, start, live, trash_first,
                     page_table):
-            dk, dv, dks, dvs = _split_state(state, qz)
+            pools = _split_state(state)
             cur = tok0
             props = []
             for j in range(k_steps + 1):
@@ -1033,14 +785,13 @@ class DecodeEngine:
                 if j == 0:
                     pid = jnp.where(trash_first, 0, pid)
                 off = pos % p
-                logits, dk, dv, dks, dvs = self._token_step_body(
-                    model, weights, dk, dv, dks, dvs, cur,
+                logits, pools = self._token_step_body(
+                    model, weights, pools, cur,
                     jnp.clip(pos, 0, model.max_seq_len - 1),
                     page_table, pid, off)
                 cur = greedy_sample(logits)                  # [S]
                 props.append(cur)
-            return (jnp.stack(props, axis=1),), _join_state(
-                dk, dv, dks, dvs, qz)
+            return (jnp.stack(props, axis=1),), _join_state(pools)
 
         return jax.jit(propose, donate_argnums=(0,))
 
@@ -1070,7 +821,7 @@ class DecodeEngine:
         if fn is None:
             model = self.model if which == "target" else self._draft_model
             fn = self._prefill_fns[key] = self._build_prefill_fn(
-                t_pad, model, quantized=qz)
+                t_pad, model, qz)
             stat_add("decode_prefill_compiles")
         return fn
 
@@ -1079,8 +830,7 @@ class DecodeEngine:
         fn = self._rows_fns.get(key)
         if fn is None:
             model = self.model if which == "target" else self._draft_model
-            fn = self._rows_fns[key] = self._build_rows_fn(
-                n_rows, n_slots, model)
+            fn = self._rows_fns[key] = self._build_rows_fn(n_rows, model)
             stat_add("decode_prefill_compiles")
         return fn
 

@@ -390,6 +390,119 @@ def test_decode_bitwise_equals_full_recompute_every_step(
                 f"{np.abs(oracle - r.logits_trace[t]).max()})")
 
 
+# -- the seam: the engine serves whatever implements its contract ---------
+
+
+class _ParallelRmsLM:
+    """A served model that is NOT TransformerLM: an RMS-style norm with
+    no gain, ONE norm a layer feeding attention and MLP in parallel, a
+    fused QKV projection, relu.  It implements only what
+    ``DecodeEngine``'s docstring lists."""
+
+    def __init__(self, vocab_size, d_model, num_layers, num_heads,
+                 max_seq_len):
+        self.vocab_size, self.d_model = vocab_size, d_model
+        self.num_layers, self.num_heads = num_layers, num_heads
+        self.head_dim, self.max_seq_len = d_model // num_heads, max_seq_len
+
+    def init_weights(self, key):
+        import jax
+
+        d, v = self.d_model, self.vocab_size
+        keys = iter(jax.random.split(key, 3 + 4 * self.num_layers))
+
+        def w(*shape):
+            return jax.random.normal(next(keys), shape) * shape[0] ** -0.5
+
+        return {"emb": w(v, d), "pos": w(self.max_seq_len, d),
+                "head": w(d, v),
+                "blocks": [{"qkv": w(d, 3 * d), "o": w(d, d),
+                            "up": w(d, 2 * d), "down": w(2 * d, d)}
+                           for _ in range(self.num_layers)]}
+
+    @staticmethod
+    def _rms(x):
+        import jax
+        import jax.numpy as jnp
+
+        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6)
+
+    def forward(self, weights, tokens, positions, cache, attend):
+        import jax
+        import jax.numpy as jnp
+
+        x = weights["emb"][tokens] + weights["pos"][positions]
+        for l, b in enumerate(weights["blocks"]):
+            h = self._rms(x)
+            q, k, v = jnp.split((h @ b["qkv"]).reshape(
+                *h.shape[:-1], self.num_heads, 3 * self.head_dim), 3, -1)
+            ctx, cache = attend(l, q, k, v, cache)
+            x = x + ctx.reshape(x.shape) @ b["o"] \
+                + jax.nn.relu(h @ b["up"]) @ b["down"]
+        return self._rms(x) @ weights["head"], cache
+
+
+@pytest.mark.parametrize("path", ["whole_prompt", "chunked", "suffix_hit"])
+def test_engine_serves_a_model_that_only_meets_the_contract(path):
+    """Another block than TransformerLM's behind the same engine, through
+    each of its three ``attend``s (one token a slot in every decode
+    step; the whole prompt; R rows a slot for chunks and for the suffix
+    after a prefix-cache hit): greedy tokens and logits bitwise equal to
+    the engine's own full-recompute oracle."""
+    import jax
+
+    model = _ParallelRmsLM(VOCAB, 32, 2, 2, max_seq_len=64)
+    cfg = dict(slots=2, max_seq_len=64, page_size=8, max_new_tokens=8)
+    if path == "chunked":
+        cfg.update(prefill_chunk_pages=1, prefix_cache=False)
+    eng = DecodeEngine(model, model.init_weights(jax.random.PRNGKey(3)),
+                       DecodeConfig(**cfg)).start()
+    base = list(range(1, 17))  # two whole pages
+    prompt = {"whole_prompt": base + [20, 21, 22],
+              "chunked": list(range(1, 28)),       # four one-page chunks
+              "suffix_hit": base + [40, 41, 42]}[path]
+    try:
+        chunks0 = stat_get("prefill_chunks")
+        hits0 = 0
+        if path == "suffix_hit":
+            eng.generate(base + [20, 21], max_new_tokens=2)
+            chunks0 = stat_get("prefill_chunks")
+            hits0 = stat_get("decode_prefix_pages_hit")
+        r = eng.submit(prompt, max_new_tokens=5, record_logits=True)
+        out = r.result(timeout=120)
+    finally:
+        eng.stop()
+    # the path the case is named for is the one the prompt took
+    assert stat_get("prefill_chunks") - chunks0 == {
+        "whole_prompt": 0, "chunked": 4, "suffix_hit": 1}[path]
+    if path == "suffix_hit":
+        assert stat_get("decode_prefix_pages_hit") - hits0 == 2
+    assert len(out) == 5 and len(r.logits_trace) == 5
+    for t in range(len(out)):
+        oracle = eng.recompute_logits(prompt + out[:t])
+        assert np.array_equal(oracle, r.logits_trace[t]), (path, t)
+        assert out[t] == int(np.argmax(oracle))
+
+
+def test_forward_with_a_dense_causal_attend_matches_the_oracle(
+        model_and_weights):
+    """``TransformerLM.forward`` under a plain causal softmax ``attend``
+    with no cache at all (chip_smoke.py's reference, which it compares
+    with on the chip) agrees with the paged engine's oracle."""
+    import jax.numpy as jnp
+
+    from chip_smoke import reference_forward
+
+    model, weights = model_and_weights
+    eng = make_engine(model_and_weights)
+    seq = [5, 9, 2, 40, 17, 3, 3, 58, 11, 7, 1]
+    buf = np.zeros((16,), np.int32)
+    buf[:len(seq)] = seq
+    dense = reference_forward(model, weights, jnp.asarray(buf), len(seq))
+    np.testing.assert_allclose(np.asarray(dense), eng.recompute_logits(seq),
+                               rtol=1e-4, atol=1e-5)
+
+
 def test_batch_composition_invariance(model_and_weights):
     """A request's (greedy) tokens must not depend on what else is in
     the slot batch — the continuous-batching correctness property."""
