@@ -72,8 +72,10 @@ RULES = (
          "or ragged dispatch; a chunk that samples no token has no "
          "sync and ends with its dispatch)", exact=True),
     Rule("decode_step_seconds", "histogram", "serving",
-         "One batched decode step, dispatch + sync (a speculative "
-         "round: draft burst + verify)", exact=True),
+         "One batched decode step, dispatch + sync: from the hand-over "
+         "until its tokens are on the host, a whole-prompt prefill "
+         "handed over ahead of it in the same iteration included (a "
+         "speculative round: draft burst + verify)", exact=True),
     Rule("ttft_seconds", "histogram", "slo",
          "Time to first token (SLO input): submit until the prefill's "
          "sampled token has been read back and is handed to the caller, "
@@ -156,8 +158,10 @@ RULES = (
     # -- serving ---------------------------------------------------------
     Rule("decode_h2d_", "gauge", "serving",
          "Host arrays the engine's argument builders hand to the device "
-         "(`_uploads`: count, `_bytes`), counted where `_step_args` and "
-         "the prefill builders hand them over; the weights are not in it"),
+         "(`_uploads`: count, `_bytes`), counted where they are handed "
+         "over: ONE packed int32 array a joint decode step and a "
+         "whole-prompt prefill, one a field for the rows, ragged and "
+         "speculative builders; the weights are not in it"),
     Rule("decode_attn_blocks_", "gauge", "serving",
          "Blocks of page-table entries the paged-attention kernel meets "
          "a layer, added once a joint decode step from the lengths the "

@@ -73,14 +73,20 @@ Observability: ``decode_*`` counters/gauges (``decode_cache_hit_rate``,
 fleet KV HTTP server runs.  One iteration of the engine thread is a row
 of leaf spans that follow one another (``observe/tracer.py``: events of
 a running ``jax.profiler`` trace, ring-buffer records under
-``FLAGS_enable_tracer``): ``serving/lock_wait`` | ``serving/admit`` (or
-``serving/idle_wait``) | per prefill ``serving/prefill_args`` |
-``prefill_dispatch`` | ``prefill_sync`` | ``prefill_deliver`` |
-``serving/reap`` | per decode round ``serving/step_cow`` | ``step_args``
-| ``step_dispatch`` | ``step_sync`` | ``step_deliver``.  No span encloses the iteration, so a
+``FLAGS_enable_tracer``): ``serving/reap`` | per decode round
+``serving/step_cow`` | ``step_args`` | then ``serving/lock_wait`` |
+``serving/admit`` (or ``serving/idle_wait``) | per whole-prompt prefill
+``serving/prefill_args`` | ``prefill_dispatch`` | then
+``serving/step_dispatch`` | per prefill ``serving/prefill_sync`` |
+``prefill_deliver`` | then ``serving/step_sync`` | ``step_deliver`` (a
+chunked, ragged or suffix prefill runs its four phases in a row where
+the whole-prompt one is dispatched).  No span encloses the iteration, so a
 device gap is named by the phase the host was in.  The ``*_args`` spans
 carry the host arrays their builder handed to the device (``uploads``,
 ``upload_bytes``; counters ``decode_h2d_uploads`` / ``decode_h2d_bytes``).
+The joint step and the whole-prompt prefill take everything after the
+weights as ONE packed int32 array (``_words`` / ``_unpack``): one upload
+a dispatch, whatever the number of fields.
 """
 from __future__ import annotations
 
@@ -108,7 +114,9 @@ from .transformer_lm import (TransformerLM, quantize_moe_weights,  # noqa: F401
 
 class _Uploads:
     """Counts the host arrays one dispatch's argument builder hands to
-    the device, at the sites that hand them over."""
+    the device, at the sites that hand them over: one packed array for
+    the joint step and the whole-prompt prefill, one a field for the
+    rows, ragged and speculative builders."""
 
     __slots__ = ("n", "nbytes")
 
@@ -137,6 +145,61 @@ class _Uploads:
 def _rid(req):
     """The request's id, as ``submit`` minted it (spans' ``req``)."""
     return req.trace.trace_id if req.trace is not None else ""
+
+
+# -- packed arguments -------------------------------------------------------
+# What a dispatch takes after its weights travels as ONE int32 host array.
+# A numpy record dtype names the fields; every field is four bytes wide, so
+# the records' own memory viewed as int32 IS the upload (float32 and uint32
+# fields by their bit pattern, never through a cast), and the jitted body
+# slices the words back into the same fields.
+
+_SAMPLING = [("key", np.uint32, (2,)), ("temperature", np.float32),
+             ("top_k", np.int32), ("top_p", np.float32)]
+_LIVE, _TRASH = 1, 2  # bits of a step row's ``flags``
+
+
+def _step_row(pages_per_slot: int) -> np.dtype:
+    """One slot of the joint decode step; ``flags`` holds ``_LIVE`` and
+    ``_TRASH`` (``write_trash_once``: the write aims at page 0, offset
+    0), ``pages`` the slot's page-table row."""
+    return np.dtype([("token", np.int32), ("position", np.int32),
+                     ("flags", np.int32), ("counter", np.int32)]
+                    + _SAMPLING + [("pages", np.int32, (pages_per_slot,))])
+
+
+def _prefill_row(t_pad: int, pages_per_slot: int) -> np.dtype:
+    """One whole-prompt prefill at bucket ``t_pad``."""
+    return np.dtype([("tokens", np.int32, (t_pad,)), ("length", np.int32)]
+                    + _SAMPLING + [("pages", np.int32, (pages_per_slot,))])
+
+
+def _words(records: np.ndarray) -> np.ndarray:
+    """The int32 words of a record array, ``[..., words a record]``: the
+    same memory, which is what is uploaded."""
+    return records.view(np.int32).reshape(records.shape + (-1,))
+
+
+def _unpack(words, row: np.dtype) -> dict:
+    """Inside a jitted body: ``words`` (``_words`` of ``row`` records)
+    sliced back into the fields, each in its own dtype by bit pattern."""
+    from jax import lax
+
+    fields = {}
+    for name in row.names:
+        field, offset = row.fields[name][:2]
+        w = words[..., offset // 4:(offset + field.itemsize) // 4]
+        if field.base != np.int32:
+            w = lax.bitcast_convert_type(w, field.base)
+        fields[name] = w.reshape(words.shape[:-1] + field.shape)
+    return fields
+
+
+def _key_words(seed: int) -> np.ndarray:
+    """The two uint32 words of ``jax.random.PRNGKey(seed)`` (threefry
+    over jax's 32-bit integers: a zero word, then the seed's low 32
+    bits), made on the host."""
+    return np.array([0, seed & 0xFFFFFFFF], np.uint32)
 
 
 DRAFT_K_PAGES_VAR = "__decode_draft_k_pages__"
@@ -512,6 +575,7 @@ class DecodeEngine:
             self._scope.set_var(nm, self._commit(self._scope.get_var(nm)))
         self._buckets = BucketSpec(
             (1,), prefill_bucket_grid(c.max_seq_len, c.page_size))
+        self._step_row = _step_row(self._cache.config.pages_per_slot)
         self._step_fn = self._build_step_fn(model)
         self._prefill_fns = {}   # (t_pad, which, qz) -> jitted prefill
         self._rows_fns = {}      # (rows, slots, which) -> jitted multirow
@@ -643,15 +707,28 @@ class DecodeEngine:
         # the scopes on the jitted bodies are metadata only: a trace's
         # device ops read "jit(step)/decode_step/...".  The functions
         # keep their names (the programs are jit_step, jit_prefill, ...)
+        row = self._step_row
+        page_size = self._cache.config.page_size
+
         @jax.named_scope("decode_step")
-        def step(state, weights, tokens, positions, live, page_table,
-                 write_page, write_off, base_keys, counters, temp, top_k,
-                 top_p):
+        def step(state, weights, packed):
+            a = _unpack(packed, row)                        # fields [S]
+            positions, page_table = a["position"], a["pages"]
+            live = (a["flags"] & _LIVE) != 0
+            # where this step's K/V land: the table's page for the
+            # position; page 0, offset 0 (trash) for a dead slot and
+            # for a cache-hit first step
+            write = live & ((a["flags"] & _TRASH) == 0)
+            write_page = jnp.where(write, jnp.take_along_axis(
+                page_table, (positions // page_size)[:, None],
+                axis=1)[:, 0], 0)
+            write_off = jnp.where(write, positions % page_size, 0)
             logits, pools = self._token_step_body(
-                model, weights, _split_state(state), tokens,
+                model, weights, _split_state(state), a["token"],
                 positions, page_table, write_page, write_off)
-            keys = jax.vmap(jax.random.fold_in)(base_keys, counters)
-            nxt = sample_tokens(keys, logits, temp, top_k, top_p)
+            keys = jax.vmap(jax.random.fold_in)(a["key"], a["counter"])
+            nxt = sample_tokens(keys, logits, a["temperature"],
+                                a["top_k"], a["top_p"])
             nxt = jnp.where(live, nxt, 0)
             return (nxt, logits), _join_state(pools)
 
@@ -670,9 +747,12 @@ class DecodeEngine:
         n_bp = t_pad // cc.page_size
         cdt = cc.dtype
 
+        row = _prefill_row(t_pad, cc.pages_per_slot)
+
         @jax.named_scope("prefill_full")
-        def prefill(state, weights, tokens, length, pages, base_key,
-                    temp, top_k, top_p):
+        def prefill(state, weights, packed):
+            a = _unpack(packed, row)
+            tokens, length, pages = a["tokens"], a["length"], a["pages"]
             positions = jnp.arange(t_pad, dtype=jnp.int32)
             row_lengths = positions + 1
             shape = (t_max, model.num_heads, model.head_dim)
@@ -710,9 +790,10 @@ class DecodeEngine:
                 attend)
             last = jax.lax.dynamic_index_in_dim(
                 logits, length - 1, 0, keepdims=False)
-            key0 = jax.random.fold_in(base_key, 0)
-            tok = sample_tokens(key0[None], last[None], temp[None],
-                                top_k[None], top_p[None])[0]
+            key0 = jax.random.fold_in(a["key"], 0)
+            tok = sample_tokens(key0[None], last[None],
+                                a["temperature"][None], a["top_k"][None],
+                                a["top_p"][None])[0]
             return (tok, last), _join_state(pools)
 
         return jax.jit(prefill, donate_argnums=(0,))
@@ -1088,8 +1169,6 @@ class DecodeEngine:
             stat_set("decode_queue_depth", len(self._queue))
 
     def _admit_locked(self):
-        import jax
-
         if not self._continuous and self.live_slots:
             return []  # one-shot baseline: groups never mix
         admitted = []
@@ -1130,7 +1209,7 @@ class DecodeEngine:
                           free_pages=self._cache.allocator.num_free)
                 break  # FIFO head-of-line: wait for pages to free
             self._queue.popleft()
-            st = _SlotState(req, jax.random.PRNGKey(req.seed))
+            st = _SlotState(req, _key_words(req.seed))
             st.spec = (self.spec_enabled and req.temperature <= 0.0
                        and req.speculative is not False
                        and req.kv_import is None)
@@ -1311,7 +1390,24 @@ class DecodeEngine:
                 self._release(i)
 
     def _loop(self):
+        """One iteration: the arguments of the joint step of the slots
+        that decode now are built and uploaded; the queue is admitted,
+        as late as anything can still go ahead of that step; the
+        whole-prompt prefills of the admitted are handed to the device
+        and the step right behind them, neither waited for; then the
+        prefills' first tokens are read and delivered, then the step's.
+        A request admitted here joins the NEXT step (its first token is
+        not on the host when this one is handed over), nothing runs
+        ahead of its prefill on the device, and the step's dispatch
+        costs the device no idle time behind a prefill.  Nothing waits
+        for a caller: one that submits while the arguments are built
+        (a reply's caller gets the interpreter in their upload, the
+        first place after ``step_deliver`` where this thread lets go
+        of it) makes this admission, a later one the next."""
         while True:
+            with otrace.span("serving/reap"):
+                self._reap_live()
+            step = self._prepare_decode_round()
             # ``with self._cond:`` with the wait for the lock (callers
             # hold it while they submit) as a phase of its own, so that
             # the iteration is spanned end to end
@@ -1339,21 +1435,26 @@ class DecodeEngine:
                     continue
             finally:
                 self._cond.release()
-            self._service_prefills()
-            with otrace.span("serving/reap"):
-                self._reap_live()
-            self._run_decode_round()
+            finishes = self._start_prefills()
+            if step is not None:
+                finishes.append(self._dispatch_step(*step))
+            for finish in finishes:
+                finish()
 
     # -- device work: prefill ---------------------------------------------
-    def _service_prefills(self):
+    def _start_prefills(self):
         """Advance prefill-phase slots.  Chunked mode dispatches ONE
         chunk per engine-loop iteration (round-robin across prefilling
         slots) so the decoding slots keep stepping between chunks;
-        unchunked mode completes each prefill in one dispatch."""
+        unchunked mode completes each prefill in one dispatch.  A
+        whole-prompt prefill is only handed to the device here:
+        returned are the halves that wait for its token and deliver
+        it.  The other paths run to their end."""
         pre = [i for i, st in enumerate(self._slots)
                if st is not None and st.phase == "prefill"]
+        finishes = []
         if not pre:
-            return
+            return finishes
         chunk = self.config.prefill_chunk_pages
         if chunk > 0 and self.config.ragged_prefill_rows > 0:
             # ragged packing: several prompts' tails share one
@@ -1370,68 +1471,94 @@ class DecodeEngine:
             for i in pre:
                 st = self._slots[i]
                 if st.prefill_pos == 0:
-                    self._run_prefill_full(i)
+                    finishes.append(self._start_prefill_full(i))
                 else:
                     # prefix-cache suffix: only the unmatched tail of
                     # the prompt is computed, in one dispatch
                     rows = self._buckets.seq_bucket(
                         len(st.req.prompt) - st.prefill_pos)
                     self._run_prefill_rows(i, rows)
+        return finishes
 
-    def _run_prefill_full(self, slot: int):
+    def _prefill_args(self, t_pad: int, prompt, pages=0, key=0,
+                      temperature=0.0, top_k=0, top_p=1.0) -> np.ndarray:
+        """Everything one whole-prompt prefill takes after its weights,
+        as the int32 words of one ``_prefill_row`` record (host).  The
+        defaults are a greedy request that writes the trash page."""
+        rec = np.zeros(1, _prefill_row(
+            t_pad, self._cache.config.pages_per_slot))
+        rec["tokens"][0, :len(prompt)] = prompt
+        rec["length"] = len(prompt)
+        rec["pages"] = pages
+        rec["key"] = key
+        rec["temperature"] = temperature
+        rec["top_k"] = top_k
+        rec["top_p"] = top_p
+        return _words(rec)[0]
+
+    def _start_prefill_full(self, slot: int):
         """The whole-prompt prefill fast path (no cache hit, chunking
         off): page-wholesale K/V writes + locally-built full-width
-        attention, one dispatch."""
+        attention, one dispatch.  Returns the half that waits for the
+        first token and delivers it."""
         st = self._slots[slot]
         req = st.req
+
+        def failed(e):  # fault isolation per request
+            stat_add("decode_prefill_errors")
+            self._finish_slot(slot, e)
+
         try:
             t_pad = self._buckets.seq_bucket(len(req.prompt))
             attrs = {"slot": slot, "bucket": t_pad, "req": _rid(req)}
             t0 = time.monotonic()
             with otrace.span("serving/prefill_args", **attrs):
                 up = _Uploads()
-                tokens = np.zeros((t_pad,), np.int32)
-                tokens[:len(req.prompt)] = req.prompt
-                args = lambda w: (  # noqa: E731
-                    w, up(tokens), up.host(np.int32(len(req.prompt))),
-                    up(self._cache.page_table[slot]), st.base_key,
-                    up.host(np.float32(req.temperature)),
-                    up.host(np.int32(req.top_k)),
-                    up.host(np.float32(req.top_p)))
-                target_args = args(self.weights)
-                draft_args = args(self.draft_weights) if st.spec else None
+                packed = up(self._prefill_args(
+                    t_pad, req.prompt, self._cache.page_table[slot],
+                    st.base_key, req.temperature, req.top_k, req.top_p))
                 up.record()
             with otrace.span("serving/prefill_dispatch", **attrs):
                 tok, last = self._exe.run_persistent(
                     self._prefill_fn(t_pad), self._state_vars,
-                    args=target_args, scope=self._scope)
+                    args=(self.weights, packed), scope=self._scope)
                 if st.spec:
                     # mirror the prefill into the draft's pools (same
-                    # page ids) so proposals can read the prompt
+                    # page ids, the same uploaded arguments) so
+                    # proposals can read the prompt
                     self._exe.run_persistent(
                         self._prefill_fn(t_pad, "draft"),
                         self._draft_state_vars,
-                        args=draft_args, scope=self._scope)
-            with otrace.span("serving/prefill_sync", **attrs):
-                tok = int(np.asarray(tok))  # the prefill's sync point
-            # through the sync: the time until the token is on the host
-            dur = time.monotonic() - t0
-            stat_time("decode_prefill_seconds", dur)
-            with otrace.span("serving/prefill_deliver", **attrs):
-                self._tev(req, "prefill", slot=slot, bucket=t_pad,
-                          tokens=len(req.prompt),
-                          dur_ms=round(dur * 1e3, 3))
-                stat_add("decode_prefills")
-                record_pad_waste(len(req.prompt), t_pad)
-                st.prefill_pos = len(req.prompt)
-                st.phase = "decode"
-                self._cache.lengths[slot] = len(req.prompt)
-                if req.record_logits:
-                    req.logits_trace.append(np.asarray(last))
-                self._deliver(slot, tok)
-        except Exception as e:  # noqa: BLE001 — fault isolation per req
-            stat_add("decode_prefill_errors")
-            self._finish_slot(slot, e)
+                        args=(self.draft_weights, packed),
+                        scope=self._scope)
+        except Exception as e:  # noqa: BLE001
+            failed(e)
+            return lambda: None
+
+        def finish():
+            try:
+                with otrace.span("serving/prefill_sync", **attrs):
+                    first = int(np.asarray(tok))  # the prefill's sync point
+                # through the sync: the time until the token is on the
+                # host
+                dur = time.monotonic() - t0
+                stat_time("decode_prefill_seconds", dur)
+                with otrace.span("serving/prefill_deliver", **attrs):
+                    self._tev(req, "prefill", slot=slot, bucket=t_pad,
+                              tokens=len(req.prompt),
+                              dur_ms=round(dur * 1e3, 3))
+                    stat_add("decode_prefills")
+                    record_pad_waste(len(req.prompt), t_pad)
+                    st.prefill_pos = len(req.prompt)
+                    st.phase = "decode"
+                    self._cache.lengths[slot] = len(req.prompt)
+                    if req.record_logits:
+                        req.logits_trace.append(np.asarray(last))
+                    self._deliver(slot, first)
+            except Exception as e:  # noqa: BLE001
+                failed(e)
+
+        return finish
 
     def _run_prefill_rows(self, slot: int, rows: int):
         """One prefill chunk of ``rows`` positions starting at the
@@ -1469,7 +1596,7 @@ class DecodeEngine:
                                        np.int32)),
                     up(self._cache.page_table[slot:slot + 1]),
                     up(write_page), up(write_off),
-                    up(np.asarray(st.base_key)[None]),
+                    up(st.base_key[None]),
                     up.host(np.zeros((1,), np.int32)),
                     up.host(np.asarray([req.temperature], np.float32)),
                     up.host(np.asarray([req.top_k], np.int32)),
@@ -1575,8 +1702,7 @@ class DecodeEngine:
                     (L,) + self._cache.page_table[0].shape, np.int32)
                 write_page = np.zeros((L, 1), np.int32)
                 write_off = np.zeros((L, 1), np.int32)
-                key0 = np.asarray(self._slots[picks[0][0]].base_key)
-                base_keys = np.zeros((L,) + key0.shape, key0.dtype)
+                base_keys = np.zeros((L, 2), np.uint32)
                 temp = np.zeros((L,), np.float32)
                 top_k = np.zeros((L,), np.int32)
                 top_p = np.ones((L,), np.float32)
@@ -1595,7 +1721,7 @@ class DecodeEngine:
                         write_page[lane, 0] = self._cache.page_table[i][
                             pos // cc.page_size]
                         write_off[lane, 0] = pos % cc.page_size
-                        base_keys[lane] = np.asarray(st.base_key)
+                        base_keys[lane] = st.base_key
                         temp[lane] = req.temperature
                         top_k[lane] = req.top_k
                         top_p[lane] = req.top_p
@@ -1704,11 +1830,14 @@ class DecodeEngine:
                           dst=int(dst),
                           dur_ms=round((time.monotonic() - t0) * 1e3, 3))
 
-    def _run_decode_round(self):
+    def _prepare_decode_round(self):
+        """The round of the slots that decode now: speculative rounds
+        run to their end; for the joint step of the rest, what
+        ``_dispatch_step`` takes (None without one)."""
         decoding = [i for i, st in enumerate(self._slots)
                     if st is not None and st.phase == "decode"]
         if not decoding:
-            return
+            return None
         stat_max("decode_slot_occupancy_max", len(decoding))
         spec = [i for i in decoding
                 if self._slots[i].spec
@@ -1718,52 +1847,55 @@ class DecodeEngine:
             self._run_spec(spec)
         normal = [i for i in decoding
                   if self._slots[i] is not None and i not in set(spec)]
-        if normal:
-            self._run_step(normal)
+        return self._prepare_step(normal) if normal else None
 
-    def _step_args(self, live_idx, up):
-        """Everything one joint decode step takes after its state
-        tuple: the weights plus per-slot feeds (dead slots zeroed),
-        each handed to the device through ``up`` (an ``_Uploads``)."""
-        s = self._cache.config.num_slots
-        tokens = np.zeros((s,), np.int32)
-        positions = np.zeros((s,), np.int32)
-        live = np.zeros((s,), bool)
-        write_page = np.zeros((s,), np.int32)
-        write_off = np.zeros((s,), np.int32)
-        counters = np.zeros((s,), np.int32)
-        temp = np.zeros((s,), np.float32)
-        top_k = np.zeros((s,), np.int32)
-        top_p = np.ones((s,), np.float32)
-        base_keys = np.zeros((s, 2), np.uint32)
+    def _step_args(self, live_idx) -> np.ndarray:
+        """Everything one joint decode step takes after its weights:
+        the int32 words of one ``_step_row`` record a slot (host).  A
+        dead slot reads zeros (``top_p`` 1) beside its page-table row."""
+        rows = np.zeros(self._cache.config.num_slots, self._step_row)
+        rows["top_p"] = 1.0
+        rows["pages"] = self._cache.page_table
+        # one view a field: a scalar store into it costs a quarter of
+        # one through the record
+        tokens, positions, flags, counters, base_keys, temp, top_k, top_p \
+            = (rows[name] for name in (
+                "token", "position", "flags", "counter", "key",
+                "temperature", "top_k", "top_p"))
         for i in live_idx:
             st = self._slots[i]
             tokens[i] = st.last_token
             positions[i] = self._cache.lengths[i]
-            live[i] = True
-            if st.write_trash_once:
-                # cache-hit first step: the shared pages already hold
-                # this position's K/V — re-deriving it writes identical
-                # bytes, but shared pages are immutable, so aim at trash
-                write_page[i], write_off[i] = 0, 0
-            else:
-                write_page[i], write_off[i] = self._cache.write_coords(i)
+            # cache-hit first step: the shared pages already hold this
+            # position's K/V — re-deriving it writes identical bytes,
+            # but shared pages are immutable, so the write aims at trash
+            flags[i] = _LIVE | (_TRASH if st.write_trash_once else 0)
             counters[i] = st.n_generated
+            base_keys[i] = st.base_key
             temp[i] = st.req.temperature
             top_k[i] = st.req.top_k
             top_p[i] = st.req.top_p
-            base_keys[i] = np.asarray(st.base_key)
         if live_idx:
             # how much of the table the kernel's walk is this step, a
             # layer: the blocks holding a position any slot attends (a
             # dead slot's one included) against every block there is
-            stat_add("decode_attn_blocks_live",
-                     int((positions // self._attn_block + 1).sum()))
+            stat_add("decode_attn_blocks_live", int(
+                (positions // self._attn_block + 1).sum()))
             stat_add("decode_attn_blocks_walked", self._attn_table_blocks)
-        return (self.weights, up(tokens), up(positions), up(live),
-                up(self._cache.page_table), up(write_page),
-                up(write_off), up(base_keys), up(counters), up(temp),
-                up(top_k), up(top_p))
+        return _words(rows)
+
+    def _lower(self, fn, packed, sharding):
+        """``fn`` (the step, a whole-prompt prefill) lowered at this
+        engine's own shapes; nothing runs."""
+        import jax
+
+        args = (tuple(self._scope.get_var(n) for n in self._state_vars),
+                self.weights, packed)
+        if sharding is not None:
+            args = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=sharding), args)
+        return fn.lower(*args)
 
     def lower_step(self, sharding=None):
         """The joint decode step lowered at this engine's own shapes;
@@ -1771,17 +1903,19 @@ class DecodeEngine:
         in it (``tpu_custom_call``), ``.compile()`` asks the compiler.
         ``sharding`` re-targets every operand (e.g. to one device of a
         described, unattached topology) by lowering from shapes."""
-        import jax
+        return self._lower(self._step_fn, self._step_args(()), sharding)
 
-        args = (tuple(self._scope.get_var(n) for n in self._state_vars),
-                *self._step_args((), _Uploads()))
-        if sharding is not None:
-            args = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=sharding), args)
-        return self._step_fn.lower(*args)
+    def lower_prefill(self, t_pad: int, sharding=None):
+        """The whole-prompt prefill of bucket ``t_pad``, as
+        ``lower_step``."""
+        return self._lower(self._prefill_fn(t_pad),
+                           self._prefill_args(t_pad, (0,)), sharding)
 
-    def _run_step(self, live_idx):
+    def _prepare_step(self, live_idx):
+        """The joint step of ``live_idx`` up to its uploaded arguments.
+        Nothing between here and ``_dispatch_step`` touches a slot that
+        decodes: an admission claims pages no live slot owns, and a
+        slot it fills reads as dead in these arguments."""
         attrs = {"step": self._decode_steps, "live": len(live_idx)}
         # copy-on-write any shared page this step would write (a
         # borrowed partial tail at its first divergent token)
@@ -1792,39 +1926,56 @@ class DecodeEngine:
                         i, [int(self._cache.lengths[i])]))
         with otrace.span("serving/step_args", **attrs):
             up = _Uploads()
-            args = self._step_args(live_idx, up)
+            args = (self.weights, up(self._step_args(live_idx)))
             up.record()
+        return live_idx, attrs, args
+
+    def _dispatch_step(self, live_idx, attrs, args):
+        """Hand the prepared joint step to the device; returns the half
+        that reads its tokens and delivers them."""
         t0 = time.monotonic()
+
+        def failed(e):  # fail the batch loudly, free every slot, keep
+            # the consumer thread alive
+            stat_add("decode_step_errors")
+            for i in live_idx:
+                self._finish_slot(i, e)
+
         try:
             with otrace.span("serving/step_dispatch", **attrs):
                 nxt, logits = self._exe.run_persistent(
                     self._step_fn, self._state_vars, args=args,
                     scope=self._scope)
-            with otrace.span("serving/step_sync", **attrs):
-                nxt = np.asarray(nxt)  # THE per-step sync point
-        except Exception as e:  # noqa: BLE001 — fail the batch loudly,
-            # free every slot, keep the consumer thread alive
-            stat_add("decode_step_errors")
-            for i in live_idx:
-                self._finish_slot(i, e)
-            return
-        stat_time("decode_step_seconds", time.monotonic() - t0)
-        self._decode_steps += 1
-        with otrace.span("serving/step_deliver", **attrs):
-            logits_np = None
-            for i in live_idx:
-                st = self._slots[i]
-                st.write_trash_once = False
-                if st.spec:
-                    st.draft_lag += 1  # target-only write: draft stale
-                self._cache.lengths[i] += 1
-                if st.req.record_logits:
-                    if logits_np is None:
-                        logits_np = np.asarray(logits)
-                    st.req.logits_trace.append(logits_np[i].copy())
-                self._deliver(i, int(nxt[i]))
-            stat_set("decode_slot_occupancy", self.live_slots)
-            stat_add("decode_steps")
+        except Exception as e:  # noqa: BLE001
+            failed(e)
+            return lambda: None
+
+        def finish():
+            try:
+                with otrace.span("serving/step_sync", **attrs):
+                    tokens = np.asarray(nxt)  # THE per-step sync point
+            except Exception as e:  # noqa: BLE001
+                failed(e)
+                return
+            stat_time("decode_step_seconds", time.monotonic() - t0)
+            self._decode_steps += 1
+            with otrace.span("serving/step_deliver", **attrs):
+                logits_np = None
+                for i in live_idx:
+                    st = self._slots[i]
+                    st.write_trash_once = False
+                    if st.spec:
+                        st.draft_lag += 1  # target-only write: draft stale
+                    self._cache.lengths[i] += 1
+                    if st.req.record_logits:
+                        if logits_np is None:
+                            logits_np = np.asarray(logits)
+                        st.req.logits_trace.append(logits_np[i].copy())
+                    self._deliver(i, int(tokens[i]))
+                stat_set("decode_slot_occupancy", self.live_slots)
+                stat_add("decode_steps")
+
+        return finish
 
     def _run_spec(self, spec_idx):
         """One speculative round for the greedy slots: a k-token draft
@@ -1978,14 +2129,11 @@ class DecodeEngine:
         compares the default oracle bitwise on unquantized engines;
         ``tests/test_decode_prefix_spec.py`` does the same for the
         shared-prefix, CoW, chunked, and speculative paths."""
-        import jax
         import jax.numpy as jnp
 
         qz = bool(quantized) if quantized is not None else False
         tokens = [int(t) for t in tokens]
         t_pad = self._buckets.seq_bucket(len(tokens))
-        arr = np.zeros((t_pad,), np.int32)
-        arr[:len(tokens)] = tokens
         cc = self._cache.config
         shape = cc.pool_shape()
         if qz:
@@ -2000,11 +2148,7 @@ class DecodeEngine:
             scratch = (jnp.zeros(shape, cc.dtype),
                        jnp.zeros(shape, cc.dtype))
         (tok, last), _ = self._prefill_fn(t_pad, quantized=qz)(
-            scratch, self.weights, jnp.asarray(arr),
-            np.int32(len(tokens)),
-            jnp.zeros((cc.pages_per_slot,), jnp.int32),
-            jax.random.PRNGKey(0), np.float32(0.0), np.int32(0),
-            np.float32(1.0))
+            scratch, self.weights, self._prefill_args(t_pad, tokens))
         return np.asarray(last)
 
     def debug_requests(self) -> List[dict]:

@@ -249,11 +249,9 @@ def test_gpt2_width_step_walks_blocks_of_eight_pages():
     16-token pages): every layer's kernel runs one grid step a SLOT and
     holds two buffers of EIGHT pages a pool, so a silent fall-back to a
     page a block fails here.  Traced, not compiled: needs no chip."""
-    from paddle_tpu.serving.decode import _Uploads
-
     eng = _gpt2_width_engine(16, False, vocab_size=512, slots=32)
     args = (tuple(eng._scope.get_var(n) for n in eng._state_vars),
-            *eng._step_args((), _Uploads()))
+            eng.weights, eng._step_args(()))
     calls = list(_pallas_calls(eng._step_fn.trace(*args).jaxpr.jaxpr))
     assert len(calls) == eng.model.num_layers
     for eqn in calls:
@@ -343,17 +341,12 @@ def _on_chip(tree, one_chip):
 def _lower_program(eng, program, one_chip):
     """One of the engine's pool-taking programs lowered at its own
     shapes; returns it with the shapes of its state tuple."""
-    cc = eng._cache.config
     state = tuple(eng._scope.get_var(n) for n in eng._state_vars)
-    i32, f32 = jnp.int32, jnp.float32
+    i32 = jnp.int32
     if program == "step":
         lowered = eng.lower_step(sharding=one_chip)
     elif program == "prefill":        # one whole-prompt bucket
-        t_pad = 128
-        args = (state, eng.weights, jnp.zeros((t_pad,), i32), i32(100),
-                jnp.zeros((cc.pages_per_slot,), i32),
-                jax.random.PRNGKey(0), f32(0.0), i32(0), f32(1.0))
-        lowered = eng._prefill_fn(t_pad).lower(*_on_chip(args, one_chip))
+        lowered = eng.lower_prefill(128, sharding=one_chip)
     else:                             # copy-on-write of one page
         lowered = eng._build_cow_fn().lower(
             *_on_chip((state, i32(1), i32(2)), one_chip))
