@@ -111,6 +111,15 @@ RULES = (
          "Collective-matmul chunking engagement/fallbacks"),
     Rule("ep_", "gauge", "expert_parallel",
          "Expert-parallel ('ep' axis) mesh/plan bookkeeping"),
+    Rule("moe_local_assignments", "gauge", "expert_parallel",
+         "(live row, chosen expert) pairs whose expert THIS chip holds "
+         "(`ops/moe_ops.py` `moe_share_*`), summed over a joint decode "
+         "step's layers: what the held share computes.  Rides the "
+         "step's one read-back behind the tokens"),
+    Rule("moe_experts_hit", "gauge", "expert_parallel",
+         "Held experts some live row of a joint decode step chose, "
+         "summed over its layers: the expert weights the step cannot "
+         "avoid reading.  Rides the step's one read-back"),
     Rule("moe_", "gauge", "expert_parallel",
          "Mixture-of-experts routing: expert balance and drop "
          "fractions (ppm), routed-FFN engagement, all-to-all "
@@ -169,6 +178,15 @@ RULES = (
          "a position some row attends; one for a dead slot), `_walked` "
          "every block of every slot's table, which a fixed grid would "
          "walk.  Their ratio is the share of the table that is work"),
+    Rule("decode_state_bytes", "gauge", "serving",
+         "Device bytes of the slot-indexed slabs that hold the state of "
+         "a model's recurrent layers (linear attention: a matrix a head "
+         "and a convolution tail), all slots; 0 for a model whose "
+         "layers all keep keys"),
+    Rule("decode_prefix_bypassed", "gauge", "serving",
+         "Admissions of an engine whose prefix cache was asked for and "
+         "left out because the model keeps recurrent state (a page of "
+         "keys says nothing about it): every request admitted fresh"),
     Rule("decode_", "gauge", "serving",
          "Decode-engine lifecycle, paging, speculation, goodput"),
     Rule("serving_", "gauge", "serving",

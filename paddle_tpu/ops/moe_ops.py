@@ -46,6 +46,9 @@ __all__ = [
     "moe_router_ref",
     "moe_ffn_ref",
     "moe_balance_gauges",
+    "moe_share_route",
+    "moe_share_counts",
+    "moe_share_ffn",
 ]
 
 
@@ -262,3 +265,84 @@ def _moe_ffn_lower(ctx, op):
     ctx.set_out(op, "Out", out)
     ctx.set_out(op, "AuxLoss", jnp.reshape(aux, (1,)))
     ctx.set_out(op, "ExpertLoad", load)
+
+
+# ---------------------------------------------------------------------------
+# an expert layer that holds a SHARE of the experts (serving)
+# ---------------------------------------------------------------------------
+# Expert parallelism seen from one chip: the router keeps its full width,
+# every token picks its top-k over ALL experts, and this chip computes the
+# part of the result its own experts give.  Nothing is dropped and nothing
+# is sized by a capacity: the held experts' weights are three plain
+# matrices ([D, n_held*F], [D, n_held*F], [n_held*F, D]), every row runs
+# through them, and a row's hidden units of an expert it did not choose
+# are multiplied by zero.  At decode (a hundred rows) the three matmuls
+# stream the weights once and are bound by that read; a prefill pays
+# n_held / (local assignments a row) times the multiply-adds a grouped
+# matmul over sorted rows would (PERF.md says what that costs).
+# The scopes name the ops in lowered text; a trace's device events carry
+# the HLO instruction, whose operands name the weights they read.
+
+ROUTE_SCOPE = "moe_route"
+EXPERTS_SCOPE = "moe_experts"
+
+
+def moe_share_route(h, router_w, router_bias, *, top_k, held_ids,
+                    live=None):
+    """Sigmoid routing of rows ``h [..., D]`` over ALL experts
+    (``router_w [D, E]``, float32 at ``highest``: a score's rounding
+    decides the top-k), for a chip that holds ``held_ids`` ([n_held]
+    expert ids).  The top-k is taken by score + ``router_bias`` [E] (the
+    load-balance correction, zero here); the weights are the plain
+    scores, normalised over the chosen k.  ``live`` (rows' shape, bool)
+    takes dead and padding rows out: they choose and weigh as any row,
+    and give this chip nothing to compute.
+
+    Returns ``(ids [..., K] int32, weights [..., K] f32, local
+    [..., n_held] f32)``: ``local[r, j]`` is row r's weight for held
+    expert j, zero where it did not choose it."""
+    with jax.named_scope(ROUTE_SCOPE):
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "...d,de->...e", h.astype(jnp.float32),
+            router_w.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _, ids = lax.top_k(scores + router_bias.astype(jnp.float32),
+                           int(top_k))
+        weights = jnp.take_along_axis(scores, ids, axis=-1)
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        held = jnp.asarray(held_ids, jnp.int32)
+        chosen = ids[..., :, None] == held                  # [..., K, n]
+        if live is not None:
+            chosen = chosen & live[..., None, None]
+        local = jnp.sum(jnp.where(chosen, weights[..., None], 0.0),
+                        axis=-2)
+    return ids.astype(jnp.int32), weights, local
+
+
+def moe_share_counts(local):
+    """(local assignments, held experts hit) of one routed layer, int32
+    scalars: how many (row, chosen expert) pairs this chip computes and
+    how many of its experts some row chose."""
+    hit = (local > 0.0).reshape(-1, local.shape[-1])
+    return (jnp.sum(hit, dtype=jnp.int32),
+            jnp.sum(jnp.any(hit, axis=0), dtype=jnp.int32))
+
+
+def moe_share_ffn(h, local, w_gate, w_up, w_down):
+    """The held experts' part of the routed result for rows ``h
+    [..., D]``: ``sum_j local[r, j] * E_j(h_r)`` with ``E(h) =
+    (silu(h W_gate) * h W_up) W_down``.  ``w_gate``/``w_up`` are
+    ``[D, n_held*F]`` (expert j's columns ``j*F:(j+1)*F``), ``w_down``
+    ``[n_held*F, D]``; matmuls take the weights' dtype in and float32
+    out.  Dropless under any imbalance: every row meets every held
+    expert, the weight decides."""
+    n_held = local.shape[-1]
+    with jax.named_scope(EXPERTS_SCOPE):
+        x = h.astype(w_gate.dtype)
+        gate = jnp.matmul(x, w_gate, preferred_element_type=jnp.float32)
+        up = jnp.matmul(x, w_up, preferred_element_type=jnp.float32)
+        act = (jax.nn.silu(gate) * up).reshape(
+            *h.shape[:-1], n_held, -1) * local[..., None]
+        return jnp.matmul(
+            act.reshape(*h.shape[:-1], -1).astype(w_down.dtype), w_down,
+            preferred_element_type=jnp.float32)

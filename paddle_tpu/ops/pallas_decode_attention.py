@@ -120,6 +120,25 @@ def decode_attention_reference(q, k, v, lengths, *, sm_scale=None):
     return out.astype(q.dtype)
 
 
+def grouped_causal_attention(q, k, v, *, sm_scale=None):
+    """Causal attention of one sequence whose query heads outnumber its
+    K/V heads: q [T, H, D], k/v [T, Hkv, D], query head i reading K/V
+    head ``i // (H // Hkv)``; row t attends positions ``<= t``.  The
+    whole-prompt prefill of a grouped-query model (serving/decode.py):
+    plain jnp at the prompt's own width, float32 softmax."""
+    t, h, d = q.shape
+    kv_heads = k.shape[1]
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    qg = q.astype(jnp.float32).reshape(t, kv_heads, h // kv_heads, d)
+    s = jnp.einsum("thgd,uhd->hgtu", qg, k.astype(jnp.float32)) * sm_scale
+    pos = jnp.arange(t, dtype=jnp.int32)
+    s = jnp.where(pos[None, :] <= pos[:, None], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("hgtu,uhd->thgd", p, v.astype(jnp.float32))
+    return out.reshape(t, h, d).astype(q.dtype)
+
+
 def _gather_dequant(pages, scales, layer, page_table, num_heads):
     """Reference-path page gather out of the stacked pool: [S, pps*page,
     H, D] at full width (the lane-folded row reshaped back to heads,
@@ -437,7 +456,12 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
     q [S,R,H,D]; k/v_pages [L,P,page,H*D] (the stacked pools; ``layer``
     is the static layer to read); page_table [S,pps] i32; row_lengths
     [S,R] i32 — row r of slot s attends positions
-    ``t < row_lengths[s, r]``.  Serves both tentpole callers
+    ``t < row_lengths[s, r]``.  Grouped-query heads: a pool row of
+    ``Hkv*D`` lanes with ``Hkv < H`` makes query head i read K/V head
+    ``i // (H // Hkv)``; the group's heads ride as extra rows of their
+    K/V head, so a slot's pages are still read once (one-token decode of
+    64 query over 8 K/V heads is the kernel at R=8, H=8).  Serves both
+    tentpole callers
     in serving/decode.py: chunked prefill (R = chunk rows, one slot at
     a time) and speculative-decode verification (R = 1 + draft window,
     every slot jointly).  The reference path broadcasts each slot's
@@ -450,6 +474,26 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    s, r, h, d = q.shape
+    kv_heads = k_pages.shape[-1] // d
+    if kv_heads != h:
+        # grouped-query heads: the G query heads that share a K/V head
+        # ride as G more ROWS of that head's slot, so the kernel (and
+        # the reference) read a slot's pages once for all of them
+        if h % kv_heads or k_pages.shape[-1] % d:
+            raise ValueError(
+                f"q has {h} heads of {d} but the pool rows hold "
+                f"{k_pages.shape[-1]} lanes: not a whole group a K/V head")
+        g = h // kv_heads
+        rows = q.reshape(s, r, kv_heads, g, d).transpose(0, 1, 3, 2, 4) \
+            .reshape(s, r * g, kv_heads, d)
+        out = paged_chunk_attention(
+            rows, k_pages, v_pages, page_table,
+            jnp.repeat(row_lengths, g, axis=1), layer=layer,
+            sm_scale=sm_scale, use_pallas=use_pallas, interpret=interpret,
+            k_scales=k_scales, v_scales=v_scales)
+        return out.reshape(s, r, g, kv_heads, d).transpose(0, 1, 3, 2, 4) \
+            .reshape(q.shape)
     if use_pallas == "auto":
         use_pallas = "always" if jax.default_backend() == "tpu" \
             else "never"
@@ -457,7 +501,6 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
         return _chunk_call(q, k_pages, v_pages, jnp.int32(layer),
                            page_table, row_lengths, k_scales, v_scales,
                            sm_scale=float(sm_scale), interpret=interpret)
-    s, r, h = q.shape[:3]
     k = _gather_dequant(k_pages, k_scales, layer, page_table, h)
     v = _gather_dequant(v_pages, v_scales, layer, page_table, h)
     kr = jnp.broadcast_to(k[:, None], (s, r) + k.shape[1:]) \

@@ -50,6 +50,7 @@ from .disagg import (  # noqa: F401
     DisaggRequest,
     DisaggServer,
 )
+from .hybrid_moe_lm import HybridMoELM  # noqa: F401
 from .kv_cache import (  # noqa: F401
     CacheConfig,
     CacheExhaustedError,
@@ -69,7 +70,7 @@ __all__ = [
     "Autoscaler", "Batcher", "BucketSpec", "CacheConfig",
     "CacheExhaustedError", "DeadlineExceededError", "DecodeConfig",
     "DecodeEngine", "DecodeRequest", "DecodeServer", "DisaggConfig",
-    "DisaggRequest", "DisaggServer", "InferenceRequest",
+    "DisaggRequest", "DisaggServer", "HybridMoELM", "InferenceRequest",
     "KVPageExport", "PageAllocator", "PagedKVCache", "PrefixIndex",
     "QueueFullError", "RequestAbandonedError", "RequestBase",
     "RequestTooLargeError", "Server", "ServerClosedError",

@@ -106,7 +106,7 @@ from .buckets import (BucketSpec, DeadlineExceededError, QueueFullError,
                       RequestTooLargeError, ServerClosedError,
                       prefill_bucket_grid, record_pad_waste)
 from . import kv_cache
-from .kv_cache import CacheConfig, PagedKVCache
+from .kv_cache import CacheConfig, PagedKVCache, RecurrentSpec
 # the reference model lives beside the engine; its names stay importable here
 from .transformer_lm import (TransformerLM, quantize_moe_weights,  # noqa: F401
                              shard_moe_weights)
@@ -168,10 +168,14 @@ def _step_row(pages_per_slot: int) -> np.dtype:
                     + _SAMPLING + [("pages", np.int32, (pages_per_slot,))])
 
 
-def _prefill_row(t_pad: int, pages_per_slot: int) -> np.dtype:
-    """One whole-prompt prefill at bucket ``t_pad``."""
+def _prefill_row(t_pad: int, pages_per_slot: int,
+                 slot: bool = False) -> np.dtype:
+    """One whole-prompt prefill at bucket ``t_pad``; with ``slot`` (a
+    model with recurrent layers) also the slot whose state rows it
+    leaves the prompt's final state in."""
     return np.dtype([("tokens", np.int32, (t_pad,)), ("length", np.int32)]
-                    + _SAMPLING + [("pages", np.int32, (pages_per_slot,))])
+                    + _SAMPLING + [("pages", np.int32, (pages_per_slot,))]
+                    + ([("slot", np.int32)] if slot else []))
 
 
 def _words(records: np.ndarray) -> np.ndarray:
@@ -225,6 +229,86 @@ def _join_state(pools):
     return tuple(p for p in pools if p is not None)
 
 
+class _Mixed:
+    """The layout of a model whose layers are not all attention (it
+    declares ``layer_kinds``): which pool layer an attention layer's K/V
+    live in, which slabs a recurrent layer's state, and how the
+    persistent-state tuple (pools, then slabs layer-major) splits into
+    the ``(pools, recurrent)`` cache that ``forward`` threads."""
+
+    def __init__(self, model, spec: Optional[RecurrentSpec]):
+        kinds = tuple(model.layer_kinds)
+        self.pool_layer = {l: i for i, l in enumerate(
+            l for l, k in enumerate(kinds) if k == "attention")}
+        self.rec_layer = {l: i for i, l in enumerate(
+            l for l, k in enumerate(kinds) if k != "attention")}
+        self.names = tuple(spec.arrays) if spec is not None else ()
+        self.n_arrays = len(self.rec_layer) * len(self.names)
+        self.tallies = tuple(getattr(model, "tallies", ()))
+
+    def split(self, state):
+        n = len(state) - self.n_arrays
+        flat, k = state[n:], len(self.names)
+        return _split_state(state[:n]), tuple(
+            dict(zip(self.names, flat[i * k:(i + 1) * k]))
+            for i in range(len(self.rec_layer)))
+
+    def join(self, cache):
+        pools, rec = cache
+        return _join_state(pools) + tuple(
+            layer[name] for layer in rec for name in self.names)
+
+
+class _Mixers:
+    """What ``forward`` of a model with ``layer_kinds`` gets as
+    ``attend``: the call is attention as ever (the model's layer mapped
+    to its pool layer); ``recur(layer, token_fn, rows, cache)`` runs a
+    recurrent layer's one-token update ``token_fn(rows, state) -> (out,
+    state)`` where the program keeps that state; ``live`` (bool, the
+    rows' shape) says which rows are a request's; ``tally(name, n)``
+    adds an int32 scalar to the counter ``name``, one of the model's
+    declared ``tallies`` (a joint step's ride its one read-back, in the
+    declared order); ``record(name, rows)`` keeps a per-row array a
+    layer for a request that records its logits."""
+
+    def __init__(self, mixed: _Mixed, recur, live, attend=None):
+        self._mixed, self._recur, self.attend = mixed, recur, attend
+        self.live = live
+        self.counts, self.records = dict.fromkeys(mixed.tallies, 0), {}
+
+    def __call__(self, layer, q, k, v, cache):
+        pools, rec = cache
+        ctx, pools = self.attend(self._mixed.pool_layer[layer], q, k, v,
+                                 pools)
+        return ctx, (pools, rec)
+
+    def recur(self, layer, token_fn, rows, cache):
+        pools, rec = cache
+        i = self._mixed.rec_layer[layer]
+        out, new = self._recur(token_fn, rows, rec[i])
+        return out, (pools, rec[:i] + (new,) + rec[i + 1:])
+
+    def tally(self, name, value):
+        if name not in self.counts:
+            raise KeyError(f"{name!r} is not among the model's declared "
+                           f"tallies {self._mixed.tallies}")
+        self.counts[name] += value
+
+    def record(self, name, rows):
+        self.records.setdefault(name, []).append(rows)
+
+    def recorded(self):
+        """{name: [rows, layers, ...]}."""
+        import jax.numpy as jnp
+
+        return {n: jnp.stack(v, axis=1) for n, v in self.records.items()}
+
+
+def recurrent_layers(model) -> int:
+    """How many of ``model``'s layers keep state instead of keys."""
+    return sum(k != "attention" for k in getattr(model, "layer_kinds", ()))
+
+
 # ---------------------------------------------------------------------------
 # requests
 
@@ -242,8 +326,8 @@ class DecodeRequest(RequestBase):
     __slots__ = ("prompt", "max_new_tokens", "temperature", "top_k",
                  "top_p", "seed", "on_token", "generated", "_stream",
                  "t_first_token", "t_last_token", "record_logits",
-                 "logits_trace", "speculative", "finish_reason",
-                 "extract_kv", "kv_import", "kv_export")
+                 "logits_trace", "records", "speculative",
+                 "finish_reason", "extract_kv", "kv_import", "kv_export")
 
     _deadline_stat = "decode_deadline_exceeded"
     _outcome_prefix = "decode"
@@ -265,6 +349,11 @@ class DecodeRequest(RequestBase):
         self.t_last_token: Optional[float] = None
         self.record_logits = bool(record_logits)
         self.logits_trace: List[np.ndarray] = []
+        # beside the logits, what the model recorded a row (``attend.
+        # record``; a routed model's chosen expert ids): {name: [per
+        # dispatch, as logits_trace; a prefill's entry holds every
+        # prompt position]}
+        self.records: dict = {}
         self.speculative = speculative  # None=auto, False=opt out
         self.finish_reason: Optional[str] = None
         # disaggregated serving (serving/disagg.py): an extract_kv
@@ -464,6 +553,35 @@ class DecodeEngine:
     about the model; bitwise parity of cached decode with a recompute
     needs a ``forward`` whose other operations are row-independent.
 
+    **Layers that keep state instead of keys**
+    (``serving/hybrid_moe_lm.py`` is the reference).  A model may also
+    declare ``num_kv_heads`` (grouped-query heads: ``k``/``v`` rows of
+    ``[..., Hkv, D]``, the pools ``Hkv * D`` lanes wide, query head i
+    reading K/V head ``i // (H / Hkv)``) and ``layer_kinds``, one of
+    ``"attention"`` | ``"recurrent"`` a layer, with ``recurrent_state``:
+    ``{name: (shape, dtype)}`` of ONE slot's state of ONE recurrent layer,
+    and ``tallies``: the names of the counters its ``forward`` adds to.
+    The pools then hold the attention layers only, the recurrent layers'
+    state lives in slot-indexed slabs beside them (``kv_cache.
+    RecurrentSpec``), and ``attend`` is a ``_Mixers``: the call as above
+    for an attention layer; ``attend.recur(layer, token_fn, rows, cache)
+    -> (out, cache)`` for a recurrent one, where ``token_fn(rows, state)
+    -> (out, state)`` is the model's one-token update over rows
+    ``[R, ...]`` and the engine decides what state that is and where it
+    goes (the joint step: every live slot's row, in place; the
+    whole-prompt prefill: from zero through the prompt's real tokens one
+    by one, the last one's state into the slot's row); ``attend.live``
+    (which rows are a request's), ``attend.tally(name, n)`` (counters
+    the model declares by name in ``tallies``; they ride the step's one
+    read-back into ``stat_add(name)``) and ``attend.record(name, rows)``
+    (a per-row array a layer, kept beside the logits of a
+    ``record_logits`` request in ``req.records``).  Such
+    a model is served by the whole-prompt prefill and the joint step
+    alone: every request is admitted fresh (no prefix index; counter
+    ``decode_prefix_bypassed``), and chunked or ragged prefill,
+    speculative decoding, ``kv_quant`` and the disaggregated hand-over
+    refuse at construction or submit, naming the mechanism.
+
     ``draft_model``/``draft_weights`` arm speculative decoding (with
     ``spec_k > 0``): the draft's page pools are indexed by the SAME
     page ids as the target's, so prefix sharing, reservation
@@ -514,13 +632,25 @@ class DecodeEngine:
         # also what mesh-sharded (expert-parallel) weights need
         self._device = self._exe.place.jax_device()
         self._pinned = place is not None
+        # layers that keep state instead of keys: pools for the
+        # attention layers only, slot-indexed slabs for the others
+        n_rec = recurrent_layers(model)
+        spec = RecurrentSpec(n_rec, model.recurrent_state) if n_rec \
+            else None
+        self._mixed = _Mixed(model, spec) \
+            if getattr(model, "layer_kinds", None) else None
+        # the model's counters behind a step's tokens, as it declares them
+        self._tallies = self._mixed.tallies if self._mixed else ()
+        if n_rec:
+            self._refuse_for_recurrent(c, draft_model)
         with jax.default_device(self._device):
             self._cache = PagedKVCache(
-                CacheConfig(model.num_layers, model.num_heads,
+                CacheConfig(max(model.num_layers - n_rec, 1),
+                            getattr(model, "num_kv_heads", model.num_heads),
                             model.head_dim, c.slots, c.max_seq_len,
                             c.page_size, num_pages=c.num_pages,
                             dtype=c.cache_dtype, quantized=c.kv_quant),
-                self._scope, prefix_cache=c.prefix_cache)
+                self._scope, prefix_cache=c.prefix_cache, recurrent=spec)
         # whether the pools' one layout is also an unpadded one (the
         # tile rule in serving/kv_cache.py): the counter that says the
         # lane-dense representation engaged for this model's shape
@@ -546,6 +676,8 @@ class DecodeEngine:
         # persistent-state tuples every jitted step threads (the scale
         # pools join them under FLAGS_decode_kv_quant)
         self._state_vars = self._cache.state_var_names()
+        n_pools = len(self._state_vars) \
+            - len(self._cache.recurrent_var_names())
         self._draft_state_vars = ()
         if draft_model is not None:
             self.draft_weights = self._commit(draft_weights)
@@ -581,7 +713,8 @@ class DecodeEngine:
         self._rows_fns = {}      # (rows, slots, which) -> jitted multirow
         self._propose_fn = None  # draft k-token burst (lazy)
         self._cow_fn = None      # page copy across every pool (lazy)
-        self._cow_state = self._state_vars + self._draft_state_vars
+        self._cow_state = self._state_vars[:n_pools] \
+            + self._draft_state_vars
         self._slots: List[Optional[_SlotState]] = [None] * c.slots
         self._queue = collections.deque()
         self._cond = threading.Condition()
@@ -601,6 +734,30 @@ class DecodeEngine:
         # decode rounds dispatched by THIS engine (joint steps and
         # speculative rounds): the step spans' ``step``
         self._decode_steps = 0
+
+    @staticmethod
+    def _refuse_for_recurrent(c: "DecodeConfig", draft_model) -> None:
+        """A model with recurrent layers runs the whole-prompt prefill
+        and the joint step; every mechanism that replays, skips or
+        exports positions would need the state at a position that no
+        slab holds, and refuses here rather than run wrong."""
+        why = ("the model's recurrent layers keep one state a slot, "
+               "the state after the LAST token: ")
+        if c.prefill_chunk_pages > 0 or c.ragged_prefill_rows > 0:
+            raise ValueError(
+                why + "chunked/ragged prefill (prefill_chunk_pages="
+                f"{c.prefill_chunk_pages}, ragged_prefill_rows="
+                f"{c.ragged_prefill_rows}) would have to carry it from "
+                "chunk to chunk, which the multi-row step does not")
+        if draft_model is not None or c.spec_k > 0:
+            raise ValueError(
+                why + "speculative decoding (a draft model, spec_k="
+                f"{c.spec_k}) would have to rewind it past rejected "
+                "proposals, which nothing here can")
+        if c.kv_quant:
+            raise ValueError(
+                why + "kv_quant (int8 K/V pages) is not wired for a "
+                "model whose pools hold only some of its layers")
 
     def _commit(self, tree):
         """Device arrays for ``tree``; on a pinned replica every leaf
@@ -686,17 +843,21 @@ class DecodeEngine:
         return attend
 
     def _token_step_body(self, model, weights, pools, tokens, positions,
-                         page_table, write_page, write_off):
+                         page_table, write_page, write_off, mix=None):
         """One single-token step of ``model`` over ``pools``, the token
         written THIS step attended with the slot's history.  Shared
         VERBATIM by the target step and the draft proposal burst so both
-        read the cache through one formulation.  -> (logits, pools)."""
+        read the cache through one formulation.  -> (logits, pools).
+        ``mix`` (a ``_Mixers``) takes the attention for a model with
+        ``layer_kinds`` (``pools`` is then its ``(pools, recurrent)``)."""
         from ..ops.pallas_decode_attention import paged_decode_attention
 
-        return model.forward(
-            weights, tokens, positions, pools, self._paged_attend(
-                paged_decode_attention, page_table, positions + 1,
-                write_page, write_off))
+        attend = self._paged_attend(
+            paged_decode_attention, page_table, positions + 1,
+            write_page, write_off)
+        if mix is not None:
+            mix.attend, attend = attend, mix
+        return model.forward(weights, tokens, positions, pools, attend)
 
     def _build_step_fn(self, model):
         import jax
@@ -709,12 +870,24 @@ class DecodeEngine:
         # keep their names (the programs are jit_step, jit_prefill, ...)
         row = self._step_row
         page_size = self._cache.config.page_size
+        mixed = self._mixed
 
         @jax.named_scope("decode_step")
         def step(state, weights, packed):
             a = _unpack(packed, row)                        # fields [S]
             positions, page_table = a["position"], a["pages"]
             live = (a["flags"] & _LIVE) != 0
+
+            def recur(token_fn, rows, rec):
+                """Every slot's state one token on; a dead slot's row (a
+                prefill ahead of this step may just have filled it)
+                stays as it is."""
+                out, new = token_fn(rows, rec)
+                return out, {
+                    n: jnp.where(live.reshape((-1,) + (1,) * (v.ndim - 1)),
+                                 new[n].astype(v.dtype), v)
+                    for n, v in rec.items()}
+
             # where this step's K/V land: the table's page for the
             # position; page 0, offset 0 (trash) for a dead slot and
             # for a cache-hit first step
@@ -723,14 +896,29 @@ class DecodeEngine:
                 page_table, (positions // page_size)[:, None],
                 axis=1)[:, 0], 0)
             write_off = jnp.where(write, positions % page_size, 0)
-            logits, pools = self._token_step_body(
-                model, weights, _split_state(state), a["token"],
-                positions, page_table, write_page, write_off)
+            if mixed is None:
+                logits, pools = self._token_step_body(
+                    model, weights, _split_state(state), a["token"],
+                    positions, page_table, write_page, write_off)
+                new_state = _join_state(pools)
+            else:
+                mix = _Mixers(mixed, recur, live)
+                logits, cache = self._token_step_body(
+                    model, weights, mixed.split(state), a["token"],
+                    positions, page_table, write_page, write_off, mix=mix)
+                new_state = mixed.join(cache)
             keys = jax.vmap(jax.random.fold_in)(a["key"], a["counter"])
             nxt = sample_tokens(keys, logits, a["temperature"],
                                 a["top_k"], a["top_p"])
             nxt = jnp.where(live, nxt, 0)
-            return (nxt, logits), _join_state(pools)
+            if mixed is None:
+                return (nxt, logits), new_state
+            # the model's counters ride the tokens' read-back: the
+            # step's one sync reads them too
+            tallies = [jnp.asarray(mix.counts[n], jnp.int32).reshape(1)
+                       for n in mixed.tallies]
+            return (jnp.concatenate([nxt] + tallies), logits,
+                    mix.recorded()), new_state
 
         return jax.jit(step, donate_argnums=(0,))
 
@@ -738,8 +926,8 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..ops.pallas_decode_attention import \
-            decode_attention_reference
+        from ..ops.pallas_decode_attention import (
+            decode_attention_reference, grouped_causal_attention)
         from ..ops.sampling_ops import sample_tokens
 
         cc = self._cache.config
@@ -747,7 +935,8 @@ class DecodeEngine:
         n_bp = t_pad // cc.page_size
         cdt = cc.dtype
 
-        row = _prefill_row(t_pad, cc.pages_per_slot)
+        mixed = self._mixed if model is self.model else None
+        row = _prefill_row(t_pad, cc.pages_per_slot, slot=mixed is not None)
 
         @jax.named_scope("prefill_full")
         def prefill(state, weights, packed):
@@ -756,6 +945,33 @@ class DecodeEngine:
             positions = jnp.arange(t_pad, dtype=jnp.int32)
             row_lengths = positions + 1
             shape = (t_max, model.num_heads, model.head_dim)
+
+            def recur(token_fn, rows, rec):
+                """The prompt's tokens one after another from the zero
+                state (a token scan: a chunked form is what a later
+                change owes this), its ``length`` real ones only, so
+                padding rows never touch the state; what the last one
+                leaves goes into the slot's rows of the slabs."""
+                state0 = {n: jnp.zeros((1,) + v.shape[1:], v.dtype)
+                          for n, v in rec.items()}
+                at = lambda t: {n: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+                    v, t, 1, axis=0) for n, v in rows.items()}
+                out = jax.eval_shape(token_fn, at(0), state0)[0]
+
+                def token(t, carry):
+                    st, outs = carry
+                    o, new = token_fn(at(t), st)
+                    return ({n: new[n].astype(v.dtype)
+                             for n, v in st.items()},
+                            jax.lax.dynamic_update_slice_in_dim(
+                                outs, o, t, axis=0))
+
+                st, outs = jax.lax.fori_loop(
+                    0, length, token,
+                    (state0, jnp.zeros((t_pad,) + out.shape[1:], out.dtype)))
+                return outs, {
+                    n: jax.lax.dynamic_update_slice_in_dim(
+                        v, st[n], a["slot"], axis=0) for n, v in rec.items()}
 
             def attend(l, q, k, v, pools):                  # [T_pad, H, D]
                 k_pages, v_pages, k_scales, v_scales = pools
@@ -777,6 +993,11 @@ class DecodeEngine:
                     vl = kv_cache.dequantize_kv(vq, vsc, cdt)
                 else:
                     kl, vl = k.astype(cdt), v.astype(cdt)
+                if q.shape[-2] != k.shape[-2]:
+                    # grouped-query heads: the prompt's own width, the
+                    # pages' dtype; no bitwise contract to keep (bf16)
+                    return grouped_causal_attention(q, kl, vl), (
+                        k_pages, v_pages, k_scales, v_scales)
                 kf = jnp.zeros(shape, cdt).at[:t_pad].set(kl)
                 vf = jnp.zeros(shape, cdt).at[:t_pad].set(vl)
                 ctx = decode_attention_reference(
@@ -785,16 +1006,25 @@ class DecodeEngine:
                     row_lengths)
                 return ctx, (k_pages, v_pages, k_scales, v_scales)
 
-            logits, pools = model.forward(                  # [T_pad, V]
-                weights, tokens, positions, _split_state(state),
-                attend)
+            if mixed is None:
+                logits, pools = model.forward(              # [T_pad, V]
+                    weights, tokens, positions, _split_state(state),
+                    attend)
+                new_state = _join_state(pools)
+            else:
+                mix = _Mixers(mixed, recur, positions < length, attend)
+                logits, cache = model.forward(
+                    weights, tokens, positions, mixed.split(state), mix)
+                new_state = mixed.join(cache)
             last = jax.lax.dynamic_index_in_dim(
                 logits, length - 1, 0, keepdims=False)
             key0 = jax.random.fold_in(a["key"], 0)
             tok = sample_tokens(key0[None], last[None],
                                 a["temperature"][None], a["top_k"][None],
                                 a["top_p"][None])[0]
-            return (tok, last), _join_state(pools)
+            if mixed is None:
+                return (tok, last), new_state
+            return (tok, last, mix.recorded()), new_state
 
         return jax.jit(prefill, donate_argnums=(0,))
 
@@ -977,6 +1207,13 @@ class DecodeEngine:
         c = self.config
         if not prompt:
             raise ValueError("prompt must hold at least one token id")
+        if (extract_kv or kv_import is not None) \
+                and self._cache.recurrent is not None:
+            raise ValueError(
+                "disaggregated serving hands a prompt over as its K/V "
+                "pages (extract_kv / kv_import); this model's recurrent "
+                "layers keep state that is not in any page, so the "
+                "hand-over would decode from the wrong state")
         if kv_import is not None:
             # migrated admission (serving/disagg.py): validate the
             # payload against THIS engine's pool geometry at submit
@@ -1106,6 +1343,7 @@ class DecodeEngine:
         stat_set("decode_kv_quant_enabled",
                  1 if self.config.kv_quant else 0)
         stat_set("decode_kv_page_bytes", self._cache.config.page_bytes())
+        stat_set("decode_state_bytes", self._cache.state_bytes())
         return self
 
     def stop(self, drain: bool = True):
@@ -1256,6 +1494,10 @@ class DecodeEngine:
         phase plan and the hit-rate accounting."""
         req = st.req
         n = len(req.prompt)
+        if self._cache.prefix_bypassed:
+            # asked for, and left out: a model with recurrent layers
+            # admits every request as fresh (serving/kv_cache.py)
+            stat_add("decode_prefix_bypassed")
         self._hit_pages += info.hit_pages
         self._prompt_pages += info.prompt_pages
         if info.hit_pages:
@@ -1481,12 +1723,17 @@ class DecodeEngine:
         return finishes
 
     def _prefill_args(self, t_pad: int, prompt, pages=0, key=0,
-                      temperature=0.0, top_k=0, top_p=1.0) -> np.ndarray:
+                      temperature=0.0, top_k=0, top_p=1.0,
+                      slot=0) -> np.ndarray:
         """Everything one whole-prompt prefill takes after its weights,
         as the int32 words of one ``_prefill_row`` record (host).  The
-        defaults are a greedy request that writes the trash page."""
+        defaults are a greedy request that writes the trash page (and,
+        where the model keeps recurrent state, slot 0's rows)."""
         rec = np.zeros(1, _prefill_row(
-            t_pad, self._cache.config.pages_per_slot))
+            t_pad, self._cache.config.pages_per_slot,
+            slot=self._mixed is not None))
+        if self._mixed is not None:
+            rec["slot"] = slot
         rec["tokens"][0, :len(prompt)] = prompt
         rec["length"] = len(prompt)
         rec["pages"] = pages
@@ -1516,12 +1763,14 @@ class DecodeEngine:
                 up = _Uploads()
                 packed = up(self._prefill_args(
                     t_pad, req.prompt, self._cache.page_table[slot],
-                    st.base_key, req.temperature, req.top_k, req.top_p))
+                    st.base_key, req.temperature, req.top_k, req.top_p,
+                    slot=slot))
                 up.record()
             with otrace.span("serving/prefill_dispatch", **attrs):
-                tok, last = self._exe.run_persistent(
+                tok, last, *recorded = self._exe.run_persistent(
                     self._prefill_fn(t_pad), self._state_vars,
                     args=(self.weights, packed), scope=self._scope)
+                recorded = recorded[0] if recorded else {}
                 if st.spec:
                     # mirror the prefill into the draft's pools (same
                     # page ids, the same uploaded arguments) so
@@ -1554,6 +1803,9 @@ class DecodeEngine:
                     self._cache.lengths[slot] = len(req.prompt)
                     if req.record_logits:
                         req.logits_trace.append(np.asarray(last))
+                        for name, rows in recorded.items():
+                            req.records.setdefault(name, []).append(
+                                np.asarray(rows)[:len(req.prompt)])
                     self._deliver(slot, first)
             except Exception as e:  # noqa: BLE001
                 failed(e)
@@ -1943,9 +2195,10 @@ class DecodeEngine:
 
         try:
             with otrace.span("serving/step_dispatch", **attrs):
-                nxt, logits = self._exe.run_persistent(
+                nxt, logits, *recorded = self._exe.run_persistent(
                     self._step_fn, self._state_vars, args=args,
                     scope=self._scope)
+                recorded = recorded[0] if recorded else {}
         except Exception as e:  # noqa: BLE001
             failed(e)
             return lambda: None
@@ -1960,7 +2213,12 @@ class DecodeEngine:
             stat_time("decode_step_seconds", time.monotonic() - t0)
             self._decode_steps += 1
             with otrace.span("serving/step_deliver", **attrs):
-                logits_np = None
+                # behind the slots' tokens, the model's counters of
+                # this step (a model with ``layer_kinds``)
+                for name, n in zip(self._tallies,
+                                   tokens[len(self._slots):]):
+                    stat_add(name, int(n))
+                logits_np = recorded_np = None
                 for i in live_idx:
                     st = self._slots[i]
                     st.write_trash_once = False
@@ -1970,7 +2228,12 @@ class DecodeEngine:
                     if st.req.record_logits:
                         if logits_np is None:
                             logits_np = np.asarray(logits)
+                            recorded_np = {n: np.asarray(v)
+                                           for n, v in recorded.items()}
                         st.req.logits_trace.append(logits_np[i].copy())
+                        for name, rows in recorded_np.items():
+                            st.req.records.setdefault(name, []).append(
+                                rows[i].copy())
                     self._deliver(i, int(tokens[i]))
                 stat_set("decode_slot_occupancy", self.live_slots)
                 stat_add("decode_steps")
@@ -2147,7 +2410,14 @@ class DecodeEngine:
         else:
             scratch = (jnp.zeros(shape, cc.dtype),
                        jnp.zeros(shape, cc.dtype))
-        (tok, last), _ = self._prefill_fn(t_pad, quantized=qz)(
+        if self._cache.recurrent is not None:
+            # one slot's worth of throwaway state rows
+            rec = self._cache.recurrent
+            scratch += tuple(
+                jnp.zeros((1,) + rshape, rdtype)
+                for _ in range(rec.num_layers)
+                for rshape, rdtype in rec.arrays.values())
+        (_tok, last, *_), _ = self._prefill_fn(t_pad, quantized=qz)(
             scratch, self.weights, self._prefill_args(t_pad, tokens))
         return np.asarray(last)
 

@@ -1,0 +1,254 @@
+"""A served model whose layers are of two kinds: softmax attention with
+grouped-query heads and an output gate, and gated-delta-rule linear
+attention (a decay a channel, a short causal convolution) that keeps a
+fixed-size recurrent state a request instead of keys; every layer's
+feed-forward is a mixture of experts of which this chip HOLDS A SHARE
+(``ops/moe_ops.py`` ``moe_share_*``) beside a shared expert.  The
+architecture is Solar-Open2-250B's; the equations are in the reference's
+docstring (``benchmark/reference/hybrid_moe_lm.py``, a copy in
+``tests/``), which this file is tested against and shares no code with.
+
+It sits behind ``DecodeEngine`` on the contract in that class's
+docstring, like ``transformer_lm.py``: ``forward(weights, tokens,
+positions, cache, attend)``.  What it declares beyond the reference
+model's attributes: ``layer_kinds`` (``"attention"`` or ``"recurrent"`` a
+layer), ``num_kv_heads``, ``recurrent_state`` (one slot's state of one
+recurrent layer, ``{name: (shape, dtype)}``), ``tallies`` (the counters
+``forward`` adds to, by name).  What it asks of ``attend``
+beyond the call: ``attend.recur`` runs a recurrent layer's one-token
+update over the rows' state, ``attend.live`` masks dead and padding rows
+out of the routing, ``attend.tally`` and ``attend.record`` take the
+routing counts and the chosen expert ids.
+
+Precision as served: weights (and K/V pages) in ``dtype`` (bfloat16),
+every matmul accumulating in float32; the residual stream, norms, router
+scores, softmax, the gates and THE RECURRENT STATE in float32.
+"""
+from __future__ import annotations
+
+import functools
+import math
+from typing import Sequence
+
+import numpy as np
+
+from ..ops import moe_ops
+
+KDA_SCOPE = "kda_update"
+
+
+class HybridMoELM:
+    """Sized by constructor arguments; ``layer_kinds`` is the pattern
+    (Solar-Open2: one ``"attention"`` then three ``"recurrent"`` a
+    period).  ``held_experts`` are the routed-expert ids this chip holds
+    of ``num_experts``; the router keeps its full width."""
+
+    def __init__(self, vocab_size: int, d_model: int,
+                 layer_kinds: Sequence[str], num_heads: int,
+                 num_kv_heads: int, head_dim: int, lin_heads: int,
+                 lin_head_dim: int, conv_kernel: int, gate_rank: int,
+                 num_experts: int, top_k: int,
+                 held_experts: Sequence[int], expert_dim: int,
+                 shared_dim: int, rms_eps: float = 1e-5,
+                 dtype="bfloat16", max_seq_len: int = 1 << 20):
+        self.vocab_size, self.d_model = int(vocab_size), int(d_model)
+        self.layer_kinds = tuple(layer_kinds)
+        bad = set(self.layer_kinds) - {"attention", "recurrent"}
+        if bad or not self.layer_kinds:
+            raise ValueError(f"layer_kinds holds {sorted(bad) or 'nothing'}")
+        self.num_layers = len(self.layer_kinds)
+        self.num_heads, self.num_kv_heads = int(num_heads), int(num_kv_heads)
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError("num_heads must be a multiple of num_kv_heads")
+        self.head_dim = int(head_dim)
+        self.lin_heads, self.lin_head_dim = int(lin_heads), int(lin_head_dim)
+        self.conv_kernel, self.gate_rank = int(conv_kernel), int(gate_rank)
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.held_experts = tuple(int(e) for e in held_experts)
+        if not self.held_experts or min(self.held_experts) < 0 \
+                or max(self.held_experts) >= self.num_experts \
+                or len(set(self.held_experts)) != len(self.held_experts):
+            raise ValueError(
+                f"held_experts must be distinct ids below {num_experts}")
+        self.expert_dim, self.shared_dim = int(expert_dim), int(shared_dim)
+        self.rms_eps = float(rms_eps)
+        self.dtype = str(dtype)
+        self.max_seq_len = int(max_seq_len)     # no positional table
+        # the counters forward adds to through attend.tally
+        self.tallies = ("moe_local_assignments", "moe_experts_hit")
+        c = self.lin_heads * self.lin_head_dim
+        # one slot's state of ONE recurrent layer: the delta rule's
+        # matrix a head, and the K-1 positions the convolution looks
+        # back on, oldest first, side by side in one lane-dense row
+        self.recurrent_state = {
+            "s": ((self.lin_heads, self.lin_head_dim, self.lin_head_dim),
+                  np.float32),
+            "tail": (((self.conv_kernel - 1) * 3 * c,), np.float32)}
+
+    # -- weights ------------------------------------------------------------
+    def init_weights(self, key):
+        """Seeded weights at variance-preserving scales; the decay's
+        ``A_log``/``dt_bias`` as the gated linear-attention families set
+        them (rates 1..16, steps 1e-3..1e-1: decays 0.2..0.999)."""
+        import jax
+        import jax.numpy as jnp
+
+        dt = jnp.dtype(self.dtype)
+        dm, v = self.d_model, self.vocab_size
+        hq = self.num_heads * self.head_dim
+        hkv = self.num_kv_heads * self.head_dim
+        c = self.lin_heads * self.lin_head_dim
+        r, e, f = self.gate_rank, self.num_experts, self.expert_dim
+        nf = len(self.held_experts) * f
+        keys = iter(jax.random.split(key, 4 + 24 * self.num_layers))
+
+        def dense(shape, scale=None, dtype=dt):
+            scale = 1.0 / math.sqrt(shape[0]) if scale is None else scale
+            return (jax.random.normal(next(keys), shape, jnp.float32)
+                    * scale).astype(dtype)
+
+        ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
+        w = {"tok_emb": dense((v, dm), 1.0), "lm_head": dense((dm, v)),
+             "norm_f": ones(dm), "layers": []}
+        for kind in self.layer_kinds:
+            lw = {"norm1": ones(dm), "norm2": ones(dm)}
+            if kind == "attention":
+                lw.update(wq=dense((dm, hq)), wk=dense((dm, hkv)),
+                          wv=dense((dm, hkv)), wg=dense((dm, hq)),
+                          wo=dense((hq, dm)))
+            else:
+                rate = jax.random.uniform(next(keys), (self.lin_heads,),
+                                          jnp.float32, 1.0, 16.0)
+                step = jnp.exp(jax.random.uniform(
+                    next(keys), (c,), jnp.float32,
+                    math.log(1e-3), math.log(1e-1)))
+                lw.update(
+                    kda_wqkv=dense((dm, 3 * c)),
+                    kda_conv=dense((self.conv_kernel, 3 * c),
+                                   1.0 / math.sqrt(self.conv_kernel),
+                                   jnp.float32),
+                    kda_a_log=jnp.log(rate),
+                    # softplus^-1(step)
+                    kda_dt_bias=step + jnp.log(-jnp.expm1(-step)),
+                    kda_wa_down=dense((dm, r)), kda_wa_up=dense((r, c)),
+                    kda_wbeta=dense((dm, self.lin_heads)),
+                    kda_wo_down=dense((dm, r)), kda_wo_up=dense((r, c)),
+                    kda_onorm=ones(self.lin_head_dim),
+                    kda_wout=dense((c, dm)))
+            lw.update(
+                moe_router=dense((dm, e), dtype=jnp.float32),
+                moe_router_bias=jnp.zeros((e,), jnp.float32),
+                moe_w_gate=dense((dm, nf)), moe_w_up=dense((dm, nf)),
+                moe_w_down=dense((nf, dm), 1.0 / math.sqrt(f)),
+                shared_w_gate=dense((dm, self.shared_dim)),
+                shared_w_up=dense((dm, self.shared_dim)),
+                shared_w_down=dense((self.shared_dim, dm)))
+            w["layers"].append(lw)
+        return w
+
+    # -- the block ------------------------------------------------------------
+    def forward(self, weights, tokens, positions, cache, attend):
+        """Logits for ``tokens`` (``[S]`` one token a slot, ``[T]`` one
+        prompt) -> ``(logits [..., V], cache)``; ``positions`` are not
+        read (no positional term).  See the module header for what
+        ``attend`` carries."""
+        import jax
+        import jax.numpy as jnp
+
+        w = weights
+        x = w["tok_emb"][tokens].astype(jnp.float32)
+        lead = x.shape[:-1]
+        for l, kind in enumerate(self.layer_kinds):
+            lw = w["layers"][l]
+            h = self._rms(x, lw["norm1"])
+            if kind == "attention":
+                q = _mm(h, lw["wq"]).reshape(*lead, self.num_heads,
+                                             self.head_dim)
+                k = _mm(h, lw["wk"]).reshape(*lead, self.num_kv_heads,
+                                             self.head_dim)
+                v = _mm(h, lw["wv"]).reshape(*lead, self.num_kv_heads,
+                                             self.head_dim)
+                ctx, cache = attend(l, q, k, v, cache)
+                y = _mm(ctx.reshape(*lead, -1).astype(jnp.float32)
+                        * jax.nn.sigmoid(_mm(h, lw["wg"])), lw["wo"])
+            else:
+                rows = {"u": _mm(h, lw["kda_wqkv"]),
+                        "gate": _mm(_mm(h, lw["kda_wa_down"]),
+                                    lw["kda_wa_up"]),
+                        "beta": _mm(h, lw["kda_wbeta"])}
+                o, cache = attend.recur(
+                    l, functools.partial(self._kda_token, lw), rows, cache)
+                o = o * jax.lax.rsqrt(jnp.mean(
+                    o * o, -1, keepdims=True) + self.rms_eps) \
+                    * lw["kda_onorm"]
+                y = _mm(o.reshape(*lead, -1) * jax.nn.sigmoid(_mm(
+                    _mm(h, lw["kda_wo_down"]), lw["kda_wo_up"])),
+                    lw["kda_wout"])
+            x = x + y
+            h = self._rms(x, lw["norm2"])
+            ids, _, local = moe_ops.moe_share_route(
+                h, lw["moe_router"], lw["moe_router_bias"],
+                top_k=self.top_k, held_ids=self.held_experts,
+                live=attend.live)
+            assigned, hit = moe_ops.moe_share_counts(local)
+            attend.tally("moe_local_assignments", assigned)
+            attend.tally("moe_experts_hit", hit)
+            attend.record("moe_topk", ids)
+            with jax.named_scope("moe_shared"):
+                shared = _mm(jax.nn.silu(_mm(h, lw["shared_w_gate"]))
+                             * _mm(h, lw["shared_w_up"]),
+                             lw["shared_w_down"])
+            x = x + moe_ops.moe_share_ffn(
+                h, local, lw["moe_w_gate"], lw["moe_w_up"],
+                lw["moe_w_down"]) + shared
+        return _mm(self._rms(x, w["norm_f"]), w["lm_head"]), cache
+
+    def _rms(self, x, g):
+        import jax
+        import jax.numpy as jnp
+
+        return x * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=-1, keepdims=True) + self.rms_eps) * g
+
+    def _kda_token(self, lw, rows, state):
+        """One token a row through a recurrent layer: ``rows`` the
+        token's projections (``u [R, 3C]`` before the convolution,
+        ``gate [R, C]``, ``beta [R, heads]``), ``state`` the rows' state
+        BEFORE it (``s [R, heads, dk, dv]``, ``tail [R, (K-1)*3C]``) ->
+        (``o [R, heads, dv]``, the state after it).  All float32.  The
+        state is read twice and written once: ``S'^T k`` and ``S'^T q``
+        come out of one pass, and ``o = S'^T q + (k.q) b (v - S'^T k)``
+        is ``S_t^T q`` without a third."""
+        import jax
+        import jax.numpy as jnp
+
+        nh, dk = self.lin_heads, self.lin_head_dim
+        with jax.named_scope(KDA_SCOPE):
+            c3 = rows["u"].shape[-1]
+            window = jnp.concatenate([state["tail"], rows["u"]], axis=1)
+            conv = sum(window[:, j * c3:(j + 1) * c3] * lw["kda_conv"][j]
+                       for j in range(self.conv_kernel))
+            q, k, v = jnp.moveaxis(jax.nn.silu(conv).reshape(
+                -1, 3, nh, dk), 1, 0)
+            q = q * jax.lax.rsqrt(
+                jnp.sum(q * q, -1, keepdims=True) + 1e-6) / math.sqrt(dk)
+            k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+            decay = jnp.exp(
+                -jnp.exp(lw["kda_a_log"])[:, None] * jax.nn.softplus(
+                    rows["gate"] + lw["kda_dt_bias"]).reshape(-1, nh, dk))
+            beta = 2.0 * jax.nn.sigmoid(rows["beta"])          # [R, nh]
+            s = decay[..., None] * state["s"]                  # Diag(a) S
+            ks = jnp.sum(k[..., None] * s, axis=-2)            # S'^T k
+            qs = jnp.sum(q[..., None] * s, axis=-2)            # S'^T q
+            delta = beta[..., None] * (v - ks)
+            s = s + k[..., None] * delta[..., None, :]
+            o = qs + jnp.sum(q * k, -1, keepdims=True) * delta
+        return o, {"s": s, "tail": window[:, c3:]}
+
+
+def _mm(a, w):
+    """``a @ w`` at the weight's dtype in, float32 out."""
+    import jax.numpy as jnp
+
+    return jnp.matmul(a.astype(w.dtype), w,
+                      preferred_element_type=jnp.float32)

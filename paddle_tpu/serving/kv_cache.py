@@ -73,6 +73,23 @@ host bookkeeping, export/install and copy-on-write index; they never
 see the fold.  A shape that cannot be lane-dense still works, only
 padded: ``CacheConfig.lane_dense`` says which.
 
+**Two kinds of state, one allocator** (``RecurrentSpec``).  A model
+whose layers are not all softmax attention keeps, for the others, a
+FIXED-SIZE state a request instead of keys: a linear-attention layer's
+``[heads, d_k, d_v]`` matrix and the few positions its short convolution
+looks back on.  The pools then hold the attention layers only (``layer``
+above counts those), and each recurrent layer's state lives in slabs
+indexed by SLOT, ``[num_slots, *shape]`` one array a layer and name,
+beside the pools in the same scope and threaded through the same donated
+programs: the slot is the allocation, claimed and released with the
+slot's pages by the one ``claim``/``release``.  A slab row needs no
+clearing: the whole-prompt prefill that opens a request starts from zero
+state and overwrites the row with the state its last token left.  Such
+a cache has NO prefix index: a page of keys says nothing about the state
+three quarters of the layers reached after the same tokens, so every
+request is admitted fresh (``prefix_bypassed`` says the index was asked
+for and left out; the engine counts the admissions).
+
 **Quantized storage** (``FLAGS_decode_kv_quant``): pages are stored
 int8 (same folded rows) beside parallel scale pools ``[layers, pages,
 page_size, heads]``
@@ -244,6 +261,32 @@ class CacheConfig:
         """Total device bytes of the page arrays (k + v, scale pools
         included when quantized)."""
         return self.num_pages * self.per_page_pool_bytes()
+
+
+class RecurrentSpec:
+    """The per-slot state of a model's layers that keep state instead
+    of keys: ``num_layers`` such layers, each holding the ``arrays``
+    ``{name: (shape, dtype)}`` of ONE slot (``PagedKVCache`` allocates
+    ``[num_slots, *shape]`` a layer and name)."""
+
+    def __init__(self, num_layers: int, arrays):
+        self.num_layers = int(num_layers)
+        self.arrays = {str(n): (tuple(int(d) for d in shape),
+                                np.dtype(dtype))
+                       for n, (shape, dtype) in arrays.items()}
+
+    def var_names(self) -> Tuple[str, ...]:
+        """Scope names, layer-major, a layer's arrays in ``arrays``'
+        order."""
+        return tuple(f"__decode_state_{name}_{i}__"
+                     for i in range(self.num_layers)
+                     for name in self.arrays)
+
+    def slot_bytes(self) -> int:
+        """Device bytes one slot's state costs over all layers."""
+        return self.num_layers * sum(
+            int(np.prod(shape)) * dtype.itemsize
+            for shape, dtype in self.arrays.values())
 
 
 class PageAllocator:
@@ -457,11 +500,19 @@ class PagedKVCache:
     prefix index) + the device page arrays, which live in ``scope`` so
     Executor.run_persistent can donate them through each decode step."""
 
-    def __init__(self, config: CacheConfig, scope, prefix_cache=True):
+    def __init__(self, config: CacheConfig, scope, prefix_cache=True,
+                 recurrent: Optional[RecurrentSpec] = None):
         import jax.numpy as jnp
 
         self.config = config
         self.scope = scope
+        # slot-indexed slabs of the layers that keep state instead of
+        # keys (module header); with them there is no prefix index
+        self.recurrent = recurrent if recurrent is not None \
+            and recurrent.num_layers else None
+        self.prefix_bypassed = bool(prefix_cache) \
+            and self.recurrent is not None
+        prefix_cache = bool(prefix_cache) and self.recurrent is None
         # optional per-request tracing hook: ``on_event(slot, name,
         # **attrs)`` fired on cache lifecycle events (cow_swap, evict,
         # register) — the decode engine wires it to the owning
@@ -508,14 +559,31 @@ class PagedKVCache:
             scope.set_var(V_SCALES_VAR,
                           jnp.full(sshape, SCALE_EPS, c.scale_dtype))
             self.scale_vars = [K_SCALES_VAR, V_SCALES_VAR]
+        if self.recurrent is not None:
+            spec = self.recurrent
+            for var, (rshape, rdtype) in zip(
+                    spec.var_names(),
+                    list(spec.arrays.values()) * spec.num_layers):
+                scope.set_var(var, jnp.zeros((c.num_slots,) + rshape,
+                                             rdtype))
 
     def state_var_names(self) -> Tuple[str, ...]:
         """Scope names a persistent step must thread (in order): the
-        two page pools, plus the scale pools when quantized."""
+        two page pools, plus the scale pools when quantized, then the
+        recurrent layers' slabs."""
         names = (K_PAGES_VAR, V_PAGES_VAR)
         if self.config.quantized:
             names += (K_SCALES_VAR, V_SCALES_VAR)
-        return names
+        return names + self.recurrent_var_names()
+
+    def recurrent_var_names(self) -> Tuple[str, ...]:
+        return self.recurrent.var_names() if self.recurrent is not None \
+            else ()
+
+    def state_bytes(self) -> int:
+        """Device bytes of the recurrent layers' slabs, all slots."""
+        return self.config.num_slots * self.recurrent.slot_bytes() \
+            if self.recurrent is not None else 0
 
     def _fire(self, slot, name, **attrs) -> None:
         hook = self.on_event
@@ -703,6 +771,10 @@ class PagedKVCache:
         guaranteed by jax dispatch order, and its result never aliases
         a pool buffer, so the payload survives the source's next
         step."""
+        if self.recurrent is not None:
+            raise ValueError(
+                "a cache with recurrent state exports no pages: the "
+                "state of the layers without keys is not in them")
         idx = np.asarray([int(p) for p in pages], np.int32)
         return {name: self.scope.get_var(name)[:, idx]
                 for name in self.state_var_names()}

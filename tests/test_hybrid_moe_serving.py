@@ -1,0 +1,311 @@
+"""The hybrid linear/softmax-attention model with a held share of its
+experts (``serving/hybrid_moe_lm.py``) behind the real ``DecodeEngine``,
+against the plain reference (``tests/reference_hybrid_moe_lm.py``, a copy
+of ``benchmark/reference/hybrid_moe_lm.py``): float32, seeded, tiny."""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.monitor import stat_get
+from paddle_tpu.ops import moe_ops
+from paddle_tpu.ops import pallas_decode_attention as pda
+from paddle_tpu.serving import DecodeConfig, DecodeEngine
+from paddle_tpu.serving.hybrid_moe_lm import HybridMoELM
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+import reference_hybrid_moe_lm as ref  # noqa: E402
+
+PERIOD = ("attention", "recurrent", "recurrent", "recurrent")
+VOCAB = 97
+
+
+def make_model(kinds=PERIOD, held=(0, 1, 2, 3, 4), **kw):
+    sizes = dict(vocab_size=VOCAB, d_model=32, layer_kinds=kinds,
+                 num_heads=4, num_kv_heads=2, head_dim=8, lin_heads=2,
+                 lin_head_dim=8, conv_kernel=4, gate_rank=4, num_experts=16,
+                 top_k=4, held_experts=held, expert_dim=16, shared_dim=16,
+                 dtype="float32")
+    sizes.update(kw)
+    return HybridMoELM(**sizes)
+
+
+def dims(m, held=None):
+    return dict(num_heads=m.num_heads, num_kv_heads=m.num_kv_heads,
+                head_dim=m.head_dim, lin_heads=m.lin_heads,
+                lin_head_dim=m.lin_head_dim, conv_kernel=m.conv_kernel,
+                top_k=m.top_k, held=list(held or m.held_experts),
+                expert_dim=m.expert_dim, eps=m.rms_eps,
+                kinds=list(m.layer_kinds))
+
+
+def engine(model, weights, **cfg):
+    cfg = dict(dict(slots=3, max_seq_len=64, page_size=8), **cfg)
+    return DecodeEngine(model, weights, DecodeConfig(**cfg))
+
+
+def served_vs_reference(eng, model, weights, prompts, n_new=5):
+    """Worst |dlogit| over the prompts' prefill and decode positions,
+    the reference given the server's own tokens (its own routing)."""
+    reqs = [eng.submit(p, max_new_tokens=n_new, record_logits=True)
+            for p in prompts]
+    worst = 0.0
+    for p, r in zip(prompts, reqs):
+        toks = r.result(timeout=300)
+        got = np.stack(r.logits_trace)
+        want, _ = ref.forward_logits(
+            weights, jnp.asarray(p + toks[:-1], jnp.int32), dims(model))
+        assert got.shape == (n_new, VOCAB)
+        worst = max(worst, float(np.abs(
+            got - np.asarray(want)[len(p) - 1:]).max()))
+        # the recorded routing is the reference's own: prefill rows then
+        # one row a step, [positions, layers, k]
+        routed = r.records["moe_topk"]
+        ids = np.concatenate([routed[0]] + [x[None] for x in routed[1:]])
+        assert ids.shape == (len(p) + n_new - 1, model.num_layers,
+                             model.top_k)
+        _, gap = ref.forward_logits(
+            weights, jnp.asarray(p + toks[:-1], jnp.int32), dims(model),
+            routing=jnp.asarray(ids))
+        assert float(gap.max()) == 0.0
+    return worst
+
+
+@pytest.mark.parametrize("kinds", [("attention",), ("recurrent",), PERIOD],
+                         ids=["softmax", "kda", "period"])
+def test_prefill_then_decode_matches_the_reference(kinds):
+    model = make_model(kinds)
+    weights = model.init_weights(jax.random.PRNGKey(1))
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(0, VOCAB, n).tolist() for n in (5, 19, 11, 8)]
+    with engine(model, weights) as eng:
+        assert served_vs_reference(eng, model, weights, prompts) < 5e-5
+
+
+def test_paged_kernel_serves_the_grouped_heads_in_interpret_mode():
+    model = make_model(PERIOD)
+    weights = model.init_weights(jax.random.PRNGKey(3))
+    rng = np.random.RandomState(4)
+    prompts = [rng.randint(0, VOCAB, n).tolist() for n in (13, 21)]
+    with engine(model, weights, use_pallas="always", interpret=True) as eng:
+        assert served_vs_reference(eng, model, weights, prompts, 4) < 5e-5
+
+
+def test_a_slots_second_request_sees_none_of_the_firsts_state():
+    model = make_model(PERIOD)
+    weights = model.init_weights(jax.random.PRNGKey(5))
+    rng = np.random.RandomState(6)
+    with engine(model, weights, slots=1) as eng:
+        for n in (23, 6, 17):       # one slot: each reuses the last's rows
+            p = [rng.randint(0, VOCAB, n).tolist()]
+            assert served_vs_reference(eng, model, weights, p) < 5e-5
+
+
+def _state_after_prefill(model, weights, prompt, page_size):
+    with engine(model, weights, slots=2, page_size=page_size) as eng:
+        eng.submit([1, 2, 3], max_new_tokens=1).result(timeout=300)
+        eng.submit(prompt, max_new_tokens=1).result(timeout=300)
+        names = eng._cache.recurrent_var_names()
+        return {n: np.asarray(eng._scope.get_var(n)) for n in names}
+
+
+def test_padding_rows_leave_the_state_alone():
+    """The same 9-token prompt prefilled in a bucket of 16 and in one of
+    32: what the slot's rows hold is the state after token 9, however
+    many padding rows followed it."""
+    model = make_model(("recurrent", "attention"))
+    weights = model.init_weights(jax.random.PRNGKey(7))
+    prompt = np.random.RandomState(8).randint(0, VOCAB, 9).tolist()
+    a = _state_after_prefill(model, weights, prompt, 8)
+    b = _state_after_prefill(model, weights, prompt, 32)
+    assert set(a) == set(b) and len(a) == 2
+    for name in a:
+        assert np.abs(a[name][0]).max() > 0      # slot 0 was written
+        np.testing.assert_allclose(a[name][0], b[name][0], atol=1e-6)
+        assert not a[name][1].any()              # slot 1 never was
+
+
+def test_grouped_query_kernel_against_the_reference_in_interpret_mode():
+    s, hq, hkv, d, page, pps, layers = 3, 8, 2, 8, 8, 4, 2
+    rng = np.random.RandomState(9)
+    q = jnp.asarray(rng.randn(s, hq, d), jnp.float32)
+    k_pages, v_pages = (jnp.asarray(rng.randn(layers, 16, page, hkv * d),
+                                    jnp.float32) for _ in range(2))
+    table = jnp.asarray(rng.permutation(np.arange(1, 13)).reshape(s, pps),
+                        jnp.int32)
+    lengths = jnp.asarray([5, 32, 17], jnp.int32)
+    got = pda.paged_decode_attention(
+        q, k_pages, v_pages, table, lengths, layer=1, use_pallas="always",
+        interpret=True)
+    # query head i reads K/V head i // 4: gather, repeat, attend
+    full = [jnp.repeat(p[1][table].reshape(s, pps * page, hkv, d),
+                       hq // hkv, axis=2) for p in (k_pages, v_pages)]
+    want = pda.decode_attention_reference(q, *full, lengths)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    via_ref = pda.paged_decode_attention(
+        q, k_pages, v_pages, table, lengths, layer=1, use_pallas="never")
+    np.testing.assert_allclose(via_ref, want, atol=2e-5)
+
+
+def _expert(lw, j, f, h):
+    cols = slice(j * f, (j + 1) * f)
+    return (jax.nn.silu(h @ lw["moe_w_gate"][:, cols])
+            * (h @ lw["moe_w_up"][:, cols])) @ lw["moe_w_down"][cols]
+
+
+def test_routing_is_dropless_when_every_row_picks_one_held_expert():
+    model = make_model(("attention",))
+    lw = model.init_weights(jax.random.PRNGKey(10))["layers"][0]
+    h = jax.random.normal(jax.random.PRNGKey(11), (64, 32))
+    # the correction bias lifts expert 3 into every row's top-k: all 64
+    # rows land on it, where a capacity of S*K/E slots would keep 16
+    bias = jnp.zeros((16,)).at[3].set(50.0)
+    ids, _, local = moe_ops.moe_share_route(
+        h, lw["moe_router"], bias, top_k=4, held_ids=model.held_experts)
+    assert bool((ids == 3).any(axis=1).all())
+    assert float(local[:, 3].min()) > 0              # nobody was dropped
+    assigned, hit = moe_ops.moe_share_counts(local)
+    assert int(assigned) >= 64 and 1 <= int(hit) <= 5
+    out = moe_ops.moe_share_ffn(h, local, lw["moe_w_gate"],
+                                lw["moe_w_up"], lw["moe_w_down"])
+    want = sum(local[:, j:j + 1] * _expert(lw, j, 16, h)
+               for j in range(len(model.held_experts)))
+    np.testing.assert_allclose(out, want, atol=1e-4)
+    # the bias ranks, it does not weigh: a row's weights are its plain
+    # scores over the chosen, summing to one over all of them
+    scores = jax.nn.sigmoid(h @ lw["moe_router"])
+    chosen = jnp.take_along_axis(scores, ids, axis=1)
+    np.testing.assert_allclose(
+        local[:, 3], scores[:, 3] / chosen.sum(axis=1), rtol=1e-5)
+    # dead rows choose as any row and give the chip nothing to compute
+    live = jnp.arange(64) % 2 == 0
+    _, _, masked = moe_ops.moe_share_route(
+        h, lw["moe_router"], bias, top_k=4, held_ids=model.held_experts,
+        live=live)
+    assert not np.asarray(masked)[1::2].any()
+    np.testing.assert_allclose(np.asarray(masked)[::2],
+                               np.asarray(local)[::2])
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Four chips hold four experts each of one 16-expert layer: their
+    routed parts, with the shared expert counted once, are what the
+    reference gives for the whole layer."""
+    whole = make_model(("attention",), held=tuple(range(16)))
+    lw = whole.init_weights(jax.random.PRNGKey(12))["layers"][0]
+    x = jax.random.normal(jax.random.PRNGKey(13), (24, 32))
+    want, _ = ref.moe_layer(lw, x, dims(whole))
+    h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5) \
+        * lw["norm2"]
+    total = (jax.nn.silu(h @ lw["shared_w_gate"]) * (h @ lw["shared_w_up"])) \
+        @ lw["shared_w_down"]
+    f = 16
+    for chip in range(4):
+        held = tuple(range(4 * chip, 4 * chip + 4))
+        cols = slice(4 * chip * f, (4 * chip + 4) * f)
+        _, _, local = moe_ops.moe_share_route(
+            h, lw["moe_router"], lw["moe_router_bias"], top_k=4,
+            held_ids=held)
+        part = moe_ops.moe_share_ffn(
+            h, local, lw["moe_w_gate"][:, cols], lw["moe_w_up"][:, cols],
+            lw["moe_w_down"][cols])
+        # the reference given the same share (and no shared expert)
+        share = {**lw, "moe_w_gate": lw["moe_w_gate"][:, cols],
+                 "moe_w_up": lw["moe_w_up"][:, cols],
+                 "moe_w_down": lw["moe_w_down"][cols]}
+        ref_part, _ = ref.moe_layer(share, x, dims(whole), shared=False,
+                                    held=list(held))
+        np.testing.assert_allclose(part, ref_part - x, atol=1e-4)
+        total = total + part
+    np.testing.assert_allclose(x + total, want, atol=1e-4)
+
+
+@pytest.mark.parametrize("cfg, names", [
+    (dict(prefill_chunk_pages=1), "chunked/ragged prefill"),
+    (dict(prefill_chunk_pages=1, ragged_prefill_rows=8),
+     "chunked/ragged prefill"),
+    (dict(spec_k=2), "speculative decoding"),
+    (dict(kv_quant=True), "kv_quant"),
+], ids=["chunked", "ragged", "speculative", "kv_quant"])
+def test_what_cannot_carry_recurrent_state_refuses_by_name(cfg, names):
+    model = make_model(PERIOD)
+    weights = model.init_weights(jax.random.PRNGKey(14))
+    with pytest.raises(ValueError, match=names):
+        engine(model, weights, **cfg)
+
+
+def test_a_draft_model_and_the_disaggregated_hand_over_refuse():
+    from paddle_tpu.serving.decode import TransformerLM
+    from paddle_tpu.serving.disagg import DisaggServer
+
+    model = make_model(PERIOD)
+    weights = model.init_weights(jax.random.PRNGKey(15))
+    draft = TransformerLM(vocab_size=VOCAB, d_model=16, num_layers=1,
+                          num_heads=2, max_seq_len=64)
+    with pytest.raises(ValueError, match="speculative decoding"):
+        DecodeEngine(model, weights, DecodeConfig(
+            slots=2, max_seq_len=64, page_size=8), draft_model=draft,
+            draft_weights=draft.init_weights(jax.random.PRNGKey(0)))
+    with pytest.raises(ValueError, match="disaggregated"):
+        DisaggServer(model, weights, config=DecodeConfig(
+            slots=2, max_seq_len=64, page_size=8))
+    eng = engine(model, weights)
+    with pytest.raises(ValueError, match="extract_kv"):
+        eng.submit([1, 2, 3], max_new_tokens=2, extract_kv=True)
+    with pytest.raises(ValueError, match="exports no pages"):
+        eng._cache.export_pages([1])
+
+
+def test_every_request_is_admitted_fresh_and_the_counts_ride_the_sync():
+    model = make_model(PERIOD)
+    weights = model.init_weights(jax.random.PRNGKey(16))
+    prompt = list(range(1, 25))
+    before = {n: stat_get(n) for n in (
+        "decode_prefix_bypassed", "decode_prefix_pages_hit",
+        "moe_local_assignments", "moe_experts_hit", "decode_steps",
+        "decode_h2d_uploads", "decode_prefills")}
+    with engine(model, weights) as eng:
+        assert eng._cache.prefix is None and eng._cache.prefix_bypassed
+        first = eng.submit(prompt, max_new_tokens=6).result(timeout=300)
+        # the same prompt again: a prefix cache would skip its prefill
+        again = eng.submit(prompt, max_new_tokens=6).result(timeout=300)
+        assert first == again
+        state_bytes = eng._cache.state_bytes()
+    d = {n: stat_get(n) - v for n, v in before.items()}
+    assert d["decode_prefix_bypassed"] == 2 and d["decode_prefills"] == 2
+    assert d["decode_prefix_pages_hit"] == 0
+    # one upload a step and one a prefill, as for any model: the counts
+    # came back with the tokens
+    assert d["decode_h2d_uploads"] == d["decode_steps"] + 2
+    steps = d["decode_steps"]
+    assert 0 < d["moe_experts_hit"] <= steps * 4 * 5
+    assert d["moe_experts_hit"] <= d["moe_local_assignments"] \
+        <= steps * 4 * 4
+    assert stat_get("decode_state_bytes") == state_bytes == 3 * (
+        3 * (2 * 8 * 8 + 3 * 3 * 16) * 4)
+
+
+def test_the_tallies_are_the_models_declared_names_before_any_trace():
+    """The names behind a step's tokens are fixed when the engine is
+    built, from what the model declares: an engine whose step was never
+    traced in this process reads them all the same, and a count the
+    model did not declare fails the trace by name."""
+    model = make_model(PERIOD)
+    weights = model.init_weights(jax.random.PRNGKey(17))
+    eng = engine(model, weights)
+    assert eng._tallies == model.tallies == (
+        "moe_local_assignments", "moe_experts_hit")
+    model.tallies = ("moe_experts_hit",)
+    with pytest.raises(KeyError, match="moe_local_assignments"):
+        engine(model, weights).lower_step()
+
+
+def test_the_two_copies_of_the_reference_are_one():
+    with open(os.path.join(HERE, "reference_hybrid_moe_lm.py")) as a, \
+            open(os.path.join(HERE, "..", "benchmark", "reference",
+                              "hybrid_moe_lm.py")) as b:
+        assert a.read() == b.read()

@@ -375,3 +375,104 @@ def test_pools_keep_one_layout_through_program(one_chip, heads, kv_quant,
     relaid = (cc.pool_shape(row_lanes=cc.num_heads),) \
         if kv_quant and program != "cow" else ()
     _assert_pools_stay_put(lowered.compile(), pools, relaid)
+
+
+# -- the hybrid model: grouped heads, slabs beside the pools ---------------
+
+def test_paged_attention_compiles_with_grouped_query_heads(one_chip):
+    """64 query heads over 8 K/V heads of 128 in bf16 pools of 1,024
+    lanes (Solar-Open2's softmax layer): the group's heads ride as rows,
+    so one-token decode is the kernel at R=8, H=8."""
+    shapes = _paged_shapes(8, 128, 16, 0, False, jnp.bfloat16)
+    shapes[0] = ((SLOTS, 64, 128), jnp.float32)     # 8 query heads a K/V
+    text = _compile(one_chip,
+                    functools.partial(_paged, pda.paged_decode_attention),
+                    *shapes)
+    assert text.count("tpu_custom_call") == 1
+
+
+def _hybrid_engine():
+    """Solar-Open2's head shapes (64 x 128 linear heads cut to 16, 8
+    query over 2 K/V heads of 128), one layer of each kind, narrow
+    everywhere else: only shapes matter to a compile.  128 slots make
+    the layer's matrices 134 MB: a slab of 64 MB was still prefetched
+    whole into fast memory, which would prove nothing."""
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+    from paddle_tpu.serving.hybrid_moe_lm import HybridMoELM
+
+    model = HybridMoELM(
+        vocab_size=512, d_model=512,
+        layer_kinds=("attention", "recurrent"),
+        num_heads=8, num_kv_heads=2, head_dim=128, lin_heads=16,
+        lin_head_dim=128, conv_kernel=4, gate_rank=128, num_experts=32,
+        top_k=8, held_experts=range(4), expert_dim=256, shared_dim=256)
+    weights = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
+    return DecodeEngine(model, weights, DecodeConfig(
+        slots=128, max_seq_len=1024, use_pallas="always",
+        cache_dtype="bfloat16"))
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_recurrent_state_is_updated_in_place(one_chip, program):
+    """The slabs of the recurrent layers beside the pools: every state
+    output is its donated input in the same device layout, and no
+    program holds a slab-sized copy or transposition (the step updates
+    all slots' rows, the prefill one slot's, in place)."""
+    eng = _hybrid_engine()
+    lowered, state = _lower_program(eng, program, one_chip)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (1 if program == "step" else 0)
+    n = len(state)
+    assert n == 2 + 2
+    ins = jax.tree_util.tree_leaves(compiled.input_formats)
+    outs = jax.tree_util.tree_leaves(compiled.output_formats)
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    pairs = {(int(o), int(i)) for o, i in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", alias[1])}
+    assert {(len(outs) - n + i, i) for i in range(n)} <= pairs
+    for i in range(n):
+        assert ins[i].layout == outs[len(outs) - n + i].layout, (
+            i, state[i], ins[i].layout, outs[len(outs) - n + i].layout)
+    # the matrices (a few MB a slot); a convolution tail small enough
+    # is prefetched whole into fast memory, which is no re-layout
+    slabs = {tuple(s) for s in state[2::2]}
+    for line in _computation(text, "ENTRY "):
+        m = _INSTR.match(line)
+        if m and m["op"] in ("copy", "transpose", "copy-start"):
+            dims = {tuple(int(d) for d in a.split(",") if d)
+                    for a in _ARRAY.findall(m["type"])}
+            assert not dims & slabs, line[:160]
+    if program == "step":
+        _assert_the_benchmarks_pattern_finds_the_state_update(text, slabs)
+
+
+def _assert_the_benchmarks_pattern_finds_the_state_update(text, slabs):
+    """``kda_ms_per_step.serve`` and ``kda_state_roofline`` find the
+    state update in a trace by the operands its instructions read: the
+    slabs' positions in the step's state tuple.  A state tuple in another
+    order, or an update that no longer reads the slabs by those names,
+    must fail HERE and not read None on the chip: the pattern has to
+    match, in the compiled step, an instruction that writes a new slab
+    and none that reads only the pools."""
+    import json
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "layer_metrics")
+    patterns = set()
+    for name in ("kda_ms_per_step.serve", "kda_state_roofline"):
+        with open(os.path.join(bench, name + ".json")) as f:
+            patterns.add(json.load(f)["params"]["pattern"])
+    assert len(patterns) == 1
+    pat = re.compile(patterns.pop())
+    matched = [line for line in _computation(text, "ENTRY ")
+               if pat.search(line.split(" = ", 1)[-1])]
+    writes = [line for line in matched if {
+        tuple(int(d) for d in a.split(",") if d)
+        for a in _ARRAY.findall(_INSTR.match(line)["type"])} & slabs]
+    assert writes, "no matched instruction writes a slab"
+    assert not [line for line in matched if "paged_attention" in line]
+    # the pools are operands 0 and 1: the pattern leaves them out
+    assert not pat.search("%x = f32[1] fusion(%state_0_.1, %state_1_.1)")
