@@ -178,6 +178,14 @@ RULES = (
          "a position some row attends; one for a dead slot), `_walked` "
          "every block of every slot's table, which a fixed grid would "
          "walk.  Their ratio is the share of the table that is work"),
+    Rule("decode_prefill_keys_", "gauge", "serving",
+         "Cache positions a whole-prompt prefill's attention meets a "
+         "layer and head, added once a prefill from the numbers the "
+         "host holds: `_attended` the bucket's rows times the positions "
+         "each row's softmax spans (the bucket), `_live` those a prompt "
+         "row can see (length x (length + 1) / 2).  Their ratio is the "
+         "share of the attention that is work; the rest is the causal "
+         "triangle's other half and the bucket's padding"),
     Rule("decode_state_bytes", "gauge", "serving",
          "Device bytes of the slot-indexed slabs that hold the state of "
          "a model's recurrent layers (linear attention: a matrix a head "

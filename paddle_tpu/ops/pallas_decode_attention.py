@@ -97,15 +97,15 @@ KERNEL_NAME = "paged_attention"
 
 
 def decode_attention_reference(q, k, v, lengths, *, sm_scale=None):
-    """Masked single-token attention over full-width K/V.
+    """Masked single-token attention over K/V of any width T >= max length.
 
-    q: [S, H, D]; k/v: [S, T, H, D] (slot-major, any width T >= max
-    length); lengths: [S] — position t of slot s participates iff
-    t < lengths[s].  This exact formulation (mask -> -1e30, softmax
-    over the full width) is shared by the decode fallback AND the
-    prefill path in serving/decode.py, which is what makes
-    decode-with-cache logits bitwise-comparable to a full recompute.
-    """
+    q: [S, H, D]; k/v: [S, T, H, D] (slot-major); lengths: [S] — position
+    t of slot s participates iff t < lengths[s].  This exact formulation
+    (mask -> -1e30, float32 softmax) is shared by the decode fallback, at
+    the cache's width, AND the whole-prompt prefill in serving/decode.py,
+    at the prompt's bucket: a masked position weighs exactly zero, so T is
+    free, and decode-with-cache logits stay bitwise-comparable to a full
+    recompute."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     qf = q.astype(jnp.float32)

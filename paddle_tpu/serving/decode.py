@@ -61,10 +61,10 @@ table as scalar-prefetch operands — a slot's live pages copied a block
 of them at a time), the
 pure-jnp gather+mask reference on CPU so tier-1 stays green.  Every
 path — prefill, chunked prefill, decode, speculative verify — shares
-ONE masked-softmax formulation at one width, which is what makes
-decode-with-cache logits bitwise-equal to a full recompute
-(`tests/test_decode_engine.py` + `tests/test_decode_prefix_spec.py`
-pin it at every step on every path).
+ONE masked-softmax formulation (the cache's width, or a whole prompt's
+bucket: masked positions weigh zero), which keeps decode-with-cache
+logits bitwise-equal to a full recompute (`tests/test_decode_engine.py`
++ `tests/test_decode_prefix_spec.py` pin it every step on every path).
 
 Observability: ``decode_*`` counters/gauges (``decode_cache_hit_rate``,
 ``decode_shared_pages``, ``decode_cow_copies``, ``spec_accept_rate``,
@@ -931,7 +931,6 @@ class DecodeEngine:
         from ..ops.sampling_ops import sample_tokens
 
         cc = self._cache.config
-        t_max = cc.max_seq_len
         n_bp = t_pad // cc.page_size
         cdt = cc.dtype
 
@@ -944,7 +943,6 @@ class DecodeEngine:
             tokens, length, pages = a["tokens"], a["length"], a["pages"]
             positions = jnp.arange(t_pad, dtype=jnp.int32)
             row_lengths = positions + 1
-            shape = (t_max, model.num_heads, model.head_dim)
 
             def recur(token_fn, rows, rec):
                 """The prompt's tokens one after another from the zero
@@ -979,13 +977,15 @@ class DecodeEngine:
                     k_pages, k_scales, l, k, pages[:n_bp])
                 v_pages, v_scales = kv_cache.write_prompt_layer(
                     v_pages, v_scales, l, v, pages[:n_bp])
-                # attention at FULL cache width through the SAME cache
-                # representation the pages store — each row's numerics
-                # are the ones decode will reproduce from the pages,
-                # which is the bitwise prefix-cache contract.  In
-                # quantized mode that representation is the local
-                # quant-dequant round trip (identical bytes to what
-                # write_prompt_layer just stored).
+                # attention in decode's own formulation through the SAME
+                # cache representation the pages store — each row's
+                # numerics are the ones decode will reproduce from the
+                # pages, which is the bitwise prefix-cache contract.  The
+                # softmax spans the prompt's own bucket: nothing is cached
+                # beyond it, and the masked positions a wider one would add
+                # weigh exactly zero.  In quantized mode the representation
+                # is the local quant-dequant round trip (identical bytes to
+                # what write_prompt_layer just stored).
                 if qz:
                     kq, ksc = kv_cache.quantize_kv(k)
                     vq, vsc = kv_cache.quantize_kv(v)
@@ -998,11 +998,9 @@ class DecodeEngine:
                     # pages' dtype; no bitwise contract to keep (bf16)
                     return grouped_causal_attention(q, kl, vl), (
                         k_pages, v_pages, k_scales, v_scales)
-                kf = jnp.zeros(shape, cdt).at[:t_pad].set(kl)
-                vf = jnp.zeros(shape, cdt).at[:t_pad].set(vl)
                 ctx = decode_attention_reference(
-                    q, jnp.broadcast_to(kf[None], (t_pad,) + shape),
-                    jnp.broadcast_to(vf[None], (t_pad,) + shape),
+                    q, jnp.broadcast_to(kl[None], (t_pad,) + kl.shape),
+                    jnp.broadcast_to(vl[None], (t_pad,) + vl.shape),
                     row_lengths)
                 return ctx, (k_pages, v_pages, k_scales, v_scales)
 
@@ -1745,9 +1743,9 @@ class DecodeEngine:
 
     def _start_prefill_full(self, slot: int):
         """The whole-prompt prefill fast path (no cache hit, chunking
-        off): page-wholesale K/V writes + locally-built full-width
-        attention, one dispatch.  Returns the half that waits for the
-        first token and delivers it."""
+        off): page-wholesale K/V writes + locally-built attention at
+        the bucket's width, one dispatch.  Returns the half that waits
+        for the first token and delivers it."""
         st = self._slots[slot]
         req = st.req
 
@@ -1797,10 +1795,16 @@ class DecodeEngine:
                               tokens=len(req.prompt),
                               dur_ms=round(dur * 1e3, 3))
                     stat_add("decode_prefills")
-                    record_pad_waste(len(req.prompt), t_pad)
-                    st.prefill_pos = len(req.prompt)
+                    # how much of the prompt's attention is work: the
+                    # positions every row's softmax spans (the bucket)
+                    # against those a row can see (the causal triangle)
+                    n = len(req.prompt)
+                    stat_add("decode_prefill_keys_attended", t_pad * t_pad)
+                    stat_add("decode_prefill_keys_live", n * (n + 1) // 2)
+                    record_pad_waste(n, t_pad)
+                    st.prefill_pos = n
                     st.phase = "decode"
-                    self._cache.lengths[slot] = len(req.prompt)
+                    self._cache.lengths[slot] = n
                     if req.record_logits:
                         req.logits_trace.append(np.asarray(last))
                         for name, rows in recorded.items():

@@ -6,10 +6,12 @@ the beam-search text decoder.)
 The load-bearing test is the prefix-cache ORACLE: decode-with-cache
 logits must be BITWISE equal to a full recompute of the whole prefix
 at every generated step — prefill and decode share one masked-softmax
-formulation at one width, so any cache bug (wrong page, wrong offset,
-stale entry) shows up as a bit difference.
+formulation (each at its own width: a masked position weighs exactly
+zero), so any cache bug (wrong page, wrong offset, stale entry) shows
+up as a bit difference.
 """
 import json
+import re
 import time
 import urllib.request
 
@@ -388,6 +390,88 @@ def test_decode_bitwise_equals_full_recompute_every_step(
                 f"decode-with-cache logits diverged from the full "
                 f"recompute at step {t} (max diff "
                 f"{np.abs(oracle - r.logits_trace[t]).max()})")
+
+
+# -- the whole-prompt prefill: decode's formulation at the bucket's width --
+
+
+def _cache_width_prefill_logits(model, weights, prompt, t_pad, t_max,
+                                quantized):
+    """The whole-prompt prefill's last-row logits with every row's
+    softmax spanning all ``t_max`` cache positions, K/V zero-padded from
+    the bucket to that width: the formulation the engine's prefill had
+    before it took its bucket's own width, kept here as the oracle of
+    the narrower one."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas_decode_attention import \
+        decode_attention_reference
+    from paddle_tpu.serving import kv_cache as kvc
+
+    def attend(l, q, k, v, cache):
+        if quantized:  # what the pages store, read back
+            k = kvc.dequantize_kv(*kvc.quantize_kv(k), jnp.float32)
+            v = kvc.dequantize_kv(*kvc.quantize_kv(v), jnp.float32)
+        shape = (t_max,) + k.shape[1:]
+        kf = jnp.zeros(shape, k.dtype).at[:t_pad].set(k)
+        vf = jnp.zeros(shape, v.dtype).at[:t_pad].set(v)
+        return decode_attention_reference(
+            q, jnp.broadcast_to(kf[None], (t_pad,) + shape),
+            jnp.broadcast_to(vf[None], (t_pad,) + shape),
+            jnp.arange(t_pad, dtype=jnp.int32) + 1), cache
+
+    tokens = np.zeros((t_pad,), np.int32)
+    tokens[:len(prompt)] = prompt
+    logits, _ = jax.jit(lambda w, t: model.forward(
+        w, t, jnp.arange(t_pad, dtype=jnp.int32), None, attend))(
+            weights, tokens)
+    return np.asarray(logits[len(prompt) - 1])
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("t_pad,length", [(8, 5), (32, 19), (48, 43)],
+                         ids=["short", "middle", "cache_width"])
+def test_whole_prompt_prefill_attends_its_own_bucket(
+        model_and_weights, t_pad, length, quantized):
+    """A whole-prompt prefill spans its bucket, not the cache: (a) its
+    recorded logits and first token are bitwise those of the same
+    formulation at the cache's width (the masked tail weighs exactly
+    zero); (b) its lowered program holds no tensor with a cache-width
+    dimension beside a bucket-width one; (c) the counters say how much
+    of the attention is work."""
+    t_max = 48      # equals no other size of this model or engine
+    model, weights = model_and_weights
+    eng = make_engine(model_and_weights, max_seq_len=t_max,
+                      kv_quant=quantized, max_new_tokens=2).start()
+    prompt = [(7 * i + 3) % VOCAB for i in range(length)]
+    names = ("decode_prefill_keys_attended", "decode_prefill_keys_live",
+             "decode_prefills")
+    before = [stat_get(n) for n in names]
+    try:
+        req = eng.submit(prompt, max_new_tokens=2, record_logits=True)
+        out = req.result(timeout=120)
+    finally:
+        eng.stop()
+    attended, live, prefills = (
+        stat_get(n) - b for n, b in zip(names, before))
+    assert (prefills, attended, live) == (
+        1, t_pad * t_pad, length * (length + 1) // 2)
+
+    want = _cache_width_prefill_logits(model, weights, prompt, t_pad,
+                                       t_max, quantized)
+    assert np.array_equal(req.logits_trace[0], want), (
+        np.abs(req.logits_trace[0] - want).max())
+    assert out[0] == int(np.argmax(want))
+    assert np.array_equal(
+        eng.recompute_logits(prompt, quantized=quantized), want)
+
+    shapes = {tuple(int(d) for d in dims.split("x")) for dims in re.findall(
+        r"tensor<([\dx]+)x\w+>", eng.lower_prefill(t_pad).as_text())}
+    assert (t_pad, model.num_heads, model.head_dim) in shapes   # q, k, v
+    if t_pad < t_max:
+        wide = sorted(sh for sh in shapes if t_max in sh and t_pad in sh)
+        assert not wide, wide
 
 
 # -- the seam: the engine serves whatever implements its contract ---------

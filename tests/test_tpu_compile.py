@@ -374,7 +374,27 @@ def test_pools_keep_one_layout_through_program(one_chip, heads, kv_quant,
     # A standing finding, not a contract: drop the exemption with it
     relaid = (cc.pool_shape(row_lanes=cc.num_heads),) \
         if kv_quant and program != "cow" else ()
-    _assert_pools_stay_put(lowered.compile(), pools, relaid)
+    compiled = lowered.compile()
+    _assert_pools_stay_put(compiled, pools, relaid)
+    if program == "prefill":
+        _assert_prompt_attends_its_bucket(compiled.as_text(), 128, cc)
+
+
+def _assert_prompt_attends_its_bucket(text, t_pad, cc):
+    """No instruction of the compiled whole-prompt prefill, inside a
+    fusion or out, has a result of the prompt's rows by the cache's
+    ``max_seq_len`` positions: the scores ``[t_pad, max_seq_len, heads]``
+    and the K/V every row read, ``[..., heads, head_dim]`` (at GPT-2
+    medium's widths the device trace's ``f32[128,1024,16]`` fusions,
+    in any order of the dimensions).  The rows' own are there."""
+    wide = {tuple(sorted((t_pad, cc.max_seq_len, cc.num_heads) + tail))
+            for tail in ((), (cc.head_dim,))}
+    found = {tuple(int(d) for d in a.split(",") if d)
+             for line in text.splitlines() if (m := _INSTR.match(line))
+             for a in _ARRAY.findall(m["type"])}
+    assert (t_pad, cc.num_heads, cc.head_dim) in found
+    offenders = sorted(d for d in found if tuple(sorted(d)) in wide)
+    assert not offenders, offenders
 
 
 # -- the hybrid model: grouped heads, slabs beside the pools ---------------
