@@ -193,8 +193,30 @@ RULES = (
          "layers all keep keys"),
     Rule("decode_prefix_bypassed", "gauge", "serving",
          "Admissions of an engine whose prefix cache was asked for and "
-         "left out because the model keeps recurrent state (a page of "
-         "keys says nothing about it): every request admitted fresh"),
+         "left out because the model keeps recurrent state or a ring of "
+         "a window layer's last positions (a shared page of keys says "
+         "nothing about either): every request admitted fresh"),
+    Rule("decode_window_pages_", "gauge", "serving",
+         "The rings of a model's window layers (a slot keeps ceil(window "
+         "/ page) + 1 pages a layer, however long its request), over all "
+         "window layers, set or added once a joint decode step: `_held` "
+         "(gauge) the ring pages that hold a position of a live request, "
+         "never more than slots x layers x ring; `_recycled` the pages a "
+         "step's tokens overwrote because they had slid out of the "
+         "window"),
+    Rule("decode_window_blocks_walked", "gauge", "serving",
+         "Blocks of ring entries the window layers' paged-attention "
+         "kernel walks a layer (one or two a live slot: from the block "
+         "that holds the window's first position), added once a joint "
+         "decode step from the lengths the engine holds"),
+    Rule("decode_window_positions_live", "gauge", "serving",
+         "Positions inside some live row's window a layer (min(length, "
+         "window) a slot), added once a joint decode step; over "
+         "`decode_window_blocks_walked` x a block's positions it is the "
+         "share of what the window kernel reads that is attended"),
+    Rule("decode_window_bytes", "gauge", "serving",
+         "Device bytes of the window layers' ring pools, all slots: no "
+         "term in max_seq_len; 0 for a model without window layers"),
     Rule("decode_", "gauge", "serving",
          "Decode-engine lifecycle, paging, speculation, goodput"),
     Rule("serving_", "gauge", "serving",

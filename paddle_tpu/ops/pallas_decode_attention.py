@@ -71,6 +71,22 @@ page table (O(S * max_seq) materialization) and do masked attention.
 It is also the CPU-backend default so tier-1 stays green without
 Mosaic; ``interpret=True`` runs the real kernel on CPU for tests.
 
+**K and V of different widths, a window, a sink.**  The K pool's rows
+hold ``H*Dk`` lanes and the V pool's ``H*Dv``: the query and the scores
+follow K's width, the accumulator and the output V's (MiMo-V2.5: 192
+and 128).  With ``window=W`` a row of length ``n`` attends positions
+``n - W <= t < n`` only, and the slot's page table is read as a RING:
+logical page ``j`` (positions ``j*page ...``) lives at entry ``j % pps``
+(`serving/kv_cache.py` keeps ``ceil(W / page) + 1`` pages a slot for such
+a layer and overwrites the oldest; a table that holds every page is the
+ring that never wraps).  The walk then starts at the block that holds
+position ``n - W`` and moves the pages from there on: one or two blocks
+however long the slot.  ``sinks`` is a learned logit a query head that
+joins the softmax's denominator and nothing else: the online softmax
+starts from ``m = sink, l = 1, acc = 0`` instead of ``-inf, 0, 0``, so
+it costs no pass.  The window call has its own name
+(``WINDOW_KERNEL_NAME``), so a trace tells the two kinds of layer apart.
+
 ``paged_chunk_attention`` is the kernel itself: R query rows per slot
 with per-row causal lengths over one shared page table — the attention
 shape of chunked/suffix prefill and speculative verification
@@ -94,13 +110,20 @@ _LANES = 128  # TPU vector lane width; row stats broadcast across lanes
 # event "%paged_attention.<n> = ... custom-call(...)" (still a
 # tpu_custom_call), so it can be told apart from any other kernel
 KERNEL_NAME = "paged_attention"
+WINDOW_KERNEL_NAME = "paged_attention_window"    # the call with a window
 
 
-def decode_attention_reference(q, k, v, lengths, *, sm_scale=None):
+def decode_attention_reference(q, k, v, lengths, *, sm_scale=None,
+                               window=None, sinks=None, offset=None):
     """Masked single-token attention over K/V of any width T >= max length.
 
-    q: [S, H, D]; k/v: [S, T, H, D] (slot-major); lengths: [S] — position
-    t of slot s participates iff t < lengths[s].  This exact formulation
+    q: [S, H, D]; k/v: [S, T, H, D] (slot-major; V's D may differ, the
+    output has it); lengths: [S] — position
+    t of slot s participates iff t < lengths[s] (and, with ``window``,
+    ``t >= lengths[s] - window``; column t is position ``t + offset[s]``
+    where ``offset`` is given).  ``sinks`` [S, H]: a logit that joins each
+    row's softmax and takes its share of the weights with it.  This exact
+    formulation
     (mask -> -1e30, float32 softmax) is shared by the decode fallback, at
     the cache's width, AND the whole-prompt prefill in serving/decode.py,
     at the prompt's bucket: a masked position weighs exactly zero, so T is
@@ -112,20 +135,33 @@ def decode_attention_reference(q, k, v, lengths, *, sm_scale=None):
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
     s = jnp.einsum("shd,sthd->sht", qf, kf) * sm_scale      # [S, H, T]
-    t = jnp.arange(k.shape[1], dtype=jnp.int32)
-    mask = t[None, None, :] < lengths[:, None, None]
+    t = jnp.arange(k.shape[1], dtype=jnp.int32)[None, None, :]
+    if offset is not None:
+        t = t + offset[:, None, None]
+    mask = t < lengths[:, None, None]
+    if window is not None:
+        mask = mask & (t >= lengths[:, None, None] - window)
     s = jnp.where(mask, s, _NEG_INF)
+    if sinks is not None:
+        s = jnp.concatenate(
+            [s, sinks.astype(jnp.float32)[..., None]], axis=-1)
     p = jax.nn.softmax(s, axis=-1)
+    if sinks is not None:
+        p = p[..., :-1]
     out = jnp.einsum("sht,sthd->shd", p, vf)
     return out.astype(q.dtype)
 
 
-def grouped_causal_attention(q, k, v, *, sm_scale=None):
+def grouped_causal_attention(q, k, v, *, sm_scale=None, window=None,
+                             sinks=None):
     """Causal attention of one sequence whose query heads outnumber its
-    K/V heads: q [T, H, D], k/v [T, Hkv, D], query head i reading K/V
-    head ``i // (H // Hkv)``; row t attends positions ``<= t``.  The
-    whole-prompt prefill of a grouped-query model (serving/decode.py):
-    plain jnp at the prompt's own width, float32 softmax."""
+    K/V heads: q [T, H, D], k [T, Hkv, D], v [T, Hkv, Dv], query head i
+    reading K/V head ``i // (H // Hkv)``; row t attends positions
+    ``<= t`` (with ``window``: the last ``window`` of them, itself
+    counted), and ``sinks`` [H] joins each head's softmax as a logit
+    with no value.  The whole-prompt prefill of a grouped-query model
+    (serving/decode.py): plain jnp at the prompt's own width, float32
+    softmax."""
     t, h, d = q.shape
     kv_heads = k.shape[1]
     if sm_scale is None:
@@ -133,10 +169,19 @@ def grouped_causal_attention(q, k, v, *, sm_scale=None):
     qg = q.astype(jnp.float32).reshape(t, kv_heads, h // kv_heads, d)
     s = jnp.einsum("thgd,uhd->hgtu", qg, k.astype(jnp.float32)) * sm_scale
     pos = jnp.arange(t, dtype=jnp.int32)
-    s = jnp.where(pos[None, :] <= pos[:, None], s, _NEG_INF)
+    mask = pos[None, :] <= pos[:, None]
+    if window is not None:
+        mask = mask & (pos[None, :] > pos[:, None] - window)
+    s = jnp.where(mask, s, _NEG_INF)
+    if sinks is not None:
+        sink = sinks.astype(jnp.float32).reshape(kv_heads, -1, 1, 1)
+        s = jnp.concatenate(
+            [s, jnp.broadcast_to(sink, s.shape[:-1] + (1,))], axis=-1)
     p = jax.nn.softmax(s, axis=-1)
+    if sinks is not None:
+        p = p[..., :-1]
     out = jnp.einsum("hgtu,uhd->thgd", p, v.astype(jnp.float32))
-    return out.reshape(t, h, d).astype(q.dtype)
+    return out.reshape(t, h, v.shape[-1]).astype(q.dtype)
 
 
 def _gather_dequant(pages, scales, layer, page_table, num_heads):
@@ -157,7 +202,7 @@ def _gather_dequant(pages, scales, layer, page_table, num_heads):
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            layer=0, sm_scale=None, use_pallas="auto",
                            interpret=False, k_scales=None,
-                           v_scales=None):
+                           v_scales=None, window=None, sinks=None):
     """Decode attention straight off the page pool.
 
     q [S,H,D]; k/v_pages [L,P,page,H*D] (the stacked pools; ``layer``
@@ -169,7 +214,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     [L,P,page,H] arm the quantized path (FLAGS_decode_kv_quant): pages
     are int8 and BOTH paths dequantize them inline — the Pallas kernel
     per tile in VMEM, the reference during the gather — before the one
-    shared masked-softmax formulation.
+    shared masked-softmax formulation.  ``window`` / ``sinks`` [H]: see
+    ``paged_chunk_attention``.
     """
     # one query row per slot IS the chunk kernel at R=1: Mosaic has no
     # matmul for a query with no free row dimension, so the row axis
@@ -177,7 +223,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     return paged_chunk_attention(
         q[:, None], k_pages, v_pages, page_table, lengths[:, None],
         layer=layer, sm_scale=sm_scale, use_pallas=use_pallas,
-        interpret=interpret, k_scales=k_scales, v_scales=v_scales)[:, 0]
+        interpret=interpret, k_scales=k_scales, v_scales=v_scales,
+        window=window, sinks=sinks)[:, 0]
 
 
 # -- the kernel: R query rows per slot (decode is R=1) --------------------
@@ -188,10 +235,11 @@ _MAX_STACK_ROWS = 32  # query rows one matmul carries (hb heads x R rows)
 _BLOCK_VMEM_BYTES = 4 << 20
 
 
-def _stack_heads(num_heads, head_dim, n_rows):
+def _stack_heads(num_heads, head_dim, n_rows, v_dim=None):
     """How many heads' query rows one matmul stacks block-diagonally.
 
-    A stack spans ``hb * head_dim`` lanes of the pool row, so it must
+    A stack spans ``hb * head_dim`` lanes of the pool row (``hb *
+    v_dim`` of a V row of another width), so it must
     cut the row at 128-lane boundaries (or be the whole row: toy
     widths, interpret mode).  More heads a stack means fewer, fuller
     matmuls and softmax updates but ``hb`` times the accumulator, so
@@ -199,12 +247,14 @@ def _stack_heads(num_heads, head_dim, n_rows):
     taken, and the smallest legal one when none fits."""
     legal = [hb for hb in range(1, num_heads + 1)
              if num_heads % hb == 0
-             and ((hb * head_dim) % _LANES == 0 or hb == num_heads)]
+             and (((hb * head_dim) % _LANES == 0
+                   and (hb * (v_dim or head_dim)) % _LANES == 0)
+                  or hb == num_heads)]
     fit = [hb for hb in legal if hb * n_rows <= _MAX_STACK_ROWS]
     return max(fit) if fit else min(legal)
 
 
-def pages_per_block(page, pps, row_lanes, itemsize):
+def pages_per_block(page, pps, row_lanes, itemsize, v_lanes=None):
     """How many consecutive page-table entries one block covers.
 
     The largest count whose positions fill at most one 128-lane tile of
@@ -212,44 +262,63 @@ def pages_per_block(page, pps, row_lanes, itemsize):
     buffered, fit ``_BLOCK_VMEM_BYTES``; never more than the table
     holds, never less than 1 (a page of 128 positions or more is a
     block by itself).  ``pps`` need not be a multiple: the last block's
-    missing entries are dead like any other."""
+    missing entries are dead like any other.  ``v_lanes``: the V rows'
+    width where it is not K's ``row_lanes``."""
     by_tile = _LANES // page
-    by_vmem = _BLOCK_VMEM_BYTES // (2 * 2 * page * row_lanes * itemsize)
+    by_vmem = _BLOCK_VMEM_BYTES // (
+        2 * page * (row_lanes + (v_lanes or row_lanes)) * itemsize)
     return max(1, min(by_tile, by_vmem, pps))
 
 
-def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, q_ref, k_hbm,
-                  v_hbm, *rest, sm_scale, page, pps, ppb, n_slots, n_rows,
-                  head_dim, quantized=False):
+def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
+                  page, pps, ppb, n_slots, n_rows, head_dim, v_dim=None,
+                  quantized=False, window=None, sinks=False):
     """One grid step is one SLOT: R query rows (a prefill chunk, a
     speculative t0+draft window, or decode's one) over the slot's live
     blocks of ``ppb`` page-table entries.  Row r of slot s attends
     positions ``t < len_ref[s*R + r]`` — per-row causal masks over one
     shared page table, so shared and partially-filled pages need no
-    special casing beyond the mask.
+    special casing beyond the mask.  With ``window`` the positions
+    below ``len - window`` are masked too, ``slot_lo_ref[s]`` (one more
+    scalar-prefetch operand) is the first position any row of the slot
+    attends, the walk and the copies start at its block and page, and
+    table entry ``j % pps`` holds logical page ``j`` (a ring).
 
-    Refs: q/o (1, R, H*D) blocks; k/v the whole stacked pools, left in
-    HBM; int8 pools' scales (1, positions, H), the slot's own, gathered
-    by the caller.  Scratch, per stack of ``hb`` heads: the
-    block-diagonal query (hb*R, hb*D), running max and denominator
-    (hb*R, 128), accumulator (hb*R, hb*D); then the two block buffers
+    Refs: q (1, R, H*Dk) and o (1, R, H*Dv) blocks; k/v the whole stacked
+    pools, left in HBM; int8 pools' scales (1, positions, H), the slot's
+    own, gathered by the caller; ``sinks``: the logit of each stacked row
+    (n_stacks, hb*R, 128).  Scratch, per stack of ``hb`` heads: the
+    block-diagonal query (hb*R, hb*Dk), running max and denominator
+    (hb*R, 128), accumulator (hb*R, hb*Dv); then the two block buffers
     (2, ppb, page, H*D) a pool, one DMA semaphore a buffer, and in SMEM
     which buffer holds the block that is computed next."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if window is not None:
+        slot_lo_ref, *rest = rest
+    q_ref, k_hbm, v_hbm, *rest = rest
     if quantized:
         ks_ref, vs_ref, *rest = rest
+    if sinks:
+        sink_ref, *rest = rest
     o_ref, qbd_scr, m_scr, l_scr, acc_scr, k_buf, v_buf, sem, cur = rest
     pools = ((k_hbm, k_buf), (v_hbm, v_buf))
 
     s_idx = pl.program_id(0)
     layer = layer_ref[0]
-    n_stacks, rows, width = acc_scr.shape
+    n_stacks, rows, width = qbd_scr.shape
+    v_width = acc_scr.shape[2]
     hb = rows // n_rows                 # heads a stack
     block = ppb * page                  # positions a block
     # head (within its stack) that owns each lane of a stack
     lane_head = lax.broadcasted_iota(jnp.int32, (1, width), 1) // head_dim
+    v_lane_head = lane_head if v_width == width else lax.broadcasted_iota(
+        jnp.int32, (1, v_width), 1) // v_dim
+
+    def first_block(s):
+        """The block a slot's walk starts at."""
+        return 0 if window is None else slot_lo_ref[s] // block
 
     def block_dma(s, b, buf, start):
         """Start, or wait for, the copies of block ``b`` of slot ``s``
@@ -260,7 +329,10 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, q_ref, k_hbm,
 
         def _page(entry, carry):
             # a wait only needs the copy's shape: no table read
-            pid = pt_ref[s * pps + entry] if start else 0
+            if window is None:
+                pid = pt_ref[s * pps + entry] if start else 0
+            else:
+                pid = pt_ref[s * pps + entry % pps] if start else 0
             for hbm, vmem in pools:
                 copy = pltpu.make_async_copy(
                     hbm.at[layer, pid], vmem.at[buf, entry - first],
@@ -269,15 +341,23 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, q_ref, k_hbm,
             return carry
 
         n_live = pl.cdiv(slot_len_ref[s], page)
-        lax.fori_loop(first, jnp.minimum(first + ppb, n_live), _page, 0)
+        lo = first if window is None \
+            else jnp.maximum(first, slot_lo_ref[s] // page)
+        lax.fori_loop(lo, jnp.minimum(first + ppb, n_live), _page, 0)
 
     @pl.when(s_idx == 0)
     def _first():
         cur[0] = 0
-        block_dma(0, 0, 0, start=True)
+        block_dma(0, first_block(0), 0, start=True)
 
-    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
+    if sinks:
+        # the sink is one more logit of every row: the running max
+        # starts at it and the denominator at its own exp(0)
+        m_scr[...] = sink_ref[...]
+        l_scr[...] = jnp.ones_like(l_scr)
+    else:
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
     q = q_ref[0].astype(jnp.float32) * sm_scale            # (R, H*D)
     for j in range(n_stacks):
@@ -304,7 +384,11 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, q_ref, k_hbm,
         # flight while this one is computed
         last = b + 1 == n_blocks
         nxt_s = jnp.where(last, s_idx + 1, s_idx)
-        nxt_b = jnp.where(last, 0, b + 1)
+        if window is None:
+            nxt_b = jnp.where(last, 0, b + 1)
+        else:
+            nxt_b = jnp.where(last, first_block(
+                jnp.minimum(s_idx + 1, n_slots - 1)), b + 1)
         pl.when(nxt_s < n_slots)(
             lambda: block_dma(nxt_s, nxt_b, 1 - buf, start=True))
         block_dma(s_idx, b, buf, start=False)
@@ -316,18 +400,23 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, q_ref, k_hbm,
         # buffer held there (NaN included) must not reach ``p @ v``
         # through ``0 * v``, so V is zeroed by position (K's scores
         # are replaced by the mask)
-        v_live = b * block + lax.broadcasted_iota(
-            jnp.int32, (block, 1), 0) < max_len
+        col = b * block + lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+        v_live = col < max_len
+        if window is not None:
+            live = live & (pos >= row_len - window)
+            # nor were the pages before the window's first
+            v_live = v_live & (col >= slot_lo_ref[s_idx] // page * page)
         for j in range(n_stacks):
             lanes = slice(j * width, (j + 1) * width)
+            v_lanes = slice(j * v_width, (j + 1) * v_width)
             k = k_buf[buf, :, :, lanes].astype(jnp.float32) \
                 .reshape(block, width)
-            v = v_buf[buf, :, :, lanes].astype(jnp.float32) \
-                .reshape(block, width)
+            v = v_buf[buf, :, :, v_lanes].astype(jnp.float32) \
+                .reshape(block, v_width)
             if quantized:  # dequant-fused: int8 tile * VMEM scale
                 at = pl.ds(pl.multiple_of(b * block, block), block)
                 k = k * _scale_lanes(ks_ref, at, j, hb, lane_head)
-                v = v * _scale_lanes(vs_ref, at, j, hb, lane_head)
+                v = v * _scale_lanes(vs_ref, at, j, hb, v_lane_head)
             v = jnp.where(v_live, v, 0.0)
             # every stacked head's scores in one matmul: (rows, block)
             s = lax.dot_general(
@@ -350,17 +439,17 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, q_ref, k_hbm,
             l_scr[j] = jnp.broadcast_to(l_new, l_scr.shape[1:])
         return carry
 
-    lax.fori_loop(0, n_blocks, _block, 0)
+    lax.fori_loop(first_block(s_idx), n_blocks, _block, 0)
 
     for j in range(n_stacks):
-        out = jnp.zeros((n_rows, width), jnp.float32)
+        out = jnp.zeros((n_rows, v_width), jnp.float32)
         for h in range(hb):
             blk = slice(h * n_rows, (h + 1) * n_rows)
             l = l_scr[j, blk, :1]
             out = jnp.where(
-                lane_head == h,
+                v_lane_head == h,
                 acc_scr[j, blk, :] / jnp.where(l == 0.0, 1.0, l), out)
-        o_ref[0, :, j * width:(j + 1) * width] = out.astype(o_ref.dtype)
+        o_ref[0, :, j * v_width:(j + 1) * v_width] = out.astype(o_ref.dtype)
 
 
 def _scale_lanes(scale_ref, at, stack, hb, lane_head):
@@ -373,28 +462,37 @@ def _scale_lanes(scale_ref, at, stack, hb, lane_head):
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "interpret", "window"))
 def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
-                k_scales=None, v_scales=None, *, sm_scale, interpret):
+                k_scales=None, v_scales=None, sinks=None, *, sm_scale,
+                interpret, window=None):
     """The ``pallas_call``.  ``layer`` is an OPERAND (int32 scalar), so
     a model's layers share one traced and lowered kernel: a program
-    pays for the body once, not once a layer."""
+    pays for the body once, not once a layer.  ``sinks`` [R, H]: the
+    logit of each query row and head."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n_slots, n_rows, h, d = q.shape
     pps = page_table.shape[1]
     page, hd = k_pages.shape[2:]
-    if hd != h * d:
+    v_hd = v_pages.shape[3]
+    if hd != h * d or v_hd % h:
         raise ValueError(
-            f"pool rows are {hd} lanes wide but q has {h} heads of {d}")
-    hb = _stack_heads(h, d, n_rows)
-    n_stacks, rows, width = h // hb, hb * n_rows, hb * d
-    ppb = pages_per_block(page, pps, hd, k_pages.dtype.itemsize)
+            f"pool rows are {hd} (K) and {v_hd} (V) lanes wide but q has "
+            f"{h} heads of {d}")
+    dv = v_hd // h
+    hb = _stack_heads(h, d, n_rows, dv)
+    n_stacks, rows, width, v_width = h // hb, hb * n_rows, hb * d, hb * dv
+    ppb = pages_per_block(page, pps, hd, k_pages.dtype.itemsize, v_hd)
     quantized = k_scales is not None
     row_lengths = row_lengths.astype(jnp.int32)
     prefetch = [layer.reshape(1), page_table.reshape(-1).astype(jnp.int32),
                 row_lengths.reshape(-1), row_lengths.max(axis=1)]
+    if window is not None:
+        # the first position any row of the slot attends
+        prefetch.append(jnp.maximum(row_lengths.min(axis=1) - window, 0))
 
     def slot_block(*shape):
         return pl.BlockSpec((1, *shape), lambda s, *_: (s, 0, 0))
@@ -414,49 +512,66 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
         in_specs += [slot_block(positions, h)] * 2
         operands += [sc[layer, table].reshape(n_slots, positions, h)
                      for sc in (k_scales, v_scales)]
+    if sinks is not None:
+        # stacked row hh*R + r of stack j is query row r of head j*hb+hh
+        in_specs.append(pl.BlockSpec((n_stacks, rows, _LANES),
+                                     lambda s, *_: (0, 0, 0)))
+        operands.append(jnp.broadcast_to(
+            sinks.astype(jnp.float32).T.reshape(n_stacks, rows, 1),
+            (n_stacks, rows, _LANES)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        # (layer, flat page table, flat row lengths, widest row a slot)
+        # (layer, flat page table, flat row lengths, widest row a slot
+        # [, the window's first position a slot])
         num_scalar_prefetch=len(prefetch),
         grid=(n_slots,),
         in_specs=in_specs,
-        out_specs=slot_block(n_rows, hd),
+        out_specs=slot_block(n_rows, v_hd),
         scratch_shapes=[
             pltpu.VMEM((n_stacks, rows, width), jnp.float32),   # query
             pltpu.VMEM((n_stacks, rows, _LANES), jnp.float32),  # max
             pltpu.VMEM((n_stacks, rows, _LANES), jnp.float32),  # denom
-            pltpu.VMEM((n_stacks, rows, width), jnp.float32),   # acc
+            pltpu.VMEM((n_stacks, rows, v_width), jnp.float32),  # acc
             pltpu.VMEM((2, ppb, page, hd), k_pages.dtype),      # K blocks
-            pltpu.VMEM((2, ppb, page, hd), v_pages.dtype),      # V blocks
+            pltpu.VMEM((2, ppb, page, v_hd), v_pages.dtype),    # V blocks
             pltpu.SemaphoreType.DMA((2,)),     # one a buffer, K and V
             pltpu.SMEM((1,), jnp.int32),       # the buffer computed next
         ],
     )
     kern = functools.partial(_chunk_kernel, sm_scale=sm_scale, page=page,
                              pps=pps, ppb=ppb, n_slots=n_slots,
-                             n_rows=n_rows, head_dim=d, quantized=quantized)
+                             n_rows=n_rows, head_dim=d, v_dim=dv,
+                             quantized=quantized, window=window,
+                             sinks=sinks is not None)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_slots, n_rows, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_slots, n_rows, v_hd), q.dtype),
         # a slot hands its successor's first block over in flight
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name=KERNEL_NAME,
+        name=KERNEL_NAME if window is None else WINDOW_KERNEL_NAME,
     )(*prefetch, *operands)
-    return out.reshape(q.shape)
+    return out.reshape(q.shape[:-1] + (dv,))
 
 
 def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
                           *, layer=0, sm_scale=None, use_pallas="auto",
                           interpret=False, k_scales=None,
-                          v_scales=None):
+                          v_scales=None, window=None, sinks=None):
     """Multi-row attention off the page pool — R query rows per slot.
 
-    q [S,R,H,D]; k/v_pages [L,P,page,H*D] (the stacked pools; ``layer``
-    is the static layer to read); page_table [S,pps] i32; row_lengths
+    q [S,R,H,D]; k_pages [L,P,page,Hkv*D] and v_pages [L,P,page,Hkv*Dv]
+    (the stacked pools; ``layer``
+    is the static layer to read; the output is [S,R,H,Dv]); page_table
+    [S,pps] i32; row_lengths
     [S,R] i32 — row r of slot s attends positions
-    ``t < row_lengths[s, r]``.  Grouped-query heads: a pool row of
+    ``t < row_lengths[s, r]``, with ``window`` only the last ``window``
+    of them, the table then read as a ring (the module header: logical
+    page j at entry ``j % pps``; every attended position must lie in the
+    slot's last ``pps`` pages).  ``sinks`` [H] (or [R, H]): a logit a
+    query head that joins the softmax's denominator.  Grouped-query
+    heads: a pool row of
     ``Hkv*D`` lanes with ``Hkv < H`` makes query head i read K/V head
     ``i // (H // Hkv)``; the group's heads ride as extra rows of their
     K/V head, so a slot's pages are still read once (one-token decode of
@@ -476,6 +591,8 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     s, r, h, d = q.shape
     kv_heads = k_pages.shape[-1] // d
+    if sinks is not None and sinks.ndim == 1:
+        sinks = jnp.broadcast_to(sinks, (r, h))
     if kv_heads != h:
         # grouped-query heads: the G query heads that share a K/V head
         # ride as G more ROWS of that head's slot, so the kernel (and
@@ -487,20 +604,35 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
         g = h // kv_heads
         rows = q.reshape(s, r, kv_heads, g, d).transpose(0, 1, 3, 2, 4) \
             .reshape(s, r * g, kv_heads, d)
+        if sinks is not None:
+            sinks = sinks.reshape(r, kv_heads, g).transpose(0, 2, 1) \
+                .reshape(r * g, kv_heads)
         out = paged_chunk_attention(
             rows, k_pages, v_pages, page_table,
             jnp.repeat(row_lengths, g, axis=1), layer=layer,
             sm_scale=sm_scale, use_pallas=use_pallas, interpret=interpret,
-            k_scales=k_scales, v_scales=v_scales)
-        return out.reshape(s, r, g, kv_heads, d).transpose(0, 1, 3, 2, 4) \
-            .reshape(q.shape)
+            k_scales=k_scales, v_scales=v_scales, window=window,
+            sinks=sinks)
+        return out.reshape(s, r, g, kv_heads, -1).transpose(0, 1, 3, 2, 4) \
+            .reshape(s, r, h, -1)
     if use_pallas == "auto":
         use_pallas = "always" if jax.default_backend() == "tpu" \
             else "never"
     if use_pallas == "always":
         return _chunk_call(q, k_pages, v_pages, jnp.int32(layer),
                            page_table, row_lengths, k_scales, v_scales,
-                           sm_scale=float(sm_scale), interpret=interpret)
+                           sinks, sm_scale=float(sm_scale),
+                           interpret=interpret, window=window)
+    offset = None
+    if window is not None:
+        # the ring read in logical order: its pps entries hold the
+        # slot's LAST pps pages, the oldest of them at position offset
+        pps, page = page_table.shape[1], k_pages.shape[2]
+        first = jnp.maximum(
+            -(-row_lengths.max(axis=1) // page) - pps, 0)       # [S]
+        page_table = jnp.take_along_axis(
+            page_table, (first[:, None] + jnp.arange(pps)) % pps, axis=1)
+        offset = jnp.repeat(first * page, r)
     k = _gather_dequant(k_pages, k_scales, layer, page_table, h)
     v = _gather_dequant(v_pages, v_scales, layer, page_table, h)
     kr = jnp.broadcast_to(k[:, None], (s, r) + k.shape[1:]) \
@@ -509,5 +641,7 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
         .reshape(s * r, *v.shape[1:])
     out = decode_attention_reference(
         q.reshape((s * r,) + q.shape[2:]), kr, vr,
-        row_lengths.reshape(-1), sm_scale=sm_scale)
-    return out.reshape(q.shape)
+        row_lengths.reshape(-1), sm_scale=sm_scale, window=window,
+        sinks=None if sinks is None else jnp.tile(sinks, (s, 1)),
+        offset=offset)
+    return out.reshape(s, r, h, -1)

@@ -59,6 +59,7 @@ from .kv_cache import (  # noqa: F401
     PageAllocator,
     PrefixIndex,
 )
+from .window_moe_lm import WindowMoELM  # noqa: F401
 from .server import (  # noqa: F401
     DecodeServer,
     Server,
@@ -74,7 +75,7 @@ __all__ = [
     "KVPageExport", "PageAllocator", "PagedKVCache", "PrefixIndex",
     "QueueFullError", "RequestAbandonedError", "RequestBase",
     "RequestTooLargeError", "Server", "ServerClosedError",
-    "ServingConfig", "ServingError", "TransformerLM",
+    "ServingConfig", "ServingError", "TransformerLM", "WindowMoELM",
     "least_loaded_order", "prefill_bucket_grid", "quantize_moe_weights",
     "shard_moe_weights",
 ]

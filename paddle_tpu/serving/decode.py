@@ -10,7 +10,9 @@ latency to every new arrival.  This engine is the TPU-native fix:
 
 - **Persistent per-slot KV cache** (`kv_cache.py`): each of the
   ``slots`` concurrent requests owns paged key/value blocks inside two
-  device-resident pool arrays.  The pools ride
+  device-resident pool arrays (a model's window layers: a ring of
+  pages a slot in pools of their own; its recurrent layers: slabs).
+  The pools ride
   ``Executor.run_persistent`` with donation, so the cache NEVER
   round-trips to host between steps — per-token work is O(1) in the
   prefix length.
@@ -106,7 +108,8 @@ from .buckets import (BucketSpec, DeadlineExceededError, QueueFullError,
                       RequestTooLargeError, ServerClosedError,
                       prefill_bucket_grid, record_pad_waste)
 from . import kv_cache
-from .kv_cache import CacheConfig, PagedKVCache, RecurrentSpec
+from .kv_cache import (CacheConfig, PagedKVCache, RecurrentSpec,
+                       WindowSpec)
 # the reference model lives beside the engine; its names stay importable here
 from .transformer_lm import (TransformerLM, quantize_moe_weights,  # noqa: F401
                              shard_moe_weights)
@@ -206,6 +209,8 @@ def _key_words(seed: int) -> np.ndarray:
     return np.array([0, seed & 0xFFFFFFFF], np.uint32)
 
 
+WINDOW_SCOPE = "window_attention"   # names a window layer's attention ops
+
 DRAFT_K_PAGES_VAR = "__decode_draft_k_pages__"
 DRAFT_V_PAGES_VAR = "__decode_draft_v_pages__"
 DRAFT_K_SCALES_VAR = "__decode_draft_k_scales__"
@@ -229,40 +234,49 @@ def _join_state(pools):
     return tuple(p for p in pools if p is not None)
 
 
+_KINDS = ("attention", "window", "recurrent")  # what a layer may be
+
+
 class _Mixed:
     """The layout of a model whose layers are not all attention (it
     declares ``layer_kinds``): which pool layer an attention layer's K/V
-    live in, which slabs a recurrent layer's state, and how the
-    persistent-state tuple (pools, then slabs layer-major) splits into
-    the ``(pools, recurrent)`` cache that ``forward`` threads."""
+    live in, which layer of the window pools a window layer's, which
+    slabs a recurrent layer's state, and how the persistent-state tuple
+    (pools, then the window pools, then slabs layer-major) splits into
+    the ``(pools, window pools, recurrent)`` cache that ``forward``
+    threads."""
 
-    def __init__(self, model, spec: Optional[RecurrentSpec]):
-        kinds = tuple(model.layer_kinds)
-        self.pool_layer = {l: i for i, l in enumerate(
-            l for l, k in enumerate(kinds) if k == "attention")}
-        self.rec_layer = {l: i for i, l in enumerate(
-            l for l, k in enumerate(kinds) if k != "attention")}
+    def __init__(self, model, spec: Optional[RecurrentSpec],
+                 window: Optional[WindowSpec] = None):
+        self.kinds = tuple(model.layer_kinds)
+        self.layer = {kind: {l: i for i, l in enumerate(
+            l for l, k in enumerate(self.kinds) if k == kind)}
+            for kind in _KINDS}
         self.names = tuple(spec.arrays) if spec is not None else ()
-        self.n_arrays = len(self.rec_layer) * len(self.names)
+        self.n_arrays = len(self.layer["recurrent"]) * len(self.names)
+        self.n_window = 2 if window is not None else 0
         self.tallies = tuple(getattr(model, "tallies", ()))
 
     def split(self, state):
-        n = len(state) - self.n_arrays
-        flat, k = state[n:], len(self.names)
-        return _split_state(state[:n]), tuple(
-            dict(zip(self.names, flat[i * k:(i + 1) * k]))
-            for i in range(len(self.rec_layer)))
+        n = len(state) - self.n_window - self.n_arrays
+        flat, k = state[n + self.n_window:], len(self.names)
+        return (_split_state(state[:n]), tuple(state[n:n + self.n_window]),
+                tuple(dict(zip(self.names, flat[i * k:(i + 1) * k]))
+                      for i in range(len(self.layer["recurrent"]))))
 
     def join(self, cache):
-        pools, rec = cache
-        return _join_state(pools) + tuple(
+        pools, window, rec = cache
+        return _join_state(pools) + tuple(window) + tuple(
             layer[name] for layer in rec for name in self.names)
 
 
 class _Mixers:
     """What ``forward`` of a model with ``layer_kinds`` gets as
-    ``attend``: the call is attention as ever (the model's layer mapped
-    to its pool layer); ``recur(layer, token_fn, rows, cache)`` runs a
+    ``attend``: the call is attention as ever, the model's layer mapped
+    to ITS kind's pools and layer there (``attend`` for an
+    ``"attention"`` layer, ``attend_window`` for a ``"window"`` one, which
+    also gets the call's ``sinks``); ``recur(layer, token_fn, rows,
+    cache)`` runs a
     recurrent layer's one-token update ``token_fn(rows, state) -> (out,
     state)`` where the program keeps that state; ``live`` (bool, the
     rows' shape) says which rows are a request's; ``tally(name, n)``
@@ -271,22 +285,28 @@ class _Mixers:
     declared order); ``record(name, rows)`` keeps a per-row array a
     layer for a request that records its logits."""
 
-    def __init__(self, mixed: _Mixed, recur, live, attend=None):
+    def __init__(self, mixed: _Mixed, recur, live, attend=None,
+                 attend_window=None):
         self._mixed, self._recur, self.attend = mixed, recur, attend
+        self.attend_window = attend_window
         self.live = live
         self.counts, self.records = dict.fromkeys(mixed.tallies, 0), {}
 
-    def __call__(self, layer, q, k, v, cache):
-        pools, rec = cache
-        ctx, pools = self.attend(self._mixed.pool_layer[layer], q, k, v,
-                                 pools)
-        return ctx, (pools, rec)
+    def __call__(self, layer, q, k, v, cache, sinks=None):
+        pools, window, rec = cache
+        if self._mixed.kinds[layer] == "window":
+            ctx, window = self.attend_window(
+                self._mixed.layer["window"][layer], q, k, v, window, sinks)
+        else:
+            ctx, pools = self.attend(self._mixed.layer["attention"][layer],
+                                     q, k, v, pools)
+        return ctx, (pools, window, rec)
 
     def recur(self, layer, token_fn, rows, cache):
-        pools, rec = cache
-        i = self._mixed.rec_layer[layer]
+        pools, window, rec = cache
+        i = self._mixed.layer["recurrent"][layer]
         out, new = self._recur(token_fn, rows, rec[i])
-        return out, (pools, rec[:i] + (new,) + rec[i + 1:])
+        return out, (pools, window, rec[:i] + (new,) + rec[i + 1:])
 
     def tally(self, name, value):
         if name not in self.counts:
@@ -304,9 +324,37 @@ class _Mixers:
         return {n: jnp.stack(v, axis=1) for n, v in self.records.items()}
 
 
+def layers_of_kind(model, kind: str) -> int:
+    """How many of ``model``'s layers are of ``kind`` (one of
+    ``"attention"`` | ``"window"`` | ``"recurrent"``); a model that
+    declares no ``layer_kinds`` has attention layers only."""
+    kinds = getattr(model, "layer_kinds", None)
+    if kinds is None:
+        return int(model.num_layers) if kind == "attention" else 0
+    bad = set(kinds) - set(_KINDS)
+    if bad:
+        raise ValueError(f"layer_kinds holds {sorted(bad)}: a layer is one "
+                         f"of {_KINDS}")
+    return sum(k == kind for k in kinds)
+
+
 def recurrent_layers(model) -> int:
     """How many of ``model``'s layers keep state instead of keys."""
-    return sum(k != "attention" for k in getattr(model, "layer_kinds", ()))
+    return layers_of_kind(model, "recurrent")
+
+
+def per_slot_kinds(model):
+    """The kinds of ``model``'s layers whose state is NOT "every
+    position in pages" (a recurrent layer's one state a slot, a window
+    layer's ring of its last positions), each with what it keeps: what
+    every mechanism that replays, skips, shares or exports positions
+    has to refuse."""
+    keeps = {"recurrent": "keep one state a slot, the state after the "
+                          "LAST token",
+             "window": "keep a slot's last positions only, in a ring of "
+                       "pages that overwrites the oldest"}
+    return [(k, keeps[k]) for k in ("recurrent", "window")
+            if layers_of_kind(model, k)]
 
 
 # ---------------------------------------------------------------------------
@@ -553,18 +601,36 @@ class DecodeEngine:
     about the model; bitwise parity of cached decode with a recompute
     needs a ``forward`` whose other operations are row-independent.
 
-    **Layers that keep state instead of keys**
-    (``serving/hybrid_moe_lm.py`` is the reference).  A model may also
-    declare ``num_kv_heads`` (grouped-query heads: ``k``/``v`` rows of
-    ``[..., Hkv, D]``, the pools ``Hkv * D`` lanes wide, query head i
-    reading K/V head ``i // (H / Hkv)``) and ``layer_kinds``, one of
-    ``"attention"`` | ``"recurrent"`` a layer, with ``recurrent_state``:
-    ``{name: (shape, dtype)}`` of ONE slot's state of ONE recurrent layer,
-    and ``tallies``: the names of the counters its ``forward`` adds to.
-    The pools then hold the attention layers only, the recurrent layers'
-    state lives in slot-indexed slabs beside them (``kv_cache.
-    RecurrentSpec``), and ``attend`` is a ``_Mixers``: the call as above
-    for an attention layer; ``attend.recur(layer, token_fn, rows, cache)
+    **Three kinds of layer, geometry by kind, two lifetimes**
+    (``serving/hybrid_moe_lm.py`` and ``serving/window_moe_lm.py`` are
+    the references).  A model may also declare ``num_kv_heads``
+    (grouped-query heads: ``k``/``v`` rows of ``[..., Hkv, D]``, the
+    pools ``Hkv * D`` lanes wide, query head i reading K/V head ``i //
+    (H / Hkv)``), ``v_head_dim`` (V heads narrower than K's ``head_dim``:
+    the V pool's rows, and ``ctx``, have that width) and ``layer_kinds``,
+    one of ``"attention"`` | ``"window"`` | ``"recurrent"`` a layer:
+
+    * ``"attention"``: every position in pages, for as long as the
+      request lives (the pools above; they hold these layers only).
+    * ``"window"``: the layer attends a position's last ``window``
+      positions (itself counted) and, where its call hands ``sinks=``
+      (a logit a query head), the sink in the softmax's denominator.
+      The model declares ``window`` and ``window_kv_heads`` (this
+      kind's own K/V head count); its K/V live in a second pair of
+      pools in which a slot owns a RING of ``ceil(window / page) + 1``
+      pages a layer, whatever the request's length
+      (``kv_cache.WindowSpec``): the step writes the token into the
+      ring and attends the ring, the whole-prompt prefill attends the
+      prompt's bucket masked to the window and leaves only the window's
+      tail in the ring.  Nothing is uploaded for it.
+    * ``"recurrent"``: ``recurrent_state``, ``{name: (shape, dtype)}``
+      of ONE slot's state of ONE such layer, in slot-indexed slabs
+      (``kv_cache.RecurrentSpec``).
+
+    ``tallies`` are the names of the counters ``forward`` adds to.
+    ``attend`` is then a ``_Mixers``: the call as above for an attention
+    or window layer (mapped to ITS kind's pools);
+    ``attend.recur(layer, token_fn, rows, cache)
     -> (out, cache)`` for a recurrent one, where ``token_fn(rows, state)
     -> (out, state)`` is the model's one-token update over rows
     ``[R, ...]`` and the engine decides what state that is and where it
@@ -575,12 +641,13 @@ class DecodeEngine:
     the model declares by name in ``tallies``; they ride the step's one
     read-back into ``stat_add(name)``) and ``attend.record(name, rows)``
     (a per-row array a layer, kept beside the logits of a
-    ``record_logits`` request in ``req.records``).  Such
-    a model is served by the whole-prompt prefill and the joint step
-    alone: every request is admitted fresh (no prefix index; counter
-    ``decode_prefix_bypassed``), and chunked or ragged prefill,
+    ``record_logits`` request in ``req.records``).  A model with window
+    or recurrent layers is served by the whole-prompt prefill and the
+    joint step alone: every request is admitted fresh (no prefix index;
+    counter ``decode_prefix_bypassed``), and chunked or ragged prefill,
     speculative decoding, ``kv_quant`` and the disaggregated hand-over
-    refuse at construction or submit, naming the mechanism.
+    refuse at construction or submit, naming the kind and the mechanism
+    (``per_slot_kinds``).
 
     ``draft_model``/``draft_weights`` arm speculative decoding (with
     ``spec_k > 0``): the draft's page pools are indexed by the SAME
@@ -632,25 +699,32 @@ class DecodeEngine:
         # also what mesh-sharded (expert-parallel) weights need
         self._device = self._exe.place.jax_device()
         self._pinned = place is not None
-        # layers that keep state instead of keys: pools for the
-        # attention layers only, slot-indexed slabs for the others
+        # layers that keep state instead of keys, or a window of their
+        # keys: pools for the attention layers only, slot-indexed slabs
+        # and rings for the others
         n_rec = recurrent_layers(model)
+        n_win = layers_of_kind(model, "window")
         spec = RecurrentSpec(n_rec, model.recurrent_state) if n_rec \
             else None
-        self._mixed = _Mixed(model, spec) \
+        kv_heads = getattr(model, "num_kv_heads", model.num_heads)
+        v_dim = getattr(model, "v_head_dim", model.head_dim)
+        self._window = WindowSpec(
+            n_win, getattr(model, "window_kv_heads", kv_heads),
+            model.head_dim, v_dim, model.window) if n_win else None
+        self._mixed = _Mixed(model, spec, self._window) \
             if getattr(model, "layer_kinds", None) else None
         # the model's counters behind a step's tokens, as it declares them
         self._tallies = self._mixed.tallies if self._mixed else ()
-        if n_rec:
-            self._refuse_for_recurrent(c, draft_model)
+        self._refuse_for_kinds(model, c, draft_model)
         with jax.default_device(self._device):
             self._cache = PagedKVCache(
-                CacheConfig(max(model.num_layers - n_rec, 1),
-                            getattr(model, "num_kv_heads", model.num_heads),
-                            model.head_dim, c.slots, c.max_seq_len,
-                            c.page_size, num_pages=c.num_pages,
-                            dtype=c.cache_dtype, quantized=c.kv_quant),
-                self._scope, prefix_cache=c.prefix_cache, recurrent=spec)
+                CacheConfig(max(model.num_layers - n_rec - n_win, 1),
+                            kv_heads, model.head_dim, c.slots,
+                            c.max_seq_len, c.page_size,
+                            num_pages=c.num_pages, dtype=c.cache_dtype,
+                            quantized=c.kv_quant, v_head_dim=v_dim),
+                self._scope, prefix_cache=c.prefix_cache, recurrent=spec,
+                window=self._window)
         # whether the pools' one layout is also an unpadded one (the
         # tile rule in serving/kv_cache.py): the counter that says the
         # lane-dense representation engaged for this model's shape
@@ -665,9 +739,15 @@ class DecodeEngine:
         cc = self._cache.config
         self._attn_block = cc.page_size * pages_per_block(
             cc.page_size, cc.pages_per_slot, cc.row_lanes,
-            cc.store_dtype.itemsize)
+            cc.store_dtype.itemsize, cc.v_row_lanes)
         self._attn_table_blocks = cc.num_slots * -(
             -cc.max_seq_len // self._attn_block)
+        if self._window is not None:
+            w = self._window
+            self._ring = w.ring_pages(cc.page_size)
+            self._window_block = cc.page_size * pages_per_block(
+                cc.page_size, self._ring, w.num_heads * w.head_dim,
+                cc.store_dtype.itemsize, w.num_heads * w.v_head_dim)
         # per-request timeline hook: claim/CoW/register/evict events
         # from the cache land on the owning request's trace
         self._cache.on_event = self._on_cache_event
@@ -677,6 +757,7 @@ class DecodeEngine:
         # pools join them under FLAGS_decode_kv_quant)
         self._state_vars = self._cache.state_var_names()
         n_pools = len(self._state_vars) \
+            - len(self._cache.window_var_names()) \
             - len(self._cache.recurrent_var_names())
         self._draft_state_vars = ()
         if draft_model is not None:
@@ -736,28 +817,31 @@ class DecodeEngine:
         self._decode_steps = 0
 
     @staticmethod
-    def _refuse_for_recurrent(c: "DecodeConfig", draft_model) -> None:
-        """A model with recurrent layers runs the whole-prompt prefill
-        and the joint step; every mechanism that replays, skips or
-        exports positions would need the state at a position that no
-        slab holds, and refuses here rather than run wrong."""
-        why = ("the model's recurrent layers keep one state a slot, "
-               "the state after the LAST token: ")
-        if c.prefill_chunk_pages > 0 or c.ragged_prefill_rows > 0:
-            raise ValueError(
-                why + "chunked/ragged prefill (prefill_chunk_pages="
-                f"{c.prefill_chunk_pages}, ragged_prefill_rows="
-                f"{c.ragged_prefill_rows}) would have to carry it from "
-                "chunk to chunk, which the multi-row step does not")
-        if draft_model is not None or c.spec_k > 0:
-            raise ValueError(
-                why + "speculative decoding (a draft model, spec_k="
-                f"{c.spec_k}) would have to rewind it past rejected "
-                "proposals, which nothing here can")
-        if c.kv_quant:
-            raise ValueError(
-                why + "kv_quant (int8 K/V pages) is not wired for a "
-                "model whose pools hold only some of its layers")
+    def _refuse_for_kinds(model, c: "DecodeConfig", draft_model) -> None:
+        """A model with layers whose state is not "every position in
+        pages" (``per_slot_kinds``) runs the whole-prompt prefill and
+        the joint step; every mechanism that replays, skips or exports
+        positions would need a state, or a position, that nothing
+        holds, and refuses here, by kind and mechanism, rather than run
+        wrong."""
+        for kind, keeps in per_slot_kinds(model):
+            why = f"the model's {kind} layers {keeps}: "
+            if c.prefill_chunk_pages > 0 or c.ragged_prefill_rows > 0:
+                raise ValueError(
+                    why + "chunked/ragged prefill (prefill_chunk_pages="
+                    f"{c.prefill_chunk_pages}, ragged_prefill_rows="
+                    f"{c.ragged_prefill_rows}) would have to carry that "
+                    "from chunk to chunk, which the multi-row step does "
+                    "not")
+            if draft_model is not None or c.spec_k > 0:
+                raise ValueError(
+                    why + "speculative decoding (a draft model, spec_k="
+                    f"{c.spec_k}) would have to rewind that past rejected "
+                    "proposals, which nothing here can")
+            if c.kv_quant:
+                raise ValueError(
+                    why + "kv_quant (int8 K/V pages) is not wired for a "
+                    "model whose pools hold only some of its layers")
 
     def _commit(self, tree):
         """Device arrays for ``tree``; on a pinned replica every leaf
@@ -826,7 +910,7 @@ class DecodeEngine:
                 k_pages, k_scales, l, k.reshape(flat),
                 write_page.reshape(-1), write_off.reshape(-1))
             v_pages, v_scales = kv_cache.write_token_layer(
-                v_pages, v_scales, l, v.reshape(flat),
+                v_pages, v_scales, l, v.reshape((-1,) + v.shape[-2:]),
                 write_page.reshape(-1), write_off.reshape(-1))
             # all backend dispatch (auto/always/never, Pallas vs the
             # gather+mask reference) lives in ONE place: the op itself —
@@ -839,6 +923,35 @@ class DecodeEngine:
                     interpret=self.config.interpret,
                     k_scales=k_scales, v_scales=v_scales)
             return ctx, (k_pages, v_pages, k_scales, v_scales)
+
+        return attend
+
+    def _window_attend(self, lengths, write_page, write_off):
+        """The joint step's ``attend`` of a window layer: the token's
+        K/V written into the slot's ring at (page, offset), then each
+        row attending the ring's last ``window`` positions (and the
+        layer's sink); the ring's table is a constant of the program."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.pallas_decode_attention import paged_decode_attention
+
+        cc, w = self._cache.config, self._window
+        table = jnp.asarray(w.ring_table(cc.num_slots, cc.page_size))
+
+        def attend(l, q, k, v, pools, sinks):
+            k_pages, v_pages = pools
+            k_pages = kv_cache.scatter_token_layer(
+                k_pages, l, k, write_page, write_off)
+            v_pages = kv_cache.scatter_token_layer(
+                v_pages, l, v, write_page, write_off)
+            with jax.named_scope(WINDOW_SCOPE):
+                ctx = paged_decode_attention(
+                    q, k_pages, v_pages, table, lengths, layer=l,
+                    use_pallas=self.config.use_pallas,
+                    interpret=self.config.interpret, window=w.window,
+                    sinks=sinks)
+            return ctx, (k_pages, v_pages)
 
         return attend
 
@@ -903,6 +1016,17 @@ class DecodeEngine:
                 new_state = _join_state(pools)
             else:
                 mix = _Mixers(mixed, recur, live)
+                if self._window is not None:
+                    # the position's page of the slot's own ring
+                    # (kv_cache.WindowSpec.ring_table), trash for a dead
+                    # slot
+                    slot = jnp.arange(live.shape[0], dtype=jnp.int32)
+                    ring = self._ring
+                    mix.attend_window = self._window_attend(
+                        positions + 1, jnp.where(
+                            write, 1 + slot * ring
+                            + (positions // page_size) % ring, 0),
+                        write_off)
                 logits, cache = self._token_step_body(
                     model, weights, mixed.split(state), a["token"],
                     positions, page_table, write_page, write_off, mix=mix)
@@ -1004,13 +1128,30 @@ class DecodeEngine:
                     row_lengths)
                 return ctx, (k_pages, v_pages, k_scales, v_scales)
 
+            def attend_window(l, q, k, v, pools, sinks):
+                """A window layer of the prompt: masked to the window,
+                with the sink, at the prompt's bucket; of its K/V only
+                the tail a later token can still attend goes into the
+                slot's ring."""
+                ring_row = jnp.asarray(self._window.ring_table(
+                    cc.num_slots, cc.page_size))[a["slot"]]
+                pools = tuple(kv_cache.write_window_prompt_layer(
+                    pool, l, val, length, ring_row)
+                    for pool, val in zip(pools, (k, v)))
+                with jax.named_scope(WINDOW_SCOPE):
+                    ctx = grouped_causal_attention(
+                        q, k.astype(cdt), v.astype(cdt),
+                        window=self._window.window, sinks=sinks)
+                return ctx, pools
+
             if mixed is None:
                 logits, pools = model.forward(              # [T_pad, V]
                     weights, tokens, positions, _split_state(state),
                     attend)
                 new_state = _join_state(pools)
             else:
-                mix = _Mixers(mixed, recur, positions < length, attend)
+                mix = _Mixers(mixed, recur, positions < length, attend,
+                              attend_window)
                 logits, cache = model.forward(
                     weights, tokens, positions, mixed.split(state), mix)
                 new_state = mixed.join(cache)
@@ -1206,11 +1347,12 @@ class DecodeEngine:
         if not prompt:
             raise ValueError("prompt must hold at least one token id")
         if (extract_kv or kv_import is not None) \
-                and self._cache.recurrent is not None:
+                and per_slot_kinds(self.model):
+            kind, keeps = per_slot_kinds(self.model)[0]
             raise ValueError(
                 "disaggregated serving hands a prompt over as its K/V "
-                "pages (extract_kv / kv_import); this model's recurrent "
-                "layers keep state that is not in any page, so the "
+                f"pages (extract_kv / kv_import); this model's {kind} "
+                f"layers {keeps}, which no exported page holds, so the "
                 "hand-over would decode from the wrong state")
         if kv_import is not None:
             # migrated admission (serving/disagg.py): validate the
@@ -1342,6 +1484,7 @@ class DecodeEngine:
                  1 if self.config.kv_quant else 0)
         stat_set("decode_kv_page_bytes", self._cache.config.page_bytes())
         stat_set("decode_state_bytes", self._cache.state_bytes())
+        stat_set("decode_window_bytes", self._cache.window_bytes())
         return self
 
     def stop(self, drain: bool = True):
@@ -2138,7 +2281,29 @@ class DecodeEngine:
             stat_add("decode_attn_blocks_live", int(
                 (positions // self._attn_block + 1).sum()))
             stat_add("decode_attn_blocks_walked", self._attn_table_blocks)
+            if self._window is not None:
+                self._count_window(positions[list(live_idx)])
         return _words(rows)
+
+    def _count_window(self, positions) -> None:
+        """A joint step's window-layer counters from the live slots'
+        positions: what the window kernel walks and attends (a layer),
+        and what the rings hold and recycle (all window layers)."""
+        w, page = self._window, self._cache.config.page_size
+        n = positions + 1                       # positions attended
+        first = np.maximum(n - w.window, 0)
+        stat_add("decode_window_blocks_walked", int(
+            (positions // self._window_block
+             - first // self._window_block + 1).sum()))
+        stat_add("decode_window_positions_live", int(
+            np.minimum(n, w.window).sum()))
+        # a token that opens a page the ring has already been round
+        # once overwrites the page that slid out of the window
+        stat_add("decode_window_pages_recycled", w.num_layers * int(
+            ((positions % page == 0)
+             & (positions // page >= self._ring)).sum()))
+        stat_set("decode_window_pages_held",
+                 self._cache.window_pages_held())
 
     def _lower(self, fn, packed, sharding):
         """``fn`` (the step, a whole-prompt prefill) lowered at this
@@ -2402,18 +2567,24 @@ class DecodeEngine:
         tokens = [int(t) for t in tokens]
         t_pad = self._buckets.seq_bucket(len(tokens))
         cc = self._cache.config
-        shape = cc.pool_shape()
+        shape, vshape = cc.pool_shape(), cc.pool_shape(
+            row_lanes=cc.v_row_lanes)
         if qz:
             sshape = cc.pool_shape(row_lanes=cc.num_heads)
             scratch = (jnp.zeros(shape, jnp.int8),
-                       jnp.zeros(shape, jnp.int8),
+                       jnp.zeros(vshape, jnp.int8),
                        jnp.full(sshape, kv_cache.SCALE_EPS,
                                 cc.scale_dtype),
                        jnp.full(sshape, kv_cache.SCALE_EPS,
                                 cc.scale_dtype))
         else:
             scratch = (jnp.zeros(shape, cc.dtype),
-                       jnp.zeros(shape, cc.dtype))
+                       jnp.zeros(vshape, cc.dtype))
+        if self._window is not None:
+            # slot 0's ring is all a throwaway prefill writes
+            scratch += tuple(
+                jnp.zeros(wshape, cc.dtype) for wshape in
+                self._window.pool_shapes(1, cc.page_size))
         if self._cache.recurrent is not None:
             # one slot's worth of throwaway state rows
             rec = self._cache.recurrent

@@ -244,15 +244,15 @@ class DisaggServer:
                  disagg: Optional[DisaggConfig] = None, place=None,
                  autoscale: bool = False,
                  autoscaler_kw: Optional[dict] = None):
-        from .decode import DecodeConfig, DecodeEngine, recurrent_layers
+        from .decode import DecodeConfig, DecodeEngine, per_slot_kinds
         from .server import replica_places
 
-        if recurrent_layers(model):
+        for kind, keeps in per_slot_kinds(model):
             raise ValueError(
                 "disaggregated serving hands a prompt from a prefill "
                 "replica to a decode replica as its K/V pages; this "
-                "model's recurrent layers keep state that is not in any "
-                "page, so it cannot be served disaggregated")
+                f"model's {kind} layers {keeps}, which no exported page "
+                "holds, so it cannot be served disaggregated")
         self.config = config or DecodeConfig()
         self.disagg = disagg or DisaggConfig()
         d = self.disagg

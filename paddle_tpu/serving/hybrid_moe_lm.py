@@ -186,14 +186,8 @@ class HybridMoELM:
                     lw["kda_wout"])
             x = x + y
             h = self._rms(x, lw["norm2"])
-            ids, _, local = moe_ops.moe_share_route(
-                h, lw["moe_router"], lw["moe_router_bias"],
-                top_k=self.top_k, held_ids=self.held_experts,
-                live=attend.live)
-            assigned, hit = moe_ops.moe_share_counts(local)
-            attend.tally("moe_local_assignments", assigned)
-            attend.tally("moe_experts_hit", hit)
-            attend.record("moe_topk", ids)
+            local = route_share(h, lw, attend, self.top_k,
+                                self.held_experts)
             with jax.named_scope("moe_shared"):
                 shared = _mm(jax.nn.silu(_mm(h, lw["shared_w_gate"]))
                              * _mm(h, lw["shared_w_up"]),
@@ -204,11 +198,7 @@ class HybridMoELM:
         return _mm(self._rms(x, w["norm_f"]), w["lm_head"]), cache
 
     def _rms(self, x, g):
-        import jax
-        import jax.numpy as jnp
-
-        return x * jax.lax.rsqrt(
-            jnp.mean(x * x, axis=-1, keepdims=True) + self.rms_eps) * g
+        return rms_norm(x, g, self.rms_eps)
 
     def _kda_token(self, lw, rows, state):
         """One token a row through a recurrent layer: ``rows`` the
@@ -252,3 +242,26 @@ def _mm(a, w):
 
     return jnp.matmul(a.astype(w.dtype), w,
                       preferred_element_type=jnp.float32)
+
+
+def rms_norm(x, g, eps):
+    import jax
+    import jax.numpy as jnp
+
+    return x * jax.lax.rsqrt(
+        jnp.mean(x * x, axis=-1, keepdims=True) + eps) * g
+
+
+def route_share(h, lw, attend, top_k, held_experts):
+    """Rows ``h`` routed over all of the layer's experts: the weights
+    of the held ones a row (``moe_ops.moe_share_route``'s ``local``),
+    with the counts tallied and the chosen ids recorded through
+    ``attend``.  Shared with ``window_moe_lm.py``."""
+    ids, _, local = moe_ops.moe_share_route(
+        h, lw["moe_router"], lw["moe_router_bias"], top_k=top_k,
+        held_ids=held_experts, live=attend.live)
+    assigned, hit = moe_ops.moe_share_counts(local)
+    attend.tally("moe_local_assignments", assigned)
+    attend.tally("moe_experts_hit", hit)
+    attend.record("moe_topk", ids)
+    return local

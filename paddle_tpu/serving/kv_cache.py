@@ -4,10 +4,12 @@
 Layout (the vLLM PagedAttention idea, TPU-native): all keys/values for
 every serving slot live in TWO device arrays of fixed-size pages
 
-    k_pages, v_pages : [num_layers, num_pages, page_size, heads * head_dim]
+    k_pages : [num_layers, num_pages, page_size, heads * head_dim]
+    v_pages : [num_layers, num_pages, page_size, heads * v_head_dim]
 
 (a position's heads folded into ONE lane-dense row; see "Device layout"
-below) and each slot owns an ordered list of page ids (its *page table*).  A
+below; V's heads may be narrower than K's) and each slot owns an
+ordered list of page ids (its *page table*).  A
 slot's logical sequence position ``t`` maps to page ``table[t // page]``
 offset ``t % page``.  Pages are allocated from a host-side free list at
 admission and returned when their REFCOUNT drops to zero — a finished
@@ -90,6 +92,29 @@ three quarters of the layers reached after the same tokens, so every
 request is admitted fresh (``prefix_bypassed`` says the index was asked
 for and left out; the engine counts the admissions).
 
+**Geometry by layer kind, two lifetimes** (``WindowSpec``).  A layer
+that attends a sliding window needs a slot's last ``window`` positions
+and no others, however long the request grows, and may have other head
+counts than the layers that attend everything.  Its K/V live in a second
+pair of pools of their own geometry
+
+    window k/v : [window_layers, num_slots * ring + 1, page_size, lanes]
+
+in which a slot owns a RING of ``ring = ceil(window / page_size) + 1``
+pages: position ``t`` lands in ring entry ``(t // page_size) % ring`` at
+offset ``t % page_size``, overwriting the page that slid out of the
+window (the ``+ 1``: a window that starts mid-page still has its oldest
+positions while the newest page fills).  The slot IS the allocation, as
+for recurrent state: slot ``s`` owns pages ``1 + s * ring ...``, claimed
+and released with the slot by the one ``claim``/``release``, page 0 is
+this pool's own trash page, nothing is uploaded for it (``ring_table``
+is a constant) and its bytes do not depend on ``max_seq_len``.  The
+pools above then hold the layers that attend every position only.  A
+cache with window layers has NO prefix index either (a shared page of a
+global layer says nothing of what the ring held at that position), and
+no page of it can be exported: ``prefix_bypassed`` as for recurrent
+layers.
+
 **Quantized storage** (``FLAGS_decode_kv_quant``): pages are stored
 int8 (same folded rows) beside parallel scale pools ``[layers, pages,
 page_size, heads]``
@@ -122,6 +147,8 @@ K_PAGES_VAR = "__decode_k_pages__"
 V_PAGES_VAR = "__decode_v_pages__"
 K_SCALES_VAR = "__decode_k_scales__"
 V_SCALES_VAR = "__decode_v_scales__"
+WINDOW_K_VAR = "__decode_window_k_pages__"
+WINDOW_V_VAR = "__decode_window_v_pages__"
 
 KV_QMAX = 127.0  # symmetric int8 grid for quantized pages
 
@@ -165,7 +192,9 @@ class CacheConfig:
     """Geometry of the paged cache (everything static / compile-time).
 
     A pool is ``[num_layers, num_pages, page_size, row_lanes]`` with
-    ``row_lanes = num_heads * head_dim``: one position's heads folded
+    ``row_lanes = num_heads * head_dim`` (``v_row_lanes = num_heads *
+    v_head_dim`` for the V pool where ``v_head_dim`` is given): one
+    position's heads folded
     into one row (the module header's tile rule).  ``lane_dense`` says
     whether that row and the page fill the chip's (8, 128) tiles
     exactly, i.e. whether the pool keeps one unpadded layout through
@@ -188,7 +217,8 @@ class CacheConfig:
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  num_slots: int, max_seq_len: int, page_size: int,
                  num_pages: Optional[int] = None, dtype="float32",
-                 quantized: bool = False):
+                 quantized: bool = False,
+                 v_head_dim: Optional[int] = None):
         if max_seq_len % page_size:
             raise ValueError(
                 f"max_seq_len ({max_seq_len}) must be a multiple of "
@@ -196,6 +226,8 @@ class CacheConfig:
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
+        self.v_head_dim = int(head_dim if v_head_dim is None
+                              else v_head_dim)
         self.num_slots = int(num_slots)
         self.max_seq_len = int(max_seq_len)
         self.page_size = int(page_size)
@@ -224,10 +256,16 @@ class CacheConfig:
         return self.num_heads * self.head_dim
 
     @property
+    def v_row_lanes(self) -> int:
+        """Width of one stored position of V."""
+        return self.num_heads * self.v_head_dim
+
+    @property
     def lane_dense(self) -> bool:
         """Whether ``(page_size, row_lanes)`` fills the chip's (8, 128)
-        tiles exactly (the module header's tile rule)."""
-        return self.row_lanes % 128 == 0 and self.page_size % 8 == 0
+        tiles exactly (the module header's tile rule), V's rows too."""
+        return self.row_lanes % 128 == 0 and self.v_row_lanes % 128 == 0 \
+            and self.page_size % 8 == 0
 
     def pool_shape(self, num_layers: Optional[int] = None,
                    row_lanes: Optional[int] = None) -> Tuple[int, ...]:
@@ -240,11 +278,13 @@ class CacheConfig:
     def pages_for(self, seq_len: int) -> int:
         return max(1, math.ceil(int(seq_len) / self.page_size))
 
-    def page_bytes(self) -> int:
-        """Device bytes ONE page costs in one pool — including its
+    def page_bytes(self, v: bool = False) -> int:
+        """Device bytes ONE page costs in one pool (K's, or with ``v``
+        V's) — including its
         scale plane when quantized, so capacity math can't hide the
         scale overhead."""
-        data = (self.page_size * self.num_heads * self.head_dim
+        data = (self.page_size * self.num_heads
+                * (self.v_head_dim if v else self.head_dim)
                 * self.store_dtype.itemsize)
         if self.quantized:
             data += (self.page_size * self.num_heads
@@ -255,7 +295,8 @@ class CacheConfig:
         """Total device bytes one page costs across EVERY pool (k + v,
         all layers, scale planes included) — the unit a fixed byte
         budget is divided by to size ``num_pages``."""
-        return 2 * self.num_layers * self.page_bytes()
+        return self.num_layers * (self.page_bytes()
+                                  + self.page_bytes(v=True))
 
     def cache_bytes(self) -> int:
         """Total device bytes of the page arrays (k + v, scale pools
@@ -287,6 +328,46 @@ class RecurrentSpec:
         return self.num_layers * sum(
             int(np.prod(shape)) * dtype.itemsize
             for shape, dtype in self.arrays.values())
+
+
+class WindowSpec:
+    """The K/V of a model's layers that attend a sliding ``window``
+    (the module header: a ring of pages a slot, in pools of their own):
+    ``num_layers`` such layers, ``num_heads`` K/V heads of ``head_dim``
+    (K) and ``v_head_dim`` (V) lanes."""
+
+    def __init__(self, num_layers: int, num_heads: int, head_dim: int,
+                 v_head_dim: int, window: int):
+        self.num_layers, self.num_heads = int(num_layers), int(num_heads)
+        self.head_dim, self.v_head_dim = int(head_dim), int(v_head_dim)
+        self.window = int(window)
+        if self.window < 1:
+            raise ValueError(f"window must be positive, got {window}")
+
+    def ring_pages(self, page_size: int) -> int:
+        """Pages a slot's ring holds a layer: the window's, and one more
+        for the page that fills while the oldest is still attended."""
+        return math.ceil(self.window / int(page_size)) + 1
+
+    def pool_shapes(self, num_slots: int, page_size: int):
+        """(K pool shape, V pool shape); page 0 is the pools' trash."""
+        lead = (self.num_layers,
+                int(num_slots) * self.ring_pages(page_size) + 1,
+                int(page_size))
+        return (lead + (self.num_heads * self.head_dim,),
+                lead + (self.num_heads * self.v_head_dim,))
+
+    def ring_table(self, num_slots: int, page_size: int) -> np.ndarray:
+        """[num_slots, ring] page ids: slot s owns ``1 + s * ring ...``;
+        a constant of every program, never uploaded."""
+        ring = self.ring_pages(page_size)
+        return (1 + np.arange(int(num_slots) * ring, dtype=np.int32)
+                ).reshape(int(num_slots), ring)
+
+    def bytes(self, num_slots: int, page_size: int, itemsize: int) -> int:
+        """Device bytes of both pools: no term in ``max_seq_len``."""
+        return sum(int(np.prod(shape)) * int(itemsize)
+                   for shape in self.pool_shapes(num_slots, page_size))
 
 
 class PageAllocator:
@@ -501,18 +582,22 @@ class PagedKVCache:
     Executor.run_persistent can donate them through each decode step."""
 
     def __init__(self, config: CacheConfig, scope, prefix_cache=True,
-                 recurrent: Optional[RecurrentSpec] = None):
+                 recurrent: Optional[RecurrentSpec] = None,
+                 window: Optional[WindowSpec] = None):
         import jax.numpy as jnp
 
         self.config = config
         self.scope = scope
         # slot-indexed slabs of the layers that keep state instead of
-        # keys (module header); with them there is no prefix index
+        # keys, slot-indexed rings of the layers that attend a window
+        # (module header); with either there is no prefix index
         self.recurrent = recurrent if recurrent is not None \
             and recurrent.num_layers else None
-        self.prefix_bypassed = bool(prefix_cache) \
-            and self.recurrent is not None
-        prefix_cache = bool(prefix_cache) and self.recurrent is None
+        self.window = window if window is not None \
+            and window.num_layers else None
+        per_slot = self.recurrent is not None or self.window is not None
+        self.prefix_bypassed = bool(prefix_cache) and per_slot
+        prefix_cache = bool(prefix_cache) and not per_slot
         # optional per-request tracing hook: ``on_event(slot, name,
         # **attrs)`` fired on cache lifecycle events (cow_swap, evict,
         # register) — the decode engine wires it to the owning
@@ -536,9 +621,14 @@ class PagedKVCache:
         # reserved CoW target for a borrowed partial page (at most one)
         self._cow_spare: List[List[int]] = [[] for _ in range(c.num_slots)]
         self._refs = [0] * c.num_pages
-        shape = c.pool_shape()
-        scope.set_var(K_PAGES_VAR, jnp.zeros(shape, c.store_dtype))
-        scope.set_var(V_PAGES_VAR, jnp.zeros(shape, c.store_dtype))
+        scope.set_var(K_PAGES_VAR, jnp.zeros(c.pool_shape(), c.store_dtype))
+        scope.set_var(V_PAGES_VAR, jnp.zeros(
+            c.pool_shape(row_lanes=c.v_row_lanes), c.store_dtype))
+        if self.window is not None:
+            for var, shape in zip((WINDOW_K_VAR, WINDOW_V_VAR),
+                                  self.window.pool_shapes(c.num_slots,
+                                                          c.page_size)):
+                scope.set_var(var, jnp.zeros(shape, c.store_dtype))
         # quantized mode: parallel per-page scale pools (one scale per
         # head per position-in-page), plus the freed-page reset queue
         # the scale audit relies on.  ``scale_vars`` also collects any
@@ -570,15 +660,36 @@ class PagedKVCache:
     def state_var_names(self) -> Tuple[str, ...]:
         """Scope names a persistent step must thread (in order): the
         two page pools, plus the scale pools when quantized, then the
-        recurrent layers' slabs."""
+        window layers' two pools, then the recurrent layers' slabs."""
         names = (K_PAGES_VAR, V_PAGES_VAR)
         if self.config.quantized:
             names += (K_SCALES_VAR, V_SCALES_VAR)
-        return names + self.recurrent_var_names()
+        return names + self.window_var_names() + self.recurrent_var_names()
+
+    def window_var_names(self) -> Tuple[str, ...]:
+        return (WINDOW_K_VAR, WINDOW_V_VAR) if self.window is not None \
+            else ()
 
     def recurrent_var_names(self) -> Tuple[str, ...]:
         return self.recurrent.var_names() if self.recurrent is not None \
             else ()
+
+    def window_bytes(self) -> int:
+        """Device bytes of the window layers' pools, all slots."""
+        c = self.config
+        return self.window.bytes(c.num_slots, c.page_size,
+                                 c.store_dtype.itemsize) \
+            if self.window is not None else 0
+
+    def window_pages_held(self) -> int:
+        """Ring pages that hold a position of a live request, over the
+        window layers: never more than ``slots * layers * ring``."""
+        if self.window is None:
+            return 0
+        c = self.config
+        ring = self.window.ring_pages(c.page_size)
+        pages = -(-self.lengths // c.page_size)
+        return int(np.minimum(pages, ring).sum()) * self.window.num_layers
 
     def state_bytes(self) -> int:
         """Device bytes of the recurrent layers' slabs, all slots."""
@@ -775,6 +886,10 @@ class PagedKVCache:
             raise ValueError(
                 "a cache with recurrent state exports no pages: the "
                 "state of the layers without keys is not in them")
+        if self.window is not None:
+            raise ValueError(
+                "a cache with window layers exports no pages: a slot's "
+                "ring of them holds its last positions only")
         idx = np.asarray([int(p) for p in pages], np.int32)
         return {name: self.scope.get_var(name)[:, idx]
                 for name in self.state_var_names()}
@@ -943,6 +1058,24 @@ class PagedKVCache:
                     f"scale pool {name}: migrated-in pages "
                     f"{mig_idx.tolist()} hold non-finite/non-positive "
                     f"scales — the migration dropped a scale plane")
+        if self.window is not None:
+            # the rings: a slot's pages are its own by arithmetic, so
+            # what can go wrong is the table or the pools' size, and a
+            # ring that claims more pages than it has
+            c = self.config
+            ring = self.window.ring_pages(c.page_size)
+            table = self.window.ring_table(c.num_slots, c.page_size)
+            assert table.min() == 1 and len(set(table.ravel().tolist())) \
+                == c.num_slots * ring, "window rings share a page"
+            for var, shape in zip(self.window_var_names(),
+                                  self.window.pool_shapes(c.num_slots,
+                                                          c.page_size)):
+                got = tuple(self.scope.get_var(var).shape)
+                assert got == shape and got[1] == table.max() + 1, (
+                    f"window pool {var} is {got}, not {shape}: its "
+                    f"size must not follow the sequence length")
+            assert self.window_pages_held() <= \
+                c.num_slots * self.window.num_layers * ring
         if not self.config.quantized:
             return
         free_idx = np.asarray(sorted(free), np.int32)
@@ -1044,3 +1177,21 @@ def write_prompt_layer(pages, scales, layer: int, val, page_ids):
     return (scatter_prompt_layer(pages, layer, q, page_ids),
             scales.at[layer, page_ids].set(
                 s.reshape(n, -1, s.shape[-1]).astype(scales.dtype)))
+
+
+def write_window_prompt_layer(pages, layer: int, val, length, ring_row):
+    """The whole-prompt prefill's write into a window layer's ring: of
+    ``val`` [n_pages*page, H, D] (the prompt padded to its bucket, of
+    which ``length`` positions are real) only the LAST ``ring`` pages
+    that hold a real position are stored, logical page j at ``ring_row[j
+    % ring]`` (``ring_row`` [ring]: the slot's page ids); where the prompt
+    has fewer, the rest of the writes aim at page 0 (trash)."""
+    import jax.numpy as jnp
+
+    ring, page = ring_row.shape[0], pages.shape[2]
+    v = _fold_heads(val).reshape(-1, page, pages.shape[3])
+    last = (length - 1) // page                    # the newest real page
+    logical = last - (ring - 1) + jnp.arange(ring, dtype=jnp.int32)
+    ids = jnp.where(logical >= 0, ring_row[logical % ring], 0)
+    src = jnp.take(v, jnp.clip(logical, 0, v.shape[0] - 1), axis=0)
+    return pages.at[layer, ids].set(src.astype(pages.dtype))

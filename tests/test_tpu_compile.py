@@ -496,3 +496,36 @@ def _assert_the_benchmarks_pattern_finds_the_state_update(text, slabs):
     assert not [line for line in matched if "paged_attention" in line]
     # the pools are operands 0 and 1: the pattern leaves them out
     assert not pat.search("%x = f32[1] fusion(%state_0_.1, %state_1_.1)")
+
+
+# -- MiMo-V2.5's rows: K wider than V, a window off a ring, a sink ----------
+
+@pytest.mark.parametrize("kv_heads, window", [(4, None), (8, 128)],
+                         ids=["global_768_512", "window_1536_1024"])
+def test_paged_attention_compiles_at_mimo_rows(one_chip, kv_heads, window):
+    """64 query heads of 192 lanes over 4 (global) or 8 (window) K/V
+    heads, K rows of 768 / 1,536 bf16 lanes beside V rows of 512 /
+    1,024, pages of 16: the global call over a table of 256 pages a slot
+    (4,096 positions), the window call over a ring of 9 with its sinks
+    and its own name."""
+    pps = PPS * 4 if window is None else 9
+    pool = SLOTS * pps + 1
+    shapes = [((SLOTS, 64, 192), jnp.float32),
+              ((LAYERS, pool, 16, kv_heads * 192), jnp.bfloat16),
+              ((LAYERS, pool, 16, kv_heads * 128), jnp.bfloat16),
+              ((SLOTS, pps), jnp.int32), ((SLOTS,), jnp.int32)]
+    if window is None:
+        fn = functools.partial(_paged, pda.paged_decode_attention)
+    else:
+        shapes.append(((64,), jnp.float32))
+
+        def fn(q, k, v, pt, ln, sinks):
+            return pda.paged_decode_attention(
+                q, k, v, pt, ln, layer=1, use_pallas="always",
+                window=window, sinks=sinks)
+
+    text = _compile(one_chip, fn, *shapes)
+    assert text.count("tpu_custom_call") == 1
+    assert ("paged_attention_window" in text) == (window is not None)
+    assert re.search(r"bf16\[%d,64,128\]|f32\[%d,64,128\]" % (SLOTS, SLOTS),
+                     text), "the output has V's width"
