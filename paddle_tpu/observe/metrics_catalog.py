@@ -186,6 +186,14 @@ RULES = (
          "row can see (length x (length + 1) / 2).  Their ratio is the "
          "share of the attention that is work; the rest is the causal "
          "triangle's other half and the bucket's padding"),
+    Rule("decode_steps_", "gauge", "serving",
+         "Joint decode steps whose sampler does more than an argmax, "
+         "added once a step from the knobs the engine hands over: "
+         "`_drawn` the steps in which a live slot has temperature > 0 "
+         "(the categorical draw runs), `_filtered` those in which such "
+         "a slot also has top_k > 0 or top_p < 1 (the vocabulary's sort "
+         "runs).  Either over `decode_steps` is the share of steps that "
+         "pay for it; every other step takes the argmax alone"),
     Rule("decode_state_bytes", "gauge", "serving",
          "Device bytes of the slot-indexed slabs that hold the state of "
          "a model's recurrent layers (linear attention: a matrix a head "

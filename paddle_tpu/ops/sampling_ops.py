@@ -229,22 +229,31 @@ def filter_top_k_top_p(logits, top_k, top_p):
     data, not static arguments, so one compiled step serves any mix of
     per-slot sampling configs.  Ties at the threshold logit are kept
     (the standard sorted-threshold caveat).
+
+    The sort runs only when some row asks for a filter (a ``lax.cond``
+    on the knobs): a row with both disabled keeps every logit either
+    way, so its result does not depend on its neighbours.
     """
-    v = logits.shape[-1]
-    desc = jnp.flip(jnp.sort(logits, axis=-1), axis=-1)
-    # top-k: keep logits >= the k-th largest (k clipped into [1, V])
-    k_idx = jnp.clip(top_k - 1, 0, v - 1)
-    thresh_k = jnp.take_along_axis(desc, k_idx[..., None], axis=-1)
-    keep_k = (top_k <= 0)[..., None] | (logits >= thresh_k)
-    # top-p: over the sorted distribution keep the minimal prefix whose
-    # mass reaches p (the first token is always kept: cum - prob < p)
-    probs = jax.nn.softmax(desc, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep_sorted = (cum - probs) < top_p[..., None]
-    thresh_p = jnp.min(jnp.where(keep_sorted, desc, jnp.inf), axis=-1,
-                       keepdims=True)
-    keep_p = (top_p >= 1.0)[..., None] | (logits >= thresh_p)
-    return jnp.where(keep_k & keep_p, logits, -jnp.inf)
+    def filtered():
+        v = logits.shape[-1]
+        desc = jnp.flip(jnp.sort(logits, axis=-1), axis=-1)
+        # top-k: keep logits >= the k-th largest (k clipped into [1, V])
+        k_idx = jnp.clip(top_k - 1, 0, v - 1)
+        thresh_k = jnp.take_along_axis(desc, k_idx[..., None], axis=-1)
+        keep_k = (top_k <= 0)[..., None] | (logits >= thresh_k)
+        # top-p: over the sorted distribution keep the minimal prefix
+        # whose mass reaches p (the first token is always kept: cum -
+        # prob < p)
+        probs = jax.nn.softmax(desc, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep_sorted = (cum - probs) < top_p[..., None]
+        thresh_p = jnp.min(jnp.where(keep_sorted, desc, jnp.inf), axis=-1,
+                           keepdims=True)
+        keep_p = (top_p >= 1.0)[..., None] | (logits >= thresh_p)
+        return jnp.where(keep_k & keep_p, logits, -jnp.inf)
+
+    return jax.lax.cond(jnp.any((top_k > 0) | (top_p < 1.0)), filtered,
+                        lambda: logits)
 
 
 def sample_tokens(keys, logits, temperature, top_k, top_p):
@@ -252,10 +261,26 @@ def sample_tokens(keys, logits, temperature, top_k, top_p):
     categorical draw over the temperature-scaled, top-k/top-p-filtered
     distribution.  ``keys`` [S, 2] uint32 (one PRNGKey per row — the
     explicit key thread), logits [S, V]; temperature/top_k/top_p [S].
+
+    The batch pays for what its rows ask: with no temperature above 0
+    the result is the argmax alone, and the draw sorts only when a
+    drawing row filters (``filter_top_k_top_p``).  Each row's token is
+    the same in either branch, so a request's tokens do not depend on
+    its batch neighbours.  The predicates read every row: a row that is
+    no request (the engine's dead slot) must carry temperature 0, as
+    ``DecodeEngine._step_args`` fills it (with top_k 0, top_p 1).
     """
-    greedy = temperature <= 0.0
-    t = jnp.where(greedy, 1.0, temperature)
-    filt = filter_top_k_top_p(logits / t[..., None], top_k, top_p)
-    drawn = jax.vmap(jax.random.categorical)(keys, filt)
-    return jnp.where(greedy, greedy_sample(logits),
-                     drawn.astype(jnp.int32))
+    def draw():
+        greedy = temperature <= 0.0
+        t = jnp.where(greedy, 1.0, temperature)
+        # a greedy row's filter is never read: its knobs do not count
+        # towards the sort
+        filt = filter_top_k_top_p(
+            logits / t[..., None], jnp.where(greedy, 0, top_k),
+            jnp.where(greedy, 1.0, top_p))
+        drawn = jax.vmap(jax.random.categorical)(keys, filt)
+        return jnp.where(greedy, greedy_sample(logits),
+                         drawn.astype(jnp.int32))
+
+    return jax.lax.cond(jnp.any(temperature > 0.0), draw,
+                        lambda: greedy_sample(logits))

@@ -2281,6 +2281,15 @@ class DecodeEngine:
             stat_add("decode_attn_blocks_live", int(
                 (positions // self._attn_block + 1).sum()))
             stat_add("decode_attn_blocks_walked", self._attn_table_blocks)
+            # what the sampler's conditionals take this step (a dead
+            # slot's knobs are the zeros above: no mask): the draw when
+            # a slot samples, the vocabulary's sort when such a slot
+            # also filters
+            drawing = temp > 0.0
+            if drawing.any():
+                stat_add("decode_steps_drawn")
+                if (drawing & ((top_k > 0) | (top_p < 1.0))).any():
+                    stat_add("decode_steps_filtered")
             if self._window is not None:
                 self._count_window(positions[list(live_idx)])
         return _words(rows)

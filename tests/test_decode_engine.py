@@ -896,6 +896,225 @@ def test_sampling_filters_unit():
     assert (np.asarray(a) == np.asarray(b)).all()
 
 
+def _sample_tokens_unconditional(keys, logits, temperature, top_k, top_p):
+    """The sampler as it was before it chose its work from the knobs:
+    every batch sorts, filters and draws, then greedy rows take the
+    argmax.  ``sample_tokens`` is held to this bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    v = logits.shape[-1]
+    greedy = temperature <= 0.0
+    t = jnp.where(greedy, 1.0, temperature)
+    x = logits / t[..., None]
+    desc = jnp.flip(jnp.sort(x, axis=-1), axis=-1)
+    k_idx = jnp.clip(top_k - 1, 0, v - 1)
+    thresh_k = jnp.take_along_axis(desc, k_idx[..., None], axis=-1)
+    keep_k = (top_k <= 0)[..., None] | (x >= thresh_k)
+    probs = jax.nn.softmax(desc, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep_sorted = (cum - probs) < top_p[..., None]
+    thresh_p = jnp.min(jnp.where(keep_sorted, desc, jnp.inf), axis=-1,
+                       keepdims=True)
+    keep_p = (top_p >= 1.0)[..., None] | (x >= thresh_p)
+    filt = jnp.where(keep_k & keep_p, x, -jnp.inf)
+    drawn = jax.vmap(jax.random.categorical)(keys, filt)
+    return jnp.where(greedy, jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                     drawn.astype(jnp.int32))
+
+
+_ROWS = 6
+# (temperature, top_k, top_p) a row; a scalar stands for every row
+_SAMPLING_MIXES = {
+    "all_greedy": (0.0, 0, 1.0),
+    "all_temperature_only": ([0.7, 1.0, 1.3, 0.2, 2.0, 1.0], 0, 1.0),
+    "all_top_k": (1.0, [1, 2, 3, 5, 8, 40], 1.0),
+    "all_top_p": ([0.8, 1.0, 1.0, 1.2, 1.0, 0.5], 0,
+                  [0.1, 0.5, 0.9, 0.95, 0.99, 0.3]),
+    "one_filtered_among_greedy": ([0, 0, 0, 0.9, 0, 0],
+                                  [0, 0, 0, 7, 0, 0],
+                                  [1, 1, 1, 0.95, 1, 1]),
+    "one_temperature_only_among_greedy": ([0, 1.1, 0, 0, 0, 0], 0, 1.0),
+    # a greedy caller that leaves its knobs set: they are never read
+    "greedy_with_knobs_among_temperature_only": (
+        [1.0, 0, 0.6, 1.0, 0, 1.5], [0, 5, 0, 0, 0, 0],
+        [1, 1, 1, 1, 0.5, 1]),
+    # what _step_args hands over for a step of dead slots
+    "dead_slot_record": (0.0, 0, 1.0),
+}
+
+
+def _sampling_mix(name):
+    import jax
+    import jax.numpy as jnp
+
+    rs = np.random.RandomState(11)
+    logits = rs.randn(_ROWS, 97).astype("f4") * 3.0
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(_ROWS) + 40)
+    if name == "dead_slot_record":
+        # a dead slot's key and counter are zeros too
+        keys = jnp.zeros_like(keys)
+    temp, top_k, top_p = _SAMPLING_MIXES[name]
+    return (keys, jnp.asarray(logits),
+            jnp.asarray(np.broadcast_to(temp, (_ROWS,)), jnp.float32),
+            jnp.asarray(np.broadcast_to(top_k, (_ROWS,)), jnp.int32),
+            jnp.asarray(np.broadcast_to(top_p, (_ROWS,)), jnp.float32))
+
+
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("mix", sorted(_SAMPLING_MIXES))
+def test_sample_tokens_bitwise_equals_unconditional_formula(mix, jitted):
+    import jax
+
+    from paddle_tpu.ops.sampling_ops import sample_tokens
+
+    args = _sampling_mix(mix)
+    new, old = sample_tokens, _sample_tokens_unconditional
+    if jitted:
+        new, old = jax.jit(new), jax.jit(old)
+    got, want = np.asarray(new(*args)), np.asarray(old(*args))
+    assert got.dtype == want.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+
+
+_VOCAB_WIDE_WORK = ("sort", "cumsum", "random_bits", "threefry2x32")
+
+
+def _primitives(jaxpr, *, into_conditionals):
+    """Names of the primitives of ``jaxpr`` and of every jaxpr nested in
+    it; a ``cond``'s branches only where ``into_conditionals``."""
+    import jax
+
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name == "cond" and not into_conditionals:
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _primitives(sub, into_conditionals=into_conditionals)
+    return names
+
+
+def test_sample_tokens_sorts_and_draws_only_inside_a_conditional():
+    import jax
+
+    from paddle_tpu.ops.sampling_ops import sample_tokens
+
+    jaxpr = jax.make_jaxpr(sample_tokens)(
+        *_sampling_mix("all_greedy")).jaxpr
+    top = _primitives(jaxpr, into_conditionals=False)
+    assert "cond" in top
+    assert not set(top) & set(_VOCAB_WIDE_WORK), top
+    every = _primitives(jaxpr, into_conditionals=True)
+    # the work is still there, behind the predicates: a sort inside the
+    # filter's conditional inside the draw's
+    assert {"sort", "cumsum"} <= set(every), every
+    assert every.count("cond") == 2
+    (outer,) = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    greedy_branch, draw_branch = outer.params["branches"]
+    assert "sort" not in _primitives(greedy_branch.jaxpr,
+                                     into_conditionals=True)
+    assert "sort" not in _primitives(draw_branch.jaxpr,
+                                     into_conditionals=False)
+
+
+def _unconditional_lines(text):
+    """The lines of a lowered module that run whatever any conditional
+    takes: ``@main`` and the functions it calls, without the regions of
+    ``stablehlo.case`` / ``stablehlo.if`` (and what only they call)."""
+    funcs, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"\s*func\.func (?:public |private )?@([\w.]+)\(",
+                     line)
+        if m:
+            cur = funcs.setdefault(m.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    seen, todo, out = set(), ["main"], []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        closer = None  # indentation of the conditional we are inside
+        for line in funcs[name]:
+            indent = len(line) - len(line.lstrip())
+            if closer is not None:
+                if indent == closer and line.lstrip().startswith("})"):
+                    closer = None
+                continue
+            if re.search(r"stablehlo\.(case|if)\b", line):
+                closer = indent
+                continue
+            out.append(line)
+            todo += re.findall(r"call @([\w.]+)\(", line)
+    return out
+
+
+def test_engine_step_sorts_the_vocabulary_only_inside_a_conditional(
+        model_and_weights):
+    eng = make_engine(model_and_weights, slots=3)
+    text = eng.lower_step().as_text()
+    wide = f"x{VOCAB}xui32"  # random bits a vocabulary wide
+    assert "stablehlo.sort" in text and wide in text
+    always = "\n".join(_unconditional_lines(text))
+    assert "stablehlo.dot_general" in always  # the model is in there
+    for work in ("stablehlo.sort", "cumsum", "reduce_window", wide):
+        assert work not in always, work
+    # the whole-prompt prefill's one-row tail likewise
+    text = eng.lower_prefill(16).as_text()
+    assert "stablehlo.sort" in text
+    always = "\n".join(_unconditional_lines(text))
+    assert "stablehlo.dot_general" in always
+    assert "stablehlo.sort" not in always
+
+
+def _sampler_counts():
+    return np.array([stat_get("decode_steps"),
+                     stat_get("decode_steps_drawn"),
+                     stat_get("decode_steps_filtered")])
+
+
+def test_sampling_request_same_tokens_beside_greedy_neighbours(
+        model_and_weights):
+    """The branch the step's sampler takes depends on the whole batch;
+    a request's tokens do not.  The counters say how many joint steps
+    drew and how many sorted."""
+    kw = dict(max_new_tokens=8, temperature=1.0, top_k=7, top_p=0.95,
+              seed=123)
+    # no prefix cache: a prompt seen before would take its first token
+    # from a joint step, and the counts below are of steps
+    eng = make_engine(model_and_weights, slots=3, max_new_tokens=16,
+                      prefix_cache=False).start()
+    try:
+        c0 = _sampler_counts()
+        greedy_alone = eng.generate([9] * 5, max_new_tokens=8)
+        c1 = _sampler_counts()
+        # an all-greedy run takes the argmax alone: 7 joint steps (the
+        # first token is the prefill's), none drawn
+        assert (c1 - c0).tolist() == [7, 0, 0]
+        alone = eng.generate([4, 5, 6], **kw)
+        c2 = _sampler_counts()
+        assert (c2 - c1).tolist() == [7, 7, 7]
+        # beside greedy neighbours that are there before it and outlive
+        # it: only the steps it is live in draw and sort
+        others = [eng.submit([9] * 5, max_new_tokens=16),
+                  eng.submit([3, 1, 4, 1, 5], max_new_tokens=16)]
+        beside = eng.generate([4, 5, 6], **kw)
+        outs = [o.result(timeout=120) for o in others]
+        c3 = _sampler_counts()
+        steps, drawn, filtered = (c3 - c2).tolist()
+        assert drawn == filtered == 7 and steps >= 15
+        # temperature alone draws without the sort
+        eng.generate([4, 5, 6], max_new_tokens=8, temperature=0.8, seed=5)
+        assert (_sampler_counts() - c3).tolist() == [7, 7, 0]
+    finally:
+        eng.stop()
+    assert beside == alone and len(alone) == 8
+    # and the greedy neighbour's tokens are what it emits alone
+    assert outs[0][:8] == greedy_alone
+
+
 def test_two_replicas_same_seed_emit_identical_tokens(
         model_and_weights):
     """The PR 7 sharding-invariant-RNG guarantee carried to serving:
