@@ -76,6 +76,15 @@ RULES = (
          "until its tokens are on the host, a whole-prompt prefill "
          "handed over ahead of it in the same iteration included (a "
          "speculative round: draft burst + verify)", exact=True),
+    Rule("decode_turnaround_seconds", "histogram", "serving",
+         "The engine loop between two joint decode steps: from a "
+         "step's tokens on the host until the next step's hand-over "
+         "begins (delivery, reaping, the next step's arguments, the "
+         "admission, the prefills handed over ahead), never across an "
+         "idle wait.  With `decode_step_seconds` it sums to the loop's "
+         "period; its share of that sum is the share of a period the "
+         "device waits for the host in a loop that runs one step at a "
+         "time", exact=True),
     Rule("ttft_seconds", "histogram", "slo",
          "Time to first token (SLO input): submit until the prefill's "
          "sampled token has been read back and is handed to the caller, "
@@ -193,7 +202,12 @@ RULES = (
          "(the categorical draw runs), `_filtered` those in which such "
          "a slot also has top_k > 0 or top_p < 1 (the vocabulary's sort "
          "runs).  Either over `decode_steps` is the share of steps that "
-         "pay for it; every other step takes the argmax alone"),
+         "pay for it; every other step takes the argmax alone.  `_slow` "
+         "the joint steps whose hand-over to tokens on the host passed "
+         "0.5 s, the program's first run (a compile or a load from the "
+         "compile cache) apart: each leaves a `serving/slow_step` event "
+         "in the flight recorder (iteration, step, live slots, prefills "
+         "ahead, the hand-over's and the read-back's begin and end)"),
     Rule("decode_state_bytes", "gauge", "serving",
          "Device bytes of the slot-indexed slabs that hold the state of "
          "a model's recurrent layers (linear attention: a matrix a head "

@@ -150,6 +150,13 @@ def enabled() -> bool:
     return bool(_flags.flag("enable_tracer"))
 
 
+def recording() -> bool:
+    """Whether a span opened now reaches a sink: a ``jax.profiler``
+    session runs or the ring buffer is on.  For a site whose span
+    attributes cost a clock read a token to gather."""
+    return _Annotation.is_enabled() or enabled()
+
+
 def enable() -> None:
     _flags.set_flags({"enable_tracer": True})
 

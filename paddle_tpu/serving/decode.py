@@ -83,9 +83,20 @@ a running ``jax.profiler`` trace, ring-buffer records under
 ``prefill_deliver`` | then ``serving/step_sync`` | ``step_deliver`` (a
 chunked, ragged or suffix prefill runs its four phases in a row where
 the whole-prompt one is dispatched).  No span encloses the iteration, so a
-device gap is named by the phase the host was in.  The ``*_args`` spans
+device gap is named by the phase the host was in.  Every leaf carries
+``iter`` (the loop's own ordinal: a period's spans group by it).  The
+``*_args`` spans
 carry the host arrays their builder handed to the device (``uploads``,
-``upload_bytes``; counters ``decode_h2d_uploads`` / ``decode_h2d_bytes``).
+``upload_bytes``; counters ``decode_h2d_uploads`` / ``decode_h2d_bytes``);
+the ``*_deliver`` spans, while a profiler session or the ring buffer
+takes them, what they carried (``tokens``, ``finished``, ``emit_ms`` in
+the callers' ``on_token`` and stream, ``finish_ms`` in
+``_finish_slot``).  ``decode_turnaround_seconds`` is the other half of
+``decode_step_seconds``' period: tokens on the host until the next joint
+step's hand-over begins.  A step whose hand-over-to-tokens passes
+``SLOW_STEP_S`` (the program's first run apart) leaves a
+``serving/slow_step`` event in the flight recorder and counts
+``decode_steps_slow``.
 The joint step and the whole-prompt prefill take everything after the
 weights as ONE packed int32 array (``_words`` / ``_unpack``): one upload
 a dispatch, whatever the number of fields.
@@ -143,6 +154,43 @@ class _Uploads:
         stat_add("decode_h2d_uploads", self.n)
         stat_add("decode_h2d_bytes", self.nbytes)
         otrace.set_span_args(uploads=self.n, upload_bytes=self.nbytes)
+
+
+class _Delivery:
+    """A ``*_deliver`` leaf that says what it carried.  While a sink
+    takes the span (a profiler session or the ring buffer, asked once
+    as it opens) ``_deliver`` counts into the engine's ``_carried``:
+    tokens, slots that ended, ns in ``req._emit``, ns in
+    ``_finish_slot``; with no sink ``_carried`` is None and ``_deliver``
+    reads no clock.  A class, not a generator: what opens and closes a
+    leaf lies between two spans, under no phase's name."""
+
+    __slots__ = ("_engine", "_span")
+
+    def __init__(self, engine, name, attrs):
+        self._engine = engine
+        self._span = otrace.span(name, **attrs)
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._engine._carried = [0, 0, 0, 0] if otrace.recording() \
+            else None
+        return self
+
+    def __exit__(self, *exc):
+        carried, self._engine._carried = self._engine._carried, None
+        if carried is not None:
+            tokens, finished, emit_ns, finish_ns = carried
+            otrace.set_span_args(tokens=tokens, finished=finished,
+                                 emit_ms=emit_ns * 1e-6,
+                                 finish_ms=finish_ns * 1e-6)
+        return self._span.__exit__(*exc)
+
+
+# hand-over -> tokens on the host beyond which a joint step leaves a
+# ``serving/slow_step`` record: 7 times the longest prefill of any
+# benchmarked cell, so no healthy step passes it
+SLOW_STEP_S = 0.5
 
 
 def _rid(req):
@@ -815,6 +863,18 @@ class DecodeEngine:
         # decode rounds dispatched by THIS engine (joint steps and
         # speculative rounds): the step spans' ``step``
         self._decode_steps = 0
+        # ``_loop`` iterations begun: the leaf spans' ``iter``
+        self._iter = 0
+        # what the open ``*_deliver`` span carried so far (tokens,
+        # slots that ended, ns in ``req._emit``, ns in ``_finish_slot``),
+        # None while no sink takes it or none is open: ``_Delivery``
+        self._carried = None
+        # when the last joint step's tokens reached the host (None
+        # after an idle wait): where ``decode_turnaround_seconds`` starts
+        self._t_tokens = None
+        # whether the joint step's program has run once: its first run
+        # (a compile, or a load from the compile cache) is no slow step
+        self._step_ran = False
 
     @staticmethod
     def _refuse_for_kinds(model, c: "DecodeConfig", draft_model) -> None:
@@ -1788,13 +1848,14 @@ class DecodeEngine:
         first place after ``step_deliver`` where this thread lets go
         of it) makes this admission, a later one the next."""
         while True:
-            with otrace.span("serving/reap"):
+            self._iter += 1
+            with otrace.span("serving/reap", iter=self._iter):
                 self._reap_live()
             step = self._prepare_decode_round()
             # ``with self._cond:`` with the wait for the lock (callers
             # hold it while they submit) as a phase of its own, so that
             # the iteration is spanned end to end
-            with otrace.span("serving/lock_wait"):
+            with otrace.span("serving/lock_wait", iter=self._iter):
                 self._cond.acquire()
             try:
                 if self._abort:
@@ -1803,7 +1864,7 @@ class DecodeEngine:
                             self._finish_slot(i, ServerClosedError(
                                 "engine stopped mid-generation"))
                     return
-                with otrace.span("serving/admit"):
+                with otrace.span("serving/admit", iter=self._iter):
                     self._reap_queue_locked()
                     admitted = self._admit_locked()
                     otrace.set_span_args(admitted=len(admitted),
@@ -1813,14 +1874,18 @@ class DecodeEngine:
                         return
                     # short cap keeps queued deadlines (and a pages-
                     # blocked head) honest while idle
-                    with otrace.span("serving/idle_wait"):
+                    # waiting for work is not the loop's turnaround
+                    self._t_tokens = None
+                    with otrace.span("serving/idle_wait",
+                                     iter=self._iter):
                         self._cond.wait(0.05 if self._queue else None)
                     continue
             finally:
                 self._cond.release()
             finishes = self._start_prefills()
             if step is not None:
-                finishes.append(self._dispatch_step(*step))
+                finishes.append(self._dispatch_step(
+                    *step, ahead=len(finishes)))
             for finish in finishes:
                 finish()
 
@@ -1898,7 +1963,8 @@ class DecodeEngine:
 
         try:
             t_pad = self._buckets.seq_bucket(len(req.prompt))
-            attrs = {"slot": slot, "bucket": t_pad, "req": _rid(req)}
+            attrs = {"iter": self._iter, "slot": slot, "bucket": t_pad,
+                     "req": _rid(req)}
             t0 = time.monotonic()
             with otrace.span("serving/prefill_args", **attrs):
                 up = _Uploads()
@@ -1933,7 +1999,7 @@ class DecodeEngine:
                 # host
                 dur = time.monotonic() - t0
                 stat_time("decode_prefill_seconds", dur)
-                with otrace.span("serving/prefill_deliver", **attrs):
+                with _Delivery(self, "serving/prefill_deliver", attrs):
                     self._tev(req, "prefill", slot=slot, bucket=t_pad,
                               tokens=len(req.prompt),
                               dur_ms=round(dur * 1e3, 3))
@@ -1974,8 +2040,8 @@ class DecodeEngine:
             start = st.prefill_pos
             n_live = min(rows, n - start)
             final = start + n_live >= n
-            attrs = {"slot": slot, "bucket": rows, "start": start,
-                     "req": _rid(req)}
+            attrs = {"iter": self._iter, "slot": slot, "bucket": rows,
+                     "start": start, "req": _rid(req)}
             t0 = time.monotonic()
             with otrace.span("serving/prefill_args", **attrs):
                 up = _Uploads()
@@ -2019,7 +2085,7 @@ class DecodeEngine:
                     tok = int(np.asarray(tok)[0])
             dur = time.monotonic() - t0
             stat_time("decode_prefill_seconds", dur)
-            with otrace.span("serving/prefill_deliver", **attrs):
+            with _Delivery(self, "serving/prefill_deliver", attrs):
                 stat_add("prefill_chunks")
                 record_pad_waste(n_live, rows)
                 self._prefill_chunk_count += 1
@@ -2088,7 +2154,8 @@ class DecodeEngine:
         self._prefill_rr = (picks[-1][0] + 1) % self.config.slots
         live = L - lanes_left
 
-        attrs = {"lanes": L, "live": live, "slots": len(picks),
+        attrs = {"iter": self._iter, "lanes": L, "live": live,
+                 "slots": len(picks),
                  "req": ",".join(_rid(self._slots[i].req)
                                  for i, _s, _t in picks)}
         try:
@@ -2151,7 +2218,7 @@ class DecodeEngine:
                     tok = np.asarray(tok)
             dur = time.monotonic() - t0
             stat_time("decode_prefill_seconds", dur)
-            with otrace.span("serving/prefill_deliver", **attrs):
+            with _Delivery(self, "serving/prefill_deliver", attrs):
                 stat_add("prefill_chunks")
                 stat_add("decode_ragged_dispatches")
                 record_pad_waste(live, L)
@@ -2197,16 +2264,30 @@ class DecodeEngine:
         st.last_token = token
         self.tokens_total += 1
         stat_add("decode_tokens_total")
-        st.req._emit(token)
+        carried = self._carried  # None: no sink takes the open span
+        if carried is None:
+            st.req._emit(token)
+        else:
+            t0 = time.perf_counter_ns()
+            st.req._emit(token)
+            carried[0] += 1
+            carried[2] += time.perf_counter_ns() - t0
         self._tev(st.req, "token", slot=slot, token=int(token),
                   n=st.n_generated)
         eos = self.config.eos_id
         if eos is not None and token == eos:
             st.req.finish_reason = "eos"
-            self._finish_slot(slot)
         elif st.n_generated >= st.req.max_new_tokens:
             st.req.finish_reason = "budget"
+        else:
+            return
+        if carried is None:
             self._finish_slot(slot)
+        else:
+            t0 = time.perf_counter_ns()
+            self._finish_slot(slot)
+            carried[1] += 1
+            carried[3] += time.perf_counter_ns() - t0
 
     def _perform_cow(self, slot, plans):
         """Run the device half of every planned copy-on-write BEFORE
@@ -2346,7 +2427,8 @@ class DecodeEngine:
         Nothing between here and ``_dispatch_step`` touches a slot that
         decodes: an admission claims pages no live slot owns, and a
         slot it fills reads as dead in these arguments."""
-        attrs = {"step": self._decode_steps, "live": len(live_idx)}
+        attrs = {"iter": self._iter, "step": self._decode_steps,
+                 "live": len(live_idx)}
         # copy-on-write any shared page this step would write (a
         # borrowed partial tail at its first divergent token)
         with otrace.span("serving/step_cow", **attrs):
@@ -2360,10 +2442,17 @@ class DecodeEngine:
             up.record()
         return live_idx, attrs, args
 
-    def _dispatch_step(self, live_idx, attrs, args):
-        """Hand the prepared joint step to the device; returns the half
-        that reads its tokens and delivers them."""
+    def _dispatch_step(self, live_idx, attrs, args, ahead=0):
+        """Hand the prepared joint step to the device, behind the
+        ``ahead`` whole-prompt prefills of its iteration; returns the
+        half that reads its tokens and delivers them."""
         t0 = time.monotonic()
+        # the period's other half: the last step's tokens on the host
+        # until here; observed behind this step's own observation, so
+        # that nothing new lies inside ``decode_step_seconds``
+        turnaround = None if self._t_tokens is None \
+            else t0 - self._t_tokens
+        self._t_tokens = None
 
         def failed(e):  # fail the batch loudly, free every slot, keep
             # the consumer thread alive
@@ -2377,6 +2466,7 @@ class DecodeEngine:
                     self._step_fn, self._state_vars, args=args,
                     scope=self._scope)
                 recorded = recorded[0] if recorded else {}
+                t1 = time.monotonic()
         except Exception as e:  # noqa: BLE001
             failed(e)
             return lambda: None
@@ -2384,13 +2474,22 @@ class DecodeEngine:
         def finish():
             try:
                 with otrace.span("serving/step_sync", **attrs):
+                    t2 = time.monotonic()
                     tokens = np.asarray(nxt)  # THE per-step sync point
             except Exception as e:  # noqa: BLE001
                 failed(e)
                 return
-            stat_time("decode_step_seconds", time.monotonic() - t0)
+            t3 = self._t_tokens = time.monotonic()
+            stat_time("decode_step_seconds", t3 - t0)
             self._decode_steps += 1
-            with otrace.span("serving/step_deliver", **attrs):
+            with _Delivery(self, "serving/step_deliver", attrs):
+                if turnaround is not None:
+                    stat_time("decode_turnaround_seconds", turnaround)
+                # the program's first run loads or compiles it: exempt
+                if t3 - t0 > SLOW_STEP_S and self._step_ran:
+                    self._record_slow_step(attrs, ahead,
+                                           (t0, t1, t2, t3))
+                self._step_ran = True
                 # behind the slots' tokens, the model's counters of
                 # this step (a model with ``layer_kinds``)
                 for name, n in zip(self._tallies,
@@ -2418,6 +2517,21 @@ class DecodeEngine:
 
         return finish
 
+    def _record_slow_step(self, attrs, ahead, stamps):
+        """A joint step that took longer than ``SLOW_STEP_S`` from its
+        hand-over to its tokens: how often, in which phase (the
+        hand-over or the read-back; whether the device or the wake-up
+        was late no host clock can say), behind how many prefills."""
+        from ..observe import flight as _flight
+
+        stat_add("decode_steps_slow")
+        t0, t1, t2, t3 = stamps
+        _flight.record(
+            "serving/slow_step", name=self.name, iter=attrs["iter"],
+            step=attrs["step"], live=attrs["live"], prefills_ahead=ahead,
+            seconds=round(t3 - t0, 6), t_handover_begin=t0,
+            t_handover_end=t1, t_readback_begin=t2, t_readback_end=t3)
+
     def _run_spec(self, spec_idx):
         """One speculative round for the greedy slots: a k-token draft
         burst (ONE dispatch) then ONE batched target step verifying all
@@ -2430,8 +2544,8 @@ class DecodeEngine:
         k = self.config.spec_k
         rows = k + 1
         k_live = {}
-        attrs = {"step": self._decode_steps, "live": len(spec_idx),
-                 "k": k}
+        attrs = {"iter": self._iter, "step": self._decode_steps,
+                 "live": len(spec_idx), "k": k}
         with otrace.span("serving/step_cow", **attrs):
             for i in spec_idx:
                 st = self._slots[i]
@@ -2513,7 +2627,7 @@ class DecodeEngine:
         self._decode_steps += 1
         logits_np = None
         proposed = accepted = 0
-        with otrace.span("serving/step_deliver", **attrs):
+        with _Delivery(self, "serving/step_deliver", attrs):
             for i in spec_idx:
                 st = self._slots[i]
                 a = 0

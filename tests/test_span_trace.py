@@ -7,6 +7,12 @@ back with ``ProfileData`` holds each of them exactly once, attributes as
 the event's stats.  The decode engine's loop is a row of leaf phases
 under ``serving/``; its ``*_args`` spans carry the uploads the counters
 count; ``decode_prefill_seconds`` runs through the prefill's sync.
+
+PR 37: every leaf of one engine iteration carries the same ``iter``;
+the ``*_deliver`` spans say what they carried while a sink takes them
+(``observe.tracer.recording``); ``decode_turnaround_seconds`` is the
+loop between two joint steps; a step slower than ``SLOW_STEP_S`` leaves
+a flight-recorder event, the program's first run apart.
 """
 import contextlib
 import glob
@@ -158,6 +164,26 @@ def test_unbalanced_end_and_flag_flip_stay_balanced():
         observe.clear()
 
 
+# -- whether a span opened now reaches a sink -------------------------------
+
+@pytest.mark.parametrize("sink", ["none", "ring", "trace"])
+def test_recording_says_whether_a_sink_takes_a_span(sink, tmp_path):
+    from paddle_tpu.observe import tracer
+
+    assert not tracer.recording()
+    if sink == "ring":
+        pt.set_flags({"enable_tracer": True})
+        try:
+            assert tracer.recording()
+        finally:
+            pt.set_flags({"enable_tracer": False})
+            observe.clear()
+    elif sink == "trace":
+        with traced(tmp_path):
+            assert tracer.recording()
+    assert not tracer.recording()
+
+
 # -- the engine loop as sequential leaf phases ----------------------------
 
 PREFILL = ["serving/prefill_args", "serving/prefill_dispatch",
@@ -204,7 +230,8 @@ def test_engine_iteration_is_a_row_of_leaf_phases(model_and_weights,
     by_name = {}
     for e in engine:
         by_name.setdefault(e[2], []).append(e[3])
-    assert by_name["serving/admit"][0] == {"admitted": 1, "queued": 0}
+    admit = by_name["serving/admit"][0]
+    assert (admit["admitted"], admit["queued"]) == (1, 0)
     # one TTFT can be walked by the request's id, one step by its number
     rid = req.trace.trace_id
     assert all(by_name[n][0]["req"] == rid for n in PREFILL)
@@ -232,8 +259,10 @@ def test_speculative_round_has_the_same_phases(model_and_weights,
     try:
         eng.submit(list(range(1, 12)), max_new_tokens=4).result(timeout=120)
         with traced(tmp_path):
+            # the prefill's token, then two rounds (the draft is the
+            # target: each yields 3) and a joint step for the eighth
             eng.submit(list(range(2, 12)),
-                       max_new_tokens=4).result(timeout=120)
+                       max_new_tokens=8).result(timeout=120)
             time.sleep(0.05)
     finally:
         eng.stop()
@@ -247,8 +276,240 @@ def test_speculative_round_has_the_same_phases(model_and_weights,
         ["serving/step_deliver"]
     assert engine[i][3]["k"] == 2
     assert not any(n.startswith("serving/decode_") for n in names)
+    # the round's eight leaves are of one iteration, the next round's
+    # of the next
+    assert len({e[3]["iter"] for e in engine[i:i + 8]}) == 1
+    j = names.index("serving/step_cow", i + 1)
+    assert engine[j][3]["iter"] == engine[i][3]["iter"] + 1
+    deliver = engine[i + 7][3]
+    assert 1 <= deliver["tokens"] <= 3 and deliver["live"] == 1
     for a, b in zip(engine, engine[1:]):
         assert a[1] <= b[0], (a, b)
+
+
+# -- an iteration has a number, a delivery says what it carried ------------
+
+SLOW_CALLBACK_S = 0.02
+
+
+def engine_line(log_dir):
+    """The engine thread's ``serving/*`` events, sorted by start."""
+    (rows,) = [rows for rows in host_lines(log_dir, "serving/")
+               if any(e[2] == "serving/step_dispatch" for e in rows)]
+    return rows
+
+
+@pytest.fixture(scope="module")
+def loop_trace(model_and_weights, tmp_path_factory):
+    """Two requests of four tokens, one after the other with the engine
+    idle between them, the first with a slow ``on_token``: the engine
+    thread's events and what the two histograms saw."""
+    log_dir = tmp_path_factory.mktemp("loop_trace")
+    idle_s = 0.3
+
+    def slow(_token):
+        time.sleep(SLOW_CALLBACK_S)
+
+    eng = make_engine(model_and_weights).start()
+    try:
+        eng.submit(list(range(1, 12)), max_new_tokens=2).result(timeout=120)
+        time.sleep(0.1)                  # the engine is in its idle wait
+        turn, step = (histogram("decode_turnaround_seconds"),
+                      histogram("decode_step_seconds"))
+        before = (turn.count, turn.sum, step.count)
+        with traced(log_dir):
+            eng.submit(list(range(2, 12)), max_new_tokens=4,
+                       on_token=slow).result(timeout=120)
+            time.sleep(idle_s)
+            eng.submit(list(range(3, 12)),
+                       max_new_tokens=4).result(timeout=120)
+            time.sleep(0.05)             # the last deliver span closes
+        seen = (turn.count - before[0], turn.sum - before[1],
+                step.count - before[2])
+    finally:
+        eng.stop()
+    return {"engine": engine_line(log_dir), "idle_s": idle_s,
+            "turnarounds": seen[0], "turnaround_s": seen[1],
+            "steps": seen[2]}
+
+
+def by_iteration(engine):
+    """{iter: [event]} in the order of the thread's line."""
+    out = {}
+    for e in engine:
+        out.setdefault(e[3]["iter"], []).append(e)
+    return out
+
+
+def test_every_leaf_of_an_iteration_carries_its_ordinal(loop_trace):
+    engine = loop_trace["engine"]
+    assert all("iter" in e[3] for e in engine)
+    iters = by_iteration(engine)
+    # consecutive iterations, consecutive ordinals, each a stretch of
+    # the line of its own
+    assert sorted(iters) == list(range(min(iters), max(iters) + 1))
+    assert [e[3]["iter"] for e in engine] == sorted(
+        e[3]["iter"] for e in engine)
+    stepped = [rows for rows in iters.values()
+               if any(e[2] == "serving/step_dispatch" for e in rows)]
+    assert len(stepped) == loop_trace["steps"] == 6
+    for rows in stepped:
+        assert [e[2] for e in rows] == \
+            ["serving/reap"] + STEP[:2] + \
+            ["serving/lock_wait", "serving/admit"] + STEP[2:]
+        # the step spans' own number stays beside the iteration's
+        assert len({e[3]["step"] for e in rows if "step" in e[3]}) == 1
+    (first, second) = [rows for rows in iters.values()
+                       if any(e[2] == "serving/prefill_dispatch"
+                              for e in rows)]
+    assert [e[2] for e in first if "prefill" in e[2]] == PREFILL
+
+
+def test_step_deliver_says_what_it_carried(loop_trace):
+    delivers = [e for e in loop_trace["engine"]
+                if e[2] == "serving/step_deliver"]
+    assert len(delivers) == 6
+    for a, b, _, attrs in delivers:
+        assert attrs["tokens"] == attrs["live"] == 1
+        assert attrs["emit_ms"] + attrs["finish_ms"] <= (b - a) * 1e-6
+    # a request of 4 tokens: the prefill's and three steps', the last
+    # of which ends the slot
+    assert [e[3]["finished"] for e in delivers] == [0, 0, 1, 0, 0, 1]
+    assert all(e[3]["finish_ms"] > 0 for e in delivers[2::3])
+    assert all(e[3]["finish_ms"] == 0 for e in delivers
+               if not e[3]["finished"])
+    prefills = [e for e in loop_trace["engine"]
+                if e[2] == "serving/prefill_deliver"]
+    assert [(e[3]["tokens"], e[3]["finished"]) for e in prefills] == \
+        [(1, 0), (1, 0)]
+
+
+def test_a_slow_on_token_shows_in_emit_ms_alone(loop_trace):
+    carried = [e[3] for e in loop_trace["engine"]
+               if e[2].endswith("_deliver")]
+    slow, fast = carried[:4], carried[4:]
+    assert all(c["emit_ms"] >= SLOW_CALLBACK_S * 1e3 for c in slow)
+    assert all(c["emit_ms"] < SLOW_CALLBACK_S * 1e3 / 2 for c in fast)
+    assert all(c["finish_ms"] < SLOW_CALLBACK_S * 1e3 / 2 for c in carried)
+
+
+def test_a_delivery_reads_no_clock_while_no_sink_takes_its_span(
+        model_and_weights, monkeypatch):
+    """No profiler session, the ring buffer off: ``_deliver`` makes no
+    ``perf_counter_ns`` call a token, and counts nothing."""
+    from paddle_tpu.serving import decode
+
+    eng = make_engine(model_and_weights)
+    carried = []
+    deliver = eng._deliver
+
+    def spy(slot, token):
+        carried.append(eng._carried)
+        return deliver(slot, token)
+
+    monkeypatch.setattr(eng, "_deliver", spy)
+    reads = []
+    clock = time.perf_counter_ns
+    monkeypatch.setattr(decode.time, "perf_counter_ns",
+                        lambda: reads.append(1) or clock())
+    assert not observe.enabled()
+    eng.start()
+    try:
+        toks = eng.submit(list(range(1, 8)),
+                          max_new_tokens=4).result(timeout=120)
+        assert len(toks) == 4 and carried == [None] * 4 and not reads
+        pt.set_flags({"enable_tracer": True})
+        eng.submit(list(range(1, 8)), max_new_tokens=4).result(timeout=120)
+        time.sleep(0.05)                 # the last deliver span closes
+    finally:
+        pt.set_flags({"enable_tracer": False})
+        eng.stop()
+    # the ring buffer on: two reads a token, two more for the slot's end
+    assert len(reads) == 2 * 4 + 2
+    assert all(c is not None for c in carried[4:])
+    delivered = [r.args for r in observe.snapshot()
+                 if r.name.endswith("_deliver")]
+    observe.clear()
+    assert [a["tokens"] for a in delivered] == [1] * 4
+    assert eng._carried is None
+
+
+def test_turnaround_is_seen_once_a_step_and_never_across_an_idle_wait(
+        loop_trace):
+    # three joint steps a request; the first follows the engine's idle
+    # wait (and an iteration with the prefill alone): no turnaround
+    assert loop_trace["steps"] == 6
+    assert loop_trace["turnarounds"] == 4
+    assert any(e[2] == "serving/idle_wait" for e in loop_trace["engine"])
+    # the 0.3 s the engine waited for the second request are in none;
+    # the slow callback's deliveries are (tokens on the host -> the
+    # next hand-over)
+    assert 2 * SLOW_CALLBACK_S <= loop_trace["turnaround_s"] \
+        < loop_trace["idle_s"]
+
+
+def test_turnaround_runs_from_the_sync_to_the_next_hand_over(loop_trace):
+    """With ``decode_step_seconds`` (hand-over -> tokens on the host)
+    the histogram tiles the period: what it saw is the trace's time
+    from each ``step_sync``'s end to the next ``step_dispatch``'s
+    begin, the two spans' own opening and closing aside."""
+    engine = loop_trace["engine"]
+    dispatch = [e for e in engine if e[2] == "serving/step_dispatch"]
+    sync = [e for e in engine if e[2] == "serving/step_sync"]
+    gaps = [dispatch[k][0] - sync[k - 1][1] for k in (1, 2, 4, 5)]
+    assert all(g > 0 for g in gaps)
+    assert abs(sum(gaps) * 1e-9 - loop_trace["turnaround_s"]) < 5e-3
+
+
+# -- a slow step leaves a record -------------------------------------------
+
+@pytest.mark.parametrize("slow_run", [0, 1])
+def test_a_slow_step_leaves_a_flight_record(model_and_weights,
+                                            monkeypatch, slow_run):
+    """The step program's run number ``slow_run`` is slow: its first
+    run (a compile, or a load from the compile cache) leaves nothing,
+    any later one a record and a count."""
+    from paddle_tpu.observe import flight
+    from paddle_tpu.serving import decode
+
+    monkeypatch.setattr(decode, "SLOW_STEP_S", SLOW_SYNC_S * 0.9)
+    eng = make_engine(model_and_weights)
+    run = eng._exe.run_persistent
+    runs = []
+
+    def slow_once(fn, state_vars, args, scope):
+        out = run(fn, state_vars, args=args, scope=scope)
+        if fn is eng._step_fn:
+            runs.append(True)
+            if len(runs) == slow_run + 1:
+                return (_SlowToken(out[0]),) + tuple(out[1:])
+        return out
+
+    monkeypatch.setattr(eng._exe, "run_persistent", slow_once)
+    seq0 = max([e["seq"] for e in flight.tail()], default=0)
+    slow0 = stat_get("decode_steps_slow")
+    eng.start()
+    try:
+        toks = eng.submit(list(range(1, 8)),
+                          max_new_tokens=4).result(timeout=120)
+    finally:
+        eng.stop()
+    assert len(toks) == 4 and len(runs) == 3
+    records = [e for e in flight.tail() if e["seq"] > seq0
+               and e["event"] == "serving/slow_step"]
+    assert stat_get("decode_steps_slow") - slow0 == len(records) \
+        == slow_run
+    if not slow_run:
+        return
+    (rec,) = records
+    assert rec["live"] == 1 and rec["prefills_ahead"] == 0
+    assert rec["iter"] >= 3 and rec["step"] == 1
+    stamps = [rec[k] for k in ("t_handover_begin", "t_handover_end",
+                               "t_readback_begin", "t_readback_end")]
+    assert stamps == sorted(stamps)
+    # the phase that was late is the read-back
+    assert stamps[3] - stamps[2] >= SLOW_SYNC_S
+    assert rec["seconds"] >= SLOW_SYNC_S
 
 
 # -- timers that time what their names say --------------------------------
