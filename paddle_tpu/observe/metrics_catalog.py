@@ -75,16 +75,23 @@ RULES = (
          "One batched decode step, dispatch + sync: from the hand-over "
          "until its tokens are on the host, a whole-prompt prefill "
          "handed over ahead of it in the same iteration included (a "
-         "speculative round: draft burst + verify)", exact=True),
+         "speculative round: draft burst + verify).  A joint step is "
+         "handed over while the one before it is in flight "
+         "(`decode_steps_ahead`) and read an iteration later, so the "
+         "interval also holds the wait behind that step and the host's "
+         "work beside it: consecutive observations overlap, and their "
+         "sum passes the wall time", exact=True),
     Rule("decode_turnaround_seconds", "histogram", "serving",
-         "The engine loop between two joint decode steps: from a "
-         "step's tokens on the host until the next step's hand-over "
-         "begins (delivery, reaping, the next step's arguments, the "
-         "admission, the prefills handed over ahead), never across an "
-         "idle wait.  With `decode_step_seconds` it sums to the loop's "
-         "period; its share of that sum is the share of a period the "
-         "device waits for the host in a loop that runs one step at a "
-         "time", exact=True),
+         "Where the engine loop leaves the device waiting for the "
+         "host: from a joint step's tokens on the host until the next "
+         "joint step's hand-over begins (delivery, reaping, the next "
+         "step's arguments, the admission, the prefills handed over "
+         "ahead), observed ONLY at a hand-over with no joint step in "
+         "flight, never across an idle wait.  A step handed over behind "
+         "one in flight (`decode_steps_ahead` of `decode_steps`) waits "
+         "for nothing the host does and observes nothing, so the sum's "
+         "rate, seconds a second, is the share of the time the device "
+         "waits for the host between joint steps", exact=True),
     Rule("ttft_seconds", "histogram", "slo",
          "Time to first token (SLO input): submit until the prefill's "
          "sampled token has been read back and is handed to the caller, "
@@ -203,11 +210,21 @@ RULES = (
          "a slot also has top_k > 0 or top_p < 1 (the vocabulary's sort "
          "runs).  Either over `decode_steps` is the share of steps that "
          "pay for it; every other step takes the argmax alone.  `_slow` "
-         "the joint steps whose hand-over to tokens on the host passed "
-         "0.5 s, the program's first run (a compile or a load from the "
+         "the joint steps whose hand-over and read-back together kept "
+         "the host longer than 0.5 s, the program's first run (a compile or a load from the "
          "compile cache) apart: each leaves a `serving/slow_step` event "
          "in the flight recorder (iteration, step, live slots, prefills "
-         "ahead, the hand-over's and the read-back's begin and end)"),
+         "ahead, the hand-over's and the read-back's begin and end).  "
+         "`_ahead` the joint steps handed over while the joint step "
+         "before them was in flight, its tokens not yet on the host "
+         "(the next token comes from the device): over `decode_steps` "
+         "the share of steps the host's path ran beside the device's"),
+    Rule("decode_rows_discarded", "gauge", "serving",
+         "Rows of a joint decode step that were nobody's at its "
+         "delivery: the slot no longer held the request the row was "
+         "computed for (an end token in the step before, a deadline "
+         "reap, an abort or a failed read-back released it while the "
+         "step was in flight)"),
     Rule("decode_state_bytes", "gauge", "serving",
          "Device bytes of the slot-indexed slabs that hold the state of "
          "a model's recurrent layers (linear attention: a matrix a head "

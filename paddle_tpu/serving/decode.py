@@ -79,10 +79,18 @@ a running ``jax.profiler`` trace, ring-buffer records under
 ``serving/step_cow`` | ``step_args`` | then ``serving/lock_wait`` |
 ``serving/admit`` (or ``serving/idle_wait``) | per whole-prompt prefill
 ``serving/prefill_args`` | ``prefill_dispatch`` | then
-``serving/step_dispatch`` | per prefill ``serving/prefill_sync`` |
-``prefill_deliver`` | then ``serving/step_sync`` | ``step_deliver`` (a
-chunked, ragged or suffix prefill runs its four phases in a row where
-the whole-prompt one is dispatched).  No span encloses the iteration, so a
+``serving/step_dispatch`` | then, of the joint step handed over an
+iteration AGO, ``serving/step_sync`` | ``step_deliver`` | then per
+prefill ``serving/prefill_sync`` | ``prefill_deliver`` (a chunked,
+ragged or suffix prefill runs its four phases in a row where the
+whole-prompt one is dispatched; a round that holds a speculative slot
+reads its own joint step, behind the prefills).  A joint step is handed
+over while the one before it is in flight (``DecodeEngine._loop``): a
+step's five leaves share its ``step`` number, fixed at the hand-over,
+across two iterations, and ``step_dispatch`` says how many joint steps
+were in flight then (``in_flight``, 0 or 1; counters
+``decode_steps_ahead``, ``decode_rows_discarded``).  No span encloses
+the iteration, so a
 device gap is named by the phase the host was in.  Every leaf carries
 ``iter`` (the loop's own ordinal: a period's spans group by it).  The
 ``*_args`` spans
@@ -91,12 +99,15 @@ carry the host arrays their builder handed to the device (``uploads``,
 the ``*_deliver`` spans, while a profiler session or the ring buffer
 takes them, what they carried (``tokens``, ``finished``, ``emit_ms`` in
 the callers' ``on_token`` and stream, ``finish_ms`` in
-``_finish_slot``).  ``decode_turnaround_seconds`` is the other half of
-``decode_step_seconds``' period: tokens on the host until the next joint
-step's hand-over begins.  A step whose hand-over-to-tokens passes
-``SLOW_STEP_S`` (the program's first run apart) leaves a
-``serving/slow_step`` event in the flight recorder and counts
-``decode_steps_slow``.
+``_finish_slot``).  ``decode_step_seconds`` runs from a step's hand-over
+to its tokens on the host (behind a step in flight: that step's
+remainder and the host's path beside it included);
+``decode_turnaround_seconds``, tokens on the host until the next joint
+step's hand-over begins, is observed only at a hand-over with nothing
+in flight: where the device waits for the host.  A step whose hand-over
+and read-back together keep the host longer than ``SLOW_STEP_S`` (the
+program's first run apart) leaves a ``serving/slow_step`` event in the
+flight recorder and counts ``decode_steps_slow``.
 The joint step and the whole-prompt prefill take everything after the
 weights as ONE packed int32 array (``_words`` / ``_unpack``): one upload
 a dispatch, whatever the number of fields.
@@ -187,9 +198,10 @@ class _Delivery:
         return self._span.__exit__(*exc)
 
 
-# hand-over -> tokens on the host beyond which a joint step leaves a
-# ``serving/slow_step`` record: 7 times the longest prefill of any
-# benchmarked cell, so no healthy step passes it
+# what the host may wait in a joint step's hand-over and read-back
+# together before the step leaves a ``serving/slow_step`` record: 7 times
+# the longest prefill of any benchmarked cell, so no healthy step passes
+# it.  (Between the two the host runs its own path beside the device.)
 SLOW_STEP_S = 0.5
 
 
@@ -207,13 +219,15 @@ def _rid(req):
 
 _SAMPLING = [("key", np.uint32, (2,)), ("temperature", np.float32),
              ("top_k", np.int32), ("top_p", np.float32)]
-_LIVE, _TRASH = 1, 2  # bits of a step row's ``flags``
+_LIVE, _TRASH, _CARRY = 1, 2, 4  # bits of a step row's ``flags``
 
 
 def _step_row(pages_per_slot: int) -> np.dtype:
-    """One slot of the joint decode step; ``flags`` holds ``_LIVE`` and
+    """One slot of the joint decode step; ``flags`` holds ``_LIVE``,
     ``_TRASH`` (``write_trash_once``: the write aims at page 0, offset
-    0), ``pages`` the slot's page-table row."""
+    0) and ``_CARRY`` (the slot was live in the joint step in flight:
+    its token is that step's, still on the device, and ``token`` is not
+    read), ``pages`` the slot's page-table row."""
     return np.dtype([("token", np.int32), ("position", np.int32),
                      ("flags", np.int32), ("counter", np.int32)]
                     + _SAMPLING + [("pages", np.int32, (pages_per_slot,))])
@@ -553,12 +567,16 @@ class DecodeRequest(RequestBase):
 class _SlotState:
     __slots__ = ("req", "base_key", "n_generated", "last_token", "t_last",
                  "phase", "prefill_pos", "write_trash_once", "spec",
-                 "draft_lag", "chunks", "t_admit")
+                 "draft_lag", "chunks", "t_admit", "ahead")
 
     def __init__(self, req, base_key):
         self.req = req
         self.base_key = base_key
         self.n_generated = 0
+        # joint steps handed over for this slot whose tokens are not on
+        # the host yet (0 or 1): the next step's position and sampling
+        # counter are that much further on
+        self.ahead = 0
         self.last_token = 0
         self.t_last = time.monotonic()
         self.t_admit = self.t_last
@@ -860,9 +878,18 @@ class DecodeEngine:
         self._prefill_chunk_count = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
-        # decode rounds dispatched by THIS engine (joint steps and
+        # decode rounds handed over by THIS engine (joint steps and
         # speculative rounds): the step spans' ``step``
         self._decode_steps = 0
+        # the joint step handed over and not yet read (the half of
+        # ``_dispatch_step`` that reads and delivers it), and the tokens
+        # of the last joint step as they lie on the device: what the next
+        # step's ``_CARRY`` rows read.  Zeros until a step has run and
+        # after one has failed
+        self._flying = None
+        self._no_tokens = self._where_tokens_land(np.zeros(
+            c.slots + len(self._tallies), np.int32))
+        self._step_tokens = self._no_tokens
         # ``_loop`` iterations begun: the leaf spans' ``iter``
         self._iter = 0
         # what the open ``*_deliver`` span carried so far (tokens,
@@ -918,6 +945,22 @@ class DecodeEngine:
             return jnp.asarray(x)
 
         return jax.tree_util.tree_map(leaf, tree)
+
+    def _where_tokens_land(self, tokens):
+        """``tokens`` placed as the step's own output is: replicated over
+        the mesh the weights are spread over, else as ``_commit`` places
+        a leaf.  The step's first run then takes the operand its later
+        runs take, and compiles once."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        for leaf in jax.tree_util.tree_leaves(self.weights):
+            spread = getattr(leaf, "sharding", None)
+            if isinstance(spread, NamedSharding) \
+                    and len(spread.device_set) > 1:
+                return jax.device_put(
+                    tokens, NamedSharding(spread.mesh, PartitionSpec()))
+        return self._commit(tokens)
 
     @property
     def device(self):
@@ -1045,11 +1088,24 @@ class DecodeEngine:
         page_size = self._cache.config.page_size
         mixed = self._mixed
 
+        eos = self.config.eos_id
+
         @jax.named_scope("decode_step")
-        def step(state, weights, packed):
+        def step(state, weights, packed, carried):
             a = _unpack(packed, row)                        # fields [S]
             positions, page_table = a["position"], a["pages"]
             live = (a["flags"] & _LIVE) != 0
+            # a slot that was live in the joint step in flight takes its
+            # token from that step's output, which never visits the
+            # host (``carried``: the tokens, then that step's tallies)
+            carry = (a["flags"] & _CARRY) != 0
+            tokens = jnp.where(carry, carried[:carry.shape[0]], a["token"])
+            if eos is not None:
+                # the host has not seen that token: a row whose carried
+                # token ends its request runs dead (its write aims at
+                # the trash page, its state rows and ring stay), and
+                # the delivery drops what it yields
+                live = live & ~(carry & (tokens == eos))
 
             def recur(token_fn, rows, rec):
                 """Every slot's state one token on; a dead slot's row (a
@@ -1071,7 +1127,7 @@ class DecodeEngine:
             write_off = jnp.where(write, positions % page_size, 0)
             if mixed is None:
                 logits, pools = self._token_step_body(
-                    model, weights, _split_state(state), a["token"],
+                    model, weights, _split_state(state), tokens,
                     positions, page_table, write_page, write_off)
                 new_state = _join_state(pools)
             else:
@@ -1088,7 +1144,7 @@ class DecodeEngine:
                             + (positions // page_size) % ring, 0),
                         write_off)
                 logits, cache = self._token_step_body(
-                    model, weights, mixed.split(state), a["token"],
+                    model, weights, mixed.split(state), tokens,
                     positions, page_table, write_page, write_off, mix=mix)
                 new_state = mixed.join(cache)
             keys = jax.vmap(jax.random.fold_in)(a["key"], a["counter"])
@@ -1837,21 +1893,68 @@ class DecodeEngine:
         that decode now are built and uploaded; the queue is admitted,
         as late as anything can still go ahead of that step; the
         whole-prompt prefills of the admitted are handed to the device
-        and the step right behind them, neither waited for; then the
-        prefills' first tokens are read and delivered, then the step's.
+        and the step right behind them, neither waited for; THEN the
+        joint step handed over an iteration ago is read and delivered,
+        then the prefills' first tokens.  The step just handed over
+        stays in flight (``_flying``) into the next iteration: the host
+        delivers, reaps, builds and hands over under it, and the device
+        finds the next step queued when this one ends.
+
+        What makes the step ahead possible: a slot that was live in the
+        step in flight takes its token from that step's output on the
+        device (``_CARRY``), and what the host knows without the token
+        it computes ahead (``_SlotState.ahead``): the position, the
+        sampler's counter, the write's page, and which slots END at the
+        step in flight by their budget (those are left out, so a budget
+        finish costs no row and frees its slot when it always did).
+        What it cannot know the delivery settles: a row is delivered
+        only while its slot still holds the request it was computed for
+        (an end token, a reap, an abort or a failed read-back may have
+        released it, and an admission refilled it, meanwhile; counter
+        ``decode_rows_discarded``).  Every release while a step is in
+        flight is safe because the device runs programs in hand-over
+        order: whatever is handed over for a freed page or slot (a
+        prefill, a copy, an import) runs after the step that may still
+        write it, that write lies one position past anything the prefix
+        index registered, and the export at a finish reads prompt pages
+        no step writes.
+
+        The depth is 0 or 1, chosen from what the loop observes, by no
+        knob.  A step at which a slot ENDS by its budget (known at its
+        hand-over) is read before anything is handed over behind it: a
+        freed slot is where the next admission comes from (a queued
+        request, or in a closed loop the caller's next), and its prefill
+        then finds the device free instead of behind a step's remainder
+        (measured: `PERF.md` section 6, PR 38).  While a slot
+        speculates the loop runs one step at a time, each joint step
+        read in its own iteration (the order the loop had): a
+        speculative round runs to its own syncs.  Chunked, ragged and
+        suffix prefills queue behind the step in flight and run to
+        their own sync; before an idle wait, a stop and an abort nothing
+        is left in flight.
+
         A request admitted here joins the NEXT step (its first token is
-        not on the host when this one is handed over), nothing runs
-        ahead of its prefill on the device, and the step's dispatch
-        costs the device no idle time behind a prefill.  Nothing waits
-        for a caller: one that submits while the arguments are built
-        (a reply's caller gets the interpreter in their upload, the
-        first place after ``step_deliver`` where this thread lets go
-        of it) makes this admission, a later one the next."""
+        not on the host when this one is handed over).  Nothing waits
+        for a caller: one that submits while the arguments are built (a
+        reply's caller gets the interpreter in their upload, the first
+        place after ``step_deliver`` where this thread lets go of it)
+        makes this admission, a later one the next."""
         while True:
             self._iter += 1
             with otrace.span("serving/reap", iter=self._iter):
                 self._reap_live()
-            step = self._prepare_decode_round()
+            if self._flying is not None and (
+                    not self.live_slots or any(
+                        st is not None and st.ahead and st.n_generated
+                        + st.ahead >= st.req.max_new_tokens
+                        for st in self._slots)):
+                # a slot ends at the step in flight by its budget, and a
+                # freed slot is where the next admission comes from: that
+                # step is read BEFORE anything is handed over behind it,
+                # so that the admitted prefill finds the device free.
+                # (Or every row's slot was released: read and dropped.)
+                self._drain()
+            step, ahead_ok = self._prepare_decode_round()
             # ``with self._cond:`` with the wait for the lock (callers
             # hold it while they submit) as a phase of its own, so that
             # the iteration is spanned end to end
@@ -1863,6 +1966,7 @@ class DecodeEngine:
                         if st is not None:
                             self._finish_slot(i, ServerClosedError(
                                 "engine stopped mid-generation"))
+                    self._drain()
                     return
                 with otrace.span("serving/admit", iter=self._iter):
                     self._reap_queue_locked()
@@ -1883,11 +1987,26 @@ class DecodeEngine:
             finally:
                 self._cond.release()
             finishes = self._start_prefills()
+            handed = None
             if step is not None:
-                finishes.append(self._dispatch_step(
-                    *step, ahead=len(finishes)))
+                handed = self._dispatch_step(*step, ahead=len(finishes))
+            self._drain()   # the step handed over an iteration ago
             for finish in finishes:
                 finish()
+            if ahead_ok:
+                self._flying = handed
+            elif handed is not None:
+                handed()
+            if handed is None:
+                # no joint step in this iteration: the next one's
+                # hand-over follows more than the loop's own path
+                self._t_tokens = None
+
+    def _drain(self) -> None:
+        """Read and deliver the joint step in flight, if there is one."""
+        flying, self._flying = self._flying, None
+        if flying is not None:
+            flying()
 
     # -- device work: prefill ---------------------------------------------
     def _start_prefills(self):
@@ -2313,11 +2432,20 @@ class DecodeEngine:
     def _prepare_decode_round(self):
         """The round of the slots that decode now: speculative rounds
         run to their end; for the joint step of the rest, what
-        ``_dispatch_step`` takes (None without one)."""
+        ``_dispatch_step`` takes (None without one), and whether that
+        step may stay in flight into the next iteration.  A slot whose
+        budget ends at the step in flight is in no round: its last
+        token is on its way."""
+        speculates = any(st is not None and st.spec for st in self._slots)
+        if speculates:
+            # a speculative round runs to its own syncs on what the
+            # host holds: nothing stays in flight beside it
+            self._drain()
         decoding = [i for i, st in enumerate(self._slots)
-                    if st is not None and st.phase == "decode"]
+                    if st is not None and st.phase == "decode"
+                    and st.n_generated + st.ahead < st.req.max_new_tokens]
         if not decoding:
-            return None
+            return None, not speculates
         stat_max("decode_slot_occupancy_max", len(decoding))
         spec = [i for i in decoding
                 if self._slots[i].spec
@@ -2327,7 +2455,8 @@ class DecodeEngine:
             self._run_spec(spec)
         normal = [i for i in decoding
                   if self._slots[i] is not None and i not in set(spec)]
-        return self._prepare_step(normal) if normal else None
+        return (self._prepare_step(normal) if normal else None), \
+            not speculates
 
     def _step_args(self, live_idx) -> np.ndarray:
         """Everything one joint decode step takes after its weights:
@@ -2344,13 +2473,17 @@ class DecodeEngine:
                 "temperature", "top_k", "top_p"))
         for i in live_idx:
             st = self._slots[i]
+            # with a step of this slot in flight (``ahead``) the token
+            # is that step's, on the device, and everything the host
+            # counts is one further on than its books
             tokens[i] = st.last_token
-            positions[i] = self._cache.lengths[i]
+            positions[i] = self._cache.lengths[i] + st.ahead
             # cache-hit first step: the shared pages already hold this
             # position's K/V — re-deriving it writes identical bytes,
             # but shared pages are immutable, so the write aims at trash
-            flags[i] = _LIVE | (_TRASH if st.write_trash_once else 0)
-            counters[i] = st.n_generated
+            flags[i] = _LIVE | (_TRASH if st.write_trash_once else 0) \
+                | (_CARRY if st.ahead else 0)
+            counters[i] = st.n_generated + st.ahead
             base_keys[i] = st.base_key
             temp[i] = st.req.temperature
             top_k[i] = st.req.top_k
@@ -2395,13 +2528,14 @@ class DecodeEngine:
         stat_set("decode_window_pages_held",
                  self._cache.window_pages_held())
 
-    def _lower(self, fn, packed, sharding):
+    def _lower(self, fn, operands, sharding):
         """``fn`` (the step, a whole-prompt prefill) lowered at this
-        engine's own shapes; nothing runs."""
+        engine's own shapes, ``operands`` what it takes after the
+        weights; nothing runs."""
         import jax
 
         args = (tuple(self._scope.get_var(n) for n in self._state_vars),
-                self.weights, packed)
+                self.weights) + tuple(operands)
         if sharding is not None:
             args = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
@@ -2414,13 +2548,14 @@ class DecodeEngine:
         in it (``tpu_custom_call``), ``.compile()`` asks the compiler.
         ``sharding`` re-targets every operand (e.g. to one device of a
         described, unattached topology) by lowering from shapes."""
-        return self._lower(self._step_fn, self._step_args(()), sharding)
+        return self._lower(self._step_fn,
+                           (self._step_args(()), self._no_tokens), sharding)
 
     def lower_prefill(self, t_pad: int, sharding=None):
         """The whole-prompt prefill of bucket ``t_pad``, as
         ``lower_step``."""
         return self._lower(self._prefill_fn(t_pad),
-                           self._prefill_args(t_pad, (0,)), sharding)
+                           (self._prefill_args(t_pad, (0,)),), sharding)
 
     def _prepare_step(self, live_idx):
         """The joint step of ``live_idx`` up to its uploaded arguments.
@@ -2429,39 +2564,52 @@ class DecodeEngine:
         slot it fills reads as dead in these arguments."""
         attrs = {"iter": self._iter, "step": self._decode_steps,
                  "live": len(live_idx)}
+        self._decode_steps += 1   # the ordinal is the hand-over's
         # copy-on-write any shared page this step would write (a
         # borrowed partial tail at its first divergent token)
         with otrace.span("serving/step_cow", **attrs):
             for i in live_idx:
-                if not self._slots[i].write_trash_once:
+                st = self._slots[i]
+                if not st.write_trash_once:
                     self._perform_cow(i, self._cache.plan_cow(
-                        i, [int(self._cache.lengths[i])]))
+                        i, [int(self._cache.lengths[i]) + st.ahead]))
         with otrace.span("serving/step_args", **attrs):
             up = _Uploads()
-            args = (self.weights, up(self._step_args(live_idx)))
+            args = (self.weights, up(self._step_args(live_idx)),
+                    self._step_tokens)
             up.record()
         return live_idx, attrs, args
 
     def _dispatch_step(self, live_idx, attrs, args, ahead=0):
-        """Hand the prepared joint step to the device, behind the
-        ``ahead`` whole-prompt prefills of its iteration; returns the
-        half that reads its tokens and delivers them."""
+        """Hand the prepared joint step to the device, behind the joint
+        step in flight (if any) and the ``ahead`` whole-prompt prefills
+        of its iteration; returns the half that reads its tokens and
+        delivers them, which the loop calls an iteration later."""
         t0 = time.monotonic()
-        # the period's other half: the last step's tokens on the host
-        # until here; observed behind this step's own observation, so
-        # that nothing new lies inside ``decode_step_seconds``
-        turnaround = None if self._t_tokens is None \
+        in_flight = int(self._flying is not None)
+        # the period's other half where the loop runs serially: the
+        # last step's tokens on the host until a hand-over with nothing
+        # in flight (behind a step in flight the device waits for
+        # nothing the host does here); observed behind this step's own
+        # observation, so that nothing new lies inside
+        # ``decode_step_seconds``
+        turnaround = None if self._t_tokens is None or in_flight \
             else t0 - self._t_tokens
         self._t_tokens = None
+        # the requests these rows are computed for: a slot may be
+        # released, and filled again, before they are delivered
+        states = [self._slots[i] for i in live_idx]
 
-        def failed(e):  # fail the batch loudly, free every slot, keep
-            # the consumer thread alive
+        def failed(e):  # fail the batch loudly, free every slot that
+            # still holds its request, keep the consumer thread alive
             stat_add("decode_step_errors")
-            for i in live_idx:
-                self._finish_slot(i, e)
+            for i, st in zip(live_idx, states):
+                if self._slots[i] is st:
+                    self._finish_slot(i, e)
 
         try:
-            with otrace.span("serving/step_dispatch", **attrs):
+            with otrace.span("serving/step_dispatch", in_flight=in_flight,
+                             **attrs):
                 nxt, logits, *recorded = self._exe.run_persistent(
                     self._step_fn, self._state_vars, args=args,
                     scope=self._scope)
@@ -2470,23 +2618,32 @@ class DecodeEngine:
         except Exception as e:  # noqa: BLE001
             failed(e)
             return lambda: None
+        self._step_tokens = nxt
+        stat_add("decode_steps_ahead", in_flight)
+        for st in states:
+            st.ahead += 1
+            st.write_trash_once = False
 
         def finish():
+            # the step's number stays, the iteration is the one that reads
+            leaf = {**attrs, "iter": self._iter}
             try:
-                with otrace.span("serving/step_sync", **attrs):
+                with otrace.span("serving/step_sync", **leaf):
                     t2 = time.monotonic()
                     tokens = np.asarray(nxt)  # THE per-step sync point
             except Exception as e:  # noqa: BLE001
+                if self._step_tokens is nxt:
+                    # no later step may carry what could not be read
+                    self._step_tokens = self._no_tokens
                 failed(e)
                 return
             t3 = self._t_tokens = time.monotonic()
             stat_time("decode_step_seconds", t3 - t0)
-            self._decode_steps += 1
-            with _Delivery(self, "serving/step_deliver", attrs):
+            with _Delivery(self, "serving/step_deliver", leaf):
                 if turnaround is not None:
                     stat_time("decode_turnaround_seconds", turnaround)
                 # the program's first run loads or compiles it: exempt
-                if t3 - t0 > SLOW_STEP_S and self._step_ran:
+                if (t1 - t0) + (t3 - t2) > SLOW_STEP_S and self._step_ran:
                     self._record_slow_step(attrs, ahead,
                                            (t0, t1, t2, t3))
                 self._step_ran = True
@@ -2496,9 +2653,16 @@ class DecodeEngine:
                                    tokens[len(self._slots):]):
                     stat_add(name, int(n))
                 logits_np = recorded_np = None
-                for i in live_idx:
-                    st = self._slots[i]
-                    st.write_trash_once = False
+                discarded = 0
+                for i, st in zip(live_idx, states):
+                    if self._slots[i] is not st:
+                        # released while the step was in flight (an end
+                        # token a step before, a reap, an abort, a
+                        # failed batch), perhaps filled again since:
+                        # the row is nobody's
+                        discarded += 1
+                        continue
+                    st.ahead -= 1
                     if st.spec:
                         st.draft_lag += 1  # target-only write: draft stale
                     self._cache.lengths[i] += 1
@@ -2512,16 +2676,18 @@ class DecodeEngine:
                             st.req.records.setdefault(name, []).append(
                                 rows[i].copy())
                     self._deliver(i, int(tokens[i]))
+                if discarded:
+                    stat_add("decode_rows_discarded", discarded)
                 stat_set("decode_slot_occupancy", self.live_slots)
                 stat_add("decode_steps")
 
         return finish
 
     def _record_slow_step(self, attrs, ahead, stamps):
-        """A joint step that took longer than ``SLOW_STEP_S`` from its
-        hand-over to its tokens: how often, in which phase (the
-        hand-over or the read-back; whether the device or the wake-up
-        was late no host clock can say), behind how many prefills."""
+        """A joint step whose hand-over and read-back together took the
+        host longer than ``SLOW_STEP_S``: how often, in which phase
+        (whether the device or the wake-up was late no host clock can
+        say), behind how many prefills."""
         from ..observe import flight as _flight
 
         stat_add("decode_steps_slow")
@@ -2529,7 +2695,7 @@ class DecodeEngine:
         _flight.record(
             "serving/slow_step", name=self.name, iter=attrs["iter"],
             step=attrs["step"], live=attrs["live"], prefills_ahead=ahead,
-            seconds=round(t3 - t0, 6), t_handover_begin=t0,
+            seconds=round((t1 - t0) + (t3 - t2), 6), t_handover_begin=t0,
             t_handover_end=t1, t_readback_begin=t2, t_readback_end=t3)
 
     def _run_spec(self, spec_idx):
@@ -2579,7 +2745,8 @@ class DecodeEngine:
                 self._propose_fn = self._build_propose_fn(k)
             # the round is two dispatches, each with its own phases:
             # the draft burst, then the target's verify of its proposals
-            with otrace.span("serving/step_dispatch", **attrs):
+            with otrace.span("serving/step_dispatch", in_flight=0,
+                             **attrs):
                 (props,) = self._exe.run_persistent(
                     self._propose_fn, self._draft_state_vars,
                     args=propose_args, scope=self._scope)
@@ -2611,7 +2778,8 @@ class DecodeEngine:
                     up.host(np.zeros((s,), np.int32)),
                     up.host(np.ones((s,), np.float32)))
                 up.record()
-            with otrace.span("serving/step_dispatch", **attrs):
+            with otrace.span("serving/step_dispatch", in_flight=0,
+                             **attrs):
                 _tok, greedy, logits = self._exe.run_persistent(
                     self._rows_fn(rows, s), self._state_vars,
                     args=verify_args, scope=self._scope)

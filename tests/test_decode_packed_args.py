@@ -232,10 +232,12 @@ def test_lowering_for_a_look_uploads_nothing(model_and_weights):
     assert "module @jit_step" in eng.lower_step().as_text()
     assert "module @jit_prefill" in eng.lower_prefill(16).as_text()
     assert stat_get("decode_h2d_uploads") == before
-    # one operand after the state and the weights: the packed words
-    (packed,) = eng.lower_step().args_info[0][2:]
+    # two operands after the state and the weights: the packed words,
+    # and the tokens of the step before as they lie on the device
+    packed, carried = eng.lower_step().args_info[0][2:]
     assert packed.shape == (3, eng._step_row.itemsize // 4)
     assert packed.dtype == np.int32
+    assert (carried.shape, carried.dtype) == ((3,), np.int32)
 
 
 # -- the order of an iteration ----------------------------------------------
@@ -246,8 +248,11 @@ def test_admission_follows_the_steps_upload_and_its_prefill_goes_first(
     one's first ``on_token``, on the engine's thread: no race) is
     admitted AFTER the joint step's arguments were built and BEFORE the
     step is handed to the device; its prefill is handed over ahead of
-    that step and its first token delivered ahead of the step's; it
-    joins the next step."""
+    that step and its first token delivered; it joins the next step,
+    which is handed over BEFORE the tokens of the step in flight are
+    read: the next token of a slot that was live in it never visits
+    the host.  Both requests end by their budget at the step in flight,
+    so no third step is built for them."""
     eng = make_engine(model_and_weights, slots=2, prefix_cache=False)
     log = []
     run, admit, deliver, step_args = (
@@ -289,5 +294,6 @@ def test_admission_follows_the_steps_upload_and_its_prefill_goes_first(
     finally:
         eng.stop()
     assert log == ["admit", "prefill", "token0",
-                   "args[0]", "admit", "prefill", "step", "token1", "token0",
-                   "args[0, 1]", "step", "token0", "token1"]
+                   "args[0]", "admit", "prefill", "step", "token1",
+                   "args[0, 1]", "step", "token0",
+                   "token0", "token1"]

@@ -13,6 +13,12 @@ the ``*_deliver`` spans say what they carried while a sink takes them
 (``observe.tracer.recording``); ``decode_turnaround_seconds`` is the
 loop between two joint steps; a step slower than ``SLOW_STEP_S`` leaves
 a flight-recorder event, the program's first run apart.
+
+PR 38: a joint step is handed over while the one before it is in flight
+and read an iteration later: a step's five leaves span two iterations
+and share one ``step``; ``step_dispatch`` says how many joint steps were
+in flight (``in_flight``); the turnaround is observed only where the
+loop runs one step at a time (a round with a speculative slot).
 """
 import contextlib
 import glob
@@ -199,12 +205,14 @@ def test_engine_iteration_is_a_row_of_leaf_phases(model_and_weights,
         eng.submit(list(range(1, 12)), max_new_tokens=2).result(timeout=120)
         uploads0 = stat_get("decode_h2d_uploads")
         bytes0 = stat_get("decode_h2d_bytes")
+        ahead0 = stat_get("decode_steps_ahead")
         with traced(tmp_path):
             req = eng.submit(list(range(2, 12)), max_new_tokens=3)
             req.result(timeout=120)
             time.sleep(0.05)             # the last deliver span closes
         uploads = stat_get("decode_h2d_uploads") - uploads0
         nbytes = stat_get("decode_h2d_bytes") - bytes0
+        ahead = stat_get("decode_steps_ahead") - ahead0
     finally:
         eng.stop()
     (engine,) = [rows for rows in host_lines(tmp_path, "serving/")
@@ -213,17 +221,25 @@ def test_engine_iteration_is_a_row_of_leaf_phases(model_and_weights,
     # (reap ->) lock_wait -> admit -> the request's prefill, then whole
     # iterations with nothing to admit: the step's arguments are built
     # and uploaded BEFORE the admission, the step is handed over after
-    # it: 1 + 2 tokens.  The idle wait the submit woke the engine from
-    # began before the trace and left no event, and the microsecond of
-    # reap right behind it is not always kept.
+    # it, and only THEN the step of the iteration before is read and
+    # delivered: 1 + 2 tokens, the second step handed over while the
+    # first is in flight, no third built (the budget ends at the step
+    # in flight, which is therefore read at the head of the next
+    # iteration: the slot it frees is where an admission would come
+    # from).  The idle wait the submit woke the engine from began
+    # before the trace and left no event, and the microsecond of reap
+    # right behind it is not always kept.
     if names[0] == "serving/reap":
         del names[0], engine[0]
     head = ["serving/lock_wait", "serving/admit"]
-    iteration = ["serving/reap"] + STEP[:2] + head + STEP[2:]
+    hand_over = ["serving/reap"] + STEP[:2] + head + STEP[2:3]
+    read = STEP[3:]
     first = head + PREFILL
     assert names[:len(first)] == first
-    assert names[len(first):][:len(iteration)] == iteration
-    assert names.count("serving/step_dispatch") == 2
+    rest = names[len(first):]
+    want = hand_over + hand_over + read + ["serving/reap"] + read + head
+    assert rest[:len(want)] == want
+    assert names.count("serving/step_dispatch") == 2 and ahead == 1
     # leaf phases: each ends before the next begins, none encloses another
     for a, b in zip(engine, engine[1:]):
         assert a[1] <= b[0], (a, b)
@@ -232,18 +248,24 @@ def test_engine_iteration_is_a_row_of_leaf_phases(model_and_weights,
         by_name.setdefault(e[2], []).append(e[3])
     admit = by_name["serving/admit"][0]
     assert (admit["admitted"], admit["queued"]) == (1, 0)
-    # one TTFT can be walked by the request's id, one step by its number
+    # one TTFT can be walked by the request's id, one step by its
+    # number, fixed at its hand-over: its read-back and delivery, an
+    # iteration later, carry it too
     rid = req.trace.trace_id
     assert all(by_name[n][0]["req"] == rid for n in PREFILL)
     step = by_name["serving/step_cow"][0]["step"]
-    assert all(by_name[n][0]["step"] == step for n in STEP)
-    assert by_name["serving/step_cow"][1]["step"] == step + 1
+    for k in (0, 1):
+        assert all(by_name[n][k]["step"] == step + k for n in STEP)
+        assert by_name["serving/step_deliver"][k]["iter"] == \
+            by_name["serving/step_dispatch"][k]["iter"] + 1
+    assert [a["in_flight"] for a in by_name["serving/step_dispatch"]] \
+        == [0, 1]
     # the *_args spans carry what the counters counted, no more, no less
     args = [e[3] for e in engine if e[2].endswith("_args")]
     assert sum(a["uploads"] for a in args) == uploads > 0
     assert sum(a["upload_bytes"] for a in args) == nbytes > 0
     # ONE packed array a joint step and a whole-prompt prefill, whatever
-    # the number of fields in it
+    # the number of fields in it: the carried tokens are no upload
     assert [a["uploads"] for a in by_name["serving/step_args"]] == [1, 1]
     assert [a["uploads"] for a in by_name["serving/prefill_args"]] == [1]
     assert uploads == 3
@@ -316,7 +338,8 @@ def loop_trace(model_and_weights, tmp_path_factory):
         time.sleep(0.1)                  # the engine is in its idle wait
         turn, step = (histogram("decode_turnaround_seconds"),
                       histogram("decode_step_seconds"))
-        before = (turn.count, turn.sum, step.count)
+        before = (turn.count, turn.sum, step.count,
+                  stat_get("decode_steps_ahead"))
         with traced(log_dir):
             eng.submit(list(range(2, 12)), max_new_tokens=4,
                        on_token=slow).result(timeout=120)
@@ -325,12 +348,13 @@ def loop_trace(model_and_weights, tmp_path_factory):
                        max_new_tokens=4).result(timeout=120)
             time.sleep(0.05)             # the last deliver span closes
         seen = (turn.count - before[0], turn.sum - before[1],
-                step.count - before[2])
+                step.count - before[2],
+                stat_get("decode_steps_ahead") - before[3])
     finally:
         eng.stop()
     return {"engine": engine_line(log_dir), "idle_s": idle_s,
             "turnarounds": seen[0], "turnaround_s": seen[1],
-            "steps": seen[2]}
+            "steps": seen[2], "ahead": seen[3]}
 
 
 def by_iteration(engine):
@@ -353,16 +377,43 @@ def test_every_leaf_of_an_iteration_carries_its_ordinal(loop_trace):
     stepped = [rows for rows in iters.values()
                if any(e[2] == "serving/step_dispatch" for e in rows)]
     assert len(stepped) == loop_trace["steps"] == 6
+    hand_over = ["serving/reap"] + STEP[:2] + \
+        ["serving/lock_wait", "serving/admit"] + STEP[2:3]
     for rows in stepped:
+        (dispatch,) = [e[3] for e in rows
+                       if e[2] == "serving/step_dispatch"]
+        # behind a step in flight the iteration also reads that step;
+        # a request's first step finds nothing to read
         assert [e[2] for e in rows] == \
-            ["serving/reap"] + STEP[:2] + \
-            ["serving/lock_wait", "serving/admit"] + STEP[2:]
-        # the step spans' own number stays beside the iteration's
-        assert len({e[3]["step"] for e in rows if "step" in e[3]}) == 1
+            hand_over + (STEP[3:] if dispatch["in_flight"] else [])
+        # the step spans' own number stays beside the iteration's: the
+        # leaves that hand over carry this step's, the two that read
+        # the number of the step handed over an iteration ago
+        for e in rows:
+            if "step" in e[3]:
+                assert e[3]["step"] == dispatch["step"] - (
+                    e[2] in STEP[3:])
+    # the last step of a request is read in an iteration that hands
+    # nothing over: every step is read exactly once
+    by_step = {}
+    for e in engine:
+        if "step" in e[3]:
+            by_step.setdefault(e[3]["step"], []).append(e[2])
+    assert len(by_step) == 6 and all(v == STEP for v in by_step.values())
     (first, second) = [rows for rows in iters.values()
                        if any(e[2] == "serving/prefill_dispatch"
                               for e in rows)]
     assert [e[2] for e in first if "prefill" in e[2]] == PREFILL
+
+
+def test_steps_run_ahead_except_behind_an_idle_wait(loop_trace):
+    """Two requests of four tokens with the engine idle between them:
+    three joint steps each, the first handed over with nothing in
+    flight, the other two behind the step before them."""
+    in_flight = [e[3]["in_flight"] for e in loop_trace["engine"]
+                 if e[2] == "serving/step_dispatch"]
+    assert in_flight == [0, 1, 1, 0, 1, 1]
+    assert loop_trace["ahead"] == sum(in_flight) == loop_trace["steps"] - 2
 
 
 def test_step_deliver_says_what_it_carried(loop_trace):
@@ -434,34 +485,106 @@ def test_a_delivery_reads_no_clock_while_no_sink_takes_its_span(
     assert eng._carried is None
 
 
-def test_turnaround_is_seen_once_a_step_and_never_across_an_idle_wait(
+def test_turnaround_is_seen_at_no_hand_over_behind_a_step_in_flight(
         loop_trace):
     # three joint steps a request; the first follows the engine's idle
-    # wait (and an iteration with the prefill alone): no turnaround
-    assert loop_trace["steps"] == 6
-    assert loop_trace["turnarounds"] == 4
+    # wait (and an iteration with the prefill alone), the others are
+    # handed over behind the step before them: the device waits for
+    # nothing the host does, and nothing is observed, not even beside
+    # a callback that sleeps
+    assert loop_trace["steps"] == 6 and loop_trace["ahead"] == 4
+    assert loop_trace["turnarounds"] == 0
+    assert loop_trace["turnaround_s"] == 0
     assert any(e[2] == "serving/idle_wait" for e in loop_trace["engine"])
-    # the 0.3 s the engine waited for the second request are in none;
-    # the slow callback's deliveries are (tokens on the host -> the
-    # next hand-over)
-    assert 2 * SLOW_CALLBACK_S <= loop_trace["turnaround_s"] \
-        < loop_trace["idle_s"]
 
 
-def test_turnaround_runs_from_the_sync_to_the_next_hand_over(loop_trace):
-    """With ``decode_step_seconds`` (hand-over -> tokens on the host)
-    the histogram tiles the period: what it saw is the trace's time
-    from each ``step_sync``'s end to the next ``step_dispatch``'s
-    begin, the two spans' own opening and closing aside."""
-    engine = loop_trace["engine"]
-    dispatch = [e for e in engine if e[2] == "serving/step_dispatch"]
-    sync = [e for e in engine if e[2] == "serving/step_sync"]
-    gaps = [dispatch[k][0] - sync[k - 1][1] for k in (1, 2, 4, 5)]
+@pytest.fixture(scope="module")
+def serial_trace(model_and_weights, tmp_path_factory):
+    """A round that holds a speculative slot runs one step at a time:
+    a request that speculates beside one that opted out, so that every
+    iteration is a speculative round and then a joint step, read in its
+    own iteration."""
+    log_dir = tmp_path_factory.mktemp("serial_trace")
+    model, weights = model_and_weights
+    eng = DecodeEngine(model, weights, DecodeConfig(
+        slots=2, max_seq_len=64, page_size=8, max_new_tokens=8,
+        prefix_cache=False, spec_k=2),
+        draft_model=model, draft_weights=weights).start()
+    try:
+        eng.submit(list(range(1, 12)), max_new_tokens=4).result(timeout=120)
+        eng.submit(list(range(1, 12)), max_new_tokens=3,
+                   speculative=False).result(timeout=120)
+        time.sleep(0.1)                  # the engine is in its idle wait
+        turn = histogram("decode_turnaround_seconds")
+        before = (turn.count, turn.sum, stat_get("decode_steps_ahead"))
+        with traced(log_dir):
+            plain = eng.submit(list(range(3, 12)), max_new_tokens=6,
+                               speculative=False)
+            spec = eng.submit(list(range(2, 12)), max_new_tokens=16)
+            plain.result(timeout=120), spec.result(timeout=120)
+            time.sleep(0.05)
+        seen = (turn.count - before[0], turn.sum - before[1],
+                stat_get("decode_steps_ahead") - before[2])
+    finally:
+        eng.stop()
+    return {"engine": engine_line(log_dir), "turnarounds": seen[0],
+            "turnaround_s": seen[1], "ahead": seen[2]}
+
+
+def joint_steps(engine, name):
+    """The ``name`` leaves of joint steps (a speculative round's carry
+    ``k``)."""
+    return [e for e in engine if e[2] == name and "k" not in e[3]]
+
+
+def test_turnaround_is_seen_where_the_loop_runs_one_step_at_a_time(
+        serial_trace):
+    engine = serial_trace["engine"]
+    dispatch = joint_steps(engine, "serving/step_dispatch")
+    # the request that opted out: five joint steps beside the other's
+    # speculative rounds, none ahead, each read in its own iteration
+    assert len(dispatch) >= 5 and serial_trace["ahead"] == 0
+    assert all(e[3]["in_flight"] == 0 for e in engine
+               if e[2] == "serving/step_dispatch")
+    for d, sync in zip(dispatch, joint_steps(engine, "serving/step_sync")):
+        assert d[3]["iter"] == sync[3]["iter"]
+    # every joint step but the first of the busy stretch saw one
+    assert serial_trace["turnarounds"] == len(dispatch) - 1
+
+
+def test_turnaround_runs_from_the_sync_to_the_next_hand_over(serial_trace):
+    """Where the loop runs serially the histogram is the other half of
+    ``decode_step_seconds``' period: what it saw is the trace's time
+    from each joint ``step_sync``'s end to the next joint
+    ``step_dispatch``'s begin, the two spans' own opening and closing
+    aside."""
+    engine = serial_trace["engine"]
+    dispatch = joint_steps(engine, "serving/step_dispatch")
+    sync = joint_steps(engine, "serving/step_sync")
+    gaps = [dispatch[k][0] - sync[k - 1][1]
+            for k in range(1, len(dispatch))]
     assert all(g > 0 for g in gaps)
-    assert abs(sum(gaps) * 1e-9 - loop_trace["turnaround_s"]) < 5e-3
+    assert abs(sum(gaps) * 1e-9 - serial_trace["turnaround_s"]) < 5e-3
 
 
 # -- a slow step leaves a record -------------------------------------------
+
+class _SlowRead:
+    """``numpy`` as the engine's module sees it, but for ``asarray`` of
+    one chosen array, which takes ``SLOW_SYNC_S``: a slow read-back of a
+    step whose tokens stay a device array (the next step carries them)."""
+
+    def __init__(self):
+        self.slow = None
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, a, *args, **kw):
+        if a is self.slow:
+            time.sleep(SLOW_SYNC_S)
+        return np.asarray(a, *args, **kw)
+
 
 @pytest.mark.parametrize("slow_run", [0, 1])
 def test_a_slow_step_leaves_a_flight_record(model_and_weights,
@@ -473,6 +596,8 @@ def test_a_slow_step_leaves_a_flight_record(model_and_weights,
     from paddle_tpu.serving import decode
 
     monkeypatch.setattr(decode, "SLOW_STEP_S", SLOW_SYNC_S * 0.9)
+    reads = _SlowRead()
+    monkeypatch.setattr(decode, "np", reads)
     eng = make_engine(model_and_weights)
     run = eng._exe.run_persistent
     runs = []
@@ -482,7 +607,7 @@ def test_a_slow_step_leaves_a_flight_record(model_and_weights,
         if fn is eng._step_fn:
             runs.append(True)
             if len(runs) == slow_run + 1:
-                return (_SlowToken(out[0]),) + tuple(out[1:])
+                reads.slow = out[0]
         return out
 
     monkeypatch.setattr(eng._exe, "run_persistent", slow_once)

@@ -251,7 +251,7 @@ def test_gpt2_width_step_walks_blocks_of_eight_pages():
     page a block fails here.  Traced, not compiled: needs no chip."""
     eng = _gpt2_width_engine(16, False, vocab_size=512, slots=32)
     args = (tuple(eng._scope.get_var(n) for n in eng._state_vars),
-            eng.weights, eng._step_args(()))
+            eng.weights, eng._step_args(()), eng._no_tokens)
     calls = list(_pallas_calls(eng._step_fn.trace(*args).jaxpr.jaxpr))
     assert len(calls) == eng.model.num_layers
     for eqn in calls:

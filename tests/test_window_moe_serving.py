@@ -236,7 +236,7 @@ def test_the_window_call_has_its_own_name_and_walks_two_blocks_at_most():
 
     state = tuple(eng._scope.get_var(n) for n in eng._state_vars)
     walk(jax.make_jaxpr(eng._step_fn)(
-        state, eng.weights, eng._step_args(())).jaxpr)
+        state, eng.weights, eng._step_args(()), eng._no_tokens).jaxpr)
     # (name, flat page-table words): 3 slots x 16 pages, 3 slots x ring
     assert names == {("paged_attention", 3 * 16),
                      ("paged_attention_window", 3 * RING)}
