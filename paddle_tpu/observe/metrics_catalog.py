@@ -196,12 +196,15 @@ RULES = (
          "walk.  Their ratio is the share of the table that is work"),
     Rule("decode_prefill_keys_", "gauge", "serving",
          "Cache positions a whole-prompt prefill's attention meets a "
-         "layer and head, added once a prefill from the numbers the "
-         "host holds: `_attended` the bucket's rows times the positions "
-         "each row's softmax spans (the bucket), `_live` those a prompt "
-         "row can see (length x (length + 1) / 2).  Their ratio is the "
-         "share of the attention that is work; the rest is the causal "
-         "triangle's other half and the bucket's padding"),
+         "head, summed over the model's layers that have keys, added "
+         "once a prefill from the numbers the host holds: `_attended` "
+         "the bucket's rows times the positions each row's softmax spans "
+         "(the bucket; in a window layer the keys a block of query rows "
+         "reaches through the window), `_live` those a prompt row can "
+         "see (length x (length + 1) / 2; in a window layer its last "
+         "`window`).  Their ratio is the share of the attention that is "
+         "work; the rest is the causal triangle's other half and the "
+         "bucket's padding"),
     Rule("decode_steps_", "gauge", "serving",
          "Joint decode steps whose sampler does more than an argmax, "
          "added once a step from the knobs the engine hands over: "
@@ -253,6 +256,13 @@ RULES = (
          "window) a slot), added once a joint decode step; over "
          "`decode_window_blocks_walked` x a block's positions it is the "
          "share of what the window kernel reads that is attended"),
+    Rule("decode_window_rows", "gauge", "serving",
+         "Whether the window caps anything, added once a joint decode "
+         "step from the lengths the engine holds: `decode_window_rows` "
+         "the live rows, `_capped` those whose length exceeds the window "
+         "(their attention reads the window, not the context).  Their "
+         "ratio is the share of the step's rows for which a window layer "
+         "is cheaper than a global one"),
     Rule("decode_window_bytes", "gauge", "serving",
          "Device bytes of the window layers' ring pools, all slots: no "
          "term in max_seq_len; 0 for a model without window layers"),

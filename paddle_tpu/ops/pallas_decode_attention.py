@@ -152,6 +152,27 @@ def decode_attention_reference(q, k, v, lengths, *, sm_scale=None,
     return out.astype(q.dtype)
 
 
+# float32 scores one block of query rows may hold, all heads: what bounds
+# the whole-prompt prefill's attention temporaries (128 heads x 4,096 keys
+# make that 256 rows; a prompt whose whole score tensor fits is one block)
+_SCORE_BLOCK_BYTES = 512 << 20
+
+
+def prefill_key_span(t, num_heads, window=None):
+    """(query rows a block, keys a block's softmax spans) of
+    ``grouped_causal_attention`` at a prompt bucket of ``t`` rows and
+    ``num_heads`` query heads: the fewest equal blocks whose float32
+    scores ``[H, rows, t]`` fit ``_SCORE_BLOCK_BYTES``; a window layer's
+    block spans the keys its rows' windows reach (``window - 1 + rows``,
+    up to whole lanes), never more than ``t``."""
+    need = -(-num_heads * t * t * 4 // _SCORE_BLOCK_BYTES)
+    n = next(n for n in range(max(need, 1), t + 1) if t % n == 0)
+    rows = t // n
+    if window is None:
+        return rows, t
+    return rows, min(t, -(-(window - 1 + rows) // _LANES) * _LANES)
+
+
 def grouped_causal_attention(q, k, v, *, sm_scale=None, window=None,
                              sinks=None):
     """Causal attention of one sequence whose query heads outnumber its
@@ -160,27 +181,49 @@ def grouped_causal_attention(q, k, v, *, sm_scale=None, window=None,
     ``<= t`` (with ``window``: the last ``window`` of them, itself
     counted), and ``sinks`` [H] joins each head's softmax as a logit
     with no value.  The whole-prompt prefill of a grouped-query model
-    (serving/decode.py): plain jnp at the prompt's own width, float32
-    softmax."""
+    (serving/decode.py): plain jnp, float32 softmax, in blocks of query
+    rows one after another (``prefill_key_span``), so the scores that
+    live at once are a block's ``[Hkv, G, rows, span]`` and not ``T x
+    T``; a masked key weighs exactly zero, so a block's softmax over the
+    span is the row's over the prompt."""
     t, h, d = q.shape
     kv_heads = k.shape[1]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    qg = q.astype(jnp.float32).reshape(t, kv_heads, h // kv_heads, d)
-    s = jnp.einsum("thgd,uhd->hgtu", qg, k.astype(jnp.float32)) * sm_scale
-    pos = jnp.arange(t, dtype=jnp.int32)
-    mask = pos[None, :] <= pos[:, None]
-    if window is not None:
-        mask = mask & (pos[None, :] > pos[:, None] - window)
-    s = jnp.where(mask, s, _NEG_INF)
+    rows, span = prefill_key_span(t, h, window)
+    qg = q.astype(jnp.float32).reshape(t // rows, rows, kv_heads,
+                                       h // kv_heads, d)
+    kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
     if sinks is not None:
-        sink = sinks.astype(jnp.float32).reshape(kv_heads, -1, 1, 1)
-        s = jnp.concatenate(
-            [s, jnp.broadcast_to(sink, s.shape[:-1] + (1,))], axis=-1)
-    p = jax.nn.softmax(s, axis=-1)
-    if sinks is not None:
-        p = p[..., :-1]
-    out = jnp.einsum("hgtu,uhd->thgd", p, v.astype(jnp.float32))
+        sink = jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(kv_heads, -1, 1, 1),
+            (kv_heads, h // kv_heads, rows, 1))
+
+    def block(first, qb):
+        """Query rows ``first ...`` against the ``span`` keys that end
+        with the block's last row (from 0 while the prompt is shorter)."""
+        lo = jnp.clip(first + rows - span, 0, t - span)
+        kb = lax.dynamic_slice_in_dim(kf, lo, span)
+        vb = lax.dynamic_slice_in_dim(vf, lo, span)
+        s = jnp.einsum("thgd,uhd->hgtu", qb, kb) * sm_scale
+        row = first + jnp.arange(rows, dtype=jnp.int32)[:, None]
+        col = lo + jnp.arange(span, dtype=jnp.int32)[None, :]
+        mask = col <= row
+        if window is not None:
+            mask = mask & (col > row - window)
+        s = jnp.where(mask, s, _NEG_INF)
+        if sinks is not None:
+            s = jnp.concatenate([s, sink], axis=-1)
+        p = jax.nn.softmax(s, axis=-1)
+        if sinks is not None:
+            p = p[..., :-1]
+        return jnp.einsum("hgtu,uhd->thgd", p, vb)
+
+    if rows == t:
+        out = block(0, qg[0])
+    else:
+        out = lax.map(lambda a: block(*a), (
+            jnp.arange(0, t, rows, dtype=jnp.int32), qg))
     return out.reshape(t, h, v.shape[-1]).astype(q.dtype)
 
 

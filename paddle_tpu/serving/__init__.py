@@ -59,6 +59,7 @@ from .kv_cache import (  # noqa: F401
     PageAllocator,
     PrefixIndex,
 )
+from .parallel_moe_lm import ParallelMoELM  # noqa: F401
 from .window_moe_lm import WindowMoELM  # noqa: F401
 from .server import (  # noqa: F401
     DecodeServer,
@@ -72,7 +73,8 @@ __all__ = [
     "CacheExhaustedError", "DeadlineExceededError", "DecodeConfig",
     "DecodeEngine", "DecodeRequest", "DecodeServer", "DisaggConfig",
     "DisaggRequest", "DisaggServer", "HybridMoELM", "InferenceRequest",
-    "KVPageExport", "PageAllocator", "PagedKVCache", "PrefixIndex",
+    "KVPageExport", "PageAllocator", "PagedKVCache", "ParallelMoELM",
+    "PrefixIndex",
     "QueueFullError", "RequestAbandonedError", "RequestBase",
     "RequestTooLargeError", "Server", "ServerClosedError",
     "ServingConfig", "ServingError", "TransformerLM", "WindowMoELM",

@@ -2127,8 +2127,9 @@ class DecodeEngine:
                     # positions every row's softmax spans (the bucket)
                     # against those a row can see (the causal triangle)
                     n = len(req.prompt)
-                    stat_add("decode_prefill_keys_attended", t_pad * t_pad)
-                    stat_add("decode_prefill_keys_live", n * (n + 1) // 2)
+                    attended, live = self._prefill_keys(t_pad, n)
+                    stat_add("decode_prefill_keys_attended", attended)
+                    stat_add("decode_prefill_keys_live", live)
                     record_pad_waste(n, t_pad)
                     st.prefill_pos = n
                     st.phase = "decode"
@@ -2508,6 +2509,26 @@ class DecodeEngine:
                 self._count_window(positions[list(live_idx)])
         return _words(rows)
 
+    def _prefill_keys(self, t_pad: int, n: int):
+        """(keys the softmaxes of a whole-prompt prefill span, keys its
+        ``n`` prompt rows can see) a head, over the model's layers that
+        have keys: a layer that attends everything spans the bucket a
+        row and sees the causal triangle, a window layer spans what
+        ``grouped_causal_attention``'s blocks give it
+        (``prefill_key_span``) and sees its window's last positions."""
+        from ..ops.pallas_decode_attention import prefill_key_span
+
+        m = self.model
+        n_win = layers_of_kind(m, "window")
+        n_full = layers_of_kind(m, "attention")
+        attended, live = n_full * t_pad * t_pad, n_full * n * (n + 1) // 2
+        if n_win:
+            w = min(self._window.window, n)
+            attended += n_win * t_pad * prefill_key_span(
+                t_pad, m.num_heads, self._window.window)[1]
+            live += n_win * (w * (w + 1) // 2 + (n - w) * w)
+        return attended, live
+
     def _count_window(self, positions) -> None:
         """A joint step's window-layer counters from the live slots'
         positions: what the window kernel walks and attends (a layer),
@@ -2520,6 +2541,10 @@ class DecodeEngine:
              - first // self._window_block + 1).sum()))
         stat_add("decode_window_positions_live", int(
             np.minimum(n, w.window).sum()))
+        # whether the window caps anything: the live rows, and those of
+        # them that are past it
+        stat_add("decode_window_rows", len(n))
+        stat_add("decode_window_rows_capped", int((n > w.window).sum()))
         # a token that opens a page the ring has already been round
         # once overwrites the page that slid out of the window
         stat_add("decode_window_pages_recycled", w.num_layers * int(

@@ -455,8 +455,10 @@ def test_whole_prompt_prefill_attends_its_own_bucket(
         eng.stop()
     attended, live, prefills = (
         stat_get(n) - b for n, b in zip(names, before))
+    # a head's, summed over the layers
     assert (prefills, attended, live) == (
-        1, t_pad * t_pad, length * (length + 1) // 2)
+        1, model.num_layers * t_pad * t_pad,
+        model.num_layers * length * (length + 1) // 2)
 
     want = _cache_width_prefill_logits(model, weights, prompt, t_pad,
                                        t_max, quantized)

@@ -529,3 +529,76 @@ def test_paged_attention_compiles_at_mimo_rows(one_chip, kv_heads, window):
     assert ("paged_attention_window" in text) == (window is not None)
     assert re.search(r"bf16\[%d,64,128\]|f32\[%d,64,128\]" % (SLOTS, SLOTS),
                      text), "the output has V's width"
+
+
+# -- Command A+'s rows: rings of 257 pages, 16 query heads a K/V head ------
+
+@pytest.mark.parametrize("window", [None, 4096],
+                         ids=["global_385_pages", "window_ring_257"])
+def test_paged_attention_compiles_at_command_a_plus_rows(one_chip, window):
+    """128 query heads over 8 K/V heads of 128 lanes (16 rows a K/V
+    head), K and V rows of 1,024 bf16 lanes, pages of 16: the global
+    call over a table of 385 pages a slot (6,144 positions and a spare),
+    the window call over a ring of 257 with no sink, 33 blocks of 128
+    positions at most."""
+    pps = 385 if window is None else 257
+    pool = SLOTS * pps + 1
+    assert pda.pages_per_block(16, pps, 1024, 2, 1024) == 8
+    shapes = [((SLOTS, 128, 128), jnp.float32),
+              ((LAYERS, pool, 16, 1024), jnp.bfloat16),
+              ((LAYERS, pool, 16, 1024), jnp.bfloat16),
+              ((SLOTS, pps), jnp.int32), ((SLOTS,), jnp.int32)]
+
+    def fn(q, k, v, pt, ln):
+        return pda.paged_decode_attention(
+            q, k, v, pt, ln, layer=1, use_pallas="always", window=window)
+
+    text = _compile(one_chip, fn, *shapes)
+    assert text.count("tpu_custom_call") == 1
+    assert ("paged_attention_window" in text) == (window is not None)
+
+
+def _parallel_engine():
+    """Command A+'s widths behind the engine at the cell's serving
+    sizes (48 slots, 6,144 positions, bf16 pages), one layer of each
+    kind, one held expert and a short vocabulary: only shapes matter to
+    a compile, and these are the ones the chip's compiler could refuse
+    (1,024-lane rows, a ring of 257, 128 heads over a 4,096-row prompt)."""
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+    from paddle_tpu.serving.parallel_moe_lm import ParallelMoELM
+
+    model = ParallelMoELM(
+        vocab_size=1024, d_model=4096, layer_kinds=("window", "attention"),
+        num_heads=128, num_kv_heads=8, head_dim=128, rope_theta=5e4,
+        window=4096, num_experts=128, top_k=8, held_experts=(0,),
+        expert_dim=4096, shared_experts=4, shared_dim=4096)
+    weights = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
+    return DecodeEngine(model, weights, DecodeConfig(
+        slots=48, max_seq_len=6144, num_pages=48 * 385 + 1,
+        use_pallas="always", cache_dtype="bfloat16"))
+
+
+@pytest.mark.parametrize("program", ["step", "prefill_4096"])
+def test_command_a_plus_width_programs_compile(one_chip, program):
+    """The joint step (both kernels in it, by name) and the 4,096-row
+    whole-prompt prefill, whose attention runs in 16 blocks of 256 query
+    rows: no tensor of the program holds a ``4096 x 4096`` score plane
+    for all 128 heads."""
+    eng = _parallel_engine()
+    assert eng._ring == 257 and eng._window_block == 128
+    if program == "step":
+        compiled = eng.lower_step(sharding=one_chip).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 2
+        assert "paged_attention_window" in text
+    else:
+        assert pda.prefill_key_span(4096, 128, 4096) == (256, 4096)
+        compiled = eng.lower_prefill(4096, sharding=one_chip).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" not in text
+        assert not re.search(r"f32\[8,16,4096,4096\]", text)
+        assert re.search(r"f32\[8,16,256,4096\]", text)
+    # what the program needs beside its operands stays far inside the chip
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
