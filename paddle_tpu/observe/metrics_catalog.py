@@ -266,6 +266,13 @@ RULES = (
     Rule("decode_window_bytes", "gauge", "serving",
          "Device bytes of the window layers' ring pools, all slots: no "
          "term in max_seq_len; 0 for a model without window layers"),
+    Rule("decode_attn_feed_bits", "gauge", "serving",
+         "Width of the K and V operands the paged attention kernel's "
+         "matmuls take, read from the pools' dtype when the engine "
+         "starts: 16 = bfloat16 blocks go to the MXU as they lie in the "
+         "pool and the float32 query and probabilities ride as three "
+         "groups of bfloat16 rows; 32 = float32 (and int8, dequantized) "
+         "blocks, float32 operands on both sides"),
     Rule("decode_", "gauge", "serving",
          "Decode-engine lifecycle, paging, speculation, goodput"),
     Rule("serving_", "gauge", "serving",

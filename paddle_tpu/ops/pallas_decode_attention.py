@@ -52,11 +52,14 @@ read.  **The hazard that follows**: a dead page of a live block was
 never copied, its buffer holds whatever was there, and although its
 scores are masked (``p == 0``), ``0 * NaN`` is NaN.  So V is zeroed by
 position before ``p @ v`` (tests poison dead pages and fresh buffers
-with NaN).  The int8 pools' f32 scale planes ``[L, P, page, H]`` are the
-exception to "the pools stay put": the chip's compiler cuts no page out
-of a plane H lanes wide in HBM, so a slot's scales are gathered by its
-page table outside the kernel (1/D of the pages' bytes) and arrive as
-one block a slot.
+with NaN); the bfloat16 feed below zeroes the dead pages where they lie
+in the V buffer instead, whole packed tiles, and only in a block that
+has any (a slot's last, a window's first), and in the last live page
+the positions past the slot's length.  The int8 pools' f32 scale
+planes ``[L, P, page, H]`` are the exception to "the pools stay put":
+the chip's compiler cuts no page out of a plane H lanes wide in HBM, so
+a slot's scales are gathered by its page table outside the kernel (1/D
+of the pages' bytes) and arrive as one block a slot.
 
 Heads are read out of lanes without a reshape: the query rows of
 ``hb`` heads are stacked block-diagonally (row ``h*R + r`` holds query
@@ -65,6 +68,39 @@ against the page's ``[page, hb*D]`` lanes yields every head's scores,
 one softmax update covers them all, and ``p @ v`` at full lane width
 leaves head ``h``'s context in its own lanes of row block ``h``
 (`_stack_heads` picks ``hb`` from ``(H, D, R)``).
+
+**The feed, chosen by the pools' dtype** (``feed_bits``; no flag, and
+nothing but ``k_pages.dtype`` is asked).  Float32 pools, and int8 pools
+once dequantized, meet float32 operands on both sides of the block's two
+matmuls, one stack after the other.  The chip's compiler runs such a
+matmul as ONE bfloat16 pass: it rounds the query and the probabilities
+to bfloat16 (measured: a one-term bfloat16 feed gives the float32
+feed's bits).  A bfloat16 pool's K and V go to the matmuls as they lie
+in the block buffers, with no float32 copy of a block (unpacking 256
+lanes x 128 positions twice a stack, a select over all of V and the
+repack were the body's largest vector work), and the float32 side is
+kept whole by ROWS: ``x = hi + mid + lo`` with ``hi = bf16(x)``, ``mid =
+bf16(x - hi)``, ``lo = bf16(x - hi - mid)`` carries all 24 bits of a
+float32, a product of two bfloat16 values is exact in float32, and K and
+V ARE bfloat16, so the three terms stacked as three groups of rows of
+one left operand against ONE load of a K or V tile, their float32
+products added smallest first, give the float32 x float32 product to
+float32 rounding: more than the float32 feed keeps on the chip.  The
+block-diagonal query is built and split once a slot into a bfloat16
+scratch (``sm_scale`` stays on the float32 query); a group starts on a
+packed tile of 16 rows.  Two things about the ORDER of that body, both
+measured on the v5e at Command A+'s rows (48 rings of 257 pages, ms a
+layer's call; the copies alone take 1.06): (1) a stack's update is a
+chain of latencies (matmul, a lane reduction, exp, a lane reduction,
+matmul), and run one stack after the other the chains do not overlap
+(2.69 as it was, 2.45 with the bfloat16 feed alone): the body runs a
+phase at a time over ALL stacks, every load before the first store
+(1.88), and stacks carry ``_MAX_SPLIT_ROWS`` = 16 query rows, twice as
+many shorter chains (1.80); (2) sixteen copies a block are started and
+waited for by the scalar core, in loops that stand between the blocks'
+arithmetic: a block whose every page is live starts its copies one
+after the other and waits ONCE a pool for the bytes of all of them
+(1.49).  Which pages move is unchanged.
 
 ``decode_attention_reference`` is the pure-jnp oracle — gather the
 page table (O(S * max_seq) materialization) and do masked attention.
@@ -273,12 +309,17 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
 # -- the kernel: R query rows per slot (decode is R=1) --------------------
 
 _MAX_STACK_ROWS = 32  # query rows one matmul carries (hb heads x R rows)
+# ... where each rides as three bfloat16 rows (``feed_bits`` 16): 48
+# stacked rows, and twice the stacks to run beside one another
+_MAX_SPLIT_ROWS = 16
 # both buffers of both pools' blocks, in fast memory beside the body's
-# f32 working tiles (a fraction of the 16 MB the chip scopes to a kernel)
+# working tiles (float32 copies of a stack's K and V lanes where the
+# feed is float32; a fraction of the 16 MB the chip scopes to a kernel)
 _BLOCK_VMEM_BYTES = 4 << 20
 
 
-def _stack_heads(num_heads, head_dim, n_rows, v_dim=None):
+def _stack_heads(num_heads, head_dim, n_rows, v_dim=None,
+                 max_rows=_MAX_STACK_ROWS):
     """How many heads' query rows one matmul stacks block-diagonally.
 
     A stack spans ``hb * head_dim`` lanes of the pool row (``hb *
@@ -286,14 +327,14 @@ def _stack_heads(num_heads, head_dim, n_rows, v_dim=None):
     cut the row at 128-lane boundaries (or be the whole row: toy
     widths, interpret mode).  More heads a stack means fewer, fuller
     matmuls and softmax updates but ``hb`` times the accumulator, so
-    the largest legal ``hb`` with ``hb * n_rows <= _MAX_STACK_ROWS`` is
+    the largest legal ``hb`` with ``hb * n_rows <= max_rows`` is
     taken, and the smallest legal one when none fits."""
     legal = [hb for hb in range(1, num_heads + 1)
              if num_heads % hb == 0
              and (((hb * head_dim) % _LANES == 0
                    and (hb * (v_dim or head_dim)) % _LANES == 0)
                   or hb == num_heads)]
-    fit = [hb for hb in legal if hb * n_rows <= _MAX_STACK_ROWS]
+    fit = [hb for hb in legal if hb * n_rows <= max_rows]
     return max(fit) if fit else min(legal)
 
 
@@ -311,6 +352,46 @@ def pages_per_block(page, pps, row_lanes, itemsize, v_lanes=None):
     by_vmem = _BLOCK_VMEM_BYTES // (
         2 * page * (row_lanes + (v_lanes or row_lanes)) * itemsize)
     return max(1, min(by_tile, by_vmem, pps))
+
+
+def feed_bits(pool_dtype):
+    """Width of the K and V operands the kernel's two matmuls take: 16
+    where the pools are bfloat16 (the blocks go to the MXU as they lie
+    in the pool, the float32 side rides as extra rows), 32 for every
+    other pool (float32, and int8 dequantized in fast memory).  The
+    pools' dtype alone decides."""
+    return 16 if jnp.dtype(pool_dtype) == jnp.bfloat16 else 32
+
+
+_SPLIT_TERMS = 3    # bfloat16 terms that carry a float32's 24 bits
+_BF16_ROWS = 16     # sublanes of one packed bfloat16 tile
+
+
+def _bf16_terms(x):
+    """Float32 ``x`` as ``_SPLIT_TERMS`` bfloat16 arrays, largest first,
+    whose sum is ``x``: each term rounds what the ones before it left."""
+    terms = []
+    for _ in range(_SPLIT_TERMS):
+        terms.append(x.astype(jnp.bfloat16))
+        x = x - terms[-1].astype(jnp.float32)
+    return terms
+
+
+def _split_rows(rows):
+    """Rows one term's group takes in a stacked bfloat16 left operand:
+    whole packed tiles, so every group starts on one."""
+    return -(-rows // _BF16_ROWS) * _BF16_ROWS
+
+
+def _join_terms(x, rows):
+    """The float32 product of a left operand stacked by ``_bf16_terms``:
+    its row groups added, smallest first."""
+    step = _split_rows(rows)
+    out = None
+    for t in reversed(range(_SPLIT_TERMS)):
+        group = x[t * step:t * step + rows]
+        out = group if out is None else group + out
+    return out
 
 
 def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
@@ -334,7 +415,13 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
     block-diagonal query (hb*R, hb*Dk), running max and denominator
     (hb*R, 128), accumulator (hb*R, hb*Dv); then the two block buffers
     (2, ppb, page, H*D) a pool, one DMA semaphore a buffer, and in SMEM
-    which buffer holds the block that is computed next."""
+    which buffer holds the block that is computed next.  Bfloat16 pools
+    (``feed_bits`` 16): the query scratch holds the block-diagonal
+    query's ``_bf16_terms`` as groups of rows, (n_stacks, 3 * hb*R up to
+    whole packed tiles, hb*Dk) in bfloat16, and two more scratch follow:
+    the float32 rows the query is stacked in before it is split, and
+    each stack's probabilities as groups of rows, (n_stacks, 3 * hb*R
+    ..., ppb*page) in bfloat16."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -345,13 +432,17 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
         ks_ref, vs_ref, *rest = rest
     if sinks:
         sink_ref, *rest = rest
-    o_ref, qbd_scr, m_scr, l_scr, acc_scr, k_buf, v_buf, sem, cur = rest
+    o_ref, qbd_scr, m_scr, l_scr, acc_scr, k_buf, v_buf, sem, cur, \
+        *split = rest
+    if split:                           # the bfloat16 feed's scratch
+        stage_scr, p_scr = split
     pools = ((k_hbm, k_buf), (v_hbm, v_buf))
 
     s_idx = pl.program_id(0)
     layer = layer_ref[0]
-    n_stacks, rows, width = qbd_scr.shape
-    v_width = acc_scr.shape[2]
+    n_stacks, rows, v_width = acc_scr.shape
+    width = qbd_scr.shape[2]
+    group = _split_rows(rows)           # rows from a term to the next
     hb = rows // n_rows                 # heads a stack
     block = ppb * page                  # positions a block
     # head (within its stack) that owns each lane of a stack
@@ -363,12 +454,21 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
         """The block a slot's walk starts at."""
         return 0 if window is None else slot_lo_ref[s] // block
 
+    def live_entries(s, b):
+        """(first, lo, hi): block ``b`` of slot ``s`` covers the table
+        entries from ``first``; those in ``[lo, hi)`` are live."""
+        first = b * ppb
+        n_live = pl.cdiv(slot_len_ref[s], page)
+        lo = first if window is None \
+            else jnp.maximum(first, slot_lo_ref[s] // page)
+        return first, lo, jnp.minimum(first + ppb, n_live)
+
     def block_dma(s, b, buf, start):
         """Start, or wait for, the copies of block ``b`` of slot ``s``
         into buffer ``buf``.  Only LIVE pages move, and both sides
         walk the same range, so every started copy is waited for
         exactly once."""
-        first = b * ppb
+        first, lo, hi = live_entries(s, b)
 
         def _page(entry, carry):
             # a wait only needs the copy's shape: no table read
@@ -383,10 +483,28 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
                 copy.start() if start else copy.wait()
             return carry
 
-        n_live = pl.cdiv(slot_len_ref[s], page)
-        lo = first if window is None \
-            else jnp.maximum(first, slot_lo_ref[s] // page)
-        lax.fori_loop(lo, jnp.minimum(first + ppb, n_live), _page, 0)
+        if not split:
+            lax.fori_loop(lo, hi, _page, 0)
+            return
+        # a block whose every page is live (all but a slot's last and a
+        # window's first) needs no loop: its copies start one after the
+        # other, and ONE wait a pool takes the bytes of all of them
+        whole = hi - lo == ppb
+
+        @pl.when(whole)
+        def _whole():
+            if start:
+                for entry in range(ppb):
+                    _page(first + entry, 0)
+            else:
+                for hbm, vmem in pools:
+                    pltpu.make_async_copy(
+                        hbm.at[layer, pl.ds(0, ppb)], vmem.at[buf],
+                        sem.at[buf]).wait()
+
+        @pl.when(jnp.logical_not(whole))
+        def _page_by_page():
+            lax.fori_loop(lo, hi, _page, 0)
 
     @pl.when(s_idx == 0)
     def _first():
@@ -405,6 +523,16 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
     q = q_ref[0].astype(jnp.float32) * sm_scale            # (R, H*D)
     for j in range(n_stacks):
         qj = q[:, j * width:(j + 1) * width]
+        if split:
+            # stacked in float32 rows, then split once a slot: each term
+            # is a group of rows of the one left operand (a group's
+            # padding rows are never written, and never read back)
+            for h in range(hb):
+                stage_scr[h * n_rows:(h + 1) * n_rows, :] = jnp.where(
+                    lane_head == h, qj, 0.0)
+            for t, term in enumerate(_bf16_terms(stage_scr[...])):
+                qbd_scr[j, t * group:t * group + rows, :] = term
+            continue
         for h in range(hb):
             qbd_scr[j, h * n_rows:(h + 1) * n_rows, :] = jnp.where(
                 lane_head == h, qj, 0.0)
@@ -420,6 +548,71 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
     row_len = jnp.zeros((rows, 1), jnp.int32) + lens[0]
     for r in range(1, n_rows):
         row_len = jnp.where(query_row == r, lens[r], row_len)
+
+    def zero_dead_v(b, buf, last):
+        """The bfloat16 feed's form of the guard against ``0 * NaN``:
+        the V buffer's dead pages zeroed where they lie, whole packed
+        tiles, and only in a block that has any (a slot's last, a
+        window's first); in the last live page the positions past the
+        slot's length, which hold what the pool held there."""
+        first, lo, hi = live_entries(s_idx, b)
+
+        def _zero(entry, carry):
+            v_buf[buf, entry - first] = jnp.zeros(
+                v_buf.shape[2:], v_buf.dtype)
+            return carry
+
+        @pl.when(hi - lo < ppb)
+        def _dead_pages():
+            lax.fori_loop(first, lo, _zero, 0)
+            lax.fori_loop(hi, first + ppb, _zero, 0)
+
+        tail = max_len % page
+
+        @pl.when(last & (tail > 0))
+        def _tail():
+            at = max_len // page - first
+            row = lax.broadcasted_iota(jnp.int32, (page, 1), 0)
+            v_buf[buf, at] = jnp.where(
+                row < tail, v_buf[buf, at].astype(jnp.float32),
+                0.0).astype(v_buf.dtype)
+
+    def update_from_bf16(buf, live):
+        """One block's online-softmax update of every stack, K and V
+        read as they lie in the buffers.  A phase at a time over ALL the
+        stacks, every load before the first store: a stack's chain
+        (matmul, two lane reductions, matmul) is latency, and the
+        stacks' chains overlap only while no store of one stands
+        before the loads of the next."""
+        nn = (((1,), (0,)), ((), ()))
+        nt = (((1,), (1,)), ((), ()))
+        stacks = range(n_stacks)
+        k = [k_buf[buf, :, :, j * width:(j + 1) * width]
+             .reshape(block, width) for j in stacks]
+        v = [v_buf[buf, :, :, j * v_width:(j + 1) * v_width]
+             .reshape(block, v_width) for j in stacks]
+        m_prev = [m_scr[j, :, :1] for j in stacks]
+        l_prev = [l_scr[j, :, :1] for j in stacks]
+        # the split query's three groups of rows meet ONE load of a tile
+        s = [jnp.where(live, _join_terms(lax.dot_general(
+            qbd_scr[j], k[j], nt, preferred_element_type=jnp.float32),
+            rows), _NEG_INF) for j in stacks]
+        m_new = [jnp.maximum(m_prev[j], jnp.max(s[j], axis=1, keepdims=True))
+                 for j in stacks]
+        alpha = [jnp.exp(m_prev[j] - m_new[j]) for j in stacks]
+        p = [jnp.exp(s[j] - m_new[j]) for j in stacks]
+        l_new = [alpha[j] * l_prev[j] + jnp.sum(p[j], axis=1, keepdims=True)
+                 for j in stacks]
+        for j in stacks:
+            for t, term in enumerate(_bf16_terms(p[j])):
+                p_scr[j, t * group:t * group + rows, :] = term
+        pv = [_join_terms(lax.dot_general(
+            p_scr[j], v[j], nn, preferred_element_type=jnp.float32), rows)
+            for j in stacks]
+        for j in stacks:
+            acc_scr[j] = acc_scr[j] * alpha[j] + pv[j]
+            m_scr[j] = jnp.broadcast_to(m_new[j], m_scr.shape[1:])
+            l_scr[j] = jnp.broadcast_to(l_new[j], l_scr.shape[1:])
 
     def _block(b, carry):
         buf = cur[0]
@@ -439,6 +632,12 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
 
         pos = b * block + lax.broadcasted_iota(jnp.int32, (rows, block), 1)
         live = pos < row_len                               # (rows, block)
+        if split:
+            if window is not None:
+                live = live & (pos >= row_len - window)
+            zero_dead_v(b, buf, last)
+            update_from_bf16(buf, live)
+            return carry
         # a dead page of a live block was never copied: whatever the
         # buffer held there (NaN included) must not reach ``p @ v``
         # through ``0 * v``, so V is zeroed by position (K's scores
@@ -526,7 +725,11 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
             f"pool rows are {hd} (K) and {v_hd} (V) lanes wide but q has "
             f"{h} heads of {d}")
     dv = v_hd // h
-    hb = _stack_heads(h, d, n_rows, dv)
+    # bfloat16 blocks go to the matmuls as they lie in the pool, and the
+    # float32 side rides as three groups of bfloat16 rows
+    split = feed_bits(k_pages.dtype) == feed_bits(v_pages.dtype) == 16
+    hb = _stack_heads(h, d, n_rows, dv,
+                      _MAX_SPLIT_ROWS if split else _MAX_STACK_ROWS)
     n_stacks, rows, width, v_width = h // hb, hb * n_rows, hb * d, hb * dv
     ppb = pages_per_block(page, pps, hd, k_pages.dtype.itemsize, v_hd)
     quantized = k_scales is not None
@@ -562,6 +765,9 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
         operands.append(jnp.broadcast_to(
             sinks.astype(jnp.float32).T.reshape(n_stacks, rows, 1),
             (n_stacks, rows, _LANES)))
+    stacked = _SPLIT_TERMS * _split_rows(rows)  # rows of a split operand
+    query_scratch = pltpu.VMEM((n_stacks, stacked, width), jnp.bfloat16) \
+        if split else pltpu.VMEM((n_stacks, rows, width), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # (layer, flat page table, flat row lengths, widest row a slot
         # [, the window's first position a slot])
@@ -570,7 +776,7 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
         in_specs=in_specs,
         out_specs=slot_block(n_rows, v_hd),
         scratch_shapes=[
-            pltpu.VMEM((n_stacks, rows, width), jnp.float32),   # query
+            query_scratch,
             pltpu.VMEM((n_stacks, rows, _LANES), jnp.float32),  # max
             pltpu.VMEM((n_stacks, rows, _LANES), jnp.float32),  # denom
             pltpu.VMEM((n_stacks, rows, v_width), jnp.float32),  # acc
@@ -578,7 +784,11 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
             pltpu.VMEM((2, ppb, page, v_hd), v_pages.dtype),    # V blocks
             pltpu.SemaphoreType.DMA((2,)),     # one a buffer, K and V
             pltpu.SMEM((1,), jnp.int32),       # the buffer computed next
-        ],
+        ] + ([
+            pltpu.VMEM((rows, width), jnp.float32),  # query, unsplit
+            pltpu.VMEM((n_stacks, stacked, ppb * page),
+                       jnp.bfloat16),                # probabilities
+        ] if split else []),
     )
     kern = functools.partial(_chunk_kernel, sm_scale=sm_scale, page=page,
                              pps=pps, ppb=ppb, n_slots=n_slots,

@@ -1601,6 +1601,10 @@ class DecodeEngine:
         stat_set("decode_kv_page_bytes", self._cache.config.page_bytes())
         stat_set("decode_state_bytes", self._cache.state_bytes())
         stat_set("decode_window_bytes", self._cache.window_bytes())
+        from ..ops.pallas_decode_attention import feed_bits
+
+        stat_set("decode_attn_feed_bits",
+                 feed_bits(self._cache.config.store_dtype))
         return self
 
     def stop(self, drain: bool = True):

@@ -101,6 +101,27 @@ def test_engine_build_says_whether_the_pools_are_lane_dense(
                             heads * head_dim)
 
 
+@pytest.mark.parametrize("cfg, bits", [
+    (dict(), 32), (dict(cache_dtype="bfloat16"), 16),
+    (dict(kv_quant=True), 32)], ids=["float32", "bfloat16", "int8"])
+def test_engine_start_says_how_the_attention_kernel_is_fed(
+        model_and_weights, cfg, bits):
+    """The gauge that says the bfloat16 feed engaged, read from the
+    pools' dtype alone; and the served logits through the kernel (in
+    interpret mode) are those of the gather reference over the same
+    cache."""
+    prompt = list(range(3, 24))
+    traces = []
+    for use_pallas in ("always", "never"):
+        with make_engine(model_and_weights, use_pallas=use_pallas,
+                         interpret=True, **cfg) as eng:
+            assert stat_get("decode_attn_feed_bits") == bits
+            r = eng.submit(prompt, max_new_tokens=6, record_logits=True)
+            r.result(timeout=300)
+            traces.append(np.stack(r.logits_trace))
+    np.testing.assert_allclose(traces[0], traces[1], atol=5e-5)
+
+
 def test_prefill_bucket_grid():
     assert prefill_bucket_grid(64, 8) == (8, 16, 32, 64)
     assert prefill_bucket_grid(48, 16) == (16, 32, 48)
@@ -132,17 +153,20 @@ def test_paged_attention_pallas_interpret_matches_reference():
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("pool", ["f32", "int8", "bf16"])
 @pytest.mark.parametrize("rows", [1, 4])
 @pytest.mark.parametrize("h,d", [(16, 64), (12, 64), (4, 16), (8, 128)])
-def test_paged_kernel_reads_heads_out_of_lanes(h, d, rows, quantized):
+def test_paged_kernel_reads_heads_out_of_lanes(h, d, rows, pool):
     """The kernel in interpret mode against the plain masked-softmax
     reference on the heads-major K/V, at GPT-2-medium's and -small's
     head shapes, a toy row narrower than a lane tile and head_dim 128,
     one query row (decode) and four (a chunk), over ragged lengths:
     nothing, one token, a page boundary - 1 / on it / + 1, the full
     table.  The pools are stacked and lane-folded as the cache stores
-    them, and a middle layer is read."""
+    them, and a middle layer is read.  A bfloat16 pool's blocks go to
+    the matmuls as bfloat16 and the float32 query and probabilities as
+    three groups of bfloat16 rows: the same tolerance holds, against the
+    reference in float32 on the pool's own values."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas_decode_attention import (
@@ -161,8 +185,11 @@ def test_paged_kernel_reads_heads_out_of_lanes(h, d, rows, quantized):
     kv = [jnp.asarray(rs.randn(layers, pool, page, h, d).astype("f4"))
           for _ in range(2)]
     scales = [None, None]
+    quantized = pool == "int8"
     if quantized:
         (kv[0], scales[0]), (kv[1], scales[1]) = map(quantize_kv, kv)
+    elif pool == "bf16":
+        kv = [x.astype(jnp.bfloat16) for x in kv]
     pal = paged_chunk_attention(
         q, *(x.reshape(layers, pool, page, h * d) for x in kv),
         jnp.asarray(table), jnp.asarray(row_lengths), layer=layer,
@@ -172,8 +199,8 @@ def test_paged_kernel_reads_heads_out_of_lanes(h, d, rows, quantized):
         kv = [dequantize_kv(x, sc, jnp.float32)
               for x, sc in zip(kv, scales)]
     # the reference, one query row at a time over its slot's pages
-    full = [np.asarray(x)[layer][table].reshape(s, pps * page, h, d)
-            for x in kv]
+    full = [np.asarray(x, np.float32)[layer][table]
+            .reshape(s, pps * page, h, d) for x in kv]
     for r in range(rows):
         ref = decode_attention_reference(
             q[:, r], jnp.asarray(full[0]), jnp.asarray(full[1]),
@@ -263,18 +290,20 @@ def test_paged_kernel_at_its_blocks_edges(rows, pool):
     np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
 
 
-def test_paged_kernel_ragged_last_block_and_nan_scratch():
+@pytest.mark.parametrize("pool", ["float32", "bfloat16"])
+def test_paged_kernel_ragged_last_block_and_nan_scratch(pool):
     """A table of 12 entries is a block of 8 and a block of 4: the
     entries the last block lacks are dead like any other.  Run under
     the TPU interpreter, whose fresh buffers read NaN, so a page that
-    was never copied shows if it reaches the output."""
+    was never copied shows if it reaches the output (a bfloat16 pool's
+    feed zeroes such pages where they lie in the V buffer)."""
     from jax.experimental.pallas import tpu as pltpu
 
     from paddle_tpu.ops.pallas_decode_attention import (
         pages_per_block, paged_chunk_attention)
 
-    args, kwargs, want, live = block_edge_case(5, "float32", pps=12)
-    assert pages_per_block(16, 12, 128, 4) == 8
+    args, kwargs, want, live = block_edge_case(5, pool, pps=12)
+    assert pages_per_block(16, 12, 128, args[1].dtype.itemsize) == 8
     got = np.asarray(paged_chunk_attention(
         *args, use_pallas="always",
         interpret=pltpu.InterpretParams(uninitialized_memory="nan"),

@@ -138,6 +138,68 @@ def test_decode_through_the_ring_equals_a_recompute():
             np.testing.assert_allclose(r.logits_trace[j], again, atol=2e-5)
 
 
+# -- the kernel on bfloat16 pools, at the served models' rows ---------------
+
+def _bf16_pools(hkv, d, page, table_pages, lengths, window, seed):
+    """Bfloat16 pools filled as the cache fills them (logical page j of a
+    slot at table entry ``j % table_pages``: a ring that wraps once the
+    slot outgrows it), every position never written NaN (dead pages,
+    and the last live page past the slot's length); beside them the K/V
+    in logical order, float32 arrays of the values the pools hold."""
+    rng = np.random.RandomState(seed)
+    s, t = len(lengths), max(lengths)
+    full = [rng.randn(s, t, hkv, d).astype(jnp.bfloat16)
+            .astype(np.float32) for _ in range(2)]
+    table = (1 + np.arange(s * table_pages, dtype=np.int32)) \
+        .reshape(s, table_pages)
+    pools = [np.full((2, 1 + s * table_pages, page, hkv * d), np.nan,
+                     np.float32) for _ in range(2)]
+    for i, n in enumerate(lengths):
+        for pos in range(n):
+            pid = table[i, (pos // page) % table_pages]
+            for pool, x in zip(pools, full):
+                pool[1, pid, pos % page] = x[i, pos].reshape(-1)
+    return [jnp.asarray(x, jnp.bfloat16) for x in pools], table, full
+
+
+@pytest.mark.parametrize("hq, hkv, rows, window", [
+    (32, 2, 1, None), (32, 2, 1, 100), (16, 2, 1, None), (8, 2, 3, None)],
+    ids=["16_rows_a_kv_head", "16_rows_over_a_wrapped_ring",
+         "8_rows_a_kv_head", "3_ragged_query_rows"])
+def test_kernel_on_bfloat16_pools_against_the_float32_reference(
+        hq, hkv, rows, window):
+    """Command A+'s 16 query rows a K/V head of 128 lanes (a global
+    table, and a window read off a ring that has wrapped three times),
+    Solar's 8 rows, and three query rows of ragged causal lengths a
+    slot: the blocks go to the matmuls as bfloat16, the float32 query
+    and probabilities as three groups of bfloat16 rows, and the result
+    is the float32 reference's at the tolerance float32 pools are held
+    to.  Never-written positions are NaN: dead pages of a slot's last
+    block and of a window's first, and the last live page's positions
+    past the slot's length, must not reach the output."""
+    d, page = 128, 16
+    lengths = [9, 401, 128, 250]
+    table_pages = -(-window // page) + 1 if window else 26
+    (kp, vp), table, (kfull, vfull) = _bf16_pools(
+        hkv, d, page, table_pages, lengths, window, seed=hq + rows)
+    s, g = len(lengths), hq // hkv
+    q = jnp.asarray(np.random.RandomState(3).randn(s, rows, hq, d),
+                    jnp.float32)
+    # row r of a slot is causal: it attends rows - 1 - r positions fewer
+    row_lengths = np.maximum(
+        np.asarray(lengths)[:, None] - np.arange(rows - 1, -1, -1), 1)
+    got = pda.paged_chunk_attention(
+        q, kp, vp, jnp.asarray(table), jnp.asarray(row_lengths, jnp.int32),
+        layer=1, use_pallas="always", interpret=True, window=window)
+    assert got.dtype == jnp.float32 and bool(jnp.isfinite(got).all())
+    k, v = (jnp.repeat(jnp.asarray(x), g, axis=2) for x in (kfull, vfull))
+    for r in range(rows):
+        want = pda.decode_attention_reference(
+            q[:, r], k, v, jnp.asarray(row_lengths[:, r], jnp.int32),
+            window=window)
+        np.testing.assert_allclose(got[:, r], want, rtol=2e-5, atol=2e-5)
+
+
 # -- what must fail: each departure served, the reference as it is ----------
 
 class _Sequential(ParallelMoELM):

@@ -114,11 +114,14 @@ def test_paged_chunk_attention_compiles(one_chip, h, d, rows, quantized):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("rows", [0, 16], ids=["decode", "chunk16"])
+@pytest.mark.parametrize("rows", [0, 5, 16],
+                         ids=["decode", "verify5", "chunk16"])
 def test_paged_attention_compiles_at_2048_bf16_lanes(one_chip, rows):
     """16 heads of 128 in bfloat16 (an OLMoE-shaped row, 2,048 lanes):
     eight pages a block are 2 MB of block buffers, as at GPT-2's f32
-    rows."""
+    rows.  The bfloat16 feed: a stack's query rows ride as three groups
+    of bfloat16 rows, each group on whole packed tiles (5 rows of a
+    speculative window take 16)."""
     assert pda.pages_per_block(16, PPS, 16 * 128, 2) == 8
     op = pda.paged_chunk_attention if rows else pda.paged_decode_attention
     text = _compile(one_chip, functools.partial(_paged, op),
@@ -236,12 +239,15 @@ def test_gpt2_width_decode_step_compiles(one_chip, kv_quant):
         debug_info=True)
 
 
-def _pallas_calls(jaxpr):
+def _eqns(jaxpr):
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn
+        yield eqn
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _pallas_calls(sub)
+            yield from _eqns(sub)
+
+
+def _pallas_calls(jaxpr):
+    return (e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call")
 
 
 def test_gpt2_width_step_walks_blocks_of_eight_pages():
@@ -260,6 +266,67 @@ def test_gpt2_width_step_walks_blocks_of_eight_pages():
         buffers = [a.shape for a in grid.scratch_avals
                    if len(a.shape) == 4]
         assert buffers == [(2, 8, 16, 1024)] * 2, buffers
+
+
+# -- what the kernel's two matmuls are fed, by the pools' dtype -------------
+
+def _kernel_body(dtype, quantized=False, h=16, d=64, hq=None, window=None):
+    """The jaxpr of the paged kernel's body (SLOTS slots of PPS pages)."""
+    shapes = [jax.ShapeDtypeStruct(s, t) for s, t in
+              _paged_shapes(h, d, 16, 0, quantized, dtype)]
+    if hq:
+        shapes[0] = jax.ShapeDtypeStruct((SLOTS, hq, d), jnp.float32)
+
+    def fn(q, k, v, pt, ln, ks=None, vs=None):
+        return pda.paged_decode_attention(
+            q, k, v, pt, ln, layer=1, use_pallas="always", k_scales=ks,
+            v_scales=vs, window=window)
+
+    call, = _pallas_calls(jax.make_jaxpr(fn)(*shapes).jaxpr)
+    return call.params["jaxpr"]
+
+
+@pytest.mark.parametrize("hq, h, d, window", [
+    (128, 8, 128, None), (128, 8, 128, 4096), (64, 8, 128, None),
+    (None, 16, 64, None)],
+    ids=["command_a_plus", "command_a_plus_window", "solar", "mha_16x64"])
+def test_bf16_pools_reach_the_matmuls_as_they_lie(hq, h, d, window):
+    """A bfloat16 pool's two matmuls take bfloat16 operands on both
+    sides (K and V as the block buffers hold them, the float32 query
+    and probabilities as bfloat16 terms), accumulate in float32, and
+    nothing makes a float32 copy of a block.  Traced: needs no chip."""
+    body = _kernel_body(jnp.bfloat16, h=h, d=d, hq=hq, window=window)
+    dots = [e for e in _eqns(body) if e.primitive.name == "dot_general"]
+    assert len(dots) >= 2
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2
+        assert e.outvars[0].aval.dtype == jnp.float32
+    block = 128                          # eight pages of 16 positions
+    for e in _eqns(body):
+        out = e.outvars[0].aval if e.outvars else None
+        if e.primitive.name == "convert_element_type" \
+                and out.dtype == jnp.float32:
+            assert block not in out.shape[:1] and out.shape[:2] != (8, 16), \
+                f"a float32 copy of a block: {out}"
+
+
+@pytest.mark.parametrize("quantized, want", [
+    (False, (435, 2, 4, 2, 55)), (True, (637, 2, 4, 2, 87))],
+    ids=["f32", "int8"])
+def test_float32_and_int8_pools_keep_the_body_they_had(quantized, want):
+    """GPT-2's path: the body of a float32 (and an int8) pool's kernel
+    is the one it was before the bfloat16 feed, counted from its jaxpr
+    at commit e4b55d6 (equations; matmuls; copies started and waited
+    for; selects).  Its matmuls take float32 on both sides.  A jax
+    upgrade moves the counts: re-read them from that commit's kernel."""
+    body = _kernel_body(jnp.float32, quantized)
+    count = {}
+    for e in _eqns(body):
+        count[e.primitive.name] = count.get(e.primitive.name, 0) + 1
+        if e.primitive.name == "dot_general":
+            assert [v.aval.dtype for v in e.invars] == [jnp.float32] * 2
+    assert (sum(count.values()), count["dot_general"], count["dma_start"],
+            count["dma_wait"], count["select_n"]) == want
 
 
 # -- the K/V pools keep ONE layout through every serving program ----------

@@ -145,14 +145,16 @@ def test_decode_through_the_ring_equals_a_recompute():
             np.testing.assert_allclose(r.logits_trace[j], again, atol=2e-5)
 
 
-def _paged_case(h, hkv, dk, dv, page, ring, lengths, seed):
+def _paged_case(h, hkv, dk, dv, page, ring, lengths, seed,
+                dtype="float32"):
     """Pools filled as the cache fills them (logical page j at ring
     entry j % ring), never-written pages poisoned with NaN; returns the
-    kernel's arguments and the K/V in logical order."""
+    kernel's arguments (the pools in ``dtype``) and the K/V in
+    logical order, float32 arrays of the values the pools hold."""
     rng = np.random.RandomState(seed)
     s, t = len(lengths), max(lengths)
-    kfull = rng.randn(s, t, hkv, dk).astype(np.float32)
-    vfull = rng.randn(s, t, hkv, dv).astype(np.float32)
+    kfull = rng.randn(s, t, hkv, dk).astype(dtype).astype(np.float32)
+    vfull = rng.randn(s, t, hkv, dv).astype(dtype).astype(np.float32)
     table = (1 + np.arange(s * ring, dtype=np.int32)).reshape(s, ring)
     kp = np.full((2, 1 + s * ring, page, hkv * dk), np.nan, np.float32)
     vp = np.full((2, 1 + s * ring, page, hkv * dv), np.nan, np.float32)
@@ -164,7 +166,7 @@ def _paged_case(h, hkv, dk, dv, page, ring, lengths, seed):
                     pool[1, pid] = 7.0      # a written page holds numbers
                 pool[1, pid, p % page] = full[i, p].reshape(-1)
     q = rng.randn(s, h, dk).astype(np.float32)
-    return q, kp, vp, table, kfull, vfull
+    return q, kp.astype(dtype), vp.astype(dtype), table, kfull, vfull
 
 
 def _plain(q, kfull, vfull, lengths, window, sinks):
@@ -183,20 +185,27 @@ def _plain(q, kfull, vfull, lengths, window, sinks):
     return out
 
 
-@pytest.mark.parametrize("h, hkv, window, sink", [
-    (64, 4, None, False), (64, 8, 128, True), (16, 4, 20, False),
-    (8, 8, 20, True)], ids=["groups16", "groups8_window_sink",
-                            "window", "ungrouped_window_sink"])
+@pytest.mark.parametrize("pool", ["float32", "bfloat16"])
+@pytest.mark.parametrize("h, hkv, window, sink, dk, dv", [
+    (64, 4, None, False, 24, 16), (64, 8, 128, True, 24, 16),
+    (16, 4, 20, False, 24, 16), (8, 8, 20, True, 24, 16),
+    (32, 2, None, False, 192, 128), (16, 2, 128, True, 192, 128)],
+    ids=["groups16", "groups8_window_sink", "window",
+         "ungrouped_window_sink", "mimo_lanes_global",
+         "mimo_lanes_window_sink"])
 def test_kernel_with_wider_keys_a_window_and_sinks_in_interpret_mode(
-        h, hkv, window, sink):
-    """K heads of 24 lanes over V heads of 16, groups of 16 and 8 query
-    heads a K/V head, the window read off a ring that has wrapped; the
-    kernel and the gather reference against plain numpy."""
+        h, hkv, window, sink, dk, dv, pool):
+    """K heads of 24 lanes over V heads of 16 (and MiMo's 192 over 128:
+    stacks cut at lane tiles), groups of 16 and 8 query heads a K/V
+    head, the window read off a ring that has wrapped, never-written
+    pages NaN; the kernel and the gather reference against plain numpy.
+    Bfloat16 pools take the other feed (blocks as they lie, the
+    float32 side as groups of rows) and hold the same tolerance."""
     page = 16
     ring = -(-window // page) + 1 if window else 12
     lengths = [5, 190, 64, 131]
     q, kp, vp, table, kfull, vfull = _paged_case(
-        h, hkv, 24, 16, page, ring, lengths, seed=h + hkv)
+        h, hkv, dk, dv, page, ring, lengths, seed=h + hkv, dtype=pool)
     sinks = np.random.RandomState(1).randn(h).astype(np.float32) \
         if sink else None
     want = _plain(q, kfull, vfull, lengths, window, sinks)
@@ -206,7 +215,7 @@ def test_kernel_with_wider_keys_a_window_and_sinks_in_interpret_mode(
             jnp.asarray(table), jnp.asarray(lengths, jnp.int32))
     got = pda.paged_decode_attention(*args, use_pallas="always",
                                      interpret=True, **kw)
-    assert got.shape == (len(lengths), h, 16)
+    assert got.shape == (len(lengths), h, dv)
     np.testing.assert_allclose(got, want, atol=3e-5)
     # the gather reads whole pages: no poison for it
     clean = (args[0], jnp.nan_to_num(args[1], nan=3.0),
