@@ -205,6 +205,15 @@ RULES = (
          "`window`).  Their ratio is the share of the attention that is "
          "work; the rest is the causal triangle's other half and the "
          "bucket's padding"),
+    Rule("decode_prefill_scan_", "gauge", "serving",
+         "How a model's recurrent layers take a prompt in a whole-prompt "
+         "prefill, counted in the program and read back with the "
+         "prefill's token, summed over the recurrent layers: `_steps` "
+         "the iterations of the scan over the prompt, `_tokens` the real "
+         "tokens they took (padding rows are never scanned).  Their "
+         "ratio is the tokens an iteration takes: 1 where the model "
+         "hands the one-token update alone, near the chunk's length "
+         "where it hands the rule's chunk form"),
     Rule("decode_steps_", "gauge", "serving",
          "Joint decode steps whose sampler does more than an argmax, "
          "added once a step from the knobs the engine hands over: "

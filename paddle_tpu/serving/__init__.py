@@ -50,6 +50,7 @@ from .disagg import (  # noqa: F401
     DisaggRequest,
     DisaggServer,
 )
+from .gated_delta_lm import GatedDeltaLM  # noqa: F401
 from .hybrid_moe_lm import HybridMoELM  # noqa: F401
 from .kv_cache import (  # noqa: F401
     CacheConfig,
@@ -72,7 +73,8 @@ __all__ = [
     "Autoscaler", "Batcher", "BucketSpec", "CacheConfig",
     "CacheExhaustedError", "DeadlineExceededError", "DecodeConfig",
     "DecodeEngine", "DecodeRequest", "DecodeServer", "DisaggConfig",
-    "DisaggRequest", "DisaggServer", "HybridMoELM", "InferenceRequest",
+    "DisaggRequest", "DisaggServer", "GatedDeltaLM", "HybridMoELM",
+    "InferenceRequest",
     "KVPageExport", "PageAllocator", "PagedKVCache", "ParallelMoELM",
     "PrefixIndex",
     "QueueFullError", "RequestAbandonedError", "RequestBase",

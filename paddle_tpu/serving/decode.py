@@ -297,6 +297,9 @@ def _join_state(pools):
 
 
 _KINDS = ("attention", "window", "recurrent")  # what a layer may be
+# what a whole-prompt prefill counts of its recurrent layers' scans, a
+# layer: iterations of the loop, and the real tokens they took
+_SCAN_TALLIES = ("decode_prefill_scan_steps", "decode_prefill_scan_tokens")
 
 
 class _Mixed:
@@ -338,9 +341,11 @@ class _Mixers:
     to ITS kind's pools and layer there (``attend`` for an
     ``"attention"`` layer, ``attend_window`` for a ``"window"`` one, which
     also gets the call's ``sinks``); ``recur(layer, token_fn, rows,
-    cache)`` runs a
+    cache, chunk_fn=None, chunk=0)`` runs a
     recurrent layer's one-token update ``token_fn(rows, state) -> (out,
-    state)`` where the program keeps that state; ``live`` (bool, the
+    state)`` where the program keeps that state (a whole-prompt prefill
+    runs ``chunk_fn(rows, n_real, state)`` over ``chunk`` consecutive
+    tokens at once where the model hands one); ``live`` (bool, the
     rows' shape) says which rows are a request's; ``tally(name, n)``
     adds an int32 scalar to the counter ``name``, one of the model's
     declared ``tallies`` (a joint step's ride its one read-back, in the
@@ -348,11 +353,14 @@ class _Mixers:
     layer for a request that records its logits."""
 
     def __init__(self, mixed: _Mixed, recur, live, attend=None,
-                 attend_window=None):
+                 attend_window=None, own_tallies=()):
         self._mixed, self._recur, self.attend = mixed, recur, attend
         self.attend_window = attend_window
         self.live = live
-        self.counts, self.records = dict.fromkeys(mixed.tallies, 0), {}
+        # ``own_tallies``: counters the engine's side of a program adds
+        # to beside the model's declared ones
+        self.counts = dict.fromkeys(mixed.tallies + tuple(own_tallies), 0)
+        self.records = {}
 
     def __call__(self, layer, q, k, v, cache, sinks=None):
         pools, window, rec = cache
@@ -364,10 +372,10 @@ class _Mixers:
                                      q, k, v, pools)
         return ctx, (pools, window, rec)
 
-    def recur(self, layer, token_fn, rows, cache):
+    def recur(self, layer, token_fn, rows, cache, chunk_fn=None, chunk=0):
         pools, window, rec = cache
         i = self._mixed.layer["recurrent"][layer]
-        out, new = self._recur(token_fn, rows, rec[i])
+        out, new = self._recur(token_fn, rows, rec[i], chunk_fn, chunk)
         return out, (pools, window, rec[:i] + (new,) + rec[i + 1:])
 
     def tally(self, name, value):
@@ -696,13 +704,21 @@ class DecodeEngine:
     ``tallies`` are the names of the counters ``forward`` adds to.
     ``attend`` is then a ``_Mixers``: the call as above for an attention
     or window layer (mapped to ITS kind's pools);
-    ``attend.recur(layer, token_fn, rows, cache)
+    ``attend.recur(layer, token_fn, rows, cache, chunk_fn=None, chunk=0)
     -> (out, cache)`` for a recurrent one, where ``token_fn(rows, state)
     -> (out, state)`` is the model's one-token update over rows
     ``[R, ...]`` and the engine decides what state that is and where it
     goes (the joint step: every live slot's row, in place; the
-    whole-prompt prefill: from zero through the prompt's real tokens one
-    by one, the last one's state into the slot's row); ``attend.live``
+    whole-prompt prefill: from zero through the prompt's real tokens,
+    the last one's state into the slot's row).  The prefill takes the
+    tokens one by one through ``token_fn`` unless the model also hands
+    ``chunk_fn(rows, n_real, state) -> (out, state)``: the same rule over
+    ``chunk`` consecutive rows of ONE request from the state before them
+    (leading dimension 1), of which only the first ``n_real`` are the
+    request's and may touch the state; it then runs once a chunk
+    (counters ``decode_prefill_scan_steps`` / ``decode_prefill_scan_
+    tokens``: loop iterations and real tokens, a recurrent layer).
+    ``attend.live``
     (which rows are a request's), ``attend.tally(name, n)`` (counters
     the model declares by name in ``tallies``; they ride the step's one
     read-back into ``stat_add(name)``) and ``attend.record(name, rows)``
@@ -1107,7 +1123,7 @@ class DecodeEngine:
                 # the delivery drops what it yields
                 live = live & ~(carry & (tokens == eos))
 
-            def recur(token_fn, rows, rec):
+            def recur(token_fn, rows, rec, chunk_fn=None, chunk=0):
                 """Every slot's state one token on; a dead slot's row (a
                 prefill ahead of this step may just have filled it)
                 stays as it is."""
@@ -1176,6 +1192,9 @@ class DecodeEngine:
 
         mixed = self._mixed if model is self.model else None
         row = _prefill_row(t_pad, cc.pages_per_slot, slot=mixed is not None)
+        scan_tallies = _SCAN_TALLIES \
+            if mixed is not None and mixed.layer["recurrent"] else ()
+        fresh_only = bool(per_slot_kinds(model))
 
         @jax.named_scope("prefill_full")
         def prefill(state, weights, packed):
@@ -1184,30 +1203,43 @@ class DecodeEngine:
             positions = jnp.arange(t_pad, dtype=jnp.int32)
             row_lengths = positions + 1
 
-            def recur(token_fn, rows, rec):
-                """The prompt's tokens one after another from the zero
-                state (a token scan: a chunked form is what a later
-                change owes this), its ``length`` real ones only, so
-                padding rows never touch the state; what the last one
-                leaves goes into the slot's rows of the slabs."""
+            def recur(token_fn, rows, rec, chunk_fn=None, chunk=0):
+                """The prompt's ``length`` real tokens from the zero
+                state, so padding rows never touch the state: ``chunk``
+                at a time through ``chunk_fn`` where the model hands one
+                (the last chunk is told how many of its rows are real),
+                else one after another through ``token_fn``; what the
+                last one leaves goes into the slot's rows of the slabs."""
                 state0 = {n: jnp.zeros((1,) + v.shape[1:], v.dtype)
                           for n, v in rec.items()}
-                at = lambda t: {n: jax.lax.dynamic_slice_in_dim(  # noqa: E731
-                    v, t, 1, axis=0) for n, v in rows.items()}
-                out = jax.eval_shape(token_fn, at(0), state0)[0]
+                if chunk_fn is None:
+                    chunk, fn = 1, lambda r, n_real, st: token_fn(r, st)
+                else:
+                    fn = chunk_fn
+                t_run = -(-t_pad // chunk) * chunk
+                rows = {n: jnp.pad(v, ((0, t_run - t_pad),)
+                                   + ((0, 0),) * (v.ndim - 1))
+                        for n, v in rows.items()}
+                at = lambda i: {n: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+                    v, i * chunk, chunk, axis=0) for n, v in rows.items()}
+                out = jax.eval_shape(fn, at(0), length, state0)[0]
 
-                def token(t, carry):
+                def scan_step(i, carry):
                     st, outs = carry
-                    o, new = token_fn(at(t), st)
+                    o, new = fn(at(i), jnp.minimum(length - i * chunk,
+                                                   chunk), st)
                     return ({n: new[n].astype(v.dtype)
                              for n, v in st.items()},
                             jax.lax.dynamic_update_slice_in_dim(
-                                outs, o, t, axis=0))
+                                outs, o, i * chunk, axis=0))
 
+                steps = -(-length // chunk)
                 st, outs = jax.lax.fori_loop(
-                    0, length, token,
-                    (state0, jnp.zeros((t_pad,) + out.shape[1:], out.dtype)))
-                return outs, {
+                    0, steps, scan_step,
+                    (state0, jnp.zeros((t_run,) + out.shape[1:], out.dtype)))
+                mix.tally(_SCAN_TALLIES[0], steps)
+                mix.tally(_SCAN_TALLIES[1], length)
+                return outs[:t_pad], {
                     n: jax.lax.dynamic_update_slice_in_dim(
                         v, st[n], a["slot"], axis=0) for n, v in rec.items()}
 
@@ -1233,9 +1265,11 @@ class DecodeEngine:
                     vl = kv_cache.dequantize_kv(vq, vsc, cdt)
                 else:
                     kl, vl = k.astype(cdt), v.astype(cdt)
-                if q.shape[-2] != k.shape[-2]:
-                    # grouped-query heads: the prompt's own width, the
-                    # pages' dtype; no bitwise contract to keep (bf16)
+                if fresh_only or q.shape[-2] != k.shape[-2]:
+                    # grouped-query heads (a group may be ONE head), or
+                    # a model whose requests are all admitted fresh: the
+                    # prompt's own width, the pages' dtype, in blocks of
+                    # query rows; no cached prefix to stay bitwise with
                     return grouped_causal_attention(q, kl, vl), (
                         k_pages, v_pages, k_scales, v_scales)
                 ctx = decode_attention_reference(
@@ -1267,7 +1301,7 @@ class DecodeEngine:
                 new_state = _join_state(pools)
             else:
                 mix = _Mixers(mixed, recur, positions < length, attend,
-                              attend_window)
+                              attend_window, own_tallies=scan_tallies)
                 logits, cache = model.forward(
                     weights, tokens, positions, mixed.split(state), mix)
                 new_state = mixed.join(cache)
@@ -1279,6 +1313,11 @@ class DecodeEngine:
                                 a["top_p"][None])[0]
             if mixed is None:
                 return (tok, last), new_state
+            if scan_tallies:
+                # the scans' counters ride the token's read-back
+                tok = jnp.stack([tok] + [
+                    jnp.asarray(mix.counts[n], jnp.int32)
+                    for n in scan_tallies])
             return (tok, last, mix.recorded()), new_state
 
         return jax.jit(prefill, donate_argnums=(0,))
@@ -2117,7 +2156,10 @@ class DecodeEngine:
         def finish():
             try:
                 with otrace.span("serving/prefill_sync", **attrs):
-                    first = int(np.asarray(tok))  # the prefill's sync point
+                    # the prefill's sync point: the token, then what
+                    # the program counted
+                    first, *counted = (int(x) for x in np.asarray(
+                        tok).reshape(-1))
                 # through the sync: the time until the token is on the
                 # host
                 dur = time.monotonic() - t0
@@ -2127,6 +2169,8 @@ class DecodeEngine:
                               tokens=len(req.prompt),
                               dur_ms=round(dur * 1e3, 3))
                     stat_add("decode_prefills")
+                    for name, x in zip(_SCAN_TALLIES, counted):
+                        stat_add(name, x)
                     # how much of the prompt's attention is work: the
                     # positions every row's softmax spans (the bucket)
                     # against those a row can see (the causal triangle)

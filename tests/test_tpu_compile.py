@@ -669,3 +669,101 @@ def test_command_a_plus_width_programs_compile(one_chip, program):
         assert re.search(r"f32\[8,16,256,4096\]", text)
     # what the program needs beside its operands stays far inside the chip
     assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
+
+
+# -- Olmo-Hybrid's rows: ONE query row a K/V head, keys of 96 on values of 192
+
+def test_paged_attention_compiles_at_olmo_hybrid_rows(one_chip):
+    """30 query heads over 30 K/V heads of 128 lanes (a group of ONE row
+    a K/V head), K and V rows of 3,840 bf16 lanes, pages of 16, a table
+    of 352 pages a slot: the kernel's row stacking at its smallest
+    group, blocks of 8 pages."""
+    pps = 352
+    pool = SLOTS * pps + 1
+    assert pda.pages_per_block(16, pps, 3840, 2, 3840) == 8
+    shapes = [((SLOTS, 30, 128), jnp.float32),
+              ((LAYERS, pool, 16, 3840), jnp.bfloat16),
+              ((LAYERS, pool, 16, 3840), jnp.bfloat16),
+              ((SLOTS, pps), jnp.int32), ((SLOTS,), jnp.int32)]
+    text = _compile(one_chip,
+                    functools.partial(_paged, pda.paged_decode_attention),
+                    *shapes)
+    assert text.count("tpu_custom_call") == 1
+
+
+def _gated_delta_engine():
+    """Olmo-Hybrid-7B's widths behind the engine at the cell's serving
+    sizes (32 slots, 5,632 positions, bf16 pages, the whole vocabulary),
+    one layer of each kind: only shapes matter to a compile, and these
+    are the ones the chip's compiler could refuse (a 96 x 192 state a
+    head, 3,840-lane rows, 30 heads over a 4,096-row prompt, logits of
+    100,352)."""
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine, GatedDeltaLM
+
+    model = GatedDeltaLM(
+        vocab_size=100352, d_model=3840,
+        layer_kinds=("recurrent", "attention"), num_heads=30, head_dim=128,
+        lin_heads=30, lin_key_dim=96, lin_value_dim=192, conv_kernel=4,
+        ffn_dim=11008)
+    weights = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
+    return DecodeEngine(model, weights, DecodeConfig(
+        slots=32, max_seq_len=5632, num_pages=32 * 353 + 1,
+        use_pallas="always", cache_dtype="bfloat16"))
+
+
+def _metric_pattern(name):
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "layer_metrics",
+            name + ".json")) as f:
+        return re.compile(json.load(f)["params"]["pattern"])
+
+
+@pytest.mark.parametrize("program", ["step", "prefill_4096"])
+def test_olmo_hybrid_width_programs_compile(one_chip, program):
+    """The joint step (the paged kernel at one row a K/V head, the
+    one-token update on the slabs) and the 4,096-row whole-prompt
+    prefill, whose recurrent layer is ONE loop over chunks of 64 and
+    whose attention runs in 4 blocks of 1,024 query rows; the
+    benchmark's patterns find in the compiled programs what their
+    metrics time."""
+    eng = _gated_delta_engine()
+    slab = tuple(eng._scope.get_var(
+        eng._cache.recurrent_var_names()[0]).shape)
+    assert slab == (32, 30, 96, 192)
+    if program == "step":
+        compiled = eng.lower_step(sharding=one_chip).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1
+        entry = [line.split(" = ", 1)[-1]
+                 for line in _computation(text, "ENTRY ")]
+        gdn = _metric_pattern("gdn_ms_per_step.serve")
+        assert _metric_pattern("gdn_state_roofline").pattern == gdn.pattern
+        assert [l for l in entry if gdn.search(l) and "f32[32,30,96,192]"
+                in l], "no matched instruction writes a slab"
+        assert not [l for l in entry if gdn.search(l)
+                    and "paged_attention" in l]
+        ffn = _metric_pattern("dense_ffn_ms_per_step.serve")
+        assert {m for l in entry for m in ffn.findall(l)} == {"gate", "up",
+                                                               "down"}
+    else:
+        assert pda.prefill_key_span(4096, 30) == (1024, 4096)
+        compiled = eng.lower_prefill(4096, sharding=one_chip).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" not in text
+        assert not re.search(r"f32\[30,1,4096,4096\]", text)
+        loop = _metric_pattern("gdn_prefill_ms.serve")
+        assert _metric_pattern("gdn_prefill_roofline").pattern \
+            == loop.pattern
+        whiles = [line for line in _computation(text, "ENTRY ")
+                  if re.search(r"\bwhile\(", line)]
+        chunked = [line for line in whiles if loop.search(line.strip())]
+        # one loop a recurrent layer carries the slot's state; the
+        # attention's loop over its blocks carries none
+        assert len(chunked) == 1 and len(whiles) >= 2, whiles
+    # what the program needs beside its operands stays inside the chip:
+    # 4.9 GB of weights, 5.6 GB of pages and 0.6 GB of slabs are resident
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
