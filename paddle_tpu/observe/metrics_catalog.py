@@ -136,6 +136,24 @@ RULES = (
          "Held experts some live row of a joint decode step chose, "
          "summed over its layers: the expert weights the step cannot "
          "avoid reading.  Rides the step's one read-back"),
+    Rule("moe_grouped_pairs", "gauge", "expert_parallel",
+         "(live row, held expert) pairs that calls of `moe_share_ffn` "
+         "computed in the grouped form (`ops/pallas_moe_grouped.py`: "
+         "the pairs sorted by expert, a row meets only the experts it "
+         "chose), summed over a whole-prompt prefill's layers and read "
+         "back with the prefill's token.  0 over a routed model's "
+         "prefills means the form never engaged"),
+    Rule("moe_grouped_rows_dense", "gauge", "expert_parallel",
+         "Rows x held experts of those same calls: the pairs the dense "
+         "form (every row through every held expert) would have "
+         "computed.  `moe_grouped_pairs` over this is the share of the "
+         "old multiply-adds that is left"),
+    Rule("moe_grouped_extra_passes", "gauge", "expert_parallel",
+         "Passes over the grouped form's sorted buffer beyond a call's "
+         "first: a call whose rows chose more pairs than the buffer "
+         "holds (two a row) fills and walks it again, reading the held "
+         "experts' weights once more each time; nothing is dropped and "
+         "no other form takes over.  0 under any routing near uniform"),
     Rule("moe_", "gauge", "expert_parallel",
          "Mixture-of-experts routing: expert balance and drop "
          "fractions (ppm), routed-FFN engagement, all-to-all "

@@ -49,6 +49,9 @@ __all__ = [
     "moe_share_route",
     "moe_share_counts",
     "moe_share_ffn",
+    "grouped_rule",
+    "ridge_rows",
+    "GROUPED_TALLIES",
 ]
 
 
@@ -273,13 +276,18 @@ def _moe_ffn_lower(ctx, op):
 # Expert parallelism seen from one chip: the router keeps its full width,
 # every token picks its top-k over ALL experts, and this chip computes the
 # part of the result its own experts give.  Nothing is dropped and nothing
-# is sized by a capacity: the held experts' weights are three plain
-# matrices ([D, n_held*F], [D, n_held*F], [n_held*F, D]), every row runs
-# through them, and a row's hidden units of an expert it did not choose
-# are multiplied by zero.  At decode (a hundred rows) the three matmuls
-# stream the weights once and are bound by that read; a prefill pays
-# n_held / (local assignments a row) times the multiply-adds a grouped
-# matmul over sorted rows would (PERF.md says what that costs).
+# is sized by a capacity that drops: the held experts' weights are three
+# plain matrices ([D, n_held*F], [D, n_held*F], [n_held*F, D]) and one
+# result has two forms, chosen by the call's shape (``grouped_rule``).
+# At decode (a hundred rows) every row runs through all three matrices
+# and a row's hidden units of an expert it did not choose are multiplied
+# by zero: three matmuls that stream the weights once and are bound by
+# that read.  At a prefill's rows that form would pay n_held / (local
+# assignments a row) times the multiply-adds, so the (row, held expert)
+# pairs with a weight are sorted by expert and run as a grouped matmul
+# (``ops/pallas_moe_grouped.py``): the weights still read once, where
+# they lie, and a row meets only the experts it chose (PERF.md says what
+# each form costs).
 # The scopes name the ops in lowered text; a trace's device events carry
 # the HLO instruction, whose operands name the weights they read.
 
@@ -328,16 +336,77 @@ def moe_share_counts(local):
             jnp.sum(jnp.any(hit, axis=0), dtype=jnp.int32))
 
 
-def moe_share_ffn(h, local, w_gate, w_up, w_down):
+# what the grouped form counts of the calls it takes, through ``tally``
+GROUPED_TALLIES = ("moe_grouped_pairs", "moe_grouped_rows_dense",
+                   "moe_grouped_extra_passes")
+# measured on the v5e, alone, 40 experts of 1,280: 256 rows read 2.1 ms
+# dense and 2.8 grouped, 512 rows 3.7 and 3.2 (PERF.md, PR 43): the
+# crossover lies between one ridge and two, nearer two
+GROUPED_FROM_RIDGES = 2.0
+
+
+def ridge_rows():
+    """Rows at which the dense form's multiply-adds take as long as the
+    read of the weights it cannot avoid: the attached chip's bf16 peak
+    over its HBM rate (``observe/device_peaks.py``; the v5e's 197
+    TFLOP/s over 819 GB/s = 240.5).  A process whose device has no row
+    there (the CPU: tests, a compile for a described chip) takes the
+    v5e's, the part every cell runs on."""
+    from ..observe.device_peaks import DEVICE_PEAKS, device_peak
+
+    row = device_peak() or DEVICE_PEAKS["TPU v5 lite"]
+    return row["bf16_tflops"] * 1e3 / row["hbm_gbps"]
+
+
+def grouped_rule(rows, n_held, expert_dim, d_model):
+    """Whether a call of ``rows`` rows over ``n_held`` experts of
+    ``expert_dim`` hidden units takes the grouped form: a function of
+    the call's static shape alone.  Under ``GROUPED_FROM_RIDGES`` ridges
+    the dense form is bound by the weight read, or nearly, and the
+    grouped form's own passes (the pairs to their slots, the rows
+    gathered, the result's blocks read and written) buy nothing.  The
+    sorted buffer's tiles at their worst (two pairs a row, every
+    expert's run ending a tile early) are computed whole: they must be
+    under half the dense form's rows x experts.  And the kernels' blocks
+    are whole lanes: a width no 128 divides keeps the dense form."""
+    from .pallas_moe_grouped import sorted_rows
+
+    return (rows >= GROUPED_FROM_RIDGES * ridge_rows()
+            and 2 * sorted_rows(rows, n_held) <= rows * n_held
+            and expert_dim % 128 == 0 and d_model % 128 == 0)
+
+
+def moe_share_ffn(h, local, w_gate, w_up, w_down, *, tally=None,
+                  interpret=False):
     """The held experts' part of the routed result for rows ``h
     [..., D]``: ``sum_j local[r, j] * E_j(h_r)`` with ``E(h) =
     (silu(h W_gate) * h W_up) W_down``.  ``w_gate``/``w_up`` are
     ``[D, n_held*F]`` (expert j's columns ``j*F:(j+1)*F``), ``w_down``
     ``[n_held*F, D]``; matmuls take the weights' dtype in and float32
-    out.  Dropless under any imbalance: every row meets every held
-    expert, the weight decides."""
+    out.  Dropless under any imbalance.  Few rows (``grouped_rule``):
+    every row meets every held expert, the weight decides.  Many: the
+    pairs with a weight, sorted by expert, as a grouped matmul; the
+    same sum to float32's order of summation.  ``tally(name, n)``, where
+    given, takes what the grouped form counts (``GROUPED_TALLIES``:
+    the pairs it computed, rows x ``n_held`` of the same calls, the
+    passes over the sorted buffer beyond a call's first).
+    ``interpret`` runs the grouped form's kernels interpreted (tests on
+    the CPU, as ``DecodeConfig.interpret`` does the attention's); with
+    no chip and without it the kernels fail to lower, loudly."""
     n_held = local.shape[-1]
+    rows = math.prod(h.shape[:-1])
     with jax.named_scope(EXPERTS_SCOPE):
+        if grouped_rule(rows, n_held, w_down.shape[0] // n_held,
+                        h.shape[-1]):
+            from .pallas_moe_grouped import grouped_share_ffn
+
+            out, pairs, passes = grouped_share_ffn(
+                h, local, w_gate, w_up, w_down, interpret=interpret)
+            if tally is not None:
+                for name, n in zip(GROUPED_TALLIES, (
+                        pairs, rows * n_held, jnp.maximum(passes - 1, 0))):
+                    tally(name, n)
+            return out
         x = h.astype(w_gate.dtype)
         gate = jnp.matmul(x, w_gate, preferred_element_type=jnp.float32)
         up = jnp.matmul(x, w_up, preferred_element_type=jnp.float32)

@@ -1,0 +1,281 @@
+"""The held experts of MANY rows as a grouped matmul over the (row, held
+expert) pairs that have a weight, sorted by expert.
+
+``moe_ops.moe_share_ffn`` at a prefill's rows: the dense form multiplies
+every row by every held expert and most products by zero; here a row
+meets only the experts it chose.  The pairs are laid out expert by
+expert, each expert's run padded to whole tiles of ``default_tiles`` rows,
+so a tile belongs to ONE expert; two Pallas kernels walk the tiles.
+The first runs a tile's rows through its expert's gate and up columns
+and multiplies the pair's weight in before the cast.  The second runs
+the down projection a block of columns at a time with ALL rows of the
+result resident, and adds each live pair's row to its row there: a
+row's partial results are summed in float32 in the order of its
+experts, and there is no plane of pairs x D and no scatter.  Tiles past
+the last live pair are skipped: their index maps stand still, so nothing
+is copied for them either.
+The weights are read where they lie: expert j is the COLUMN block
+``j*F:(j+1)*F`` of ``w_gate`` / ``w_up`` ``[D, n_held*F]`` and the row
+block of ``w_down`` ``[n_held*F, D]``; no operand is transposed or
+copied.
+
+Dropless under any imbalance with a bounded buffer: the sorted buffer
+holds ``PAIRS_A_ROW`` pairs a row, and a call whose rows chose more runs
+the same two kernels again over the next pairs (a ``while_loop`` over
+passes; one pass under any routing near uniform).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+__all__ = ["grouped_share_ffn", "default_tiles", "sorted_rows",
+           "GATE_UP_KERNEL_NAME", "DOWN_KERNEL_NAME"]
+
+GATE_UP_KERNEL_NAME = "moe_grouped_gate_up"
+DOWN_KERNEL_NAME = "moe_grouped_down"
+_LANES = 128
+_VMEM_LIMIT = 96 * 1024 * 1024
+# pairs a row the sorted buffer holds: the cells' rows choose 0.5-1 of
+# the held experts each, four times that at a skewed router's worst seen
+PAIRS_A_ROW = 2
+
+
+def _round_up(x, m):
+    return -(-x // m) * m
+
+
+def _col_tile(width):
+    """The widest of 512 / 256 / 128 columns that divides ``width`` (a
+    width none divides goes whole: small sizes, interpret mode)."""
+    return next((t for t in (512, 256, _LANES) if width % t == 0), width)
+
+
+def default_tiles(rows, n_held):
+    """Rows a tile for a call of ``rows`` rows over ``n_held`` experts:
+    128 (the MXU's side), or 256 where even rows / n_held pairs an
+    expert would fill more than one such tile (a tile is computed whole
+    however few of its rows hold a pair: on the chip 512 lost to 256 at
+    4,096 rows over 8 experts, and 256 to 128 at 2,048 over 16)."""
+    return 256 if rows > 128 * n_held else 128
+
+
+def sorted_rows(rows, n_held):
+    """Rows of the sorted buffer of such a call: ``PAIRS_A_ROW`` pairs a
+    row in whole tiles, and a tile more an expert, since every expert's
+    run may end a tile early.  A pass computes as many of its tiles as
+    hold a pair, whole."""
+    tm = default_tiles(rows, n_held)
+    return _round_up(PAIRS_A_ROW * rows, tm) + n_held * tm
+
+
+def _down_cols(rows, f, d):
+    """Columns of the result resident at a time in the down kernel: all
+    its rows by as many columns as keep that block of float32 and the
+    expert's block of ``w_down`` within 8 MiB each (a tile's activations
+    are read again for every block of columns, so wide blocks; each
+    block is double-buffered)."""
+    tn = d
+    while tn > _LANES and tn % 2 == 0 and max(2 * rows, f) * tn * 2 > 8 << 20:
+        tn //= 2
+    return tn
+
+
+def _gate_up_kernel(tile_expert, n_active, x_ref, ws_ref, wg_ref, wu_ref,
+                    act_ref):
+    """One tile's rows through one block of its expert's gate and up
+    columns; the pair's weight goes in before the cast."""
+    import jax.experimental.pallas as pl
+
+    @pl.when(pl.program_id(0) < n_active[0])
+    def _():
+        x = x_ref[...]
+        gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+        up = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+        act_ref[...] = (jax.nn.silu(gate) * up * ws_ref[...]).astype(
+            act_ref.dtype)
+
+
+def _down_kernel(tile_expert, n_active, slot_row, tile_live, act_ref,
+                 wd_ref, acc_ref, out_ref, y_ref, *, tm):
+    """One tile's down projection, each of its live rows then added to
+    its row of the result: the block of ``out`` (all rows, one block of
+    columns) stays where it is while the tiles go by."""
+    import jax.experimental.pallas as pl
+
+    m = pl.program_id(1)
+
+    @pl.when(m == 0)
+    def _():
+        out_ref[...] = acc_ref[...]
+
+    @pl.when(m < n_active[0])
+    def _():
+        y_ref[...] = jnp.dot(act_ref[...], wd_ref[...],
+                             preferred_element_type=jnp.float32)
+
+        def add_row(i, carry):
+            at = pl.ds(slot_row[m * tm + i], 1)
+            out_ref[at, :] = out_ref[at, :] + y_ref[pl.ds(i, 1), :]
+            return carry
+
+        lax.fori_loop(0, tile_live[m], add_row, 0)
+
+
+def _clamped(n_active, m):
+    """Tile ``m``, or the last live tile where ``m`` is behind it: a
+    dead tile's blocks are the ones already there, and the pipeline
+    copies nothing for it."""
+    return jnp.minimum(m, jnp.maximum(n_active[0] - 1, 0))
+
+
+def _gate_up_call(x_sorted, w_sorted, tile_expert, n_active, w_gate, w_up,
+                  *, n_held, tm, interpret):
+    """``act [M, F]``: a tile's rows through ITS expert's gate and up
+    columns, times the pairs' weights, in the weights' dtype."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m_rows, d = x_sorted.shape
+    f = w_gate.shape[1] // n_held
+    tn = _col_tile(f)
+    cols = f // tn
+
+    def rows_of(m, n, te, na):
+        return _clamped(na, m), 0
+
+    def columns(m, n, te, na):          # a dead tile: the last block
+        return jnp.where(m < na[0], n, cols - 1)
+
+    def expert_cols(m, n, te, na):      # expert j is a COLUMN block
+        return 0, te[_clamped(na, m)] * cols + columns(m, n, te, na)
+
+    return pl.pallas_call(
+        _gate_up_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(m_rows // tm, cols),
+            in_specs=[pl.BlockSpec((tm, d), rows_of),
+                      pl.BlockSpec((tm, 1), rows_of),
+                      pl.BlockSpec((d, tn), expert_cols),
+                      pl.BlockSpec((d, tn), expert_cols)],
+            out_specs=pl.BlockSpec(
+                (tm, tn), lambda m, n, te, na: (
+                    _clamped(na, m), columns(m, n, te, na)))),
+        out_shape=jax.ShapeDtypeStruct((m_rows, f), w_gate.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name=GATE_UP_KERNEL_NAME,
+    )(tile_expert, n_active, x_sorted, w_sorted, w_gate, w_up)
+
+
+def _down_call(act, acc, tile_expert, n_active, slot_row, tile_live,
+               w_down, *, tm, interpret):
+    """``acc [rows, D]`` plus every live pair's down projection, added
+    to the pair's row: a block of columns at a time, all rows of it
+    resident while the tiles go by (no ``[M, D]`` plane, no scatter)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m_rows, f = act.shape
+    rows, d = acc.shape
+    tn = _down_cols(rows, f, d)
+
+    def block(n, m, *_):
+        return 0, n
+
+    return pl.pallas_call(
+        functools.partial(_down_kernel, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(d // tn, m_rows // tm),
+            in_specs=[
+                pl.BlockSpec((tm, f), lambda n, m, te, na, *_: (
+                    _clamped(na, m), 0)),
+                # expert j is a ROW block of w_down
+                pl.BlockSpec((f, tn), lambda n, m, te, na, *_: (
+                    te[_clamped(na, m)], n)),
+                pl.BlockSpec((rows, tn), block)],
+            out_specs=pl.BlockSpec((rows, tn), block),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name=DOWN_KERNEL_NAME,
+    )(tile_expert, n_active, slot_row, tile_live, act, w_down, acc)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def grouped_share_ffn(h, local, w_gate, w_up, w_down, *, interpret=False):
+    """``moe_ops.moe_share_ffn``'s result for rows ``h [..., D]`` and
+    weights ``local [..., n_held]``, computed over the pairs with a
+    non-zero weight only -> ``(out [..., D] float32, pairs, passes)``:
+    ``pairs`` (int32) is how many pairs there were, ``passes`` how many
+    times the sorted buffer (``sorted_rows``) was filled and walked.
+    Jitted: a model's layers share one traced and lowered call (six
+    layers' calls of a prefill program trace and lower in 0.1 s, not
+    0.5: host time of every set-up, timed on the CPU)."""
+    n_held = local.shape[-1]
+    d = h.shape[-1]
+    x = h.reshape(-1, d).astype(w_gate.dtype)
+    rows = x.shape[0]
+    tm = default_tiles(rows, n_held)
+    m_rows = sorted_rows(rows, n_held)
+    cap = m_rows - n_held * tm
+    n_tiles = m_rows // tm
+
+    # the pairs expert by expert, a row after the rows before it, are
+    # the non-zero entries of local's transpose in the order they lie:
+    # pair p is the entry whose running count is p + 1
+    flat = local.reshape(rows, n_held).T.reshape(-1)
+    hit = flat != 0.0
+    count = jnp.cumsum(hit, dtype=jnp.int32)
+    ends = count[rows - 1::rows]
+    starts, pairs = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]]), \
+        ends[-1]
+    passes = -(-pairs // cap)
+    entry = jnp.arange(n_held * rows, dtype=jnp.int32)
+    expert, row = entry // rows, entry % rows
+    tile_at = jnp.arange(n_tiles, dtype=jnp.int32) * tm
+
+    def one_pass(carry):
+        c, out = carry
+        lo = c * cap
+        hi = jnp.minimum(lo + cap, pairs)
+        first = jnp.clip(starts, lo, hi)            # this pass's share of
+        size = jnp.clip(ends, lo, hi) - first       # every expert's run
+        padded = _round_up(size, tm)
+        p_end = jnp.cumsum(padded)
+        p_start = p_end - padded
+        tile_expert = jnp.minimum(jnp.searchsorted(
+            p_end, tile_at, side="right"), n_held - 1).astype(jnp.int32)
+        tile_live = jnp.clip(
+            size[tile_expert] - (tile_at - p_start[tile_expert]), 0, tm)
+        # every pair of this pass to its slot, every other entry past
+        # the buffer's end, each to a place of its own (the scatter is
+        # told its indices are unique; a sort of these compiles for
+        # twenty seconds, a search over the counts runs for a
+        # millisecond); a slot no pair takes repeats row 0 with the
+        # weight zero
+        p = count - 1
+        slot = jnp.where(hit & (p >= lo) & (p < hi),
+                         (p_start - first)[expert] + p, m_rows + entry)
+        slot_row = jnp.zeros(m_rows, jnp.int32).at[slot].set(
+            row, mode="drop", unique_indices=True)
+        slot_weight = jnp.zeros(m_rows, jnp.float32).at[slot].set(
+            flat.astype(jnp.float32), mode="drop", unique_indices=True)
+        n_active = (p_end[-1] // tm).reshape(1)
+        act = _gate_up_call(
+            x[slot_row], slot_weight[:, None], tile_expert, n_active,
+            w_gate, w_up, n_held=n_held, tm=tm, interpret=interpret)
+        return c + 1, _down_call(act, out, tile_expert, n_active, slot_row,
+                                 tile_live, w_down, tm=tm,
+                                 interpret=interpret)
+
+    _, out = lax.while_loop(
+        lambda carry: carry[0] < passes, one_pass,
+        (jnp.int32(0), jnp.zeros((rows, d), jnp.float32)))
+    return out.reshape(*h.shape[:-1], d), pairs, passes

@@ -321,6 +321,12 @@ class _Mixed:
         self.n_arrays = len(self.layer["recurrent"]) * len(self.names)
         self.n_window = 2 if window is not None else 0
         self.tallies = tuple(getattr(model, "tallies", ()))
+        # what a whole-prompt prefill reads back with its token: its
+        # recurrent layers' scans, then those of the model's counters it
+        # declares for a prefill
+        self.prefill_tallies = (
+            _SCAN_TALLIES if self.layer["recurrent"] else ()) + tuple(
+            getattr(model, "prefill_tallies", ()))
 
     def split(self, state):
         n = len(state) - self.n_window - self.n_arrays
@@ -349,16 +355,20 @@ class _Mixers:
     rows' shape) says which rows are a request's; ``tally(name, n)``
     adds an int32 scalar to the counter ``name``, one of the model's
     declared ``tallies`` (a joint step's ride its one read-back, in the
-    declared order); ``record(name, rows)`` keeps a per-row array a
+    declared order; a name of its ``prefill_tallies`` counts where a
+    whole-prompt prefill reads it back and nowhere else);
+    ``interpret`` is ``DecodeConfig.interpret`` for a layer's own Pallas
+    kernels; ``record(name, rows)`` keeps a per-row array a
     layer for a request that records its logits."""
 
     def __init__(self, mixed: _Mixed, recur, live, attend=None,
-                 attend_window=None, own_tallies=()):
+                 attend_window=None, own_tallies=(), interpret=False):
         self._mixed, self._recur, self.attend = mixed, recur, attend
         self.attend_window = attend_window
         self.live = live
-        # ``own_tallies``: counters the engine's side of a program adds
-        # to beside the model's declared ones
+        self.interpret = bool(interpret)
+        # ``own_tallies``: counters this program reads back beside the
+        # model's declared ones
         self.counts = dict.fromkeys(mixed.tallies + tuple(own_tallies), 0)
         self.records = {}
 
@@ -379,10 +389,11 @@ class _Mixers:
         return out, (pools, window, rec[:i] + (new,) + rec[i + 1:])
 
     def tally(self, name, value):
-        if name not in self.counts:
+        if name in self.counts:
+            self.counts[name] += value
+        elif name not in self._mixed.prefill_tallies:
             raise KeyError(f"{name!r} is not among the model's declared "
                            f"tallies {self._mixed.tallies}")
-        self.counts[name] += value
 
     def record(self, name, rows):
         self.records.setdefault(name, []).append(rows)
@@ -721,7 +732,11 @@ class DecodeEngine:
     ``attend.live``
     (which rows are a request's), ``attend.tally(name, n)`` (counters
     the model declares by name in ``tallies``; they ride the step's one
-    read-back into ``stat_add(name)``) and ``attend.record(name, rows)``
+    read-back into ``stat_add(name)``; those it names in
+    ``prefill_tallies`` ride a whole-prompt prefill's token instead and
+    are not counted in a step), ``attend.interpret``
+    (``DecodeConfig.interpret``, for a layer's own Pallas kernels) and
+    ``attend.record(name, rows)``
     (a per-row array a layer, kept beside the logits of a
     ``record_logits`` request in ``req.records``).  A model with window
     or recurrent layers is served by the whole-prompt prefill and the
@@ -797,6 +812,9 @@ class DecodeEngine:
             if getattr(model, "layer_kinds", None) else None
         # the model's counters behind a step's tokens, as it declares them
         self._tallies = self._mixed.tallies if self._mixed else ()
+        # and what rides a whole-prompt prefill's token
+        self._prefill_tallies = self._mixed.prefill_tallies \
+            if self._mixed else ()
         self._refuse_for_kinds(model, c, draft_model)
         with jax.default_device(self._device):
             self._cache = PagedKVCache(
@@ -1147,7 +1165,8 @@ class DecodeEngine:
                     positions, page_table, write_page, write_off)
                 new_state = _join_state(pools)
             else:
-                mix = _Mixers(mixed, recur, live)
+                mix = _Mixers(mixed, recur, live,
+                              interpret=self.config.interpret)
                 if self._window is not None:
                     # the position's page of the slot's own ring
                     # (kv_cache.WindowSpec.ring_table), trash for a dead
@@ -1192,8 +1211,7 @@ class DecodeEngine:
 
         mixed = self._mixed if model is self.model else None
         row = _prefill_row(t_pad, cc.pages_per_slot, slot=mixed is not None)
-        scan_tallies = _SCAN_TALLIES \
-            if mixed is not None and mixed.layer["recurrent"] else ()
+        counted = mixed.prefill_tallies if mixed is not None else ()
         fresh_only = bool(per_slot_kinds(model))
 
         @jax.named_scope("prefill_full")
@@ -1301,7 +1319,8 @@ class DecodeEngine:
                 new_state = _join_state(pools)
             else:
                 mix = _Mixers(mixed, recur, positions < length, attend,
-                              attend_window, own_tallies=scan_tallies)
+                              attend_window, own_tallies=counted,
+                              interpret=self.config.interpret)
                 logits, cache = model.forward(
                     weights, tokens, positions, mixed.split(state), mix)
                 new_state = mixed.join(cache)
@@ -1313,11 +1332,10 @@ class DecodeEngine:
                                 a["top_p"][None])[0]
             if mixed is None:
                 return (tok, last), new_state
-            if scan_tallies:
-                # the scans' counters ride the token's read-back
+            if counted:
+                # what the program counted rides the token's read-back
                 tok = jnp.stack([tok] + [
-                    jnp.asarray(mix.counts[n], jnp.int32)
-                    for n in scan_tallies])
+                    jnp.asarray(mix.counts[n], jnp.int32) for n in counted])
             return (tok, last, mix.recorded()), new_state
 
         return jax.jit(prefill, donate_argnums=(0,))
@@ -2169,7 +2187,7 @@ class DecodeEngine:
                               tokens=len(req.prompt),
                               dur_ms=round(dur * 1e3, 3))
                     stat_add("decode_prefills")
-                    for name, x in zip(_SCAN_TALLIES, counted):
+                    for name, x in zip(self._prefill_tallies, counted):
                         stat_add(name, x)
                     # how much of the prompt's attention is work: the
                     # positions every row's softmax spans (the bucket)

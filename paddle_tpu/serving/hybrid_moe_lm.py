@@ -74,8 +74,10 @@ class HybridMoELM:
         self.rms_eps = float(rms_eps)
         self.dtype = str(dtype)
         self.max_seq_len = int(max_seq_len)     # no positional table
-        # the counters forward adds to through attend.tally
+        # the counters forward adds to through attend.tally: a joint
+        # step's, and those only a whole-prompt prefill reads back
         self.tallies = ("moe_local_assignments", "moe_experts_hit")
+        self.prefill_tallies = moe_ops.GROUPED_TALLIES
         c = self.lin_heads * self.lin_head_dim
         # one slot's state of ONE recurrent layer: the delta rule's
         # matrix a head, and the K-1 positions the convolution looks
@@ -194,7 +196,8 @@ class HybridMoELM:
                              lw["shared_w_down"])
             x = x + moe_ops.moe_share_ffn(
                 h, local, lw["moe_w_gate"], lw["moe_w_up"],
-                lw["moe_w_down"]) + shared
+                lw["moe_w_down"], tally=attend.tally,
+                interpret=attend.interpret) + shared
         return _mm(self._rms(x, w["norm_f"]), w["lm_head"]), cache
 
     def _rms(self, x, g):

@@ -85,8 +85,10 @@ class ParallelMoELM:
         self.logit_scale = float(logit_scale)
         self.dtype = str(dtype)
         self.max_seq_len = int(max_seq_len)     # no positional table
-        # the counters forward adds to through attend.tally
+        # the counters forward adds to through attend.tally: a joint
+        # step's, and those only a whole-prompt prefill reads back
         self.tallies = ("moe_local_assignments", "moe_experts_hit")
+        self.prefill_tallies = moe_ops.GROUPED_TALLIES
 
     # -- weights ------------------------------------------------------------
     def init_weights(self, key):
@@ -177,7 +179,8 @@ class ParallelMoELM:
                          lw["shared_w_down"]) / self.shared_experts
         return moe_ops.moe_share_ffn(
             h, local, lw["moe_w_gate"], lw["moe_w_up"],
-            lw["moe_w_down"]) + shared
+            lw["moe_w_down"], tally=attend.tally,
+            interpret=attend.interpret) + shared
 
     def _head(self, weights, x):
         """The final norm times the TRANSPOSED input embedding."""

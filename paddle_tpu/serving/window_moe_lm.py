@@ -90,8 +90,10 @@ class WindowMoELM:
         self.rms_eps = float(rms_eps)
         self.dtype = str(dtype)
         self.max_seq_len = int(max_seq_len)     # no positional table
-        # the counters forward adds to through attend.tally
+        # the counters forward adds to through attend.tally: a joint
+        # step's, and those only a whole-prompt prefill reads back
         self.tallies = ("moe_local_assignments", "moe_experts_hit")
+        self.prefill_tallies = moe_ops.GROUPED_TALLIES
 
     def kv_heads(self, kind: str) -> int:
         return self.window_kv_heads if kind == "window" \
@@ -180,7 +182,8 @@ class WindowMoELM:
                                     self.held_experts)
                 x = x + moe_ops.moe_share_ffn(
                     h, local, lw["moe_w_gate"], lw["moe_w_up"],
-                    lw["moe_w_down"])
+                    lw["moe_w_down"], tally=attend.tally,
+                    interpret=attend.interpret)
         return _mm(rms_norm(x, w["norm_f"], self.rms_eps),
                    w["lm_head"]), cache
 

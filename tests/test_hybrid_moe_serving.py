@@ -14,6 +14,7 @@ from paddle_tpu.monitor import stat_get
 from paddle_tpu.ops import moe_ops
 from paddle_tpu.ops import pallas_decode_attention as pda
 from paddle_tpu.serving import DecodeConfig, DecodeEngine
+from paddle_tpu.serving import decode as decode_mod
 from paddle_tpu.serving.hybrid_moe_lm import HybridMoELM
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -224,6 +225,157 @@ def test_the_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(x + total, want, atol=1e-4)
 
 
+def _share_case(case, dtype, d=32, f=16):
+    """Rows, the held experts' weights and ``local`` for one way the
+    rows may have chosen.  200 rows end a tile of 128 early, 128 fill
+    theirs; every row on all of 8 held experts is 1,600 pairs for a
+    sorted buffer that holds 512."""
+    rows = 128 if case == "whole_tiles" else 200
+    n_held = 8 if case == "overflow" else 4
+    k = jax.random.split(jax.random.PRNGKey(21), 6)
+    h = jax.random.normal(k[0], (rows, d), jnp.float32)
+    router = jax.random.normal(k[1], (d, 16)) / np.sqrt(d)
+    w = [(jax.random.normal(kk, shape) / np.sqrt(shape[0] / (
+        n_held if shape[0] > d else 1))).astype(dtype)
+        for kk, shape in zip(k[2:5], ((d, n_held * f), (d, n_held * f),
+                                      (n_held * f, d)))]
+    live = jnp.arange(rows) < rows - 9 if case == "padding_tail" else None
+    _, _, local = moe_ops.moe_share_route(
+        h, router, jnp.zeros((16,)), top_k=3, held_ids=range(n_held),
+        live=live)
+    if case == "one_expert":
+        local = jnp.zeros_like(local).at[:, 2].set(0.4)
+    elif case == "no_expert":
+        local = jnp.zeros_like(local)
+    elif case == "overflow":
+        local = jax.random.uniform(k[5], local.shape, minval=0.05,
+                                   maxval=0.2)
+    return h, local, w
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", [
+    "routers_draw", "one_expert", "no_expert", "padding_tail",
+    "whole_tiles", "overflow"])
+def test_the_grouped_form_is_the_dense_form(case, dtype):
+    """``grouped_share_ffn`` (the kernels interpreted, the tiles and the
+    sorted buffer it gives itself) against the three matmuls over every
+    row and every held expert: float32 to 1e-5 of the largest value,
+    bfloat16 weights to the dense form's own rounding (its distance
+    from the same sum over the float32 values of the same weights)."""
+    from paddle_tpu.ops import pallas_moe_grouped as grouped
+
+    h, local, w = _share_case(case, jnp.dtype(dtype))
+    rows, n_held = local.shape
+    assert not moe_ops.grouped_rule(rows, n_held, 16, 32)
+    dense = moe_ops.moe_share_ffn(h, local, *w)
+    out, pairs, passes = grouped.grouped_share_ffn(
+        h, local, *w, interpret=True)
+    n = int((np.asarray(local) != 0).sum())
+    assert int(pairs) == n
+    assert grouped.default_tiles(rows, n_held) == 128
+    holds = grouped.sorted_rows(rows, n_held) - n_held * 128
+    assert holds == (256 if case == "whole_tiles" else 512)
+    assert int(passes) == -(-n // holds)
+    if case == "overflow":
+        assert int(passes) == 4
+    if case == "padding_tail":
+        assert not np.asarray(out)[-9:].any()
+    scale = float(jnp.abs(dense).max()) or 1.0
+    if dtype == "float32":
+        tol = 1e-5 * scale
+    else:
+        exact = moe_ops.moe_share_ffn(
+            h.astype(jnp.bfloat16).astype(jnp.float32), local,
+            *(x.astype(jnp.float32) for x in w))
+        tol = float(jnp.abs(dense - exact).max())
+    assert float(jnp.abs(out - dense).max()) <= tol
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+def test_the_form_follows_the_calls_shape():
+    """The rule reads the call's static shape and nothing else: a
+    decode step's rows keep the three matmuls they had, as do too few
+    experts and a width the chip's lanes do not divide; a prompt's rows
+    over enough experts take the two kernels inside the loop over
+    passes, and the counts go where ``tally`` says."""
+    f, d, n_held = 128, 128, 8
+    w = (jnp.zeros((d, n_held * f)), jnp.zeros((d, n_held * f)),
+         jnp.zeros((n_held * f, d)))
+
+    def lowered(rows, held=n_held, f=f, **kw):
+        return list(_primitives(jax.make_jaxpr(
+            lambda h, local: moe_ops.moe_share_ffn(
+                h, local, w[0][:, :held * f], w[1][:, :held * f],
+                w[2][:held * f], **kw))(
+            jnp.zeros((rows, d)), jnp.zeros((rows, held))).jaxpr))
+
+    # the v5e's ridge where no chip is attached: 197 TFLOP/s / 819 GB/s
+    assert 240 < moe_ops.ridge_rows() < 241
+    for rows, held, width in ((128, n_held, f), (480, n_held, f),
+                              (512, 5, f), (4096, 1, f), (512, n_held, 16)):
+        assert not moe_ops.grouped_rule(rows, held, width, d)
+        ops = lowered(rows, held, width)
+        assert ops.count("dot_general") == 3 and "pallas_call" not in ops
+        assert "while" not in ops and "sort" not in ops
+    counted = {}
+    assert moe_ops.grouped_rule(512, n_held, f, d)
+    ops = lowered(512, tally=counted.__setitem__)
+    assert ops.count("pallas_call") == 2 and "while" in ops
+    assert "sort" not in ops        # 16-19 s a layer to compile for the chip
+    assert tuple(counted) == moe_ops.GROUPED_TALLIES
+    # no chip and not asked to interpret: the kernels refuse, loudly
+    with pytest.raises(ValueError, match="interpret"):
+        moe_ops.moe_share_ffn(jnp.zeros((512, d)), jnp.ones((512, n_held)),
+                              *w)
+
+
+def test_a_long_prompts_prefill_groups_its_pairs_and_counts_them():
+    """A prompt whose bucket (512 rows over 8 held experts) passes the
+    rule: the served logits are the reference's, and the prefill's
+    counters, read back with its token behind the scan's, say the
+    grouped form computed exactly the pairs the prompt's rows chose
+    among the held experts."""
+    held = tuple(range(8))
+    # widths of whole lanes, as the rule asks; the engine's
+    # ``interpret`` is what lets the two kernels run without a chip
+    model = make_model(("attention", "recurrent"), held=held, d_model=128,
+                       expert_dim=128)
+    weights = model.init_weights(jax.random.PRNGKey(22))
+    rng = np.random.RandomState(23)
+    prompt = rng.randint(0, VOCAB, 300).tolist()
+    names = moe_ops.GROUPED_TALLIES + ("decode_prefills",
+                                       "decode_prefill_scan_tokens")
+    before = {n: stat_get(n) for n in names}
+    with engine(model, weights, max_seq_len=512, interpret=True) as eng:
+        assert eng._prefill_tallies[2:] == moe_ops.GROUPED_TALLIES
+        assert not set(eng._tallies) & set(moe_ops.GROUPED_TALLIES)
+        eng.submit(rng.randint(0, VOCAB, 40).tolist(),
+                   max_new_tokens=2).result(timeout=300)
+        # a bucket of 64 rows keeps the three matmuls: nothing counted
+        assert all(stat_get(n) == before[n] for n in moe_ops.GROUPED_TALLIES)
+        req = eng.submit(prompt, max_new_tokens=3, record_logits=True)
+        toks = req.result(timeout=300)
+    want, _ = ref.forward_logits(
+        weights, jnp.asarray(prompt + toks[:-1], jnp.int32), dims(model))
+    assert float(np.abs(np.stack(req.logits_trace)
+                        - np.asarray(want)[len(prompt) - 1:]).max()) < 5e-5
+    d = {n: stat_get(n) - v for n, v in before.items()}
+    assert d["decode_prefills"] == 2
+    assert d["decode_prefill_scan_tokens"] == 40 + 300
+    chosen = np.asarray(req.records["moe_topk"][0])     # [300, layers, k]
+    assert chosen.shape == (300, 2, model.top_k)
+    assert d["moe_grouped_pairs"] == int(np.isin(chosen, held).sum()) > 300
+    assert d["moe_grouped_rows_dense"] == 2 * 512 * len(held)
+    assert d["moe_grouped_extra_passes"] == 0
+
+
 @pytest.mark.parametrize("cfg, names", [
     (dict(prefill_chunk_pages=1), "chunked/ragged prefill"),
     (dict(prefill_chunk_pages=1, ragged_prefill_rows=8),
@@ -299,6 +451,13 @@ def test_the_tallies_are_the_models_declared_names_before_any_trace():
     eng = engine(model, weights)
     assert eng._tallies == model.tallies == (
         "moe_local_assignments", "moe_experts_hit")
+    # a step reads back no counter of the prefill's; a count of one
+    # there goes nowhere and fails nothing
+    assert eng._prefill_tallies[-3:] == model.prefill_tallies \
+        == moe_ops.GROUPED_TALLIES
+    mix = decode_mod._Mixers(eng._mixed, None, None)
+    mix.tally("moe_grouped_pairs", 7)
+    assert "moe_grouped_pairs" not in mix.counts
     model.tallies = ("moe_experts_hit",)
     with pytest.raises(KeyError, match="moe_local_assignments"):
         engine(model, weights).lower_step()

@@ -767,3 +767,76 @@ def test_olmo_hybrid_width_programs_compile(one_chip, program):
     # what the program needs beside its operands stays inside the chip:
     # 4.9 GB of weights, 5.6 GB of pages and 0.6 GB of slabs are resident
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+# -- a prefill's held experts as a grouped matmul (the three routed cells) --
+
+def _routed_engine(cell):
+    """One routed layer at the cell's expert widths (hidden 4,096, the
+    held experts as the cell holds them) behind the engine, few heads
+    and a short vocabulary: the grouped form sees the rows, ``n_held``,
+    ``F`` and ``D`` it sees in the cell."""
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+    from paddle_tpu.serving.hybrid_moe_lm import HybridMoELM
+    from paddle_tpu.serving.parallel_moe_lm import ParallelMoELM
+    from paddle_tpu.serving.window_moe_lm import WindowMoELM
+
+    if cell == "command_a_plus":
+        model = ParallelMoELM(
+            vocab_size=1024, d_model=4096, layer_kinds=("attention",),
+            num_heads=16, num_kv_heads=8, head_dim=128, rope_theta=5e4,
+            window=4096, num_experts=128, top_k=8, held_experts=range(8),
+            expert_dim=4096, shared_experts=1, shared_dim=512)
+    elif cell == "mimo_v2_5":
+        model = WindowMoELM(
+            vocab_size=1024, d_model=4096, layer_kinds=("attention",),
+            dense_layers=0, num_heads=16, num_kv_heads=4, window_kv_heads=8,
+            head_dim=192, v_head_dim=128, rotary_dim=64, rope_theta=1e7,
+            window_rope_theta=1e4, window=128, value_scale=0.707,
+            dense_dim=512, num_experts=256, top_k=8, held_experts=range(16),
+            expert_dim=2048)
+    else:
+        model = HybridMoELM(
+            vocab_size=1024, d_model=4096, layer_kinds=("attention",),
+            num_heads=16, num_kv_heads=8, head_dim=128, lin_heads=16,
+            lin_head_dim=128, conv_kernel=4, gate_rank=128, num_experts=320,
+            top_k=8, held_experts=range(40), expert_dim=1280, shared_dim=512)
+    weights = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
+    return DecodeEngine(model, weights, DecodeConfig(
+        slots=4, max_seq_len=4096, use_pallas="always",
+        cache_dtype="bfloat16"))
+
+
+@pytest.mark.parametrize("cell, rows", [
+    ("command_a_plus", 4096), ("mimo_v2_5", 2048),
+    ("solar_open2_250b", 1024)])
+def test_routed_prefills_compile_with_the_grouped_experts(
+        one_chip, cell, rows):
+    """The longest whole-prompt prefill of each routed cell, one layer:
+    the two kernels of the grouped form are in the program by name, the
+    dense form's ``[rows, n_held * F]`` plane is not, and the held
+    experts' matrices are read where they lie: no transposition or copy
+    of anything their size."""
+    from paddle_tpu.ops import moe_ops, pallas_moe_grouped as grouped
+
+    eng = _routed_engine(cell)
+    lw = eng.weights["layers"][0]
+    wide, tall = lw["moe_w_gate"].shape, lw["moe_w_down"].shape
+    assert wide == tall[::-1] == (4096, tall[0]) and tall[0] >= 32768
+    assert moe_ops.grouped_rule(rows, len(eng.model.held_experts),
+                                eng.model.expert_dim, 4096)
+    compiled = eng.lower_prefill(rows, sharding=one_chip).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert grouped.GATE_UP_KERNEL_NAME in text
+    assert grouped.DOWN_KERNEL_NAME in text
+    assert not re.search(r"f32\[%d,%d\]" % (rows, wide[1]), text)
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m and m["op"] in ("copy", "transpose", "copy-start"):
+            dims = {tuple(int(d) for d in a.split(",") if d)
+                    for a in _ARRAY.findall(m["type"])}
+            assert not dims & {wide, tall}, line[:160]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
