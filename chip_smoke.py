@@ -35,7 +35,7 @@ SEED = 0
 BERT = dict(batch_size=256, seq_len=128, vocab_size=30522, hidden=768,
             n_layers=12, n_heads=12, ffn_size=3072, max_preds_per_seq=20)
 RESNET = dict(batch_size=128, img_shape=(3, 224, 224), class_num=1000)
-# bench.py's 0.1 is the peak of a warmed-up schedule on real data; on one
+# a rate of 0.1 is the peak of a warmed-up schedule on real data; on one
 # fixed random batch it overshoots for the first steps (seen at a small
 # size on the CPU), and this smoke asserts a falling loss
 RESNET_LR = 0.02
@@ -102,7 +102,7 @@ def cache_entries():
 
 
 def bert_program(batch_size, fleet_dp=False, **overrides):
-    """BERT-base pretraining step as bench.py drives it (bf16 AMP,
+    """BERT-base pretraining step as the benchmark's cell has it (bf16 AMP,
     AdamW); ``fleet_dp`` routes minimize through the fleet collective
     optimizer (c_allreduce_sum grads)."""
     from paddle_tpu.amp.static_amp import decorate

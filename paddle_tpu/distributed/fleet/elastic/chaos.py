@@ -2,11 +2,11 @@
 
 Recovery paths that are only exercised when real hardware dies are
 recovery paths that have silently rotted by the time they matter
-(BENCH r04/r05: the first genuine device loss produced 0.0 because
+(the first genuine device loss here produced a 0.0 round because
 nothing had ever rehearsed it).  This module keeps a small process-wide
 armory of *injectable* faults that the supervisor's hook points — and
 nothing else — consult, so every classified failure mode is driven
-continuously by tests and the ``bench.py`` chaos leg:
+continuously by tests (tests/test_elastic.py, tests/test_disagg.py):
 
 - ``kill_rank_mid_step``   (params ``rank``, ``at_step``): raises
   :class:`RankKilled` from the supervisor's step hook — the

@@ -2,7 +2,7 @@
 
 A chip belongs to one process at a time.  Every caller of the preflight
 (the elastic supervisor before each attempt, the disagg autoscaler from a
-live server, bench.py) either already holds the chip or is about to, so a
+live server) either already holds the chip or is about to, so a
 probe in a CHILD process could never load the TPU library and would read
 every healthy device as dead.  The probe therefore runs in the calling
 process, on a daemon thread under a deadline: a tiny jit dispatch

@@ -335,9 +335,9 @@ define_flag("elastic_max_restarts", 3,
             "restart budget — how many times ElasticSupervisor.run may "
             "restart (in place) or re-shard (after a dead rank) "
             "following a classified failure before raising a terminal "
-            "ElasticTerminated with the full restart history; bench.py "
-            "flagship rounds share the same budget for device-failure "
-            "retries")
+            "ElasticTerminated with the full restart history (the "
+            "terminal path: tests/test_elastic.py, "
+            "test_restart_budget_exhaustion_is_terminal_not_a_hang)")
 define_flag("elastic_preflight_timeout_s", 240.0,
             "deadline for ONE device preflight probe "
             "(fleet.elastic.preflight_device: a tiny jit dispatch on a "

@@ -7,8 +7,8 @@ binary click loss.  TPU-native: both tables are built
 ``is_sparse=True``, which under a tensor-parallel fleet program makes
 the ShardingPropagationPass row-shard them P('mp', None) and the
 lookup ride the distributed engine (ops/embedding_ops.py) — no
-parameter server.  Shared by tests/test_sharded_embedding.py,
-bench.py::bench_dlrm and the __graft_entry__ MULTICHIP embedding leg.
+parameter server.  tests/test_sharded_embedding.py trains it on a
+dp×mp mesh and holds the table to vocab/mp rows a chip.
 """
 from __future__ import annotations
 

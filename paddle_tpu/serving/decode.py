@@ -671,7 +671,7 @@ class DecodeEngine:
     consumer thread that runs admission -> prefill -> joint decode
     step, forever.  ``continuous=False`` degrades admission to the
     one-shot group mode (a new group only starts when EVERY slot is
-    free) — the static-batching baseline bench.py's A/B uses.
+    free) — the static-batching baseline (tests/test_decode_engine.py).
 
     What the engine reads off ``model`` is the whole contract
     (``serving/transformer_lm.py`` is the reference): ``num_layers``,

@@ -1307,7 +1307,7 @@ def test_decode_server_least_loaded_dispatch_and_stats(
 
 
 def test_one_shot_mode_vs_continuous_admission(model_and_weights):
-    """continuous=False degrades to group admission (the bench A/B
+    """continuous=False degrades to group admission (the static-batching
     baseline): a follow-up request cannot start until the WHOLE group
     finishes, while the continuous engine admits it mid-flight."""
     model, weights = model_and_weights

@@ -200,8 +200,7 @@ def test_mid_page_divergence_is_a_miss_and_stays_bitwise(
 # -- admission capacity: >= 2x at fixed pool size -------------------------
 
 
-@pytest.mark.slow  # wall-clock paced (sleep-held slots); the 2x ratio
-# is also enforced by bench.py's decode_shared_admission_capacity_ratio
+@pytest.mark.slow  # wall-clock paced (sleep-held slots)
 def test_shared_admission_capacity_at_least_doubles(model_and_weights):
     """The acceptance bar: at a FIXED pool size, prefix sharing must
     admit >= 2x the concurrent requests of the unshared engine.  Each

@@ -269,6 +269,37 @@ def test_autoscaler_rerole_cooldown_and_preflight(model_and_weights):
     assert all(not r.draining for r in srv.replicas)
 
 
+def test_autoscaler_reroles_on_the_burn_of_real_traffic(
+        model_and_weights):
+    """Nothing of the trigger is a stand-in but the bar: an objective no
+    request can meet makes every served request a violation, the
+    autoscaler's OWN burn signal (observe/slo.py's snapshot) fires, one
+    decode replica re-roles, and the cooldown drops the retrigger."""
+    from paddle_tpu.observe import slo
+
+    model, weights = model_and_weights
+    slo.configure([slo.Objective("ttft_p99", "ttft", 1e-6, 0.01)])
+    try:
+        srv = DisaggServer(
+            model, weights, config=_decode_cfg(),
+            disagg=DisaggConfig(prefill_replicas=1, decode_replicas=3,
+                                autoscale_cooldown_s=3600.0))
+        with srv:
+            for r in [srv.submit([9, 8, 7], max_new_tokens=4, seed=70 + i)
+                      for i in range(4)]:
+                r.result(timeout=120)
+            auto = Autoscaler(srv, queue_fn=lambda: 0.0,
+                              preflight=lambda: True)
+            skips0 = stat_get("autoscale_cooldown_skips_total")
+            assert auto.tick() == "decode->prefill"
+            assert _roles(srv) == ["prefill", "prefill", "decode",
+                                   "decode"]
+            assert auto.tick() is None
+            assert stat_get("autoscale_cooldown_skips_total") == skips0 + 1
+    finally:
+        slo.configure(None)
+
+
 def test_autoscaler_thread_lifecycle(model_and_weights):
     model, weights = model_and_weights
     srv = DisaggServer(

@@ -6,7 +6,7 @@ break_continue_transformer.py) — a dygraph function with python
 ``if``/``while``/``for`` over tensor values must export a static
 program whose cond/while OPS reproduce eager outputs on BOTH branches
 and at data-dependent trip counts, through TracedLayer and the
-inference Predictor (the VERDICT round-3 'done' criterion).
+inference Predictor.
 """
 import numpy as np
 import pytest
@@ -117,7 +117,7 @@ def test_bool_ops_and_not():
 
 
 def test_jit_save_load_predictor_roundtrip(tmp_path):
-    """The VERDICT criterion: data-dependent branch + loop export via
+    """The criterion: data-dependent branch + loop export via
     jit.save; the loaded Predictor reproduces eager on both branches."""
     from paddle_tpu.hapi.model import InputSpec
 

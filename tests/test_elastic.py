@@ -161,6 +161,26 @@ class TestSupervisor:
         bundles = os.listdir(tmp_path / "postmortem")
         assert any("elastic_topology_change" in b for b in bundles)
 
+    def test_preflight_timeout_and_rank_kill_in_one_run(self, tmp_path):
+        """The supervisor's own preflight (on by default, the real
+        in-process probe) with one injected init-timeout, then a rank
+        killed mid-step: the run retries the probe, re-shards, and ends
+        with every step's real number — not the 0.0 of a dead round."""
+        mgr = CheckpointManager(str(tmp_path / "c"), keep_n=0,
+                                async_save=False)
+        chaos.inject("preflight_init_timeout", count=1)
+        chaos.inject("kill_rank_mid_step", rank=1, at_step=4)
+        r = elastic.ElasticSupervisor(
+            world_size=2, preflight_attempts=2, preflight_timeout_s=60.0,
+            backoff_s=0.0).run(lambda topo: _Toy(), manager=mgr,
+                               loader=_BATCHES, total_steps=6)
+        mgr.close()
+        assert r.status == "recovered"
+        assert r.preflight_retries == 1
+        assert r.restarts == 1 and r.reshards == 1
+        assert r.final_world_size == 1 and r.losses == _CUMSUM
+        assert chaos.armed() == []
+
     def test_train_fn_sees_shrunken_topology(self, tmp_path):
         worlds = []
 

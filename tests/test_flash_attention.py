@@ -43,7 +43,9 @@ from paddle_tpu.ops import flash_attention as fa
 def _flag_reset():
     yield
     pt.set_flags({"FLAGS_flash_attention": "auto",
-                  "FLAGS_layer_scan": False})
+                  "FLAGS_layer_scan": False,
+                  "FLAGS_hbm_bytes_per_device": 0,
+                  "FLAGS_hbm_budget_fraction": 0.0})
 
 
 def _qkv(rs, B=1, H=2, S=256, D=64):
@@ -143,10 +145,11 @@ HID = HEADS * D
 
 
 def _attn_train_program(with_mask=True, dropout=0.0, learnable_mask=False,
-                        seed=11):
+                        seed=11, S=S, D=D):
     """A train program around the exact unfused chain static_models
     emits: qkv projections -> matmul(alpha) -> [mask add] -> softmax ->
     matmul -> out projection -> mse, SGD-with-momentum backward."""
+    HID = HEADS * D
     main, startup = Program(), Program()
     main.random_seed = seed
     with program_guard(main, startup):
@@ -186,7 +189,7 @@ def _attn_train_program(with_mask=True, dropout=0.0, learnable_mask=False,
     return main, startup, loss, probs.name
 
 
-def _feed(with_mask=True, n=4):
+def _feed(with_mask=True, n=4, S=S, HID=HID):
     rs = np.random.RandomState(0)
     fd = {"x": rs.randn(n, S, HID).astype("f4"),
           "y": rs.randn(n, S, HID).astype("f4")}
@@ -270,6 +273,60 @@ def test_executor_always_matches_never_bitwise():
     assert stat_get("pass_flash_attention_fused") >= 1
     assert stat_get("pass_flash_attention_grad_fused") >= 1
     np.testing.assert_array_equal(ref, got)
+
+
+def test_rewrite_keeps_the_contractions_flops():
+    """hapi/model_stat prices the fused op as the two contractions it
+    replaced, forward and backward, so an MFU read before and after the
+    rewrite has the same numerator but for the softmax it folds in (one
+    operation a score, where the contractions are 4 * D)."""
+    from paddle_tpu.hapi.model_stat import program_flops
+
+    main, _, loss, _ = _attn_train_program(with_mask=False)
+    total0, before = program_flops(main, detail=True)
+    pt.set_flags({"FLAGS_flash_attention": "always"})
+    assert passes_mod.FlashAttentionPass().apply(
+        main, passes_mod.PassContext(fetch_names=(loss.name,)))
+    total1, after = program_flops(main, detail=True)
+    assert after["flash_attention"] == before["matmul"] > 0
+    assert after["flash_attention_grad"] == before["matmul_grad"] > 0
+    assert total0 - total1 == before["softmax"]
+
+
+def test_budget_gate_refuses_the_unfused_chain_and_passes_the_fused(
+        monkeypatch):
+    """The memory claim as a refusal: at 1,024 keys, with the device's
+    capacity pinned to 0.6 of what the unfused step needs, the unfused
+    compile raises MemoryBudgetError before any dispatch and the
+    rewritten one (the kernels, in interpret mode) compiles and runs."""
+    from paddle_tpu.observe.xla_stats import MemoryBudgetError
+    from paddle_tpu.ops import fused
+
+    seq, d = 1024, 64
+    fd = _feed(with_mask=False, n=1, S=seq, HID=HEADS * d)
+
+    def step(mode, capacity=0):
+        pt.set_flags({"FLAGS_flash_attention": mode,
+                      "FLAGS_hbm_bytes_per_device": capacity,
+                      "FLAGS_hbm_budget_fraction": 1.0 if capacity else 0.0})
+        with unique_name.guard():
+            main, startup, loss, _ = _attn_train_program(
+                with_mask=False, S=seq, D=d)
+        out = _train(main, startup, loss, fd, steps=1)
+        return out, stat_get("hbm_required_bytes")
+
+    monkeypatch.setattr(fused, "_FORCE_INTERPRET", True)
+    ref, unfused_bytes = step("never")
+    if not unfused_bytes:
+        pytest.skip("this jax gives no memory_analysis")
+    capacity = int(0.6 * unfused_bytes)
+    with pytest.raises(MemoryBudgetError):
+        step("never", capacity)
+    engaged0 = stat_get("flash_attention_engaged")
+    got, fused_bytes = step("always", capacity)
+    assert stat_get("flash_attention_engaged") > engaged0
+    assert fused_bytes <= capacity
+    np.testing.assert_allclose(got, ref, rtol=0, atol=1e-4)
 
 
 def test_pass_refuses_dropout_on_probs():

@@ -2,13 +2,10 @@
 layers with a scalar decay a head and keys narrower than values, beside
 position-free softmax layers with QK-norm and one K/V head a query head)
 behind the real ``DecodeEngine``, against the plain reference
-(``tests/reference_gated_delta_lm.py``, a copy of
-``benchmark/reference/gated_delta_lm.py``): float32, seeded, tiny, with
-widths that keep ``dk != dv`` and neither a multiple of the other's
-tile."""
+(``benchmark/reference/gated_delta_lm.py``, the one the cell's check
+uses): float32, seeded, tiny, with widths that keep ``dk != dv`` and
+neither a multiple of the other's tile."""
 import functools
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -20,9 +17,7 @@ from paddle_tpu.ops import pallas_decode_attention as pda
 from paddle_tpu.serving import DecodeConfig, DecodeEngine, GatedDeltaLM
 from paddle_tpu.serving import gated_delta_lm as gdl
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-sys.path.insert(0, HERE)
-import reference_gated_delta_lm as ref  # noqa: E402
+from benchmark.reference import gated_delta_lm as ref
 
 PERIOD = ("recurrent", "recurrent", "recurrent", "attention")
 VOCAB = 97
@@ -431,10 +426,3 @@ def test_the_check_would_see_a_departure(departure):
     prompts = [np.random.RandomState(23).randint(0, VOCAB, 70).tolist()]
     with engine(model, weights) as eng:
         assert served_vs_reference(eng, model, weights, prompts) > 1e-2
-
-
-def test_the_two_copies_of_the_reference_are_one():
-    with open(os.path.join(HERE, "reference_gated_delta_lm.py")) as a, \
-            open(os.path.join(HERE, "..", "benchmark", "reference",
-                              "gated_delta_lm.py")) as b:
-        assert a.read() == b.read()

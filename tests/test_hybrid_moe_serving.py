@@ -1,10 +1,7 @@
 """The hybrid linear/softmax-attention model with a held share of its
 experts (``serving/hybrid_moe_lm.py``) behind the real ``DecodeEngine``,
-against the plain reference (``tests/reference_hybrid_moe_lm.py``, a copy
-of ``benchmark/reference/hybrid_moe_lm.py``): float32, seeded, tiny."""
-import os
-import sys
-
+against the plain reference (``benchmark/reference/hybrid_moe_lm.py``, the
+one the cell's check uses): float32, seeded, tiny."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,9 +14,7 @@ from paddle_tpu.serving import DecodeConfig, DecodeEngine
 from paddle_tpu.serving import decode as decode_mod
 from paddle_tpu.serving.hybrid_moe_lm import HybridMoELM
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-sys.path.insert(0, HERE)
-import reference_hybrid_moe_lm as ref  # noqa: E402
+from benchmark.reference import hybrid_moe_lm as ref
 
 PERIOD = ("attention", "recurrent", "recurrent", "recurrent")
 VOCAB = 97
@@ -461,10 +456,3 @@ def test_the_tallies_are_the_models_declared_names_before_any_trace():
     model.tallies = ("moe_experts_hit",)
     with pytest.raises(KeyError, match="moe_local_assignments"):
         engine(model, weights).lower_step()
-
-
-def test_the_two_copies_of_the_reference_are_one():
-    with open(os.path.join(HERE, "reference_hybrid_moe_lm.py")) as a, \
-            open(os.path.join(HERE, "..", "benchmark", "reference",
-                              "hybrid_moe_lm.py")) as b:
-        assert a.read() == b.read()

@@ -1,7 +1,7 @@
-"""Input-pipeline-inclusive training path (the bench.py pipeline mode):
-multiprocess DataLoader -> uint8 feed -> on-device normalize -> chunked
-run_steps.  Small shapes on CPU; the full-size numbers come from
-bench.py on the chip."""
+"""Input-pipeline-inclusive training path: multiprocess DataLoader ->
+uint8 feed -> on-device normalize -> chunked run_steps.  Small shapes on
+the CPU; at full size it is not measured on the chip (no cell feeds a
+trainer from a loader, ROADMAP W5)."""
 import numpy as np
 
 import paddle_tpu as pt

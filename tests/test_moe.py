@@ -7,12 +7,13 @@ partitioning config), capacity-factor drop accounting, plan-time
 rejection of ep-sharded consumers outside the routed-FFN family, the
 aux-loss gradient path, and the FLAGS_ep_degree mesh-carve validation.
 
-Slow-marked composition matrix, per the dist-test oracle discipline:
-ep×dp per-step loss parity <= 1e-4 vs the replicated single-device
-oracle (dense execution of the same routed FFN — matched activated
-FLOPs by construction), ep×mp×pp compile + collective-ledger keys, and
-elastic checkpoint resume across an ep 2->4 retag (bitwise on the
-surviving state).
+Composition matrix, per the dist-test oracle discipline: ep×dp
+per-step loss parity <= 1e-4 vs the replicated single-device oracle
+(dense execution of the same routed FFN — matched activated FLOPs by
+construction), the chunked all-to-all schedule bitwise the sequential
+one with the ledger's hidden all-to-alls, and (slow-marked) ep×mp×pp
+compile + collective-ledger keys and elastic checkpoint resume across
+an ep 2->4 retag (bitwise on the surviving state).
 """
 import numpy as np
 import pytest
@@ -251,7 +252,6 @@ class TestPlanTime:
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.slow
 class TestComposition:
     def test_ep_dp_parity_vs_replicated_oracle(self, mesh_dp_ep):
         """Per-step losses of the dp×ep run match the replicated
@@ -274,6 +274,49 @@ class TestComposition:
                         for s in w1.addressable_shards}
         assert shard_shapes == {(E // 2, DM, FFN)}
 
+    def test_chunked_alltoall_is_bitwise_the_sequential_schedule(
+            self, mesh_dp_ep):
+        """FLAGS_moe_alltoall_chunks slices the capacity axis (20 rows
+        here, 4 chunks) and combines once: the same program's losses are
+        the sequential schedule's bit for bit, the chunked lowering is
+        the one that ran, and the ledger hides all-to-alls under it that
+        the sequential schedule exposes."""
+        from paddle_tpu.distributed.parallel_env import set_mesh
+        from paddle_tpu.monitor import stat_get
+        from paddle_tpu.observe.phases import collective_inventory
+
+        X, Y = _data()
+        set_mesh(mesh_dp_ep)
+        losses = {}
+        try:
+            for chunks in (0, 4):
+                pt.set_flags({"FLAGS_moe_alltoall_chunks": chunks})
+                chunked0 = stat_get("moe_alltoall_chunked")
+                main, startup, loss = _build_moe(True)
+                losses[chunks], _, _ = _train(main, startup, loss, X, Y,
+                                              mesh_dp_ep)
+                assert (stat_get("moe_alltoall_chunked") > chunked0) \
+                    == bool(chunks)
+        finally:
+            pt.set_flags({"FLAGS_moe_alltoall_chunks": 0})
+        assert losses[4] == losses[0]
+
+        plan = passes_mod.apply_passes(
+            main, fetch_names=(loss.name,), feed_names=("x", "y"),
+            mesh=mesh_dp_ep)
+        blk = plan.global_block
+
+        def exposed_share(chunks):
+            a2a = [e for e in collective_inventory(
+                blk, list(blk.ops), mesh=mesh_dp_ep,
+                tp_plan=plan._tp_plan, moe_chunks=chunks)
+                if e["op"] == "ep_alltoall"]
+            return (sum(e["bytes"] for e in a2a if not e["overlap"])
+                    / sum(e["bytes"] for e in a2a))
+
+        assert exposed_share(4) < exposed_share(0) == 1.0
+
+    @pytest.mark.slow
     def test_ep_mp_pp_compile_and_ledger_keys(self):
         """The full ep×mp×pp composition compiles and trains (moe
         stage 0, Megatron ffn pair stage 1), and the collective ledger
@@ -351,6 +394,7 @@ class TestComposition:
         finally:
             reset_mesh()
 
+    @pytest.mark.slow
     def test_elastic_ckpt_resumes_across_ep_retag(self, tmp_path):
         """ep=2 state saves through the ckpt manager and restores into
         an ep=4 mesh bitwise (single-process: fully-addressable arrays

@@ -295,11 +295,10 @@ class TestAnchorsAndBuckets:
 
 
 # ---------------------------------------------------------------------------
-# composition matrix (compile-heavy: slow tier)
+# composition matrix (compile-heavy: slow tier, but for the mp×pp parity)
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.slow
 class TestComposedMesh:
     def test_mp_pp_parity_vs_replicated_oracle(self, mesh_pp2, _set_mesh):
         """mp×pp loss parity ≤1e-4 vs the same GPipe schedule with mp
@@ -335,6 +334,7 @@ class TestComposedMesh:
         assert len(per_dev) == 4
         assert all(s == (1, 1, buf.shape[-1]) for s in per_dev.values())
 
+    @pytest.mark.slow
     def test_dp_mp_pp_parity_with_dropout(self, _set_mesh):
         """Full 3-axis composition (2,2,2) vs the dp×pp oracle WITH
         dropout: identical micro-batching, identical per-(stage,
@@ -355,6 +355,7 @@ class TestComposedMesh:
                            X, Y, mesh_3d)
         np.testing.assert_allclose(got, base, rtol=1e-4, atol=1e-6)
 
+    @pytest.mark.slow
     def test_chunked_collective_matmul_pipeline(self, _set_mesh):
         """FLAGS_collective_matmul_chunks on the manual pipeline×mp
         path: per-chunk g-psum, numerics equal to the unchunked run."""
@@ -374,6 +375,7 @@ class TestComposedMesh:
         assert stat_get("collective_matmul_chunked") > 0
         np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-7)
 
+    @pytest.mark.slow
     def test_chunked_collective_matmul_gspmd_mp_only(self, _set_mesh):
         """GSPMD path: chunking engages on an mp-only mesh (exact vs
         unchunked); a mesh with a live dp axis falls back LOUDLY — the
@@ -571,7 +573,6 @@ class TestElasticCkptAcrossPPDegree:
         np.testing.assert_allclose(got, base, rtol=1e-4, atol=1e-6)
 
 
-@pytest.mark.slow
 class TestStretchedBucketE2E:
     def test_stretched_bucket_numerics_bitwise_vs_unfused(self):
         """A layer-scanned dp program whose stacked grad carriers AND

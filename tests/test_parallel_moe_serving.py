@@ -1,12 +1,9 @@
 """The parallel-block model with window and position-free global layers,
 a held share of its experts, averaged shared experts and a tied head
 (``serving/parallel_moe_lm.py``) behind the real ``DecodeEngine``,
-against the plain reference (``tests/reference_parallel_moe_lm.py``, a
-copy of ``benchmark/reference/parallel_moe_lm.py``): float32, seeded,
-tiny."""
+against the plain reference (``benchmark/reference/parallel_moe_lm.py``,
+the one the cell's check uses): float32, seeded, tiny."""
 import math
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -20,9 +17,7 @@ from paddle_tpu.serving import DecodeConfig, DecodeEngine
 from paddle_tpu.serving.hybrid_moe_lm import rms_norm
 from paddle_tpu.serving.parallel_moe_lm import ParallelMoELM
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-sys.path.insert(0, HERE)
-import reference_parallel_moe_lm as ref  # noqa: E402
+from benchmark.reference import parallel_moe_lm as ref
 
 # Command A+'s period in small: three window layers to a global one
 PERIOD = ("window", "window", "window", "attention")
@@ -418,10 +413,3 @@ def test_what_cannot_hold_over_a_ring_refuses():
         engine(model, weights, prefill_chunk_pages=1)
     with pytest.raises(ValueError, match="window layers.*speculative"):
         engine(model, weights, spec_k=2)
-
-
-def test_the_two_copies_of_the_reference_are_one():
-    with open(os.path.join(HERE, "reference_parallel_moe_lm.py")) as a, \
-            open(os.path.join(HERE, "..", "benchmark", "reference",
-                              "parallel_moe_lm.py")) as b:
-        assert a.read() == b.read()

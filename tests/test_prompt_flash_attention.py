@@ -152,7 +152,7 @@ def test_the_engine_counts_the_walk_of_the_form_that_runs(monkeypatch):
     prompt of 150 in a bucket of 256: with the kernel asked for, the
     counters hold its visited blocks and the layer-calls by form, and
     the logits are the reference's."""
-    import reference_parallel_moe_lm as ref
+    from benchmark.reference import parallel_moe_lm as ref
     from test_parallel_moe_serving import dims, engine, make_model
 
     monkeypatch.setattr(ppa, "_MAX_ROWS", 64)

@@ -319,9 +319,8 @@ def test_request_trace_overhead_ratio_below_5pct(model_and_weights):
     best-of-4 per mode (alternating runs cancel host drift): recording
     must cost <= 5%.  GC is quiesced during measurement — mid-suite,
     collection pauses over earlier tests' dead device pools dwarf the
-    ~µs/event recording cost being measured (the same effect bench.py
-    guards its seqlen8x ratio against) — and a failing attempt is
-    re-measured up to twice before it counts."""
+    ~µs/event recording cost being measured — and a failing attempt
+    is re-measured up to twice before it counts."""
     import gc
 
     eng = make_engine(model_and_weights, slots=1, max_seq_len=128,

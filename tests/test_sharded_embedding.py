@@ -423,7 +423,7 @@ class TestCheckpoint:
 
 
 # ---------------------------------------------------------------------------
-# slow composition matrix: dp×mp parity+budget, mp×pp, elastic mp retag
+# composition matrix: dp×mp parity+budget; slow-marked: mp×pp, elastic mp retag
 # ---------------------------------------------------------------------------
 
 # the "one simulated chip" of the acceptance: both replicated tables
@@ -433,7 +433,6 @@ BIG = dict(vocab_size=4096, emb_dim=16, n_fields=8, batch_size=16,
            n_dense=4, hidden=(32,), padding_idx=0)
 
 
-@pytest.mark.slow
 class TestComposition:
     def test_dp_mp_parity_and_chip_budget(self, mesh_dp_mp,
                                           restore_flags_budget):
@@ -488,6 +487,7 @@ class TestComposition:
         assert stats.get("emb_rows_per_shard") == \
             BIG["vocab_size"] // 4
 
+    @pytest.mark.slow
     def test_pipeline_mp_composed_parity(self):
         """mp×pp: the embedding rides the EXPLICIT all-to-all engine
         inside the per-stage shard_map; parity vs the pp-only
@@ -566,6 +566,7 @@ class TestComposition:
         np.testing.assert_allclose(got, base, rtol=1e-4, atol=1e-6)
         assert _sg("emb_alltoall_bytes") > 0  # explicit engine engaged
 
+    @pytest.mark.slow
     def test_elastic_resume_mp4_to_mp2(self):
         """Elastic retag mp 4 -> 2: the checkpointed table restores
         BITWISE onto the new topology (placed as vocab/2 row shards)

@@ -548,17 +548,60 @@ class TestMetaOptimizerComposition:
                     scope=scope)
 
 
+def _small_bert(use_fleet_tp=False):
+    """(main, startup, loss, feed) for a small BERT-style pretraining
+    step; with ``use_fleet_tp`` the program is built through
+    fleet.distributed_optimizer with strategy.tensor_parallel (the
+    default Megatron rules match the enc_*_{q,k,v,out}/ffn1/ffn2 +
+    word_embedding naming)."""
+    from paddle_tpu.text import bert_base_pretrain_program
+
+    B, S, V, P = 16, 32, 512, 4
+    with unique_name.guard():  # repeat builds keep .w_0 param names
+        main_p, startup, _, loss, opt = bert_base_pretrain_program(
+            batch_size=B, seq_len=S, vocab_size=V, hidden=64,
+            n_layers=2, n_heads=4, ffn_size=128, max_preds_per_seq=P)
+    main_p.random_seed = 1
+    with unique_name.guard(), program_guard(main_p, startup):
+        if use_fleet_tp:
+            from paddle_tpu.distributed import fleet
+
+            strat = fleet.DistributedStrategy()
+            strat.tensor_parallel = True
+            fleet.init(is_collective=True, strategy=strat)
+            fleet.distributed_optimizer(opt)
+            fleet.minimize(loss)
+        else:
+            opt.minimize(loss)
+
+    rng = np.random.RandomState(0)
+    ids = rng.randint(0, V, (B, S)).astype("int64")
+    flat_pos = np.concatenate(
+        [b * S + rng.choice(S, P, replace=False) for b in range(B)]
+    ).astype("int64")
+    labels = ids.reshape(-1)[flat_pos].reshape(-1, 1).astype("int64")
+    feed = {
+        "input_ids": ids,
+        "token_type_ids": np.zeros((B, S), "int64"),
+        "pos_ids": np.tile(np.arange(S, dtype="int64"), (B, 1)),
+        "input_mask": np.zeros((B, 1, 1, S), "float32"),
+        "masked_flat_pos": flat_pos,
+        "masked_labels": labels,
+        "masked_weights": np.ones((B * P, 1), "float32"),
+        "nsp_labels": rng.randint(0, 2, (B, 1)).astype("int64"),
+    }
+    return main_p, startup, loss, feed
+
+
 class TestBertStyleTP:
     def test_bert_default_rules_parity_and_sharding(self, mesh_dp_mp):
         """BERT-style model under the DEFAULT Megatron rules: loss
         parity vs the replicated oracle, QKV/FFN weights and their Adam
         moments mp-sharded, vocab-parallel embedding."""
-        import bench
-
         from paddle_tpu.distributed.parallel_env import reset_mesh, set_mesh
 
         reset_mesh()
-        m0, s0, l0, feed = bench._small_bert(pt)
+        m0, s0, l0, feed = _small_bert()
         sc0 = pt.framework.Scope()
         e0 = pt.Executor(pt.CPUPlace())
         e0.run(s0, scope=sc0)
@@ -567,7 +610,7 @@ class TestBertStyleTP:
             for _ in range(3)]
 
         set_mesh(mesh_dp_mp)
-        m1, s1, l1, feed1 = bench._small_bert(pt, use_fleet_tp=True)
+        m1, s1, l1, feed1 = _small_bert(use_fleet_tp=True)
         sc1 = pt.framework.Scope()
         e1 = pt.Executor(pt.CPUPlace(), mesh=mesh_dp_mp)
         e1.run(s1, scope=sc1)

@@ -5,8 +5,11 @@ Reference parity: tools/print_signatures.py + check_api_approvals.sh
 op_version_registry (removing an op breaks saved programs).
 """
 import os
+import re
 import subprocess
 import sys
+
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -41,96 +44,34 @@ def test_op_spec_counts_grads():
 
 
 # ---------------------------------------------------------------------------
-# tools/bench_diff.py against round records of the driver's shape
+# the documents name files that exist
 # ---------------------------------------------------------------------------
 
-
-def _round(path, n, parsed):
-    """One bench-round record as the driver used to keep them: a wrapper
-    (round number, command, exit code, output tail) around the parsed
-    JSON line of bench.py."""
-    import json
-
-    path.write_text(json.dumps({
-        "n": n, "cmd": "python bench.py", "rc": 0,
-        "tail": json.dumps(parsed) + "\n", "parsed": parsed}))
-    return str(path)
+_PATH_TOKEN = re.compile(r"^([\w./\-]+\.(?:py|json|md|sh))(?:[:,.;)].*)?$")
 
 
-_METRIC = {"metric": "resnet50_bf16_images_per_sec",
-           "unit": "images/sec/chip"}
-
-
-def _bench_diff(*args):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    return subprocess.run(
-        [sys.executable, "-m", "tools.bench_diff", *args],
-        capture_output=True, text=True, env=env, cwd=ROOT)
-
-
-def test_bench_diff_clean_rounds_improvement(tmp_path):
-    """A clean improving pair of wrapped rounds: no regression, exit 0,
-    and the improvement is flagged."""
-    a = _round(tmp_path / "r02.json", 2,
-               dict(_METRIC, value=1889.2, vs_baseline=0.724))
-    b = _round(tmp_path / "r03.json", 3,
-               dict(_METRIC, value=2686.7, vs_baseline=1.029,
-                    resnet50_images_per_sec=2686.7,
-                    bert_base_tokens_per_sec=155564.6))
-    p = _bench_diff(a, b)
-    assert p.returncode == 0, p.stderr
-    assert "no regressions past threshold" in p.stdout
-    assert "improved" in p.stdout
-    assert "caveat" not in p.stdout
-
-
-def test_bench_diff_broken_round_is_advisory_not_a_failure(tmp_path):
-    """A dead-device round (preflight timeout, every metric zeroed, an
-    ``error`` key): the -100% 'regression' must be downgraded to
-    advisory — exit 0 — with the caveat printed."""
-    a = _round(tmp_path / "r03.json", 3,
-               dict(_METRIC, value=2686.7, vs_baseline=1.029))
-    b = _round(tmp_path / "r05.json", 5,
-               dict(_METRIC, value=0.0, vs_baseline=0.0,
-                    error="device preflight failed: device init did not "
-                          "complete within 240s"))
-    p = _bench_diff(a, b)
-    assert p.returncode == 0, p.stderr
-    assert "caveat [B]" in p.stdout
-    assert "ADVISORY" in p.stdout
-
-
-def test_bench_diff_real_regression_fails(tmp_path):
-    import json
-
-    a = tmp_path / "a.json"
-    b = tmp_path / "b.json"
-    a.write_text(json.dumps({"resnet50_images_per_sec": 1000.0,
-                             "vs_baseline": 1.0, "status": "ok"}))
-    b.write_text(json.dumps({"resnet50_images_per_sec": 800.0,
-                             "vs_baseline": 0.8, "status": "ok"}))
-    p = _bench_diff(str(a), str(b))
-    assert p.returncode == 1, p.stdout
-    assert "REGRESSION" in p.stdout
-    # json mode carries the same verdict for machines
-    pj = _bench_diff(str(a), str(b), "--json")
-    doc = json.loads(pj.stdout)
-    assert doc["advisory"] is False
-    assert "resnet50_images_per_sec" in doc["regressions"]
-
-
-def test_bench_diff_threshold_is_respected(tmp_path):
-    import json
-
-    a = tmp_path / "a.json"
-    b = tmp_path / "b.json"
-    a.write_text(json.dumps({"bert_base_tokens_per_sec": 100.0}))
-    b.write_text(json.dumps({"bert_base_tokens_per_sec": 93.0}))
-    assert _bench_diff(str(a), str(b), "--threshold",
-                       "0.10").returncode == 0
-    assert _bench_diff(str(a), str(b), "--threshold",
-                       "0.05").returncode == 1
+@pytest.mark.parametrize("doc", [
+    "README.md", "COVERAGE.md", "PERF.md", "METRICS.md", "BASELINE.md",
+    ".claude/skills/verify/SKILL.md"])
+def test_every_path_a_document_names_exists(doc):
+    """A backticked word that looks like a file of this tree (`*.py`,
+    `*.json`, `*.md`, `*.sh`) resolves under the root, `paddle_tpu/`,
+    `benchmark/` or `tests/`, so a deleted file cannot live on in the
+    manual.  Not judged: an absolute path, and a word with a placeholder
+    in it (`<bundle>/requests.json`, `<train.py>`).  A path of another
+    tree (the reference's, a model hub's) goes without backticks.
+    ROADMAP.md and CHANGES.md are history and are not read."""
+    with open(os.path.join(ROOT, doc)) as f:
+        fences = re.split(r"```", f.read())   # odd pieces lie inside a fence
+    spans = fences[1::2] + re.findall(r"`([^`]+)`", " ".join(fences[0::2]))
+    words = (w.strip("()[],;\"'") for span in spans for w in span.split())
+    named = {m.group(1) for m in map(_PATH_TOKEN.match, words)
+             if m and not m.group(1).startswith("/")}
+    missing = sorted(
+        p for p in named if not any(
+            os.path.exists(os.path.join(ROOT, base, p))
+            for base in ("", "paddle_tpu", "benchmark", "tests")))
+    assert not missing, f"{doc} names files that do not exist: {missing}"
 
 
 # ---------------------------------------------------------------------------
@@ -298,8 +239,6 @@ def test_import_initialises_no_backend_and_creates_nothing(tmp_path):
 def test_launcher_refuses_several_trainers_on_a_tpu_host(monkeypatch):
     """One process drives all local chips: N trainers that would each
     open every chip are refused with the reason, not left to hang."""
-    import pytest
-
     from paddle_tpu.distributed import launch
 
     monkeypatch.setattr(launch, "tpu_present", lambda: True)
@@ -309,13 +248,3 @@ def test_launcher_refuses_several_trainers_on_a_tpu_host(monkeypatch):
     monkeypatch.undo()
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     assert launch.tpu_present() is False
-
-
-def test_bench_refuses_to_measure_without_a_tpu():
-    """bench.py on a process that selected the CPU: non-zero exit, no
-    JSON record on stdout - a CPU number is never a device number."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
-    p = subprocess.run([sys.executable, "bench.py"], env=env, cwd=ROOT,
-                       capture_output=True, text=True, timeout=300)
-    assert p.returncode != 0
-    assert "needs a TPU" in p.stderr and "{" not in p.stdout

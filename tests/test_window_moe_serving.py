@@ -1,10 +1,7 @@
 """The window/global-attention model with a held share of its experts
 (``serving/window_moe_lm.py``) behind the real ``DecodeEngine``, against
-the plain reference (``tests/reference_window_moe_lm.py``, a copy of
-``benchmark/reference/window_moe_lm.py``): float32, seeded, tiny."""
-import os
-import sys
-
+the plain reference (``benchmark/reference/window_moe_lm.py``, the one the
+cell's check uses): float32, seeded, tiny."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,9 +13,7 @@ from paddle_tpu.ops import pallas_decode_attention as pda
 from paddle_tpu.serving import DecodeConfig, DecodeEngine, kv_cache
 from paddle_tpu.serving.window_moe_lm import WindowMoELM
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-sys.path.insert(0, HERE)
-import reference_window_moe_lm as ref  # noqa: E402
+from benchmark.reference import window_moe_lm as ref
 
 # MiMo-V2.5's first layers in small: a leading dense layer that attends
 # everything, window layers, a second global layer
@@ -408,10 +403,3 @@ def test_every_request_is_admitted_fresh_and_the_ring_costs_no_upload():
     assert d["decode_window_blocks_walked"] == 10
     assert 0 < d["moe_experts_hit"] <= 10 * 4 * 5
     assert stat_get("decode_window_bytes") == eng._cache.window_bytes()
-
-
-def test_the_two_copies_of_the_reference_are_one():
-    with open(os.path.join(HERE, "reference_window_moe_lm.py")) as a, \
-            open(os.path.join(HERE, "..", "benchmark", "reference",
-                              "window_moe_lm.py")) as b:
-        assert a.read() == b.read()
