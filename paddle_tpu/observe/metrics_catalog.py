@@ -273,7 +273,9 @@ RULES = (
          "Admissions of an engine whose prefix cache was asked for and "
          "left out because the model keeps recurrent state or a ring of "
          "a window layer's last positions (a shared page of keys says "
-         "nothing about either): every request admitted fresh"),
+         "nothing about either), or a latent page (what would read a "
+         "hit's suffix through the pages is not built for its rows): "
+         "every request admitted fresh"),
     Rule("decode_window_pages_", "gauge", "serving",
          "The rings of a model's window layers (a slot keeps ceil(window "
          "/ page) + 1 pages a layer, however long its request), over all "
@@ -302,6 +304,23 @@ RULES = (
     Rule("decode_window_bytes", "gauge", "serving",
          "Device bytes of the window layers' ring pools, all slots: no "
          "term in max_seq_len; 0 for a model without window layers"),
+    Rule("decode_latent_bytes", "gauge", "serving",
+         "Device bytes of the pool of a model with latent attention: ONE "
+         "row a position a layer for keys and values, at whole lane "
+         "tiles, every page of every layer; 0 for a model that caches K "
+         "and V"),
+    Rule("decode_latent_positions_live", "gauge", "serving",
+         "Cached rows the live slots' tokens attend a layer in a model "
+         "with latent attention (a slot's length, the token itself "
+         "counted), added once a joint decode step from the lengths the "
+         "engine holds: times the row's published bytes and the layers "
+         "it is what the latent kernel has to read"),
+    Rule("decode_latent_blocks_walked", "gauge", "serving",
+         "Blocks of page-table entries the latent kernel's loop runs a "
+         "layer (those holding a position a live slot attends), added "
+         "once a joint decode step; `decode_latent_positions_live` over "
+         "it x a block's positions is the share of what it copies that "
+         "is attended"),
     Rule("decode_attn_feed_bits", "gauge", "serving",
          "Width of the K and V operands the paged attention kernel's "
          "matmuls take, read from the pools' dtype when the engine "

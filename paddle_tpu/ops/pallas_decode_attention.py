@@ -123,6 +123,21 @@ starts from ``m = sink, l = 1, acc = 0`` instead of ``-inf, 0, 0``, so
 it costs no pass.  The window call has its own name
 (``WINDOW_KERNEL_NAME``), so a trace tells the two kinds of layer apart.
 
+**One pool, read once, used twice** (``v_pages=None``, ``value_lanes``).
+A latent cache (`serving/kv_cache.py`) keeps ONE row a position, shared
+by every query head: the scores read the whole row and the values are
+its first ``value_lanes`` lanes.  The query heads then ride as rows of
+that one head (64 of them make a stack of 64 rows, whatever
+``_MAX_SPLIT_ROWS`` says: one head cannot be cut), a block is copied
+from HBM once and meets both matmuls from the one buffer, and the query
+is padded with zeros to the pool's row (whole lane tiles, where the
+model's row is not).  At 2 x 64 x (576 + 512) FLOP a 1,152-byte row the
+body is near the chip's ridge, so the float32 side rides as
+``_LATENT_TERMS`` bfloat16 terms and not three (2.35 ms a layer's call
+against 1.44 at the cell's rows), and a block is ``_LATENT_BLOCK``
+positions and not one lane tile of scores.  The call has its own name
+(``LATENT_KERNEL_NAME``).
+
 ``paged_chunk_attention`` is the kernel itself: R query rows per slot
 with per-row causal lengths over one shared page table — the attention
 shape of chunked/suffix prefill and speculative verification
@@ -147,6 +162,7 @@ _LANES = 128  # TPU vector lane width; row stats broadcast across lanes
 # tpu_custom_call), so it can be told apart from any other kernel
 KERNEL_NAME = "paged_attention"
 WINDOW_KERNEL_NAME = "paged_attention_window"    # the call with a window
+LATENT_KERNEL_NAME = "paged_attention_latent"    # one pool, values in keys
 
 
 def decode_attention_reference(q, k, v, lengths, *, sm_scale=None,
@@ -330,7 +346,8 @@ def _gather_dequant(pages, scales, layer, page_table, num_heads):
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            layer=0, sm_scale=None, use_pallas="auto",
                            interpret=False, k_scales=None,
-                           v_scales=None, window=None, sinks=None):
+                           v_scales=None, window=None, sinks=None,
+                           value_lanes=None):
     """Decode attention straight off the page pool.
 
     q [S,H,D]; k/v_pages [L,P,page,H*D] (the stacked pools; ``layer``
@@ -352,7 +369,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         q[:, None], k_pages, v_pages, page_table, lengths[:, None],
         layer=layer, sm_scale=sm_scale, use_pallas=use_pallas,
         interpret=interpret, k_scales=k_scales, v_scales=v_scales,
-        window=window, sinks=sinks)[:, 0]
+        window=window, sinks=sinks, value_lanes=value_lanes)[:, 0]
 
 
 # -- the kernel: R query rows per slot (decode is R=1) --------------------
@@ -365,6 +382,15 @@ _MAX_SPLIT_ROWS = 16
 # working tiles (float32 copies of a stack's K and V lanes where the
 # feed is float32; a fraction of the 16 MB the chip scopes to a kernel)
 _BLOCK_VMEM_BYTES = 4 << 20
+# positions a block of the latent body holds.  Its two matmuls are most
+# of its time (near the ridge), and a block of one lane tile of scores
+# gives the MXUs ONE chain of products to run and pays the block's fixed
+# work (64 copies started, a wait, the accumulator's rescale) every 128
+# positions.  Measured on the v5e at the cell's rows (64 slots of 7.1-10.2k
+# positions, ms a layer's call; the rows' bytes alone take 0.88): blocks
+# of 128 / 256 / 512 / 1,024 / 2,048 positions read 3.39 / 2.24 / 1.67 /
+# 1.44 / 1.39; both buffers of 1,024 fit ``_BLOCK_VMEM_BYTES``
+_LATENT_BLOCK = 1024
 
 
 def _stack_heads(num_heads, head_dim, n_rows, v_dim=None,
@@ -396,10 +422,12 @@ def pages_per_block(page, pps, row_lanes, itemsize, v_lanes=None):
     holds, never less than 1 (a page of 128 positions or more is a
     block by itself).  ``pps`` need not be a multiple: the last block's
     missing entries are dead like any other.  ``v_lanes``: the V rows'
-    width where it is not K's ``row_lanes``."""
-    by_tile = _LANES // page
-    by_vmem = _BLOCK_VMEM_BYTES // (
-        2 * page * (row_lanes + (v_lanes or row_lanes)) * itemsize)
+    width where it is not K's ``row_lanes``; 0 for a latent pool (no V
+    pool: a block of ``_LATENT_BLOCK`` positions, the module header)."""
+    latent = v_lanes == 0       # one pool: the values are K's own lanes
+    by_tile = (_LATENT_BLOCK if latent else _LANES) // page
+    by_vmem = _BLOCK_VMEM_BYTES // (2 * page * (
+        row_lanes + (0 if latent else v_lanes or row_lanes)) * itemsize)
     return max(1, min(by_tile, by_vmem, pps))
 
 
@@ -413,14 +441,18 @@ def feed_bits(pool_dtype):
 
 
 _SPLIT_TERMS = 3    # bfloat16 terms that carry a float32's 24 bits
+# ... and what the latent body's query and probabilities ride as: one
+# rounding to bfloat16, as every other activation meets a bfloat16 weight
+_LATENT_TERMS = 1
 _BF16_ROWS = 16     # sublanes of one packed bfloat16 tile
 
 
-def _bf16_terms(x):
-    """Float32 ``x`` as ``_SPLIT_TERMS`` bfloat16 arrays, largest first,
-    whose sum is ``x``: each term rounds what the ones before it left."""
+def _bf16_terms(x, n=_SPLIT_TERMS):
+    """Float32 ``x`` as ``n`` bfloat16 arrays, largest first, whose sum
+    is ``x`` (to bfloat16's rounding of the last): each term rounds what
+    the ones before it left."""
     terms = []
-    for _ in range(_SPLIT_TERMS):
+    for _ in range(n):
         terms.append(x.astype(jnp.bfloat16))
         x = x - terms[-1].astype(jnp.float32)
     return terms
@@ -432,12 +464,12 @@ def _split_rows(rows):
     return -(-rows // _BF16_ROWS) * _BF16_ROWS
 
 
-def _join_terms(x, rows):
+def _join_terms(x, rows, n=_SPLIT_TERMS):
     """The float32 product of a left operand stacked by ``_bf16_terms``:
-    its row groups added, smallest first."""
+    its ``n`` row groups added, smallest first."""
     step = _split_rows(rows)
     out = None
-    for t in reversed(range(_SPLIT_TERMS)):
+    for t in reversed(range(n)):
         group = x[t * step:t * step + rows]
         out = group if out is None else group + out
     return out
@@ -445,7 +477,8 @@ def _join_terms(x, rows):
 
 def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
                   page, pps, ppb, n_slots, n_rows, head_dim, v_dim=None,
-                  quantized=False, window=None, sinks=False):
+                  quantized=False, window=None, sinks=False, latent=False,
+                  terms=_SPLIT_TERMS):
     """One grid step is one SLOT: R query rows (a prefill chunk, a
     speculative t0+draft window, or decode's one) over the slot's live
     blocks of ``ppb`` page-table entries.  Row r of slot s attends
@@ -470,22 +503,32 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
     whole packed tiles, hb*Dk) in bfloat16, and two more scratch follow:
     the float32 rows the query is stacked in before it is split, and
     each stack's probabilities as groups of rows, (n_stacks, 3 * hb*R
-    ..., ppb*page) in bfloat16."""
+    ..., ppb*page) in bfloat16.  ``latent``: there is no V pool and no V
+    buffer, the values are the leading ``v_dim`` lanes of the K buffer's
+    rows; ``terms``: how many bfloat16 terms the float32 side rides as."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if window is not None:
         slot_lo_ref, *rest = rest
-    q_ref, k_hbm, v_hbm, *rest = rest
+    if latent:
+        q_ref, k_hbm, *rest = rest
+    else:
+        q_ref, k_hbm, v_hbm, *rest = rest
     if quantized:
         ks_ref, vs_ref, *rest = rest
     if sinks:
         sink_ref, *rest = rest
-    o_ref, qbd_scr, m_scr, l_scr, acc_scr, k_buf, v_buf, sem, cur, \
-        *split = rest
+    o_ref, qbd_scr, m_scr, l_scr, acc_scr, k_buf, *rest = rest
+    if latent:
+        # one copy of a block serves the scores and the values
+        v_buf, pools = k_buf, ((k_hbm, k_buf),)
+    else:
+        v_buf, *rest = rest
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+    sem, cur, *split = rest
     if split:                           # the bfloat16 feed's scratch
         stage_scr, p_scr = split
-    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
 
     s_idx = pl.program_id(0)
     layer = layer_ref[0]
@@ -579,7 +622,7 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
             for h in range(hb):
                 stage_scr[h * n_rows:(h + 1) * n_rows, :] = jnp.where(
                     lane_head == h, qj, 0.0)
-            for t, term in enumerate(_bf16_terms(stage_scr[...])):
+            for t, term in enumerate(_bf16_terms(stage_scr[...], terms)):
                 qbd_scr[j, t * group:t * group + rows, :] = term
             continue
         for h in range(hb):
@@ -645,7 +688,7 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
         # the split query's three groups of rows meet ONE load of a tile
         s = [jnp.where(live, _join_terms(lax.dot_general(
             qbd_scr[j], k[j], nt, preferred_element_type=jnp.float32),
-            rows), _NEG_INF) for j in stacks]
+            rows, terms), _NEG_INF) for j in stacks]
         m_new = [jnp.maximum(m_prev[j], jnp.max(s[j], axis=1, keepdims=True))
                  for j in stacks]
         alpha = [jnp.exp(m_prev[j] - m_new[j]) for j in stacks]
@@ -653,11 +696,11 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
         l_new = [alpha[j] * l_prev[j] + jnp.sum(p[j], axis=1, keepdims=True)
                  for j in stacks]
         for j in stacks:
-            for t, term in enumerate(_bf16_terms(p[j])):
+            for t, term in enumerate(_bf16_terms(p[j], terms)):
                 p_scr[j, t * group:t * group + rows, :] = term
         pv = [_join_terms(lax.dot_general(
-            p_scr[j], v[j], nn, preferred_element_type=jnp.float32), rows)
-            for j in stacks]
+            p_scr[j], v[j], nn, preferred_element_type=jnp.float32), rows,
+            terms) for j in stacks]
         for j in stacks:
             acc_scr[j] = acc_scr[j] * alpha[j] + pv[j]
             m_scr[j] = jnp.broadcast_to(m_new[j], m_scr.shape[1:])
@@ -753,22 +796,35 @@ def _scale_lanes(scale_ref, at, stack, hb, lane_head):
     return out
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "interpret", "window"))
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "interpret", "window", "value_lanes"))
 def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
                 k_scales=None, v_scales=None, sinks=None, *, sm_scale,
-                interpret, window=None):
+                interpret, window=None, value_lanes=None):
     """The ``pallas_call``.  ``layer`` is an OPERAND (int32 scalar), so
     a model's layers share one traced and lowered kernel: a program
     pays for the body once, not once a layer.  ``sinks`` [R, H]: the
-    logit of each query row and head."""
+    logit of each query row and head.  ``v_pages=None``: the values are
+    the first ``value_lanes`` lanes of K's rows (one head; the module
+    header)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n_slots, n_rows, h, d = q.shape
     pps = page_table.shape[1]
     page, hd = k_pages.shape[2:]
-    v_hd = v_pages.shape[3]
+    latent = v_pages is None
+    if latent:
+        if h != 1 or d > hd or not value_lanes or value_lanes > hd:
+            raise ValueError(
+                f"a latent pool's rows ({hd} lanes) are one head's: q has "
+                f"{h} heads of {d}, the values {value_lanes} lanes")
+        # the query meets the pool's row: zeros where the model's row
+        # stops short of whole lane tiles
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, hd - d),))
+        d, v_hd = hd, value_lanes
+    else:
+        v_hd = v_pages.shape[3]
     if hd != h * d or v_hd % h:
         raise ValueError(
             f"pool rows are {hd} (K) and {v_hd} (V) lanes wide but q has "
@@ -776,11 +832,14 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
     dv = v_hd // h
     # bfloat16 blocks go to the matmuls as they lie in the pool, and the
     # float32 side rides as three groups of bfloat16 rows
-    split = feed_bits(k_pages.dtype) == feed_bits(v_pages.dtype) == 16
+    split = feed_bits(k_pages.dtype) == 16 and (
+        latent or feed_bits(v_pages.dtype) == 16)
+    terms = _LATENT_TERMS if latent else _SPLIT_TERMS
     hb = _stack_heads(h, d, n_rows, dv,
                       _MAX_SPLIT_ROWS if split else _MAX_STACK_ROWS)
     n_stacks, rows, width, v_width = h // hb, hb * n_rows, hb * d, hb * dv
-    ppb = pages_per_block(page, pps, hd, k_pages.dtype.itemsize, v_hd)
+    ppb = pages_per_block(page, pps, hd, k_pages.dtype.itemsize,
+                          0 if latent else v_hd)
     quantized = k_scales is not None
     row_lengths = row_lengths.astype(jnp.int32)
     prefetch = [layer.reshape(1), page_table.reshape(-1).astype(jnp.int32),
@@ -795,8 +854,10 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
     # the stacked pools stay in HBM; the kernel copies the live pages
     # of one layer itself, so nothing else of a pool ever moves
     pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
-    in_specs = [slot_block(n_rows, hd), pool_spec, pool_spec]
-    operands = [q.reshape(n_slots, n_rows, hd), k_pages, v_pages]
+    in_specs = [slot_block(n_rows, hd), pool_spec] \
+        + [pool_spec] * (not latent)
+    operands = [q.reshape(n_slots, n_rows, hd), k_pages] \
+        + [v_pages] * (not latent)
     if quantized:
         # the chip's compiler cuts no page out of a plane H lanes wide
         # in HBM, so a slot's scales are gathered by its table out here
@@ -814,7 +875,7 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
         operands.append(jnp.broadcast_to(
             sinks.astype(jnp.float32).T.reshape(n_stacks, rows, 1),
             (n_stacks, rows, _LANES)))
-    stacked = _SPLIT_TERMS * _split_rows(rows)  # rows of a split operand
+    stacked = terms * _split_rows(rows)  # rows of a split operand
     query_scratch = pltpu.VMEM((n_stacks, stacked, width), jnp.bfloat16) \
         if split else pltpu.VMEM((n_stacks, rows, width), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -830,7 +891,9 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
             pltpu.VMEM((n_stacks, rows, _LANES), jnp.float32),  # denom
             pltpu.VMEM((n_stacks, rows, v_width), jnp.float32),  # acc
             pltpu.VMEM((2, ppb, page, hd), k_pages.dtype),      # K blocks
+        ] + ([] if latent else [
             pltpu.VMEM((2, ppb, page, v_hd), v_pages.dtype),    # V blocks
+        ]) + [
             pltpu.SemaphoreType.DMA((2,)),     # one a buffer, K and V
             pltpu.SMEM((1,), jnp.int32),       # the buffer computed next
         ] + ([
@@ -843,7 +906,8 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
                              pps=pps, ppb=ppb, n_slots=n_slots,
                              n_rows=n_rows, head_dim=d, v_dim=dv,
                              quantized=quantized, window=window,
-                             sinks=sinks is not None)
+                             sinks=sinks is not None, latent=latent,
+                             terms=terms)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
@@ -852,7 +916,8 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name=KERNEL_NAME if window is None else WINDOW_KERNEL_NAME,
+        name=LATENT_KERNEL_NAME if latent else KERNEL_NAME
+        if window is None else WINDOW_KERNEL_NAME,
     )(*prefetch, *operands)
     return out.reshape(q.shape[:-1] + (dv,))
 
@@ -860,7 +925,8 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
 def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
                           *, layer=0, sm_scale=None, use_pallas="auto",
                           interpret=False, k_scales=None,
-                          v_scales=None, window=None, sinks=None):
+                          v_scales=None, window=None, sinks=None,
+                          value_lanes=None):
     """Multi-row attention off the page pool — R query rows per slot.
 
     q [S,R,H,D]; k_pages [L,P,page,Hkv*D] and v_pages [L,P,page,Hkv*Dv]
@@ -887,19 +953,23 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
     formulation at one width that keeps every cache path bitwise-equal
     to the full-recompute oracle.  ``use_pallas`` dispatch and the
     quantized ``k_scales``/``v_scales`` [L,P,page,H] contract match
-    ``paged_decode_attention``.
+    ``paged_decode_attention``.  ``v_pages=None`` with ``value_lanes``:
+    a latent pool (the module header), ONE row a position of at least
+    D lanes that every query head reads, whose first ``value_lanes``
+    lanes are the values (the output is [S,R,H,value_lanes]).
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     s, r, h, d = q.shape
-    kv_heads = k_pages.shape[-1] // d
+    latent = v_pages is None
+    kv_heads = 1 if latent else k_pages.shape[-1] // d
     if sinks is not None and sinks.ndim == 1:
         sinks = jnp.broadcast_to(sinks, (r, h))
     if kv_heads != h:
         # grouped-query heads: the G query heads that share a K/V head
         # ride as G more ROWS of that head's slot, so the kernel (and
         # the reference) read a slot's pages once for all of them
-        if h % kv_heads or k_pages.shape[-1] % d:
+        if h % kv_heads or (k_pages.shape[-1] % d and not latent):
             raise ValueError(
                 f"q has {h} heads of {d} but the pool rows hold "
                 f"{k_pages.shape[-1]} lanes: not a whole group a K/V head")
@@ -914,14 +984,15 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
             jnp.repeat(row_lengths, g, axis=1), layer=layer,
             sm_scale=sm_scale, use_pallas=use_pallas, interpret=interpret,
             k_scales=k_scales, v_scales=v_scales, window=window,
-            sinks=sinks)
+            sinks=sinks, value_lanes=value_lanes)
         return out.reshape(s, r, g, kv_heads, -1).transpose(0, 1, 3, 2, 4) \
             .reshape(s, r, h, -1)
     if _kernel_asked(use_pallas):
         return _chunk_call(q, k_pages, v_pages, jnp.int32(layer),
                            page_table, row_lengths, k_scales, v_scales,
                            sinks, sm_scale=float(sm_scale),
-                           interpret=interpret, window=window)
+                           interpret=interpret, window=window,
+                           value_lanes=value_lanes)
     offset = None
     if window is not None:
         # the ring read in logical order: its pps entries hold the
@@ -933,7 +1004,10 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
             page_table, (first[:, None] + jnp.arange(pps)) % pps, axis=1)
         offset = jnp.repeat(first * page, r)
     k = _gather_dequant(k_pages, k_scales, layer, page_table, h)
-    v = _gather_dequant(v_pages, v_scales, layer, page_table, h)
+    if latent:
+        k, v = k[..., :d], k[..., :value_lanes]
+    else:
+        v = _gather_dequant(v_pages, v_scales, layer, page_table, h)
     kr = jnp.broadcast_to(k[:, None], (s, r) + k.shape[1:]) \
         .reshape(s * r, *k.shape[1:])
     vr = jnp.broadcast_to(v[:, None], (s, r) + v.shape[1:]) \

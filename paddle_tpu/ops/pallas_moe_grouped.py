@@ -78,8 +78,18 @@ def _down_cols(rows, f, d):
     expert's block of ``w_down`` within 8 MiB each (a tile's activations
     are read again for every block of columns, so wide blocks; each
     block is double-buffered)."""
+    def fits(tn):
+        return max(2 * rows, f) * tn * 2 <= 8 << 20
+
+    if d % _LANES == 0:
+        # the widest whole-lane divisor of ``d`` that fits (7,168 is 56
+        # lane tiles: halving it leaves the lanes at 224)
+        tiles = d // _LANES
+        return _LANES * next(
+            k for k in range(tiles, 0, -1)
+            if tiles % k == 0 and (k == 1 or fits(_LANES * k)))
     tn = d
-    while tn > _LANES and tn % 2 == 0 and max(2 * rows, f) * tn * 2 > 8 << 20:
+    while tn > _LANES and tn % 2 == 0 and not fits(tn):
         tn //= 2
     return tn
 

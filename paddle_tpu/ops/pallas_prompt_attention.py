@@ -31,7 +31,12 @@ them, a row's heads folded into its lanes (``[T, H*D]``: what the
 projection before writes and the one after reads, so no copy of either
 is made); a program takes its group's lanes of a row block and stacks
 the heads' lanes as rows in scratch once a row block.  Only K and V, an
-``Hkv / H``-th of the query's bytes, are laid out head-major.  K and V
+``Hkv / H``-th of the query's bytes, are laid out head-major.  One
+shape cannot leave the query there: ONE head a group whose K lanes are
+not whole lane tiles (64 ungrouped heads of 192, the expanded form of a
+latent attention's prompt), where a program's lanes of a row would
+start inside a tile; that query is laid out head-major like its K, a
+copy of ``T x H x D``, and a program takes whole rows of its head.  K and V
 go to the MXU in the dtype they come in (the pages' dtype), as they lie.
 Where that is bfloat16 the query (``sm_scale`` multiplied in in float32
 first) and the probabilities meet them as ONE bfloat16 term each: what
@@ -76,6 +81,14 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 _STACK_ROWS = 2048
 _MAX_ROWS = 256
 _KEY_BLOCK = 1024
+# ... and the rows of the one shape whose query is laid out head-major
+# (one head a group of K lanes that are not whole tiles).  Measured on
+# the v5e at 64 ungrouped heads of K 192 / V 128, a prompt of 7,200 in
+# the 8,192 bucket, ms a call: row blocks of 256 / 512 / 1,024 / 2,048
+# under key blocks of 1,024 read 23.5 / 20.0 / 18.5 / 18.9 (82 / 77 / 69
+# / 62 % of the walked keys live); key blocks of 512 read 27.9-29.7, of
+# 2,048 19.0
+_HEAD_MAJOR_ROWS = 1024
 
 
 def flash_rule(t, num_heads, kv_heads, d, dv, window=None):
@@ -83,23 +96,29 @@ def flash_rule(t, num_heads, kv_heads, d, dv, window=None):
     prompt bucket of ``t`` rows, ``num_heads`` query heads on
     ``kv_heads`` K/V heads of ``d`` (K) and ``dv`` (V) lanes, or None
     where the kernel does not take the shape and the plain form stays:
-    heads that are not whole groups, a group whose K lanes or a head
-    whose V lanes are not whole lane tiles (K heads of 192 in even
-    groups are), a bucket that is not a whole number of blocks of at
-    least 128 rows.  A key block is the widest of 1,024 / 512 / 256 /
+    heads that are not whole groups, a group of several heads whose K
+    lanes or a head whose V lanes are not whole lane tiles (K heads of
+    192 in even groups are, and so is ONE head a group of K lanes that
+    fill whole sublanes: its query is laid out head-major), a bucket
+    that is not a whole number of blocks of at least 128 rows.  A key
+    block is the widest of 1,024 / 512 / 256 /
     128 positions that divides the bucket and does not pass the window
     (up to whole lanes); a row block stacks ``_STACK_ROWS`` rows over
     the group's heads, at most ``_MAX_ROWS`` of one head's and, under a
     window, no more than a key block (what the window's edge wastes
     grows with both)."""
-    if num_heads % kv_heads or num_heads // kv_heads * d % _LANES \
-            or dv % _LANES or t % _LANES:
+    if num_heads % kv_heads or dv % _LANES or t % _LANES:
+        return None
+    group = num_heads // kv_heads
+    head_major = group * d % _LANES != 0
+    if head_major and (group != 1 or d % 8 or d < _LANES):
         return None
     widest = _KEY_BLOCK if window is None else max(
         _LANES, min(_KEY_BLOCK, -(-window // _LANES) * _LANES))
     bk = next(b for b in (1024, 512, 256, _LANES)
               if b <= widest and t % b == 0)
-    bq = min(t, _MAX_ROWS, _STACK_ROWS // (num_heads // kv_heads))
+    bq = min(t, _HEAD_MAJOR_ROWS) if head_major \
+        else min(t, _MAX_ROWS, _STACK_ROWS // group)
     if window is not None:
         bq = min(bq, bk)
     if bq < 16 or t % bq:
@@ -257,8 +276,18 @@ def prompt_flash_attention(q, k, v, length, sinks=None, *, sm_scale,
         return kh, jnp.where(i > at, hi, jnp.minimum(lo + j, hi)), 0
 
     # ``sm_scale`` goes in in float32, then ONE rounding to the feed
-    qs = (q.astype(jnp.float32) * sm_scale).astype(feed).reshape(t, h * d)
-    in_specs = [pl.BlockSpec((bq, g * d), q_map),
+    qs = (q.astype(jnp.float32) * sm_scale).astype(feed)
+    if g * d % _LANES:
+        # one head a group of K lanes that are not whole tiles
+        # (``flash_rule``): head-major, a program takes its head's rows
+        qs = qs.transpose(1, 0, 2)
+        q_spec = pl.BlockSpec(
+            (None, bq, d), lambda kh, i, j, len_ref: (
+                kh, jnp.minimum(i, last_live(len_ref)), 0))
+    else:
+        qs = qs.reshape(t, h * d)
+        q_spec = pl.BlockSpec((bq, g * d), q_map)
+    in_specs = [q_spec,
                 pl.BlockSpec((1, bk, d), kv_map),
                 pl.BlockSpec((1, bk, dv), kv_map)]
     operands = [qs, ks, vs]

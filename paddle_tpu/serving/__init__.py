@@ -60,6 +60,7 @@ from .kv_cache import (  # noqa: F401
     PageAllocator,
     PrefixIndex,
 )
+from .latent_moe_lm import LatentMoELM  # noqa: F401
 from .parallel_moe_lm import ParallelMoELM  # noqa: F401
 from .window_moe_lm import WindowMoELM  # noqa: F401
 from .server import (  # noqa: F401
@@ -75,7 +76,8 @@ __all__ = [
     "DecodeEngine", "DecodeRequest", "DecodeServer", "DisaggConfig",
     "DisaggRequest", "DisaggServer", "GatedDeltaLM", "HybridMoELM",
     "InferenceRequest",
-    "KVPageExport", "PageAllocator", "PagedKVCache", "ParallelMoELM",
+    "KVPageExport", "LatentMoELM", "PageAllocator", "PagedKVCache",
+    "ParallelMoELM",
     "PrefixIndex",
     "QueueFullError", "RequestAbandonedError", "RequestBase",
     "RequestTooLargeError", "Server", "ServerClosedError",

@@ -272,6 +272,7 @@ def _key_words(seed: int) -> np.ndarray:
 
 
 WINDOW_SCOPE = "window_attention"   # names a window layer's attention ops
+LATENT_SCOPE = "latent_attention"   # ... and a latent layer's, in the step
 
 DRAFT_K_PAGES_VAR = "__decode_draft_k_pages__"
 DRAFT_V_PAGES_VAR = "__decode_draft_v_pages__"
@@ -287,8 +288,11 @@ _DONE = object()  # stream sentinel
 
 def _split_state(state):
     """Persistent-state tuple -> (k_pages, v_pages, k_scales,
-    v_scales); the scale pools exist only under FLAGS_decode_kv_quant."""
-    kp, vp, *scales = state
+    v_scales); the scale pools exist only under FLAGS_decode_kv_quant,
+    and a latent cache's one pool comes alone (``v_pages`` None: the
+    values are lanes of K's rows)."""
+    kp, *rest = state
+    vp, *scales = rest or (None,)
     return (kp, vp, *(scales or (None, None)))
 
 
@@ -359,27 +363,38 @@ class _Mixers:
     whole-prompt prefill reads it back and nowhere else);
     ``interpret`` is ``DecodeConfig.interpret`` for a layer's own Pallas
     kernels; ``record(name, rows)`` keeps a per-row array a
-    layer for a request that records its logits."""
+    layer for a request that records its logits.  ``prompt`` says the
+    rows are ONE prompt's from position 0 and attend themselves alone
+    (the whole-prompt prefill): a model whose attention has one form to
+    read the cache by and another to attend a prompt in picks by it, and
+    hands the call ``keep=``, what the cache keeps of the rows where
+    that is not the ``k`` and ``v`` they attend.  ``read_row`` (with
+    ``prompt``; an int32 scalar) is the ONE row whose logits the program
+    reads, the prompt's last real token: a model may form that row's
+    logits alone and hand back ``[1, V]``."""
 
     def __init__(self, mixed: _Mixed, recur, live, attend=None,
-                 attend_window=None, own_tallies=(), interpret=False):
+                 attend_window=None, own_tallies=(), interpret=False,
+                 prompt=False, read_row=None):
         self._mixed, self._recur, self.attend = mixed, recur, attend
         self.attend_window = attend_window
         self.live = live
         self.interpret = bool(interpret)
+        self.prompt, self.read_row = bool(prompt), read_row
         # ``own_tallies``: counters this program reads back beside the
         # model's declared ones
         self.counts = dict.fromkeys(mixed.tallies + tuple(own_tallies), 0)
         self.records = {}
 
-    def __call__(self, layer, q, k, v, cache, sinks=None):
+    def __call__(self, layer, q, k, v, cache, sinks=None, keep=None):
         pools, window, rec = cache
         if self._mixed.kinds[layer] == "window":
             ctx, window = self.attend_window(
                 self._mixed.layer["window"][layer], q, k, v, window, sinks)
         else:
-            ctx, pools = self.attend(self._mixed.layer["attention"][layer],
-                                     q, k, v, pools)
+            ctx, pools = self.attend(
+                self._mixed.layer["attention"][layer], q, k, v, pools,
+                **({} if keep is None else {"keep": keep}))
         return ctx, (pools, window, rec)
 
     def recur(self, layer, token_fn, rows, cache, chunk_fn=None, chunk=0):
@@ -746,6 +761,24 @@ class DecodeEngine:
     refuse at construction or submit, naming the kind and the mechanism
     (``per_slot_kinds``).
 
+    **One row for keys and values** (``serving/latent_moe_lm.py`` is
+    the reference).  A model with latent attention declares
+    ``values_in_keys`` beside ``num_kv_heads`` 1: what it caches of a
+    position is ONE row of ``head_dim`` lanes that all its query heads
+    read, whose first ``v_head_dim`` lanes are the values.  Its layers
+    are ``"attention"`` layers (every position in pages, the same free
+    list, tables and admission); the cache has the K pool alone
+    (``kv_cache.CacheConfig(latent=True)``).  In the step it hands
+    ``attend`` the query in the row's space, the row as ``k`` and ``v``
+    None; in the whole-prompt prefill (``attend.prompt``) whatever heads
+    it attends the prompt in (``prompt_heads``: their K/V head count, K
+    and V lanes) and the rows to cache as ``keep=``, and forms the
+    logits of ``attend.read_row`` alone.  Every request is
+    admitted fresh (``decode_prefix_bypassed``), and chunked or ragged
+    prefill, speculation, ``kv_quant`` and the hand-over refuse, naming
+    the latent page: the programs that extend a sequence R rows at a time
+    through the pages are not built for its rows.
+
     ``draft_model``/``draft_weights`` arm speculative decoding (with
     ``spec_k > 0``): the draft's page pools are indexed by the SAME
     page ids as the target's, so prefix sharing, reservation
@@ -816,13 +849,17 @@ class DecodeEngine:
         self._prefill_tallies = self._mixed.prefill_tallies \
             if self._mixed else ()
         self._refuse_for_kinds(model, c, draft_model)
+        latent = bool(getattr(model, "values_in_keys", False))
+        if latent:
+            self._refuse_for_latent(c, draft_model)
         with jax.default_device(self._device):
             self._cache = PagedKVCache(
                 CacheConfig(max(model.num_layers - n_rec - n_win, 1),
                             kv_heads, model.head_dim, c.slots,
                             c.max_seq_len, c.page_size,
                             num_pages=c.num_pages, dtype=c.cache_dtype,
-                            quantized=c.kv_quant, v_head_dim=v_dim),
+                            quantized=c.kv_quant, v_head_dim=v_dim,
+                            latent=latent),
                 self._scope, prefix_cache=c.prefix_cache, recurrent=spec,
                 window=self._window)
         # whether the pools' one layout is also an unpadded one (the
@@ -964,6 +1001,32 @@ class DecodeEngine:
                     why + "kv_quant (int8 K/V pages) is not wired for a "
                     "model whose pools hold only some of its layers")
 
+    @staticmethod
+    def _refuse_for_latent(c: "DecodeConfig", draft_model) -> None:
+        """A model that caches one latent row a position is served by
+        the whole-prompt prefill and the joint step: what extends a
+        sequence R rows at a time THROUGH the pages would have to run
+        its attention in the row's space at R times its heads in
+        stacked rows, and an int8 row has no head to scale by."""
+        why = ("the model keeps a latent page (one row a position for "
+               "keys and values): ")
+        if c.prefill_chunk_pages > 0 or c.ragged_prefill_rows > 0:
+            raise ValueError(
+                why + "chunked/ragged prefill (prefill_chunk_pages="
+                f"{c.prefill_chunk_pages}, ragged_prefill_rows="
+                f"{c.ragged_prefill_rows}) attends its rows through the "
+                "pages, which the multi-row step is not built to do in "
+                "the row's space")
+        if draft_model is not None or c.spec_k > 0:
+            raise ValueError(
+                why + "speculative decoding (a draft model, spec_k="
+                f"{c.spec_k}) verifies its window through the pages with "
+                "the multi-row step, which is not built for such rows")
+        if c.kv_quant:
+            raise ValueError(
+                why + "kv_quant (int8 K/V pages) scales a row a head, "
+                "and the latent row has none")
+
     def _commit(self, tree):
         """Device arrays for ``tree``; on a pinned replica every leaf
         that is not already spread over a mesh is committed to the
@@ -1046,19 +1109,25 @@ class DecodeEngine:
             k_pages, k_scales = kv_cache.write_token_layer(
                 k_pages, k_scales, l, k.reshape(flat),
                 write_page.reshape(-1), write_off.reshape(-1))
-            v_pages, v_scales = kv_cache.write_token_layer(
-                v_pages, v_scales, l, v.reshape((-1,) + v.shape[-2:]),
-                write_page.reshape(-1), write_off.reshape(-1))
+            if v_pages is not None:
+                v_pages, v_scales = kv_cache.write_token_layer(
+                    v_pages, v_scales, l, v.reshape((-1,) + v.shape[-2:]),
+                    write_page.reshape(-1), write_off.reshape(-1))
             # all backend dispatch (auto/always/never, Pallas vs the
             # gather+mask reference) lives in ONE place: the op itself —
             # including the quantized dequant-inline paths.  The scope
             # is metadata only: it names the call's device ops in a trace
-            with jax.named_scope(KERNEL_NAME):
+            # a latent cache's one pool (``v_pages`` None): the values
+            # are the leading lanes of the rows just written
+            with jax.named_scope(
+                    KERNEL_NAME if v_pages is not None else LATENT_SCOPE):
                 ctx = attention(
                     q, k_pages, v_pages, page_table, lengths, layer=l,
                     use_pallas=self.config.use_pallas,
                     interpret=self.config.interpret,
-                    k_scales=k_scales, v_scales=v_scales)
+                    k_scales=k_scales, v_scales=v_scales,
+                    value_lanes=None if v_pages is not None
+                    else self._cache.config.v_head_dim)
             return ctx, (k_pages, v_pages, k_scales, v_scales)
 
         return attend
@@ -1212,7 +1281,7 @@ class DecodeEngine:
         mixed = self._mixed if model is self.model else None
         row = _prefill_row(t_pad, cc.pages_per_slot, slot=mixed is not None)
         counted = mixed.prefill_tallies if mixed is not None else ()
-        fresh_only = bool(per_slot_kinds(model))
+        fresh_only = bool(per_slot_kinds(model)) or cc.latent
 
         @jax.named_scope("prefill_full")
         def prefill(state, weights, packed):
@@ -1261,12 +1330,16 @@ class DecodeEngine:
                     n: jax.lax.dynamic_update_slice_in_dim(
                         v, st[n], a["slot"], axis=0) for n, v in rec.items()}
 
-            def attend(l, q, k, v, pools):                  # [T_pad, H, D]
+            def attend(l, q, k, v, pools, keep=None):       # [T_pad, H, D]
                 k_pages, v_pages, k_scales, v_scales = pools
+                # ``keep``: the rows the cache holds where they are not
+                # the K the prompt attends (a latent cache's one pool)
                 k_pages, k_scales = kv_cache.write_prompt_layer(
-                    k_pages, k_scales, l, k, pages[:n_bp])
-                v_pages, v_scales = kv_cache.write_prompt_layer(
-                    v_pages, v_scales, l, v, pages[:n_bp])
+                    k_pages, k_scales, l, k if keep is None else keep,
+                    pages[:n_bp])
+                if v_pages is not None:
+                    v_pages, v_scales = kv_cache.write_prompt_layer(
+                        v_pages, v_scales, l, v, pages[:n_bp])
                 # attention in decode's own formulation through the SAME
                 # cache representation the pages store — each row's
                 # numerics are the ones decode will reproduce from the
@@ -1326,12 +1399,15 @@ class DecodeEngine:
             else:
                 mix = _Mixers(mixed, recur, positions < length, attend,
                               attend_window, own_tallies=counted,
-                              interpret=self.config.interpret)
+                              interpret=self.config.interpret, prompt=True,
+                              read_row=length - 1)
                 logits, cache = model.forward(
                     weights, tokens, positions, mixed.split(state), mix)
                 new_state = mixed.join(cache)
-            last = jax.lax.dynamic_index_in_dim(
-                logits, length - 1, 0, keepdims=False)
+            # every row's logits, or the read row's alone (``read_row``)
+            last = logits[0] if logits.shape[0] != t_pad else \
+                jax.lax.dynamic_index_in_dim(
+                    logits, length - 1, 0, keepdims=False)
             key0 = jax.random.fold_in(a["key"], 0)
             tok = sample_tokens(key0[None], last[None],
                                 a["temperature"][None], a["top_k"][None],
@@ -1533,6 +1609,13 @@ class DecodeEngine:
                 f"pages (extract_kv / kv_import); this model's {kind} "
                 f"layers {keeps}, which no exported page holds, so the "
                 "hand-over would decode from the wrong state")
+        if (extract_kv or kv_import is not None) \
+                and self._cache.config.latent:
+            raise ValueError(
+                "disaggregated serving hands a prompt over as its K/V "
+                "pages (extract_kv / kv_import); this model keeps a latent "
+                "page (one row a position for keys and values), which the "
+                "export and the install are not built for")
         if kv_import is not None:
             # migrated admission (serving/disagg.py): validate the
             # payload against THIS engine's pool geometry at submit
@@ -1664,6 +1747,7 @@ class DecodeEngine:
         stat_set("decode_kv_page_bytes", self._cache.config.page_bytes())
         stat_set("decode_state_bytes", self._cache.state_bytes())
         stat_set("decode_window_bytes", self._cache.window_bytes())
+        stat_set("decode_latent_bytes", self._cache.latent_bytes())
         from ..ops.pallas_decode_attention import feed_bits
 
         stat_set("decode_attn_feed_bits",
@@ -2571,6 +2655,14 @@ class DecodeEngine:
             stat_add("decode_attn_blocks_live", int(
                 (positions // self._attn_block + 1).sum()))
             stat_add("decode_attn_blocks_walked", self._attn_table_blocks)
+            if self._cache.config.latent:
+                # a layer's: the rows of latents the live slots attend,
+                # and the blocks the latent kernel walks for them
+                live = positions[list(live_idx)]
+                stat_add("decode_latent_positions_live",
+                         int((live + 1).sum()))
+                stat_add("decode_latent_blocks_walked",
+                         int((live // self._attn_block + 1).sum()))
             # what the sampler's conditionals take this step (a dead
             # slot's knobs are the zeros above: no mask): the draw when
             # a slot samples, the vocabulary's sort when such a slot
@@ -2597,9 +2689,13 @@ class DecodeEngine:
         n_full = layers_of_kind(m, "attention")
         if n_full:
             grouped = per_slot_kinds(m) or m.num_heads != cc.num_heads
+            # the heads a prompt is attended in, where they are not the
+            # cached rows' (a latent model's expanded form)
+            kv_heads, d, dv = getattr(m, "prompt_heads", (
+                cc.num_heads, cc.head_dim, cc.v_head_dim))
             walks.append((n_full, None, prefill_walk(
-                t_pad, m.num_heads, cc.num_heads, cc.head_dim,
-                cc.v_head_dim, None, self.config.use_pallas)
+                t_pad, m.num_heads, kv_heads, d, dv, None,
+                self.config.use_pallas)
                 if grouped else ("blocks", t_pad, t_pad)))
         if w is not None:
             walks.append((w.num_layers, w.window, prefill_walk(
@@ -2990,6 +3086,8 @@ class DecodeEngine:
                                 cc.scale_dtype),
                        jnp.full(sshape, kv_cache.SCALE_EPS,
                                 cc.scale_dtype))
+        elif cc.latent:
+            scratch = (jnp.zeros(shape, cc.dtype),)
         else:
             scratch = (jnp.zeros(shape, cc.dtype),
                        jnp.zeros(vshape, cc.dtype))

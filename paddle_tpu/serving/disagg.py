@@ -253,6 +253,13 @@ class DisaggServer:
                 "replica to a decode replica as its K/V pages; this "
                 f"model's {kind} layers {keeps}, which no exported page "
                 "holds, so it cannot be served disaggregated")
+        if getattr(model, "values_in_keys", False):
+            raise ValueError(
+                "disaggregated serving hands a prompt from a prefill "
+                "replica to a decode replica as its K/V pages; this model "
+                "keeps a latent page (one row a position for keys and "
+                "values), which the export and the install are not built "
+                "for")
         self.config = config or DecodeConfig()
         self.disagg = disagg or DisaggConfig()
         d = self.disagg

@@ -115,6 +115,19 @@ global layer says nothing of what the ring held at that position), and
 no page of it can be exported: ``prefix_bypassed`` as for recurrent
 layers.
 
+**One row for keys and values** (``CacheConfig(latent=True)``).  A
+model with latent attention caches ONE row a position a layer, shared by
+all its query heads: the scores read the whole row, the values are its
+first ``v_head_dim`` lanes.  Such a cache has the K pool ALONE, behind
+the same free list, tables and copy-on-write (``state_var_names`` is one
+name); there is no V pool to keep in step.  The row is stored at whole
+lane tiles (``row_lanes``: 576 lanes of a latent and its rotary key take
+640, the rest zeros that a zero-padded query meets), said by the shape
+and by ``latent_bytes`` rather than left to the chip's own padding.  No
+prefix index (``prefix_bypassed``), no int8 form, no export: the
+programs that would read such rows R at a time (a prefix hit's suffix,
+a chunk, a speculative window) are not built for them.
+
 **Quantized storage** (``FLAGS_decode_kv_quant``): pages are stored
 int8 (same folded rows) beside parallel scale pools ``[layers, pages,
 page_size, heads]``
@@ -218,7 +231,8 @@ class CacheConfig:
                  num_slots: int, max_seq_len: int, page_size: int,
                  num_pages: Optional[int] = None, dtype="float32",
                  quantized: bool = False,
-                 v_head_dim: Optional[int] = None):
+                 v_head_dim: Optional[int] = None,
+                 latent: bool = False):
         if max_seq_len % page_size:
             raise ValueError(
                 f"max_seq_len ({max_seq_len}) must be a multiple of "
@@ -241,6 +255,14 @@ class CacheConfig:
         if self.num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is trash)")
         self.quantized = bool(quantized)
+        # one row a position for scores AND values (the module header)
+        self.latent = bool(latent)
+        if self.latent and (self.quantized or self.num_heads != 1
+                            or self.v_head_dim > self.head_dim):
+            raise ValueError(
+                "a latent page is ONE unquantized row a position whose "
+                "leading lanes are the values: num_heads 1, v_head_dim "
+                "<= head_dim, no kv_quant")
         # ``dtype`` stays the COMPUTE/reference dtype (what dequantized
         # values and the full-recompute oracle use); ``store_dtype`` is
         # what the page pools hold
@@ -252,13 +274,16 @@ class CacheConfig:
     @property
     def row_lanes(self) -> int:
         """Width of one stored position: every head's ``head_dim``
-        values side by side."""
+        values side by side; a latent row up to whole lane tiles."""
+        if self.latent:
+            return -(-self.head_dim // 128) * 128
         return self.num_heads * self.head_dim
 
     @property
     def v_row_lanes(self) -> int:
-        """Width of one stored position of V."""
-        return self.num_heads * self.v_head_dim
+        """Width of one stored position of V (a latent cache stores
+        none: its values are lanes of the K row)."""
+        return 0 if self.latent else self.num_heads * self.v_head_dim
 
     @property
     def lane_dense(self) -> bool:
@@ -283,8 +308,8 @@ class CacheConfig:
         V's) — including its
         scale plane when quantized, so capacity math can't hide the
         scale overhead."""
-        data = (self.page_size * self.num_heads
-                * (self.v_head_dim if v else self.head_dim)
+        data = (self.page_size
+                * (self.v_row_lanes if v else self.row_lanes)
                 * self.store_dtype.itemsize)
         if self.quantized:
             data += (self.page_size * self.num_heads
@@ -596,8 +621,10 @@ class PagedKVCache:
         self.window = window if window is not None \
             and window.num_layers else None
         per_slot = self.recurrent is not None or self.window is not None
-        self.prefix_bypassed = bool(prefix_cache) and per_slot
-        prefix_cache = bool(prefix_cache) and not per_slot
+        # ... and none over latent rows (module header)
+        fresh_only = per_slot or config.latent
+        self.prefix_bypassed = bool(prefix_cache) and fresh_only
+        prefix_cache = bool(prefix_cache) and not fresh_only
         # optional per-request tracing hook: ``on_event(slot, name,
         # **attrs)`` fired on cache lifecycle events (cow_swap, evict,
         # register) — the decode engine wires it to the owning
@@ -622,8 +649,9 @@ class PagedKVCache:
         self._cow_spare: List[List[int]] = [[] for _ in range(c.num_slots)]
         self._refs = [0] * c.num_pages
         scope.set_var(K_PAGES_VAR, jnp.zeros(c.pool_shape(), c.store_dtype))
-        scope.set_var(V_PAGES_VAR, jnp.zeros(
-            c.pool_shape(row_lanes=c.v_row_lanes), c.store_dtype))
+        if not c.latent:
+            scope.set_var(V_PAGES_VAR, jnp.zeros(
+                c.pool_shape(row_lanes=c.v_row_lanes), c.store_dtype))
         if self.window is not None:
             for var, shape in zip((WINDOW_K_VAR, WINDOW_V_VAR),
                                   self.window.pool_shapes(c.num_slots,
@@ -660,8 +688,10 @@ class PagedKVCache:
     def state_var_names(self) -> Tuple[str, ...]:
         """Scope names a persistent step must thread (in order): the
         two page pools, plus the scale pools when quantized, then the
-        window layers' two pools, then the recurrent layers' slabs."""
-        names = (K_PAGES_VAR, V_PAGES_VAR)
+        window layers' two pools, then the recurrent layers' slabs.  A
+        latent cache has the K pool alone."""
+        names = (K_PAGES_VAR,) if self.config.latent \
+            else (K_PAGES_VAR, V_PAGES_VAR)
         if self.config.quantized:
             names += (K_SCALES_VAR, V_SCALES_VAR)
         return names + self.window_var_names() + self.recurrent_var_names()
@@ -680,6 +710,11 @@ class PagedKVCache:
         return self.window.bytes(c.num_slots, c.page_size,
                                  c.store_dtype.itemsize) \
             if self.window is not None else 0
+
+    def latent_bytes(self) -> int:
+        """Device bytes of the latent rows' pool, all layers and pages
+        (0 for a cache of K and V)."""
+        return self.config.cache_bytes() if self.config.latent else 0
 
     def window_pages_held(self) -> int:
         """Ring pages that hold a position of a live request, over the
@@ -890,6 +925,10 @@ class PagedKVCache:
             raise ValueError(
                 "a cache with window layers exports no pages: a slot's "
                 "ring of them holds its last positions only")
+        if self.config.latent:
+            raise ValueError(
+                "a cache of latent pages exports none: the hand-over is "
+                "not built for a pool of one row for keys and values")
         idx = np.asarray([int(p) for p in pages], np.int32)
         return {name: self.scope.get_var(name)[:, idx]
                 for name in self.state_var_names()}
@@ -1002,7 +1041,8 @@ class PagedKVCache:
 
     def arrays(self):
         return (self.scope.get_var(K_PAGES_VAR),
-                self.scope.get_var(V_PAGES_VAR))
+                None if self.config.latent
+                else self.scope.get_var(V_PAGES_VAR))
 
     # -- integrity audit (chaos tests / debugging) ------------------------
     def debug_check(self) -> None:
@@ -1102,13 +1142,26 @@ def _fold_heads(val):
     return val.reshape(val.shape[:-2] + (val.shape[-2] * val.shape[-1],))
 
 
+def _pool_rows(val, lanes: int):
+    """``val``'s heads folded into rows of the pool's ``lanes``: a row
+    narrower than the pool's (a latent's, stored at whole lane tiles)
+    is filled with zeros."""
+    import jax.numpy as jnp
+
+    rows = _fold_heads(val)
+    short = lanes - rows.shape[-1]
+    if not short:
+        return rows
+    return jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, short),))
+
+
 def scatter_token_layer(pages, layer: int, val, page_id, offset):
     """Write one new position per row: val [R, H, D] lands as the row
     [R, H*D] at (layer, page_id[r], offset[r]) of pages [L, P, page,
     H*D] — dead rows pass page 0 (trash).  Indexing the three leading
     axes of a lane-dense pool is what lets the chip scatter in place."""
     return pages.at[layer, page_id, offset].set(
-        _fold_heads(val).astype(pages.dtype))
+        _pool_rows(val, pages.shape[-1]).astype(pages.dtype))
 
 
 def scatter_prompt_layer(pages, layer: int, val, page_ids):
@@ -1117,7 +1170,7 @@ def scatter_prompt_layer(pages, layer: int, val, page_ids):
     wholesale, as [n_pages, page, H*D], into ``page_ids`` [n_pages]."""
     n = page_ids.shape[0]
     page = pages.shape[2]
-    v = _fold_heads(val).reshape(n, page, -1)
+    v = _pool_rows(val, pages.shape[-1]).reshape(n, page, -1)
     return pages.at[layer, page_ids].set(v.astype(pages.dtype))
 
 

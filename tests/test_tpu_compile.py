@@ -18,6 +18,7 @@ compilation cache off (an entry compiled here cannot be read back
 without a chip).
 """
 import functools
+import json
 import os
 import re
 
@@ -930,3 +931,117 @@ def test_routed_prefills_compile_with_the_grouped_experts(
                     for a in _ARRAY.findall(m["type"])}
             assert not dims & {wide, tall}, line[:160]
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+# -- Kimi-K2.5's rows: ONE latent row a position, 64 heads as its rows ------
+
+def _latent_engine(layers=2, **cfg):
+    """Kimi-K2.5's attention widths behind the engine at the cell's
+    serving sizes (64 slots, 10,240 positions, bf16 latent pages), a
+    dense layer and an expert layer, 12 held experts of the published
+    width and a short vocabulary: only shapes matter to a compile, and
+    these are the ones the chip's compiler could refuse (rows of 576
+    lanes in a pool of 640, 64 stacked query rows, blocks of 1,024
+    positions, 64 ungrouped heads of 192 over an 8,192-row prompt, the
+    grouped experts at a width of 7,168)."""
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+    from paddle_tpu.serving.latent_moe_lm import LatentMoELM
+
+    model = LatentMoELM(
+        vocab_size=1024, d_model=7168, num_layers=layers, dense_layers=1,
+        num_heads=64, q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+        v_dim=128, rope_theta=5e4, rope_factor=64.0, rope_orig_len=4096,
+        rope_beta_fast=32.0, rope_beta_slow=1.0, rope_mscale=1.0,
+        rope_mscale_all_dim=1.0, dense_dim=2048, num_experts=384, top_k=8,
+        held_experts=range(12), expert_dim=2048, shared_dim=2048,
+        routed_scale=2.827)
+    weights = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
+    return DecodeEngine(model, weights, DecodeConfig(**dict(dict(
+        slots=64, max_seq_len=10240, num_pages=64 * 20 + 1,
+        use_pallas="always", cache_dtype="bfloat16"), **cfg)))
+
+
+@pytest.mark.parametrize("program", ["step", "prefill_8192"])
+def test_kimi_width_programs_compile(one_chip, program):
+    """The joint step (the latent kernel a layer, by its own name: one
+    pool in, 64 x 64 rows of 512 lanes out, no V pool anywhere) and the
+    8,192-row whole-prompt prefill (the flash kernel over 64 ungrouped
+    heads of K 192 / V 128, the query head-major; the two grouped-expert
+    kernels at a width of 7,168; the latents, not the expanded K/V, to
+    the pages)."""
+    from paddle_tpu.ops import pallas_moe_grouped as grouped
+    from paddle_tpu.ops import pallas_prompt_attention as ppa
+
+    eng = _latent_engine()
+    cc = eng._cache.config
+    assert (cc.latent, cc.row_lanes, cc.v_row_lanes, cc.lane_dense) == (
+        True, 640, 0, True)
+    assert eng._state_vars == ("__decode_k_pages__",)
+    assert eng._attn_block == 1024
+    pool = "bf16[2,1281,16,640]"
+    if program == "step":
+        compiled = eng.lower_step(sharding=one_chip).compile()
+        text = compiled.as_text()
+        calls = [line.strip() for line in text.splitlines() if re.match(
+            r"\s*%" + pda.LATENT_KERNEL_NAME + r"[.\d]* = ", line)]
+        assert len(calls) == text.count("tpu_custom_call") == 2
+        assert all(c.startswith("%s = f32[64,64,512]" % c.split(" = ")[0])
+                   and "f32[64,64,640]" in c and c.count(pool) == 1
+                   for c in calls), calls
+        # the names by which the other cells' metrics find THEIR kernels
+        # take none of these calls; the latent metrics' pattern all
+        for name in ("paged_attn_ms_per_step.serve",
+                     "full_attn_ms_per_step.serve",
+                     "window_attn_ms_per_step.serve"):
+            with open(os.path.join(_LAYER_METRICS, name + ".json")) as f:
+                mfile = json.load(f)
+            pats = list(mfile.get("kernels", {}).values()) + [
+                mfile.get("params", {}).get("pattern")]
+            assert not [c for c in calls for p in pats
+                        if p and re.search(p, c)], name
+        assert all(_metric_pattern("latent_attn_ms_per_step.serve")
+                   .search(c) for c in calls)
+        assert ppa.KERNEL_NAME not in text
+    else:
+        assert eng._prefill_walks(8192) == [(2, None, ("flash", 1024, 1024))]
+        compiled = eng.lower_prefill(8192, sharding=one_chip).compile()
+        text = compiled.as_text()
+        # the flash kernel a layer and the expert layer's two kernels
+        assert text.count("tpu_custom_call") == 4
+        assert grouped.GATE_UP_KERNEL_NAME in text
+        assert grouped.DOWN_KERNEL_NAME in text
+        calls = _assert_prefill_holds_the_flash_kernel(text, 8192)
+        assert len(calls) == 2
+        # 64 heads of V's 128 lanes out, the query and K head-major
+        assert all(c.startswith("%s = f32[8192,8192]" % c.split(" = ")[0])
+                   and c.count("bf16[64,8192,192]") == 2
+                   and "bf16[64,8192,128]" in c for c in calls), calls
+        assert pda.LATENT_KERNEL_NAME not in text
+        # the head runs over the one row that is read, not the bucket
+        assert "f32[1,1024]" in text and "f32[8192,1024]" not in text
+    # the one pool goes in and comes out in its one layout, and nothing
+    # of an expanded cache (64 heads x (192 + 128) lanes a position) is
+    # kept: what the program needs beside its operands stays small
+    _assert_pools_stay_put(compiled, [(2, 1281, 16, 640)])
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+def test_kimi_cell_pool_is_the_latent_rows_at_whole_lane_tiles():
+    """The cell's cache by arithmetic (no chip, nothing allocated): 5
+    layers x 41,025 pages x 16 rows of 640 bfloat16 lanes, 1,280 B a
+    position a layer where 1,152 are published and 40,960 would be the
+    expanded heads'."""
+    from paddle_tpu.serving.kv_cache import CacheConfig
+
+    cc = CacheConfig(5, 1, 576, 64, 10240, 16, num_pages=41025,
+                     dtype="bfloat16", v_head_dim=512, latent=True)
+    assert cc.pool_shape() == (5, 41025, 16, 640)
+    assert cc.cache_bytes() == 5 * 41025 * 16 * 1280 == 4200960000
+    assert cc.page_bytes() == 16 * 1280 and cc.page_bytes(v=True) == 0
+    with pytest.raises(ValueError, match="latent page"):
+        CacheConfig(5, 64, 192, 64, 10240, 16, latent=True)
+    with pytest.raises(ValueError, match="latent page"):
+        CacheConfig(5, 1, 576, 64, 10240, 16, v_head_dim=512, latent=True,
+                    quantized=True)
