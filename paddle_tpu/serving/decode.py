@@ -115,6 +115,7 @@ a dispatch, whatever the number of fields.
 from __future__ import annotations
 
 import collections
+import inspect
 import queue as _queue
 import threading
 import time
@@ -353,7 +354,9 @@ class _Mixers:
     also gets the call's ``sinks``); ``recur(layer, token_fn, rows,
     cache, chunk_fn=None, chunk=0)`` runs a
     recurrent layer's one-token update ``token_fn(rows, state) -> (out,
-    state)`` where the program keeps that state (a whole-prompt prefill
+    state)`` where the program keeps that state (the joint step hands a
+    ``token_fn`` that has a ``live`` parameter the rows' mask and leaves
+    the dead rows to it; a whole-prompt prefill
     runs ``chunk_fn(rows, n_real, state)`` over ``chunk`` consecutive
     tokens at once where the model hands one); ``live`` (bool, the
     rows' shape) says which rows are a request's; ``tally(name, n)``
@@ -418,6 +421,13 @@ class _Mixers:
         import jax.numpy as jnp
 
         return {n: jnp.stack(v, axis=1) for n, v in self.records.items()}
+
+
+def _takes_live(token_fn) -> bool:
+    """Whether a model's one-token update has a ``live`` parameter: its
+    statement that it leaves the state of a row that is not live as it
+    was (``DecodeEngine``'s contract)."""
+    return "live" in inspect.signature(token_fn).parameters
 
 
 def layers_of_kind(model, kind: str) -> int:
@@ -736,7 +746,17 @@ class DecodeEngine:
     ``[R, ...]`` and the engine decides what state that is and where it
     goes (the joint step: every live slot's row, in place; the
     whole-prompt prefill: from zero through the prompt's real tokens,
-    the last one's state into the slot's row).  The prefill takes the
+    the last one's state into the slot's row).  In the joint step a
+    dead slot's row has to stay as it is, and whose work that is the
+    update's signature says: a ``token_fn`` WITH a ``live`` parameter
+    is called ``token_fn(rows, state, live=live)`` (bool ``[R]``) and
+    promises to hand a row that is not live back with the state it had,
+    every array of it, bit for bit; the engine then touches no slab
+    itself (an update that is a kernel over the slabs in place stays the
+    only instruction that passes over them).  One without it is called
+    ``token_fn(rows, state)`` and the engine masks what it returns
+    (``where(live, new, old)``, a pass over every slab).  A prefill
+    passes no ``live``: its rows are one request's.  The prefill takes the
     tokens one by one through ``token_fn`` unless the model also hands
     ``chunk_fn(rows, n_real, state) -> (out, state)``: the same rule over
     ``chunk`` consecutive rows of ONE request from the state before them
@@ -1213,7 +1233,12 @@ class DecodeEngine:
             def recur(token_fn, rows, rec, chunk_fn=None, chunk=0):
                 """Every slot's state one token on; a dead slot's row (a
                 prefill ahead of this step may just have filled it)
-                stays as it is."""
+                stays as it is: left so by a ``token_fn`` that takes
+                ``live``, else masked here, a pass over every slab."""
+                if _takes_live(token_fn):
+                    out, new = token_fn(rows, rec, live=live)
+                    return out, {n: new[n].astype(v.dtype)
+                                 for n, v in rec.items()}
                 out, new = token_fn(rows, rec)
                 return out, {
                     n: jnp.where(live.reshape((-1,) + (1,) * (v.ndim - 1)),

@@ -20,6 +20,18 @@ update over the rows' state, ``attend.live`` masks dead and padding rows
 out of the routing, ``attend.tally`` and ``attend.record`` take the
 routing counts and the chosen expert ids.
 
+The recurrent layers' rule has ONE definition in two forms, chosen by
+``ops/pallas_kda_update.py`` ``kda_rule`` from the state's static shape
+alone: a float32 state of whole lane tiles (the served widths) goes
+through the kernel there, which holds a block of heads' states in VMEM,
+reads and writes each once, in place; anything else (every toy width)
+through the XLA lines of ``_kda_rule_xla``, which are also the tests'
+oracle.  In either form the one-token update takes ``live`` and owns
+the dead rows, so the engine's step passes over no slab itself (with
+the kernel, nothing but the kernel does); with the kernel the
+whole-prompt prefill also gets a chunk function: ``PREFILL_CHUNK``
+tokens a kernel call over a state that stays in VMEM between them.
+
 Precision as served: weights (and K/V pages) in ``dtype`` (bfloat16),
 every matmul accumulating in float32; the residual stream, norms, router
 scores, softmax, the gates and THE RECURRENT STATE in float32.
@@ -33,8 +45,13 @@ from typing import Sequence
 import numpy as np
 
 from ..ops import moe_ops
+from ..ops import pallas_kda_update as kda
 
 KDA_SCOPE = "kda_update"
+# tokens a kernel call of the whole-prompt prefill (the state passes
+# through HBM once a chunk): PERF.md section 5 has what 64, 256 and a
+# whole bucket read on the chip
+PREFILL_CHUNK = 64
 
 
 class HybridMoELM:
@@ -76,7 +93,10 @@ class HybridMoELM:
         self.max_seq_len = int(max_seq_len)     # no positional table
         # the counters forward adds to through attend.tally: a joint
         # step's, and those only a whole-prompt prefill reads back
-        self.tallies = ("moe_local_assignments", "moe_experts_hit")
+        # ``kda_kernel_rows``: live rows x recurrent layers whose state a
+        # step's kernel calls updated (0 where the XLA form serves)
+        self.tallies = ("moe_local_assignments", "moe_experts_hit",
+                        "kda_kernel_rows")
         self.prefill_tallies = moe_ops.GROUPED_TALLIES
         c = self.lin_heads * self.lin_head_dim
         # one slot's state of ONE recurrent layer: the delta rule's
@@ -178,8 +198,7 @@ class HybridMoELM:
                         "gate": _mm(_mm(h, lw["kda_wa_down"]),
                                     lw["kda_wa_up"]),
                         "beta": _mm(h, lw["kda_wbeta"])}
-                o, cache = attend.recur(
-                    l, functools.partial(self._kda_token, lw), rows, cache)
+                o, cache = self._recur(l, lw, rows, cache, attend)
                 o = o * jax.lax.rsqrt(jnp.mean(
                     o * o, -1, keepdims=True) + self.rms_eps) \
                     * lw["kda_onorm"]
@@ -203,40 +222,133 @@ class HybridMoELM:
     def _rms(self, x, g):
         return rms_norm(x, g, self.rms_eps)
 
-    def _kda_token(self, lw, rows, state):
-        """One token a row through a recurrent layer: ``rows`` the
-        token's projections (``u [R, 3C]`` before the convolution,
-        ``gate [R, C]``, ``beta [R, heads]``), ``state`` the rows' state
-        BEFORE it (``s [R, heads, dk, dv]``, ``tail [R, (K-1)*3C]``) ->
-        (``o [R, heads, dv]``, the state after it).  All float32.  The
-        state is read twice and written once: ``S'^T k`` and ``S'^T q``
-        come out of one pass, and ``o = S'^T q + (k.q) b (v - S'^T k)``
-        is ``S_t^T q`` without a third."""
+    def _recur(self, l, lw, rows, cache, attend):
+        """Recurrent layer ``l`` over the rows' projections -> (``o``,
+        cache).  Where the kernel takes the state's shape a whole-prompt
+        prefill runs ``PREFILL_CHUNK`` tokens a call through
+        ``_kda_chunk``, and a step counts the rows it updated."""
+        import jax.numpy as jnp
+
+        token = functools.partial(self._kda_token, lw,
+                                  interpret=attend.interpret)
+        shape, dtype = self.recurrent_state["s"]
+        if not kda.kda_rule(*shape, dtype):
+            return attend.recur(l, token, rows, cache)
+        if not attend.prompt:
+            attend.tally("kda_kernel_rows",
+                         jnp.sum(attend.live, dtype=jnp.int32))
+        return attend.recur(
+            l, token, rows, cache, chunk=PREFILL_CHUNK,
+            chunk_fn=functools.partial(self._kda_chunk, lw,
+                                       interpret=attend.interpret))
+
+    def _kda_vectors(self, lw, conv, gate, beta):
+        """What the rule takes of ``N`` tokens, from their convolved
+        rows ``conv [N, 3C]`` and the ``gate [N, C]`` and ``beta [N,
+        heads]`` projections -> (q, k, v, decay ``[N, heads, dk]``, beta
+        ``[N, heads]``): q and k at unit length a head, q scaled by
+        ``dk^-1/2``; the decay a channel in (0, 1); beta in (0, 2)."""
         import jax
         import jax.numpy as jnp
 
         nh, dk = self.lin_heads, self.lin_head_dim
+        q, k, v = jnp.moveaxis(jax.nn.silu(conv).reshape(
+            -1, 3, nh, dk), 1, 0)
+        q = q * jax.lax.rsqrt(
+            jnp.sum(q * q, -1, keepdims=True) + 1e-6) / math.sqrt(dk)
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+        decay = jnp.exp(
+            -jnp.exp(lw["kda_a_log"])[:, None] * jax.nn.softplus(
+                gate + lw["kda_dt_bias"]).reshape(-1, nh, dk))
+        return q, k, v, decay, 2.0 * jax.nn.sigmoid(beta)
+
+    def _kda_token(self, lw, rows, state, live=None, interpret=False):
+        """One token a row through a recurrent layer: ``rows`` the
+        token's projections (``u [R, 3C]`` before the convolution,
+        ``gate [R, C]``, ``beta [R, heads]``), ``state`` the rows' state
+        BEFORE it (``s [R, heads, dk, dv]``, ``tail [R, (K-1)*3C]``) ->
+        (``o [R, heads, dv]``, the state after it).  All float32.
+
+        It takes ``live`` (bool ``[R]``; None: every row) and OWNS the
+        dead rows: a row that is not live comes back with the state it
+        had, so the caller passes over no slab to mask it again.  Where
+        ``kda_rule`` takes the state's shape the matrices go through the
+        kernel, read once and written once where they lie (a dead row's
+        blocks written back as read); else through ``_kda_rule_xla``
+        (read twice and written once, and once more for the mask)."""
+        import jax
+        import jax.numpy as jnp
+
         with jax.named_scope(KDA_SCOPE):
             c3 = rows["u"].shape[-1]
             window = jnp.concatenate([state["tail"], rows["u"]], axis=1)
             conv = sum(window[:, j * c3:(j + 1) * c3] * lw["kda_conv"][j]
                        for j in range(self.conv_kernel))
-            q, k, v = jnp.moveaxis(jax.nn.silu(conv).reshape(
-                -1, 3, nh, dk), 1, 0)
-            q = q * jax.lax.rsqrt(
-                jnp.sum(q * q, -1, keepdims=True) + 1e-6) / math.sqrt(dk)
-            k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
-            decay = jnp.exp(
-                -jnp.exp(lw["kda_a_log"])[:, None] * jax.nn.softplus(
-                    rows["gate"] + lw["kda_dt_bias"]).reshape(-1, nh, dk))
-            beta = 2.0 * jax.nn.sigmoid(rows["beta"])          # [R, nh]
-            s = decay[..., None] * state["s"]                  # Diag(a) S
-            ks = jnp.sum(k[..., None] * s, axis=-2)            # S'^T k
-            qs = jnp.sum(q[..., None] * s, axis=-2)            # S'^T q
-            delta = beta[..., None] * (v - ks)
-            s = s + k[..., None] * delta[..., None, :]
-            o = qs + jnp.sum(q * k, -1, keepdims=True) * delta
-        return o, {"s": s, "tail": window[:, c3:]}
+            q, k, v, decay, beta = self._kda_vectors(
+                lw, conv, rows["gate"], rows["beta"])
+            s0, tail = state["s"], window[:, c3:]
+            if kda.kda_rule(*s0.shape[1:], s0.dtype):
+                n = jnp.ones(s0.shape[:1], jnp.int32) if live is None \
+                    else live.astype(jnp.int32)
+                o, s = kda.kda_update(
+                    q[:, None], k[:, None], decay[:, None], v[:, None],
+                    beta[:, None], s0, n, interpret=interpret)
+                o = o[:, 0]
+            else:
+                o, s = _kda_rule_xla(q, k, v, decay, beta, s0)
+                if live is not None:
+                    s = jnp.where(live[:, None, None, None], s, s0)
+            if live is not None:
+                tail = jnp.where(live[:, None], tail, state["tail"])
+        return o, {"s": s, "tail": tail}
+
+    def _kda_chunk(self, lw, rows, n_real, state, interpret=False):
+        """``chunk`` consecutive tokens of ONE request through a
+        recurrent layer in one kernel call: ``rows`` their projections
+        (``u [C, 3C]``, ``gate``, ``beta``), of which the first
+        ``n_real`` are the request's (the kernel's token loop stops
+        there: padding touches neither the matrices nor the tail),
+        ``state`` the request's before the chunk (leading dimension 1)
+        -> (``o [C, heads, dv]``, zero past ``n_real``; the state after
+        token ``n_real - 1``).  The convolution, the norms, the decay
+        and beta are the token form's, over all the chunk's rows at
+        once."""
+        import jax
+        import jax.numpy as jnp
+
+        with jax.named_scope(KDA_SCOPE):
+            c, c3 = rows["u"].shape
+            window = jnp.concatenate(
+                [state["tail"].reshape(self.conv_kernel - 1, c3),
+                 rows["u"]])
+            conv = sum(window[j:j + c] * lw["kda_conv"][j]
+                       for j in range(self.conv_kernel))
+            q, k, v, decay, beta = self._kda_vectors(
+                lw, conv, rows["gate"], rows["beta"])
+            o, s = kda.kda_update(
+                q[None], k[None], decay[None], v[None], beta[None],
+                state["s"], jnp.reshape(n_real, (1,)), interpret=interpret)
+            tail = jax.lax.dynamic_slice_in_dim(
+                window, n_real, self.conv_kernel - 1)
+        return o[0], {"s": s, "tail": tail.reshape(1, -1)}
+
+
+def _kda_rule_xla(q, k, v, decay, beta, s):
+    """The gated delta rule, one token a row, as XLA fusions: ``q``,
+    ``k``, ``v``, ``decay [R, heads, d]``, ``beta [R, heads]``, ``s [R,
+    heads, dk, dv]`` before the token -> (``o [R, heads, dv]``, ``s``
+    after it).  ``S'^T k`` and ``S'^T q`` come out of one pass over the
+    decayed state, and ``o = S'^T q + (k.q) b (v - S'^T k)`` is ``S_t^T
+    q`` without a third.  The form of every shape the kernel does not
+    take, and what the kernel is tested against."""
+    import jax.numpy as jnp
+
+    s = decay[..., None] * s                           # Diag(a) S
+    ks = jnp.sum(k[..., None] * s, axis=-2)            # S'^T k
+    qs = jnp.sum(q[..., None] * s, axis=-2)            # S'^T q
+    delta = beta[..., None] * (v - ks)
+    s = s + k[..., None] * delta[..., None, :]
+    return qs + jnp.sum(q * k, -1, keepdims=True) * delta, s
 
 
 def _mm(a, w):

@@ -426,3 +426,37 @@ def test_the_check_would_see_a_departure(departure):
     prompts = [np.random.RandomState(23).randint(0, VOCAB, 70).tolist()]
     with engine(model, weights) as eng:
         assert served_vs_reference(eng, model, weights, prompts) > 1e-2
+
+
+# sha256 of the lowered text of ``make_model()``'s joint step and
+# 128-row whole-prompt prefill behind ``engine()``, as PR 46's tree
+# lowers them
+PROGRAMS_AS_LOWERED = {
+    "step": "93ad8ee9930b9c80b7f7980246e89a35d1b3df4b9a69cbc6688e0aa3435f8573",
+    "prefill":
+        "8e936eecf7c93a1c54e53d0c07d96291be128d8c61b4db456fdef05d22d6c876"}
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_the_programs_are_still_the_ones_lowered_before_the_kernel(program):
+    """This model's one-token update takes no ``live``: the engine keeps
+    masking its dead rows (``where`` over every slab) and hands its
+    update nothing new, so the joint step and the whole-prompt prefill
+    lower to the text they had before ``HybridMoELM``'s update moved
+    into a kernel (PR 47).  A change MEANT to move these programs
+    replaces the digests; one that was not has found out here."""
+    import hashlib
+
+    model = make_model()
+    weights = model.init_weights(jax.random.PRNGKey(1))
+    eng = engine(model, weights)
+    text = (eng.lower_step() if program == "step"
+            else eng.lower_prefill(128)).as_text()
+    slab = "tensor<3x3x6x12xf32>"
+    if program == "step":
+        # six recurrent layers, each slab masked by the engine
+        assert len([ln for ln in text.splitlines() if "call @_where" in ln
+                    and slab in ln.split("->")[-1]]) == 6
+    assert "tpu_custom_call" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PROGRAMS_AS_LOWERED[program]

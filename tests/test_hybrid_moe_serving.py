@@ -2,6 +2,8 @@
 experts (``serving/hybrid_moe_lm.py``) behind the real ``DecodeEngine``,
 against the plain reference (``benchmark/reference/hybrid_moe_lm.py``, the
 one the cell's check uses): float32, seeded, tiny."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,7 @@ from paddle_tpu.ops import moe_ops
 from paddle_tpu.ops import pallas_decode_attention as pda
 from paddle_tpu.serving import DecodeConfig, DecodeEngine
 from paddle_tpu.serving import decode as decode_mod
+from paddle_tpu.serving import hybrid_moe_lm as hybrid
 from paddle_tpu.serving.hybrid_moe_lm import HybridMoELM
 
 from benchmark.reference import hybrid_moe_lm as ref
@@ -91,33 +94,81 @@ def test_paged_kernel_serves_the_grouped_heads_in_interpret_mode():
         assert served_vs_reference(eng, model, weights, prompts, 4) < 5e-5
 
 
-def test_a_slots_second_request_sees_none_of_the_firsts_state():
-    model = make_model(PERIOD)
+# linear heads of whole lane tiles in whole sublane tiles: the state
+# update is the kernel's (``ops/pallas_kda_update.py`` ``kda_rule``), in
+# the step and, a chunk of tokens a call, in the prefill; interpreted
+WIDTHS = {"toy": ({}, {}),
+          "kernel": (dict(lin_heads=8, lin_head_dim=128),
+                     dict(interpret=True))}
+widths = pytest.mark.parametrize("widths", list(WIDTHS))
+
+
+@pytest.fixture
+def short_chunks(monkeypatch):
+    """Prefill chunks of 16 tokens: a test's prompts span several."""
+    monkeypatch.setattr(hybrid, "PREFILL_CHUNK", 16)
+
+
+@pytest.mark.parametrize("kinds", [("recurrent",), PERIOD],
+                         ids=["kda", "period"])
+def test_prefill_then_decode_through_the_kernel_matches_the_reference(
+        kinds, short_chunks):
+    """The real engine at lane-wide heads, the kernel in the step and in
+    the prefill: prompts of less than a chunk, of whole chunks, and one
+    that ends in the middle of its third chunk."""
+    sizes, cfg = WIDTHS["kernel"]
+    model = make_model(kinds, **sizes)
+    weights = model.init_weights(jax.random.PRNGKey(1))
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(0, VOCAB, n).tolist() for n in (5, 32, 41, 16)]
+    before = {n: stat_get(n) for n in (
+        "decode_prefill_scan_steps", "decode_prefill_scan_tokens",
+        "kda_kernel_rows", "decode_tokens_total", "decode_prefills")}
+    with engine(model, weights, **cfg) as eng:
+        assert served_vs_reference(eng, model, weights, prompts) < 2e-4
+    got = {n: stat_get(n) - v for n, v in before.items()}
+    layers = kinds.count("recurrent")
+    assert got["decode_prefill_scan_tokens"] == layers * (5 + 32 + 41 + 16)
+    assert got["decode_prefill_scan_steps"] == layers * (1 + 2 + 3 + 1)
+    # a live row of a step is a token that no prefill delivered
+    assert got["kda_kernel_rows"] == layers * (
+        got["decode_tokens_total"] - got["decode_prefills"])
+
+
+@widths
+def test_a_slots_second_request_sees_none_of_the_firsts_state(
+        widths, short_chunks):
+    sizes, cfg = WIDTHS[widths]
+    model = make_model(PERIOD, **sizes)
     weights = model.init_weights(jax.random.PRNGKey(5))
     rng = np.random.RandomState(6)
-    with engine(model, weights, slots=1) as eng:
+    with engine(model, weights, slots=1, **cfg) as eng:
         for n in (23, 6, 17):       # one slot: each reuses the last's rows
             p = [rng.randint(0, VOCAB, n).tolist()]
-            assert served_vs_reference(eng, model, weights, p) < 5e-5
+            assert served_vs_reference(eng, model, weights, p) < (
+                2e-4 if sizes else 5e-5)
 
 
-def _state_after_prefill(model, weights, prompt, page_size):
-    with engine(model, weights, slots=2, page_size=page_size) as eng:
+def _state_after_prefill(model, weights, prompt, page_size, **cfg):
+    with engine(model, weights, slots=2, page_size=page_size, **cfg) as eng:
         eng.submit([1, 2, 3], max_new_tokens=1).result(timeout=300)
         eng.submit(prompt, max_new_tokens=1).result(timeout=300)
         names = eng._cache.recurrent_var_names()
         return {n: np.asarray(eng._scope.get_var(n)) for n in names}
 
 
-def test_padding_rows_leave_the_state_alone():
+@widths
+def test_padding_rows_leave_the_state_alone(widths, short_chunks):
     """The same 9-token prompt prefilled in a bucket of 16 and in one of
     32: what the slot's rows hold is the state after token 9, however
-    many padding rows followed it."""
-    model = make_model(("recurrent", "attention"))
+    many padding rows followed it (through the kernel: one chunk whose
+    token loop stops at 9, and a second chunk that never runs)."""
+    sizes, cfg = WIDTHS[widths]
+    model = make_model(("recurrent", "attention"), **sizes)
     weights = model.init_weights(jax.random.PRNGKey(7))
     prompt = np.random.RandomState(8).randint(0, VOCAB, 9).tolist()
-    a = _state_after_prefill(model, weights, prompt, 8)
-    b = _state_after_prefill(model, weights, prompt, 32)
+    a = _state_after_prefill(model, weights, prompt, 8, **cfg)
+    b = _state_after_prefill(model, weights, prompt, 32, **cfg)
     assert set(a) == set(b) and len(a) == 2
     for name in a:
         assert np.abs(a[name][0]).max() > 0      # slot 0 was written
@@ -445,7 +496,7 @@ def test_the_tallies_are_the_models_declared_names_before_any_trace():
     weights = model.init_weights(jax.random.PRNGKey(17))
     eng = engine(model, weights)
     assert eng._tallies == model.tallies == (
-        "moe_local_assignments", "moe_experts_hit")
+        "moe_local_assignments", "moe_experts_hit", "kda_kernel_rows")
     # a step reads back no counter of the prefill's; a count of one
     # there goes nowhere and fails nothing
     assert eng._prefill_tallies[-3:] == model.prefill_tallies \
@@ -456,3 +507,55 @@ def test_the_tallies_are_the_models_declared_names_before_any_trace():
     model.tallies = ("moe_experts_hit",)
     with pytest.raises(KeyError, match="moe_local_assignments"):
         engine(model, weights).lower_step()
+
+
+def _function(text, name):
+    """The lines of ``func.func ... @name(`` in lowered text, its
+    signature first."""
+    lines = text.splitlines()
+    start = next(i for i, ln in enumerate(lines)
+                 if re.search(r"func\.func \w+ @%s\(" % re.escape(name), ln))
+    end = next(i for i in range(start + 1, len(lines))
+               if lines[i].startswith("  }"))
+    return lines[start:end]
+
+
+def test_the_steps_slabs_go_through_the_kernel_and_nothing_else():
+    """The joint step of a Solar-shaped model, lowered for the chip: a
+    recurrent layer's matrices ``[slots, heads, d_k, d_v]`` are the
+    program's own argument handed straight to ONE instruction, the
+    state-update kernel, whose result of that shape is the operand's
+    buffer; nothing selects over that shape (the dead rows' mask is the
+    kernel's ``n_real``, not the engine's ``where``)."""
+    from paddle_tpu.ops import pallas_kda_update as kda
+
+    sizes, _ = WIDTHS["kernel"]
+    model = make_model(("attention", "recurrent", "recurrent"), **sizes)
+    weights = model.init_weights(jax.random.PRNGKey(19))
+    eng = engine(model, weights, use_pallas="always")
+    args = (tuple(eng._scope.get_var(n) for n in eng._state_vars),
+            eng.weights, eng._step_args(()), eng._no_tokens)
+    text = eng._step_fn.trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    slab = "tensor<3x8x128x128xf32>"
+    main = _function(text, "main")
+    slabs = re.findall(r"(%arg\d+): " + re.escape(slab), main[0])
+    assert len(slabs) == 2                          # one a recurrent layer
+    for arg in slabs:
+        uses = [ln for ln in main[1:] if re.search(arg + r"\b", ln)]
+        assert len(uses) == 1 and "call @kda_update(" in uses[0], uses
+    inner = _function(text, "kda_update")
+    passed = re.search(r"(%arg\d+): " + re.escape(slab), inner[0])[1]
+    uses = [ln for ln in inner[1:] if re.search(passed + r"\b", ln)]
+    assert len(uses) == 1 and "@tpu_custom_call" in uses[0]
+    call = uses[0]
+    assert kda.KERNEL_NAME in call
+    operands = re.search(r"@tpu_custom_call\(([^)]*)\)", call)[1].split(", ")
+    assert operands.index(passed) == 3
+    assert re.search(r"output_tuple_indices = \[1\], operand_index = 3, "
+                     r"operand_tuple_indices = \[\]", call)
+    assert not [ln for ln in text.splitlines()
+                if "stablehlo.select" in ln and slab in ln]
+    # the convolution's tail is the model's to mask: one select a layer
+    assert len([ln for ln in main if "call @_where" in ln
+                and "tensor<3x9216xf32>" in ln.split("->")[-1]]) == 2

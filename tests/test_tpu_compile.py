@@ -512,9 +512,13 @@ def test_recurrent_state_is_updated_in_place(one_chip, program):
     lowered, state = _lower_program(eng, program, one_chip)
     compiled = lowered.compile()
     text = compiled.as_text()
-    # the step's paged kernel; the prefill's flash kernel
-    assert text.count("tpu_custom_call") == 1
+    # the step's paged kernel, the prefill's flash kernel, and in each
+    # the recurrent layer's state update (the prefill's in its loop
+    # over the prompt's chunks)
+    from paddle_tpu.ops import pallas_kda_update as kda
+    assert text.count("tpu_custom_call") == 2
     assert ("prompt_flash_attention" in text) == (program == "prefill")
+    assert text.count(f"%{kda.KERNEL_NAME}") >= 1
     n = len(state)
     assert n == 2 + 2
     ins = jax.tree_util.tree_leaves(compiled.input_formats)
@@ -537,6 +541,58 @@ def test_recurrent_state_is_updated_in_place(one_chip, program):
             assert not dims & slabs, line[:160]
     if program == "step":
         _assert_the_benchmarks_pattern_finds_the_state_update(text, slabs)
+        _assert_one_kernel_passes_over_the_slab(text, slabs)
+
+
+@pytest.mark.parametrize("rows, tokens", [(128, 1), (1, 64)],
+                         ids=["step", "prefill_chunk"])
+def test_kda_state_update_compiles_at_solar_rows(one_chip, rows, tokens):
+    """The state-update kernel at the cell's own shapes (64 heads of
+    128 x 128 float32): the step's 128 slots at one token, the
+    prefill's one request at a chunk of 64.  The state is the call's
+    operand as it lies and its second result's buffer; the blocks fit
+    the kernel's VMEM budget."""
+    from paddle_tpu.ops import pallas_kda_update as kda
+
+    h, d = 64, 128
+    vec, f32 = (rows, tokens, h, d), jnp.float32
+    assert kda.kda_rule(h, d, d, f32)
+    assert kda.head_block(tokens, h, d, d) == 16
+    text = _compile(
+        one_chip, kda.kda_update, (vec, f32), (vec, f32), (vec, f32),
+        (vec, f32), (vec[:3], f32), ((rows, h, d, d), f32),
+        ((rows,), jnp.int32))
+    call = [ln for ln in text.splitlines()
+            if f"%{kda.KERNEL_NAME}" in ln.split(" = ")[0]
+            and "tpu_custom_call" in ln]
+    assert len(call) == 1
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(3, \{\}\)\}",
+                     call[0])
+
+
+def _assert_one_kernel_passes_over_the_slab(text, slabs):
+    """The step's matrices ``%state_2_`` are an operand of ONE
+    instruction of the entry computation, the state-update kernel, as
+    the program's own argument (no copy, bitcast or mask in front), and
+    that call's result of the slab's shape is the operand's buffer."""
+    from paddle_tpu.ops import pallas_kda_update as kda
+
+    entry = _computation(text, "ENTRY ")
+    readers = [ln for ln in entry
+               if re.search(r"%state_2_\.\d+[,)]", ln.split(" = ", 1)[-1])]
+    assert len(readers) == 1, [ln[:120] for ln in readers]
+    call = readers[0]
+    assert f"%{kda.KERNEL_NAME}" in call.split(" = ")[0] \
+        and "tpu_custom_call" in call
+    m = _INSTR.match(call)
+    assert {tuple(int(d) for d in a.split(",") if d)
+            for a in _ARRAY.findall(m["type"])} & slabs
+    # operand 3 (after the live flags and the two stacks of vectors) is
+    # result 1
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(3, \{\}\)\}",
+                     call), call[-400:]
+    assert not [ln for ln in entry if " select(" in ln and any(
+        "[%s]" % ",".join(map(str, s)) in ln for s in slabs)]
 
 
 def _assert_the_benchmarks_pattern_finds_the_state_update(text, slabs):
