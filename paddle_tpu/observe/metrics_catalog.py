@@ -154,6 +154,18 @@ RULES = (
          "holds (two a row) fills and walks it again, reading the held "
          "experts' weights once more each time; nothing is dropped and "
          "no other form takes over.  0 under any routing near uniform"),
+    Rule("moe_hit_form_calls", "gauge", "expert_parallel",
+         "Calls of `moe_share_ffn` in a joint decode step that took the "
+         "hit form (`ops/pallas_moe_hit.py`: one kernel over the held "
+         "experts some live row chose; engaged by `moe_ops.hit_rule` "
+         "from the step's static shape and the model's `top_k` / "
+         "`num_experts`), one an expert layer a step.  Rides the step's "
+         "one read-back; 0 where the step keeps the dense form, which "
+         "then does not read it back at all"),
+    Rule("moe_experts_skipped", "gauge", "expert_parallel",
+         "Held experts whose weights those calls did not read, summed "
+         "over a joint decode step's layers: with `moe_experts_hit` it "
+         "adds up to held experts x expert layers a step"),
     Rule("moe_", "gauge", "expert_parallel",
          "Mixture-of-experts routing: expert balance and drop "
          "fractions (ppm), routed-FFN engagement, all-to-all "

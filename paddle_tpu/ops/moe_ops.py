@@ -50,8 +50,11 @@ __all__ = [
     "moe_share_counts",
     "moe_share_ffn",
     "grouped_rule",
+    "hit_rule",
+    "expected_hit_share",
     "ridge_rows",
     "GROUPED_TALLIES",
+    "HIT_TALLIES",
 ]
 
 
@@ -278,11 +281,21 @@ def _moe_ffn_lower(ctx, op):
 # part of the result its own experts give.  Nothing is dropped and nothing
 # is sized by a capacity that drops: the held experts' weights are three
 # plain matrices ([D, n_held*F], [D, n_held*F], [n_held*F, D]) and one
-# result has two forms, chosen by the call's shape (``grouped_rule``).
-# At decode (a hundred rows) every row runs through all three matrices
-# and a row's hidden units of an expert it did not choose are multiplied
-# by zero: three matmuls that stream the weights once and are bound by
-# that read.  At a prefill's rows that form would pay n_held / (local
+# result has three forms, chosen by the call's static shape and the
+# model's published ``top_k`` / ``num_experts`` (``grouped_rule``,
+# ``hit_rule``; no flag, the same answer on every backend).
+# DENSE, few rows that between them choose nearly every held expert (a
+# decode step where a chip holds a large share of the experts): every row
+# runs through all three matrices and a row's hidden units of an expert
+# it did not choose are multiplied by zero: three matmuls that stream
+# the weights once and are bound by that read.
+# HIT, few rows that are expected to leave held experts un-chosen (a
+# decode step where a chip holds few experts of many): the dense form
+# would stream weights that are multiplied by nothing but zeros, so one
+# kernel walks the list of held experts some live row chose and reads
+# those alone (``ops/pallas_moe_hit.py``); every row still meets every
+# HIT expert, the weight decides.
+# GROUPED, a prefill's rows: the dense form would pay n_held / (local
 # assignments a row) times the multiply-adds, so the (row, held expert)
 # pairs with a weight are sorted by expert and run as a grouped matmul
 # (``ops/pallas_moe_grouped.py``): the weights still read once, where
@@ -376,28 +389,69 @@ def grouped_rule(rows, n_held, expert_dim, d_model):
             and expert_dim % 128 == 0 and d_model % 128 == 0)
 
 
+# what the hit form counts of the calls it takes, through ``tally``: the
+# calls, and the held experts whose weights such a call did not read
+HIT_TALLIES = ("moe_hit_form_calls", "moe_experts_skipped")
+# the expected share of the held experts some row chooses, under which
+# the hit form takes a call.  Measured on the v5e, alone, at the four
+# cells' step shapes (PERF.md, PR 48): the kernel reads a hit expert at
+# 81-88 % of the HBM rate where the dense form reads all of them at 90 %,
+# and is the faster wherever under 90 % of the held experts are hit (the
+# crossovers: 97, 90, 90.5 and 98 %); a trained or seeded router hits
+# fewer than uniform choices would, so the expectation errs to the dense
+# form's side
+HIT_BELOW_SHARE = 0.9
+
+
+def expected_hit_share(rows, top_k, num_experts):
+    """The share of a chip's held experts that ``rows`` rows choose at
+    least once, were each row's ``top_k`` of ``num_experts`` uniform:
+    what the call's shape says about the weights it has to read."""
+    return 1.0 - (1.0 - top_k / num_experts) ** rows
+
+
+def hit_rule(rows, n_held, expert_dim, d_model, top_k, num_experts):
+    """Whether a call of ``rows`` rows over ``n_held`` held experts of a
+    model that routes ``top_k`` of ``num_experts`` takes the hit form: a
+    function of the call's static shape and the model's published
+    routing alone.  Under a ridge the weight read is the cost, and the
+    hit form reads ``expected_hit_share`` of what the dense form reads,
+    a little slower a byte: it takes the calls whose expected share is
+    under ``HIT_BELOW_SHARE``.  The kernel's blocks are whole lanes: a
+    width no 128 divides keeps the dense form."""
+    return (rows < ridge_rows()
+            and expected_hit_share(rows, top_k, num_experts)
+            < HIT_BELOW_SHARE
+            and expert_dim % 128 == 0 and d_model % 128 == 0)
+
+
 def moe_share_ffn(h, local, w_gate, w_up, w_down, *, tally=None,
-                  interpret=False):
+                  interpret=False, top_k=None, num_experts=None):
     """The held experts' part of the routed result for rows ``h
     [..., D]``: ``sum_j local[r, j] * E_j(h_r)`` with ``E(h) =
     (silu(h W_gate) * h W_up) W_down``.  ``w_gate``/``w_up`` are
     ``[D, n_held*F]`` (expert j's columns ``j*F:(j+1)*F``), ``w_down``
     ``[n_held*F, D]``; matmuls take the weights' dtype in and float32
-    out.  Dropless under any imbalance.  Few rows (``grouped_rule``):
-    every row meets every held expert, the weight decides.  Many: the
-    pairs with a weight, sorted by expert, as a grouped matmul; the
-    same sum to float32's order of summation.  ``tally(name, n)``, where
-    given, takes what the grouped form counts (``GROUPED_TALLIES``:
-    the pairs it computed, rows x ``n_held`` of the same calls, the
-    passes over the sorted buffer beyond a call's first).
-    ``interpret`` runs the grouped form's kernels interpreted (tests on
+    out.  Dropless under any imbalance.  Many rows (``grouped_rule``):
+    the pairs with a weight, sorted by expert, as a grouped matmul.
+    Few rows of a model whose ``top_k`` of ``num_experts`` (static; the
+    model's own) leave held experts un-chosen (``hit_rule``; never
+    where the two are not given): every row meets every held expert
+    some row chose, and the others' weights are not read.  Else every
+    row meets every held expert, the weight decides.  One sum to
+    float32's order of summation.  ``tally(name, n)``, where given,
+    takes what the grouped form counts (``GROUPED_TALLIES``: the pairs
+    it computed, rows x ``n_held`` of the same calls, the passes over
+    the sorted buffer beyond a call's first) and the hit form
+    (``HIT_TALLIES``: its calls, the held experts it skipped).
+    ``interpret`` runs either form's kernels interpreted (tests on
     the CPU, as ``DecodeConfig.interpret`` does the attention's); with
     no chip and without it the kernels fail to lower, loudly."""
     n_held = local.shape[-1]
     rows = math.prod(h.shape[:-1])
+    shape = (rows, n_held, w_down.shape[0] // n_held, h.shape[-1])
     with jax.named_scope(EXPERTS_SCOPE):
-        if grouped_rule(rows, n_held, w_down.shape[0] // n_held,
-                        h.shape[-1]):
+        if grouped_rule(*shape):
             from .pallas_moe_grouped import grouped_share_ffn
 
             out, pairs, passes = grouped_share_ffn(
@@ -405,6 +459,15 @@ def moe_share_ffn(h, local, w_gate, w_up, w_down, *, tally=None,
             if tally is not None:
                 for name, n in zip(GROUPED_TALLIES, (
                         pairs, rows * n_held, jnp.maximum(passes - 1, 0))):
+                    tally(name, n)
+            return out
+        if top_k is not None and hit_rule(*shape, top_k, num_experts):
+            from .pallas_moe_hit import hit_share_ffn
+
+            out, n_hit = hit_share_ffn(
+                h, local, w_gate, w_up, w_down, interpret=interpret)
+            if tally is not None:
+                for name, n in zip(HIT_TALLIES, (1, n_held - n_hit)):
                     tally(name, n)
             return out
         x = h.astype(w_gate.dtype)

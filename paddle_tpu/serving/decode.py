@@ -317,7 +317,7 @@ class _Mixed:
     threads."""
 
     def __init__(self, model, spec: Optional[RecurrentSpec],
-                 window: Optional[WindowSpec] = None):
+                 window: Optional[WindowSpec], slots: int):
         self.kinds = tuple(model.layer_kinds)
         self.layer = {kind: {l: i for i, l in enumerate(
             l for l, k in enumerate(self.kinds) if k == kind)}
@@ -325,7 +325,12 @@ class _Mixed:
         self.names = tuple(spec.arrays) if spec is not None else ()
         self.n_arrays = len(self.layer["recurrent"]) * len(self.names)
         self.n_window = 2 if window is not None else 0
-        self.tallies = tuple(getattr(model, "tallies", ()))
+        # every counter ``forward`` may add to, and those of them a
+        # joint step of ``slots`` rows reads back (all, unless the model
+        # says which: a form chosen by the step's shape brings its own)
+        self.declared = tuple(getattr(model, "tallies", ()))
+        self.tallies = tuple(model.step_tallies(slots)) if hasattr(
+            model, "step_tallies") else self.declared
         # what a whole-prompt prefill reads back with its token: its
         # recurrent layers' scans, then those of the model's counters it
         # declares for a prefill
@@ -409,9 +414,9 @@ class _Mixers:
     def tally(self, name, value):
         if name in self.counts:
             self.counts[name] += value
-        elif name not in self._mixed.prefill_tallies:
+        elif name not in self._mixed.declared + self._mixed.prefill_tallies:
             raise KeyError(f"{name!r} is not among the model's declared "
-                           f"tallies {self._mixed.tallies}")
+                           f"tallies {self._mixed.declared}")
 
     def record(self, name, rows):
         self.records.setdefault(name, []).append(rows)
@@ -737,7 +742,10 @@ class DecodeEngine:
       of ONE slot's state of ONE such layer, in slot-indexed slabs
       (``kv_cache.RecurrentSpec``).
 
-    ``tallies`` are the names of the counters ``forward`` adds to.
+    ``tallies`` are the names of the counters ``forward`` adds to; a
+    model with ``step_tallies(rows)`` says which of them a joint step
+    of ``rows`` rows reads back (a form of a layer chosen by the step's
+    shape brings its counters: the others are counted and dropped).
     ``attend`` is then a ``_Mixers``: the call as above for an attention
     or window layer (mapped to ITS kind's pools);
     ``attend.recur(layer, token_fn, rows, cache, chunk_fn=None, chunk=0)
@@ -861,7 +869,7 @@ class DecodeEngine:
         self._window = WindowSpec(
             n_win, getattr(model, "window_kv_heads", kv_heads),
             model.head_dim, v_dim, model.window) if n_win else None
-        self._mixed = _Mixed(model, spec, self._window) \
+        self._mixed = _Mixed(model, spec, self._window, c.slots) \
             if getattr(model, "layer_kinds", None) else None
         # the model's counters behind a step's tokens, as it declares them
         self._tallies = self._mixed.tallies if self._mixed else ()

@@ -54,6 +54,18 @@ KDA_SCOPE = "kda_update"
 PREFILL_CHUNK = 64
 
 
+def step_tallies(model, rows):
+    """Of a routed model's declared ``tallies``, those a joint step of
+    ``rows`` rows reads back: the hit form's only where that step takes
+    the form (``moe_ops.hit_rule``: the step's static shape and the
+    model's own routing), so a step that keeps the dense form is the
+    program it was.  The routed models' ``step_tallies`` method."""
+    if moe_ops.hit_rule(rows, len(model.held_experts), model.expert_dim,
+                        model.d_model, model.top_k, model.num_experts):
+        return model.tallies
+    return tuple(n for n in model.tallies if n not in moe_ops.HIT_TALLIES)
+
+
 class HybridMoELM:
     """Sized by constructor arguments; ``layer_kinds`` is the pattern
     (Solar-Open2: one ``"attention"`` then three ``"recurrent"`` a
@@ -95,8 +107,10 @@ class HybridMoELM:
         # step's, and those only a whole-prompt prefill reads back
         # ``kda_kernel_rows``: live rows x recurrent layers whose state a
         # step's kernel calls updated (0 where the XLA form serves)
+        # ``HIT_TALLIES``: read back by a step that takes the hit form
+        # (``step_tallies``), counted and dropped anywhere else
         self.tallies = ("moe_local_assignments", "moe_experts_hit",
-                        "kda_kernel_rows")
+                        "kda_kernel_rows") + moe_ops.HIT_TALLIES
         self.prefill_tallies = moe_ops.GROUPED_TALLIES
         c = self.lin_heads * self.lin_head_dim
         # one slot's state of ONE recurrent layer: the delta rule's
@@ -106,6 +120,8 @@ class HybridMoELM:
             "s": ((self.lin_heads, self.lin_head_dim, self.lin_head_dim),
                   np.float32),
             "tail": (((self.conv_kernel - 1) * 3 * c,), np.float32)}
+
+    step_tallies = step_tallies
 
     # -- weights ------------------------------------------------------------
     def init_weights(self, key):
@@ -213,10 +229,7 @@ class HybridMoELM:
                 shared = _mm(jax.nn.silu(_mm(h, lw["shared_w_gate"]))
                              * _mm(h, lw["shared_w_up"]),
                              lw["shared_w_down"])
-            x = x + moe_ops.moe_share_ffn(
-                h, local, lw["moe_w_gate"], lw["moe_w_up"],
-                lw["moe_w_down"], tally=attend.tally,
-                interpret=attend.interpret) + shared
+            x = x + share_ffn(self, h, lw, local, attend) + shared
         return _mm(self._rms(x, w["norm_f"]), w["lm_head"]), cache
 
     def _rms(self, x, g):
@@ -365,6 +378,16 @@ def rms_norm(x, g, eps):
 
     return x * jax.lax.rsqrt(
         jnp.mean(x * x, axis=-1, keepdims=True) + eps) * g
+
+
+def share_ffn(model, h, lw, local, attend):
+    """The held experts' part of the routed result for rows ``h`` under
+    ``route_share``'s ``local``, in the form the call's shape and the
+    model's published routing choose (``moe_ops.moe_share_ffn``)."""
+    return moe_ops.moe_share_ffn(
+        h, local, lw["moe_w_gate"], lw["moe_w_up"], lw["moe_w_down"],
+        tally=attend.tally, interpret=attend.interpret,
+        top_k=model.top_k, num_experts=model.num_experts)
 
 
 def route_share(h, lw, attend, top_k, held_experts):

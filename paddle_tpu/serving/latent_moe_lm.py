@@ -51,7 +51,8 @@ import math
 from typing import Sequence
 
 from ..ops import moe_ops
-from .hybrid_moe_lm import _mm, rms_norm, route_share
+from .hybrid_moe_lm import (_mm, rms_norm, route_share, share_ffn,
+                            step_tallies)
 from .window_moe_lm import DENSE_SCOPE, ROPE_SCOPE
 
 Q_PROJ_SCOPE = "latent_q_proj"          # q_a, its norm, q_b
@@ -154,8 +155,13 @@ class LatentMoELM:
         self.max_seq_len = int(max_seq_len)     # no positional table
         # the counters forward adds to through attend.tally: a joint
         # step's, and those only a whole-prompt prefill reads back
-        self.tallies = ("moe_local_assignments", "moe_experts_hit")
+        # ``HIT_TALLIES``: read back by a step that takes the hit form
+        # (``step_tallies``), counted and dropped anywhere else
+        self.tallies = ("moe_local_assignments", "moe_experts_hit") \
+            + moe_ops.HIT_TALLIES
         self.prefill_tallies = moe_ops.GROUPED_TALLIES
+
+    step_tallies = step_tallies
 
     # -- weights ------------------------------------------------------------
     def init_weights(self, key):
@@ -235,10 +241,7 @@ class LatentMoELM:
                 continue
             local = route_share(h, lw, attend, self.top_k,
                                 self.held_experts)
-            routed = moe_ops.moe_share_ffn(
-                h, local, lw["moe_w_gate"], lw["moe_w_up"],
-                lw["moe_w_down"], tally=attend.tally,
-                interpret=attend.interpret)
+            routed = share_ffn(self, h, lw, local, attend)
             with jax.named_scope(SHARED_SCOPE):
                 x = x + self.routed_scale * routed + _swiglu(h, lw, "shared")
         if attend.prompt and attend.read_row is not None:

@@ -31,7 +31,7 @@ import math
 from typing import Sequence
 
 from ..ops import moe_ops
-from .hybrid_moe_lm import _mm, route_share
+from .hybrid_moe_lm import _mm, route_share, share_ffn, step_tallies
 from .window_moe_lm import ROPE_SCOPE
 
 SHARED_SCOPE = "moe_shared"
@@ -87,8 +87,13 @@ class ParallelMoELM:
         self.max_seq_len = int(max_seq_len)     # no positional table
         # the counters forward adds to through attend.tally: a joint
         # step's, and those only a whole-prompt prefill reads back
-        self.tallies = ("moe_local_assignments", "moe_experts_hit")
+        # ``HIT_TALLIES``: read back by a step that takes the hit form
+        # (``step_tallies``), counted and dropped anywhere else
+        self.tallies = ("moe_local_assignments", "moe_experts_hit") \
+            + moe_ops.HIT_TALLIES
         self.prefill_tallies = moe_ops.GROUPED_TALLIES
+
+    step_tallies = step_tallies
 
     # -- weights ------------------------------------------------------------
     def init_weights(self, key):
@@ -177,10 +182,7 @@ class ParallelMoELM:
             shared = _mm(jax.nn.silu(_mm(h, lw["shared_w_gate"]))
                          * _mm(h, lw["shared_w_up"]),
                          lw["shared_w_down"]) / self.shared_experts
-        return moe_ops.moe_share_ffn(
-            h, local, lw["moe_w_gate"], lw["moe_w_up"],
-            lw["moe_w_down"], tally=attend.tally,
-            interpret=attend.interpret) + shared
+        return share_ffn(self, h, lw, local, attend) + shared
 
     def _head(self, weights, x):
         """The final norm times the TRANSPOSED input embedding."""

@@ -30,7 +30,8 @@ import math
 from typing import Sequence
 
 from ..ops import moe_ops
-from .hybrid_moe_lm import _mm, rms_norm, route_share
+from .hybrid_moe_lm import (_mm, rms_norm, route_share, share_ffn,
+                            step_tallies)
 
 ROPE_SCOPE = "rope"
 DENSE_SCOPE = "dense_ffn"
@@ -92,12 +93,17 @@ class WindowMoELM:
         self.max_seq_len = int(max_seq_len)     # no positional table
         # the counters forward adds to through attend.tally: a joint
         # step's, and those only a whole-prompt prefill reads back
-        self.tallies = ("moe_local_assignments", "moe_experts_hit")
+        # ``HIT_TALLIES``: read back by a step that takes the hit form
+        # (``step_tallies``), counted and dropped anywhere else
+        self.tallies = ("moe_local_assignments", "moe_experts_hit") \
+            + moe_ops.HIT_TALLIES
         self.prefill_tallies = moe_ops.GROUPED_TALLIES
 
     def kv_heads(self, kind: str) -> int:
         return self.window_kv_heads if kind == "window" \
             else self.num_kv_heads
+
+    step_tallies = step_tallies
 
     # -- weights ------------------------------------------------------------
     def init_weights(self, key):
@@ -180,10 +186,7 @@ class WindowMoELM:
             else:
                 local = route_share(h, lw, attend, self.top_k,
                                     self.held_experts)
-                x = x + moe_ops.moe_share_ffn(
-                    h, local, lw["moe_w_gate"], lw["moe_w_up"],
-                    lw["moe_w_down"], tally=attend.tally,
-                    interpret=attend.interpret)
+                x = x + share_ffn(self, h, lw, local, attend)
         return _mm(rms_norm(x, w["norm_f"], self.rms_eps),
                    w["lm_head"]), cache
 

@@ -495,8 +495,11 @@ def test_the_tallies_are_the_models_declared_names_before_any_trace():
     model = make_model(PERIOD)
     weights = model.init_weights(jax.random.PRNGKey(17))
     eng = engine(model, weights)
-    assert eng._tallies == model.tallies == (
+    # a step whose shape keeps the dense form reads back no counter of
+    # the hit form's: they are declared, counted and dropped
+    assert eng._tallies == model.step_tallies(3) == (
         "moe_local_assignments", "moe_experts_hit", "kda_kernel_rows")
+    assert model.tallies == eng._tallies + moe_ops.HIT_TALLIES
     # a step reads back no counter of the prefill's; a count of one
     # there goes nowhere and fails nothing
     assert eng._prefill_tallies[-3:] == model.prefill_tallies \
@@ -507,6 +510,49 @@ def test_the_tallies_are_the_models_declared_names_before_any_trace():
     model.tallies = ("moe_experts_hit",)
     with pytest.raises(KeyError, match="moe_local_assignments"):
         engine(model, weights).lower_step()
+
+
+# sha256 of the joint step's lowered text at the three test files' sizes
+# (``make_model()``, 3 slots), taken on the commit before the hit form
+STEPS_AS_LOWERED = {
+    "hybrid": (
+        "test_hybrid_moe_serving",
+        "23b6b195485bee5f378f52bef5cee09d580ef8b02124b31074235d758f0fb13c"),
+    "window": (
+        "test_window_moe_serving",
+        "f56d9f177d2b575b22bc1ec8ddf398b45767e37d87124243529f20670cfae4eb"),
+    "parallel": (
+        "test_parallel_moe_serving",
+        "c59449e598f7237f0f7df03b192fe62395a97438c12c1ba58e2727908d2c3c56"),
+}
+
+
+@pytest.mark.parametrize("which", sorted(STEPS_AS_LOWERED))
+def test_a_step_that_keeps_the_dense_form_is_the_program_it_was(which):
+    """``HybridMoELM``, ``WindowMoELM`` and ``ParallelMoELM`` call the
+    same ``moe_share_ffn`` as the model whose step takes the hit form
+    (PR 48).  Where the rule leaves a step on the dense form (here by
+    the toy widths, in the three cells by the share of the held experts
+    their rows are expected to hit: ``tests/test_moe.py`` has the
+    table) the step reads back no counter of the hit form's and lowers
+    to the text it had before.  A change MEANT to move these programs
+    replaces the digests; one that was not has found out here."""
+    import hashlib
+    import importlib
+
+    module, digest = STEPS_AS_LOWERED[which]
+    tests = importlib.import_module(module)
+    model = tests.make_model()
+    eng = tests.engine(model, model.init_weights(jax.random.PRNGKey(1)))
+    assert not moe_ops.hit_rule(
+        3, len(model.held_experts), model.expert_dim, model.d_model,
+        model.top_k, model.num_experts)
+    assert not set(eng._tallies) & set(moe_ops.HIT_TALLIES)
+    assert set(model.tallies) - set(eng._tallies) \
+        == set(moe_ops.HIT_TALLIES)
+    text = eng.lower_step().as_text()
+    assert "tpu_custom_call" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
 
 
 def _function(text, name):

@@ -405,3 +405,36 @@ def test_a_latent_rounded_to_eight_bits_is_told_by_the_step():
         weights, jnp.asarray(prompt + toks[:-1], jnp.int32), dims(model))
     err = np.abs(np.stack(r.logits_trace) - np.asarray(want)[29:]).max(1)
     assert err[0] < 5e-5 and err[1:].min() > 1e-3, err
+
+
+def test_a_step_that_holds_few_experts_of_many_reads_the_hit_ones_alone():
+    """Widths of whole lane tiles and 2 of 64 experts a row, 6 of them
+    held: three slots' uniform choices would hit 9 % of the held
+    experts, so the joint step takes the hit form (interpreted) and the
+    whole-prompt prefill, a bucket of 16 or 64 rows, takes it or the
+    dense lines by the same rule.  The served logits are the whole-
+    sequence reference's, and the step's own counters say one call an
+    expert layer a step, and that skipped + hit is what is held, every
+    step."""
+    held = (3, 9, 20, 33, 47, 60)
+    model = make_model(held=held, d_model=128, expert_dim=128,
+                       num_experts=64, top_k=2)
+    assert model.step_tallies(3) == model.tallies
+    assert model.tallies[-2:] == moe_ops.HIT_TALLIES
+    assert moe_ops.hit_rule(3, len(held), 128, 128, 2, 64)
+    assert moe_ops.hit_rule(16, len(held), 128, 128, 2, 64)
+    assert not moe_ops.hit_rule(128, len(held), 128, 128, 2, 64)
+    weights = model.init_weights(jax.random.PRNGKey(48))
+    rng = np.random.RandomState(49)
+    prompts = [rng.randint(0, VOCAB, n).tolist() for n in (7, 100)]
+    names = moe_ops.HIT_TALLIES + ("moe_experts_hit", "decode_steps")
+    before = {n: stat_get(n) for n in names}
+    with engine(model, weights, interpret=True) as eng:
+        assert eng._tallies == model.tallies
+        assert served_vs_reference(eng, model, weights, prompts, 9) < 5e-5
+    d = {n: stat_get(n) - v for n, v in before.items()}
+    assert d["decode_steps"] >= 8
+    assert d["moe_hit_form_calls"] == 2 * d["decode_steps"] > 0
+    assert d["moe_experts_skipped"] + d["moe_experts_hit"] \
+        == 2 * len(held) * d["decode_steps"]
+    assert d["moe_experts_skipped"] > d["moe_experts_hit"]

@@ -447,3 +447,179 @@ class TestComposition:
             assert shard_shapes == {(E // 4, DM, FFN)}
         finally:
             reset_mesh()
+
+
+# ---------------------------------------------------------------------------
+# a held share of the experts at a decode step's rows: the hit form
+# (ops/pallas_moe_hit.py), against the dense lines of moe_share_ffn
+# ---------------------------------------------------------------------------
+
+HIT_PATTERNS = ("none", "one", "every_other", "all", "last_alone",
+                "dead_rows", "zero_weight")
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """Blocks of ONE lane tile, so a test's widths of two and three
+    tiles walk several gate/up blocks and several down blocks an expert
+    (as served a block is megabytes: a test's whole width)."""
+    from paddle_tpu.ops import pallas_moe_hit as hit
+
+    monkeypatch.setattr(hit, "_GATE_UP_BLOCK", 1)
+    monkeypatch.setattr(hit, "_DOWN_BLOCK", 1)
+    hit.hit_share_ffn.clear_cache()
+    yield hit
+    hit.hit_share_ffn.clear_cache()
+
+
+def _hit_case(pattern, d, f, n_held=6, rows=9):
+    """Rows, their weights for the held experts under ``pattern`` and
+    the three matrices, float32; ``hit`` is the experts the kernel has
+    to read."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import moe_ops
+
+    k = jax.random.split(jax.random.PRNGKey(48), 6)
+    h = jax.random.normal(k[0], (rows, d), jnp.float32)
+    w = [jax.random.normal(kk, shape) / np.sqrt(shape[0])
+         for kk, shape in zip(k[1:4], ((d, n_held * f), (d, n_held * f),
+                                       (n_held * f, d)))]
+    weight = jax.random.uniform(k[4], (rows, n_held), minval=0.05,
+                                maxval=0.4)
+    mask = np.zeros((rows, n_held), bool)
+    if pattern == "one":
+        mask[[1, 4], 2] = True
+    elif pattern == "every_other":
+        mask[:, ::2] = np.random.RandomState(1).rand(rows, 3) < 0.5
+        mask[0, ::2] = True
+    elif pattern == "all":
+        mask[:] = True
+    elif pattern == "last_alone":
+        mask[3, n_held - 1] = True
+    elif pattern == "zero_weight":
+        # expert 1 is hit by row 0 alone: every other row meets it with
+        # a weight of exactly 0
+        mask[0, 1] = mask[2, 4] = mask[5, 4] = True
+    local = jnp.where(mask, weight, 0.0)
+    if pattern == "dead_rows":
+        # the router's own weights; the dead rows chose experts no live
+        # row chose, and ``live`` takes them out before the form sees them
+        router = jax.random.normal(k[5], (d, 16)) / np.sqrt(d)
+        route = lambda live: moe_ops.moe_share_route(  # noqa: E731
+            h, router, jnp.zeros((16,)), top_k=2,
+            held_ids=range(n_held), live=live)[2]
+        chosen = np.asarray(route(None)) > 0
+        live = np.ones(rows, bool)
+        for r in range(rows):       # kill rows until an expert goes dark
+            live[r] = False
+            if (chosen[live].any(0) != chosen.any(0)).any():
+                break
+        assert chosen[~live].any() and live.sum() >= 3
+        local = route(jnp.asarray(live))
+        mask = np.asarray(local) > 0
+        assert not mask[~live].any()
+        assert mask.any(0).sum() < chosen.any(0).sum()
+    return h, local, w, mask.any(0)
+
+
+@pytest.mark.parametrize("d, f", [(256, 256), (128, 384)])
+@pytest.mark.parametrize("pattern", HIT_PATTERNS)
+def test_the_hit_form_is_the_dense_form(small_blocks, pattern, d, f):
+    """``hit_share_ffn`` (interpreted; two or three blocks a phase an
+    expert) against the three matmuls over every row and every held
+    expert, to float32's order of summation; it reads exactly the
+    experts some row has a non-zero weight for (``n_hit``), none where
+    no row chose any (the result is exactly zero), the LAST one alone
+    through the clamp, and not those only dead rows chose."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import moe_ops
+
+    h, local, w, hit = _hit_case(pattern, d, f)
+    assert small_blocks.hit_blocks(d, f, 4) == (128, 128)
+    dense = moe_ops.moe_share_ffn(h, local, *w)
+    out, n_hit = small_blocks.hit_share_ffn(h, local, *w, interpret=True)
+    assert out.shape == dense.shape and out.dtype == jnp.float32
+    assert int(n_hit) == hit.sum() == {
+        "none": 0, "one": 1, "every_other": 3, "all": 6, "last_alone": 1,
+        "zero_weight": 2}.get(pattern, int(n_hit))
+    if pattern == "none":
+        assert not np.asarray(out).any()
+    scale = float(jnp.abs(dense).max()) or 1.0
+    assert float(jnp.abs(out - dense).max()) <= 1e-5 * scale
+
+
+def test_the_hit_form_counts_its_calls_and_the_experts_it_skipped(
+        small_blocks):
+    """Through ``moe_share_ffn`` with the model's routing handed in: the
+    rule takes the call, ``tally`` gets ``HIT_TALLIES`` and nothing
+    else, and skipped + hit = ``n_held`` a call; without ``top_k`` /
+    ``num_experts`` the same call keeps the dense lines and counts
+    nothing."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import moe_ops
+
+    for pattern in ("none", "every_other", "dead_rows", "all"):
+        h, local, w, hit = _hit_case(pattern, 128, 128)
+        rows, n_held = local.shape
+        assert moe_ops.hit_rule(rows, n_held, 128, 128, 2, 64)
+        counted = {}
+        out = moe_ops.moe_share_ffn(
+            h, local, *w, tally=counted.__setitem__, interpret=True,
+            top_k=2, num_experts=64)
+        assert tuple(counted) == moe_ops.HIT_TALLIES
+        assert counted["moe_hit_form_calls"] == 1
+        assert int(counted["moe_experts_skipped"]) + hit.sum() == n_held
+        assert int(moe_ops.moe_share_counts(local)[1]) == hit.sum()
+        counted.clear()
+        dense = moe_ops.moe_share_ffn(h, local, *w,
+                                      tally=counted.__setitem__)
+        assert not counted
+        assert float(jnp.abs(out - dense).max()) <= 1e-5 * max(
+            float(jnp.abs(dense).max()), 1.0)
+    # no chip and not asked to interpret: the kernel refuses, loudly
+    with pytest.raises(ValueError, match="interpret"):
+        moe_ops.moe_share_ffn(h, local, *w, top_k=2, num_experts=64)
+
+
+# (rows of the joint step, n_held, F, D, top_k, num_experts, the
+# whole-prompt prefill's buckets) of the four routed cells
+ROUTED_CELLS = {
+    "kimi_k2_5": (64, 12, 2048, 7168, 8, 384, (8192,)),
+    "solar_open2_250b": (128, 40, 1280, 4096, 8, 320, (256, 512, 1024)),
+    "mimo_v2_5": (128, 16, 2048, 4096, 8, 256, (512, 1024, 2048)),
+    "command_a_plus": (48, 8, 4096, 4096, 8, 128, (4096,)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ROUTED_CELLS))
+def test_the_rule_at_the_cells_shapes(cell):
+    """Who takes which form, by the call's static shape and the model's
+    published routing alone: Kimi-K2.5's step (12 held of 384, 64 rows:
+    uniform choices would hit 74 %) takes the hit form; Solar's, MiMo's
+    and Command A+'s (96 %, 98 %, 95.5 %) keep the dense form, where
+    the kernel's alone-timings lose to it (PERF.md, PR 48); every
+    prefill bucket is past the ridge and never asks; no call passes
+    both rules; a width no 128 divides keeps the dense form."""
+    from paddle_tpu.ops import moe_ops
+
+    rows, n_held, f, d, top_k, n_exp, buckets = ROUTED_CELLS[cell]
+    share = moe_ops.expected_hit_share(rows, top_k, n_exp)
+    assert share == pytest.approx({
+        "kimi_k2_5": 0.740, "solar_open2_250b": 0.961,
+        "mimo_v2_5": 0.983, "command_a_plus": 0.955}[cell], abs=1e-3)
+    takes = moe_ops.hit_rule(rows, n_held, f, d, top_k, n_exp)
+    assert takes == (cell == "kimi_k2_5") == (
+        share < moe_ops.HIT_BELOW_SHARE)
+    assert not moe_ops.grouped_rule(rows, n_held, f, d)
+    for bucket in buckets:
+        assert not moe_ops.hit_rule(bucket, n_held, f, d, top_k, n_exp)
+        assert moe_ops.grouped_rule(bucket, n_held, f, d) \
+            == (bucket >= 512)
+    # one row of any of them would; not at a width of broken lanes
+    assert moe_ops.hit_rule(1, n_held, f, d, top_k, n_exp)
+    assert not moe_ops.hit_rule(1, n_held, f - 64, d, top_k, n_exp)
+    assert not moe_ops.hit_rule(1, n_held, f, d - 64, top_k, n_exp)

@@ -865,7 +865,11 @@ def _window_engine():
     """MiMo-V2.5's head widths behind the engine (64 query heads of 192
     lanes on 4 global / 8 window K/V heads, values of 128, a window of
     128 with a sink a head), one layer of each kind, one held expert and
-    a short vocabulary: only shapes matter to a compile."""
+    a short vocabulary: only shapes matter to a compile.  Its 8 slots
+    stand for the cell's 128, so it routes 8 of 16 experts a row where
+    the cell routes 8 of 256: four choices a held expert a step either
+    way, and the step keeps the dense form as the cell's does
+    (``moe_ops.hit_rule``)."""
     from paddle_tpu.serving import DecodeConfig, DecodeEngine
     from paddle_tpu.serving.window_moe_lm import WindowMoELM
 
@@ -874,7 +878,7 @@ def _window_engine():
         dense_layers=0, num_heads=64, num_kv_heads=4, window_kv_heads=8,
         head_dim=192, v_head_dim=128, rotary_dim=64, rope_theta=1e7,
         window_rope_theta=1e4, window=128, value_scale=0.707,
-        dense_dim=512, num_experts=256, top_k=8, held_experts=(0,),
+        dense_dim=512, num_experts=16, top_k=8, held_experts=(0,),
         expert_dim=2048)
     weights = jax.tree_util.tree_map(
         lambda s: jnp.zeros(s.shape, s.dtype),
@@ -1019,10 +1023,48 @@ def _latent_engine(layers=2, **cfg):
         use_pallas="always", cache_dtype="bfloat16"), **cfg)))
 
 
+def _hit_form_calls(eng, text):
+    """The hit form's kernel in a compiled step of ``eng``: one call an
+    expert layer, ``[slots, D]`` float32 out; the held experts' three
+    matrices reach it as the program's own arguments, by the names the
+    routed experts' metrics match, and nothing copies, transposes or
+    slices anything their size in front of it; no dense plane ``[slots,
+    n_held * F]`` is formed."""
+    from paddle_tpu.ops import moe_ops, pallas_moe_hit as hit
+
+    m = eng.model
+    slots, n_held = eng.config.slots, len(m.held_experts)
+    assert moe_ops.hit_rule(slots, n_held, m.expert_dim, m.d_model,
+                            m.top_k, m.num_experts)
+    assert eng._tallies[-2:] == moe_ops.HIT_TALLIES
+    wide = (m.d_model, n_held * m.expert_dim)
+    calls = [line.strip() for line in text.splitlines() if re.match(
+        r"\s*%" + hit.HIT_KERNEL_NAME + r"[.\d]* = ", line)]
+    pattern = _metric_pattern("moe_ffn_ms_per_step.serve")
+    assert _metric_pattern("moe_experts_roofline").pattern \
+        == pattern.pattern
+    for c in calls:
+        assert c.startswith("%s = f32[%d,%d]" % (
+            c.split(" = ")[0], slots, m.d_model)), c[:200]
+        assert sorted(pattern.findall(c)) == ["down", "gate", "up"], c[:600]
+    assert not re.search(r"f32\[%d,%d\]" % (slots, wide[1]), text)
+    for line in text.splitlines():
+        i = _INSTR.match(line)
+        if i and i["op"] in ("copy", "transpose", "copy-start", "slice",
+                             "slice-start", "dynamic-slice"):
+            dims = {tuple(int(d) for d in a.split(",") if d)
+                    for a in _ARRAY.findall(i["type"])}
+            assert not dims & {wide, wide[::-1]}, line[:160]
+    return calls
+
+
 @pytest.mark.parametrize("program", ["step", "prefill_8192"])
 def test_kimi_width_programs_compile(one_chip, program):
     """The joint step (the latent kernel a layer, by its own name: one
-    pool in, 64 x 64 rows of 512 lanes out, no V pool anywhere) and the
+    pool in, 64 x 64 rows of 512 lanes out, no V pool anywhere; the
+    expert layer's held experts as the ONE kernel of the hit form, 64
+    rows over 12 experts of 2,048 on 7,168, fed the program's own three
+    weight arguments and found by the routed experts' metrics) and the
     8,192-row whole-prompt prefill (the flash kernel over 64 ungrouped
     heads of K 192 / V 128, the query head-major; the two grouped-expert
     kernels at a width of 7,168; the latents, not the expanded K/V, to
@@ -1042,7 +1084,9 @@ def test_kimi_width_programs_compile(one_chip, program):
         text = compiled.as_text()
         calls = [line.strip() for line in text.splitlines() if re.match(
             r"\s*%" + pda.LATENT_KERNEL_NAME + r"[.\d]* = ", line)]
-        assert len(calls) == text.count("tpu_custom_call") == 2
+        hit_calls = _hit_form_calls(eng, text)
+        assert len(calls) + len(hit_calls) \
+            == text.count("tpu_custom_call") == 3
         assert all(c.startswith("%s = f32[64,64,512]" % c.split(" = ")[0])
                    and "f32[64,64,640]" in c and c.count(pool) == 1
                    for c in calls), calls
