@@ -321,6 +321,31 @@ RULES = (
          "row a position a layer for keys and values, at whole lane "
          "tiles, every page of every layer; 0 for a model that caches K "
          "and V"),
+    Rule("decode_cache_layers", "gauge", "serving",
+         "Depth of the engine's page pools: the layers of K and V a "
+         "token leaves in the cache, what the model declares as "
+         "`cache_layers` (a stack its tokens pass through several times "
+         "on the same weights keeps K and V of every pass), else one a "
+         "weight layer that has keys"),
+    Rule("decode_kv_pool_bytes", "gauge", "serving",
+         "Device bytes of the page pools, every cache layer and page, K "
+         "and V (scale pools included when quantized; the one pool of a "
+         "latent cache): over the positions of a pool layer it is what "
+         "a cached token costs"),
+    Rule("decode_loop_passes", "gauge", "serving",
+         "Passes of its stack a looped model's `forward` ran "
+         "(`serving/looped_lm.py` adds `loops`), read back with the "
+         "tokens of a joint decode step and of a whole-prompt prefill: "
+         "over `decode_steps` + `decode_prefills` it is the passes a "
+         "token takes (a multi-row program's are counted and dropped)"),
+    Rule("decode_loop_exit_mass", "gauge", "serving",
+         "Where a looped model's exit gate would let a joint decode "
+         "step's live rows leave, in THOUSANDTHS of a pass: the sum over "
+         "the rows of sum_t p_t (t + 1), p_t = lambda_t prod_{j<t} (1 - "
+         "lambda_j) with the rest on the last pass.  Over the live rows "
+         "x 1000 it is the expected pass of exit; at the published "
+         "threshold of 1 it decides nothing (every row runs every pass): "
+         "a number to watch, limits nothing"),
     Rule("decode_latent_positions_live", "gauge", "serving",
          "Cached rows the live slots' tokens attend a layer in a model "
          "with latent attention (a slot's length, the token itself "

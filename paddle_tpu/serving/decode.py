@@ -438,10 +438,11 @@ def _takes_live(token_fn) -> bool:
 def layers_of_kind(model, kind: str) -> int:
     """How many of ``model``'s layers are of ``kind`` (one of
     ``"attention"`` | ``"window"`` | ``"recurrent"``); a model that
-    declares no ``layer_kinds`` has attention layers only."""
+    declares no ``layer_kinds`` has attention layers only, one a cache
+    layer."""
     kinds = getattr(model, "layer_kinds", None)
     if kinds is None:
-        return int(model.num_layers) if kind == "attention" else 0
+        return cache_layers(model) if kind == "attention" else 0
     bad = set(kinds) - set(_KINDS)
     if bad:
         raise ValueError(f"layer_kinds holds {sorted(bad)}: a layer is one "
@@ -452,6 +453,55 @@ def layers_of_kind(model, kind: str) -> int:
 def recurrent_layers(model) -> int:
     """How many of ``model``'s layers keep state instead of keys."""
     return layers_of_kind(model, "recurrent")
+
+
+def cache_layers(model) -> int:
+    """How many layers of K and V ``model``'s tokens leave in the cache:
+    what it declares as ``cache_layers`` (a stack its tokens pass
+    through several times on the same weights keeps K and V of every
+    pass), else one a weight layer."""
+    return int(getattr(model, "cache_layers", model.num_layers))
+
+
+class _Counting:
+    """What ``forward`` of a model WITHOUT ``layer_kinds`` that declares
+    ``tallies`` gets as ``attend``: the call as it is, ``live`` (bool,
+    the rows' shape: which rows are a request's) and ``tally(name, n)``
+    as ``_Mixers`` has them.  ``names`` are the counters this program
+    reads back; any other declared one is counted and dropped."""
+
+    def __init__(self, model, names=(), live=True, attend=None):
+        self.attend, self.live = attend, live
+        self._declared = tuple(model.tallies) + tuple(
+            getattr(model, "prefill_tallies", ()))
+        self.counts = dict.fromkeys(names, 0)
+
+    @classmethod
+    def of(cls, model, attend, names=(), live=True):
+        """``attend`` as it is for a model that declares no ``tallies``,
+        else behind a ``_Counting`` (``live`` True: every row, where the
+        program reads no counter back)."""
+        if not getattr(model, "tallies", ()):
+            return attend
+        return cls(model, names, live, attend)
+
+    def __call__(self, layer, q, k, v, cache):
+        return self.attend(layer, q, k, v, cache)
+
+    def tally(self, name, value):
+        if name in self.counts:
+            self.counts[name] += value
+        elif name not in self._declared:
+            raise KeyError(f"{name!r} is not among the model's declared "
+                           f"tallies {self._declared}")
+
+def _with_counts(tokens, mix, names):
+    """``tokens`` (int32 ``[S]``) with ``mix``'s counters ``names``
+    behind them: what a joint step's one read-back carries."""
+    import jax.numpy as jnp
+
+    return jnp.concatenate([tokens] + [
+        jnp.asarray(mix.counts[n], jnp.int32).reshape(1) for n in names])
 
 
 def per_slot_kinds(model):
@@ -708,8 +758,18 @@ class DecodeEngine:
     ``num_heads``, ``head_dim``, ``vocab_size``, ``max_seq_len`` and
     ``forward(weights, tokens, positions, cache, attend) -> (logits,
     cache)`` for any leading shape of ``tokens``, which calls
-    ``attend(layer, q, k, v, cache) -> (ctx, cache)`` once a layer, in
-    order, with ``[..., H, D]`` rows.  ``weights`` is the caller's
+    ``attend(layer, q, k, v, cache) -> (ctx, cache)`` once a CACHE
+    layer with ``[..., H, D]`` rows.  A model may declare
+    ``cache_layers`` (default ``num_layers``): how many layers of K and
+    V a token leaves behind, which is what sizes, counts and moves the
+    pools (their depth, a page's bytes and the budget, the draft's
+    pools, the hand-over).  ``attend``'s first argument is the cache
+    layer the call writes and reads: a Python ``int``, or a traced
+    ``int32`` scalar where ``forward`` runs its layers in a rolled loop
+    (``serving/looped_lm.py``: a stack every token passes through
+    ``loops`` times on the same weights, pass ``t`` of layer ``l`` at
+    cache layer ``t * num_layers + l``); every cache layer is written
+    exactly once a call of ``forward``.  ``weights`` is the caller's
     pytree (``init_weights`` is for callers).  The engine supplies
     ``attend`` — where K/V reach the pools and what is attended: one
     token a slot, a whole prompt, R rows a slot — and nothing else
@@ -742,7 +802,10 @@ class DecodeEngine:
       of ONE slot's state of ONE such layer, in slot-indexed slabs
       (``kv_cache.RecurrentSpec``).
 
-    ``tallies`` are the names of the counters ``forward`` adds to; a
+    ``tallies`` are the names of the counters ``forward`` adds to (a
+    model without ``layer_kinds`` that declares some gets ``attend``
+    with ``live`` and ``tally`` as below, a ``_Counting``; the multi-row
+    step and a draft's burst count and drop them); a
     model with ``step_tallies(rows)`` says which of them a joint step
     of ``rows`` rows reads back (a form of a layer chosen by the step's
     shape brings its counters: the others are counted and dropped).
@@ -872,17 +935,18 @@ class DecodeEngine:
         self._mixed = _Mixed(model, spec, self._window, c.slots) \
             if getattr(model, "layer_kinds", None) else None
         # the model's counters behind a step's tokens, as it declares them
-        self._tallies = self._mixed.tallies if self._mixed else ()
+        self._tallies = self._mixed.tallies if self._mixed \
+            else tuple(getattr(model, "tallies", ()))
         # and what rides a whole-prompt prefill's token
         self._prefill_tallies = self._mixed.prefill_tallies \
-            if self._mixed else ()
+            if self._mixed else tuple(getattr(model, "prefill_tallies", ()))
         self._refuse_for_kinds(model, c, draft_model)
         latent = bool(getattr(model, "values_in_keys", False))
         if latent:
             self._refuse_for_latent(c, draft_model)
         with jax.default_device(self._device):
             self._cache = PagedKVCache(
-                CacheConfig(max(model.num_layers - n_rec - n_win, 1),
+                CacheConfig(max(cache_layers(model) - n_rec - n_win, 1),
                             kv_heads, model.head_dim, c.slots,
                             c.max_seq_len, c.page_size,
                             num_pages=c.num_pages, dtype=c.cache_dtype,
@@ -929,7 +993,7 @@ class DecodeEngine:
             self.draft_weights = self._commit(draft_weights)
             cc = self._cache.config
             dshape = cc.pool_shape(
-                draft_model.num_layers,
+                cache_layers(draft_model),
                 draft_model.num_heads * draft_model.head_dim)
             self._scope.set_var(DRAFT_K_PAGES_VAR,
                                 jnp.zeros(dshape, cc.store_dtype))
@@ -937,7 +1001,7 @@ class DecodeEngine:
                                 jnp.zeros(dshape, cc.store_dtype))
             self._draft_state_vars = _DRAFT_VARS
             if cc.quantized:
-                dsshape = cc.pool_shape(draft_model.num_layers,
+                dsshape = cc.pool_shape(cache_layers(draft_model),
                                         draft_model.num_heads)
                 for nm in (DRAFT_K_SCALES_VAR, DRAFT_V_SCALES_VAR):
                     self._scope.set_var(
@@ -1196,7 +1260,8 @@ class DecodeEngine:
         VERBATIM by the target step and the draft proposal burst so both
         read the cache through one formulation.  -> (logits, pools).
         ``mix`` (a ``_Mixers``) takes the attention for a model with
-        ``layer_kinds`` (``pools`` is then its ``(pools, recurrent)``)."""
+        ``layer_kinds`` (``pools`` is then its ``(pools, recurrent)``),
+        or (a ``_Counting``) hands it on for one that only counts."""
         from ..ops.pallas_decode_attention import paged_decode_attention
 
         attend = self._paged_attend(
@@ -1204,6 +1269,9 @@ class DecodeEngine:
             write_page, write_off)
         if mix is not None:
             mix.attend, attend = attend, mix
+        else:
+            # a draft's burst: its counters are counted and dropped
+            attend = _Counting.of(model, attend)
         return model.forward(weights, tokens, positions, pools, attend)
 
     def _build_step_fn(self, model):
@@ -1262,9 +1330,13 @@ class DecodeEngine:
                 axis=1)[:, 0], 0)
             write_off = jnp.where(write, positions % page_size, 0)
             if mixed is None:
+                # a model with pools alone that counts: its counters
+                # ride the tokens' read-back as a mixed model's do
+                mix = _Counting(model, self._tallies, live) \
+                    if self._tallies else None
                 logits, pools = self._token_step_body(
                     model, weights, _split_state(state), tokens,
-                    positions, page_table, write_page, write_off)
+                    positions, page_table, write_page, write_off, mix=mix)
                 new_state = _join_state(pools)
             else:
                 mix = _Mixers(mixed, recur, live,
@@ -1288,14 +1360,14 @@ class DecodeEngine:
             nxt = sample_tokens(keys, logits, a["temperature"],
                                 a["top_k"], a["top_p"])
             nxt = jnp.where(live, nxt, 0)
-            if mixed is None:
+            if mix is None:
                 return (nxt, logits), new_state
             # the model's counters ride the tokens' read-back: the
             # step's one sync reads them too
-            tallies = [jnp.asarray(mix.counts[n], jnp.int32).reshape(1)
-                       for n in mixed.tallies]
-            return (jnp.concatenate([nxt] + tallies), logits,
-                    mix.recorded()), new_state
+            nxt = _with_counts(nxt, mix, self._tallies)
+            if mixed is None:
+                return (nxt, logits), new_state
+            return (nxt, logits, mix.recorded()), new_state
 
         return jax.jit(step, donate_argnums=(0,))
 
@@ -1313,7 +1385,8 @@ class DecodeEngine:
 
         mixed = self._mixed if model is self.model else None
         row = _prefill_row(t_pad, cc.pages_per_slot, slot=mixed is not None)
-        counted = mixed.prefill_tallies if mixed is not None else ()
+        counted = () if model is not self.model else \
+            self._prefill_tallies
         fresh_only = bool(per_slot_kinds(model)) or cc.latent
 
         @jax.named_scope("prefill_full")
@@ -1425,9 +1498,10 @@ class DecodeEngine:
                 return ctx, pools
 
             if mixed is None:
+                mix = _Counting.of(model, attend, counted,
+                                   positions < length)
                 logits, pools = model.forward(              # [T_pad, V]
-                    weights, tokens, positions, _split_state(state),
-                    attend)
+                    weights, tokens, positions, _split_state(state), mix)
                 new_state = _join_state(pools)
             else:
                 mix = _Mixers(mixed, recur, positions < length, attend,
@@ -1445,12 +1519,12 @@ class DecodeEngine:
             tok = sample_tokens(key0[None], last[None],
                                 a["temperature"][None], a["top_k"][None],
                                 a["top_p"][None])[0]
-            if mixed is None:
-                return (tok, last), new_state
             if counted:
                 # what the program counted rides the token's read-back
                 tok = jnp.stack([tok] + [
                     jnp.asarray(mix.counts[n], jnp.int32) for n in counted])
+            if mixed is None:
+                return (tok, last), new_state
             return (tok, last, mix.recorded()), new_state
 
         return jax.jit(prefill, donate_argnums=(0,))
@@ -1481,9 +1555,11 @@ class DecodeEngine:
             logits, pools = model.forward(                  # [S, R, V]
                 weights, tokens,
                 jnp.clip(positions, 0, model.max_seq_len - 1),
-                _split_state(state), self._paged_attend(
-                    paged_chunk_attention, page_table, positions + 1,
-                    write_page, write_off))
+                # a model's counters: counted and dropped here
+                _split_state(state), _Counting.of(
+                    model, self._paged_attend(
+                        paged_chunk_attention, page_table, positions + 1,
+                        write_page, write_off)))
             greedy = greedy_sample(logits)                  # [S, R]
             last = jnp.take_along_axis(
                 logits, last_row[:, None, None], axis=1)[:, 0]  # [S, V]
@@ -1781,6 +1857,8 @@ class DecodeEngine:
         stat_set("decode_state_bytes", self._cache.state_bytes())
         stat_set("decode_window_bytes", self._cache.window_bytes())
         stat_set("decode_latent_bytes", self._cache.latent_bytes())
+        stat_set("decode_cache_layers", self._cache.config.num_layers)
+        stat_set("decode_kv_pool_bytes", self._cache.config.cache_bytes())
         from ..ops.pallas_decode_attention import feed_bits
 
         stat_set("decode_attn_feed_bits",

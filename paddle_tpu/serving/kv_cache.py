@@ -4,11 +4,14 @@
 Layout (the vLLM PagedAttention idea, TPU-native): all keys/values for
 every serving slot live in TWO device arrays of fixed-size pages
 
-    k_pages : [num_layers, num_pages, page_size, heads * head_dim]
-    v_pages : [num_layers, num_pages, page_size, heads * v_head_dim]
+    k_pages : [cache_layers, num_pages, page_size, heads * head_dim]
+    v_pages : [cache_layers, num_pages, page_size, heads * v_head_dim]
 
 (a position's heads folded into ONE lane-dense row; see "Device layout"
-below; V's heads may be narrower than K's) and each slot owns an
+below; V's heads may be narrower than K's; ``cache_layers`` is the
+model's count of layers of K and V a token leaves behind: one a weight
+layer that has keys, or, for a stack its tokens pass through several
+times on the same weights, one a pass a layer) and each slot owns an
 ordered list of page ids (its *page table*).  A
 slot's logical sequence position ``t`` maps to page ``table[t // page]``
 offset ``t % page``.  Pages are allocated from a host-side free list at
@@ -204,7 +207,9 @@ class KVPageExport:
 class CacheConfig:
     """Geometry of the paged cache (everything static / compile-time).
 
-    A pool is ``[num_layers, num_pages, page_size, row_lanes]`` with
+    A pool is ``[num_layers, num_pages, page_size, row_lanes]``
+    (``num_layers``: the CACHE layers, the pools' depth, which a model
+    may declare apart from its weight layers) with
     ``row_lanes = num_heads * head_dim`` (``v_row_lanes = num_heads *
     v_head_dim`` for the V pool where ``v_head_dim`` is given): one
     position's heads folded
@@ -318,7 +323,7 @@ class CacheConfig:
 
     def per_page_pool_bytes(self) -> int:
         """Total device bytes one page costs across EVERY pool (k + v,
-        all layers, scale planes included) — the unit a fixed byte
+        all cache layers, scale planes included) — the unit a fixed byte
         budget is divided by to size ``num_pages``."""
         return self.num_layers * (self.page_bytes()
                                   + self.page_bytes(v=True))
@@ -712,7 +717,7 @@ class PagedKVCache:
             if self.window is not None else 0
 
     def latent_bytes(self) -> int:
-        """Device bytes of the latent rows' pool, all layers and pages
+        """Device bytes of the latent rows' pool, all cache layers and pages
         (0 for a cache of K and V)."""
         return self.config.cache_bytes() if self.config.latent else 0
 
@@ -1155,16 +1160,19 @@ def _pool_rows(val, lanes: int):
     return jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, short),))
 
 
-def scatter_token_layer(pages, layer: int, val, page_id, offset):
+def scatter_token_layer(pages, layer, val, page_id, offset):
     """Write one new position per row: val [R, H, D] lands as the row
     [R, H*D] at (layer, page_id[r], offset[r]) of pages [L, P, page,
-    H*D] — dead rows pass page 0 (trash).  Indexing the three leading
+    H*D] — dead rows pass page 0 (trash).  ``layer`` is the CACHE layer:
+    a Python int, or a traced int32 scalar where the model's layers run
+    in a rolled loop (the write is then a scatter at a traced index,
+    still in place in a pool the loop carries).  Indexing the three leading
     axes of a lane-dense pool is what lets the chip scatter in place."""
     return pages.at[layer, page_id, offset].set(
         _pool_rows(val, pages.shape[-1]).astype(pages.dtype))
 
 
-def scatter_prompt_layer(pages, layer: int, val, page_ids):
+def scatter_prompt_layer(pages, layer, val, page_ids):
     """Write a whole prompt's positions for one slot: val
     [n_pages*page, H, D] (padded to a page multiple) is stored page-
     wholesale, as [n_pages, page, H*D], into ``page_ids`` [n_pages]."""
@@ -1203,7 +1211,7 @@ def dequantize_kv(q, scale, dtype):
             * scale.astype(jnp.float32)[..., None]).astype(dtype)
 
 
-def write_token_layer(pages, scales, layer: int, val, page_id, offset):
+def write_token_layer(pages, scales, layer, val, page_id, offset):
     """Quantization-aware :func:`scatter_token_layer`: returns
     ``(pages, scales)``.  ``scales=None`` is the unquantized path
     (pages store ``val`` directly, scales pass through); otherwise the
@@ -1218,7 +1226,7 @@ def write_token_layer(pages, scales, layer: int, val, page_id, offset):
                 s.astype(scales.dtype)))
 
 
-def write_prompt_layer(pages, scales, layer: int, val, page_ids):
+def write_prompt_layer(pages, scales, layer, val, page_ids):
     """Quantization-aware :func:`scatter_prompt_layer`: returns
     ``(pages, scales)``; page-wholesale like the unquantized path, but
     each position quantizes independently — bitwise-identical bytes to

@@ -1337,3 +1337,30 @@ def test_one_shot_mode_vs_continuous_admission(model_and_weights):
         long_r.result(timeout=120)
     finally:
         eng.stop()
+
+
+# sha256 of the lowered text of the module fixture's model's joint step
+# and 64-row whole-prompt prefill behind ``make_engine``, as PR 48's tree
+# lowers them (taken before PR 50 touched the engine)
+PROGRAMS_AS_LOWERED = {
+    "step": "7d26b3dd3ebe8718647a3594fbb670062cfe6bdb14bc7c25ed85c425a8b49386",
+    "prefill":
+        "05f1fab5bce98713758f0ca7432cfba732f840e4e5e73c96a38054580f90db20"}
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_the_programs_are_still_the_ones_lowered_before_cache_layers(
+        model_and_weights, program):
+    """A model that declares neither ``cache_layers`` nor ``tallies``
+    hands ``attend`` a Python ``int`` a layer and counts nothing: its
+    joint step and whole-prompt prefill lower to the text they had
+    before a model could own more cache layers than weight layers (PR
+    50).  A change MEANT to move these programs replaces the digests;
+    one that was not has found out here."""
+    import hashlib
+
+    eng = make_engine(model_and_weights)
+    text = (eng.lower_step() if program == "step"
+            else eng.lower_prefill(64)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PROGRAMS_AS_LOWERED[program]

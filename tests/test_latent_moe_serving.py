@@ -438,3 +438,32 @@ def test_a_step_that_holds_few_experts_of_many_reads_the_hit_ones_alone():
     assert d["moe_experts_skipped"] + d["moe_experts_hit"] \
         == 2 * len(held) * d["decode_steps"]
     assert d["moe_experts_skipped"] > d["moe_experts_hit"]
+
+
+# sha256 of the lowered text of ``make_model()``'s joint step and 128-row
+# whole-prompt prefill behind ``engine()``, as PR 48's tree lowers them
+# (taken before PR 50 touched the engine)
+PROGRAMS_AS_LOWERED = {
+    "step": "654cd27118f1be4179f683c57c3962d6f31169c4fe4910dfb5b44af673288efc",
+    "prefill":
+        "6b284d0da4049818c9ee9e6e29efa1d41b29253b81d02c5403b14f3d72800a88"}
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_the_programs_are_still_the_ones_lowered_before_cache_layers(
+        program):
+    """This model's cache layers are its weight layers and its ``attend``
+    gets a Python ``int`` a layer: the joint step and the whole-prompt
+    prefill lower to the text they had before a model could own more
+    cache layers than weight layers (PR 50): no line that this cell's
+    programs lower has changed.  A change MEANT to move these programs
+    replaces the digests; one that was not has found out here."""
+    import hashlib
+
+    model = make_model()
+    weights = model.init_weights(jax.random.PRNGKey(1))
+    eng = engine(model, weights)
+    text = (eng.lower_step() if program == "step"
+            else eng.lower_prefill(128)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PROGRAMS_AS_LOWERED[program]

@@ -1145,3 +1145,67 @@ def test_kimi_cell_pool_is_the_latent_rows_at_whole_lane_tiles():
     with pytest.raises(ValueError, match="latent page"):
         CacheConfig(5, 1, 576, 64, 10240, 16, v_head_dim=512, latent=True,
                     quantized=True)
+
+
+# -- Ouro-2.6B: the pools in place through a loop over the passes ---------
+
+def _looped_engine():
+    """Ouro-2.6B's widths behind the engine at the cell's serving sizes
+    (16 slots of 320 positions, 337 bf16 pages, the whole vocabulary),
+    two weight layers under four passes (8 cache layers): the layers run
+    as a rolled loop over stacked weights, so the program is the cell's
+    but for the loop's trip count and the stacks' depth."""
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine, LoopedLM
+
+    model = LoopedLM(vocab_size=49152, d_model=2048, num_layers=2, loops=4,
+                     num_heads=16, head_dim=128, ffn_dim=5632)
+    weights = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
+    return DecodeEngine(model, weights, DecodeConfig(
+        slots=16, max_seq_len=320, num_pages=337, use_pallas="always",
+        cache_dtype="bfloat16"))
+
+
+@pytest.mark.parametrize("program", ["step", "prefill_128"])
+def test_ouro_width_programs_keep_the_pools_in_place_through_the_loops(
+        one_chip, program):
+    """The joint step (ONE paged-attention call whose layer is the
+    loops' counters, inside the loop over the layers inside the loop
+    over the passes) and the 128-row whole-prompt prefill: both pools
+    are the loops' carry, aliased from argument to result, and what the
+    program needs beside its operands is megabytes (a copy of one pool
+    of the cell is 4.2 GB and would not fit beside 5.3 GB of weights and
+    8.5 GB of pages); no stack of weights is turned over before the
+    loop (wq and wk lie [out, in] for that)."""
+    eng = _looped_engine()
+    pool = (8, 337, 16, 2048)
+    assert eng._cache.config.pool_shape() == pool
+    if program == "step":
+        compiled = eng.lower_step(sharding=one_chip).compile()
+    else:
+        compiled = eng.lower_prefill(128, sharding=one_chip).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (program == "step")
+    carried = "bf16[8,337,16,2048]"
+    whiles = [ln for ln in text.splitlines()
+              if re.search(r" while\(", ln) and carried in ln]
+    assert len(whiles) == 2, whiles                 # passes, layers
+    entry = _computation(text, "ENTRY ")
+    assert not [ln for ln in entry if re.search(r" copy\(", ln)
+                and "weights__layers" in ln]
+    # the two pools are the program's first parameters and last results,
+    # each result its donated parameter in the same device layout (the
+    # in-place writes are inside the loops' bodies, not in ENTRY, which
+    # is where ``_assert_pools_stay_put`` looks for them)
+    ins = jax.tree_util.tree_leaves(compiled.input_formats)
+    outs = jax.tree_util.tree_leaves(compiled.output_formats)
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    pairs = {(int(o), int(i)) for o, i in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", alias[1])}
+    assert {(len(outs) - 2 + i, i) for i in range(2)} <= pairs
+    assert all(ins[i].layout == outs[len(outs) - 2 + i].layout
+               for i in range(2))
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= 2 * 8 * 337 * 16 * 2048 * 2
+    assert ma.temp_size_in_bytes < 256 << 20
