@@ -33,15 +33,31 @@ one SLOT; the page table, the row lengths, each slot's widest row and
 Inside a slot the kernel loops over its LIVE blocks only — a block is
 ``ppb`` consecutive page-table entries — so a step's cost follows the
 positions attended, never ``S * max_seq``: a dead block costs nothing,
-not even a skipped grid step.  ``ppb`` is read from the shapes
-(`pages_per_block`): the largest count with ``ppb * page <= 128``
-positions, one full lane tile of scores, whose K and V blocks fit the
-fast-memory budget twice over (eight 16-token pages at GPT-2's 1,024
-f32 lanes and at 2,048 bf16 lanes, 2 MB; a page of 128 positions is a
-block by itself).  The scores of a block are ONE ``(hb*R, ppb*page)``
-tile a stack: one masked online-softmax update (running max /
-denominator in VMEM scratch, as in the prefill flash kernel) and one
-rescale of the accumulator a block, not a page.
+not even a skipped grid step.  ``ppb`` is read from the shapes and the
+pools' dtype (`pages_per_block`; no flag, no model's name): the largest
+count with ``ppb * page <= 128`` positions, one full lane tile of
+scores, whose K and V blocks fit the fast-memory budget twice over
+(eight 16-token pages at GPT-2's 1,024 f32 lanes and at 2,048 bf16
+lanes, 2 MB; a page of 128 positions is a block by itself) -- and up to
+``_STACKED_BLOCK`` = 512 positions, whole lane tiles, where bfloat16
+pools meet query rows STACKED on a K/V head (grouped-query decode: 16
+rows a head on MiMo-V2.5 and Command A+, 8 on Solar; a chunk's or a
+verify window's rows), as far as both buffers of both pools, the scores
+and the table allow.  At one row a head a block's arithmetic hides under
+its copies (Olmo-Hybrid: 92 % of the roofline at 128); with rows stacked
+it is a chain of latencies paid once a block, and was most of the call
+(the table by ``_STACKED_BLOCK``: MiMo's global call 2.04 -> 1.24 ms
+beside copies of 0.96).  What the shapes decide: Olmo-Hybrid's rows of
+15,360 B a position fit 128 positions and no more, MiMo's window ring of
+9 pages holds one tile, Ouro and GPT-2 have one row a head, and the
+float32 and int8 bodies (float32 copies of a stack's K and V) stay at
+one tile.  A larger block costs a slot's last block its dead positions
+(computed and masked) and a block that is not whole its page-by-page
+copies, of up to ``ppb`` pages: both inside the measured calls.  The
+scores of a block are ONE ``(hb*R, ppb*page)`` tile a stack: one masked
+online-softmax update (running max / denominator in VMEM scratch, as in
+the prefill flash kernel) and one rescale of the accumulator a block,
+not a page.
 
 Only live pages move, by the kernel's own async copies into one of two
 block buffers a pool: while a block is computed the next one — this
@@ -391,6 +407,44 @@ _BLOCK_VMEM_BYTES = 4 << 20
 # of 128 / 256 / 512 / 1,024 / 2,048 positions read 3.39 / 2.24 / 1.67 /
 # 1.44 / 1.39; both buffers of 1,024 fit ``_BLOCK_VMEM_BYTES``
 _LATENT_BLOCK = 1024
+# ... and of the K-and-V bfloat16 body where query rows are stacked on a
+# K/V head (``pages_per_block``).  At ONE row a head the block's
+# arithmetic hides under its copies; at 16 rows a head a block of one lane
+# tile pays its chain of latencies (matmul, lane reduction, exp, lane
+# reduction, matmul) and its fixed work every 128 positions, and that,
+# not the copies, was the call's time.  Measured on the v5e, the kernel
+# alone in a loop at each serving cell's slots, table, page of 16 and
+# live lengths (`tools/sweep_decode_block.py`, my chip run, PR 51), ms a
+# layer's call at blocks of 128 / 256 / 512 / 768 / 1,024 positions; in
+# brackets a kernel that starts and waits for the same copies in the
+# same blocks and computes nothing, then the rows' bytes at 819 GB/s:
+#   MiMo global   4 x (192 + 128), 16 rows a head, 128 slots of 0.8-3.5k:
+#       2.04  1.52  1.24  1.21  1.21   [1.09 0.97 0.96 0.95 0.95; 0.83]
+#   Command A+ window   8 x (128 + 128), 16 rows, 48 rings of 257 pages:
+#       1.52  1.17  1.14  1.12  1.13   [1.08 1.08 1.08 1.07 1.07; 0.95]
+#   Command A+ global   the same rows, 48 slots of 3.1-6.1k:
+#       1.72  1.34  1.32  1.31  1.31   [1.29 1.28 1.28 1.28 1.28; 1.15]
+#   Solar   8 x (128 + 128), 8 rows a head, 128 slots of 0.2-1.5k:
+#       0.98  0.77  0.70  0.71  0.73   [0.68 0.67 0.66 0.66 0.66; 0.57]
+#   Ouro   16 x (128 + 128), ONE row a head, 16 slots of 64-320:
+#       0.077 0.077                    [0.067 0.064; 0.026]
+#   Olmo-Hybrid   30 x (128 + 128), one row a head, 32 slots of 3.1-5.6k:
+#       2.91  2.90                     [2.90 2.89; 2.63]
+#   MiMo window   8 x (192 + 128), 8 rows, rings of 9 pages: 0.35 [0.18]
+#       at 128, the only block its table holds.
+# 512 takes all but 2 % of what any block gives and both its buffers are
+# 2.6 MB at MiMo's rows and ``_BLOCK_VMEM_BYTES`` at Command A+'s; past it
+# the dead positions of a slot's last block and the page-by-page copies
+# of a block that is not whole start to show (Solar).  A block that is
+# not whole was also tried with its live pages started from the unrolled
+# form under a ``pl.when`` a page: slower at every shape and block (MiMo
+# global at 512: 1.31 against 1.24), so it keeps the loop.
+_STACKED_BLOCK = 512
+# float32 scores of one block the body may hold at once, all stacks and
+# all three terms (it runs a phase at a time over every stack): what
+# keeps a chunk's or a verify window's many rows at the block they had
+# (Command A+'s 128 decode rows at 512 positions are 768 KB)
+_SCORE_VMEM_BYTES = 1 << 20
 
 
 def _stack_heads(num_heads, head_dim, n_rows, v_dim=None,
@@ -413,22 +467,42 @@ def _stack_heads(num_heads, head_dim, n_rows, v_dim=None,
     return max(fit) if fit else min(legal)
 
 
-def pages_per_block(page, pps, row_lanes, itemsize, v_lanes=None):
-    """How many consecutive page-table entries one block covers.
+def pages_per_block(page, pps, row_lanes, dtype, v_lanes=None, heads=1,
+                    n_rows=1):
+    """How many consecutive page-table entries one block covers, from
+    the call's shapes and the pools' ``dtype`` alone.
 
-    The largest count whose positions fill at most one 128-lane tile of
-    scores (``ppb * page <= 128``) and whose K and V blocks, double
-    buffered, fit ``_BLOCK_VMEM_BYTES``; never more than the table
-    holds, never less than 1 (a page of 128 positions or more is a
-    block by itself).  ``pps`` need not be a multiple: the last block's
-    missing entries are dead like any other.  ``v_lanes``: the V rows'
-    width where it is not K's ``row_lanes``; 0 for a latent pool (no V
-    pool: a block of ``_LATENT_BLOCK`` positions, the module header)."""
+    The positions a block WANTS: one 128-lane tile of scores
+    (``ppb * page <= 128``), or ``_STACKED_BLOCK`` where bfloat16 pools
+    (``feed_bits`` 16) meet ``n_rows > 1`` query rows stacked on each of
+    the ``heads`` K/V heads (``_chunk_call``'s ``q.shape``: decode's
+    group of query heads a K/V head, a chunk's or a verify window's rows
+    times it), as far as the float32 scores of all the stacks' three
+    terms fit ``_SCORE_VMEM_BYTES``.  Then never more than both buffers
+    of both pools fit in ``_BLOCK_VMEM_BYTES``, never more than the
+    table holds, whole lane tiles of scores where the K-and-V body
+    takes more than one, never less than 1 (a page of 128 positions or
+    more is a block by itself).  ``pps`` need not be a multiple: the
+    last block's missing entries are dead like any other.  ``v_lanes``:
+    the V rows' width where it is not K's ``row_lanes``; 0 for a latent
+    pool (no V pool: a block of ``_LATENT_BLOCK`` positions, the module
+    header)."""
     latent = v_lanes == 0       # one pool: the values are K's own lanes
-    by_tile = (_LATENT_BLOCK if latent else _LANES) // page
+    tile = max(_LANES // page, 1)   # pages a lane tile of scores
+    want = _LANES
+    if latent:
+        want = _LATENT_BLOCK
+    elif feed_bits(dtype) == 16 and n_rows > 1:
+        by_scores = _SCORE_VMEM_BYTES // (
+            _SPLIT_TERMS * heads * n_rows * 4 * _LANES) * _LANES
+        want = max(min(_STACKED_BLOCK, by_scores), _LANES)
     by_vmem = _BLOCK_VMEM_BYTES // (2 * page * (
-        row_lanes + (0 if latent else v_lanes or row_lanes)) * itemsize)
-    return max(1, min(by_tile, by_vmem, pps))
+        row_lanes + (0 if latent else v_lanes or row_lanes))
+        * jnp.dtype(dtype).itemsize)
+    ppb = min(want // page, by_vmem, pps)
+    if ppb > tile and not latent:
+        ppb -= ppb % tile
+    return max(1, ppb)
 
 
 def feed_bits(pool_dtype):
@@ -838,8 +912,8 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
     hb = _stack_heads(h, d, n_rows, dv,
                       _MAX_SPLIT_ROWS if split else _MAX_STACK_ROWS)
     n_stacks, rows, width, v_width = h // hb, hb * n_rows, hb * d, hb * dv
-    ppb = pages_per_block(page, pps, hd, k_pages.dtype.itemsize,
-                          0 if latent else v_hd)
+    ppb = pages_per_block(page, pps, hd, k_pages.dtype,
+                          0 if latent else v_hd, h, n_rows)
     quantized = k_scales is not None
     row_lengths = row_lengths.astype(jnp.int32)
     prefetch = [layer.reshape(1), page_table.reshape(-1).astype(jnp.int32),

@@ -968,7 +968,9 @@ class DecodeEngine:
         cc = self._cache.config
         self._attn_block = cc.page_size * pages_per_block(
             cc.page_size, cc.pages_per_slot, cc.row_lanes,
-            cc.store_dtype.itemsize, cc.v_row_lanes)
+            cc.store_dtype, cc.v_row_lanes, kv_heads,
+            model.num_heads // kv_heads)
+        stat_set("decode_attn_block_positions", self._attn_block)
         self._attn_table_blocks = cc.num_slots * -(
             -cc.max_seq_len // self._attn_block)
         if self._window is not None:
@@ -976,7 +978,9 @@ class DecodeEngine:
             self._ring = w.ring_pages(cc.page_size)
             self._window_block = cc.page_size * pages_per_block(
                 cc.page_size, self._ring, w.num_heads * w.head_dim,
-                cc.store_dtype.itemsize, w.num_heads * w.v_head_dim)
+                cc.store_dtype, w.num_heads * w.v_head_dim, w.num_heads,
+                model.num_heads // w.num_heads)
+            stat_set("decode_window_block_positions", self._window_block)
         # per-request timeline hook: claim/CoW/register/evict events
         # from the cache land on the owning request's trace
         self._cache.on_event = self._on_cache_event

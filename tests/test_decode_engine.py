@@ -303,7 +303,9 @@ def test_paged_kernel_ragged_last_block_and_nan_scratch(pool):
         pages_per_block, paged_chunk_attention)
 
     args, kwargs, want, live = block_edge_case(5, pool, pps=12)
-    assert pages_per_block(16, 12, 128, args[1].dtype.itemsize) == 8
+    # five rows a head on bfloat16 pools would take more than a lane
+    # tile of scores: the table of 12 holds one whole tile and no second
+    assert pages_per_block(16, 12, 128, args[1].dtype, None, 4, 5) == 8
     got = np.asarray(paged_chunk_attention(
         *args, use_pallas="always",
         interpret=pltpu.InterpretParams(uninitialized_memory="nan"),
@@ -312,21 +314,65 @@ def test_paged_kernel_ragged_last_block_and_nan_scratch(pool):
     np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("page,pps,lanes,itemsize,want", [
-    (16, 64, 1024, 4, 8),     # GPT-2-medium f32: a full lane tile of scores
-    (16, 64, 2048, 2, 8),     # 16 x 128 heads in bf16
-    (128, 8, 768, 4, 1),      # a page IS a block
-    (16, 12, 1024, 4, 8),     # ppb need not divide pps
-    (16, 4, 1024, 4, 4),      # never more than the table holds
-    (16, 64, 8192, 4, 2),     # VMEM-bound: 2 buffers x (K + V) <= 4 MiB
-    (16, 64, 32768, 4, 1),    # ... and never less than one page
-], ids=["gpt2_medium", "bf16_2048", "page128", "ragged", "short_table",
-        "vmem_bound", "vmem_floor"])
-def test_pages_per_block_is_read_from_the_shapes(page, pps, lanes, itemsize,
+@pytest.mark.parametrize(
+    "page,pps,lanes,dtype,v_lanes,heads,n_rows,want", [
+        # GPT-2-medium f32: a full lane tile of scores
+        (16, 64, 1024, "float32", None, 16, 1, 8),
+        (16, 64, 2048, "bfloat16", None, 16, 1, 8),  # 16 x 128 bf16, one row
+        (128, 8, 768, "float32", None, 1, 1, 1),     # a page IS a block
+        (16, 12, 1024, "float32", None, 1, 1, 8),    # ppb need not divide pps
+        (16, 4, 1024, "float32", None, 1, 1, 4),     # never past the table
+        # VMEM-bound: 2 buffers x (K + V) <= 4 MiB; never less than a page
+        (16, 64, 8192, "float32", None, 1, 1, 2),
+        (16, 64, 32768, "float32", None, 1, 1, 1),
+        # MiMo-V2.5's global layers: 16 rows on each of 4 K/V heads of
+        # 192 over 128, a table of 256 pages: 512 positions, 2.6 MB of
+        # buffers
+        (16, 256, 768, "bfloat16", 512, 4, 16, 32),
+        # ... its window layers: 8 rows a head, but the ring of 9 pages
+        # holds ONE whole lane tile
+        (16, 9, 1536, "bfloat16", 1024, 8, 8, 8),
+        # Command A+'s rings of 257 pages, 16 rows on each of 8 heads:
+        # 512, both buffers of both pools exactly the budget
+        (16, 257, 1024, "bfloat16", 1024, 8, 16, 32),
+        (16, 384, 1024, "bfloat16", 1024, 8, 16, 32),   # ... its global layer
+        # Solar: 8 rows a head at the same rows
+        (16, 128, 1024, "bfloat16", 1024, 8, 8, 32),
+        # Ouro: ONE row a head (and contexts of 320 at most)
+        (16, 20, 2048, "bfloat16", 2048, 16, 1, 8),
+        # Olmo-Hybrid: one row a head; and 15,360 B a position fit 136
+        # positions twice over, whatever the rows
+        (16, 352, 3840, "bfloat16", 3840, 30, 1, 8),
+        (16, 352, 3840, "bfloat16", 3840, 30, 16, 8),
+        # float32 and int8 pools hold float32 copies of a stack's K and V
+        (16, 64, 1024, "float32", None, 16, 16, 8),
+        (16, 64, 1024, "int8", None, 16, 16, 8),
+        # Kimi-K2.5: the latent body's own block, 64 rows or one
+        (16, 640, 640, "bfloat16", 0, 1, 64, 64),
+        # a verify window of 5 rows on Command A+'s 128 heads: the
+        # scores of 640 rows at 512 positions are past their budget
+        (16, 257, 1024, "bfloat16", 1024, 8, 80, 8),
+        # a chunk of 16 rows on 16 heads of 128: 4 MB of buffers are 256
+        (16, 64, 2048, "bfloat16", 2048, 16, 16, 16),
+        # whole lane tiles: a table of 30 pages holds 3 of them, not 30
+        (16, 30, 256, "bfloat16", 256, 2, 4, 24),
+    ], ids=["gpt2_medium", "bf16_2048", "page128", "ragged", "short_table",
+            "vmem_bound", "vmem_floor", "mimo_global", "mimo_window_ring",
+            "command_window_ring", "command_global", "solar", "ouro_one_row",
+            "olmo_one_row", "olmo_vmem", "f32_stacked_rows",
+            "int8_stacked_rows", "kimi_latent", "verify_window_scores",
+            "chunk_vmem", "whole_tiles"])
+def test_pages_per_block_is_read_from_the_shapes(page, pps, lanes, dtype,
+                                                 v_lanes, heads, n_rows,
                                                  want):
+    """Which block a call gets, the serving cells' among them, and why:
+    more than one lane tile of scores only where bfloat16 pools meet
+    query rows stacked on a K/V head, as far as the buffers, the scores
+    and the table allow."""
     from paddle_tpu.ops.pallas_decode_attention import pages_per_block
 
-    assert pages_per_block(page, pps, lanes, itemsize) == want
+    assert pages_per_block(page, pps, lanes, dtype, v_lanes, heads,
+                           n_rows) == want
 
 
 def test_engine_counts_the_attention_blocks_a_step_meets(model_and_weights):

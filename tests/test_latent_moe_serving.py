@@ -192,7 +192,7 @@ def test_the_latent_body_in_interpret_mode_at_64_rows_a_head(
     512) under 64 query heads stacked as rows of the one head, lengths
     inside a page, across a block and past two; pages nobody owns hold
     NaN.  One pool, one call, its own name."""
-    assert pda.pages_per_block(16, 20, 640, 2, 0) == 8
+    assert pda.pages_per_block(16, 20, 640, pool, 0) == 8
     rng = np.random.RandomState(0)
     n_pages, page, s, pps = 70, 16, 3, 20
     rows = rng.randn(2, n_pages, page, 640).astype(np.float32)
@@ -232,11 +232,13 @@ def test_as_served_a_block_of_the_latent_body_is_1024_positions():
     """64 pages of 16 rows of 640 bfloat16 lanes, both buffers of which
     fit beside the body's tiles; the pools of K and V keep their blocks
     of one lane tile of scores."""
-    assert pda.pages_per_block(16, 640, 640, 2, 0) == 64
+    assert pda.pages_per_block(16, 640, 640, "bfloat16", 0) == 64
     assert 2 * 64 * 16 * 640 * 2 <= pda._BLOCK_VMEM_BYTES
-    assert pda.pages_per_block(8, 32, 128, 4, 0) == 32      # a short table
-    assert pda.pages_per_block(16, 640, 2048, 2, 2048) == 8
-    assert pda.pages_per_block(16, 64, 1024, 4) == 8
+    assert pda.pages_per_block(8, 32, 128, "float32", 0) == 32  # short table
+    assert pda.pages_per_block(16, 640, 2048, "bfloat16", 2048) == 8
+    assert pda.pages_per_block(16, 64, 1024, "float32") == 8
+    # the rows riding on the one head do not move the latent block
+    assert pda.pages_per_block(16, 640, 640, "bfloat16", 0, 1, 64) == 64
 
 
 def test_flash_rule_takes_ungrouped_heads_of_192_and_nothing_else_moves():

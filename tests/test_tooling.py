@@ -248,3 +248,32 @@ def test_launcher_refuses_several_trainers_on_a_tpu_host(monkeypatch):
     monkeypatch.undo()
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     assert launch.tpu_present() is False
+
+
+def test_the_block_sweep_rehearses_on_the_cpu_and_times_nothing_there(
+        tmp_path, monkeypatch):
+    """``tools/sweep_decode_block.py`` (the numbers behind
+    ``pages_per_block``): at ``--tiny`` sizes the kernel in the
+    interpreter at a block the rule does not give and at the one it
+    does, each against the reference, the copy-only kernel beside them,
+    and the rule back in place afterwards; without ``--tiny`` it wants a
+    TPU."""
+    import json
+
+    from paddle_tpu.ops import pallas_decode_attention as pda
+    from tools import sweep_decode_block as sweep
+
+    rule, out = pda.pages_per_block, tmp_path / "sweep.json"
+    monkeypatch.setattr(sys, "argv", [
+        "sweep", "--tiny", "--shapes", "mimo_global", "--blocks", "128,512",
+        "--iters", "1", "--out", str(out)])
+    sweep.main()
+    with open(out) as f:
+        lines = json.load(f)
+    assert [(x["block"], x["rule"]) for x in lines] \
+        == [(128, False), (512, True)]
+    assert all(x["max_err"] < 1e-5 and x["copies_ms"] > 0 for x in lines)
+    assert pda.pages_per_block is rule
+    monkeypatch.setattr(sys, "argv", ["sweep"])
+    with pytest.raises(SystemExit, match="a TPU or nothing"):
+        sweep.main()

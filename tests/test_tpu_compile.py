@@ -120,10 +120,13 @@ def test_paged_chunk_attention_compiles(one_chip, h, d, rows, quantized):
 def test_paged_attention_compiles_at_2048_bf16_lanes(one_chip, rows):
     """16 heads of 128 in bfloat16 (an OLMoE-shaped row, 2,048 lanes):
     eight pages a block are 2 MB of block buffers, as at GPT-2's f32
-    rows.  The bfloat16 feed: a stack's query rows ride as three groups
-    of bfloat16 rows, each group on whole packed tiles (5 rows of a
+    rows; where rows are stacked on a head (a verify window's 5, a
+    chunk's 16) a block is the 16 pages whose buffers are 4 MB.  The
+    bfloat16 feed: a stack's query rows ride as three groups of
+    bfloat16 rows, each group on whole packed tiles (5 rows of a
     speculative window take 16)."""
-    assert pda.pages_per_block(16, PPS, 16 * 128, 2) == 8
+    assert pda.pages_per_block(16, PPS, 16 * 128, jnp.bfloat16, None, 16,
+                               max(rows, 1)) == (16 if rows else 8)
     op = pda.paged_chunk_attention if rows else pda.paged_decode_attention
     text = _compile(one_chip, functools.partial(_paged, op),
                     *_paged_shapes(16, 128, 16, rows, False, jnp.bfloat16))
@@ -636,6 +639,11 @@ def test_paged_attention_compiles_at_mimo_rows(one_chip, kv_heads, window):
     and its own name."""
     pps = PPS * 4 if window is None else 9
     pool = SLOTS * pps + 1
+    # the block the rule gives: 512 positions of the global table, the
+    # one whole lane tile the ring of 9 pages holds
+    assert pda.pages_per_block(
+        16, pps, kv_heads * 192, jnp.bfloat16, kv_heads * 128, kv_heads,
+        64 // kv_heads) == (32 if window is None else 8)
     shapes = [((SLOTS, 64, 192), jnp.float32),
               ((LAYERS, pool, 16, kv_heads * 192), jnp.bfloat16),
               ((LAYERS, pool, 16, kv_heads * 128), jnp.bfloat16),
@@ -665,11 +673,12 @@ def test_paged_attention_compiles_at_command_a_plus_rows(one_chip, window):
     """128 query heads over 8 K/V heads of 128 lanes (16 rows a K/V
     head), K and V rows of 1,024 bf16 lanes, pages of 16: the global
     call over a table of 385 pages a slot (6,144 positions and a spare),
-    the window call over a ring of 257 with no sink, 33 blocks of 128
-    positions at most."""
+    the window call over a ring of 257 with no sink, nine blocks of 512
+    positions at most (both buffers of both pools: 4 MB)."""
     pps = 385 if window is None else 257
     pool = SLOTS * pps + 1
-    assert pda.pages_per_block(16, pps, 1024, 2, 1024) == 8
+    assert pda.pages_per_block(16, pps, 1024, jnp.bfloat16, 1024, 8,
+                               16) == 32
     shapes = [((SLOTS, 128, 128), jnp.float32),
               ((LAYERS, pool, 16, 1024), jnp.bfloat16),
               ((LAYERS, pool, 16, 1024), jnp.bfloat16),
@@ -740,7 +749,9 @@ def test_command_a_plus_width_programs_compile(one_chip, program):
     layers: no tensor of the program holds a score plane, not even a
     block of 256 rows of one."""
     eng = _parallel_engine()
-    assert eng._ring == 257 and eng._window_block == 128
+    # 16 rows a K/V head on bfloat16 rings: blocks of 512 positions
+    assert eng._ring == 257 and eng._window_block == 512 \
+        and eng._attn_block == 512
     if program == "step":
         compiled = eng.lower_step(sharding=one_chip).compile()
         text = compiled.as_text()
@@ -767,7 +778,8 @@ def test_paged_attention_compiles_at_olmo_hybrid_rows(one_chip):
     group, blocks of 8 pages."""
     pps = 352
     pool = SLOTS * pps + 1
-    assert pda.pages_per_block(16, pps, 3840, 2, 3840) == 8
+    assert pda.pages_per_block(16, pps, 3840, jnp.bfloat16, 3840, 30,
+                               1) == 8
     shapes = [((SLOTS, 30, 128), jnp.float32),
               ((LAYERS, pool, 16, 3840), jnp.bfloat16),
               ((LAYERS, pool, 16, 3840), jnp.bfloat16),
@@ -897,6 +909,9 @@ def test_mimo_width_programs_compile(one_chip, program):
     from paddle_tpu.ops import pallas_prompt_attention as ppa
 
     eng = _window_engine()
+    # the global table in blocks of 512 positions, the ring of 9 pages
+    # in the one lane tile it holds
+    assert (eng._attn_block, eng._window_block) == (512, 128)
     if program == "step":
         text = eng.lower_step(sharding=one_chip).compile().as_text()
         assert text.count("tpu_custom_call") == 2

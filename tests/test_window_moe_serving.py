@@ -116,6 +116,51 @@ def test_paged_kernel_serves_both_kinds_in_interpret_mode():
         assert served_vs_reference(eng, model, weights, prompts, 12) < 5e-5
 
 
+@pytest.mark.parametrize("cache_dtype, want", [
+    ("float32", (128, 32)), ("bfloat16", (512, 32))])
+def test_the_engine_says_which_block_each_kernel_walks(cache_dtype, want):
+    """``decode_attn_block_positions`` / ``decode_window_block_positions``:
+    four query rows on each of the global layers' two K/V heads walk
+    blocks of 512 positions where the pools are bfloat16 and of one lane
+    tile where they are float32; the ring of 4 pages is one block either
+    way.  The engine's block counters read the function the op reads,
+    with the arguments the op's call has."""
+    model = make_model(PERIOD)
+    weights = model.init_weights(jax.random.PRNGKey(5))
+    eng = engine(model, weights, max_seq_len=1024, cache_dtype=cache_dtype)
+    assert (eng._attn_block, eng._window_block) == want
+    assert (stat_get("decode_attn_block_positions"),
+            stat_get("decode_window_block_positions")) == want
+    cc = eng._cache.config
+    assert want == (
+        PAGE * pda.pages_per_block(PAGE, cc.pages_per_slot, 2 * 12,
+                                   cc.store_dtype, 2 * 8, 2, 4),
+        PAGE * pda.pages_per_block(PAGE, RING, 4 * 12, cc.store_dtype,
+                                   4 * 8, 4, 2))
+
+
+def test_blocks_of_512_behind_the_engine_in_interpret_mode():
+    """Bfloat16 pools behind the engine, the kernel in the interpreter:
+    a prompt of 150 tokens and 10 steps walk one partial block of 512
+    positions in the global layers (the table holds two) and the ring in
+    the window layers; the logits are those of the engine that gathers
+    the same pools."""
+    model = make_model(PERIOD)
+    weights = model.init_weights(jax.random.PRNGKey(5))
+    prompt = np.random.RandomState(6).randint(0, VOCAB, 150).tolist()
+    traces = []
+    for cfg in (dict(use_pallas="always", interpret=True),
+                dict(use_pallas="never")):
+        with engine(model, weights, max_seq_len=1024,
+                    cache_dtype="bfloat16", **cfg) as eng:
+            assert eng._attn_block == 512
+            r = eng.submit(prompt, max_new_tokens=10, record_logits=True)
+            traces.append((r.result(timeout=300), np.stack(r.logits_trace)))
+    (toks, got), (want_toks, want) = traces
+    assert toks == want_toks
+    np.testing.assert_allclose(got, want, atol=1e-4)
+
+
 def test_a_slots_second_request_sees_none_of_the_firsts_ring():
     model = make_model(PERIOD)
     weights = model.init_weights(jax.random.PRNGKey(7))
@@ -217,6 +262,55 @@ def test_kernel_with_wider_keys_a_window_and_sinks_in_interpret_mode(
              jnp.nan_to_num(args[2], nan=3.0)) + args[3:]
     via_ref = pda.paged_decode_attention(*clean, use_pallas="never", **kw)
     np.testing.assert_allclose(via_ref, want, atol=3e-5)
+
+
+@pytest.mark.parametrize("hkv, window, sink, ring, lengths, ppb", [
+    (2, None, False, 20, [5, 300, 256, 0, 319], 16),
+    (2, None, False, 70, [600, 512, 1040, 0, 17], 32),
+    (2, 300, True, None, [700, 40, 0, 1290, 256], 16),
+    (2, 900, True, None, [1500, 0, 2300, 900, 530], 32),
+    (4, 900, False, None, [1033, 2047], 32)],
+    ids=["blocks_of_256", "blocks_of_512", "window_ring_wraps_in_256",
+         "window_ring_wraps_in_512", "two_stacks_window_512"])
+def test_the_bf16_body_at_blocks_of_several_lane_tiles_in_interpret_mode(
+        hkv, window, sink, ring, lengths, ppb):
+    """Sixteen query rows stacked on a K/V head of 192 lanes over values
+    of 128 (MiMo's global rows), bfloat16 pools: the rule gives blocks
+    of 256 positions where the table holds 20 pages and of 512 where it
+    holds more.  A slot whose last block is partial (and one that ends
+    on a block's edge), a dead slot, a window whose first block is
+    partial and whose ring (20 and 58 entries, no multiple of the block)
+    wraps INSIDE a block, a sink; never-written pages and the
+    interpreter's fresh buffers read NaN, so a dead page of a larger
+    block that reached ``p @ v`` would show.  Against
+    ``decode_attention_reference`` on the values the pools hold."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    h, dk, dv, page = 16 * hkv, 192, 128, 16
+    if ring is None:
+        ring = -(-window // page) + 1
+    assert pda.pages_per_block(page, ring, hkv * dk, jnp.bfloat16,
+                               hkv * dv, hkv, 16) == ppb
+    assert ring % ppb                   # the table is no whole blocks
+    q, kp, vp, table, kfull, vfull = _paged_case(
+        h, hkv, dk, dv, page, ring, lengths, seed=ring, dtype="bfloat16")
+    sinks = jnp.asarray(np.random.RandomState(2).randn(h), jnp.float32) \
+        if sink else None
+    lens = jnp.asarray(lengths, jnp.int32)
+    got = pda.paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(table), lens, layer=1, use_pallas="always",
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan"),
+        window=window, sinks=sinks)
+    assert got.shape == (len(lengths), h, dv)
+    assert bool(jnp.isfinite(got).all())
+    k, v = (jnp.repeat(jnp.asarray(x), 16, axis=2) for x in (kfull, vfull))
+    want = pda.decode_attention_reference(
+        jnp.asarray(q), k, v, lens, window=window,
+        sinks=None if sinks is None else jnp.broadcast_to(
+            sinks, (len(lengths), h)))
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
 
 
 def test_the_window_call_has_its_own_name_and_walks_two_blocks_at_most():
