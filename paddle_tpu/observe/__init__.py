@@ -58,7 +58,11 @@ This package is the middle:
 - ``metrics_catalog`` — the authoritative name → (type, unit,
   subsystem) catalog behind ``METRICS.md``; a tier-1 drift gate keeps
   every ``/metrics`` series documented.
-- ``xla_stats``  — XLA introspection: per-compile wall time
+- ``xla_stats``  — XLA introspection: the birth log of every program
+  the process compiles (``program_births``, ``births_summary``: trace,
+  lowering, backend compile or cache load, hit or miss, and the span
+  that caused it, from ``jax.monitoring`` listeners registered when
+  this package is imported), the Executor's per-compile wall time
   (``compile_seconds``), executable size, per-chip HBM footprint from
   ``compiled.memory_analysis()`` joined with the tensor-parallel
   sharding plan into a per-var attribution table, live
@@ -85,13 +89,17 @@ from .histogram import (Histogram, HistogramRegistry, export_histograms,
                         histogram, prometheus_text, stat_time)
 from .step_stats import (StepTimer, mfu_estimate, reset_step_stats,
                          step_timer)
-from .xla_stats import (MemoryBudgetError, check_hbm_budget,
-                        device_memory_stats, memory_breakdown,
-                        memory_report, var_attribution)
+from .xla_stats import (MemoryBudgetError, births_summary,
+                        check_hbm_budget, device_memory_stats,
+                        memory_breakdown, memory_report, program_births,
+                        var_attribution)
 from .tracer import (SpanRecord, Tracer, begin, clear, disable, enable,
                      enabled, end, get_tracer, set_span_args, snapshot,
                      span)
 from .timeline import chrome_trace, export_chrome_trace
+
+# compile telemetry covers every program of the process from here on
+xla_stats.listen_for_births()
 
 __all__ = [
     # tracer
@@ -113,7 +121,7 @@ __all__ = [
     # XLA introspection
     "xla_stats", "MemoryBudgetError", "memory_breakdown",
     "var_attribution", "check_hbm_budget", "device_memory_stats",
-    "memory_report",
+    "memory_report", "program_births", "births_summary",
     # phase attribution + profiler capture + metrics catalog
     "phases", "PhasePlan", "PhaseEngine", "build_phase_plan",
     "collective_inventory", "phase_engine", "phases_report",

@@ -53,6 +53,23 @@ RULES = (
     Rule("xla_compile_seconds", "histogram", "xla_stats",
          "XLA-side compile time where introspection exposes it",
          exact=True),
+    Rule("xla_trace_seconds", "histogram", "xla_stats",
+         "Birth log: a compiled program's own jaxpr trace (the traces of "
+         "the jitted functions it calls lie inside it), every program of "
+         "the process; host work no compile cache removes: a tail here "
+         "is set-up time a warm start still pays", exact=True),
+    Rule("xla_lower_seconds", "histogram", "xla_stats",
+         "Birth log: a program's lowering to an MLIR module; with "
+         "`xla_trace_seconds` what a warm start spends before it can "
+         "ask the cache", exact=True),
+    Rule("xla_backend_compile_seconds", "histogram", "xla_stats",
+         "Birth log: a backend compile that no cache hit replaced (a "
+         "miss, or no cache): the cold part of set-up; a sample after "
+         "start-up is a recompile", exact=True),
+    Rule("xla_cache_load_seconds", "histogram", "xla_stats",
+         "Birth log: a persistent-cache hit's retrieval (read + "
+         "deserialize_executable); grows with what a cached program "
+         "holds, so watch it when kernels are added", exact=True),
     Rule("input_wait_seconds", "histogram", "io",
          "Executor blocked waiting on the input pipeline", exact=True),
     Rule("fetch_sync_seconds", "histogram", "io",
@@ -195,6 +212,20 @@ RULES = (
          "Rank-0 aggregated cluster health (skew, stragglers, HBM)"),
     Rule("hbm_", "gauge", "xla_stats",
          "HBM budget gate and live device memory"),
+    Rule("xla_program_births", "gauge", "xla_stats",
+         "Programs compiled or loaded from the compile cache since the "
+         "process started, all threads (`xla_stats.program_births()` "
+         "holds each one's record): flat in a healthy serving process "
+         "after warm-up", exact=True),
+    Rule("xla_cache_hits", "gauge", "xla_stats",
+         "Births that a persistent-cache hit served: a warm start has "
+         "`xla_cache_hits` = `xla_program_births` less the uncached",
+         exact=True),
+    Rule("xla_cache_misses", "gauge", "xla_stats",
+         "Births that asked the persistent cache and compiled: each "
+         "leaves an `xla/program_born` flight event naming the span it "
+         "ran under; nonzero after start-up means a shape or a source "
+         "line the cache has not seen", exact=True),
     Rule("xla_", "gauge", "xla_stats",
          "XLA introspection availability/fallback counters"),
     Rule("slo_", "gauge", "slo",
@@ -237,6 +268,13 @@ RULES = (
          "layer its last `window`).  Their ratio is the share of the "
          "attention that is work; the rest is the blocks the diagonal "
          "or the window's edge crosses and the bucket's padding"),
+    Rule("decode_prefill_compiles", "gauge", "serving",
+         "jit FUNCTIONS the engine made for a prefill bucket or a "
+         "multi-row step (one a new `(bucket, model, quantized)` or "
+         "`(rows, slots, model)` key), not compiles: whether such a "
+         "function's first call compiled or loaded, and for how long, is "
+         "its birth (`xla_program_births`, `xla_cache_misses`, under "
+         "`serving/prefill_dispatch` with its `bucket`)", exact=True),
     Rule("decode_prefill_attn_", "gauge", "serving",
          "Layer-calls of a whole-prompt prefill's attention by the form "
          "they ran in, added once a prefill: `_flash` the Pallas flash "

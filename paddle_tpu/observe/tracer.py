@@ -36,7 +36,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Dict, List, NamedTuple, Optional
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 from jax.profiler import TraceAnnotation as _Annotation
 
@@ -44,7 +44,7 @@ from ..framework import flags as _flags
 
 __all__ = ["SpanRecord", "Tracer", "get_tracer", "enabled", "enable",
            "disable", "span", "begin", "end", "snapshot", "clear",
-           "NULL_SPAN"]
+           "open_spans", "NULL_SPAN"]
 
 DEFAULT_CAPACITY = 65536
 
@@ -103,8 +103,21 @@ class Tracer:
         # span begun while it was off is invisible to depth and parent
         depth = len(st)
         parent = st[-1][0] if st else None
-        rec = SpanRecord(name, t0, t1, th.ident or 0, th.name, depth,
-                         parent, args)
+        self._append(SpanRecord(name, t0, t1, th.ident or 0, th.name,
+                                depth, parent, args))
+
+    def record(self, name: str, t_begin: float, t_end: float,
+               parent: Optional[str] = None,
+               args: Optional[dict] = None) -> None:
+        """A span whose two ends are already known (seconds since the
+        tracer epoch), on the calling thread's lane: what reports a
+        phase only once it is over (a program's birth,
+        ``xla_stats``)."""
+        th = threading.current_thread()
+        self._append(SpanRecord(name, t_begin, t_end, th.ident or 0,
+                                th.name, len(self._stack()), parent, args))
+
+    def _append(self, rec: SpanRecord) -> None:
         with self._lock:
             if len(self._buf) == self._buf.maxlen:
                 self._dropped += 1
@@ -204,8 +217,11 @@ NULL_SPAN = _NULL
 
 
 # per-thread LIFO of the open spans: (profiler annotation, whether the
-# span is also in the ring buffer).  begin() decides both sinks once, so
-# a pair stays balanced when FLAGS_enable_tracer flips between the calls
+# span is also in the ring buffer, name, attributes).  begin() decides
+# both sinks once, so a pair stays balanced when FLAGS_enable_tracer
+# flips between the calls; name and attributes ride along whichever
+# sinks take the span, so that a compile can name the span it ran under
+# (``open_spans``) in a process that traces nothing
 _local = threading.local()
 
 
@@ -215,6 +231,12 @@ def _open_spans() -> list:
     except AttributeError:
         st = _local.spans = []
         return st
+
+
+def open_spans() -> List[Tuple[str, dict]]:
+    """``(name, attributes)`` of the calling thread's open spans,
+    outermost first, with the ring buffer on or off."""
+    return [(name, attrs) for _, _, name, attrs in _open_spans()]
 
 
 def span(name: str, **attrs):
@@ -239,14 +261,14 @@ def _begin(name: str, attrs: dict) -> None:
     in_ring = bool(_flags.flag("enable_tracer"))
     if in_ring:
         _TRACER.begin(name, attrs or None)
-    _open_spans().append((annotation, in_ring))
+    _open_spans().append((annotation, in_ring, name, attrs))
 
 
 def end() -> None:
     st = _open_spans()
     if not st:  # unbalanced end(): drop silently (never raise in
         return  # instrumentation paths)
-    annotation, in_ring = st.pop()
+    annotation, in_ring, _, _ = st.pop()
     if in_ring:
         _TRACER.end()
     annotation.__exit__(None, None, None)
@@ -258,7 +280,7 @@ def set_span_args(**kwargs) -> None:
     st = _open_spans()
     if not st:
         return
-    annotation, in_ring = st[-1]
+    annotation, in_ring, _, _ = st[-1]
     annotation.set_metadata(**kwargs)
     if in_ring:
         _TRACER.set_args(**kwargs)

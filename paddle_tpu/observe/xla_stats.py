@@ -8,12 +8,27 @@ at all, via an opaque RESOURCE_EXHAUSTED after dispatch.  This module
 is the reference's ``memory_optimize``/profiler role (SURVEY L1/L11)
 rebuilt on what jax actually exposes:
 
-- **Compile telemetry** — the Executor AOT-lowers every fresh entry
+- **Compile telemetry** — of EVERY program the process compiles, not
+  the Executor's alone.  Listeners on ``jax.monitoring`` (registered
+  once when ``paddle_tpu.observe`` is imported) assemble one *birth
+  record* a compiled program, per thread: its trace, its lowering, and
+  its backend compile or its load from the persistent cache, hit or
+  miss, and the span it ran under (:func:`program_births`,
+  :func:`births_summary`; counters ``xla_program_births``,
+  ``xla_cache_hits``, ``xla_cache_misses``; histograms
+  ``xla_trace_seconds``, ``xla_lower_seconds``,
+  ``xla_backend_compile_seconds``, ``xla_cache_load_seconds``; a flight
+  event ``xla/program_born`` a miss; ring-buffer spans ``xla/*`` under
+  ``FLAGS_enable_tracer``).  The serving engine's lazy ``jax.jit``s,
+  the weights' jit and eager operations are in it like the Executor's
+  entries; a listener runs while a program compiles and never on a
+  step.  The Executor besides AOT-lowers every fresh entry
   (``jit_fn.lower(...).compile()``) and hands the compiled executable
   to :func:`on_compile`: wall time into the ``compile_seconds``
-  histogram, executable size + HLO module stats as ``/metrics`` gauges,
-  an ``executor/compile_done`` flight event with the duration, and an
-  optional optimized-HLO dump (``FLAGS_hlo_dump_dir``).
+  histogram (its split is the record's ``birth``), executable size +
+  HLO module stats as ``/metrics`` gauges, an ``executor/compile_done``
+  flight event with the duration, and an optional optimized-HLO dump
+  (``FLAGS_hlo_dump_dir``).
 - **HBM accounting** — ``compiled.memory_analysis()`` (per-chip under
   SPMD, since the analyzed module is the partitioned per-device
   program) becomes a footprint
@@ -36,6 +51,8 @@ gate (explicitly armed via the flag) may raise.
 from __future__ import annotations
 
 import collections
+import functools
+import logging
 import os
 import threading
 import time
@@ -45,6 +62,7 @@ import numpy as np
 
 from ..framework import flags as _flags
 from . import flight as _flight
+from . import tracer as _tracer
 from .histogram import stat_time
 
 __all__ = ["COMPILE_SECONDS_HISTOGRAM", "MemoryBudgetError",
@@ -52,7 +70,10 @@ __all__ = ["COMPILE_SECONDS_HISTOGRAM", "MemoryBudgetError",
            "format_attribution", "device_memory_stats",
            "device_hbm_capacity", "record_device_memory",
            "check_hbm_budget", "on_compile", "compile_records",
-           "last_compile", "memory_report", "clear_compile_records"]
+           "last_compile", "memory_report", "clear_compile_records",
+           "listen_for_births", "program_births", "births_summary"]
+
+logger = logging.getLogger(__name__)
 
 COMPILE_SECONDS_HISTOGRAM = "compile_seconds"
 
@@ -390,6 +411,14 @@ def on_compile(compiled, *, fingerprint: str = "", seconds: float = 0.0,
         "compile_seconds": round(float(seconds), 6),
         "n_steps": int(n_steps),
     }
+    # what compile_seconds timed: the calling thread's newest birth, if
+    # it lies inside the seconds just measured (the caller compiled on
+    # this thread and came straight here)
+    born, _birth_local.last = getattr(_birth_local, "last", None), None
+    if born is not None and born["t_begin"] >= _now() - float(seconds) - 1.0:
+        rec["birth"] = {k: born[k] for k in (
+            "seq", "program", "trace_s", "lower_s", "backend_s", "cache",
+            "cache_load_s", "compile_saved_s")}
     if mesh is not None:
         try:
             rec["mesh"] = {str(a): int(mesh.shape[a])
@@ -501,6 +530,254 @@ def on_compile(compiled, *, fingerprint: str = "", seconds: float = 0.0,
     if budget_exc is not None:
         raise budget_exc
     return rec
+
+
+# ---------------------------------------------------------------------------
+# the birth log: one record a compiled program, from jax.monitoring
+# ---------------------------------------------------------------------------
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
+
+# a benchmark cell's set-up is 6-30 programs (a step, a prefill a bucket,
+# the weights' jit, the check's reference, a few one-operation eager
+# ones); past this many the ring forgets, births_summary() counts on
+BIRTHS_CAPACITY = 1024
+# a thread's traces wait for their lowering by function name, the newest
+# of a name: a large program's lowering traces hundreds of small jitted
+# functions AFTER the program's own trace has ended, so the newest N
+# would lose it.  Names that no lowering claims (eval_shape, make_jaxpr)
+# are dropped together past this many
+_PENDING_TRACES = 1024
+# the span attributes that say WHICH step or prefill compiled
+_UNDER_ATTRS = ("iter", "step", "bucket")
+
+_BIRTHS: "collections.deque[dict]" = collections.deque(
+    maxlen=BIRTHS_CAPACITY)
+_BIRTH_TOTALS: Dict[str, Dict[str, float]] = {}
+_BIRTH_SEQ = 0
+_LAST_BIRTH_END: Optional[float] = None
+_LISTENING = False
+# jax reports a compile's phases on the compiling thread, each as it
+# ends: a thread's pending traces, its program between lowering and
+# backend, and its newest finished birth (what on_compile attaches)
+_birth_local = threading.local()
+
+
+def _now() -> float:
+    return time.perf_counter() - _tracer._EPOCH
+
+
+def _split_fun_name(fun_name) -> Tuple[str, str]:
+    """jax's ``jit(step)`` as (the program's name as its XLA module and a
+    device trace have it, ``jit_step``; the traced function's, ``step``)."""
+    name = str(fun_name or "")
+    api, paren, rest = name.partition("(")
+    if paren and rest.endswith(")"):
+        return f"{api}_{rest[:-1]}", rest[:-1]
+    return name, name
+
+
+def _under() -> Tuple[Optional[str], dict]:
+    """The open span a birth is filed under: the innermost one of the
+    calling thread that says which step or prefill it is (an engine
+    program's first call lies inside ``executor/persistent``, which
+    names neither, inside ``serving/step_dispatch``, which does); where
+    none does, the innermost one; ``(None, {})`` outside every span."""
+    spans = _tracer.open_spans()
+    for name, attrs in reversed(spans):
+        found = {k: attrs[k] for k in _UNDER_ATTRS if k in attrs}
+        if found:
+            return name, found
+    return (spans[-1][0], {}) if spans else (None, {})
+
+
+def _never_raising(listener):
+    """jax calls a listener inside the compile it reports: a fault of
+    the log must not become a fault of the program's compile."""
+    @functools.wraps(listener)
+    def guarded(*args, **kw):
+        try:
+            listener(*args, **kw)
+        except Exception:  # noqa: BLE001 - telemetry, inside jax's compile
+            from ..monitor import stat_add
+
+            stat_add("xla_birth_log_errors")
+            logger.debug("birth log listener failed", exc_info=True)
+    return guarded
+
+
+@_never_raising
+def _on_event(event: str, **_kw) -> None:
+    born = getattr(_birth_local, "open", None)
+    if born is None:
+        return
+    if event == _CACHE_REQUEST_EVENT:
+        # jax makes the request with no cache directory too, and then
+        # finds nothing: that is no miss a warmer cache would cure
+        import jax
+
+        if jax.config.jax_compilation_cache_dir:
+            born["cache"] = "miss"  # until a hit says otherwise
+    elif event == _CACHE_HIT_EVENT:
+        born["cache"] = "hit"
+
+
+@_never_raising
+def _on_duration(event: str, duration: float, **kw) -> None:
+    if event == _TRACE_EVENT:
+        traces = getattr(_birth_local, "traces", None)
+        if traces is None or len(traces) >= _PENDING_TRACES:
+            traces = _birth_local.traces = {}
+        traces[kw.get("fun_name")] = (float(duration), _now())
+    elif event == _LOWER_EVENT:
+        _open_birth(*_split_fun_name(kw.get("fun_name")), float(duration))
+    elif event == _BACKEND_EVENT:
+        _close_birth(_split_fun_name(kw.get("fun_name"))[0],
+                     float(duration))
+    elif event in (_CACHE_LOAD_EVENT, _CACHE_SAVED_EVENT):
+        born = getattr(_birth_local, "open", None)
+        if born is not None:
+            key = "cache_load_s" if event == _CACHE_LOAD_EVENT \
+                else "compile_saved_s"
+            born[key] = float(duration)
+
+
+def _new_birth(program: str, t_begin: float, lower_s: float = 0.0,
+               t_lowered: Optional[float] = None) -> dict:
+    return {"program": program, "trace_s": 0.0, "lower_s": lower_s,
+            "t_begin": t_begin, "t_lowered": t_lowered, "cache": "off",
+            "cache_load_s": None, "compile_saved_s": None}
+
+
+def _open_birth(program: str, traced: str, lower_s: float) -> None:
+    """A lowering ended: the program's trace is the newest pending one
+    of its function's name (the traces of the jitted functions it calls
+    lie inside it, those its lowering made lie inside ``lower_s``: they
+    are dropped, not added)."""
+    t_lowered = _now()
+    born = _new_birth(program, t_lowered - lower_s, lower_s, t_lowered)
+    trace = (getattr(_birth_local, "traces", None) or {}).get(traced)
+    if trace is not None:
+        born["trace_s"], t_traced = trace
+        born["t_begin"] = min(born["t_begin"], t_traced - born["trace_s"])
+    _birth_local.traces = None
+    _birth_local.open = born
+
+
+def _close_birth(program: str, backend_s: float) -> None:
+    global _BIRTH_SEQ, _LAST_BIRTH_END
+
+    from ..monitor import stat_add
+
+    t_end = _now()
+    born, _birth_local.open = getattr(_birth_local, "open", None), None
+    if born is None or born["program"] != program:
+        # compiled from a lowering made elsewhere: what is known is here
+        born = _new_birth(program, t_end - backend_s)
+    t_lowered = born.pop("t_lowered")
+    under, under_attrs = _under()
+    th = threading.current_thread()
+    born.update(backend_s=backend_s, t_end=t_end, thread=th.name,
+                under=under, under_attrs=under_attrs)
+    cache = born["cache"]
+    with _LOCK:
+        _BIRTH_SEQ += 1
+        born["seq"] = _BIRTH_SEQ
+        _LAST_BIRTH_END = t_end
+        tot = _BIRTH_TOTALS.setdefault(cache, {
+            "births": 0, "trace_s": 0.0, "lower_s": 0.0,
+            "backend_s": 0.0, "cache_load_s": 0.0})
+        tot["births"] += 1
+        for key in ("trace_s", "lower_s", "backend_s", "cache_load_s"):
+            tot[key] += born[key] or 0.0
+        _BIRTHS.append(born)
+    _birth_local.last = born
+
+    stat_add("xla_program_births")
+    stat_time("xla_trace_seconds", born["trace_s"])
+    stat_time("xla_lower_seconds", born["lower_s"])
+    if cache == "hit":
+        stat_add("xla_cache_hits")
+        stat_time("xla_cache_load_seconds", born["cache_load_s"])
+    else:
+        stat_time("xla_backend_compile_seconds", backend_s)
+    if cache == "miss":
+        # in a serving process a miss after start-up is a recompile
+        stat_add("xla_cache_misses")
+        _flight.record("xla/program_born", program=program,
+                       birth=born["seq"], thread=th.name, under=under,
+                       **under_attrs, trace_s=round(born["trace_s"], 6),
+                       lower_s=round(born["lower_s"], 6),
+                       backend_s=round(backend_s, 6))
+    if _tracer.enabled():
+        tr = _tracer.get_tracer()
+        args = {"program": program, "birth": born["seq"], **under_attrs}
+        if born["trace_s"]:
+            tr.record("xla/trace", born["t_begin"],
+                      born["t_begin"] + born["trace_s"], under, args)
+        if t_lowered is not None:
+            tr.record("xla/lower", t_lowered - born["lower_s"], t_lowered,
+                      under, args)
+        tr.record("xla/cache_load" if cache == "hit"
+                  else "xla/backend_compile", t_end - backend_s, t_end,
+                  under, dict(args, cache=cache))
+
+
+def listen_for_births() -> None:
+    """Register the birth log's listeners on ``jax.monitoring``: once a
+    process, however often it is called (``paddle_tpu.observe`` calls
+    it when it is imported, which precedes every compile)."""
+    global _LISTENING
+
+    with _LOCK:
+        if _LISTENING:
+            return
+        _LISTENING = True
+    import jax
+
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def program_births() -> List[dict]:
+    """The newest ``BIRTHS_CAPACITY`` birth records, oldest first.  One
+    a compiled program: ``seq``, ``program`` (jax's ``fun_name``
+    ``jit(step)`` as the XLA module and a device trace name it,
+    ``jit_step``), ``thread``, ``t_begin`` and ``t_end`` (seconds on the
+    tracer's epoch: the ring buffer's timeline), ``trace_s`` (the
+    program's own trace; the traces of the jitted functions it calls lie
+    inside it), ``lower_s``, ``backend_s`` (the backend's compile, or on
+    a hit the load that took its place), ``cache`` (``hit`` | ``miss`` |
+    ``off`` where the process has no cache directory or jax asked no
+    cache), ``cache_load_s`` and
+    ``compile_saved_s`` (a hit's, else None), and ``under`` with
+    ``under_attrs``: the span that caused it (:func:`_under`) and which
+    of ``iter`` / ``step`` / ``bucket`` that span carries."""
+    with _LOCK:
+        return [dict(b) for b in _BIRTHS]
+
+
+def births_summary() -> dict:
+    """Counts and sums of every birth since the process started, by
+    ``cache`` (the ring forgets, these do not), and when the newest
+    ended."""
+    with _LOCK:
+        by_cache = {k: dict(v) for k, v in _BIRTH_TOTALS.items()}
+        last = _LAST_BIRTH_END
+    return {
+        "births": sum(int(v["births"]) for v in by_cache.values()),
+        "cache_hits": int(by_cache.get("hit", {}).get("births", 0)),
+        "cache_misses": int(by_cache.get("miss", {}).get("births", 0)),
+        "by_cache": by_cache,
+        "last_birth_t_end": last,
+        "since_last_birth_s": None if last is None else _now() - last,
+    }
 
 
 # ---------------------------------------------------------------------------

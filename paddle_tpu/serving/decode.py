@@ -3020,16 +3020,22 @@ class DecodeEngine:
         """A joint step whose hand-over and read-back together took the
         host longer than ``SLOW_STEP_S``: how often, in which phase
         (whether the device or the wake-up was late no host clock can
-        say), behind how many prefills."""
+        say), behind how many prefills, and what its process had compiled
+        by then (programs born, how many of them cold, how long ago the
+        newest): the stall's one lead is a cold set-up."""
         from ..observe import flight as _flight
+        from ..observe import xla_stats
 
         stat_add("decode_steps_slow")
         t0, t1, t2, t3 = stamps
+        born = xla_stats.births_summary()
         _flight.record(
             "serving/slow_step", name=self.name, iter=attrs["iter"],
             step=attrs["step"], live=attrs["live"], prefills_ahead=ahead,
             seconds=round((t1 - t0) + (t3 - t2), 6), t_handover_begin=t0,
-            t_handover_end=t1, t_readback_begin=t2, t_readback_end=t3)
+            t_handover_end=t1, t_readback_begin=t2, t_readback_end=t3,
+            births=born["births"], cache_misses=born["cache_misses"],
+            since_last_birth_s=born["since_last_birth_s"])
 
     def _run_spec(self, spec_idx):
         """One speculative round for the greedy slots: a k-token draft
