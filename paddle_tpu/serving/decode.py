@@ -357,13 +357,14 @@ class _Mixers:
     to ITS kind's pools and layer there (``attend`` for an
     ``"attention"`` layer, ``attend_window`` for a ``"window"`` one, which
     also gets the call's ``sinks``); ``recur(layer, token_fn, rows,
-    cache, chunk_fn=None, chunk=0)`` runs a
+    cache, chunk_fn=None, chunk=0, chunks_per_call=1)`` runs a
     recurrent layer's one-token update ``token_fn(rows, state) -> (out,
     state)`` where the program keeps that state (the joint step hands a
     ``token_fn`` that has a ``live`` parameter the rows' mask and leaves
     the dead rows to it; a whole-prompt prefill
     runs ``chunk_fn(rows, n_real, state)`` over ``chunk`` consecutive
-    tokens at once where the model hands one); ``live`` (bool, the
+    tokens at once where the model hands one, ``chunks_per_call`` of
+    the rule's own chunks a call); ``live`` (bool, the
     rows' shape) says which rows are a request's; ``tally(name, n)``
     adds an int32 scalar to the counter ``name``, one of the model's
     declared ``tallies`` (a joint step's ride its one read-back, in the
@@ -405,10 +406,12 @@ class _Mixers:
                 **({} if keep is None else {"keep": keep}))
         return ctx, (pools, window, rec)
 
-    def recur(self, layer, token_fn, rows, cache, chunk_fn=None, chunk=0):
+    def recur(self, layer, token_fn, rows, cache, chunk_fn=None, chunk=0,
+              chunks_per_call=1):
         pools, window, rec = cache
         i = self._mixed.layer["recurrent"][layer]
-        out, new = self._recur(token_fn, rows, rec[i], chunk_fn, chunk)
+        out, new = self._recur(token_fn, rows, rec[i], chunk_fn, chunk,
+                               chunks_per_call)
         return out, (pools, window, rec[:i] + (new,) + rec[i + 1:])
 
     def tally(self, name, value):
@@ -811,8 +814,9 @@ class DecodeEngine:
     shape brings its counters: the others are counted and dropped).
     ``attend`` is then a ``_Mixers``: the call as above for an attention
     or window layer (mapped to ITS kind's pools);
-    ``attend.recur(layer, token_fn, rows, cache, chunk_fn=None, chunk=0)
-    -> (out, cache)`` for a recurrent one, where ``token_fn(rows, state)
+    ``attend.recur(layer, token_fn, rows, cache, chunk_fn=None, chunk=0,
+    chunks_per_call=1) -> (out, cache)`` for a recurrent one, where
+    ``token_fn(rows, state)
     -> (out, state)`` is the model's one-token update over rows
     ``[R, ...]`` and the engine decides what state that is and where it
     goes (the joint step: every live slot's row, in place; the
@@ -832,9 +836,19 @@ class DecodeEngine:
     ``chunk_fn(rows, n_real, state) -> (out, state)``: the same rule over
     ``chunk`` consecutive rows of ONE request from the state before them
     (leading dimension 1), of which only the first ``n_real`` are the
-    request's and may touch the state; it then runs once a chunk
-    (counters ``decode_prefill_scan_steps`` / ``decode_prefill_scan_
-    tokens``: loop iterations and real tokens, a recurrent layer).
+    request's and may touch the state; it then runs once a chunk, in
+    ONE loop a layer that carries the state and holds all of the call.
+    ``chunk`` is what ONE CALL covers; a model whose rule has a chunk of
+    its own and whose call takes a group of them (what does not read the
+    state formed for the whole group, the state passed through its
+    chunks in turn: ``serving/gated_delta_lm.py``) says how many as
+    ``chunks_per_call``, and declares ``prefill_chunks_per_call(rows)``,
+    the same number for a prompt bucket of ``rows`` (0: it hands no
+    chunk form; gauge ``decode_prefill_chunks_per_call``, at the largest
+    bucket).  Counters ``decode_prefill_scan_steps`` /
+    ``decode_prefill_scan_tokens``: the rule's chunks that held a real
+    token (calls, where a call is one chunk) and real tokens, a
+    recurrent layer.
     ``attend.live``
     (which rows are a request's), ``attend.tally(name, n)`` (counters
     the model declares by name in ``tallies``; they ride the step's one
@@ -971,6 +985,12 @@ class DecodeEngine:
             cc.store_dtype, cc.v_row_lanes, kv_heads,
             model.num_heads // kv_heads)
         stat_set("decode_attn_block_positions", self._attn_block)
+        # the rule's own chunks ONE call of the model's chunk form takes
+        # of a recurrent layer's prompt, at the largest bucket (0: the
+        # model hands no chunk form)
+        stat_set("decode_prefill_chunks_per_call", getattr(
+            model, "prefill_chunks_per_call", lambda rows: 0)(
+                c.max_seq_len))
         self._attn_table_blocks = cc.num_slots * -(
             -cc.max_seq_len // self._attn_block)
         if self._window is not None:
@@ -1310,7 +1330,8 @@ class DecodeEngine:
                 # the delivery drops what it yields
                 live = live & ~(carry & (tokens == eos))
 
-            def recur(token_fn, rows, rec, chunk_fn=None, chunk=0):
+            def recur(token_fn, rows, rec, chunk_fn=None, chunk=0,
+                      chunks_per_call=1):
                 """Every slot's state one token on; a dead slot's row (a
                 prefill ahead of this step may just have filled it)
                 stays as it is: left so by a ``token_fn`` that takes
@@ -1400,13 +1421,17 @@ class DecodeEngine:
             positions = jnp.arange(t_pad, dtype=jnp.int32)
             row_lengths = positions + 1
 
-            def recur(token_fn, rows, rec, chunk_fn=None, chunk=0):
+            def recur(token_fn, rows, rec, chunk_fn=None, chunk=0,
+                      chunks_per_call=1):
                 """The prompt's ``length`` real tokens from the zero
                 state, so padding rows never touch the state: ``chunk``
                 at a time through ``chunk_fn`` where the model hands one
-                (the last chunk is told how many of its rows are real),
+                (the last call is told how many of its rows are real),
                 else one after another through ``token_fn``; what the
-                last one leaves goes into the slot's rows of the slabs."""
+                last one leaves goes into the slot's rows of the slabs.
+                A call that takes ``chunks_per_call`` of the model's own
+                chunks counts as that many scan steps, less those of
+                the last call that hold no real row."""
                 state0 = {n: jnp.zeros((1,) + v.shape[1:], v.dtype)
                           for n, v in rec.items()}
                 if chunk_fn is None:
@@ -1434,7 +1459,8 @@ class DecodeEngine:
                 st, outs = jax.lax.fori_loop(
                     0, steps, scan_step,
                     (state0, jnp.zeros((t_run,) + out.shape[1:], out.dtype)))
-                mix.tally(_SCAN_TALLIES[0], steps)
+                mix.tally(_SCAN_TALLIES[0], steps if chunks_per_call == 1
+                          else -(-length // (chunk // chunks_per_call)))
                 mix.tally(_SCAN_TALLIES[1], length)
                 return outs[:t_pad], {
                     n: jax.lax.dynamic_update_slice_in_dim(

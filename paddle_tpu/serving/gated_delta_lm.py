@@ -16,10 +16,20 @@ docstring, like ``hybrid_moe_lm.py``: ``forward(weights, tokens,
 positions, cache, attend)``.  What it declares: ``layer_kinds``
 (``"attention"`` or ``"recurrent"`` a layer), ``num_kv_heads`` (the query
 heads' count), ``recurrent_state`` (one slot's state of one recurrent
-layer), ``tallies`` (none).  What it hands ``attend.recur`` beside the
-one-token update: the same rule over a CHUNK of ``CHUNK`` consecutive
-tokens of one request (``_gdn_chunk``), which the whole-prompt prefill
-runs once a chunk instead of the token update once a token.
+layer), ``tallies`` (none), ``prefill_chunks_per_call(rows)``.  What it
+hands ``attend.recur`` beside the one-token update: the same rule over a
+GROUP of chunks of ``CHUNK`` consecutive tokens of one request
+(``_gdn_chunk``), which the whole-prompt prefill runs once a group
+instead of the token update once a token.  One call covers
+``prefill_chunks_per_call(bucket)`` chunks (the bucket's, capped by
+``GROUP_BYTES`` of temporaries: four at the served widths, a function
+of the call's shapes alone) and tells ``recur`` so
+(``chunks_per_call``): what of the rule's WY form reads no state (the
+convolution, the unit-length q and k, the decays, ``Q K^T`` and ``K
+K^T``, the triangular inverse, ``T [beta V | beta e^G K]``) is formed
+for the whole group at once inside the engine's one loop a layer, the
+state passes through the group's chunks in sequence (``_state_pass``),
+and the group's outputs follow from what the pass kept.
 
 Precision as served: weights (and K/V pages) in ``dtype`` (bfloat16),
 every matmul accumulating in float32; the residual stream, norms,
@@ -39,7 +49,11 @@ from .hybrid_moe_lm import _mm, rms_norm
 GDN_SCOPE = "gdn_update"        # the one-token update's operations
 GDN_CHUNK_SCOPE = "gdn_chunk"   # the chunk form's
 FFN_SCOPE = "dense_ffn"
-CHUNK = 64                      # tokens the chunk form takes at once
+CHUNK = 64                      # tokens of one chunk of the rule's WY form
+# what the chunks one call takes together may hold in temporaries: half
+# of the 128 MiB of fast memory the chip's compiler keeps a loop body's
+# operands in (PERF.md section 6, PR 53, has the sweep this is read from)
+GROUP_BYTES = 64 << 20
 
 
 class GatedDeltaLM:
@@ -180,29 +194,37 @@ class GatedDeltaLM:
 
         rows = {"u": _mm(x, lw["gdn_wqkv"]), "a": _mm(x, lw["gdn_wa"]),
                 "b": _mm(x, lw["gdn_wb"])}
+        group = self.prefill_chunks_per_call(x.shape[0])
         o, cache = attend.recur(
             l, functools.partial(self._gdn_token, lw), rows, cache,
-            chunk_fn=functools.partial(self._gdn_chunk, lw), chunk=CHUNK)
+            chunk_fn=functools.partial(self._gdn_chunk, lw),
+            chunk=group * CHUNK, chunks_per_call=group)
         o = rms_norm(o, lw["gdn_onorm"], self.rms_eps)
         return _mm(o.reshape(*x.shape[:-1], -1)
                    * jax.nn.silu(_mm(x, lw["gdn_wg"])), lw["gdn_wout"]), cache
+
+    def prefill_chunks_per_call(self, rows):
+        """Chunks ONE call of ``_gdn_chunk`` takes of a prompt bucket of
+        ``rows`` rows: all of them, up to what ``GROUP_BYTES`` of the
+        group's float32 temporaries allow (a chunk's: four ``C x C``
+        matrices a head, the rows q, k, v, ``T [beta V | beta e^G K]``,
+        ``e^G q``, the decayed keys, what the tokens wrote and the
+        output, and the state it started from)."""
+        nh, dk, dv = self.lin_heads, self.lin_key_dim, self.lin_value_dim
+        a_chunk = 4 * nh * (4 * CHUNK * CHUNK + CHUNK * (5 * dk + 4 * dv)
+                            + dk * dv)
+        return max(1, min(-(-int(rows) // CHUNK), GROUP_BYTES // a_chunk))
 
     # -- the gated delta rule -------------------------------------------------
     def _heads(self, conv):
         """The convolved, activated rows ``[R, lin_width]`` split into
         (q^ [R, H, dk], k^ [R, H, dk], v [R, H, dv]): q and k at unit
         length a head, q scaled by ``dk^-1/2``."""
-        import jax
-        import jax.numpy as jnp
-
         nh, dk, dv = self.lin_heads, self.lin_key_dim, self.lin_value_dim
         q = conv[:, :nh * dk].reshape(-1, nh, dk)
         k = conv[:, nh * dk:2 * nh * dk].reshape(-1, nh, dk)
         v = conv[:, 2 * nh * dk:].reshape(-1, nh, dv)
-        q = q * jax.lax.rsqrt(
-            jnp.sum(q * q, -1, keepdims=True) + 1e-6) / math.sqrt(dk)
-        k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
-        return q, k, v
+        return _unit(q) / math.sqrt(dk), _unit(k), v
 
     def _log_decay(self, lw, a):
         """``log alpha`` [R, H] (never above 0) of the gate rows ``a``."""
@@ -247,68 +269,122 @@ class GatedDeltaLM:
         return o, {"s": s, "tail": window[:, wide:]}
 
     def _gdn_chunk(self, lw, rows, n_real, state):
-        """``CHUNK`` consecutive tokens of ONE request through a
-        recurrent layer at once: ``rows`` their projections (``u [C,
-        lin_width]``, ``a`` and ``b [C, H]``), of which the first
-        ``n_real`` are the request's (the rest is padding and never
-        touches the state), ``state`` the request's state before the
-        chunk (``s [1, H, dk, dv]``, ``tail [1, (K-1)*lin_width]``) ->
-        (``o [C, H, dv]``, the state after token ``n_real - 1``).
+        """A GROUP of whole ``CHUNK``-token chunks, consecutive tokens
+        of ONE request, through a recurrent layer at once: ``rows``
+        their projections (``u [G*C, lin_width]``, ``a`` and ``b [G*C,
+        H]``), of which the first ``n_real`` are the request's (the rest
+        is padding and never touches the state), ``state`` the request's
+        state before the group (``s [1, H, dk, dv]``, ``tail [1,
+        (K-1)*lin_width]``) -> (``o [G*C, H, dv]``, the state after
+        token ``n_real - 1``).
 
-        The rule's WY form.  With ``G_t`` the summed log-decays up to
-        and with token t, ``u_t = beta_t (v_t - alpha_t S_{t-1}^T k_t)``
-        (what token t writes along ``k_t``) solves the unit lower
-        triangular system ``(I + A) U = beta (V - e^G K S_0)``, ``A_tj =
-        beta_t e^{G_t - G_j} k_t.k_j`` for ``j < t``; then ``O = e^G Q
-        S_0 + P U`` with ``P_tj = e^{G_t - G_j} q_t.k_j`` for ``j <= t``
-        and ``S_C = e^{G_C} S_0 + (e^{G_C - G} K)^T U``.  Every decay
-        ratio is the ``exp`` of a difference of summed logs that is
-        never above 0: no quotient of products, nothing to overflow.  A
-        padding row has ``beta = 0`` and ``log alpha = 0``: it writes
-        nothing and decays nothing."""
+        The rule's WY form, a chunk.  With ``G_t`` the summed log-decays
+        up to and with token t OF ITS CHUNK, ``u_t = beta_t (v_t -
+        alpha_t S_{t-1}^T k_t)`` (what token t writes along ``k_t``)
+        solves the unit lower triangular system ``(I + A) U = beta (V -
+        e^G K S_0)``, ``A_tj = beta_t e^{G_t - G_j} k_t.k_j`` for ``j <
+        t``; then ``O = e^G Q S_0 + P U`` with ``P_tj = e^{G_t - G_j}
+        q_t.k_j`` for ``j <= t`` and ``S_C = e^{G_C} S_0 + (e^{G_C - G}
+        K)^T U``.  Every decay ratio is the ``exp`` of a difference of
+        summed logs that is never above 0: no quotient of products,
+        nothing to overflow.  A padding row has ``beta = 0`` and ``log
+        alpha = 0``: it writes nothing and decays nothing, and a chunk
+        of padding rows hands the state on as it got it.
+
+        Of all that only ``U``'s right side, ``O``'s first term and
+        ``S_C`` read the chunk's ``S_0``.  Everything else is a function
+        of the chunk's own rows and is formed for all G chunks at once,
+        on ``[G, H, C, ...]`` operands; the state then passes through
+        the chunks one after another (``_state_pass``: two products and
+        two element-wise operations a chunk), and the outputs of the
+        whole group follow from what the pass kept."""
         import jax
         import jax.numpy as jnp
 
         hi = _exact
         with jax.named_scope(GDN_CHUNK_SCOPE):
-            c, wide = rows["u"].shape
-            real = jnp.arange(c, dtype=jnp.int32) < n_real
+            r, wide = rows["u"].shape
+            c, nh = CHUNK, self.lin_heads
+            dk, dv = self.lin_key_dim, self.lin_value_dim
+            if r % c:
+                raise ValueError(f"{r} rows are no whole number of chunks "
+                                 f"of {c}")
+            n = r // c
+            real = (jnp.arange(r, dtype=jnp.int32) < n_real)[:, None]
             window = jnp.concatenate(
                 [state["tail"].reshape(self.conv_kernel - 1, wide),
                  rows["u"]])
-            conv = sum(window[j:j + c] * lw["gdn_conv"][j]
-                       for j in range(self.conv_kernel))
-            # head-major from here: [H, C, ...]
-            q, k, v = (jnp.swapaxes(x, 0, 1)
-                       for x in self._heads(jax.nn.silu(conv)))
-            beta = jnp.where(real[:, None], self._beta(rows["b"]), 0.0).T
-            g = jnp.cumsum(jnp.where(
-                real[:, None], self._log_decay(lw, rows["a"]), 0.0),
-                axis=0).T                                       # [H, C]
-            ratio = g[:, :, None] - g[:, None, :]               # G_t - G_j
+
+            def heads(lo, d):
+                """Columns ``lo`` on of the window, ``d`` a head, head-
+                major BEFORE anything is computed on them (the products
+                want the heads in front: turned here the raw rows move
+                once, turned after the convolution every row moves
+                again), convolved and activated -> ``[H, G, C, d]``."""
+                win, taps = (jnp.swapaxes(
+                    x[:, lo:lo + nh * d].reshape(-1, nh, d), 0, 1)
+                    for x in (window, lw["gdn_conv"]))
+                conv = sum(win[:, j:j + r] * taps[:, j, None]
+                           for j in range(self.conv_kernel))
+                return jax.nn.silu(conv).reshape(nh, n, c, d)
+
+            q, k, v = (jnp.swapaxes(x, 0, 1) for x in (
+                _unit(heads(0, dk)) / math.sqrt(dk),
+                _unit(heads(nh * dk, dk)), heads(2 * nh * dk, dv)))
+            beta = jnp.swapaxes(jnp.where(
+                real, self._beta(rows["b"]), 0.0).reshape(n, c, nh), 1, 2)
+            g = jnp.swapaxes(jnp.cumsum(jnp.where(
+                real, self._log_decay(lw, rows["a"]), 0.0).reshape(
+                    n, c, nh), axis=1), 1, 2)                   # [G, H, C]
+            ratio = g[..., :, None] - g[..., None, :]           # G_t - G_j
             at, on = jnp.tril(jnp.ones((c, c), bool)), \
                 jnp.tril(jnp.ones((c, c), bool), -1)
             ratio = jnp.exp(jnp.where(at, ratio, 0.0))
-            a = jnp.where(on, ratio * beta[..., None]
-                          * hi(k, jnp.swapaxes(k, 1, 2)), 0.0)
-            p = jnp.where(at, ratio * hi(q, jnp.swapaxes(k, 1, 2)), 0.0)
+            # Q K^T over K K^T, one product
+            qk = hi(jnp.concatenate([q, k], axis=-2), jnp.swapaxes(k, -1, -2))
+            a = jnp.where(on, ratio * beta[..., None] * qk[..., c:, :], 0.0)
+            p = jnp.where(at, ratio * qk[..., :c, :], 0.0)
             eg = jnp.exp(g)[..., None]                          # e^G
-            s0 = state["s"][0]                                  # [H, dk, dv]
             # T [beta V | beta e^G K]: what each token would write from
             # a zero state, and what of S_0 it has to take back
             t = hi(_unit_lower_inverse(a), jnp.concatenate(
                 [beta[..., None] * v, beta[..., None] * eg * k], axis=-1))
-            read = hi(jnp.concatenate([eg * q, t[..., v.shape[-1]:]],
-                                      axis=1), s0)              # [H, 2C, dv]
-            u = t[..., :v.shape[-1]] - read[:, c:]
-            o = read[:, :c] + hi(p, u)
-            left = jnp.exp(g[:, -1:] - g)[..., None] * k        # e^{G_C - G} K
-            s = jnp.exp(g[:, -1])[:, None, None] * s0 \
-                + hi(jnp.swapaxes(left, 1, 2), u)
+            left = jnp.exp(g[..., -1:] - g)[..., None] * k      # e^{G_C - G} K
+            s0, u, s = _state_pass(
+                t[..., :dv], t[..., dv:], jnp.swapaxes(left, -1, -2),
+                jnp.exp(g[..., -1])[..., None, None], state["s"][0])
+            o = hi(eg * q, s0) + hi(p, u)                       # [G, H, C, dv]
             tail = jax.lax.dynamic_slice_in_dim(
                 window, n_real, self.conv_kernel - 1)
-        return jnp.swapaxes(o, 0, 1), {"s": s[None],
-                                       "tail": tail.reshape(1, -1)}
+        return jnp.swapaxes(o, 1, 2).reshape(r, nh, dv), {
+            "s": s[None], "tail": tail.reshape(1, -1)}
+
+
+def _state_pass(t_v, t_k, left_t, decay, s):
+    """The state ``s [H, dk, dv]`` through G chunks in sequence, the one
+    part of the chunk form that waits on the chunk before: ``t_v [G, H,
+    C, dv]`` what each token would write from a zero state, ``t_k [G, H,
+    C, dk]`` what it takes back of the state it starts from, ``left_t
+    [G, H, dk, C]`` the keys decayed to the chunk's end, ``decay [G, H,
+    1, 1]`` the chunk's whole decay -> (the state each chunk started
+    from ``[G, H, dk, dv]``, what its tokens wrote ``[G, H, C, dv]``,
+    the state after the last chunk)."""
+    import jax.numpy as jnp
+
+    starts, wrote = [], []
+    for i in range(t_v.shape[0]):
+        starts.append(s)
+        wrote.append(t_v[i] - _exact(t_k[i], s))
+        s = decay[i] * s + _exact(left_t[i], wrote[-1])
+    return jnp.stack(starts), jnp.stack(wrote), s
+
+
+def _unit(x):
+    """The rows ``x [..., d]`` at unit length."""
+    import jax
+    import jax.numpy as jnp
+
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
 
 
 def _exact(a, b):
@@ -320,33 +396,52 @@ def _exact(a, b):
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
+_SOLVED = 16    # rows of a diagonal block inverted by substitution
+
+
 def _unit_lower_inverse(a):
     """``(I + a)^-1`` for ``a [..., C, C]`` strictly lower triangular, C
-    a power of two: the inverse of a block ``[[A, 0], [B, D]]`` is
-    ``[[A^-1, 0], [-D^-1 B A^-1, D^-1]]``, from 1 x 1 blocks (whose
-    inverse is 1) up, log2(C) rounds of two matmuls over all the
-    diagonal blocks at once.  No power of ``a`` is ever formed, so what
-    is computed is no larger than the inverse's own entries."""
-    import jax
+    a power of two, in float32 throughout.  The diagonal blocks of
+    ``_SOLVED`` rows by forward substitution, row by row on the vector
+    unit (``x_i = e_i - sum_{j<i} a_ij x_j``: fifteen steps over all
+    blocks at once; products of matrices that small leave the matrix
+    unit idle).  From there by halves: the inverse of a block ``[[A, 0],
+    [B, D]]`` is ``[[A^-1, 0], [-D^-1 B A^-1, D^-1]]``, a round over all
+    the diagonal blocks of ``2b`` at once as two products of whole ``C x
+    C`` matrices: with ``X`` the inverses of the blocks of ``b`` (zero
+    elsewhere) and ``L`` the lower-left quarters of the blocks of ``2b``
+    (zero elsewhere), ``X - (X L) X`` holds the inverses of the blocks
+    of ``2b``, and the terms the zeros add are exactly zero; the last
+    round's one block is sliced out instead.  No power of ``a`` is ever
+    formed, so what is computed is no larger than the inverse's own
+    entries."""
     import jax.numpy as jnp
 
     c = a.shape[-1]
     if c & (c - 1):
         raise ValueError(f"a chunk of {c} rows is not a power of two")
-    lead = a.shape[:-2]
-    inv = jnp.ones(lead + (c, 1, 1), a.dtype)
-    b = 1
-    while b < c:
-        nb = c // (2 * b)
-        # the diagonal blocks of 2b, and of each its lower-left quarter
-        diag = jnp.moveaxis(jnp.diagonal(
-            a.reshape(lead + (nb, 2 * b, nb, 2 * b)), axis1=-4, axis2=-2),
-            -1, -3)
-        pair = inv.reshape(lead + (nb, 2, b, b))
-        first, second = pair[..., 0, :, :], pair[..., 1, :, :]
-        low = -_exact(_exact(second, diag[..., b:, :b]), first)
+    lead, b = a.shape[:-2], min(c, _SOLVED)
+    own = jnp.eye(c // b, dtype=bool)[:, None, :, None]     # block I is J
+    blocks = jnp.sum(jnp.where(own, a.reshape(
+        lead + (c // b, b, c // b, b)), 0.0), axis=-2)      # [.., C/b, b, b]
+    unit = jnp.broadcast_to(jnp.eye(b, dtype=a.dtype), blocks.shape)
+    solved = unit[..., :1, :]                               # rows so far
+    for i in range(1, b):
+        solved = jnp.concatenate([solved, unit[..., i:i + 1, :] - jnp.sum(
+            blocks[..., i, :i, None] * solved, axis=-2, keepdims=True)],
+            axis=-2)
+    inv = jnp.where(own, solved[..., None, :], 0.0).reshape(a.shape)
+    row = jnp.arange(c, dtype=jnp.int32)[:, None]
+    col = jnp.arange(c, dtype=jnp.int32)[None, :]
+    while 2 * b < c:
+        low = jnp.where((row // (2 * b) == col // (2 * b))
+                        & (row % (2 * b) >= b) & (col % (2 * b) < b), a, 0.0)
+        inv = inv - _exact(_exact(inv, low), inv)
+        b *= 2
+    if b < c:
+        first, second = inv[..., :b, :b], inv[..., b:, b:]
+        low = -_exact(_exact(second, a[..., b:, :b]), first)
         inv = jnp.concatenate([
             jnp.concatenate([first, jnp.zeros_like(first)], axis=-1),
             jnp.concatenate([low, second], axis=-1)], axis=-2)
-        b *= 2
-    return inv[..., 0, :, :]
+    return inv

@@ -244,8 +244,7 @@ class HybridMoELM:
 
         token = functools.partial(self._kda_token, lw,
                                   interpret=attend.interpret)
-        shape, dtype = self.recurrent_state["s"]
-        if not kda.kda_rule(*shape, dtype):
+        if not self.prefill_chunks_per_call(PREFILL_CHUNK):
             return attend.recur(l, token, rows, cache)
         if not attend.prompt:
             attend.tally("kda_kernel_rows",
@@ -254,6 +253,12 @@ class HybridMoELM:
             l, token, rows, cache, chunk=PREFILL_CHUNK,
             chunk_fn=functools.partial(self._kda_chunk, lw,
                                        interpret=attend.interpret))
+
+    def prefill_chunks_per_call(self, rows):
+        """One ``PREFILL_CHUNK`` a call of ``_kda_chunk`` whatever the
+        bucket, where the kernel takes the state; else no chunk form."""
+        shape, dtype = self.recurrent_state["s"]
+        return int(bool(kda.kda_rule(*shape, dtype)))
 
     def _kda_vectors(self, lw, conv, gate, beta):
         """What the rule takes of ``N`` tokens, from their convolved

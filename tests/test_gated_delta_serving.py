@@ -133,16 +133,22 @@ def _state_after_prefill(model, weights, prompt, page_size):
         return {n: np.asarray(eng._scope.get_var(n)) for n in names}
 
 
-def test_padding_rows_leave_the_state_alone():
+@pytest.mark.parametrize("wide", [64, 256],
+                         ids=["a_chunks_rest", "whole_chunks_of_a_group"])
+def test_padding_rows_leave_the_state_alone(wide):
     """The same 70-token prompt (a chunk and six tokens of the next)
-    prefilled in a bucket of 72 and in one of 128: what the slot's rows
-    hold is the state after token 70, however many padding rows the
-    second chunk carried."""
+    prefilled in a bucket of 72 and in one of 128 or of 256: what the
+    slot's rows hold is the state after token 70, however many padding
+    rows the second chunk carried, and whether or not the call's group
+    goes on for two more chunks that are padding alone (a bucket of 256:
+    ONE call of four chunks)."""
     model = make_model(("recurrent", "attention"))
+    assert model.prefill_chunks_per_call(72) == 2 \
+        and model.prefill_chunks_per_call(wide) == wide // 64
     weights = model.init_weights(jax.random.PRNGKey(7))
     prompt = np.random.RandomState(8).randint(0, VOCAB, 70).tolist()
     a = _state_after_prefill(model, weights, prompt, 8)
-    b = _state_after_prefill(model, weights, prompt, 64)
+    b = _state_after_prefill(model, weights, prompt, wide)
     assert set(a) == set(b) and len(a) == 2
     for name in a:
         assert np.abs(a[name][0]).max() > 0      # slot 0 was written
@@ -192,11 +198,11 @@ def _by_tokens(model, lw, rows, n, state):
     return outs, state
 
 
-def _by_chunks(model, lw, rows, n, state, pad_with=None):
-    """``n`` real tokens in chunks of ``CHUNK``; the rows past them are
-    ``pad_with`` (seeded noise: a padding row must weigh nothing
-    whatever it holds)."""
-    c = gdl.CHUNK
+def _by_chunks(model, lw, rows, n, state, pad_with=None, group=1):
+    """``n`` real tokens in calls of ``group`` chunks of ``CHUNK``; the
+    rows past them are ``pad_with`` (seeded noise: a padding row must
+    weigh nothing whatever it holds)."""
+    c = group * gdl.CHUNK
     total = -(-n // c) * c
     rng = np.random.RandomState(0 if pad_with is None else pad_with)
     padded = {k: jnp.concatenate([v[:n], jnp.asarray(
@@ -217,25 +223,34 @@ def _one_layer(seed):
     return model, model.init_weights(jax.random.PRNGKey(seed))["layers"][0]
 
 
-@pytest.mark.parametrize("n", [64, 128, 1, 63, 65, 150],
-                         ids=lambda n: f"{n}_tokens")
+# a call of ONE chunk, then of a group of four: a prompt shorter than a
+# chunk, exactly one group, a group and a part of the next, a last group
+# whose last live chunk is partial and whose other two are padding alone
+LENGTHS = [(n, 1) for n in (64, 128, 1, 63, 65, 150)] + [
+    (40, 4), (256, 4), (300, 4), (326, 4), (150, 2)]
+
+
+@pytest.mark.parametrize("n, group", LENGTHS, ids=[
+    f"{n}_tokens" + (f"_by_{g}" if g > 1 else "") for n, g in LENGTHS])
 @pytest.mark.parametrize("initial", [None, 21], ids=["zero", "nonzero"])
-def test_the_chunk_form_is_the_token_form(n, initial):
+def test_the_chunk_form_is_the_token_form(n, group, initial):
     """From a zero and from a non-zero state (matrix AND convolution
-    tail), at lengths that are and are not multiples of 64, with noise in
-    the padding rows: outputs and the final state agree to 2e-5 (float32
-    on both sides; the state's entries are O(1))."""
+    tail), at lengths that are and are not multiples of 64, one chunk a
+    call and a group of chunks a call, with noise in the padding rows:
+    outputs and the final state agree to 2e-5 (float32 on both sides;
+    the state's entries are O(1))."""
     model, lw = _one_layer(9)
     rows = _rows(model, lw, n, seed=10)
     want_o, want_s = _by_tokens(model, lw, rows, n, _state0(model, initial))
     got_o, got_s = _by_chunks(model, lw, rows, n, _state0(model, initial),
-                              pad_with=11)
+                              pad_with=11, group=group)
     np.testing.assert_allclose(got_o, want_o, atol=2e-5)
     for name in want_s:
         np.testing.assert_allclose(got_s[name], want_s[name], atol=2e-5)
 
 
-def test_a_negative_eigenvalue_survives_the_chunk_form():
+@pytest.mark.parametrize("group", [1, 2], ids=["a_chunk", "a_group_of_2"])
+def test_a_negative_eigenvalue_survives_the_chunk_form(group):
     """Every token the SAME key, written at ``beta`` close to 2 and
     hardly decayed: ``I - beta k k^T`` has the eigenvalue ``1 - beta``
     near -1 along k, so what the state holds along k flips its sign
@@ -251,7 +266,8 @@ def test_a_negative_eigenvalue_survives_the_chunk_form():
     beta = float(model._beta(rows["b"])[0, 0])
     assert 1.9 < beta < 2.0
     want_o, want_s = _by_tokens(model, lw, rows, n, _state0(model, 14))
-    got_o, got_s = _by_chunks(model, lw, rows, n, _state0(model, 14))
+    got_o, got_s = _by_chunks(model, lw, rows, n, _state0(model, 14),
+                              group=group)
     assert np.isfinite(np.asarray(got_o)).all()
     # what lies along k neither fades nor is forgotten: it keeps the
     # rounding of all 128 tokens (6.5e-5 read; the entries are O(1))
@@ -428,22 +444,108 @@ def test_the_check_would_see_a_departure(departure):
         assert served_vs_reference(eng, model, weights, prompts) > 1e-2
 
 
+def test_the_group_is_read_from_the_calls_shapes():
+    """``prefill_chunks_per_call``: the bucket's chunks, capped by what
+    ``GROUP_BYTES`` of a group's temporaries allow.  Olmo-Hybrid's
+    widths (30 heads, 96 on 192: 13.1 MB a chunk) take four chunks a
+    call at every bucket from 256 rows up; the toy widths the whole
+    bucket; a model without a chunk form reads 0 on the gauge."""
+    from paddle_tpu.serving.decode import TransformerLM
+
+    toy = make_model(PERIOD)
+    assert [toy.prefill_chunks_per_call(r) for r in (8, 64, 72, 128, 256)] \
+        == [1, 1, 2, 2, 4]
+    wide = make_model(PERIOD, lin_heads=30, lin_key_dim=96,
+                      lin_value_dim=192)
+    assert [wide.prefill_chunks_per_call(r)
+            for r in (64, 128, 256, 4096, 5632)] == [1, 2, 4, 4, 4]
+    assert gdl.GROUP_BYTES // (4 * 30 * (4 * 64 * 64 + 64 * (
+        5 * 96 + 4 * 192) + 96 * 192)) == 4
+    plain = TransformerLM(vocab_size=VOCAB, d_model=16, num_layers=1,
+                          num_heads=2, max_seq_len=64)
+    DecodeEngine(plain, plain.init_weights(jax.random.PRNGKey(0)),
+                 DecodeConfig(slots=2, max_seq_len=64, page_size=8))
+    assert stat_get("decode_prefill_chunks_per_call") == 0
+
+
+def _loops_of_main(text):
+    """[(header, body lines)] of the ``stablehlo.while`` operations that
+    the program's ``main`` holds itself (a header prints its carry's
+    types; its regions end at the next ``}`` of its own indentation)."""
+    lines, loops, i = text.splitlines(), [], 0
+    while i < len(lines):
+        if lines[i].startswith("    %") and "stablehlo.while(" in lines[i]:
+            end = lines.index("    }", i)
+            loops.append((lines[i], lines[i + 1:end]))
+            i = end
+        i += 1
+    return loops
+
+
+def test_a_recurrent_layer_of_the_prompt_is_one_loop_that_holds_the_group():
+    """What the benchmark's readers find a recurrent layer's chunk form
+    by (``benchmark/layer_metrics/gdn_prefill_*.json``: the ``while``
+    whose carry holds ``f32[1, heads, d_k, d_v]``, which spans its
+    body): the 256-row prefill of a model with two recurrent layers
+    holds exactly one such loop a layer, the state's pass through a
+    call's four chunks is no loop of its own, and the products of the
+    whole group that read no state (``Q K^T`` over ``K K^T`` of ``[4,
+    H, 128, 64]``, the rounds of the triangular inverse, ``T [beta V |
+    beta e^G K]``) lie inside it and nowhere else."""
+    model = make_model(("recurrent", "attention", "recurrent"))
+    group = model.prefill_chunks_per_call(256)
+    assert group == 4
+    with engine(model, model.init_weights(jax.random.PRNGKey(1))) as eng:
+        assert stat_get("decode_prefill_chunks_per_call") == group
+        text = eng.lower_prefill(256).as_text()
+    main = text[:text.index("func.func private")]
+    state = "tensor<1x3x6x12xf32>"
+    loops = [(head, body) for head, body in _loops_of_main(main)
+             if state in head]
+    assert len(loops) == 2
+    inside = 0
+    for head, body in loops:
+        assert not [ln for ln in body if "stablehlo.while" in ln]
+        products = [ln for ln in body if "stablehlo.dot_general" in ln]
+        assert all("precision = [HIGHEST, HIGHEST]" in ln
+                   for ln in products)
+        shapes = [ln.rsplit("-> ", 1)[1] for ln in products]
+        assert shapes.count(f"tensor<{group}x3x128x64xf32>") == 1
+        assert shapes.count(f"tensor<{group}x3x64x18xf32>") == 1
+        # the inverse from blocks of 16: one round of whole matrices and
+        # the last round's one block, two products each; the state's
+        # pass, two a chunk, on operands of ONE chunk; the outputs' two
+        assert shapes.count(f"tensor<{group}x3x64x64xf32>") == 2
+        assert shapes.count(f"tensor<{group}x3x32x32xf32>") == 2
+        assert shapes.count("tensor<3x64x12xf32>") \
+            == shapes.count("tensor<3x6x12xf32>") == group
+        assert len(products) == 1 + 4 + 1 + 2 * group + 2
+        inside += len(products)
+    # every product at ``highest`` in the program is the chunk form's
+    assert main.count("precision = [HIGHEST, HIGHEST]") == inside
+    outside = set(main.splitlines()) - {
+        ln for _, body in loops for ln in body}
+    assert not [ln for ln in outside if "x64x64xf32>" in ln]
+
+
 # sha256 of the lowered text of ``make_model()``'s joint step and
-# 128-row whole-prompt prefill behind ``engine()``, as PR 46's tree
-# lowers them
+# 128-row whole-prompt prefill behind ``engine()``: the step as PR 46's
+# tree lowers it, the prefill since its chunk form takes a group of
+# chunks a call (PR 53)
 PROGRAMS_AS_LOWERED = {
     "step": "93ad8ee9930b9c80b7f7980246e89a35d1b3df4b9a69cbc6688e0aa3435f8573",
     "prefill":
-        "8e936eecf7c93a1c54e53d0c07d96291be128d8c61b4db456fdef05d22d6c876"}
+        "b758e87029ce950ed99f262402691584891dc1706d5a42db29eb81b0797789f0"}
 
 
 @pytest.mark.parametrize("program", ["step", "prefill"])
 def test_the_programs_are_still_the_ones_lowered_before_the_kernel(program):
     """This model's one-token update takes no ``live``: the engine keeps
     masking its dead rows (``where`` over every slab) and hands its
-    update nothing new, so the joint step and the whole-prompt prefill
-    lower to the text they had before ``HybridMoELM``'s update moved
-    into a kernel (PR 47).  A change MEANT to move these programs
+    update nothing new, so the joint step lowers to the text it had
+    before ``HybridMoELM``'s update moved into a kernel (PR 47), and the
+    whole-prompt prefill to the text PR 53 gave it (its chunk form a
+    group of chunks a call).  A change MEANT to move these programs
     replaces the digests; one that was not has found out here."""
     import hashlib
 

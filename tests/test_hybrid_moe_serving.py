@@ -555,6 +555,39 @@ def test_a_step_that_keeps_the_dense_form_is_the_program_it_was(which):
     assert hashlib.sha256(text.encode()).hexdigest() == digest
 
 
+# sha256 of the 64-row whole-prompt prefill's lowered text at the two
+# widths (``make_model()``, 3 slots), taken on the commit before the
+# engine's ``recur`` learned how many of a rule's chunks a call covers
+# (PR 53), and what the gauge of that says of each model
+PREFILLS_AS_LOWERED = {
+    "toy": ("c434fdc64b206e6b06a262e37f7741e65d1b9e0e74b9f7c4ab155db008902a55",
+            0),
+    "kernel": (
+        "2479ab1e039ef93df77c52d6f60c5c62044e8d9d8fc1c5b02d9329543d86c4a6", 1),
+}
+
+
+@widths
+def test_a_call_that_covers_one_chunk_is_the_prefill_it_was(widths):
+    """``GatedDeltaLM`` hands ``attend.recur`` a group of its rule's
+    chunks a call (PR 53); this model's kernel call covers ONE chunk of
+    ``PREFILL_CHUNK`` tokens (at toy widths it hands no chunk form at
+    all), says so (gauge ``decode_prefill_chunks_per_call``), and its
+    prefill lowers to the text it had: the loop, its trip count and the
+    scan's two counters are untouched."""
+    import hashlib
+
+    sizes, cfg = WIDTHS[widths]
+    digest, per_call = PREFILLS_AS_LOWERED[widths]
+    model = make_model(**sizes)
+    assert model.prefill_chunks_per_call(64) == per_call \
+        == model.prefill_chunks_per_call(4096)
+    eng = engine(model, model.init_weights(jax.random.PRNGKey(1)), **cfg)
+    assert stat_get("decode_prefill_chunks_per_call") == per_call
+    text = eng.lower_prefill(64).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
 def _function(text, name):
     """The lines of ``func.func ... @name(`` in lowered text, its
     signature first."""

@@ -864,8 +864,14 @@ def test_olmo_hybrid_width_programs_compile(one_chip, program):
                   if re.search(r"\bwhile\(", line)]
         chunked = [line for line in whiles if loop.search(line.strip())]
         # one loop a recurrent layer carries the slot's state (the
-        # attention is a kernel now, no loop over blocks)
+        # attention is a kernel now, no loop over blocks), four chunks
+        # of the rule a call, and nothing inside it is a loop that
+        # carries such a state of its own: the readers' time counts once
         assert len(chunked) == 1, whiles
+        assert eng.model.prefill_chunks_per_call(4096) == 4
+        assert len([line for line in text.splitlines()
+                    if re.search(r"\bwhile\(", line)
+                    and loop.search(line.strip())]) == 1
     # what the program needs beside its operands stays inside the chip:
     # 4.9 GB of weights, 5.6 GB of pages and 0.6 GB of slabs are resident
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
