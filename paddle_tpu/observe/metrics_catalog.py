@@ -354,6 +354,11 @@ RULES = (
     Rule("decode_window_bytes", "gauge", "serving",
          "Device bytes of the window layers' ring pools, all slots: no "
          "term in max_seq_len; 0 for a model without window layers"),
+    Rule("decode_prefill_conv_rows", "gauge", "serving",
+         "Real prompt rows that went through the convolution layers' "
+         "prompt form (one call a layer for the whole bucket), a layer, "
+         "read back with a whole-prompt prefill's token: over "
+         "`decode_prefill_scan_steps` it is the rows a call took"),
     Rule("decode_latent_bytes", "gauge", "serving",
          "Device bytes of the pool of a model with latent attention: ONE "
          "row a position a layer for keys and values, at whole lane "

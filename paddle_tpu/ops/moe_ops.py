@@ -314,8 +314,10 @@ def moe_share_route(h, router_w, router_bias, *, top_k, held_ids,
     (``router_w [D, E]``, float32 at ``highest``: a score's rounding
     decides the top-k), for a chip that holds ``held_ids`` ([n_held]
     expert ids).  The top-k is taken by score + ``router_bias`` [E] (the
-    load-balance correction, zero here); the weights are the plain
-    scores, normalised over the chosen k.  ``live`` (rows' shape, bool)
+    load-balance correction: the model's own buffer, zero in four of
+    the served configurations and drawn from the seed in one); the
+    weights are the plain scores, WITHOUT the bias, normalised over the
+    chosen k.  ``live`` (rows' shape, bool)
     takes dead and padding rows out: they choose and weigh as any row,
     and give this chip nothing to compute.
 
@@ -371,21 +373,26 @@ def ridge_rows():
     return row["bf16_tflops"] * 1e3 / row["hbm_gbps"]
 
 
-def grouped_rule(rows, n_held, expert_dim, d_model):
+def grouped_rule(rows, n_held, expert_dim, d_model, top_k=None,
+                 num_experts=None):
     """Whether a call of ``rows`` rows over ``n_held`` experts of
     ``expert_dim`` hidden units takes the grouped form: a function of
-    the call's static shape alone.  Under ``GROUPED_FROM_RIDGES`` ridges
+    the call's static shape and, where given, the model's published
+    routing (``top_k`` of ``num_experts``: they size the sorted buffer
+    where every expert is held, ``pallas_moe_grouped.pairs_a_row``).
+    Under ``GROUPED_FROM_RIDGES`` ridges
     the dense form is bound by the weight read, or nearly, and the
     grouped form's own passes (the pairs to their slots, the rows
     gathered, the result's blocks read and written) buy nothing.  The
-    sorted buffer's tiles at their worst (two pairs a row, every
+    sorted buffer's tiles at their worst (its pairs a row, every
     expert's run ending a tile early) are computed whole: they must be
     under half the dense form's rows x experts.  And the kernels' blocks
     are whole lanes: a width no 128 divides keeps the dense form."""
     from .pallas_moe_grouped import sorted_rows
 
     return (rows >= GROUPED_FROM_RIDGES * ridge_rows()
-            and 2 * sorted_rows(rows, n_held) <= rows * n_held
+            and 2 * sorted_rows(rows, n_held, top_k, num_experts)
+            <= rows * n_held
             and expert_dim % 128 == 0 and d_model % 128 == 0)
 
 
@@ -434,8 +441,9 @@ def moe_share_ffn(h, local, w_gate, w_up, w_down, *, tally=None,
     ``[n_held*F, D]``; matmuls take the weights' dtype in and float32
     out.  Dropless under any imbalance.  Many rows (``grouped_rule``):
     the pairs with a weight, sorted by expert, as a grouped matmul.
-    Few rows of a model whose ``top_k`` of ``num_experts`` (static; the
-    model's own) leave held experts un-chosen (``hit_rule``; never
+    ``top_k`` of ``num_experts`` (static; the model's own) size that
+    form's buffer where every expert is held.  Few rows of a model
+    whose routing leaves held experts un-chosen (``hit_rule``; never
     where the two are not given): every row meets every held expert
     some row chose, and the others' weights are not read.  Else every
     row meets every held expert, the weight decides.  One sum to
@@ -451,11 +459,12 @@ def moe_share_ffn(h, local, w_gate, w_up, w_down, *, tally=None,
     rows = math.prod(h.shape[:-1])
     shape = (rows, n_held, w_down.shape[0] // n_held, h.shape[-1])
     with jax.named_scope(EXPERTS_SCOPE):
-        if grouped_rule(*shape):
+        if grouped_rule(*shape, top_k, num_experts):
             from .pallas_moe_grouped import grouped_share_ffn
 
             out, pairs, passes = grouped_share_ffn(
-                h, local, w_gate, w_up, w_down, interpret=interpret)
+                h, local, w_gate, w_up, w_down, interpret=interpret,
+                top_k=top_k, num_experts=num_experts)
             if tally is not None:
                 for name, n in zip(GROUPED_TALLIES, (
                         pairs, rows * n_held, jnp.maximum(passes - 1, 0))):

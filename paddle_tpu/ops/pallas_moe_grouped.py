@@ -20,9 +20,13 @@ block of ``w_down`` ``[n_held*F, D]``; no operand is transposed or
 copied.
 
 Dropless under any imbalance with a bounded buffer: the sorted buffer
-holds ``PAIRS_A_ROW`` pairs a row, and a call whose rows chose more runs
-the same two kernels again over the next pairs (a ``while_loop`` over
-passes; one pass under any routing near uniform).
+holds ``pairs_a_row`` pairs a row, a function of the call (what a row
+CAN choose where the chip holds every expert of the layer: ``top_k``,
+exactly; ``PAIRS_A_ROW`` for a share of them), and a call whose rows
+chose more runs the same two kernels again over the next pairs (a
+``while_loop`` over passes, each of which reads the experts' weights
+again; one pass under any routing near uniform, and always one where
+every expert is held).
 """
 from __future__ import annotations
 
@@ -33,14 +37,16 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["grouped_share_ffn", "default_tiles", "sorted_rows",
-           "GATE_UP_KERNEL_NAME", "DOWN_KERNEL_NAME"]
+           "pairs_a_row", "GATE_UP_KERNEL_NAME", "DOWN_KERNEL_NAME"]
 
 GATE_UP_KERNEL_NAME = "moe_grouped_gate_up"
 DOWN_KERNEL_NAME = "moe_grouped_down"
 _LANES = 128
 _VMEM_LIMIT = 96 * 1024 * 1024
-# pairs a row the sorted buffer holds: the cells' rows choose 0.5-1 of
-# the held experts each, four times that at a skewed router's worst seen
+# pairs a row the sorted buffer holds for a SHARE of a layer's experts:
+# such cells' rows choose 0.25-1 of the held experts each, and at a
+# skewed router's worst seen four times that.  A chip that holds every
+# expert takes the exact number instead (``pairs_a_row``)
 PAIRS_A_ROW = 2
 
 
@@ -54,22 +60,47 @@ def _col_tile(width):
     return next((t for t in (512, 256, _LANES) if width % t == 0), width)
 
 
-def default_tiles(rows, n_held):
+def _all_held(n_held, top_k, num_experts):
+    return top_k is not None and num_experts is not None \
+        and n_held == num_experts
+
+
+def pairs_a_row(top_k, num_experts, n_held):
+    """Pairs a row the sorted buffer holds for a call over ``n_held``
+    held experts of a layer that routes ``top_k`` of ``num_experts``
+    (None: not said).  Where the chip holds EVERY expert a live row
+    makes exactly ``top_k`` pairs: no row can make more, none makes
+    fewer, so the buffer holds them all and one pass does.  A share's
+    rows make ``top_k * n_held / num_experts`` on average and up to
+    ``top_k`` each: ``PAIRS_A_ROW``, with further passes for the
+    rest."""
+    if _all_held(n_held, top_k, num_experts):
+        return min(int(top_k), int(n_held))
+    return PAIRS_A_ROW
+
+
+def default_tiles(rows, n_held, top_k=None, num_experts=None):
     """Rows a tile for a call of ``rows`` rows over ``n_held`` experts:
-    128 (the MXU's side), or 256 where even rows / n_held pairs an
-    expert would fill more than one such tile (a tile is computed whole
-    however few of its rows hold a pair: on the chip 512 lost to 256 at
-    4,096 rows over 8 experts, and 256 to 128 at 2,048 over 16)."""
+    128 (the MXU's side), or 256 where the pairs an expert gets would
+    fill more than one such tile (a tile is computed whole however few
+    of its rows hold a pair: on the chip 512 lost to 256 at 4,096 rows
+    over 8 experts, and 256 to 128 at 2,048 over 16).  For a share of a
+    layer's experts that is judged by even ``rows / n_held`` pairs an
+    expert; where every expert is held, by what an even routing gives
+    one: ``rows * top_k / n_held``."""
+    if _all_held(n_held, top_k, num_experts):
+        return 256 if rows * top_k > 128 * n_held else 128
     return 256 if rows > 128 * n_held else 128
 
 
-def sorted_rows(rows, n_held):
-    """Rows of the sorted buffer of such a call: ``PAIRS_A_ROW`` pairs a
+def sorted_rows(rows, n_held, top_k=None, num_experts=None):
+    """Rows of the sorted buffer of such a call: ``pairs_a_row`` pairs a
     row in whole tiles, and a tile more an expert, since every expert's
     run may end a tile early.  A pass computes as many of its tiles as
     hold a pair, whole."""
-    tm = default_tiles(rows, n_held)
-    return _round_up(PAIRS_A_ROW * rows, tm) + n_held * tm
+    tm = default_tiles(rows, n_held, top_k, num_experts)
+    return _round_up(pairs_a_row(top_k, num_experts, n_held) * rows, tm) \
+        + n_held * tm
 
 
 def _down_cols(rows, f, d):
@@ -218,13 +249,17 @@ def _down_call(act, acc, tile_expert, n_active, slot_row, tile_live,
     )(tile_expert, n_active, slot_row, tile_live, act, w_down, acc)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def grouped_share_ffn(h, local, w_gate, w_up, w_down, *, interpret=False):
+@functools.partial(jax.jit, static_argnames=("interpret", "top_k",
+                                             "num_experts"))
+def grouped_share_ffn(h, local, w_gate, w_up, w_down, *, interpret=False,
+                      top_k=None, num_experts=None):
     """``moe_ops.moe_share_ffn``'s result for rows ``h [..., D]`` and
     weights ``local [..., n_held]``, computed over the pairs with a
     non-zero weight only -> ``(out [..., D] float32, pairs, passes)``:
     ``pairs`` (int32) is how many pairs there were, ``passes`` how many
-    times the sorted buffer (``sorted_rows``) was filled and walked.
+    times the sorted buffer (``sorted_rows``) was filled and walked;
+    ``top_k`` / ``num_experts`` (static, the model's own routing) size
+    the buffer and the tiles where every expert is held.
     Jitted: a model's layers share one traced and lowered call (six
     layers' calls of a prefill program trace and lower in 0.1 s, not
     0.5: host time of every set-up, timed on the CPU)."""
@@ -232,8 +267,8 @@ def grouped_share_ffn(h, local, w_gate, w_up, w_down, *, interpret=False):
     d = h.shape[-1]
     x = h.reshape(-1, d).astype(w_gate.dtype)
     rows = x.shape[0]
-    tm = default_tiles(rows, n_held)
-    m_rows = sorted_rows(rows, n_held)
+    tm = default_tiles(rows, n_held, top_k, num_experts)
+    m_rows = sorted_rows(rows, n_held, top_k, num_experts)
     cap = m_rows - n_held * tm
     n_tiles = m_rows // tm
 

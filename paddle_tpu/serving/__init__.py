@@ -50,6 +50,7 @@ from .disagg import (  # noqa: F401
     DisaggRequest,
     DisaggServer,
 )
+from .conv_moe_lm import ConvMoELM  # noqa: F401
 from .gated_delta_lm import GatedDeltaLM  # noqa: F401
 from .hybrid_moe_lm import HybridMoELM  # noqa: F401
 from .kv_cache import (  # noqa: F401
@@ -73,7 +74,8 @@ from .server import (  # noqa: F401
 
 __all__ = [
     "Autoscaler", "Batcher", "BucketSpec", "CacheConfig",
-    "CacheExhaustedError", "DeadlineExceededError", "DecodeConfig",
+    "CacheExhaustedError", "ConvMoELM", "DeadlineExceededError",
+    "DecodeConfig",
     "DecodeEngine", "DecodeRequest", "DecodeServer", "DisaggConfig",
     "DisaggRequest", "DisaggServer", "GatedDeltaLM", "HybridMoELM",
     "InferenceRequest",

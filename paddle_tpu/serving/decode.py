@@ -803,7 +803,10 @@ class DecodeEngine:
       tail in the ring.  Nothing is uploaded for it.
     * ``"recurrent"``: ``recurrent_state``, ``{name: (shape, dtype)}``
       of ONE slot's state of ONE such layer, in slot-indexed slabs
-      (``kv_cache.RecurrentSpec``).
+      (``kv_cache.RecurrentSpec``).  The state may be a convolution's
+      last inputs alone, and the ``chunk`` of its prompt form the whole
+      bucket (``serving/conv_moe_lm.py``: a layer that reads no state
+      but its own inputs runs a prompt in one call).
 
     ``tallies`` are the names of the counters ``forward`` adds to (a
     model without ``layer_kinds`` that declares some gets ``attend``

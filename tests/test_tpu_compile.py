@@ -1230,3 +1230,83 @@ def test_ouro_width_programs_keep_the_pools_in_place_through_the_loops(
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= 2 * 8 * 337 * 16 * 2048 * 2
     assert ma.temp_size_in_bytes < 256 << 20
+
+
+# -- LFM2-8B-A1B's rows: convolution tails, heads of 64, every expert held --
+
+@functools.lru_cache(maxsize=1)
+def _conv_moe_engine():
+    """LFM2-8B-A1B's widths behind the engine at the cell's serving
+    sizes (128 slots of 2,560 positions, 20,609 bf16 pages, the whole
+    vocabulary under a tied head), three layers: a convolution layer
+    with the dense feed-forward, an attention layer with it, and a
+    convolution layer with all 32 experts.  Built once for both
+    programs: a gigabyte of zeros and the pools."""
+    from paddle_tpu.serving import ConvMoELM, DecodeConfig, DecodeEngine
+
+    model = ConvMoELM(
+        vocab_size=65536, d_model=2048,
+        layer_kinds=("recurrent", "attention", "recurrent"), num_heads=32,
+        num_kv_heads=8, head_dim=64, conv_kernel=3, ffn_dim=7168,
+        dense_layers=2, num_experts=32, top_k=4, held_experts=range(32),
+        expert_dim=1792)
+    weights = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
+    return DecodeEngine(model, weights, DecodeConfig(
+        slots=128, max_seq_len=2560, num_pages=20609, use_pallas="always",
+        cache_dtype="bfloat16"))
+
+
+@pytest.mark.parametrize("program", ["step", "prefill_2048"])
+def test_lfm2_width_programs_compile(one_chip, program):
+    """The 128-row joint step (the paged kernel at 8 K/V heads of 64
+    lanes, the experts in the dense form: 128 rows hit every expert) and
+    the 2,048-row whole-prompt prefill (the grouped experts' two kernels
+    at four pairs a row, the attention in the blocked form: the flash
+    kernel declines V heads of 64 lanes; the head over one row): each
+    needs under 1.5 GB beside its operands, updates pools and tails in
+    place, and copies or transposes nothing an expert matrix's size."""
+    from paddle_tpu.ops import moe_ops, pallas_moe_grouped as grouped
+    from paddle_tpu.ops import pallas_prompt_attention as ppa
+
+    eng = _conv_moe_engine()
+    m = eng.model
+    assert eng._cache.config.pool_shape() == (1, 20609, 16, 512)
+    assert eng._cache.state_bytes() == 2 * 128 * 2 * 2048 * 4
+    assert ppa.flash_rule(2048, 32, 8, 64, 64, None) is None
+    shape = (len(m.held_experts), m.expert_dim, m.d_model, m.top_k,
+             m.num_experts)
+    assert not moe_ops.grouped_rule(128, *shape)
+    assert not moe_ops.hit_rule(128, *shape)
+    assert moe_ops.grouped_rule(2048, *shape)
+    assert grouped.default_tiles(2048, 32, 4, 32) == 256
+    assert grouped.sorted_rows(2048, 32, 4, 32) == 4 * 2048 + 32 * 256
+    if program == "step":
+        compiled = eng.lower_step(sharding=one_chip).compile()
+    else:
+        compiled = eng.lower_prefill(2048, sharding=one_chip).compile()
+    text = compiled.as_text()
+    if program == "step":
+        assert text.count("tpu_custom_call") == 1       # the paged kernel
+        assert "paged_attention" in text
+        assert not re.search(r"f32\[2048,65536\]", text)
+    else:
+        assert text.count("tpu_custom_call") == 2       # the grouped two
+        assert grouped.GATE_UP_KERNEL_NAME in text
+        assert grouped.DOWN_KERNEL_NAME in text
+        # the head over the read row alone, no plane of every row
+        assert re.search(r"f32\[1,65536\]", text)
+        assert not re.search(r"f32\[2048,65536\]", text)
+    wide, tall = (2048, 32 * 1792), (32 * 1792, 2048)
+    for line in text.splitlines():
+        mt = _INSTR.match(line)
+        if mt and mt["op"] in ("copy", "transpose", "copy-start"):
+            dims = {tuple(int(d) for d in a.split(",") if d)
+                    for a in _ARRAY.findall(mt["type"])}
+            assert not dims & {wide, tall, (65536, 2048), (2048, 65536)}, \
+                line[:160]
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < 1536 << 20
+    pools_and_tails = 2 * 20609 * 16 * 512 * 2 + 2 * 128 * 2 * 2048 * 4
+    assert ma.alias_size_in_bytes >= pools_and_tails
