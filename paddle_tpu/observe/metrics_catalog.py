@@ -171,6 +171,14 @@ RULES = (
          "holds (two a row) fills and walks it again, reading the held "
          "experts' weights once more each time; nothing is dropped and "
          "no other form takes over.  0 under any routing near uniform"),
+    Rule("moe_grouped_layout_updates", "gauge", "expert_parallel",
+         "Updates the ONE scatter of the grouped form's pair layout "
+         "walks a call (`ops/pallas_moe_grouped.py` `layout_pass`; the "
+         "chip walks them one by one, dropped ones too): rows x "
+         "min(top_k, n_held), a row's candidates, where the call is "
+         "told `top_k`, rows x held experts where not.  Set when a "
+         "call is traced, so it reads the newest traced shape; until "
+         "PR 55 two scatters walked rows x held experts each"),
     Rule("moe_hit_form_calls", "gauge", "expert_parallel",
          "Calls of `moe_share_ffn` in a joint decode step that took the "
          "hit form (`ops/pallas_moe_hit.py`: one kernel over the held "

@@ -27,17 +27,35 @@ chose more runs the same two kernels again over the next pairs (a
 ``while_loop`` over passes, each of which reads the experts' weights
 again; one pass under any routing near uniform, and always one where
 every expert is held).
+
+The LAYOUT (``layout_candidates``, ``layout_pass``: from ``local`` to
+the five arrays the kernels are fed) costs what the pairs a call CAN
+make cost, not rows x held experts.  The chip walks a scatter's updates
+one after another, the ones sent past the buffer's end too: 4.6 ns an
+update of one word, 3.2-3.5 ns one of two words, at every shape
+(``tools/sweep_moe_layout.py`` on the v5e, PERF.md PR 55; until then two
+scatters of ``rows * n_held`` updates each were 0.61 of the layout's
+0.64 ms at 2,048 rows over 32 experts, of which 5,900 landed).  So a
+row's candidates are taken first, the ``min(top_k, n_held)`` entries at
+most that can hold a weight (a masked sum over the held experts: no
+gather, no ``lax.top_k``: those add 0.25 ms there), and ONE
+scatter places row and weight together: ``rows * min(top_k, n_held)``
+updates of two words (gauge ``moe_grouped_layout_updates``, set when a
+call is traced).  The running count stays ONE ``cumsum`` over all
+entries: 1 us there, a scan along the rows an expert at a time 12.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["grouped_share_ffn", "default_tiles", "sorted_rows",
-           "pairs_a_row", "GATE_UP_KERNEL_NAME", "DOWN_KERNEL_NAME"]
+           "pairs_a_row", "layout_candidates", "layout_pass", "pass_tiles",
+           "GATE_UP_KERNEL_NAME", "DOWN_KERNEL_NAME"]
 
 GATE_UP_KERNEL_NAME = "moe_grouped_gate_up"
 DOWN_KERNEL_NAME = "moe_grouped_down"
@@ -249,6 +267,110 @@ def _down_call(act, acc, tile_expert, n_active, slot_row, tile_live,
     )(tile_expert, n_active, slot_row, tile_live, act, w_down, acc)
 
 
+class Candidates(NamedTuple):
+    """``layout_candidates``' result, what ``layout_pass`` reads."""
+    order: jax.Array        # [n_held, rows] int32
+    nth: Optional[jax.Array]    # [n_held, rows] int32
+    bits: jax.Array         # [k, rows] int32
+    ends: jax.Array         # [n_held] int32
+
+
+def layout_candidates(local, top_k=None):
+    """What every pass's layout reads of ``local [rows, n_held]``, formed
+    once a call -> ``Candidates``.  The pairs expert by
+    expert, a row after the rows before it, are the non-zero entries of
+    ``local``'s transpose in the order they lie: ``order [n_held, rows]``
+    is an entry's running count there (pair p reads p + 1; 0: no pair)
+    and ``ends [n_held]`` the count at each expert's last row.  A row
+    has a weight for ``k = min(top_k, n_held)`` experts at most (every
+    entry where ``top_k`` is None): its CANDIDATES, the only entries a
+    pass's scatter is handed.  ``nth [n_held, rows]`` says which of its
+    row's candidates an entry is (1 .. k; 0: none, and None where every
+    entry is one), ``bits [k, rows]`` are the candidates' float32
+    weights as the words the scatter moves."""
+    from ..monitor import stat_set
+
+    rows, n_held = local.shape
+    k = n_held if top_k is None else min(int(top_k), n_held)
+    # at trace time: the updates one call of the layout walks
+    stat_set("moe_grouped_layout_updates", k * rows)
+    weight = local.T.astype(jnp.float32)
+    hit = weight != 0.0
+    count = jnp.cumsum(hit.reshape(-1), dtype=jnp.int32).reshape(
+        n_held, rows)
+    order, ends = jnp.where(hit, count, 0), count[:, -1]
+    bits = lax.bitcast_convert_type(weight, jnp.int32)
+    if k == n_held:
+        return Candidates(order, None, bits, ends)
+    nth = jnp.where(hit, jnp.cumsum(hit, axis=0, dtype=jnp.int32), 0)
+    return Candidates(order, nth, _candidates(bits, nth, k), ends)
+
+
+def _candidates(plane, nth, k):
+    """``plane [n_held, rows] -> [k, rows]``: each row's entries that are
+    its 1st .. k-th candidate, 0 where it has fewer (a sum with ONE term
+    that is not zero)."""
+    if nth is None:
+        return plane
+    which = lax.broadcasted_iota(jnp.int32, (k, 1, 1), 0) + 1
+    return jnp.sum(jnp.where(nth[None] == which, plane[None], 0), axis=1)
+
+
+def pass_tiles(ends, c, *, tm, m_rows):
+    """Pass ``c``'s share of every expert's run, from the experts'
+    ``ends`` -> ``(lo, hi, shift, tile_expert, tile_live, n_active)``:
+    the pass takes pairs ``lo <= p < hi``, pair ``p`` of expert ``e``
+    goes to slot ``shift[e] + p`` (each run from a tile's first slot),
+    and a tile belongs to ``tile_expert`` with ``tile_live`` live rows;
+    ``n_active [1]`` tiles hold a pair."""
+    n_held = ends.shape[0]
+    cap = m_rows - n_held * tm
+    starts, pairs = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]]), \
+        ends[-1]
+    tile_at = jnp.arange(m_rows // tm, dtype=jnp.int32) * tm
+    lo = c * cap
+    hi = jnp.minimum(lo + cap, pairs)
+    first = jnp.clip(starts, lo, hi)            # this pass's share of
+    size = jnp.clip(ends, lo, hi) - first       # every expert's run
+    padded = _round_up(size, tm)
+    p_end = jnp.cumsum(padded)
+    p_start = p_end - padded
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        p_end, tile_at, side="right"), n_held - 1).astype(jnp.int32)
+    tile_live = jnp.clip(
+        size[tile_expert] - (tile_at - p_start[tile_expert]), 0, tm)
+    return (lo, hi, p_start - first, tile_expert, tile_live,
+            (p_end[-1] // tm).reshape(1))
+
+
+def layout_pass(cand, c, *, tm, m_rows):
+    """Pass ``c``'s sorted buffer of ``m_rows`` slots in tiles of ``tm``
+    -> ``(slot_row, slot_weight [m_rows], tile_expert, tile_live
+    [m_rows // tm], n_active [1])``: pairs ``c * cap ...`` of
+    ``layout_candidates``' order, each expert's run from a tile's first
+    slot in ascending row order; a slot no pair takes repeats row 0 with
+    the weight zero."""
+    order, nth, bits, ends = cand
+    k, rows = bits.shape
+    lo, hi, shift, tile_expert, tile_live, n_active = pass_tiles(
+        ends, c, tm=tm, m_rows=m_rows)
+    # every candidate that is a pair of this pass to its slot, every
+    # other past the buffer's end, each to a place of its own (the
+    # scatter is told its indices are unique): ONE scatter of k x rows
+    # updates of two words, the row beside the weight's bits
+    place = _candidates(
+        jnp.where((order > lo) & (order <= hi), shift[:, None] + order, 0),
+        nth, k).reshape(-1)
+    slot = jnp.where(place > 0, place - 1,
+                     m_rows + jnp.arange(k * rows, dtype=jnp.int32))
+    words = jnp.stack(
+        [lax.broadcasted_iota(jnp.int32, (k, rows), 1), bits], axis=-1)
+    taken = jnp.zeros((m_rows, 2), jnp.int32).at[slot].set(
+        words.reshape(-1, 2), mode="drop", unique_indices=True)
+    return (taken[:, 0], lax.bitcast_convert_type(taken[:, 1], jnp.float32),
+            tile_expert, tile_live, n_active)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "top_k",
                                              "num_experts"))
 def grouped_share_ffn(h, local, w_gate, w_up, w_down, *, interpret=False,
@@ -259,7 +381,9 @@ def grouped_share_ffn(h, local, w_gate, w_up, w_down, *, interpret=False,
     ``pairs`` (int32) is how many pairs there were, ``passes`` how many
     times the sorted buffer (``sorted_rows``) was filled and walked;
     ``top_k`` / ``num_experts`` (static, the model's own routing) size
-    the buffer and the tiles where every expert is held.
+    the buffer and the tiles where every expert is held, and ``top_k``
+    the layout's work: a row of ``local`` has at most ``min(top_k,
+    n_held)`` non-zero entries, as a top-k router's rows have.
     Jitted: a model's layers share one traced and lowered call (six
     layers' calls of a prefill program trace and lower in 0.1 s, not
     0.5: host time of every set-up, timed on the CPU)."""
@@ -270,49 +394,15 @@ def grouped_share_ffn(h, local, w_gate, w_up, w_down, *, interpret=False,
     tm = default_tiles(rows, n_held, top_k, num_experts)
     m_rows = sorted_rows(rows, n_held, top_k, num_experts)
     cap = m_rows - n_held * tm
-    n_tiles = m_rows // tm
 
-    # the pairs expert by expert, a row after the rows before it, are
-    # the non-zero entries of local's transpose in the order they lie:
-    # pair p is the entry whose running count is p + 1
-    flat = local.reshape(rows, n_held).T.reshape(-1)
-    hit = flat != 0.0
-    count = jnp.cumsum(hit, dtype=jnp.int32)
-    ends = count[rows - 1::rows]
-    starts, pairs = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]]), \
-        ends[-1]
+    cand = layout_candidates(local.reshape(rows, n_held), top_k)
+    pairs = cand.ends[-1]
     passes = -(-pairs // cap)
-    entry = jnp.arange(n_held * rows, dtype=jnp.int32)
-    expert, row = entry // rows, entry % rows
-    tile_at = jnp.arange(n_tiles, dtype=jnp.int32) * tm
 
     def one_pass(carry):
         c, out = carry
-        lo = c * cap
-        hi = jnp.minimum(lo + cap, pairs)
-        first = jnp.clip(starts, lo, hi)            # this pass's share of
-        size = jnp.clip(ends, lo, hi) - first       # every expert's run
-        padded = _round_up(size, tm)
-        p_end = jnp.cumsum(padded)
-        p_start = p_end - padded
-        tile_expert = jnp.minimum(jnp.searchsorted(
-            p_end, tile_at, side="right"), n_held - 1).astype(jnp.int32)
-        tile_live = jnp.clip(
-            size[tile_expert] - (tile_at - p_start[tile_expert]), 0, tm)
-        # every pair of this pass to its slot, every other entry past
-        # the buffer's end, each to a place of its own (the scatter is
-        # told its indices are unique; a sort of these compiles for
-        # twenty seconds, a search over the counts runs for a
-        # millisecond); a slot no pair takes repeats row 0 with the
-        # weight zero
-        p = count - 1
-        slot = jnp.where(hit & (p >= lo) & (p < hi),
-                         (p_start - first)[expert] + p, m_rows + entry)
-        slot_row = jnp.zeros(m_rows, jnp.int32).at[slot].set(
-            row, mode="drop", unique_indices=True)
-        slot_weight = jnp.zeros(m_rows, jnp.float32).at[slot].set(
-            flat.astype(jnp.float32), mode="drop", unique_indices=True)
-        n_active = (p_end[-1] // tm).reshape(1)
+        slot_row, slot_weight, tile_expert, tile_live, n_active = \
+            layout_pass(cand, c, tm=tm, m_rows=m_rows)
         act = _gate_up_call(
             x[slot_row], slot_weight[:, None], tile_expert, n_active,
             w_gate, w_up, n_held=n_held, tm=tm, interpret=interpret)

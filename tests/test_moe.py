@@ -623,3 +623,172 @@ def test_the_rule_at_the_cells_shapes(cell):
     assert moe_ops.hit_rule(1, n_held, f, d, top_k, n_exp)
     assert not moe_ops.hit_rule(1, n_held, f - 64, d, top_k, n_exp)
     assert not moe_ops.hit_rule(1, n_held, f, d - 64, top_k, n_exp)
+
+
+# -- the grouped form's pair layout (ops/pallas_moe_grouped.py, PR 55) ------
+
+def _layout_until_pr55(local, c, *, tm, m_rows):
+    """The layout as ``grouped_share_ffn`` had it until PR 55, kept here
+    as the reference: ONE running count over the ``n_held x rows``
+    entries of ``local``'s transpose, and two scatters of as many
+    updates each, every entry without a pair sent past the buffer's
+    end."""
+    import jax.numpy as jnp
+
+    rows, n_held = local.shape
+    cap, n_tiles = m_rows - n_held * tm, m_rows // tm
+    flat = local.T.reshape(-1)
+    hit = flat != 0.0
+    count = jnp.cumsum(hit, dtype=jnp.int32)
+    ends = count[rows - 1::rows]
+    starts, pairs = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]]), \
+        ends[-1]
+    entry = jnp.arange(n_held * rows, dtype=jnp.int32)
+    expert, row = entry // rows, entry % rows
+    tile_at = jnp.arange(n_tiles, dtype=jnp.int32) * tm
+    lo = c * cap
+    hi = jnp.minimum(lo + cap, pairs)
+    first = jnp.clip(starts, lo, hi)
+    size = jnp.clip(ends, lo, hi) - first
+    padded = -(-size // tm) * tm
+    p_end = jnp.cumsum(padded)
+    p_start = p_end - padded
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        p_end, tile_at, side="right"), n_held - 1).astype(jnp.int32)
+    tile_live = jnp.clip(
+        size[tile_expert] - (tile_at - p_start[tile_expert]), 0, tm)
+    p = count - 1
+    slot = jnp.where(hit & (p >= lo) & (p < hi),
+                     (p_start - first)[expert] + p, m_rows + entry)
+    slot_row = jnp.zeros(m_rows, jnp.int32).at[slot].set(
+        row, mode="drop", unique_indices=True)
+    slot_weight = jnp.zeros(m_rows, jnp.float32).at[slot].set(
+        flat.astype(jnp.float32), mode="drop", unique_indices=True)
+    n_active = (p_end[-1] // tm).reshape(1)
+    return slot_row, slot_weight, tile_expert, tile_live, n_active
+
+
+# rows, held experts, the router's top_k of num_experts
+LAYOUT_SHAPES = [(512, 16, 4, 16), (512, 8, 8, 128), (256, 12, 8, 384),
+                 (384, 20, 3, 20)]
+LAYOUT_CASES = ["uniform", "skewed", "dead_rows", "fewer_choices",
+                "top_k_none", "two_passes", "three_passes", "one_expert",
+                "no_pair"]
+
+
+def _layout_case(case, rows, n_held, top_k, n_exp):
+    """``(local [rows, n_held], the top_k / num_experts the call is
+    handed, the passes it must take or None)``."""
+    rs = np.random.RandomState(len(case) * 1000 + rows + n_held)
+    routing = (top_k, n_exp)
+    if case in ("two_passes", "three_passes"):
+        # every row on 3 (5) held experts where the buffer holds 2 a row
+        each = 3 if case == "two_passes" else 5
+        if n_held == n_exp:             # all held: the share's buffer
+            routing = (None, None)
+        local = np.zeros((rows, n_held), np.float32)
+        for r in range(rows):
+            local[r, rs.choice(n_held, each, replace=False)] = \
+                rs.uniform(0.05, 0.4, each)
+        return local, routing, 2 if case == "two_passes" else 3
+    if case == "one_expert":
+        local = np.zeros((rows, n_held), np.float32)
+        local[:, 2] = 0.4
+        return local, routing, 1
+    if case == "no_pair":
+        return np.zeros((rows, n_held), np.float32), routing, 0
+    scores = rs.randn(rows, n_exp).astype(np.float32)
+    if case == "skewed":                # most rows on experts 0, 1 and 5
+        scores[:, [0, 1, 5]] += (4.0, 3.0, 2.0)
+    ids = np.argsort(-scores, axis=1)[:, :top_k]
+    w = _softmax_np(np.take_along_axis(scores, ids, axis=1))
+    full = np.zeros((rows, n_exp), np.float32)
+    np.put_along_axis(full, ids, w, axis=1)
+    local = full[:, :n_held]
+    if case == "dead_rows":
+        local[int(0.6 * rows):] = 0.0
+    elif case == "fewer_choices":       # half the choices are not held
+        local[rs.rand(rows, n_held) < 0.5] = 0.0
+    elif case == "top_k_none":
+        routing = (None, None)
+    return local, routing, None
+
+
+@pytest.mark.parametrize("shape", LAYOUT_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("case", LAYOUT_CASES)
+def test_the_pair_layout_is_the_one_it_was(case, shape):
+    """The five arrays the grouped kernels are fed (``slot_row``,
+    ``slot_weight``, ``tile_expert``, ``tile_live``, ``n_active``), from
+    the candidates alone (a row's ``min(top_k, n_held)`` entries at
+    most, one scatter), against the layout over every entry: equal,
+    element for element, in every pass; and the gauge says how many
+    updates the layout's scatter walks."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.monitor import stat_get
+    from paddle_tpu.ops import pallas_moe_grouped as grouped
+
+    rows, n_held, top_k, n_exp = shape
+    local, (top_k, n_exp), passes = _layout_case(case, *shape)
+    tm = grouped.default_tiles(rows, n_held, top_k, n_exp)
+    m_rows = grouped.sorted_rows(rows, n_held, top_k, n_exp)
+    cap = m_rows - n_held * tm
+    pairs = int((local != 0).sum())
+    if passes is not None:
+        assert -(-pairs // cap) == passes
+    cand = grouped.layout_candidates(jnp.asarray(local), top_k)
+    assert stat_get("moe_grouped_layout_updates") == rows * (
+        n_held if top_k is None else min(top_k, n_held))
+    assert int(cand.ends[-1]) == pairs
+    for c in range(max(-(-pairs // cap), 1)):
+        want = _layout_until_pr55(jnp.asarray(local), c, tm=tm,
+                                  m_rows=m_rows)
+        got = grouped.layout_pass(cand, c, tm=tm, m_rows=m_rows)
+        for name, x, y in zip(("slot_row", "slot_weight", "tile_expert",
+                               "tile_live", "n_active"), got, want):
+            assert x.dtype == y.dtype and x.shape == y.shape, name
+            np.testing.assert_array_equal(
+                np.asarray(x).view(np.int32), np.asarray(y).view(np.int32),
+                err_msg=f"{name}, pass {c}")
+
+
+@pytest.mark.parametrize("case, shape", [
+    ("dead_rows", (512, 16, 4, 16)), ("two_passes", (256, 12, 8, 384)),
+    ("top_k_none", (512, 8, 8, 128))])
+def test_the_grouped_form_is_bitwise_what_the_old_layout_gave(case, shape):
+    """``grouped_share_ffn`` (the kernels interpreted) against the same
+    two kernels fed the layout as it was until PR 55, pass by pass: the
+    same float32 bits in every row."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import pallas_moe_grouped as grouped
+
+    rows, n_held, _, _ = shape
+    local, (top_k, n_exp), _ = _layout_case(case, *shape)
+    d, f = 32, 16
+    k = jax.random.split(jax.random.PRNGKey(55), 4)
+    h = jax.random.normal(k[0], (rows, d), jnp.float32)
+    w_gate, w_up, w_down = [
+        jax.random.normal(kk, s) / np.sqrt(s[0]) for kk, s in zip(
+            k[1:], ((d, n_held * f), (d, n_held * f), (n_held * f, d)))]
+    out, pairs, passes = grouped.grouped_share_ffn(
+        h, jnp.asarray(local), w_gate, w_up, w_down, interpret=True,
+        top_k=top_k, num_experts=n_exp)
+    tm = grouped.default_tiles(rows, n_held, top_k, n_exp)
+    m_rows = grouped.sorted_rows(rows, n_held, top_k, n_exp)
+    want = jnp.zeros((rows, d), jnp.float32)
+    for c in range(int(passes)):
+        slot_row, slot_weight, tile_expert, tile_live, n_active = \
+            _layout_until_pr55(jnp.asarray(local), c, tm=tm, m_rows=m_rows)
+        act = grouped._gate_up_call(
+            h[slot_row], slot_weight[:, None], tile_expert, n_active,
+            w_gate, w_up, n_held=n_held, tm=tm, interpret=True)
+        want = grouped._down_call(
+            act, want, tile_expert, n_active, slot_row, tile_live, w_down,
+            tm=tm, interpret=True)
+    assert int(pairs) == int((local != 0).sum()) and int(passes) >= 1
+    np.testing.assert_array_equal(
+        np.asarray(out).view(np.int32), np.asarray(want).view(np.int32))
+    assert np.asarray(out).any()

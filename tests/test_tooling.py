@@ -277,3 +277,32 @@ def test_the_block_sweep_rehearses_on_the_cpu_and_times_nothing_there(
     monkeypatch.setattr(sys, "argv", ["sweep"])
     with pytest.raises(SystemExit, match="a TPU or nothing"):
         sweep.main()
+
+
+def test_the_layout_sweep_rehearses_on_the_cpu_and_times_nothing_there(
+        tmp_path, monkeypatch):
+    """``tools/sweep_moe_layout.py`` (the numbers behind the grouped
+    experts' pair layout): at ``--tiny`` sizes every form it keeps, the
+    served one and the ones not taken, gives the five arrays of the
+    layout as it was until PR 55 and says how many updates its scatter
+    walks; no line holds a time; without ``--tiny`` it wants a TPU."""
+    import json
+
+    from tools import sweep_moe_layout as sweep
+
+    out = tmp_path / "sweep.json"
+    monkeypatch.setattr(sys, "argv", [
+        "sweep", "--tiny", "--shapes", "all_held,odd", "--out", str(out)])
+    sweep.main()
+    with open(out) as f:
+        lines = json.load(f)
+    assert {x["form"] for x in lines} == set(sweep.FORMS)
+    assert all(x["equal"] and "us_a_call" not in x for x in lines)
+    updates = {(x["shape"], x["form"]): x["updates"] for x in lines}
+    assert updates["all_held", "parent"] == 512 * 16
+    assert updates["all_held", "served"] == 512 * 4
+    assert updates["odd", "parent"] == 256 * 12
+    assert updates["odd", "served"] == 256 * 8
+    monkeypatch.setattr(sys, "argv", ["sweep"])
+    with pytest.raises(SystemExit, match="a TPU or nothing"):
+        sweep.main()
