@@ -62,6 +62,7 @@ from .kv_cache import (  # noqa: F401
     PrefixIndex,
 )
 from .latent_moe_lm import LatentMoELM  # noqa: F401
+from .linear_latent_lm import LinearLatentLM  # noqa: F401
 from .looped_lm import LoopedLM  # noqa: F401
 from .parallel_moe_lm import ParallelMoELM  # noqa: F401
 from .window_moe_lm import WindowMoELM  # noqa: F401
@@ -79,7 +80,8 @@ __all__ = [
     "DecodeEngine", "DecodeRequest", "DecodeServer", "DisaggConfig",
     "DisaggRequest", "DisaggServer", "GatedDeltaLM", "HybridMoELM",
     "InferenceRequest",
-    "KVPageExport", "LatentMoELM", "LoopedLM", "PageAllocator",
+    "KVPageExport", "LatentMoELM", "LinearLatentLM", "LoopedLM",
+    "PageAllocator",
     "PagedKVCache",
     "ParallelMoELM",
     "PrefixIndex",

@@ -957,10 +957,8 @@ class DecodeEngine:
         # and what rides a whole-prompt prefill's token
         self._prefill_tallies = self._mixed.prefill_tallies \
             if self._mixed else tuple(getattr(model, "prefill_tallies", ()))
-        self._refuse_for_kinds(model, c, draft_model)
         latent = bool(getattr(model, "values_in_keys", False))
-        if latent:
-            self._refuse_for_latent(c, draft_model)
+        self._refuse(model, c, draft_model, latent)
         with jax.default_device(self._device):
             self._cache = PagedKVCache(
                 CacheConfig(max(cache_layers(model) - n_rec - n_win, 1),
@@ -1092,6 +1090,24 @@ class DecodeEngine:
         # whether the joint step's program has run once: its first run
         # (a compile, or a load from the compile cache) is no slow step
         self._step_ran = False
+
+    @classmethod
+    def _refuse(cls, model, c: "DecodeConfig", draft_model, latent) -> None:
+        """What the configuration asks for that the model's layers or
+        its page cannot carry.  A model may keep state a slot in some
+        layers AND a latent page in the others: the refusal then names
+        both, each with its own reason."""
+        refusals = [(cls._refuse_for_kinds, (model, c, draft_model))]
+        if latent:
+            refusals.append((cls._refuse_for_latent, (c, draft_model)))
+        said = []
+        for refuse, args in refusals:
+            try:
+                refuse(*args)
+            except ValueError as e:
+                said.append(str(e))
+        if said:
+            raise ValueError("; and ".join(said))
 
     @staticmethod
     def _refuse_for_kinds(model, c: "DecodeConfig", draft_model) -> None:

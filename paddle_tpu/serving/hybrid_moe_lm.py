@@ -66,174 +66,72 @@ def step_tallies(model, rows):
     return tuple(n for n in model.tallies if n not in moe_ops.HIT_TALLIES)
 
 
-class HybridMoELM:
-    """Sized by constructor arguments; ``layer_kinds`` is the pattern
-    (Solar-Open2: one ``"attention"`` then three ``"recurrent"`` a
-    period).  ``held_experts`` are the routed-expert ids this chip holds
-    of ``num_experts``; the router keeps its full width."""
+class KDAMixer:
+    """The channel-decay delta-rule mixer of a model with ``lin_heads``
+    heads of ``lin_head_dim``, a convolution of ``conv_kernel`` taps,
+    low-rank gates of ``gate_rank`` and ``rms_eps``: its weights, one
+    slot's state, the mixer's residual term and the rule's two forms.
+    ``beta_scale`` is the range of the rule's step: ``(0, 2)`` where the
+    published config allows negative eigenvalues, ``(0, 1)`` else.
+    ``HybridMoELM``'s, and ``linear_latent_lm.py``'s."""
 
-    def __init__(self, vocab_size: int, d_model: int,
-                 layer_kinds: Sequence[str], num_heads: int,
-                 num_kv_heads: int, head_dim: int, lin_heads: int,
-                 lin_head_dim: int, conv_kernel: int, gate_rank: int,
-                 num_experts: int, top_k: int,
-                 held_experts: Sequence[int], expert_dim: int,
-                 shared_dim: int, rms_eps: float = 1e-5,
-                 dtype="bfloat16", max_seq_len: int = 1 << 20):
-        self.vocab_size, self.d_model = int(vocab_size), int(d_model)
-        self.layer_kinds = tuple(layer_kinds)
-        bad = set(self.layer_kinds) - {"attention", "recurrent"}
-        if bad or not self.layer_kinds:
-            raise ValueError(f"layer_kinds holds {sorted(bad) or 'nothing'}")
-        self.num_layers = len(self.layer_kinds)
-        self.num_heads, self.num_kv_heads = int(num_heads), int(num_kv_heads)
-        if self.num_heads % self.num_kv_heads:
-            raise ValueError("num_heads must be a multiple of num_kv_heads")
-        self.head_dim = int(head_dim)
-        self.lin_heads, self.lin_head_dim = int(lin_heads), int(lin_head_dim)
-        self.conv_kernel, self.gate_rank = int(conv_kernel), int(gate_rank)
-        self.num_experts, self.top_k = int(num_experts), int(top_k)
-        self.held_experts = tuple(int(e) for e in held_experts)
-        if not self.held_experts or min(self.held_experts) < 0 \
-                or max(self.held_experts) >= self.num_experts \
-                or len(set(self.held_experts)) != len(self.held_experts):
-            raise ValueError(
-                f"held_experts must be distinct ids below {num_experts}")
-        self.expert_dim, self.shared_dim = int(expert_dim), int(shared_dim)
-        self.rms_eps = float(rms_eps)
-        self.dtype = str(dtype)
-        self.max_seq_len = int(max_seq_len)     # no positional table
-        # the counters forward adds to through attend.tally: a joint
-        # step's, and those only a whole-prompt prefill reads back
-        # ``kda_kernel_rows``: live rows x recurrent layers whose state a
-        # step's kernel calls updated (0 where the XLA form serves)
-        # ``HIT_TALLIES``: read back by a step that takes the hit form
-        # (``step_tallies``), counted and dropped anywhere else
-        self.tallies = ("moe_local_assignments", "moe_experts_hit",
-                        "kda_kernel_rows") + moe_ops.HIT_TALLIES
-        self.prefill_tallies = moe_ops.GROUPED_TALLIES
+    beta_scale = 2.0
+
+    def kda_state(self):
+        """One slot's state of ONE recurrent layer: the delta rule's
+        matrix a head, and the K-1 positions the convolution looks back
+        on, oldest first, side by side in one lane-dense row."""
         c = self.lin_heads * self.lin_head_dim
-        # one slot's state of ONE recurrent layer: the delta rule's
-        # matrix a head, and the K-1 positions the convolution looks
-        # back on, oldest first, side by side in one lane-dense row
-        self.recurrent_state = {
+        return {
             "s": ((self.lin_heads, self.lin_head_dim, self.lin_head_dim),
                   np.float32),
             "tail": (((self.conv_kernel - 1) * 3 * c,), np.float32)}
 
-    step_tallies = step_tallies
-
-    # -- weights ------------------------------------------------------------
-    def init_weights(self, key):
-        """Seeded weights at variance-preserving scales; the decay's
+    def kda_weights(self, dense, keys, ones):
+        """A recurrent layer's mixer weights; the decay's
         ``A_log``/``dt_bias`` as the gated linear-attention families set
         them (rates 1..16, steps 1e-3..1e-1: decays 0.2..0.999)."""
         import jax
         import jax.numpy as jnp
 
-        dt = jnp.dtype(self.dtype)
-        dm, v = self.d_model, self.vocab_size
-        hq = self.num_heads * self.head_dim
-        hkv = self.num_kv_heads * self.head_dim
+        dm, r = self.d_model, self.gate_rank
         c = self.lin_heads * self.lin_head_dim
-        r, e, f = self.gate_rank, self.num_experts, self.expert_dim
-        nf = len(self.held_experts) * f
-        keys = iter(jax.random.split(key, 4 + 24 * self.num_layers))
+        rate = jax.random.uniform(next(keys), (self.lin_heads,),
+                                  jnp.float32, 1.0, 16.0)
+        step = jnp.exp(jax.random.uniform(
+            next(keys), (c,), jnp.float32,
+            math.log(1e-3), math.log(1e-1)))
+        return dict(
+            kda_wqkv=dense((dm, 3 * c)),
+            kda_conv=dense((self.conv_kernel, 3 * c),
+                           1.0 / math.sqrt(self.conv_kernel),
+                           jnp.float32),
+            kda_a_log=jnp.log(rate),
+            # softplus^-1(step)
+            kda_dt_bias=step + jnp.log(-jnp.expm1(-step)),
+            kda_wa_down=dense((dm, r)), kda_wa_up=dense((r, c)),
+            kda_wbeta=dense((dm, self.lin_heads)),
+            kda_wo_down=dense((dm, r)), kda_wo_up=dense((r, c)),
+            kda_onorm=ones(self.lin_head_dim),
+            kda_wout=dense((c, dm)))
 
-        def dense(shape, scale=None, dtype=dt):
-            scale = 1.0 / math.sqrt(shape[0]) if scale is None else scale
-            return (jax.random.normal(next(keys), shape, jnp.float32)
-                    * scale).astype(dtype)
-
-        ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
-        w = {"tok_emb": dense((v, dm), 1.0), "lm_head": dense((dm, v)),
-             "norm_f": ones(dm), "layers": []}
-        for kind in self.layer_kinds:
-            lw = {"norm1": ones(dm), "norm2": ones(dm)}
-            if kind == "attention":
-                lw.update(wq=dense((dm, hq)), wk=dense((dm, hkv)),
-                          wv=dense((dm, hkv)), wg=dense((dm, hq)),
-                          wo=dense((hq, dm)))
-            else:
-                rate = jax.random.uniform(next(keys), (self.lin_heads,),
-                                          jnp.float32, 1.0, 16.0)
-                step = jnp.exp(jax.random.uniform(
-                    next(keys), (c,), jnp.float32,
-                    math.log(1e-3), math.log(1e-1)))
-                lw.update(
-                    kda_wqkv=dense((dm, 3 * c)),
-                    kda_conv=dense((self.conv_kernel, 3 * c),
-                                   1.0 / math.sqrt(self.conv_kernel),
-                                   jnp.float32),
-                    kda_a_log=jnp.log(rate),
-                    # softplus^-1(step)
-                    kda_dt_bias=step + jnp.log(-jnp.expm1(-step)),
-                    kda_wa_down=dense((dm, r)), kda_wa_up=dense((r, c)),
-                    kda_wbeta=dense((dm, self.lin_heads)),
-                    kda_wo_down=dense((dm, r)), kda_wo_up=dense((r, c)),
-                    kda_onorm=ones(self.lin_head_dim),
-                    kda_wout=dense((c, dm)))
-            lw.update(
-                moe_router=dense((dm, e), dtype=jnp.float32),
-                moe_router_bias=jnp.zeros((e,), jnp.float32),
-                moe_w_gate=dense((dm, nf)), moe_w_up=dense((dm, nf)),
-                moe_w_down=dense((nf, dm), 1.0 / math.sqrt(f)),
-                shared_w_gate=dense((dm, self.shared_dim)),
-                shared_w_up=dense((dm, self.shared_dim)),
-                shared_w_down=dense((self.shared_dim, dm)))
-            w["layers"].append(lw)
-        return w
-
-    # -- the block ------------------------------------------------------------
-    def forward(self, weights, tokens, positions, cache, attend):
-        """Logits for ``tokens`` (``[S]`` one token a slot, ``[T]`` one
-        prompt) -> ``(logits [..., V], cache)``; ``positions`` are not
-        read (no positional term).  See the module header for what
-        ``attend`` carries."""
+    def kda_mixer(self, l, lw, h, cache, attend):
+        """Recurrent layer ``l``'s residual term of the normed rows
+        ``h`` -> (``y``, cache)."""
         import jax
         import jax.numpy as jnp
 
-        w = weights
-        x = w["tok_emb"][tokens].astype(jnp.float32)
-        lead = x.shape[:-1]
-        for l, kind in enumerate(self.layer_kinds):
-            lw = w["layers"][l]
-            h = self._rms(x, lw["norm1"])
-            if kind == "attention":
-                q = _mm(h, lw["wq"]).reshape(*lead, self.num_heads,
-                                             self.head_dim)
-                k = _mm(h, lw["wk"]).reshape(*lead, self.num_kv_heads,
-                                             self.head_dim)
-                v = _mm(h, lw["wv"]).reshape(*lead, self.num_kv_heads,
-                                             self.head_dim)
-                ctx, cache = attend(l, q, k, v, cache)
-                y = _mm(ctx.reshape(*lead, -1).astype(jnp.float32)
-                        * jax.nn.sigmoid(_mm(h, lw["wg"])), lw["wo"])
-            else:
-                rows = {"u": _mm(h, lw["kda_wqkv"]),
-                        "gate": _mm(_mm(h, lw["kda_wa_down"]),
-                                    lw["kda_wa_up"]),
-                        "beta": _mm(h, lw["kda_wbeta"])}
-                o, cache = self._recur(l, lw, rows, cache, attend)
-                o = o * jax.lax.rsqrt(jnp.mean(
-                    o * o, -1, keepdims=True) + self.rms_eps) \
-                    * lw["kda_onorm"]
-                y = _mm(o.reshape(*lead, -1) * jax.nn.sigmoid(_mm(
-                    _mm(h, lw["kda_wo_down"]), lw["kda_wo_up"])),
-                    lw["kda_wout"])
-            x = x + y
-            h = self._rms(x, lw["norm2"])
-            local = route_share(h, lw, attend, self.top_k,
-                                self.held_experts)
-            with jax.named_scope("moe_shared"):
-                shared = _mm(jax.nn.silu(_mm(h, lw["shared_w_gate"]))
-                             * _mm(h, lw["shared_w_up"]),
-                             lw["shared_w_down"])
-            x = x + share_ffn(self, h, lw, local, attend) + shared
-        return _mm(self._rms(x, w["norm_f"]), w["lm_head"]), cache
-
-    def _rms(self, x, g):
-        return rms_norm(x, g, self.rms_eps)
+        rows = {"u": _mm(h, lw["kda_wqkv"]),
+                "gate": _mm(_mm(h, lw["kda_wa_down"]),
+                            lw["kda_wa_up"]),
+                "beta": _mm(h, lw["kda_wbeta"])}
+        o, cache = self._recur(l, lw, rows, cache, attend)
+        o = o * jax.lax.rsqrt(jnp.mean(
+            o * o, -1, keepdims=True) + self.rms_eps) \
+            * lw["kda_onorm"]
+        return _mm(o.reshape(*h.shape[:-1], -1) * jax.nn.sigmoid(_mm(
+            _mm(h, lw["kda_wo_down"]), lw["kda_wo_up"])),
+            lw["kda_wout"]), cache
 
     def _recur(self, l, lw, rows, cache, attend):
         """Recurrent layer ``l`` over the rows' projections -> (``o``,
@@ -265,7 +163,8 @@ class HybridMoELM:
         rows ``conv [N, 3C]`` and the ``gate [N, C]`` and ``beta [N,
         heads]`` projections -> (q, k, v, decay ``[N, heads, dk]``, beta
         ``[N, heads]``): q and k at unit length a head, q scaled by
-        ``dk^-1/2``; the decay a channel in (0, 1); beta in (0, 2)."""
+        ``dk^-1/2``; the decay a channel in (0, 1); beta in (0,
+        ``beta_scale``)."""
         import jax
         import jax.numpy as jnp
 
@@ -278,7 +177,7 @@ class HybridMoELM:
         decay = jnp.exp(
             -jnp.exp(lw["kda_a_log"])[:, None] * jax.nn.softplus(
                 gate + lw["kda_dt_bias"]).reshape(-1, nh, dk))
-        return q, k, v, decay, 2.0 * jax.nn.sigmoid(beta)
+        return q, k, v, decay, self.beta_scale * jax.nn.sigmoid(beta)
 
     def _kda_token(self, lw, rows, state, live=None, interpret=False):
         """One token a row through a recurrent layer: ``rows`` the
@@ -351,6 +250,131 @@ class HybridMoELM:
         return o[0], {"s": s, "tail": tail.reshape(1, -1)}
 
 
+class HybridMoELM(KDAMixer):
+    """Sized by constructor arguments; ``layer_kinds`` is the pattern
+    (Solar-Open2: one ``"attention"`` then three ``"recurrent"`` a
+    period).  ``held_experts`` are the routed-expert ids this chip holds
+    of ``num_experts``; the router keeps its full width."""
+
+    def __init__(self, vocab_size: int, d_model: int,
+                 layer_kinds: Sequence[str], num_heads: int,
+                 num_kv_heads: int, head_dim: int, lin_heads: int,
+                 lin_head_dim: int, conv_kernel: int, gate_rank: int,
+                 num_experts: int, top_k: int,
+                 held_experts: Sequence[int], expert_dim: int,
+                 shared_dim: int, rms_eps: float = 1e-5,
+                 dtype="bfloat16", max_seq_len: int = 1 << 20):
+        self.vocab_size, self.d_model = int(vocab_size), int(d_model)
+        self.layer_kinds = tuple(layer_kinds)
+        bad = set(self.layer_kinds) - {"attention", "recurrent"}
+        if bad or not self.layer_kinds:
+            raise ValueError(f"layer_kinds holds {sorted(bad) or 'nothing'}")
+        self.num_layers = len(self.layer_kinds)
+        self.num_heads, self.num_kv_heads = int(num_heads), int(num_kv_heads)
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError("num_heads must be a multiple of num_kv_heads")
+        self.head_dim = int(head_dim)
+        self.lin_heads, self.lin_head_dim = int(lin_heads), int(lin_head_dim)
+        self.conv_kernel, self.gate_rank = int(conv_kernel), int(gate_rank)
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.held_experts = held_ids(held_experts, self.num_experts)
+        self.expert_dim, self.shared_dim = int(expert_dim), int(shared_dim)
+        self.rms_eps = float(rms_eps)
+        self.dtype = str(dtype)
+        self.max_seq_len = int(max_seq_len)     # no positional table
+        # the counters forward adds to through attend.tally: a joint
+        # step's, and those only a whole-prompt prefill reads back
+        # ``kda_kernel_rows``: live rows x recurrent layers whose state a
+        # step's kernel calls updated (0 where the XLA form serves)
+        # ``HIT_TALLIES``: read back by a step that takes the hit form
+        # (``step_tallies``), counted and dropped anywhere else
+        self.tallies = ("moe_local_assignments", "moe_experts_hit",
+                        "kda_kernel_rows") + moe_ops.HIT_TALLIES
+        self.prefill_tallies = moe_ops.GROUPED_TALLIES
+        self.recurrent_state = self.kda_state()
+
+    step_tallies = step_tallies
+
+    # -- weights ------------------------------------------------------------
+    def init_weights(self, key):
+        """Seeded weights at variance-preserving scales
+        (``kda_weights`` has the decay's)."""
+        import jax
+        import jax.numpy as jnp
+
+        dt = jnp.dtype(self.dtype)
+        dm, v = self.d_model, self.vocab_size
+        hq = self.num_heads * self.head_dim
+        hkv = self.num_kv_heads * self.head_dim
+        e, f = self.num_experts, self.expert_dim
+        nf = len(self.held_experts) * f
+        keys = iter(jax.random.split(key, 4 + 24 * self.num_layers))
+
+        dense = dense_from(keys, dt)
+        ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
+        w = {"tok_emb": dense((v, dm), 1.0), "lm_head": dense((dm, v)),
+             "norm_f": ones(dm), "layers": []}
+        for kind in self.layer_kinds:
+            lw = {"norm1": ones(dm), "norm2": ones(dm)}
+            if kind == "attention":
+                lw.update(wq=dense((dm, hq)), wk=dense((dm, hkv)),
+                          wv=dense((dm, hkv)), wg=dense((dm, hq)),
+                          wo=dense((hq, dm)))
+            else:
+                lw.update(self.kda_weights(dense, keys, ones))
+            lw.update(
+                moe_router=dense((dm, e), dtype=jnp.float32),
+                moe_router_bias=jnp.zeros((e,), jnp.float32),
+                moe_w_gate=dense((dm, nf)), moe_w_up=dense((dm, nf)),
+                moe_w_down=dense((nf, dm), 1.0 / math.sqrt(f)),
+                shared_w_gate=dense((dm, self.shared_dim)),
+                shared_w_up=dense((dm, self.shared_dim)),
+                shared_w_down=dense((self.shared_dim, dm)))
+            w["layers"].append(lw)
+        return w
+
+    # -- the block ------------------------------------------------------------
+    def forward(self, weights, tokens, positions, cache, attend):
+        """Logits for ``tokens`` (``[S]`` one token a slot, ``[T]`` one
+        prompt) -> ``(logits [..., V], cache)``; ``positions`` are not
+        read (no positional term).  See the module header for what
+        ``attend`` carries."""
+        import jax
+        import jax.numpy as jnp
+
+        w = weights
+        x = w["tok_emb"][tokens].astype(jnp.float32)
+        lead = x.shape[:-1]
+        for l, kind in enumerate(self.layer_kinds):
+            lw = w["layers"][l]
+            h = self._rms(x, lw["norm1"])
+            if kind == "attention":
+                q = _mm(h, lw["wq"]).reshape(*lead, self.num_heads,
+                                             self.head_dim)
+                k = _mm(h, lw["wk"]).reshape(*lead, self.num_kv_heads,
+                                             self.head_dim)
+                v = _mm(h, lw["wv"]).reshape(*lead, self.num_kv_heads,
+                                             self.head_dim)
+                ctx, cache = attend(l, q, k, v, cache)
+                y = _mm(ctx.reshape(*lead, -1).astype(jnp.float32)
+                        * jax.nn.sigmoid(_mm(h, lw["wg"])), lw["wo"])
+            else:
+                y, cache = self.kda_mixer(l, lw, h, cache, attend)
+            x = x + y
+            h = self._rms(x, lw["norm2"])
+            local = route_share(h, lw, attend, self.top_k,
+                                self.held_experts)
+            with jax.named_scope("moe_shared"):
+                shared = _mm(jax.nn.silu(_mm(h, lw["shared_w_gate"]))
+                             * _mm(h, lw["shared_w_up"]),
+                             lw["shared_w_down"])
+            x = x + share_ffn(self, h, lw, local, attend) + shared
+        return _mm(self._rms(x, w["norm_f"]), w["lm_head"]), cache
+
+    def _rms(self, x, g):
+        return rms_norm(x, g, self.rms_eps)
+
+
 def _kda_rule_xla(q, k, v, decay, beta, s):
     """The gated delta rule, one token a row, as XLA fusions: ``q``,
     ``k``, ``v``, ``decay [R, heads, d]``, ``beta [R, heads]``, ``s [R,
@@ -367,6 +391,32 @@ def _kda_rule_xla(q, k, v, decay, beta, s):
     delta = beta[..., None] * (v - ks)
     s = s + k[..., None] * delta[..., None, :]
     return qs + jnp.sum(q * k, -1, keepdims=True) * delta, s
+
+
+def held_ids(held_experts, num_experts):
+    """``held_experts`` as a tuple of distinct ids below
+    ``num_experts``, or a ``ValueError``."""
+    held = tuple(int(e) for e in held_experts)
+    if not held or min(held) < 0 or max(held) >= num_experts \
+            or len(set(held)) != len(held):
+        raise ValueError(
+            f"held_experts must be distinct ids below {num_experts}")
+    return held
+
+
+def dense_from(keys, dt):
+    """``dense(shape, scale=None, dtype=dt)``: a seeded normal matrix at
+    a variance-preserving scale (``shape[0] ** -0.5`` where none is
+    given), a key of ``keys`` a call."""
+    import jax
+    import jax.numpy as jnp
+
+    def dense(shape, scale=None, dtype=dt):
+        scale = 1.0 / math.sqrt(shape[0]) if scale is None else scale
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    return dense
 
 
 def _mm(a, w):

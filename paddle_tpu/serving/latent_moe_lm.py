@@ -51,8 +51,8 @@ import math
 from typing import Sequence
 
 from ..ops import moe_ops
-from .hybrid_moe_lm import (_mm, rms_norm, route_share, share_ffn,
-                            step_tallies)
+from .hybrid_moe_lm import (_mm, dense_from, held_ids, rms_norm,
+                            route_share, share_ffn, step_tallies)
 from .window_moe_lm import DENSE_SCOPE, ROPE_SCOPE
 
 Q_PROJ_SCOPE = "latent_q_proj"          # q_a, its norm, q_b
@@ -91,7 +91,97 @@ def yarn_mscale(factor, mscale):
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-class LatentMoELM:
+class LatentMixer:
+    """Latent attention of a model with ``num_heads`` heads of
+    ``nope_dim + rope_dim`` query lanes and ``v_dim`` value lanes over a
+    latent of ``kv_rank``, ``softmax_scale`` and ``rms_eps``: its
+    weights, what the engine reads of it, and the two forms.  A model
+    says how its queries are made (``_queries``, ``_query_weights``) and
+    hands ``turn``, the rotary term's two factors, or None where its
+    last ``rope_dim`` lanes carry no position (they are then lanes like
+    the others: nothing turns them, not by the identity either).
+    ``LatentMoELM``'s, and ``linear_latent_lm.py``'s."""
+
+    def latent_declares(self):
+        """What the engine reads: ONE cached row a position, all heads'."""
+        self.num_kv_heads = 1
+        self.head_dim = self.kv_rank + self.rope_dim
+        self.v_head_dim = self.kv_rank
+        self.values_in_keys = True
+        self.prompt_heads = (self.num_heads, self.nope_dim + self.rope_dim,
+                             self.v_dim)
+
+    def latent_weights(self, dense, ones):
+        """A latent layer's mixer weights, the queries' first."""
+        h = self.num_heads
+        up = 1.0 / math.sqrt(self.kv_rank)
+        return {**self._query_weights(dense, ones),
+                "kv_norm": ones(self.kv_rank),
+                "wkv_a": dense((self.d_model, self.kv_rank + self.rope_dim)),
+                # kv_b_proj, split once: head h's keys are c W_UK[h]^T,
+                # its values c W_UV[h]
+                "w_uk": dense((h, self.nope_dim, self.kv_rank), up),
+                "w_uv": dense((h, self.kv_rank, self.v_dim), up),
+                "wo": dense((h * self.v_dim, self.d_model))}
+
+    def _attention(self, lw, l, h, turn, cache, attend):
+        """One layer's context ``[..., H, v_dim]`` of rows ``h``, in the
+        form the program asks for; ``turn``: the class docstring."""
+        import jax
+        import jax.numpy as jnp
+
+        lead, nh = h.shape[:-1], self.num_heads
+        with jax.named_scope(Q_PROJ_SCOPE):
+            q = self._queries(lw, h).reshape(
+                *lead, nh, self.nope_dim + self.rope_dim)
+        with jax.named_scope(KV_PROJ_SCOPE):
+            c, k_r = self._latent(lw, _mm(h, lw["wkv_a"]))
+        if turn is None:
+            q_rot, k_rot = q[..., self.nope_dim:], k_r[..., None, :]
+        else:
+            with jax.named_scope(ROPE_SCOPE):
+                q_rot = self._rotate(q[..., self.nope_dim:], *turn)
+                k_rot = self._rotate(k_r[..., None, :], *turn)
+        q_nope = q[..., :self.nope_dim]
+        row = jnp.concatenate([c[..., None, :], k_rot], axis=-1)
+        dt = lw["w_uk"].dtype
+        if attend.prompt:
+            with jax.named_scope(EXPAND_SCOPE):
+                cb = c.astype(dt)
+                k_nope = jnp.einsum("...c,hdc->...hd", cb, lw["w_uk"],
+                                    preferred_element_type=jnp.float32)
+                v = jnp.einsum("...c,hcd->...hd", cb, lw["w_uv"],
+                               preferred_element_type=jnp.float32)
+                k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                    k_rot, (*lead, nh, self.rope_dim))], axis=-1)
+            return attend(l, self._scaled(
+                jnp.concatenate([q_nope, q_rot], axis=-1)), k, v, cache,
+                keep=row)
+        with jax.named_scope(ABSORB_Q_SCOPE):
+            q_lat = jnp.einsum("...hd,hdc->...hc", q_nope.astype(dt),
+                               lw["w_uk"],
+                               preferred_element_type=jnp.float32)
+        ctx_lat, cache = attend(l, self._scaled(
+            jnp.concatenate([q_lat, q_rot], axis=-1)), row, None, cache)
+        with jax.named_scope(ABSORB_V_SCOPE):
+            return jnp.einsum("...hc,hcd->...hd", ctx_lat.astype(dt),
+                              lw["w_uv"],
+                              preferred_element_type=jnp.float32), cache
+
+    def _latent(self, lw, kv):
+        """(c, k_r) of ``kv = h W_kva``: the norm is the latent's alone,
+        the shared key is not normed."""
+        return rms_norm(kv[..., :self.kv_rank], lw["kv_norm"],
+                        self.rms_eps), kv[..., self.kv_rank:]
+
+    def _scaled(self, q):
+        """The engine's attention divides scores by the square root of
+        the query's width; what the softmax's scale holds beyond that
+        (the heads' own width, YaRN's ``mscale^2``) rides the query."""
+        return q * (self.softmax_scale * math.sqrt(q.shape[-1]))
+
+
+class LatentMoELM(LatentMixer):
     """Sized by constructor arguments.  The first ``dense_layers`` of
     ``num_layers`` layers have a dense feed-forward of ``dense_dim``, the
     others the routed experts (``held_experts`` of ``num_experts``, the
@@ -122,13 +212,7 @@ class LatentMoELM:
         self.v_dim = int(v_dim)
         if self.rope_dim % 2:
             raise ValueError("rope_dim must be even")
-        # what the engine reads: ONE cached row a position, all heads'
-        self.num_kv_heads = 1
-        self.head_dim = self.kv_rank + self.rope_dim
-        self.v_head_dim = self.kv_rank
-        self.values_in_keys = True
-        self.prompt_heads = (self.num_heads, self.nope_dim + self.rope_dim,
-                             self.v_dim)
+        self.latent_declares()
         self.rope_theta = float(rope_theta)
         self.rope_freqs = tuple(yarn_frequencies(
             self.rope_dim, self.rope_theta, float(rope_factor),
@@ -142,12 +226,7 @@ class LatentMoELM:
             * yarn_mscale(rope_factor, rope_mscale_all_dim) ** 2
         self.dense_dim = int(dense_dim)
         self.num_experts, self.top_k = int(num_experts), int(top_k)
-        self.held_experts = tuple(int(e) for e in held_experts)
-        if not self.held_experts or min(self.held_experts) < 0 \
-                or max(self.held_experts) >= self.num_experts \
-                or len(set(self.held_experts)) != len(self.held_experts):
-            raise ValueError(
-                f"held_experts must be distinct ids below {num_experts}")
+        self.held_experts = held_ids(held_experts, self.num_experts)
         self.expert_dim, self.shared_dim = int(expert_dim), int(shared_dim)
         self.routed_scale = float(routed_scale)
         self.rms_eps = float(rms_eps)
@@ -173,47 +252,30 @@ class LatentMoELM:
         import jax.numpy as jnp
 
         dt = jnp.dtype(self.dtype)
-        dm, v, h = self.d_model, self.vocab_size, self.num_heads
-        e, f, sf = self.num_experts, self.expert_dim, self.shared_dim
-        nf = len(self.held_experts) * f
+        dm, v = self.d_model, self.vocab_size
         keys = iter(jax.random.split(key, 4 + 16 * self.num_layers))
 
-        def dense(shape, scale=None, dtype=dt):
-            scale = 1.0 / math.sqrt(shape[0]) if scale is None else scale
-            return (jax.random.normal(next(keys), shape, jnp.float32)
-                    * scale).astype(dtype)
-
+        dense = dense_from(keys, dt)
         ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
         w = {"tok_emb": dense((v, dm), 1.0), "lm_head": dense((dm, v)),
              "norm_f": ones(dm), "layers": []}
-        up = 1.0 / math.sqrt(self.kv_rank)
         for l in range(self.num_layers):
             lw = {"norm1": ones(dm), "norm2": ones(dm),
-                  "q_norm": ones(self.q_rank), "kv_norm": ones(self.kv_rank),
-                  "wq_a": dense((dm, self.q_rank)),
-                  "wq_b": dense((self.q_rank,
-                                 h * (self.nope_dim + self.rope_dim))),
-                  "wkv_a": dense((dm, self.kv_rank + self.rope_dim)),
-                  # kv_b_proj, split once: head h's keys are c W_UK[h]^T,
-                  # its values c W_UV[h]
-                  "w_uk": dense((h, self.nope_dim, self.kv_rank), up),
-                  "w_uv": dense((h, self.kv_rank, self.v_dim), up),
-                  "wo": dense((h * self.v_dim, dm))}
-            if l < self.dense_layers:
-                lw.update(ffn_w_gate=dense((dm, self.dense_dim)),
-                          ffn_w_up=dense((dm, self.dense_dim)),
-                          ffn_w_down=dense((self.dense_dim, dm)))
-            else:
-                lw.update(
-                    moe_router=dense((dm, e), dtype=jnp.float32),
-                    moe_router_bias=dense((e,), 0.1, jnp.float32),
-                    moe_w_gate=dense((dm, nf)), moe_w_up=dense((dm, nf)),
-                    moe_w_down=dense((nf, dm), 1.0 / math.sqrt(f)),
-                    shared_w_gate=dense((dm, sf)),
-                    shared_w_up=dense((dm, sf)),
-                    shared_w_down=dense((sf, dm)))
+                  **self.latent_weights(dense, ones),
+                  **ffn_weights(self, l, dense)}
             w["layers"].append(lw)
         return w
+
+    def _query_weights(self, dense, ones):
+        return {"q_norm": ones(self.q_rank),
+                "wq_a": dense((self.d_model, self.q_rank)),
+                "wq_b": dense((self.q_rank, self.num_heads
+                               * (self.nope_dim + self.rope_dim)))}
+
+    def _queries(self, lw, h):
+        """Through the bottleneck: q_a, its norm, q_b."""
+        return _mm(rms_norm(_mm(h, lw["wq_a"]), lw["q_norm"],
+                            self.rms_eps), lw["wq_b"])
 
     # -- the block ------------------------------------------------------------
     def forward(self, weights, tokens, positions, cache, attend):
@@ -234,77 +296,8 @@ class LatentMoELM:
                 attend)
             with jax.named_scope(OUT_PROJ_SCOPE):
                 x = x + _mm(ctx.reshape(*ctx.shape[:-2], -1), lw["wo"])
-            h = rms_norm(x, lw["norm2"], self.rms_eps)
-            if l < self.dense_layers:
-                with jax.named_scope(DENSE_SCOPE):
-                    x = x + _swiglu(h, lw, "ffn")
-                continue
-            local = route_share(h, lw, attend, self.top_k,
-                                self.held_experts)
-            routed = share_ffn(self, h, lw, local, attend)
-            with jax.named_scope(SHARED_SCOPE):
-                x = x + self.routed_scale * routed + _swiglu(h, lw, "shared")
-        if attend.prompt and attend.read_row is not None:
-            # the one row of a prompt whose logits are read: the head
-            # over every row would be a seventh of a prefill's matmuls
-            # and 0.67 GB of float32 nobody reads
-            x = jax.lax.dynamic_slice_in_dim(x, attend.read_row, 1, axis=0)
-        return _mm(rms_norm(x, w["norm_f"], self.rms_eps),
-                   w["lm_head"]), cache
-
-    def _attention(self, lw, l, h, turn, cache, attend):
-        """One layer's context ``[..., H, v_dim]`` of rows ``h``, in the
-        form the program asks for."""
-        import jax
-        import jax.numpy as jnp
-
-        lead, nh = h.shape[:-1], self.num_heads
-        with jax.named_scope(Q_PROJ_SCOPE):
-            q = _mm(rms_norm(_mm(h, lw["wq_a"]), lw["q_norm"],
-                             self.rms_eps), lw["wq_b"]).reshape(
-                *lead, nh, self.nope_dim + self.rope_dim)
-        with jax.named_scope(KV_PROJ_SCOPE):
-            c, k_r = self._latent(lw, _mm(h, lw["wkv_a"]))
-        with jax.named_scope(ROPE_SCOPE):
-            q_rot = self._rotate(q[..., self.nope_dim:], *turn)
-            k_rot = self._rotate(k_r[..., None, :], *turn)
-        q_nope = q[..., :self.nope_dim]
-        row = jnp.concatenate([c[..., None, :], k_rot], axis=-1)
-        dt = lw["w_uk"].dtype
-        if attend.prompt:
-            with jax.named_scope(EXPAND_SCOPE):
-                cb = c.astype(dt)
-                k_nope = jnp.einsum("...c,hdc->...hd", cb, lw["w_uk"],
-                                    preferred_element_type=jnp.float32)
-                v = jnp.einsum("...c,hcd->...hd", cb, lw["w_uv"],
-                               preferred_element_type=jnp.float32)
-                k = jnp.concatenate([k_nope, jnp.broadcast_to(
-                    k_rot, (*lead, nh, self.rope_dim))], axis=-1)
-            return attend(l, self._scaled(
-                jnp.concatenate([q_nope, q_rot], axis=-1)), k, v, cache,
-                keep=row)
-        with jax.named_scope(ABSORB_Q_SCOPE):
-            q_lat = jnp.einsum("...hd,hdc->...hc", q_nope.astype(dt),
-                               lw["w_uk"],
-                               preferred_element_type=jnp.float32)
-        ctx_lat, cache = attend(l, self._scaled(
-            jnp.concatenate([q_lat, q_rot], axis=-1)), row, None, cache)
-        with jax.named_scope(ABSORB_V_SCOPE):
-            return jnp.einsum("...hc,hcd->...hd", ctx_lat.astype(dt),
-                              lw["w_uv"],
-                              preferred_element_type=jnp.float32), cache
-
-    def _latent(self, lw, kv):
-        """(c, k_r) of ``kv = h W_kva``: the norm is the latent's alone,
-        the rotary key is not normed."""
-        return rms_norm(kv[..., :self.kv_rank], lw["kv_norm"],
-                        self.rms_eps), kv[..., self.kv_rank:]
-
-    def _scaled(self, q):
-        """The engine's attention divides scores by the square root of
-        the query's width; what the softmax's scale holds beyond that
-        (the heads' own width, YaRN's ``mscale^2``) rides the query."""
-        return q * (self.softmax_scale * math.sqrt(q.shape[-1]))
+            x = feed_forward(self, l, lw, x, attend)
+        return head_logits(self, w, x, attend), cache
 
     def _rotary(self, positions):
         """(cos, sin) ``[..., 1, rope_dim]`` at ``positions [...]``, a
@@ -334,6 +327,56 @@ class LatentMoELM:
         partner = jnp.where(even, jnp.roll(x, -1, axis=-1),
                             jnp.roll(x, 1, axis=-1))
         return x * cos + partner * sin
+
+
+def ffn_weights(model, l, dense):
+    """Layer ``l``'s feed-forward weights: a dense SwiGLU in the leading
+    ``dense_layers``, else the held experts, the router with its
+    correction bias and the shared expert."""
+    import jax.numpy as jnp
+
+    dm, e, f = model.d_model, model.num_experts, model.expert_dim
+    nf, sf = len(model.held_experts) * f, model.shared_dim
+    if l < model.dense_layers:
+        return dict(ffn_w_gate=dense((dm, model.dense_dim)),
+                    ffn_w_up=dense((dm, model.dense_dim)),
+                    ffn_w_down=dense((model.dense_dim, dm)))
+    return dict(
+        moe_router=dense((dm, e), dtype=jnp.float32),
+        moe_router_bias=dense((e,), 0.1, jnp.float32),
+        moe_w_gate=dense((dm, nf)), moe_w_up=dense((dm, nf)),
+        moe_w_down=dense((nf, dm), 1.0 / math.sqrt(f)),
+        shared_w_gate=dense((dm, sf)),
+        shared_w_up=dense((dm, sf)),
+        shared_w_down=dense((sf, dm)))
+
+
+def feed_forward(model, l, lw, x, attend):
+    """``x`` plus layer ``l``'s feed-forward of it: dense in the leading
+    layers, else the held experts' scaled part beside the shared
+    expert."""
+    import jax
+
+    h = rms_norm(x, lw["norm2"], model.rms_eps)
+    if l < model.dense_layers:
+        with jax.named_scope(DENSE_SCOPE):
+            return x + _swiglu(h, lw, "ffn")
+    local = route_share(h, lw, attend, model.top_k, model.held_experts)
+    routed = share_ffn(model, h, lw, local, attend)
+    with jax.named_scope(SHARED_SCOPE):
+        return x + model.routed_scale * routed + _swiglu(h, lw, "shared")
+
+
+def head_logits(model, w, x, attend):
+    """The logits of ``x``'s rows, or of a prompt's read row alone."""
+    import jax
+
+    if attend.prompt and attend.read_row is not None:
+        # the one row of a prompt whose logits are read: the head
+        # over every row would be a seventh of a prefill's matmuls
+        # and 0.67 GB of float32 nobody reads
+        x = jax.lax.dynamic_slice_in_dim(x, attend.read_row, 1, axis=0)
+    return _mm(rms_norm(x, w["norm_f"], model.rms_eps), w["lm_head"])
 
 
 def _swiglu(h, lw, name):

@@ -1310,3 +1310,138 @@ def test_lfm2_width_programs_compile(one_chip, program):
     assert ma.temp_size_in_bytes < 1536 << 20
     pools_and_tails = 2 * 20609 * 16 * 512 * 2 + 2 * 128 * 2 * 2048 * 4
     assert ma.alias_size_in_bytes >= pools_and_tails
+
+
+# -- Kimi-Linear: state slabs BESIDE one pool of latent rows ----------------
+
+def _linear_latent_engine():
+    """Kimi-Linear's mixer widths behind the engine at the cell's serving
+    sizes (128 slots, 6,144 positions, bf16 latent pages): one recurrent
+    layer under the dense feed-forward and one latent layer over 32 held
+    experts of the published width, a short vocabulary and a narrow
+    dense layer: only shapes matter to a compile, and these are the ones
+    the chip's compiler could refuse; 5,121 pages make the pool 105 MB,
+    past what is prefetched whole into fast memory (a state tuple of ONE pool and then
+    slabs, 32 stacked query rows on rows of 576 lanes in a pool of 640,
+    32 ungrouped heads of 192 over a 4,096-row prompt beside the state
+    kernel's loop, the grouped experts at a width of 2,304)."""
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+    from paddle_tpu.serving.linear_latent_lm import LinearLatentLM
+
+    model = LinearLatentLM(
+        vocab_size=768, d_model=2304,
+        layer_kinds=("recurrent", "attention"), dense_layers=1,
+        lin_heads=32, lin_head_dim=128, conv_kernel=4, gate_rank=128,
+        num_heads=32, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+        dense_dim=2048, num_experts=256, top_k=8, held_experts=range(32),
+        expert_dim=1024, shared_dim=1024, routed_scale=2.446)
+    weights = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
+    return DecodeEngine(model, weights, DecodeConfig(
+        slots=128, max_seq_len=6144, num_pages=128 * 40 + 1,
+        use_pallas="always", cache_dtype="bfloat16"))
+
+
+@pytest.mark.parametrize("program", ["step", "prefill_4096"])
+def test_kimi_linear_width_programs_compile(one_chip, program):
+    """The joint step (the state kernel over the 128 slots' slab, which
+    is operand 1 of the program: the benchmark's NEW pattern finds it and
+    the accepted one, which counts slabs from operand 2, does not; the
+    latent kernel by its own name at 32 rows of the one head, one pool
+    in and no V pool anywhere; 128 rows over 32 of 256 experts keep the
+    dense form) and the 4,096-row whole-prompt prefill (the state
+    kernel in the loop over chunks of 64, the flash kernel over 32
+    ungrouped heads of K 192 / V 128, the two grouped-expert kernels at
+    a width of 2,304; the latents, not the expanded K/V, to the pages).
+    The pool and both slabs go in and come out where they lie."""
+    from paddle_tpu.ops import pallas_kda_update as kda
+    from paddle_tpu.ops import pallas_moe_grouped as grouped
+    from paddle_tpu.ops import pallas_prompt_attention as ppa
+
+    eng = _linear_latent_engine()
+    cc = eng._cache.config
+    assert (cc.latent, cc.num_layers, cc.row_lanes, cc.v_row_lanes) == (
+        True, 1, 640, 0)
+    assert eng._state_vars == ("__decode_k_pages__",) \
+        + eng._cache.recurrent_var_names()
+    assert eng._attn_block == 1024
+    state = [tuple(eng._scope.get_var(n).shape) for n in eng._state_vars]
+    assert state == [(1, 5121, 16, 640), (128, 32, 128, 128),
+                     (128, 3 * 3 * 4096)]
+    slab = "f32[128,32,128,128]"
+    if program == "step":
+        compiled = eng.lower_step(sharding=one_chip).compile()
+        text = compiled.as_text()
+        entry = _computation(text, "ENTRY ")
+        latent = [ln.strip() for ln in entry if re.match(
+            r"\s*%" + pda.LATENT_KERNEL_NAME + r"[.\d]* = ", ln)]
+        update = [ln.strip() for ln in entry if re.match(
+            r"\s*%" + kda.KERNEL_NAME + r"[.\d]* = ", ln)]
+        assert len(latent) == len(update) == 1 \
+            and text.count("tpu_custom_call") == 2
+        assert latent[0].startswith(
+            "%s = f32[128,32,512]" % latent[0].split(" = ")[0]) \
+            and "f32[128,32,640]" in latent[0] \
+            and latent[0].count("bf16[1,5121,16,640]") == 1
+        assert _metric_pattern("latent_step_ms.serve").search(latent[0])
+        # the slab reaches the kernel as the program's own operand 1
+        new = _metric_pattern("kda_step_ms.serve")
+        assert _metric_pattern("kda_step_roofline").pattern == new.pattern
+        body = update[0].split(" = ", 1)[-1]
+        assert slab in update[0] and re.search(r"%state_1_\.\d+[,)]", body)
+        assert new.search(body)
+        assert not _metric_pattern("kda_ms_per_step.serve").search(body)
+        assert not new.search(latent[0].split(" = ", 1)[-1])
+        assert not new.search("%x = f32[1] fusion(%state_0_.1)")
+        assert ppa.KERNEL_NAME not in text
+        assert grouped.GATE_UP_KERNEL_NAME not in text
+    else:
+        assert eng._prefill_walks(4096) == [(1, None, ("flash", 1024, 1024))]
+        assert eng.model.prefill_chunks_per_call(4096) == 1
+        compiled = eng.lower_prefill(4096, sharding=one_chip).compile()
+        text = compiled.as_text()
+        # the state kernel in its loop, the flash kernel, the expert
+        # layer's two kernels
+        assert text.count("tpu_custom_call") == 4
+        assert text.count(f"%{kda.KERNEL_NAME}") >= 1
+        assert grouped.GATE_UP_KERNEL_NAME in text
+        assert grouped.DOWN_KERNEL_NAME in text
+        calls = _assert_prefill_holds_the_flash_kernel(text, 4096)
+        assert len(calls) == 1
+        assert calls[0].count("bf16[32,4096,192]") == 2 \
+            and "bf16[32,4096,128]" in calls[0], calls
+        assert pda.LATENT_KERNEL_NAME not in text
+        # the loop the prompt's recurrence runs in is what
+        # ``kda_prefill_*`` time: its carry holds one slot's state
+        loops = _metric_pattern("kda_prefill_ms.serve")
+        assert _metric_pattern("kda_prefill_roofline").pattern \
+            == loops.pattern
+        found = [ln for ln in text.splitlines() if loops.search(ln.strip())]
+        assert len(found) == 1 and "f32[1,32,128,128]" in found[0]
+        # the head runs over the one row that is read, not the bucket
+        assert "f32[1,768]" in text and "f32[4096,768]" not in text
+    # no rotary work anywhere: ``mla_use_nope``
+    assert " sine(" not in text and " cosine(" not in text
+    # the one pool and both slabs are the program's first parameters
+    # and last results, aliased, in one device layout; nothing the size
+    # of the pool, of its layer or of the matrices' slab is copied or
+    # turned (the tails' 18 MB are prefetched whole into fast memory,
+    # which is no re-layout)
+    n = len(state)
+    ins = jax.tree_util.tree_leaves(compiled.input_formats)
+    outs = jax.tree_util.tree_leaves(compiled.output_formats)
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    pairs = {(int(o), int(i)) for o, i in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", alias[1])}
+    assert {(len(outs) - n + i, i) for i in range(n)} <= pairs
+    for i in range(n):
+        assert ins[i].layout == outs[len(outs) - n + i].layout, (i, state[i])
+    held = {state[0], state[0][1:], state[1]}
+    for line in _computation(text, "ENTRY "):
+        m = _INSTR.match(line)
+        if m and m["op"] in ("copy", "transpose", "copy-start"):
+            dims = {tuple(int(d) for d in a.split(",") if d)
+                    for a in _ARRAY.findall(m["type"])}
+            assert not dims & held, line[:160]
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
