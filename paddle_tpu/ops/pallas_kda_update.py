@@ -17,10 +17,24 @@ into VMEM, carries them through the call's ``T`` tokens there, and
 stores them once, in place (the state is aliased to the result).  Two
 grids over it: the decode step is ``T = 1`` over ``R`` slots (grid
 ``(slot, head block)``: the slab streams through at the rate of the
-copies, the arithmetic under them), the whole-prompt prefill ``R = 1``
-over a chunk's ``T`` tokens (grid ``(1, head block)``; the loop stops at
-the row's ``n_real``, so padding never touches the state).  A row with
-``n_real == 0`` (a dead slot of the step) is written back as read.
+copies, the arithmetic under them), and ``R = 1`` over ``T`` consecutive
+tokens of one row (grid ``(1, head block)``; the loop stops at the row's
+``n_real``, so padding never touches the state).  A row with ``n_real ==
+0`` (a dead slot of the step) is written back as read.
+
+WHO CALLS WHICH.  The step of every model that takes its recurrent
+layers from ``hybrid_moe_lm.KDAMixer`` (``_kda_token``: Solar-Open2's
+and Kimi-Linear's) runs the ``T = 1`` grid.  The ``R = 1`` grid over a
+chunk's tokens served those models' whole-prompt prefill until PR 58 and
+serves NO model since: a prompt's tokens each paid the transposes below,
+one after another, and a prompt now runs the rule's chunk (WY) form on
+the matrix unit instead (``ops/pallas_kda_chunk.py``, which shares
+``kda_rule`` and the VMEM limit with this file and nothing else).  The
+grid is kept as the token rule over several tokens, which is what
+``tests/test_pallas_kda_update.py`` (several tokens a call against the
+XLA form), ``tests/test_tpu_compile.py`` (64 tokens of 64 heads for the
+described chip) and ``tools/sweep_kda_chunk.py`` (the form that was,
+beside the form that is) call it as: they keep it honest.
 
 All float32 on the vector unit, the token rule's own products and sums
 (only the order of a sum over ``d_k`` may differ from XLA's); nothing

@@ -842,10 +842,13 @@ class DecodeEngine:
     request's and may touch the state; it then runs once a chunk, in
     ONE loop a layer that carries the state and holds all of the call.
     ``chunk`` is what ONE CALL covers; a model whose rule has a chunk of
-    its own and whose call takes a group of them (what does not read the
-    state formed for the whole group, the state passed through its
-    chunks in turn: ``serving/gated_delta_lm.py``) says how many as
-    ``chunks_per_call``, and declares ``prefill_chunks_per_call(rows)``,
+    its own and whose call takes a group of them says how many as
+    ``chunks_per_call`` (``serving/gated_delta_lm.py``: what does not
+    read the state formed for the whole group as XLA operations, the
+    state passed through its chunks in turn; ``serving/hybrid_moe_lm.py``
+    ``KDAMixer``: the group's vectors formed at once and ONE kernel call
+    that keeps the state in fast memory through the group's chunks),
+    and declares ``prefill_chunks_per_call(rows)``,
     the same number for a prompt bucket of ``rows`` (0: it hands no
     chunk form; gauge ``decode_prefill_chunks_per_call``, at the largest
     bucket).  Counters ``decode_prefill_scan_steps`` /

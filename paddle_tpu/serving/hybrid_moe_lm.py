@@ -28,9 +28,17 @@ reads and writes each once, in place; anything else (every toy width)
 through the XLA lines of ``_kda_rule_xla``, which are also the tests'
 oracle.  In either form the one-token update takes ``live`` and owns
 the dead rows, so the engine's step passes over no slab itself (with
-the kernel, nothing but the kernel does); with the kernel the
-whole-prompt prefill also gets a chunk function: ``PREFILL_CHUNK``
-tokens a kernel call over a state that stays in VMEM between them.
+the kernel, nothing but the kernel does).  Where the kernel takes the
+state a whole PROMPT does not go through the token rule at all: the
+prefill gets a chunk function, ``_kda_chunk``, the rule's chunk (WY)
+form on the matrix unit (``ops/pallas_kda_chunk.py``: ``PREFILL_CHUNK``
+tokens at a time, the decay ratios a channel from differences of summed
+LOG decays that are never above 0, every product float32 at
+``highest``), a group of chunks a call over a state that stays in VMEM
+between them; the group is ``prefill_chunks_per_call``'s, from the
+bucket's rows and the model's widths alone.  It is the ONE prompt form:
+a shape the kernels do not take (every toy width) hands the engine no
+chunk function and its prompt goes token by token through the XLA lines.
 
 Precision as served: weights (and K/V pages) in ``dtype`` (bfloat16),
 every matmul accumulating in float32; the residual stream, norms, router
@@ -45,13 +53,20 @@ from typing import Sequence
 import numpy as np
 
 from ..ops import moe_ops
+from ..ops import pallas_kda_chunk as kda_chunk
 from ..ops import pallas_kda_update as kda
 
 KDA_SCOPE = "kda_update"
-# tokens a kernel call of the whole-prompt prefill (the state passes
-# through HBM once a chunk): PERF.md section 5 has what 64, 256 and a
-# whole bucket read on the chip
-PREFILL_CHUNK = 64
+# tokens of one chunk of the rule's WY form, what the whole-prompt
+# prefill's scan counts as a step
+PREFILL_CHUNK = kda_chunk.CHUNK
+# float32 bytes of ONE call's temporaries the group of chunks is cut to
+# (the state passes through HBM once a call, the call's vectors are
+# formed at once): 256 tokens a call at 32 heads, 128 at 64.  PERF.md
+# section 6 (PR 58) has what groups of 1 to a whole bucket read on the
+# chip: they differ by 2-3 %, a call of a whole 256-row bucket at 64
+# heads by 18 %
+GROUP_BYTES = 32 << 20
 
 
 def step_tallies(model, rows):
@@ -135,36 +150,52 @@ class KDAMixer:
 
     def _recur(self, l, lw, rows, cache, attend):
         """Recurrent layer ``l`` over the rows' projections -> (``o``,
-        cache).  Where the kernel takes the state's shape a whole-prompt
-        prefill runs ``PREFILL_CHUNK`` tokens a call through
-        ``_kda_chunk``, and a step counts the rows it updated."""
+        cache).  Where the kernels take the state's shape a whole-prompt
+        prefill runs ``prefill_chunks_per_call`` of the rule's chunks a
+        call through ``_kda_chunk`` (the chunk form; the engine's loop
+        holds all of it), and a step, which runs the token rule through
+        ``_kda_token`` whatever else is handed over, counts the rows it
+        updated."""
         import jax.numpy as jnp
 
         token = functools.partial(self._kda_token, lw,
                                   interpret=attend.interpret)
-        if not self.prefill_chunks_per_call(PREFILL_CHUNK):
+        group = self.prefill_chunks_per_call(rows["u"].shape[0])
+        if not group:
             return attend.recur(l, token, rows, cache)
         if not attend.prompt:
             attend.tally("kda_kernel_rows",
                          jnp.sum(attend.live, dtype=jnp.int32))
         return attend.recur(
-            l, token, rows, cache, chunk=PREFILL_CHUNK,
+            l, token, rows, cache, chunk=group * PREFILL_CHUNK,
             chunk_fn=functools.partial(self._kda_chunk, lw,
-                                       interpret=attend.interpret))
+                                       interpret=attend.interpret),
+            chunks_per_call=group)
 
     def prefill_chunks_per_call(self, rows):
-        """One ``PREFILL_CHUNK`` a call of ``_kda_chunk`` whatever the
-        bucket, where the kernel takes the state; else no chunk form."""
+        """Chunks of the rule's WY form (``PREFILL_CHUNK`` tokens) ONE
+        call of ``_kda_chunk`` takes of a prompt bucket of ``rows``
+        rows, where the kernels take the state: all of them, up to what
+        ``GROUP_BYTES`` of the call's float32 temporaries allow (a
+        token's: the convolved rows, q, k and v, the log decay and the
+        output, eight rows of all heads' lanes); else 0, no chunk form.
+        A function of the bucket and the model's widths alone."""
         shape, dtype = self.recurrent_state["s"]
-        return int(bool(kda.kda_rule(*shape, dtype)))
+        if not kda.kda_rule(*shape, dtype):
+            return 0
+        a_chunk = 4 * PREFILL_CHUNK * 8 * self.lin_heads * self.lin_head_dim
+        return max(1, min(-(-int(rows) // PREFILL_CHUNK),
+                          GROUP_BYTES // a_chunk))
 
-    def _kda_vectors(self, lw, conv, gate, beta):
+    def _kda_vectors(self, lw, conv, gate, beta, log_decay=False):
         """What the rule takes of ``N`` tokens, from their convolved
         rows ``conv [N, 3C]`` and the ``gate [N, C]`` and ``beta [N,
         heads]`` projections -> (q, k, v, decay ``[N, heads, dk]``, beta
         ``[N, heads]``): q and k at unit length a head, q scaled by
-        ``dk^-1/2``; the decay a channel in (0, 1); beta in (0,
-        ``beta_scale``)."""
+        ``dk^-1/2``; the decay a channel in (0, 1), or with
+        ``log_decay`` its logarithm as it is formed (what the chunk
+        form sums: never above 0, and there where the factor itself
+        has underflowed); beta in (0, ``beta_scale``)."""
         import jax
         import jax.numpy as jnp
 
@@ -174,9 +205,10 @@ class KDAMixer:
         q = q * jax.lax.rsqrt(
             jnp.sum(q * q, -1, keepdims=True) + 1e-6) / math.sqrt(dk)
         k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
-        decay = jnp.exp(
-            -jnp.exp(lw["kda_a_log"])[:, None] * jax.nn.softplus(
-                gate + lw["kda_dt_bias"]).reshape(-1, nh, dk))
+        decay = -jnp.exp(lw["kda_a_log"])[:, None] * jax.nn.softplus(
+            gate + lw["kda_dt_bias"]).reshape(-1, nh, dk)
+        if not log_decay:
+            decay = jnp.exp(decay)
         return q, k, v, decay, self.beta_scale * jax.nn.sigmoid(beta)
 
     def _kda_token(self, lw, rows, state, live=None, interpret=False):
@@ -220,16 +252,22 @@ class KDAMixer:
         return o, {"s": s, "tail": tail}
 
     def _kda_chunk(self, lw, rows, n_real, state, interpret=False):
-        """``chunk`` consecutive tokens of ONE request through a
-        recurrent layer in one kernel call: ``rows`` their projections
-        (``u [C, 3C]``, ``gate``, ``beta``), of which the first
-        ``n_real`` are the request's (the kernel's token loop stops
-        there: padding touches neither the matrices nor the tail),
-        ``state`` the request's before the chunk (leading dimension 1)
-        -> (``o [C, heads, dv]``, zero past ``n_real``; the state after
-        token ``n_real - 1``).  The convolution, the norms, the decay
-        and beta are the token form's, over all the chunk's rows at
-        once."""
+        """A GROUP of whole ``PREFILL_CHUNK``-token chunks, consecutive
+        tokens of ONE request, through a recurrent layer in one call of
+        the chunk kernel (``ops/pallas_kda_chunk.py``: the rule's WY
+        form on the matrix unit, a chunk at a time over a state that
+        stays in VMEM; the step's kernel is the token rule itself and
+        this calls it nowhere): ``rows`` their projections (``u [N,
+        3C]``, ``gate``, ``beta``), of which the first ``n_real`` are
+        the request's (the kernel masks the rest, ``beta = 0`` and ``g =
+        0``, and skips chunks of nothing else: padding touches neither
+        the matrices nor the tail), ``state`` the request's before them
+        (leading dimension 1) -> (``o [N, heads, dv]``, zero past
+        ``n_real``; the state after token ``n_real - 1``).  The
+        convolution, the norms and beta are the token form's, over all
+        the call's rows at once; the decay is handed over as its
+        LOGARITHM, as ``_kda_vectors`` forms it, and every product of
+        the form has float32 operands at ``highest``."""
         import jax
         import jax.numpy as jnp
 
@@ -240,10 +278,10 @@ class KDAMixer:
                  rows["u"]])
             conv = sum(window[j:j + c] * lw["kda_conv"][j]
                        for j in range(self.conv_kernel))
-            q, k, v, decay, beta = self._kda_vectors(
-                lw, conv, rows["gate"], rows["beta"])
-            o, s = kda.kda_update(
-                q[None], k[None], decay[None], v[None], beta[None],
+            q, k, v, log_decay, beta = self._kda_vectors(
+                lw, conv, rows["gate"], rows["beta"], log_decay=True)
+            o, s = kda_chunk.kda_chunk(
+                q[None], k[None], log_decay[None], v[None], beta[None],
                 state["s"], jnp.reshape(n_real, (1,)), interpret=interpret)
             tail = jax.lax.dynamic_slice_in_dim(
                 window, n_real, self.conv_kernel - 1)

@@ -13,8 +13,10 @@ copy in ``tests/``), which this file is tested against and shares no
 code with.
 
 Nothing of either mixer is written here.  The recurrent layers are
-``hybrid_moe_lm.KDAMixer``'s (the rule's token and chunk forms through
-``ops/pallas_kda_update.py``) with the step's range ``beta_scale`` 1:
+``hybrid_moe_lm.KDAMixer``'s (the rule's token form in the step,
+``ops/pallas_kda_update.py``, and its chunk (WY) form on the matrix unit
+over a whole prompt, ``ops/pallas_kda_chunk.py``, a group of 64-token
+chunks a call) with the step's range ``beta_scale`` 1:
 the published config carries no ``kda_allow_neg_eigval``.  The latent
 layers are ``latent_moe_lm.LatentMixer``'s two forms of one attention
 (absorbed in the step, expanded in a prompt) with the queries straight

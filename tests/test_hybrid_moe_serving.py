@@ -2,6 +2,7 @@
 experts (``serving/hybrid_moe_lm.py``) behind the real ``DecodeEngine``,
 against the plain reference (``benchmark/reference/hybrid_moe_lm.py``, the
 one the cell's check uses): float32, seeded, tiny."""
+import functools
 import re
 
 import jax
@@ -94,9 +95,10 @@ def test_paged_kernel_serves_the_grouped_heads_in_interpret_mode():
         assert served_vs_reference(eng, model, weights, prompts, 4) < 5e-5
 
 
-# linear heads of whole lane tiles in whole sublane tiles: the state
-# update is the kernel's (``ops/pallas_kda_update.py`` ``kda_rule``), in
-# the step and, a chunk of tokens a call, in the prefill; interpreted
+# linear heads of whole lane tiles in whole sublane tiles (``kda_rule``):
+# the step's state update is the token rule's kernel
+# (``ops/pallas_kda_update.py``), the prefill's the chunk form's
+# (``ops/pallas_kda_chunk.py``, a group of chunks a call); interpreted
 WIDTHS = {"toy": ({}, {}),
           "kernel": (dict(lin_heads=8, lin_head_dim=128),
                      dict(interpret=True))}
@@ -161,8 +163,8 @@ def _state_after_prefill(model, weights, prompt, page_size, **cfg):
 def test_padding_rows_leave_the_state_alone(widths, short_chunks):
     """The same 9-token prompt prefilled in a bucket of 16 and in one of
     32: what the slot's rows hold is the state after token 9, however
-    many padding rows followed it (through the kernel: one chunk whose
-    token loop stops at 9, and a second chunk that never runs)."""
+    many padding rows followed it (through the chunk kernel: one call
+    whose rows past 9 are masked, in a bucket of 16 and in one of 32)."""
     sizes, cfg = WIDTHS[widths]
     model = make_model(("recurrent", "attention"), **sizes)
     weights = model.init_weights(jax.random.PRNGKey(7))
@@ -555,37 +557,230 @@ def test_a_step_that_keeps_the_dense_form_is_the_program_it_was(which):
     assert hashlib.sha256(text.encode()).hexdigest() == digest
 
 
-# sha256 of the 64-row whole-prompt prefill's lowered text at the two
-# widths (``make_model()``, 3 slots), taken on the commit before the
-# engine's ``recur`` learned how many of a rule's chunks a call covers
-# (PR 53), and what the gauge of that says of each model
+# sha256 of the 64-row whole-prompt prefill's lowered text (``make_model()``,
+# 3 slots) and what the gauge says of the model.  The toy widths': taken
+# on the commit before the engine's ``recur`` learned how many of a
+# rule's chunks a call covers (PR 53): no chunk form then or now.  The
+# kernel widths': the chunk form interpreted (PR 58; until then a call
+# walked ``PREFILL_CHUNK`` tokens through the token rule's kernel)
 PREFILLS_AS_LOWERED = {
     "toy": ("c434fdc64b206e6b06a262e37f7741e65d1b9e0e74b9f7c4ab155db008902a55",
             0),
     "kernel": (
-        "2479ab1e039ef93df77c52d6f60c5c62044e8d9d8fc1c5b02d9329543d86c4a6", 1),
+        "03d9892b13bcd66b93172ca0caa8e0e707e18c62095e4cf70b9944d4d097ec95", 1),
 }
 
 
 @widths
-def test_a_call_that_covers_one_chunk_is_the_prefill_it_was(widths):
+def test_a_prefills_calls_cover_the_buckets_chunks(widths):
     """``GatedDeltaLM`` hands ``attend.recur`` a group of its rule's
-    chunks a call (PR 53); this model's kernel call covers ONE chunk of
-    ``PREFILL_CHUNK`` tokens (at toy widths it hands no chunk form at
-    all), says so (gauge ``decode_prefill_chunks_per_call``), and its
-    prefill lowers to the text it had: the loop, its trip count and the
-    scan's two counters are untouched."""
+    chunks a call (PR 53).  At toy widths this model hands no chunk
+    form at all, says so (gauge ``decode_prefill_chunks_per_call``), and
+    its prefill lowers to the text it had.  Where the kernels take the
+    state a call covers the bucket's chunks of the rule's WY form
+    (``PREFILL_CHUNK`` tokens) up to what ``GROUP_BYTES`` of its
+    temporaries allow (PR 58): a function of the bucket and the widths
+    alone, the same number on the gauge; lowered for the chip the
+    prefill holds ONE call of the chunk kernel a recurrent layer, inside
+    the engine's loop, and no call of the step's."""
     import hashlib
+    from paddle_tpu.ops import pallas_kda_chunk as chunked
+    from paddle_tpu.ops import pallas_kda_update as kda
 
     sizes, cfg = WIDTHS[widths]
     digest, per_call = PREFILLS_AS_LOWERED[widths]
     model = make_model(**sizes)
-    assert model.prefill_chunks_per_call(64) == per_call \
-        == model.prefill_chunks_per_call(4096)
+    assert model.prefill_chunks_per_call(64) == per_call
     eng = engine(model, model.init_weights(jax.random.PRNGKey(1)), **cfg)
-    assert stat_get("decode_prefill_chunks_per_call") == per_call
+    assert stat_get("decode_prefill_chunks_per_call") \
+        == model.prefill_chunks_per_call(eng.config.max_seq_len) == per_call
     text = eng.lower_prefill(64).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == digest
+    if widths == "toy":
+        assert model.prefill_chunks_per_call(4096) == 0
+        return
+    assert hybrid.PREFILL_CHUNK == chunked.CHUNK == 64
+    # 8 heads of 128: 2 MiB of temporaries a chunk, 16 chunks under the cap
+    assert [model.prefill_chunks_per_call(r)
+            for r in (8, 64, 72, 128, 256, 2048, 4096)] \
+        == [1, 1, 2, 2, 4, 16, 16]
+    # the two cells' widths: Kimi-Linear's 32 heads, Solar's 64
+    for heads, want in ((32, [4]), (64, [2, 2, 2])):
+        wide = make_model(lin_heads=heads, lin_head_dim=128)
+        assert [wide.prefill_chunks_per_call(r)
+                for r in ((4096,) if heads == 32 else (256, 512, 1024))] \
+            == want
+    # lowered for the chip, the kernel not interpreted
+    eng = engine(model, eng.weights, use_pallas="always")
+    args = (tuple(eng._scope.get_var(n) for n in eng._state_vars),
+            eng.weights, eng._prefill_args(64, (0,)))
+    text = eng._prefill_fn(64).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("@tpu_custom_call") == 1 \
+        and chunked.KERNEL_NAME in text and kda.KERNEL_NAME not in text
+
+
+# -- the chunk form against the token form, with no engine -----------------
+def recurrent_case(model, n, seed, log_decays=None, beta=None):
+    """(a recurrent layer's weights, ``n`` rows of its projections, one
+    request's non-zero state before them).  ``log_decays``: the tokens'
+    log decay a channel takes these values in turn over the channels
+    (the decay's weights set to rate 1 and bias 0, the gate's rows to
+    ``softplus^-1`` of each); ``beta``: the same number in every row of
+    that projection."""
+    lw = dict(model.init_weights(jax.random.PRNGKey(seed))["layers"][
+        model.layer_kinds.index("recurrent")])
+    rng = np.random.RandomState(seed)
+    c = model.lin_heads * model.lin_head_dim
+    rows = {"u": rng.randn(n, 3 * c), "gate": rng.randn(n, c),
+            "beta": rng.randn(n, model.lin_heads) * 3}
+    if log_decays is not None:
+        lw["kda_a_log"] = jnp.zeros_like(lw["kda_a_log"])
+        lw["kda_dt_bias"] = jnp.zeros_like(lw["kda_dt_bias"])
+        with np.errstate(divide="ignore"):
+            gate = np.log(np.expm1(-np.asarray(log_decays, np.float64)))
+        rows["gate"] = np.broadcast_to(np.resize(np.maximum(gate, -100.0),
+                                                 c), (n, c))
+    if beta is not None:
+        rows["beta"] = np.full_like(rows["beta"], beta)
+    state = {name: jnp.asarray(rng.randn(1, *shape) * 0.5, dtype)
+             for name, (shape, dtype) in model.recurrent_state.items()}
+    return lw, {k: jnp.asarray(v, jnp.float32) for k, v in rows.items()}, \
+        state
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted(model, form):
+    """``model``'s chunk function (the kernel interpreted) or its
+    one-token update, jitted once a model: the cases share a compile.
+    The token form is only ever traced by ``token_form``, which has
+    turned the kernels' rule off by then."""
+    if form == "chunk":
+        return jax.jit(lambda lw, rows, n_real, state: model._kda_chunk(
+            lw, rows, n_real, state, interpret=True))
+    return jax.jit(lambda lw, rows, state: model._kda_token(lw, rows, state))
+
+
+@functools.lru_cache(maxsize=None)
+def kernel_model():
+    return make_model(**WIDTHS["kernel"][0])
+
+
+def chunk_form(model, lw, rows, state, length, group):
+    """The first ``length`` of ``rows`` as the engine's prefill ``recur``
+    hands them to ``_kda_chunk``: ``group`` chunks a call, the last call
+    told how many of its rows are real -> (``o`` of all rows, the state
+    after token ``length - 1``)."""
+    per_call = group * hybrid.PREFILL_CHUNK
+    call = _jitted(model, "chunk")
+    outs = []
+    for lo in range(0, max(length, 1), per_call):
+        o, state = call(lw, {k: v[lo:lo + per_call] for k, v in rows.items()},
+                        jnp.int32(min(length - lo, per_call)), state)
+        outs.append(o)
+    return jnp.concatenate(outs), state
+
+
+def token_form(model, lw, rows, state, length, monkeypatch):
+    """The same rows one after another through the one-token update's
+    XLA lines (``_kda_rule_xla``: the decay as a FACTOR, no chunk)."""
+    monkeypatch.setattr(hybrid.kda, "kda_rule", lambda *a: False)
+    step = _jitted(model, "token")
+    outs = []
+    for t in range(length):
+        o, state = step(lw, {k: v[t:t + 1] for k, v in rows.items()}, state)
+        outs.append(o)
+    return jnp.concatenate(outs) if outs else None, state
+
+
+def assert_the_chunk_form_is_the_token_form(model, case, length, group,
+                                            monkeypatch, rows_run=None):
+    """Outputs, matrices and tail to 1e-5, every value finite, rows past
+    ``length`` zero."""
+    lw, rows, state = case
+    rows_run = rows_run or -(-max(length, 1) // (
+        group * hybrid.PREFILL_CHUNK)) * group * hybrid.PREFILL_CHUNK
+    rows = {k: v[:rows_run] for k, v in rows.items()}
+    o, new = chunk_form(model, lw, rows, state, length, group)
+    want_o, want = token_form(model, lw, rows, state, length, monkeypatch)
+    assert o.shape == (rows_run, model.lin_heads, model.lin_head_dim)
+    assert np.isfinite(np.asarray(o)).all() and not np.asarray(
+        o[length:]).any()
+    if length:
+        np.testing.assert_allclose(o[:length], want_o, atol=1e-5)
+    for name in state:
+        assert np.isfinite(np.asarray(new[name])).all()
+        np.testing.assert_allclose(new[name], want[name], atol=1e-5)
+    return new
+
+
+GROUP = 2       # chunks a call in the tests below: a call is 128 rows
+LENGTHS = {"one_token": 1, "one_short_of_a_chunk": 63, "a_chunk": 64,
+           "a_chunk_and_one": 65, "one_short_of_a_group": 127,
+           "several_groups": 2 * 128 + 37}
+
+
+@pytest.mark.parametrize("length", list(LENGTHS))
+def test_the_chunk_form_is_the_token_form(length, monkeypatch):
+    """``_kda_chunk`` (the rule's WY form on the matrix unit, a group of
+    chunks a call) against the token recurrence from a non-zero state,
+    beta in (0, 2) as Solar's config has it: a prompt of one token, of a
+    chunk less one, of a chunk, of a chunk and one, of a group less one,
+    and one that needs three calls and ends inside a chunk."""
+    model = kernel_model()
+    assert model.beta_scale == 2.0
+    n = LENGTHS[length]
+    assert_the_chunk_form_is_the_token_form(
+        model, recurrent_case(model, 3 * 128, 31), n, GROUP, monkeypatch)
+
+
+@pytest.mark.parametrize("decays", [
+    (0.0,), (-1e-3,), (-40.0,), (0.0, -1e-3, -40.0)],
+    ids=["none", "1e-3", "40", "side_by_side"])
+def test_no_decay_overflows_or_divides(decays, monkeypatch):
+    """Log decays of 0, of -1e-3 and of -40 a token, alone and side by
+    side in the channels of one chunk (64 tokens of -40 sum to -2,560:
+    ``e^{-G}`` is ``inf`` after three): nothing is ``inf`` or ``nan`` and
+    the result is the recurrence's."""
+    model = kernel_model()
+    case = recurrent_case(model, 128, 32, log_decays=decays)
+    lw, rows, _ = case
+    got = model._kda_vectors(lw, rows["u"], rows["gate"], rows["beta"],
+                             log_decay=True)[3]
+    np.testing.assert_allclose(np.unique(np.asarray(got)),
+                               sorted(set(decays)), rtol=1e-5, atol=1e-30)
+    assert_the_chunk_form_is_the_token_form(model, case, 100, GROUP,
+                                            monkeypatch)
+
+
+@pytest.mark.parametrize("beta", [-1e9, 1e9], ids=["beta0", "beta_scale"])
+def test_the_write_strengths_ends_hold(beta, monkeypatch):
+    """beta 0 (nothing is written: the state only decays) and beta at
+    the top of its range (2: ``I - beta k k^T`` reflects)."""
+    model = kernel_model()
+    case = recurrent_case(model, 128, 33, beta=beta)
+    assert float(model.beta_scale * jax.nn.sigmoid(case[1]["beta"]).max()) \
+        == (0.0 if beta < 0 else 2.0)
+    assert_the_chunk_form_is_the_token_form(model, case, 90, GROUP,
+                                            monkeypatch)
+
+
+@pytest.mark.parametrize("length", [0, 40], ids=["all_padding", "padded"])
+def test_padding_leaves_state_and_tail_as_they_were(length, monkeypatch):
+    """A call of 128 rows of which 40 are the request's, or none: the
+    state and the tail are what token 40 left (what came in, where no
+    row is real: bit for bit), whatever the padding rows hold."""
+    model = kernel_model()
+    lw, rows, state = recurrent_case(model, 128, 34)
+    new = assert_the_chunk_form_is_the_token_form(
+        model, (lw, rows, state), length, GROUP, monkeypatch, rows_run=128)
+    loud = {k: v.at[length:].set(1e4) for k, v in rows.items()}
+    _, again = chunk_form(model, lw, loud, state, length, GROUP)
+    for name in state:
+        assert np.array_equal(np.asarray(again[name]), np.asarray(new[name]))
+        if not length:
+            assert np.array_equal(np.asarray(new[name]),
+                                  np.asarray(state[name]))
 
 
 def _function(text, name):

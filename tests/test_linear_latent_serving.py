@@ -21,6 +21,7 @@ from paddle_tpu.serving import hybrid_moe_lm as hybrid
 from paddle_tpu.serving.kv_cache import RecurrentSpec
 from paddle_tpu.serving.linear_latent_lm import LinearLatentLM
 
+import test_hybrid_moe_serving as solar   # the chunk form's helpers
 from benchmark.reference import linear_latent_lm as ref
 from benchmark.tests.linear_latent_controls import CONTROLS, REWEIGH
 
@@ -103,9 +104,10 @@ def short_chunks(monkeypatch):
     monkeypatch.setattr(hybrid, "PREFILL_CHUNK", 16)
 
 
-# linear heads of whole lane tiles in whole sublane tiles: the state
-# update is the kernel's (``ops/pallas_kda_update.py`` ``kda_rule``), in
-# the step and, a chunk of tokens a call, in the prefill; interpreted,
+# linear heads of whole lane tiles in whole sublane tiles (``kda_rule``):
+# the step's state update is the token rule's kernel
+# (``ops/pallas_kda_update.py``), the prefill's the chunk form's
+# (``ops/pallas_kda_chunk.py``, a group of chunks a call); interpreted,
 # as is the latent body of the paged kernel
 WIDTHS = {"jnp": ({}, {}),
           "kernels": (dict(lin_heads=8, lin_head_dim=128),
@@ -513,3 +515,78 @@ def test_the_reference_in_blocks_of_rows_is_the_reference():
     blocks, _ = ref.forward_logits(weights, seq, dims(model), rows=16)
     assert float(jnp.abs(whole).max()) > 1.0
     np.testing.assert_allclose(blocks, whole, atol=2e-5)
+
+
+# sha256 of the joint step's lowered text (``make_model()`` at each of
+# ``WIDTHS``, 3 slots; at the kernels' widths the state kernel and the
+# latent body interpreted), taken on the commit before the rule's chunk
+# form (PR 58): the prompt's form changed, the step's did not
+STEPS_AS_LOWERED = {
+    "jnp": "62caf67c6af15ca0f251f38b47af230a14f664658c51742828b4a4db6f8bd0d6",
+    "kernels":
+        "b0c4be916904525a210a51f31e542318ee1cb53341247a836044407f53acdb8f",
+}
+
+
+@pytest.mark.parametrize("widths", list(WIDTHS))
+def test_the_step_is_the_program_it_was(widths):
+    """``_kda_token`` and the token rule's kernel are not the chunk
+    form's to touch: the joint step lowers to the parent's text at the
+    toy widths (the XLA lines) and at lane-wide heads (the kernel's
+    ``T = 1`` grid over the slots), while the same engine's prefill
+    holds the chunk form and says so on the gauge."""
+    import hashlib
+
+    sizes, cfg = WIDTHS[widths]
+    model = make_model(**sizes)
+    eng = engine(model, model.init_weights(jax.random.PRNGKey(1)), **cfg)
+    text = eng.lower_step().as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == STEPS_AS_LOWERED[widths]
+    group = model.prefill_chunks_per_call(eng.config.max_seq_len)
+    assert stat_get("decode_prefill_chunks_per_call") == group \
+        == (4 if sizes else 0)
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_model():
+    return make_model(**WIDTHS["kernels"][0])
+
+
+@pytest.mark.parametrize("length", list(solar.LENGTHS))
+def test_the_chunk_form_is_the_token_form_at_beta_scale_one(
+        length, monkeypatch):
+    """``KDAMixer._kda_chunk`` as this model takes it (beta in (0, 1):
+    no ``kda_allow_neg_eigval``) against the token recurrence from a
+    non-zero state, two chunks a call: outputs, matrices and tail to
+    1e-5 (``tests/test_hybrid_moe_serving.py`` has the helpers and
+    Solar's range)."""
+    model = _kernel_model()
+    assert model.beta_scale == 1.0
+    solar.assert_the_chunk_form_is_the_token_form(
+        model, solar.recurrent_case(model, 3 * 128, 41),
+        solar.LENGTHS[length], solar.GROUP, monkeypatch)
+
+
+@pytest.mark.parametrize("case", ["decays_side_by_side", "beta0", "beta1",
+                                  "all_padding"])
+def test_the_chunk_forms_ends_hold_at_beta_scale_one(case, monkeypatch):
+    """Log decays of 0, -1e-3 and -40 a token side by side in one chunk
+    (nothing ``inf`` or ``nan``), beta at 0 and at 1, and a call of
+    nothing but padding (the state and the tail come back bit for bit)."""
+    model = _kernel_model()
+    lw, rows, state = solar.recurrent_case(
+        model, 128, 42,
+        log_decays=(0.0, -1e-3, -40.0) if case.startswith("decays") else None,
+        beta={"beta0": -1e9, "beta1": 1e9}.get(case))
+    if case.startswith("beta"):
+        assert float(model.beta_scale * jax.nn.sigmoid(
+            rows["beta"]).max()) == float(case[-1])
+    length = 0 if case == "all_padding" else 100
+    new = solar.assert_the_chunk_form_is_the_token_form(
+        model, (lw, rows, state), length, solar.GROUP, monkeypatch,
+        rows_run=128)
+    if not length:
+        for name in state:
+            assert np.array_equal(np.asarray(new[name]),
+                                  np.asarray(state[name]))

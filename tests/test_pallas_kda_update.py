@@ -1,7 +1,11 @@
-"""``ops/pallas_kda_update.py`` (the gated delta rule with a head's
-state held in VMEM) interpreted on the CPU, at lanes of 128, against
-the XLA form it stands in for (``serving/hybrid_moe_lm.py``
-``_kda_rule_xla`` and the one-token update built on it)."""
+"""``ops/pallas_kda_update.py`` (the gated delta rule's TOKEN form with
+a head's state held in VMEM) interpreted on the CPU, at lanes of 128,
+against the XLA form it stands in for (``serving/hybrid_moe_lm.py``
+``_kda_rule_xla`` and the one-token update built on it).  Its grid over
+one row's ``T > 1`` tokens served a prompt until PR 58 and serves no
+model since (the prompt's form is ``ops/pallas_kda_chunk.py``, tested in
+``tests/test_pallas_kda_chunk.py``): the cases here that call it with
+several tokens are what keeps it honest."""
 import functools
 
 import jax
@@ -105,7 +109,8 @@ def _state(model, rng, rows):
 @pytest.mark.parametrize("n_real", [12, 7, 1],
                          ids=["whole_chunk", "partial_chunk", "one_token"])
 def test_a_chunk_is_its_real_tokens_one_by_one(n_real, monkeypatch):
-    """The chunk function (the kernel's token loop) against ``n_real``
+    """The model's chunk function (the rule's chunk form, here over a
+    call of 12 rows) against ``n_real``
     calls of the one-token update in its XLA form, from a non-zero
     state: the matrices, the convolution's tail and the real rows'
     output; rows past ``n_real`` touch nothing and read zero."""

@@ -306,3 +306,42 @@ def test_the_layout_sweep_rehearses_on_the_cpu_and_times_nothing_there(
     monkeypatch.setattr(sys, "argv", ["sweep"])
     with pytest.raises(SystemExit, match="a TPU or nothing"):
         sweep.main()
+
+
+def test_the_kda_sweep_rehearses_on_the_cpu_and_times_nothing_there(
+        tmp_path, monkeypatch):
+    """``tools/sweep_kda_chunk.py`` (the numbers behind the channel-decay
+    rule's chunk form, PR 58): at ``--tiny`` sizes the form that was
+    (one token after another), the served chunk form at another
+    sub-chunk and heads a grid step, and the XLA form not taken all give
+    the outputs and the state of ``T = 1`` calls of the step's kernel,
+    and the model's own call runs in the same loop;
+    no line holds a time; the module's knobs are its own again
+    afterwards; without ``--tiny`` it wants a TPU."""
+    import json
+
+    from paddle_tpu.ops import pallas_kda_chunk as chunked
+    from tools import sweep_kda_chunk as sweep
+
+    out, knobs = tmp_path / "sweep.json", (chunked.HEADS_A_STEP, chunked.SUB)
+    monkeypatch.setattr(sys, "argv", [
+        "sweep", "--tiny", "--tokens", "128", "--heads", "4", "--subs",
+        "8", "--forms", "token,chunk,xla,layer", "--out", str(out)])
+    sweep.main()
+    with open(out) as f:
+        lines = json.load(f)["lines"]
+    assert [(x["form"], x["heads_a_step"], x["sub_chunk"]) for x in lines] \
+        == [("token", None, None), ("chunk", 4, 8), ("xla", None, None),
+            ("layer", 4, 8)]
+    # the layer form (the model's own call) runs on projections of its
+    # own: finite, compared with nothing
+    assert all(x.get("max_err", 0.0) < 2e-5 and x["finite"]
+               and "ms_a_layer" not in x and "error" not in x for x in lines)
+    assert "max_err" not in lines[-1]
+    assert not any(x["served"] for x in lines)
+    assert (chunked.HEADS_A_STEP, chunked.SUB) == knobs
+    assert sweep.served_group(sweep.SHAPES["kimi"]) == 256 \
+        and sweep.served_group(sweep.SHAPES["solar512"]) == 128
+    monkeypatch.setattr(sys, "argv", ["sweep"])
+    with pytest.raises(SystemExit, match="a TPU or nothing"):
+        sweep.main()

@@ -516,12 +516,14 @@ def test_recurrent_state_is_updated_in_place(one_chip, program):
     compiled = lowered.compile()
     text = compiled.as_text()
     # the step's paged kernel, the prefill's flash kernel, and in each
-    # the recurrent layer's state update (the prefill's in its loop
-    # over the prompt's chunks)
+    # the recurrent layer's rule: the step's is the token rule's kernel,
+    # the prompt's the chunk form's (in its loop over the prompt's calls)
+    from paddle_tpu.ops import pallas_kda_chunk as chunked
     from paddle_tpu.ops import pallas_kda_update as kda
     assert text.count("tpu_custom_call") == 2
     assert ("prompt_flash_attention" in text) == (program == "prefill")
-    assert text.count(f"%{kda.KERNEL_NAME}") >= 1
+    assert (f"%{kda.KERNEL_NAME}" in text) == (program == "step")
+    assert (f"%{chunked.KERNEL_NAME}" in text) == (program == "prefill")
     n = len(state)
     assert n == 2 + 2
     ins = jax.tree_util.tree_leaves(compiled.input_formats)
@@ -548,13 +550,14 @@ def test_recurrent_state_is_updated_in_place(one_chip, program):
 
 
 @pytest.mark.parametrize("rows, tokens", [(128, 1), (1, 64)],
-                         ids=["step", "prefill_chunk"])
+                         ids=["step", "tokens_of_one_row"])
 def test_kda_state_update_compiles_at_solar_rows(one_chip, rows, tokens):
-    """The state-update kernel at the cell's own shapes (64 heads of
-    128 x 128 float32): the step's 128 slots at one token, the
-    prefill's one request at a chunk of 64.  The state is the call's
-    operand as it lies and its second result's buffer; the blocks fit
-    the kernel's VMEM budget."""
+    """The token rule's kernel at the cell's own shapes (64 heads of
+    128 x 128 float32): the step's 128 slots at one token, and one
+    row's 64 tokens one after another (the grid a prompt ran until PR
+    58; no model calls it so any more, the kernel's own tests do).  The
+    state is the call's operand as it lies and its second result's
+    buffer; the blocks fit the kernel's VMEM budget."""
     from paddle_tpu.ops import pallas_kda_update as kda
 
     h, d = 64, 128
@@ -571,6 +574,45 @@ def test_kda_state_update_compiles_at_solar_rows(one_chip, rows, tokens):
     assert len(call) == 1
     assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(3, \{\}\)\}",
                      call[0])
+
+
+@pytest.mark.parametrize("heads, bucket", [
+    (64, 256), (64, 512), (64, 1024), (32, 4096)],
+    ids=["solar_256", "solar_512", "solar_1024", "kimi_linear_4096"])
+def test_kda_chunk_form_compiles_at_the_cells_calls(one_chip, heads, bucket):
+    """The rule's chunk form (``ops/pallas_kda_chunk.py``) at ONE call
+    of each bucket the two cells prefill: Solar's 64 heads at 256, 512
+    and 1,024 rows, Kimi-Linear's 32 at 4,096, the group
+    ``KDAMixer.prefill_chunks_per_call`` gives the bucket (its
+    temporaries under ``GROUP_BYTES``).  The vectors reach the kernel
+    lane-folded as they lie, the state is the call's operand as it lies
+    and its second result's buffer, the blocks fit the VMEM budget and
+    the compiled call's own temporaries the cap."""
+    from paddle_tpu.ops import pallas_kda_chunk as chunked
+    from paddle_tpu.serving import hybrid_moe_lm as hybrid
+    from tools.sweep_kda_chunk import mixer_of
+
+    d, f32 = 128, jnp.float32
+    group = mixer_of(dict(heads=heads, beta=1.0)).prefill_chunks_per_call(
+        bucket)
+    assert group == {64: 2, 32: 4}[heads] and bucket % (group * 64) == 0
+    tokens = group * hybrid.PREFILL_CHUNK
+    assert 4 * tokens * 8 * heads * d <= hybrid.GROUP_BYTES
+    vec = (1, tokens, heads, d)
+    args = [jax.ShapeDtypeStruct(s_, t, sharding=one_chip) for s_, t in (
+        (vec, f32), (vec, f32), (vec, f32), (vec, f32), (vec[:3], f32),
+        ((1, heads, d, d), f32), ((1,), jnp.int32))]
+    compiled = jax.jit(chunked.kda_chunk).lower(*args).compile()
+    text = compiled.as_text()
+    call = [ln for ln in text.splitlines()
+            if re.search(r"%" + chunked.KERNEL_NAME + r"[.\d]* = ", ln)
+            and "tpu_custom_call" in ln]
+    assert len(call) == 1 and text.count("tpu_custom_call") == 1
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(6, \{\}\)\}",
+                     call[0])
+    assert call[0].count(f"f32[{tokens},{heads * d}]") == 5
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= hybrid.GROUP_BYTES
 
 
 def _assert_one_kernel_passes_over_the_slab(text, slabs):
@@ -1350,11 +1392,12 @@ def test_kimi_linear_width_programs_compile(one_chip, program):
     the accepted one, which counts slabs from operand 2, does not; the
     latent kernel by its own name at 32 rows of the one head, one pool
     in and no V pool anywhere; 128 rows over 32 of 256 experts keep the
-    dense form) and the 4,096-row whole-prompt prefill (the state
-    kernel in the loop over chunks of 64, the flash kernel over 32
+    dense form) and the 4,096-row whole-prompt prefill (the rule's
+    chunk kernel in the loop over calls of 4 chunks of 64, the flash kernel over 32
     ungrouped heads of K 192 / V 128, the two grouped-expert kernels at
     a width of 2,304; the latents, not the expanded K/V, to the pages).
     The pool and both slabs go in and come out where they lie."""
+    from paddle_tpu.ops import pallas_kda_chunk as chunked
     from paddle_tpu.ops import pallas_kda_update as kda
     from paddle_tpu.ops import pallas_moe_grouped as grouped
     from paddle_tpu.ops import pallas_prompt_attention as ppa
@@ -1398,13 +1441,18 @@ def test_kimi_linear_width_programs_compile(one_chip, program):
         assert grouped.GATE_UP_KERNEL_NAME not in text
     else:
         assert eng._prefill_walks(4096) == [(1, None, ("flash", 1024, 1024))]
-        assert eng.model.prefill_chunks_per_call(4096) == 1
+        assert eng.model.prefill_chunks_per_call(4096) == 4
         compiled = eng.lower_prefill(4096, sharding=one_chip).compile()
         text = compiled.as_text()
-        # the state kernel in its loop, the flash kernel, the expert
-        # layer's two kernels
+        # the rule's chunk form in its loop (256 tokens a call of 32
+        # heads: the vectors lane-folded, the step's kernel nowhere),
+        # the flash kernel, the expert layer's two kernels
         assert text.count("tpu_custom_call") == 4
-        assert text.count(f"%{kda.KERNEL_NAME}") >= 1
+        chunk = [ln for ln in text.splitlines() if re.match(
+            r"\s*%" + chunked.KERNEL_NAME + r"[.\d]* = ", ln)]
+        assert len(chunk) == 1 and f"%{kda.KERNEL_NAME}" not in text
+        assert chunk[0].count("f32[256,4096]") == 5 \
+            and chunk[0].count("f32[1,32,128,128]") >= 2
         assert grouped.GATE_UP_KERNEL_NAME in text
         assert grouped.DOWN_KERNEL_NAME in text
         calls = _assert_prefill_holds_the_flash_kernel(text, 4096)
