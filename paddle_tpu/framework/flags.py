@@ -279,13 +279,6 @@ define_flag("decode_prefill_chunk_pages", 0,
             "one long prefill dispatch (protects ttft_ms_p99 for the "
             "slots already decoding); 0 = off (one prefill dispatch "
             "per request)")
-define_flag("decode_ragged_prefill", 0,
-            "decode engine: ragged prefill packing — pack up to this "
-            "many requests' chunk tails into ONE multi-row chunk "
-            "dispatch (per-row (page, offset) coords make rows "
-            "independent), instead of padding each prompt to its "
-            "power-of-two bucket; needs decode_prefill_chunk_pages > 0; "
-            "0 = off (per-request padded dispatches)")
 define_flag("request_trace_sample", 1.0,
             "per-request tracing (paddle_tpu.observe.request_trace): "
             "head-sampling fraction of NORMAL completions whose full "

@@ -85,9 +85,9 @@ RULES = (
          exact=True),
     Rule("decode_prefill_seconds", "histogram", "serving",
          "One prefill dispatch from its arguments through the sync: "
-         "until the sampled token is on the host (per request, chunk "
-         "or ragged dispatch; a chunk that samples no token has no "
-         "sync and ends with its dispatch)", exact=True),
+         "until the sampled token is on the host (per request or "
+         "chunk; a chunk that samples no token has no sync and ends "
+         "with its dispatch)", exact=True),
     Rule("decode_step_seconds", "histogram", "serving",
          "One batched decode step, dispatch + sync: from the hand-over "
          "until its tokens are on the host, a whole-prompt prefill "
@@ -254,7 +254,7 @@ RULES = (
          "Host arrays the engine's argument builders hand to the device "
          "(`_uploads`: count, `_bytes`), counted where they are handed "
          "over: ONE packed int32 array a joint decode step and a "
-         "whole-prompt prefill, one a field for the rows, ragged and "
+         "whole-prompt prefill, one a field for the rows and "
          "speculative builders; the weights are not in it"),
     Rule("decode_attn_blocks_", "gauge", "serving",
          "Blocks of page-table entries the paged-attention kernel meets "

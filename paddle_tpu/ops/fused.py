@@ -60,10 +60,10 @@ def _shape_ok(sq, sk, d):
 def _flash_engaged(b, h, sq, sk, d):
     """Flag-controlled engagement (FLAGS_flash_attention).
 
-    'auto': measured on v5e, XLA's own attention fusion MATCHES the
-    pallas kernel on speed through S=4096 fwd+bwd (0.94-1.02x) and
-    beats it at S=128 (235 vs 335 ms/step on BERT-base), so flash's
-    value is the MEMORY ceiling, not throughput — the plain path
+    'auto': no record holds a time for the pallas kernel (the ledger's
+    BERT-base cell, S=128, runs XLA's own attention fusion at 209 ms a
+    step; no cell engages a flash kernel), so what flash is engaged for
+    is the MEMORY ceiling, not throughput — the plain path
     materializes the [B,H,Sq,Sk] fp32 score tensor in backward.  Auto
     engages only when that tensor would threaten HBM (>2 GB).
     'always' engages at any aligned shape (A/B testing, memory-bound

@@ -7,7 +7,7 @@ runs, one token a slot); this is the same rule over ``CHUNK``
 consecutive tokens of one request at a time (Kimi Delta Attention's
 chunkwise form, arXiv 2510.26692, with Gated Linear Attention's
 secondary chunking for the decay ratios, arXiv 2312.06635), what
-``serving/hybrid_moe_lm.py`` ``KDAMixer._kda_chunk`` hands the engine's
+``serving/mixers.py`` ``KDAMixer._kda_chunk`` hands the engine's
 whole-prompt prefill.  With ``g_t <= 0`` a channel's log decay and
 ``G_t`` its sum up to and with token ``t`` of the chunk, ``u_t`` (what
 token ``t`` writes along ``k_t``) solves the unit lower triangular::

@@ -1,7 +1,7 @@
 """The gated delta rule with a decay a channel, a head's state held in
 fast memory for as many tokens as the call has.
 
-``serving/hybrid_moe_lm.py``'s recurrent layers keep a ``[d_k, d_v]``
+``serving/mixers.py``'s recurrent layers keep a ``[d_k, d_v]``
 float32 matrix a head a request.  A token takes it one step on::
 
     S <- decay (.) S            a factor a d_k channel
@@ -23,7 +23,7 @@ tokens of one row (grid ``(1, head block)``; the loop stops at the row's
 0`` (a dead slot of the step) is written back as read.
 
 WHO CALLS WHICH.  The step of every model that takes its recurrent
-layers from ``hybrid_moe_lm.KDAMixer`` (``_kda_token``: Solar-Open2's
+layers from ``mixers.KDAMixer`` (``_kda_token``: Solar-Open2's
 and Kimi-Linear's) runs the ``T = 1`` grid.  The ``R = 1`` grid over a
 chunk's tokens served those models' whole-prompt prefill until PR 58 and
 serves NO model since: a prompt's tokens each paid the transposes below,

@@ -248,8 +248,7 @@ def prefill_bucket_grid(max_seq_len: int, page_size: int):
     The rounding buys a tiny executable universe at the price of dead
     query rows — a 65-token prompt dispatches a 128-row executable.
     Every admission must account that waste through
-    ``record_pad_waste`` so the cost is measurable (and so ragged
-    packing's A/B is visible on old padded rounds too)."""
+    ``record_pad_waste`` so the cost is measurable."""
     out = []
     b = int(page_size)
     while b < max_seq_len:
@@ -264,8 +263,7 @@ def record_pad_waste(live_tokens: int, dispatched_tokens: int) -> None:
     query rows computed attention for nobody.  Keeps the running
     counters and re-derives the ``prefill_pad_waste`` gauge (cumulative
     padded fraction of all dispatched prefill rows, in parts-per-million
-    — the stat registry is integer-only) — the number ragged packing
-    (FLAGS_decode_ragged_prefill) exists to drive down."""
+    — the stat registry is integer-only)."""
     from ..monitor import stat_add, stat_get, stat_set
 
     live = max(0, int(live_tokens))

@@ -13,9 +13,9 @@ docstring (``benchmark/reference/conv_moe_lm.py``), which this file is
 tested against and shares no code with.
 
 It sits behind ``DecodeEngine`` on the contract in that class's
-docstring, like ``hybrid_moe_lm.py`` (whose matmul, norm and routing
-helpers it uses; the rotary term is ``window_moe_lm.py``'s): ``forward(
-weights, tokens, positions, cache, attend)``.  What it declares:
+docstring (the matmul feed, the norm, the half-split rotary pairing and
+the routed share are ``blocks.py``'s): ``forward(weights, tokens,
+positions, cache, attend)``.  What it declares:
 ``layer_kinds`` (``"recurrent"``: the convolution; ``"attention"``),
 ``num_kv_heads``, ``recurrent_state`` (one slot's state of one
 convolution layer: ``tail``, the last ``conv_kernel - 1`` inputs of the
@@ -42,9 +42,9 @@ from typing import Sequence
 import numpy as np
 
 from ..ops import moe_ops
-from .hybrid_moe_lm import (_mm, rms_norm, route_share, share_ffn,
-                            step_tallies)
-from .window_moe_lm import DENSE_SCOPE, ROPE_SCOPE, WindowMoELM
+from .blocks import (DENSE_SCOPE, ROPE_SCOPE, _mm, dense_from,
+                     half_split_angles, half_split_rotate, held_ids,
+                     rms_norm, route_share, share_ffn, step_tallies)
 
 CONV_SCOPE = "short_conv"
 CONV_PROMPT_SCOPE = "short_conv_prompt"
@@ -83,19 +83,13 @@ class ConvMoELM:
         self.head_dim = int(head_dim)
         if self.head_dim % 2:
             raise ValueError("head_dim must be even: every lane turns")
-        self.rotary_dim = self.head_dim         # what ``_rotate`` reads
         self.conv_kernel = int(conv_kernel)
         if self.conv_kernel < 2:
             raise ValueError("conv_kernel must be 2 or more: a layer that "
                              "looks back on nothing keeps no state")
         self.ffn_dim, self.dense_layers = int(ffn_dim), int(dense_layers)
         self.num_experts, self.top_k = int(num_experts), int(top_k)
-        self.held_experts = tuple(int(e) for e in held_experts)
-        if not self.held_experts or min(self.held_experts) < 0 \
-                or max(self.held_experts) >= self.num_experts \
-                or len(set(self.held_experts)) != len(self.held_experts):
-            raise ValueError(
-                f"held_experts must be distinct ids below {num_experts}")
+        self.held_experts = held_ids(held_experts, self.num_experts)
         self.expert_dim = int(expert_dim)
         self.rope_theta, self.rms_eps = float(rope_theta), float(rms_eps)
         self.tie_head = bool(tie_head)
@@ -114,8 +108,6 @@ class ConvMoELM:
             "tail": ((self.conv_kernel - 1, self.d_model), np.float32)}
 
     step_tallies = step_tallies
-    _rotary = WindowMoELM._rotary
-    _rotate = WindowMoELM._rotate
 
     def prefill_chunks_per_call(self, rows):
         """One call of the prompt form covers the whole bucket."""
@@ -141,11 +133,7 @@ class ConvMoELM:
         nf = len(self.held_experts) * f
         keys = iter(jax.random.split(key, 4 + 12 * self.num_layers))
 
-        def dense(shape, scale=None, dtype=dt):
-            scale = 1.0 / math.sqrt(shape[0]) if scale is None else scale
-            return (jax.random.normal(next(keys), shape, jnp.float32)
-                    * scale).astype(dtype)
-
+        dense = dense_from(keys, dt)
         ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
         w = {"tok_emb": dense((v, dm), 1.0 / math.sqrt(dm)),
              "norm_f": ones(dm), "layers": []}
@@ -246,8 +234,9 @@ class ConvMoELM:
         q = rms_norm(q, lw["q_norm"], self.rms_eps)
         k = rms_norm(k, lw["k_norm"], self.rms_eps)
         with jax.named_scope(ROPE_SCOPE):
-            turn = self._rotary(positions, self.rope_theta)
-            q, k = self._rotate(q, *turn), self._rotate(k, *turn)
+            turn = half_split_angles(positions, self.rope_theta,
+                                     self.head_dim)
+            q, k = half_split_rotate(q, *turn), half_split_rotate(k, *turn)
         ctx, cache = attend(l, q, k, v, cache)
         return _mm(ctx.reshape(*lead, -1).astype(jnp.float32),
                    lw["wo"]), cache

@@ -81,9 +81,9 @@ a running ``jax.profiler`` trace, ring-buffer records under
 ``serving/prefill_args`` | ``prefill_dispatch`` | then
 ``serving/step_dispatch`` | then, of the joint step handed over an
 iteration AGO, ``serving/step_sync`` | ``step_deliver`` | then per
-prefill ``serving/prefill_sync`` | ``prefill_deliver`` (a chunked,
-ragged or suffix prefill runs its four phases in a row where the
-whole-prompt one is dispatched; a round that holds a speculative slot
+prefill ``serving/prefill_sync`` | ``prefill_deliver`` (a chunked or
+suffix prefill runs its four phases in a row where the whole-prompt one
+is dispatched; a round that holds a speculative slot
 reads its own joint step, behind the prefills).  A joint step is handed
 over while the one before it is in flight (``DecodeEngine._loop``): a
 step's five leaves share its ``step`` number, fixed at the hand-over,
@@ -142,7 +142,7 @@ class _Uploads:
     """Counts the host arrays one dispatch's argument builder hands to
     the device, at the sites that hand them over: one packed array for
     the joint step and the whole-prompt prefill, one a field for the
-    rows, ragged and speculative builders."""
+    rows and speculative builders."""
 
     __slots__ = ("n", "nbytes")
 
@@ -713,7 +713,6 @@ class DecodeConfig:
                  cache_dtype="float32",
                  prefix_cache: Optional[bool] = None,
                  prefill_chunk_pages: Optional[int] = None,
-                 ragged_prefill_rows: Optional[int] = None,
                  spec_k: Optional[int] = None,
                  kv_quant: Optional[bool] = None):
         from ..framework import flags
@@ -740,9 +739,6 @@ class DecodeConfig:
         self.prefill_chunk_pages = int(
             prefill_chunk_pages if prefill_chunk_pages is not None
             else flags.flag("decode_prefill_chunk_pages"))
-        self.ragged_prefill_rows = int(
-            ragged_prefill_rows if ragged_prefill_rows is not None
-            else flags.flag("decode_ragged_prefill"))
         self.spec_k = int(spec_k if spec_k is not None
                           else flags.flag("decode_spec_k"))
         self.kv_quant = bool(kv_quant if kv_quant is not None
@@ -752,9 +748,7 @@ class DecodeConfig:
 class DecodeEngine:
     """One decode replica: a slot batch, its paged KV cache, and the
     consumer thread that runs admission -> prefill -> joint decode
-    step, forever.  ``continuous=False`` degrades admission to the
-    one-shot group mode (a new group only starts when EVERY slot is
-    free) — the static-batching baseline (tests/test_decode_engine.py).
+    step, forever.
 
     What the engine reads off ``model`` is the whole contract
     (``serving/transformer_lm.py`` is the reference): ``num_layers``,
@@ -845,7 +839,7 @@ class DecodeEngine:
     its own and whose call takes a group of them says how many as
     ``chunks_per_call`` (``serving/gated_delta_lm.py``: what does not
     read the state formed for the whole group as XLA operations, the
-    state passed through its chunks in turn; ``serving/hybrid_moe_lm.py``
+    state passed through its chunks in turn; ``serving/mixers.py``
     ``KDAMixer``: the group's vectors formed at once and ONE kernel call
     that keeps the state in fast memory through the group's chunks),
     and declares ``prefill_chunks_per_call(rows)``,
@@ -867,7 +861,7 @@ class DecodeEngine:
     ``record_logits`` request in ``req.records``).  A model with window
     or recurrent layers is served by the whole-prompt prefill and the
     joint step alone: every request is admitted fresh (no prefix index;
-    counter ``decode_prefix_bypassed``), and chunked or ragged prefill,
+    counter ``decode_prefix_bypassed``), and chunked prefill,
     speculative decoding, ``kv_quant`` and the disaggregated hand-over
     refuse at construction or submit, naming the kind and the mechanism
     (``per_slot_kinds``).
@@ -885,7 +879,7 @@ class DecodeEngine:
     it attends the prompt in (``prompt_heads``: their K/V head count, K
     and V lanes) and the rows to cache as ``keep=``, and forms the
     logits of ``attend.read_row`` alone.  Every request is
-    admitted fresh (``decode_prefix_bypassed``), and chunked or ragged
+    admitted fresh (``decode_prefix_bypassed``), and chunked
     prefill, speculation, ``kv_quant`` and the hand-over refuse, naming
     the latent page: the programs that extend a sequence R rows at a time
     through the pages are not built for its rows.
@@ -896,7 +890,7 @@ class DecodeEngine:
     accounting, and copy-on-write cover both for free."""
 
     def __init__(self, model, weights, config: Optional[DecodeConfig] = None,
-                 place=None, name: str = "replica-0", continuous: bool = True,
+                 place=None, name: str = "replica-0",
                  draft_model=None, draft_weights=None):
         import jax
         import jax.numpy as jnp
@@ -907,7 +901,6 @@ class DecodeEngine:
         self.model = model
         self.config = config or DecodeConfig()
         self.name = name
-        self._continuous = bool(continuous)
         c = self.config
         if c.max_seq_len > model.max_seq_len:
             raise ValueError(
@@ -1122,11 +1115,10 @@ class DecodeEngine:
         wrong."""
         for kind, keeps in per_slot_kinds(model):
             why = f"the model's {kind} layers {keeps}: "
-            if c.prefill_chunk_pages > 0 or c.ragged_prefill_rows > 0:
+            if c.prefill_chunk_pages > 0:
                 raise ValueError(
-                    why + "chunked/ragged prefill (prefill_chunk_pages="
-                    f"{c.prefill_chunk_pages}, ragged_prefill_rows="
-                    f"{c.ragged_prefill_rows}) would have to carry that "
+                    why + "chunked prefill (prefill_chunk_pages="
+                    f"{c.prefill_chunk_pages}) would have to carry that "
                     "from chunk to chunk, which the multi-row step does "
                     "not")
             if draft_model is not None or c.spec_k > 0:
@@ -1148,11 +1140,10 @@ class DecodeEngine:
         stacked rows, and an int8 row has no head to scale by."""
         why = ("the model keeps a latent page (one row a position for "
                "keys and values): ")
-        if c.prefill_chunk_pages > 0 or c.ragged_prefill_rows > 0:
+        if c.prefill_chunk_pages > 0:
             raise ValueError(
-                why + "chunked/ragged prefill (prefill_chunk_pages="
-                f"{c.prefill_chunk_pages}, ragged_prefill_rows="
-                f"{c.ragged_prefill_rows}) attends its rows through the "
+                why + "chunked prefill (prefill_chunk_pages="
+                f"{c.prefill_chunk_pages}) attends its rows through the "
                 "pages, which the multi-row step is not built to do in "
                 "the row's space")
         if draft_model is not None or c.spec_k > 0:
@@ -1978,8 +1969,6 @@ class DecodeEngine:
             stat_set("decode_queue_depth", len(self._queue))
 
     def _admit_locked(self):
-        if not self._continuous and self.live_slots:
-            return []  # one-shot baseline: groups never mix
         admitted = []
         while self._queue:
             free = [i for i, s in enumerate(self._slots) if s is None]
@@ -2242,7 +2231,7 @@ class DecodeEngine:
         (measured: `PERF.md` section 6, PR 38).  While a slot
         speculates the loop runs one step at a time, each joint step
         read in its own iteration (the order the loop had): a
-        speculative round runs to its own syncs.  Chunked, ragged and
+        speculative round runs to its own syncs.  Chunked and
         suffix prefills queue behind the step in flight and run to
         their own sync; before an idle wait, a stop and an abort nothing
         is left in flight.
@@ -2337,12 +2326,7 @@ class DecodeEngine:
         if not pre:
             return finishes
         chunk = self.config.prefill_chunk_pages
-        if chunk > 0 and self.config.ragged_prefill_rows > 0:
-            # ragged packing: several prompts' tails share one
-            # fixed-width multi-lane dispatch instead of each padding
-            # its own chunk executable
-            self._run_prefill_ragged(pre)
-        elif chunk > 0:
+        if chunk > 0:
             pick = min(pre, key=lambda i:
                        (i - self._prefill_rr) % self.config.slots)
             self._prefill_rr = (pick + 1) % self.config.slots
@@ -2547,151 +2531,6 @@ class DecodeEngine:
         except Exception as e:  # noqa: BLE001 — fault isolation per req
             stat_add("decode_prefill_errors")
             self._finish_slot(slot, e)
-
-    def _run_prefill_ragged(self, pre: List[int]):
-        """Pack several prompts' tails into ONE fixed-width multi-lane
-        dispatch: each of the ``ragged_prefill_rows`` lanes is one
-        (slot, position) query row with its own page-table row, start,
-        and (page, offset) write coords — the per-row coordinates of
-        the chunk executable already make lanes independent, so the
-        only thing padding bought (one shape per dispatch) is kept
-        while its cost (dead rows rounding each prompt up to its own
-        power-of-two bucket) is shared across requests.  Lanes of the
-        SAME request at consecutive positions are sound because every
-        layer writes all rows' K/V before its attention reads
-        (``_build_rows_fn``), and per-lane logits stay bitwise-equal
-        to the padded chunk path by the same chunk-equivalence
-        contract; dead lanes write to the trash page (page 0) and are
-        ignored.  One fixed lane count -> ONE extra executable."""
-        L = self.config.ragged_prefill_rows
-        cc = self._cache.config
-        per_slot_cap = self.config.prefill_chunk_pages * cc.page_size
-
-        # round-robin lane assignment in chunk-sized shares: every
-        # prefilling slot gets a fair share first, then further rounds
-        # deal the leftover lanes out (all of a prompt's pages are
-        # reserved at admission, so one slot absorbing several chunks
-        # in one dispatch is sound) — dead lanes only remain when the
-        # total outstanding prefill work is smaller than the dispatch
-        order = sorted(pre, key=lambda i:
-                       (i - self._prefill_rr) % self.config.slots)
-        assigned = {i: 0 for i in order}
-        lanes_left = L
-        progress = True
-        while lanes_left > 0 and progress:
-            progress = False
-            for i in order:
-                st = self._slots[i]
-                t = min(len(st.req.prompt) - st.prefill_pos
-                        - assigned[i], per_slot_cap, lanes_left)
-                if t <= 0:
-                    continue
-                assigned[i] += t
-                lanes_left -= t
-                progress = True
-        picks = [(i, self._slots[i].prefill_pos, assigned[i])
-                 for i in order if assigned[i] > 0]
-        if not picks:
-            return
-        self._prefill_rr = (picks[-1][0] + 1) % self.config.slots
-        live = L - lanes_left
-
-        attrs = {"iter": self._iter, "lanes": L, "live": live,
-                 "slots": len(picks),
-                 "req": ",".join(_rid(self._slots[i].req)
-                                 for i, _s, _t in picks)}
-        try:
-            t0 = time.monotonic()
-            with otrace.span("serving/prefill_args", **attrs):
-                up = _Uploads()
-                tokens = np.zeros((L, 1), np.int32)
-                start = np.zeros((L,), np.int32)
-                page_table = np.zeros(
-                    (L,) + self._cache.page_table[0].shape, np.int32)
-                write_page = np.zeros((L, 1), np.int32)
-                write_off = np.zeros((L, 1), np.int32)
-                base_keys = np.zeros((L, 2), np.uint32)
-                temp = np.zeros((L,), np.float32)
-                top_k = np.zeros((L,), np.int32)
-                top_p = np.ones((L,), np.float32)
-                lane = 0
-                spec_any = any_final = False
-                for i, s, t in picks:
-                    st = self._slots[i]
-                    req = st.req
-                    spec_any = spec_any or st.spec
-                    any_final = any_final or s + t >= len(req.prompt)
-                    for j in range(t):
-                        pos = s + j
-                        tokens[lane, 0] = req.prompt[pos]
-                        start[lane] = pos
-                        page_table[lane] = self._cache.page_table[i]
-                        write_page[lane, 0] = self._cache.page_table[i][
-                            pos // cc.page_size]
-                        write_off[lane, 0] = pos % cc.page_size
-                        base_keys[lane] = st.base_key
-                        temp[lane] = req.temperature
-                        top_k[lane] = req.top_k
-                        top_p[lane] = req.top_p
-                        lane += 1
-                args = lambda w: (  # noqa: E731
-                    w, up(tokens), up(start),
-                    up.host(np.zeros((L,), np.int32)), up(page_table),
-                    up(write_page), up(write_off), up(base_keys),
-                    up.host(np.zeros((L,), np.int32)),
-                    up(temp), up(top_k), up(top_p))
-                target_args = args(self.weights)
-                draft_args = args(self.draft_weights) if spec_any \
-                    else None
-                up.record()
-            with otrace.span("serving/prefill_dispatch", **attrs):
-                tok, _greedy, logits = self._exe.run_persistent(
-                    self._rows_fn(1, L), self._state_vars,
-                    args=target_args, scope=self._scope)
-                if spec_any:
-                    self._exe.run_persistent(
-                        self._rows_fn(1, L, "draft"),
-                        self._draft_state_vars,
-                        args=draft_args, scope=self._scope)
-            if any_final:
-                # only a dispatch that samples some request's first
-                # token is read back
-                with otrace.span("serving/prefill_sync", **attrs):
-                    tok = np.asarray(tok)
-            dur = time.monotonic() - t0
-            stat_time("decode_prefill_seconds", dur)
-            with _Delivery(self, "serving/prefill_deliver", attrs):
-                stat_add("prefill_chunks")
-                stat_add("decode_ragged_dispatches")
-                record_pad_waste(live, L)
-                self._prefill_chunk_count += 1
-                dur_ms = round(dur * 1e3, 3)
-                lane = 0
-                for i, s, t in picks:
-                    st = self._slots[i]
-                    req = st.req
-                    lane += t
-                    n = len(req.prompt)
-                    final = s + t >= n
-                    st.chunks += 1
-                    self._tev(req, "prefill_chunk", slot=i, start=s,
-                              rows=t, live=t, final=final, ragged=True,
-                              dur_ms=dur_ms)
-                    st.prefill_pos += t
-                    if final:
-                        stat_add("decode_prefills")
-                        st.phase = "decode"
-                        self._cache.lengths[i] = n
-                        if req.record_logits:
-                            req.logits_trace.append(
-                                np.asarray(logits)[lane - 1, 0].copy())
-                        self._deliver(i, int(tok[lane - 1]))
-        except Exception as e:  # noqa: BLE001 — the packed dispatch is
-            # shared: fail every packed request, not just one
-            stat_add("decode_prefill_errors")
-            for i, _s, _t in picks:
-                if self._slots[i] is not None:
-                    self._finish_slot(i, e)
 
     # -- device work: decode ----------------------------------------------
     def _deliver(self, slot: int, token: int):
@@ -3341,7 +3180,6 @@ class DecodeEngine:
             "free_pages": self._cache.allocator.num_free,
             "num_pages": self._cache.config.num_pages,
             "cache_bytes": self._cache.config.cache_bytes(),
-            "continuous": self._continuous,
             "prefix_cache": self.config.prefix_cache,
             "kv_quant": self.config.kv_quant,
             "page_bytes": self._cache.config.page_bytes(),
@@ -3351,8 +3189,6 @@ class DecodeEngine:
             "shared_pages": self._cache.shared_pages,
             "cow_copies": self._cow_copies,
             "prefill_chunks": self._prefill_chunk_count,
-            "ragged_prefill_rows": self.config.ragged_prefill_rows,
-            "ragged_dispatches": stat_get("decode_ragged_dispatches"),
             "prefill_pad_waste": stat_get("prefill_pad_waste") / 1e6,
             "spec_enabled": self.spec_enabled,
             "spec_proposed": sp,

@@ -12,8 +12,9 @@ docstring (``benchmark/reference/gated_delta_lm.py``, a copy in
 ``tests/``), which this file is tested against and shares no code with.
 
 It sits behind ``DecodeEngine`` on the contract in that class's
-docstring, like ``hybrid_moe_lm.py``: ``forward(weights, tokens,
-positions, cache, attend)``.  What it declares: ``layer_kinds``
+docstring (the matmul feed and the norm are ``blocks.py``'s):
+``forward(weights, tokens, positions, cache, attend)``.  What it
+declares: ``layer_kinds``
 (``"attention"`` or ``"recurrent"`` a layer), ``num_kv_heads`` (the query
 heads' count), ``recurrent_state`` (one slot's state of one recurrent
 layer), ``tallies`` (none), ``prefill_chunks_per_call(rows)``.  What it
@@ -44,11 +45,10 @@ from typing import Sequence
 
 import numpy as np
 
-from .hybrid_moe_lm import _mm, rms_norm
+from .blocks import DENSE_SCOPE, _mm, dense_from, rms_norm
 
 GDN_SCOPE = "gdn_update"        # the one-token update's operations
 GDN_CHUNK_SCOPE = "gdn_chunk"   # the chunk form's
-FFN_SCOPE = "dense_ffn"
 CHUNK = 64                      # tokens of one chunk of the rule's WY form
 # what the chunks one call takes together may hold in temporaries: half
 # of the 128 MiB of fast memory the chip's compiler keeps a loop body's
@@ -108,11 +108,7 @@ class GatedDeltaLM:
         nh, cv = self.lin_heads, self.lin_heads * self.lin_value_dim
         keys = iter(jax.random.split(key, 2 + 12 * self.num_layers))
 
-        def dense(shape, scale=None, dtype=dt):
-            scale = 1.0 / math.sqrt(shape[0]) if scale is None else scale
-            return (jax.random.normal(next(keys), shape, jnp.float32)
-                    * scale).astype(dtype)
-
+        dense = dense_from(keys, dt)
         ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
         w = {"tok_emb": dense((v, dm), 1.0), "lm_head": dense((dm, v)),
              "norm_f": ones(dm), "layers": []}
@@ -159,16 +155,13 @@ class GatedDeltaLM:
             lw = weights["layers"][l]
             mixer = self._attention if kind == "attention" else self._gdn
             y, cache = mixer(l, lw, x, cache, attend)
-            x = x + self._rms(y, lw["norm_mix"])
-            with jax.named_scope(FFN_SCOPE):
+            x = x + rms_norm(y, lw["norm_mix"], self.rms_eps)
+            with jax.named_scope(DENSE_SCOPE):
                 y = _mm(jax.nn.silu(_mm(x, lw["ffn_w_gate"]))
                         * _mm(x, lw["ffn_w_up"]), lw["ffn_w_down"])
-            x = x + self._rms(y, lw["norm_ffn"])
-        return _mm(self._rms(x, weights["norm_f"]), weights["lm_head"]), \
-            cache
-
-    def _rms(self, x, g):
-        return rms_norm(x, g, self.rms_eps)
+            x = x + rms_norm(y, lw["norm_ffn"], self.rms_eps)
+        return _mm(rms_norm(x, weights["norm_f"], self.rms_eps),
+                   weights["lm_head"]), cache
 
     def _attention(self, l, lw, x, cache, attend):
         """Layer ``l``'s softmax attention of the rows ``x`` -> (its
@@ -185,7 +178,7 @@ class GatedDeltaLM:
                    lw["wo"]), cache
 
     def _qk_norm(self, x, g):
-        return self._rms(x, g)
+        return rms_norm(x, g, self.rms_eps)
 
     def _gdn(self, l, lw, x, cache, attend):
         """Layer ``l``'s Gated DeltaNet mixer of the rows ``x`` -> (its

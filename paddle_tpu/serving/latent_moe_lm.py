@@ -32,10 +32,10 @@ h's keys are ``[c W_UK[h]^T | rot(k_r)]`` and its values ``c W_UV[h]``.
   for a cached position.
 
 It sits behind ``DecodeEngine`` on the contract in that class's
-docstring, like ``window_moe_lm.py`` (whose dense layer and
-``held_experts`` plumbing it follows, and whose matmul, norm and routing
-helpers it uses).  What it declares: ``layer_kinds`` (all
-``"attention"``: every position in pages), ``num_kv_heads`` 1,
+docstring; the two forms are ``mixers.LatentMixer``'s, the feed-forward,
+the head and the rotary pairing ``blocks.py``'s.  What it declares:
+``layer_kinds`` (all ``"attention"``: every position in pages),
+``num_kv_heads`` 1,
 ``head_dim`` (the cached row, ``kv_rank + rope_dim``), ``v_head_dim``
 (``kv_rank``) and ``values_in_keys`` (the values are the row's leading
 lanes: the cache keeps no V pool), ``prompt_heads`` (the expanded form's
@@ -47,138 +47,15 @@ norms, the rotary term, router scores, and softmax in float32.
 """
 from __future__ import annotations
 
-import math
 from typing import Sequence
 
 from ..ops import moe_ops
-from .hybrid_moe_lm import (_mm, dense_from, held_ids, rms_norm,
-                            route_share, share_ffn, step_tallies)
-from .window_moe_lm import DENSE_SCOPE, ROPE_SCOPE
-
-Q_PROJ_SCOPE = "latent_q_proj"          # q_a, its norm, q_b
-KV_PROJ_SCOPE = "latent_kv_proj"        # kv_a and the latent's norm
-ABSORB_Q_SCOPE = "latent_absorb_q"      # q_nope W_UK: into the row's space
-ABSORB_V_SCOPE = "latent_absorb_v"      # ctx_lat W_UV: out of it
-EXPAND_SCOPE = "latent_expand"          # a prompt's K and V from its rows
-OUT_PROJ_SCOPE = "latent_out_proj"
-SHARED_SCOPE = "shared_ffn"
-
-
-def yarn_frequencies(rope_dim, theta, factor, orig_len, beta_fast,
-                     beta_slow):
-    """The ``rope_dim / 2`` rotary frequencies under YaRN (host floats):
-    pair j turns at ``theta^(-2j/d)`` where it completes more than
-    ``beta_fast`` turns over the original context, at a ``factor``-th of
-    that where fewer than ``beta_slow``, and at a linear blend between
-    (the DeepSeek-V3 reading of ``rope_scaling``)."""
-    half = rope_dim // 2
-
-    def turns_dim(turns):
-        return rope_dim * math.log(orig_len / (turns * 2 * math.pi)) \
-            / (2 * math.log(theta))
-
-    low = max(math.floor(turns_dim(beta_fast)), 0)
-    high = min(math.ceil(turns_dim(beta_slow)), rope_dim - 1)
-    out = []
-    for j in range(half):
-        f = theta ** (-2.0 * j / rope_dim)
-        r = min(max((j - low) / max(high - low, 1e-3), 0.0), 1.0)
-        out.append(f * (1.0 - r) + f / factor * r)
-    return out
-
-
-def yarn_mscale(factor, mscale):
-    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
-
-
-class LatentMixer:
-    """Latent attention of a model with ``num_heads`` heads of
-    ``nope_dim + rope_dim`` query lanes and ``v_dim`` value lanes over a
-    latent of ``kv_rank``, ``softmax_scale`` and ``rms_eps``: its
-    weights, what the engine reads of it, and the two forms.  A model
-    says how its queries are made (``_queries``, ``_query_weights``) and
-    hands ``turn``, the rotary term's two factors, or None where its
-    last ``rope_dim`` lanes carry no position (they are then lanes like
-    the others: nothing turns them, not by the identity either).
-    ``LatentMoELM``'s, and ``linear_latent_lm.py``'s."""
-
-    def latent_declares(self):
-        """What the engine reads: ONE cached row a position, all heads'."""
-        self.num_kv_heads = 1
-        self.head_dim = self.kv_rank + self.rope_dim
-        self.v_head_dim = self.kv_rank
-        self.values_in_keys = True
-        self.prompt_heads = (self.num_heads, self.nope_dim + self.rope_dim,
-                             self.v_dim)
-
-    def latent_weights(self, dense, ones):
-        """A latent layer's mixer weights, the queries' first."""
-        h = self.num_heads
-        up = 1.0 / math.sqrt(self.kv_rank)
-        return {**self._query_weights(dense, ones),
-                "kv_norm": ones(self.kv_rank),
-                "wkv_a": dense((self.d_model, self.kv_rank + self.rope_dim)),
-                # kv_b_proj, split once: head h's keys are c W_UK[h]^T,
-                # its values c W_UV[h]
-                "w_uk": dense((h, self.nope_dim, self.kv_rank), up),
-                "w_uv": dense((h, self.kv_rank, self.v_dim), up),
-                "wo": dense((h * self.v_dim, self.d_model))}
-
-    def _attention(self, lw, l, h, turn, cache, attend):
-        """One layer's context ``[..., H, v_dim]`` of rows ``h``, in the
-        form the program asks for; ``turn``: the class docstring."""
-        import jax
-        import jax.numpy as jnp
-
-        lead, nh = h.shape[:-1], self.num_heads
-        with jax.named_scope(Q_PROJ_SCOPE):
-            q = self._queries(lw, h).reshape(
-                *lead, nh, self.nope_dim + self.rope_dim)
-        with jax.named_scope(KV_PROJ_SCOPE):
-            c, k_r = self._latent(lw, _mm(h, lw["wkv_a"]))
-        if turn is None:
-            q_rot, k_rot = q[..., self.nope_dim:], k_r[..., None, :]
-        else:
-            with jax.named_scope(ROPE_SCOPE):
-                q_rot = self._rotate(q[..., self.nope_dim:], *turn)
-                k_rot = self._rotate(k_r[..., None, :], *turn)
-        q_nope = q[..., :self.nope_dim]
-        row = jnp.concatenate([c[..., None, :], k_rot], axis=-1)
-        dt = lw["w_uk"].dtype
-        if attend.prompt:
-            with jax.named_scope(EXPAND_SCOPE):
-                cb = c.astype(dt)
-                k_nope = jnp.einsum("...c,hdc->...hd", cb, lw["w_uk"],
-                                    preferred_element_type=jnp.float32)
-                v = jnp.einsum("...c,hcd->...hd", cb, lw["w_uv"],
-                               preferred_element_type=jnp.float32)
-                k = jnp.concatenate([k_nope, jnp.broadcast_to(
-                    k_rot, (*lead, nh, self.rope_dim))], axis=-1)
-            return attend(l, self._scaled(
-                jnp.concatenate([q_nope, q_rot], axis=-1)), k, v, cache,
-                keep=row)
-        with jax.named_scope(ABSORB_Q_SCOPE):
-            q_lat = jnp.einsum("...hd,hdc->...hc", q_nope.astype(dt),
-                               lw["w_uk"],
-                               preferred_element_type=jnp.float32)
-        ctx_lat, cache = attend(l, self._scaled(
-            jnp.concatenate([q_lat, q_rot], axis=-1)), row, None, cache)
-        with jax.named_scope(ABSORB_V_SCOPE):
-            return jnp.einsum("...hc,hcd->...hd", ctx_lat.astype(dt),
-                              lw["w_uv"],
-                              preferred_element_type=jnp.float32), cache
-
-    def _latent(self, lw, kv):
-        """(c, k_r) of ``kv = h W_kva``: the norm is the latent's alone,
-        the shared key is not normed."""
-        return rms_norm(kv[..., :self.kv_rank], lw["kv_norm"],
-                        self.rms_eps), kv[..., self.kv_rank:]
-
-    def _scaled(self, q):
-        """The engine's attention divides scores by the square root of
-        the query's width; what the softmax's scale holds beyond that
-        (the heads' own width, YaRN's ``mscale^2``) rides the query."""
-        return q * (self.softmax_scale * math.sqrt(q.shape[-1]))
+from .blocks import (OUT_PROJ_SCOPE, ROPE_SCOPE, _mm,
+                     adjacent_angles_signed_sine, adjacent_rotate_signed_sine,
+                     dense_from, feed_forward, ffn_weights, head_logits,
+                     held_ids, rms_norm, step_tallies, yarn_frequencies,
+                     yarn_mscale)
+from .mixers import LatentMixer
 
 
 class LatentMoELM(LatentMixer):
@@ -300,87 +177,12 @@ class LatentMoELM(LatentMixer):
         return head_logits(self, w, x, attend), cache
 
     def _rotary(self, positions):
-        """(cos, sin) ``[..., 1, rope_dim]`` at ``positions [...]``, a
-        pair's angle on both of its lanes, the sine negated on the even
-        one: ``_rotate``'s two factors."""
-        import jax.numpy as jnp
+        """(cos, sin) ``[..., 1, rope_dim]`` at ``positions [...]``:
+        ``_rotate``'s two factors, the sine signed."""
+        return adjacent_angles_signed_sine(positions, self.rope_freqs,
+                                           self.rope_mscale)
 
-        angle = jnp.repeat(
-            positions.astype(jnp.float32)[..., None, None]
-            * jnp.asarray(self.rope_freqs, jnp.float32), 2, axis=-1)
-        sign = jnp.where(jnp.arange(self.rope_dim) % 2 == 0, -1.0, 1.0)
-        return (jnp.cos(angle) * self.rope_mscale,
-                jnp.sin(angle) * self.rope_mscale * sign)
-
-    @staticmethod
-    def _rotate(x, cos, sin):
-        """The rotary term on ``x [..., heads, rope_dim]`` whose lanes
-        ``(2j, 2j + 1)`` are a pair, where they lie: ``y[2j] = x[2j] cos
-        - x[2j+1] sin``, ``y[2j+1] = x[2j+1] cos + x[2j] sin``, each lane
-        times its cosine plus its partner times its signed sine.  The
-        reference de-interleaves first (evens, then odds) and so names
-        the same 64 numbers in another order; queries and the cached key
-        keep THIS one, and a score is a sum over lanes."""
-        import jax.numpy as jnp
-
-        even = jnp.arange(x.shape[-1]) % 2 == 0
-        partner = jnp.where(even, jnp.roll(x, -1, axis=-1),
-                            jnp.roll(x, 1, axis=-1))
-        return x * cos + partner * sin
-
-
-def ffn_weights(model, l, dense):
-    """Layer ``l``'s feed-forward weights: a dense SwiGLU in the leading
-    ``dense_layers``, else the held experts, the router with its
-    correction bias and the shared expert."""
-    import jax.numpy as jnp
-
-    dm, e, f = model.d_model, model.num_experts, model.expert_dim
-    nf, sf = len(model.held_experts) * f, model.shared_dim
-    if l < model.dense_layers:
-        return dict(ffn_w_gate=dense((dm, model.dense_dim)),
-                    ffn_w_up=dense((dm, model.dense_dim)),
-                    ffn_w_down=dense((model.dense_dim, dm)))
-    return dict(
-        moe_router=dense((dm, e), dtype=jnp.float32),
-        moe_router_bias=dense((e,), 0.1, jnp.float32),
-        moe_w_gate=dense((dm, nf)), moe_w_up=dense((dm, nf)),
-        moe_w_down=dense((nf, dm), 1.0 / math.sqrt(f)),
-        shared_w_gate=dense((dm, sf)),
-        shared_w_up=dense((dm, sf)),
-        shared_w_down=dense((sf, dm)))
-
-
-def feed_forward(model, l, lw, x, attend):
-    """``x`` plus layer ``l``'s feed-forward of it: dense in the leading
-    layers, else the held experts' scaled part beside the shared
-    expert."""
-    import jax
-
-    h = rms_norm(x, lw["norm2"], model.rms_eps)
-    if l < model.dense_layers:
-        with jax.named_scope(DENSE_SCOPE):
-            return x + _swiglu(h, lw, "ffn")
-    local = route_share(h, lw, attend, model.top_k, model.held_experts)
-    routed = share_ffn(model, h, lw, local, attend)
-    with jax.named_scope(SHARED_SCOPE):
-        return x + model.routed_scale * routed + _swiglu(h, lw, "shared")
-
-
-def head_logits(model, w, x, attend):
-    """The logits of ``x``'s rows, or of a prompt's read row alone."""
-    import jax
-
-    if attend.prompt and attend.read_row is not None:
-        # the one row of a prompt whose logits are read: the head
-        # over every row would be a seventh of a prefill's matmuls
-        # and 0.67 GB of float32 nobody reads
-        x = jax.lax.dynamic_slice_in_dim(x, attend.read_row, 1, axis=0)
-    return _mm(rms_norm(x, w["norm_f"], model.rms_eps), w["lm_head"])
-
-
-def _swiglu(h, lw, name):
-    import jax
-
-    return _mm(jax.nn.silu(_mm(h, lw[name + "_w_gate"]))
-               * _mm(h, lw[name + "_w_up"]), lw[name + "_w_down"])
+    # the reference de-interleaves first (evens, then odds) and so names
+    # the same 64 numbers in another order; queries and the cached key
+    # keep the lanes where they lie, and a score is a sum over lanes
+    _rotate = staticmethod(adjacent_rotate_signed_sine)

@@ -13,17 +13,17 @@ copy in ``tests/``), which this file is tested against and shares no
 code with.
 
 Nothing of either mixer is written here.  The recurrent layers are
-``hybrid_moe_lm.KDAMixer``'s (the rule's token form in the step,
+``mixers.KDAMixer``'s (the rule's token form in the step,
 ``ops/pallas_kda_update.py``, and its chunk (WY) form on the matrix unit
 over a whole prompt, ``ops/pallas_kda_chunk.py``, a group of 64-token
 chunks a call) with the step's range ``beta_scale`` 1:
 the published config carries no ``kda_allow_neg_eigval``.  The latent
-layers are ``latent_moe_lm.LatentMixer``'s two forms of one attention
+layers are ``mixers.LatentMixer``'s two forms of one attention
 (absorbed in the step, expanded in a prompt) with the queries straight
 from the hidden row (no bottleneck) and ``turn`` None (``_rotary``):
 ``mla_use_nope``, no positional term anywhere, so the 64 lanes the
-sibling turns are lanes like the others and no rotary work is traced.  The feed-forward and the
-head are ``latent_moe_lm``'s.
+sibling turns are lanes like the others and no rotary work is traced.
+The feed-forward and the head are ``blocks.py``'s.
 
 It sits behind ``DecodeEngine`` on the contract in that class's
 docstring.  What it declares: ``layer_kinds``, ``recurrent_state``,
@@ -40,10 +40,10 @@ from __future__ import annotations
 from typing import Sequence
 
 from ..ops import moe_ops
-from .hybrid_moe_lm import (KDAMixer, _mm, dense_from, held_ids, rms_norm,
-                            step_tallies)
-from .latent_moe_lm import (OUT_PROJ_SCOPE, LatentMixer, feed_forward,
-                            ffn_weights, head_logits)
+from .blocks import (OUT_PROJ_SCOPE, _mm, dense_from, feed_forward,
+                     ffn_weights, head_logits, held_ids, rms_norm,
+                     step_tallies)
+from .mixers import KDAMixer, LatentMixer
 
 
 class LinearLatentLM(KDAMixer, LatentMixer):
@@ -108,7 +108,7 @@ class LinearLatentLM(KDAMixer, LatentMixer):
     def init_weights(self, key):
         """Seeded weights at variance-preserving scales: the decay's as
         ``KDAMixer.kda_weights`` sets them, the router's correction bias
-        from N(0, 0.1^2) (``latent_moe_lm.ffn_weights``)."""
+        from N(0, 0.1^2) (``blocks.ffn_weights``)."""
         import jax
         import jax.numpy as jnp
 

@@ -49,22 +49,12 @@ from __future__ import annotations
 
 import math
 
-from .hybrid_moe_lm import _mm, rms_norm
-from .window_moe_lm import WindowMoELM
+from .blocks import (DENSE_SCOPE, _mm, _mm_t, half_split_angles,
+                     half_split_rotate, rms_norm)
 
 PASS_SCOPE = "loop_pass"    # one pass's whole body
 NORM_SCOPE = "loop_norm"    # the norm between passes and the gate
-FFN_SCOPE = "dense_ffn"
 PASSES_TALLY, EXIT_TALLY = "decode_loop_passes", "decode_loop_exit_mass"
-
-
-def _mm_t(a, w):
-    """``a @ w.T`` at the weight's dtype in, float32 out (``w`` is
-    ``[out, in]``)."""
-    import jax.numpy as jnp
-
-    return jnp.einsum("...k,nk->...n", a.astype(w.dtype), w,
-                      preferred_element_type=jnp.float32)
 
 
 class LoopedLM:
@@ -84,8 +74,6 @@ class LoopedLM:
         self.rope_theta, self.rms_eps = float(rope_theta), float(rms_eps)
         self.dtype = str(dtype)
         self.max_seq_len = int(max_seq_len)
-        # the rotary term covers every lane of a head
-        self.rotary_dim = self.head_dim
         # K and V of every pass of every layer
         self.cache_layers = self.loops * self.num_layers
         self.tallies = (PASSES_TALLY, EXIT_TALLY)
@@ -142,7 +130,9 @@ class LoopedLM:
         import jax.numpy as jnp
 
         x = weights["tok_emb"][tokens].astype(jnp.float32)
-        cos, sin = self._rotary(positions, self.rope_theta)
+        # the rotary term covers every lane of a head
+        cos, sin = half_split_angles(positions, self.rope_theta,
+                                     self.head_dim)
         last = self.loops - 1
 
         def one_pass(t, carry):
@@ -190,17 +180,14 @@ class LoopedLM:
         """Where pass ``t`` of layer ``l`` keeps its K and V."""
         return t * self.num_layers + l
 
-    def _rms(self, x, g):
-        return rms_norm(x, g, self.rms_eps)
-
     def _between(self, weights, x, t):
         """The stream after pass ``t``: the final norm, after EVERY
         pass."""
-        return self._rms(x, weights["norm_f"])
+        return rms_norm(x, weights["norm_f"], self.rms_eps)
 
     def _out_norm(self, y, g):
         """The norm on a sub-block's OUTPUT, before the residual add."""
-        return self._rms(y, g)
+        return rms_norm(y, g, self.rms_eps)
 
     def _gate(self, weights, x):
         """``lambda`` of the normed rows ``x``: the exit gate, one
@@ -209,26 +196,23 @@ class LoopedLM:
 
         return jax.nn.sigmoid(x @ weights["exit_w"] + weights["exit_b"])
 
-    _rotary = WindowMoELM._rotary
-    _rotate = WindowMoELM._rotate
-
     def _layer(self, c, lw, x, cache, cos, sin, attend):
         """One application of the layer ``lw`` to the rows ``x``, its K
         and V at cache layer ``c`` -> (x, cache)."""
         import jax
         import jax.numpy as jnp
 
-        h = self._rms(x, lw["norm_attn_in"])
+        h = rms_norm(x, lw["norm_attn_in"], self.rms_eps)
         heads = (*x.shape[:-1], self.num_heads, self.head_dim)
-        q = self._rotate(_mm_t(h, lw["wq"]).reshape(heads), cos, sin)
-        k = self._rotate(_mm_t(h, lw["wk"]).reshape(heads), cos, sin)
+        q = half_split_rotate(_mm_t(h, lw["wq"]).reshape(heads), cos, sin)
+        k = half_split_rotate(_mm_t(h, lw["wk"]).reshape(heads), cos, sin)
         v = _mm(h, lw["wv"]).reshape(heads)
         ctx, cache = attend(c, q, k, v, cache)
         y = _mm(ctx.reshape(*x.shape[:-1], -1).astype(jnp.float32),
                 lw["wo"])
         x = x + self._out_norm(y, lw["norm_attn_out"])
-        with jax.named_scope(FFN_SCOPE):
-            h = self._rms(x, lw["norm_ffn_in"])
+        with jax.named_scope(DENSE_SCOPE):
+            h = rms_norm(x, lw["norm_ffn_in"], self.rms_eps)
             y = _mm(jax.nn.silu(_mm(h, lw["ffn_w_gate"]))
                     * _mm(h, lw["ffn_w_up"]), lw["ffn_w_down"])
         return x + self._out_norm(y, lw["norm_ffn_out"]), cache

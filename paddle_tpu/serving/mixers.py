@@ -1,0 +1,368 @@
+"""The two stateful mixers a served model composes, as mixins: what a
+layer keeps of a request lives with the engine (``attend``), what the
+layer computes of it is here, once, for every model that has such a
+layer.
+
+``KDAMixer``: the channel-decay gated delta rule (``"recurrent"``
+layers: a fixed-size float32 state a slot in slabs, no keys).  The rule
+has ONE definition in two forms, chosen by ``ops/pallas_kda_update.py``
+``kda_rule`` from the state's static shape alone: a float32 state of
+whole lane tiles (the served widths) goes through the kernel there,
+anything else (every toy width) through the XLA lines of
+``_kda_rule_xla``, which are also the tests' oracle; where the kernel
+takes the state a whole PROMPT runs the rule's chunk (WY) form on the
+matrix unit (``ops/pallas_kda_chunk.py``), a group of chunks a call.
+``HybridMoELM``'s and ``LinearLatentLM``'s.
+
+``LatentMixer``: latent attention (``"attention"`` layers whose cached
+row is ``[c | rot(k_r)]``, no V pool) in its two forms, expanded over a
+whole prompt and absorbed in the step.  ``LatentMoELM``'s and
+``LinearLatentLM``'s.
+
+A mixin reads the model's declared widths off ``self`` and imports no
+model file: ``blocks.py`` alone of ``serving/``
+(``tests/test_tooling.py`` holds the arrows).
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import numpy as np
+
+from ..ops import pallas_kda_chunk as kda_chunk
+from ..ops import pallas_kda_update as kda
+from .blocks import ROPE_SCOPE, _mm, rms_norm
+
+KDA_SCOPE = "kda_update"
+# tokens of one chunk of the rule's WY form, what the whole-prompt
+# prefill's scan counts as a step
+PREFILL_CHUNK = kda_chunk.CHUNK
+# float32 bytes of ONE call's temporaries the group of chunks is cut to
+# (the state passes through HBM once a call, the call's vectors are
+# formed at once): 256 tokens a call at 32 heads, 128 at 64.  PERF.md
+# section 6 (PR 58) has what groups of 1 to a whole bucket read on the
+# chip: they differ by 2-3 %, a call of a whole 256-row bucket at 64
+# heads by 18 %
+GROUP_BYTES = 32 << 20
+
+Q_PROJ_SCOPE = "latent_q_proj"          # q_a, its norm, q_b
+KV_PROJ_SCOPE = "latent_kv_proj"        # kv_a and the latent's norm
+ABSORB_Q_SCOPE = "latent_absorb_q"      # q_nope W_UK: into the row's space
+ABSORB_V_SCOPE = "latent_absorb_v"      # ctx_lat W_UV: out of it
+EXPAND_SCOPE = "latent_expand"          # a prompt's K and V from its rows
+
+
+class KDAMixer:
+    """The channel-decay delta-rule mixer of a model with ``lin_heads``
+    heads of ``lin_head_dim``, a convolution of ``conv_kernel`` taps,
+    low-rank gates of ``gate_rank`` and ``rms_eps``: its weights, one
+    slot's state, the mixer's residual term and the rule's two forms.
+    ``beta_scale`` is the range of the rule's step: ``(0, 2)`` where the
+    published config allows negative eigenvalues, ``(0, 1)`` else.
+    ``HybridMoELM``'s, and ``linear_latent_lm.py``'s."""
+
+    beta_scale = 2.0
+
+    def kda_state(self):
+        """One slot's state of ONE recurrent layer: the delta rule's
+        matrix a head, and the K-1 positions the convolution looks back
+        on, oldest first, side by side in one lane-dense row."""
+        c = self.lin_heads * self.lin_head_dim
+        return {
+            "s": ((self.lin_heads, self.lin_head_dim, self.lin_head_dim),
+                  np.float32),
+            "tail": (((self.conv_kernel - 1) * 3 * c,), np.float32)}
+
+    def kda_weights(self, dense, keys, ones):
+        """A recurrent layer's mixer weights; the decay's
+        ``A_log``/``dt_bias`` as the gated linear-attention families set
+        them (rates 1..16, steps 1e-3..1e-1: decays 0.2..0.999)."""
+        import jax
+        import jax.numpy as jnp
+
+        dm, r = self.d_model, self.gate_rank
+        c = self.lin_heads * self.lin_head_dim
+        rate = jax.random.uniform(next(keys), (self.lin_heads,),
+                                  jnp.float32, 1.0, 16.0)
+        step = jnp.exp(jax.random.uniform(
+            next(keys), (c,), jnp.float32,
+            math.log(1e-3), math.log(1e-1)))
+        return dict(
+            kda_wqkv=dense((dm, 3 * c)),
+            kda_conv=dense((self.conv_kernel, 3 * c),
+                           1.0 / math.sqrt(self.conv_kernel),
+                           jnp.float32),
+            kda_a_log=jnp.log(rate),
+            # softplus^-1(step)
+            kda_dt_bias=step + jnp.log(-jnp.expm1(-step)),
+            kda_wa_down=dense((dm, r)), kda_wa_up=dense((r, c)),
+            kda_wbeta=dense((dm, self.lin_heads)),
+            kda_wo_down=dense((dm, r)), kda_wo_up=dense((r, c)),
+            kda_onorm=ones(self.lin_head_dim),
+            kda_wout=dense((c, dm)))
+
+    def kda_mixer(self, l, lw, h, cache, attend):
+        """Recurrent layer ``l``'s residual term of the normed rows
+        ``h`` -> (``y``, cache)."""
+        import jax
+        import jax.numpy as jnp
+
+        rows = {"u": _mm(h, lw["kda_wqkv"]),
+                "gate": _mm(_mm(h, lw["kda_wa_down"]),
+                            lw["kda_wa_up"]),
+                "beta": _mm(h, lw["kda_wbeta"])}
+        o, cache = self._recur(l, lw, rows, cache, attend)
+        o = o * jax.lax.rsqrt(jnp.mean(
+            o * o, -1, keepdims=True) + self.rms_eps) \
+            * lw["kda_onorm"]
+        return _mm(o.reshape(*h.shape[:-1], -1) * jax.nn.sigmoid(_mm(
+            _mm(h, lw["kda_wo_down"]), lw["kda_wo_up"])),
+            lw["kda_wout"]), cache
+
+    def _recur(self, l, lw, rows, cache, attend):
+        """Recurrent layer ``l`` over the rows' projections -> (``o``,
+        cache).  Where the kernels take the state's shape a whole-prompt
+        prefill runs ``prefill_chunks_per_call`` of the rule's chunks a
+        call through ``_kda_chunk`` (the chunk form; the engine's loop
+        holds all of it), and a step, which runs the token rule through
+        ``_kda_token`` whatever else is handed over, counts the rows it
+        updated."""
+        import jax.numpy as jnp
+
+        token = functools.partial(self._kda_token, lw,
+                                  interpret=attend.interpret)
+        group = self.prefill_chunks_per_call(rows["u"].shape[0])
+        if not group:
+            return attend.recur(l, token, rows, cache)
+        if not attend.prompt:
+            attend.tally("kda_kernel_rows",
+                         jnp.sum(attend.live, dtype=jnp.int32))
+        return attend.recur(
+            l, token, rows, cache, chunk=group * PREFILL_CHUNK,
+            chunk_fn=functools.partial(self._kda_chunk, lw,
+                                       interpret=attend.interpret),
+            chunks_per_call=group)
+
+    def prefill_chunks_per_call(self, rows):
+        """Chunks of the rule's WY form (``PREFILL_CHUNK`` tokens) ONE
+        call of ``_kda_chunk`` takes of a prompt bucket of ``rows``
+        rows, where the kernels take the state: all of them, up to what
+        ``GROUP_BYTES`` of the call's float32 temporaries allow (a
+        token's: the convolved rows, q, k and v, the log decay and the
+        output, eight rows of all heads' lanes); else 0, no chunk form.
+        A function of the bucket and the model's widths alone."""
+        shape, dtype = self.recurrent_state["s"]
+        if not kda.kda_rule(*shape, dtype):
+            return 0
+        a_chunk = 4 * PREFILL_CHUNK * 8 * self.lin_heads * self.lin_head_dim
+        return max(1, min(-(-int(rows) // PREFILL_CHUNK),
+                          GROUP_BYTES // a_chunk))
+
+    def _kda_vectors(self, lw, conv, gate, beta, log_decay=False):
+        """What the rule takes of ``N`` tokens, from their convolved
+        rows ``conv [N, 3C]`` and the ``gate [N, C]`` and ``beta [N,
+        heads]`` projections -> (q, k, v, decay ``[N, heads, dk]``, beta
+        ``[N, heads]``): q and k at unit length a head, q scaled by
+        ``dk^-1/2``; the decay a channel in (0, 1), or with
+        ``log_decay`` its logarithm as it is formed (what the chunk
+        form sums: never above 0, and there where the factor itself
+        has underflowed); beta in (0, ``beta_scale``)."""
+        import jax
+        import jax.numpy as jnp
+
+        nh, dk = self.lin_heads, self.lin_head_dim
+        q, k, v = jnp.moveaxis(jax.nn.silu(conv).reshape(
+            -1, 3, nh, dk), 1, 0)
+        q = q * jax.lax.rsqrt(
+            jnp.sum(q * q, -1, keepdims=True) + 1e-6) / math.sqrt(dk)
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+        decay = -jnp.exp(lw["kda_a_log"])[:, None] * jax.nn.softplus(
+            gate + lw["kda_dt_bias"]).reshape(-1, nh, dk)
+        if not log_decay:
+            decay = jnp.exp(decay)
+        return q, k, v, decay, self.beta_scale * jax.nn.sigmoid(beta)
+
+    def _kda_token(self, lw, rows, state, live=None, interpret=False):
+        """One token a row through a recurrent layer: ``rows`` the
+        token's projections (``u [R, 3C]`` before the convolution,
+        ``gate [R, C]``, ``beta [R, heads]``), ``state`` the rows' state
+        BEFORE it (``s [R, heads, dk, dv]``, ``tail [R, (K-1)*3C]``) ->
+        (``o [R, heads, dv]``, the state after it).  All float32.
+
+        It takes ``live`` (bool ``[R]``; None: every row) and OWNS the
+        dead rows: a row that is not live comes back with the state it
+        had, so the caller passes over no slab to mask it again.  Where
+        ``kda_rule`` takes the state's shape the matrices go through the
+        kernel, read once and written once where they lie (a dead row's
+        blocks written back as read); else through ``_kda_rule_xla``
+        (read twice and written once, and once more for the mask)."""
+        import jax
+        import jax.numpy as jnp
+
+        with jax.named_scope(KDA_SCOPE):
+            c3 = rows["u"].shape[-1]
+            window = jnp.concatenate([state["tail"], rows["u"]], axis=1)
+            conv = sum(window[:, j * c3:(j + 1) * c3] * lw["kda_conv"][j]
+                       for j in range(self.conv_kernel))
+            q, k, v, decay, beta = self._kda_vectors(
+                lw, conv, rows["gate"], rows["beta"])
+            s0, tail = state["s"], window[:, c3:]
+            if kda.kda_rule(*s0.shape[1:], s0.dtype):
+                n = jnp.ones(s0.shape[:1], jnp.int32) if live is None \
+                    else live.astype(jnp.int32)
+                o, s = kda.kda_update(
+                    q[:, None], k[:, None], decay[:, None], v[:, None],
+                    beta[:, None], s0, n, interpret=interpret)
+                o = o[:, 0]
+            else:
+                o, s = _kda_rule_xla(q, k, v, decay, beta, s0)
+                if live is not None:
+                    s = jnp.where(live[:, None, None, None], s, s0)
+            if live is not None:
+                tail = jnp.where(live[:, None], tail, state["tail"])
+        return o, {"s": s, "tail": tail}
+
+    def _kda_chunk(self, lw, rows, n_real, state, interpret=False):
+        """A GROUP of whole ``PREFILL_CHUNK``-token chunks, consecutive
+        tokens of ONE request, through a recurrent layer in one call of
+        the chunk kernel (``ops/pallas_kda_chunk.py``: the rule's WY
+        form on the matrix unit, a chunk at a time over a state that
+        stays in VMEM; the step's kernel is the token rule itself and
+        this calls it nowhere): ``rows`` their projections (``u [N,
+        3C]``, ``gate``, ``beta``), of which the first ``n_real`` are
+        the request's (the kernel masks the rest, ``beta = 0`` and ``g =
+        0``, and skips chunks of nothing else: padding touches neither
+        the matrices nor the tail), ``state`` the request's before them
+        (leading dimension 1) -> (``o [N, heads, dv]``, zero past
+        ``n_real``; the state after token ``n_real - 1``).  The
+        convolution, the norms and beta are the token form's, over all
+        the call's rows at once; the decay is handed over as its
+        LOGARITHM, as ``_kda_vectors`` forms it, and every product of
+        the form has float32 operands at ``highest``."""
+        import jax
+        import jax.numpy as jnp
+
+        with jax.named_scope(KDA_SCOPE):
+            c, c3 = rows["u"].shape
+            window = jnp.concatenate(
+                [state["tail"].reshape(self.conv_kernel - 1, c3),
+                 rows["u"]])
+            conv = sum(window[j:j + c] * lw["kda_conv"][j]
+                       for j in range(self.conv_kernel))
+            q, k, v, log_decay, beta = self._kda_vectors(
+                lw, conv, rows["gate"], rows["beta"], log_decay=True)
+            o, s = kda_chunk.kda_chunk(
+                q[None], k[None], log_decay[None], v[None], beta[None],
+                state["s"], jnp.reshape(n_real, (1,)), interpret=interpret)
+            tail = jax.lax.dynamic_slice_in_dim(
+                window, n_real, self.conv_kernel - 1)
+        return o[0], {"s": s, "tail": tail.reshape(1, -1)}
+
+
+def _kda_rule_xla(q, k, v, decay, beta, s):
+    """The gated delta rule, one token a row, as XLA fusions: ``q``,
+    ``k``, ``v``, ``decay [R, heads, d]``, ``beta [R, heads]``, ``s [R,
+    heads, dk, dv]`` before the token -> (``o [R, heads, dv]``, ``s``
+    after it).  ``S'^T k`` and ``S'^T q`` come out of one pass over the
+    decayed state, and ``o = S'^T q + (k.q) b (v - S'^T k)`` is ``S_t^T
+    q`` without a third.  The form of every shape the kernel does not
+    take, and what the kernel is tested against."""
+    import jax.numpy as jnp
+
+    s = decay[..., None] * s                           # Diag(a) S
+    ks = jnp.sum(k[..., None] * s, axis=-2)            # S'^T k
+    qs = jnp.sum(q[..., None] * s, axis=-2)            # S'^T q
+    delta = beta[..., None] * (v - ks)
+    s = s + k[..., None] * delta[..., None, :]
+    return qs + jnp.sum(q * k, -1, keepdims=True) * delta, s
+
+
+class LatentMixer:
+    """Latent attention of a model with ``num_heads`` heads of
+    ``nope_dim + rope_dim`` query lanes and ``v_dim`` value lanes over a
+    latent of ``kv_rank``, ``softmax_scale`` and ``rms_eps``: its
+    weights, what the engine reads of it, and the two forms.  A model
+    says how its queries are made (``_queries``, ``_query_weights``) and
+    hands ``turn``, the rotary term's two factors, or None where its
+    last ``rope_dim`` lanes carry no position (they are then lanes like
+    the others: nothing turns them, not by the identity either).
+    ``LatentMoELM``'s, and ``linear_latent_lm.py``'s."""
+
+    def latent_declares(self):
+        """What the engine reads: ONE cached row a position, all heads'."""
+        self.num_kv_heads = 1
+        self.head_dim = self.kv_rank + self.rope_dim
+        self.v_head_dim = self.kv_rank
+        self.values_in_keys = True
+        self.prompt_heads = (self.num_heads, self.nope_dim + self.rope_dim,
+                             self.v_dim)
+
+    def latent_weights(self, dense, ones):
+        """A latent layer's mixer weights, the queries' first."""
+        h = self.num_heads
+        up = 1.0 / math.sqrt(self.kv_rank)
+        return {**self._query_weights(dense, ones),
+                "kv_norm": ones(self.kv_rank),
+                "wkv_a": dense((self.d_model, self.kv_rank + self.rope_dim)),
+                # kv_b_proj, split once: head h's keys are c W_UK[h]^T,
+                # its values c W_UV[h]
+                "w_uk": dense((h, self.nope_dim, self.kv_rank), up),
+                "w_uv": dense((h, self.kv_rank, self.v_dim), up),
+                "wo": dense((h * self.v_dim, self.d_model))}
+
+    def _attention(self, lw, l, h, turn, cache, attend):
+        """One layer's context ``[..., H, v_dim]`` of rows ``h``, in the
+        form the program asks for; ``turn``: the class docstring."""
+        import jax
+        import jax.numpy as jnp
+
+        lead, nh = h.shape[:-1], self.num_heads
+        with jax.named_scope(Q_PROJ_SCOPE):
+            q = self._queries(lw, h).reshape(
+                *lead, nh, self.nope_dim + self.rope_dim)
+        with jax.named_scope(KV_PROJ_SCOPE):
+            c, k_r = self._latent(lw, _mm(h, lw["wkv_a"]))
+        if turn is None:
+            q_rot, k_rot = q[..., self.nope_dim:], k_r[..., None, :]
+        else:
+            with jax.named_scope(ROPE_SCOPE):
+                q_rot = self._rotate(q[..., self.nope_dim:], *turn)
+                k_rot = self._rotate(k_r[..., None, :], *turn)
+        q_nope = q[..., :self.nope_dim]
+        row = jnp.concatenate([c[..., None, :], k_rot], axis=-1)
+        dt = lw["w_uk"].dtype
+        if attend.prompt:
+            with jax.named_scope(EXPAND_SCOPE):
+                cb = c.astype(dt)
+                k_nope = jnp.einsum("...c,hdc->...hd", cb, lw["w_uk"],
+                                    preferred_element_type=jnp.float32)
+                v = jnp.einsum("...c,hcd->...hd", cb, lw["w_uv"],
+                               preferred_element_type=jnp.float32)
+                k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                    k_rot, (*lead, nh, self.rope_dim))], axis=-1)
+            return attend(l, self._scaled(
+                jnp.concatenate([q_nope, q_rot], axis=-1)), k, v, cache,
+                keep=row)
+        with jax.named_scope(ABSORB_Q_SCOPE):
+            q_lat = jnp.einsum("...hd,hdc->...hc", q_nope.astype(dt),
+                               lw["w_uk"],
+                               preferred_element_type=jnp.float32)
+        ctx_lat, cache = attend(l, self._scaled(
+            jnp.concatenate([q_lat, q_rot], axis=-1)), row, None, cache)
+        with jax.named_scope(ABSORB_V_SCOPE):
+            return jnp.einsum("...hc,hcd->...hd", ctx_lat.astype(dt),
+                              lw["w_uv"],
+                              preferred_element_type=jnp.float32), cache
+
+    def _latent(self, lw, kv):
+        """(c, k_r) of ``kv = h W_kva``: the norm is the latent's alone,
+        the shared key is not normed."""
+        return rms_norm(kv[..., :self.kv_rank], lw["kv_norm"],
+                        self.rms_eps), kv[..., self.kv_rank:]
+
+    def _scaled(self, q):
+        """The engine's attention divides scores by the square root of
+        the query's width; what the softmax's scale holds beyond that
+        (the heads' own width, YaRN's ``mscale^2``) rides the query."""
+        return q * (self.softmax_scale * math.sqrt(q.shape[-1]))

@@ -13,8 +13,9 @@ A+'s (``cohere2_moe``); the equations are in the reference's docstring
 which this file is tested against and shares no code with.
 
 It sits behind ``DecodeEngine`` on the contract in that class's
-docstring, like ``window_moe_lm.py``: ``forward(weights, tokens,
-positions, cache, attend)``.  What it declares: ``layer_kinds``
+docstring (the matmul feed, the rotary pairing and the routed share are
+``blocks.py``'s): ``forward(weights, tokens, positions, cache,
+attend)``.  What it declares: ``layer_kinds``
 (``"attention"`` or ``"window"`` a layer), ``num_kv_heads`` /
 ``window_kv_heads`` (the same count here), ``head_dim`` / ``v_head_dim``
 (the same width), ``window``, ``tallies``.  The engine decides where
@@ -31,10 +32,9 @@ import math
 from typing import Sequence
 
 from ..ops import moe_ops
-from .hybrid_moe_lm import _mm, route_share, share_ffn, step_tallies
-from .window_moe_lm import ROPE_SCOPE
-
-SHARED_SCOPE = "moe_shared"
+from .blocks import (MOE_SHARED_SCOPE, ROPE_SCOPE, _mm, adjacent_angles,
+                     adjacent_rotate_negated_partner, dense_from, held_ids,
+                     route_share, share_ffn, step_tallies)
 
 
 class ParallelMoELM:
@@ -72,12 +72,7 @@ class ParallelMoELM:
         self.rotary_kinds = ("window",)
         self.window = int(window)
         self.num_experts, self.top_k = int(num_experts), int(top_k)
-        self.held_experts = tuple(int(e) for e in held_experts)
-        if not self.held_experts or min(self.held_experts) < 0 \
-                or max(self.held_experts) >= self.num_experts \
-                or len(set(self.held_experts)) != len(self.held_experts):
-            raise ValueError(
-                f"held_experts must be distinct ids below {num_experts}")
+        self.held_experts = held_ids(held_experts, self.num_experts)
         self.expert_dim = int(expert_dim)
         self.shared_experts = int(shared_experts)
         self.shared_dim = int(shared_dim)
@@ -112,11 +107,7 @@ class ParallelMoELM:
         sf = self.shared_experts * self.shared_dim
         keys = iter(jax.random.split(key, 2 + 12 * self.num_layers))
 
-        def dense(shape, scale=None, dtype=dt):
-            scale = 1.0 / math.sqrt(shape[0]) if scale is None else scale
-            return (jax.random.normal(next(keys), shape, jnp.float32)
-                    * scale).astype(dtype)
-
+        dense = dense_from(keys, dt)
         ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
         w = {"tok_emb": dense((self.vocab_size, dm), 1.0),
              "norm_f": ones(dm), "layers": []}
@@ -177,7 +168,7 @@ class ParallelMoELM:
             h, dict(lw, moe_router_bias=jnp.zeros((self.num_experts,),
                                                   jnp.float32)),
             attend, self.top_k, self.held_experts)
-        with jax.named_scope(SHARED_SCOPE):
+        with jax.named_scope(MOE_SHARED_SCOPE):
             # the concatenated down-projection SUMS the shared experts
             shared = _mm(jax.nn.silu(_mm(h, lw["shared_w_gate"]))
                          * _mm(h, lw["shared_w_up"]),
@@ -205,22 +196,7 @@ class ParallelMoELM:
     def _rotary(self, positions):
         """(cos, sin) ``[..., 1, head_dim]`` of the rotary angles at
         ``positions [...]``, each pair's angle on both of its lanes."""
-        import jax.numpy as jnp
+        return adjacent_angles(positions, self.rope_theta, self.head_dim)
 
-        pair = jnp.arange(self.head_dim, dtype=jnp.int32) // 2
-        freq = self.rope_theta ** (
-            -2.0 * pair.astype(jnp.float32) / self.head_dim)
-        angle = positions.astype(jnp.float32)[..., None, None] * freq
-        return jnp.cos(angle), jnp.sin(angle)
-
-    def _rotate(self, x, cos, sin):
-        """The rotary term on ADJACENT lanes of every head of ``x [...,
-        heads, D]``: lanes ``(2j, 2j + 1)`` turn together.  Each lane's
-        partner comes by a roll along the lanes, so no head is cut into
-        pairs (a trailing dimension of 2 pads 64-fold on the chip)."""
-        import jax.numpy as jnp
-
-        even = jnp.arange(self.head_dim, dtype=jnp.int32) % 2 == 0
-        partner = jnp.where(even, -jnp.roll(x, -1, axis=-1),
-                            jnp.roll(x, 1, axis=-1))
-        return x * cos + partner * sin
+    # on ADJACENT lanes of every head: lanes ``(2j, 2j + 1)`` turn together
+    _rotate = staticmethod(adjacent_rotate_negated_partner)

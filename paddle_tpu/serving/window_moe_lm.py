@@ -11,8 +11,9 @@ in ``tests/``), which this file is tested against and shares no code
 with.
 
 It sits behind ``DecodeEngine`` on the contract in that class's
-docstring, like ``hybrid_moe_lm.py`` (whose matmul, norm and routing
-helpers it uses): ``forward(weights, tokens, positions, cache, attend)``.
+docstring (the matmul feed, the norm, the rotary pairing and the routed
+share are ``blocks.py``'s): ``forward(weights, tokens, positions, cache,
+attend)``.
 What it declares: ``layer_kinds`` (``"attention"`` or ``"window"`` a
 layer), ``num_kv_heads`` / ``window_kv_heads``, ``head_dim`` (K) and
 ``v_head_dim``, ``window``, ``tallies``.  A window layer hands its
@@ -30,11 +31,9 @@ import math
 from typing import Sequence
 
 from ..ops import moe_ops
-from .hybrid_moe_lm import (_mm, rms_norm, route_share, share_ffn,
-                            step_tallies)
-
-ROPE_SCOPE = "rope"
-DENSE_SCOPE = "dense_ffn"
+from .blocks import (DENSE_SCOPE, ROPE_SCOPE, _mm, dense_from,
+                     half_split_angles, half_split_rotate, held_ids,
+                     rms_norm, route_share, share_ffn, step_tallies)
 
 
 class WindowMoELM:
@@ -81,12 +80,7 @@ class WindowMoELM:
         self.value_scale = float(value_scale)
         self.dense_dim = int(dense_dim)
         self.num_experts, self.top_k = int(num_experts), int(top_k)
-        self.held_experts = tuple(int(e) for e in held_experts)
-        if not self.held_experts or min(self.held_experts) < 0 \
-                or max(self.held_experts) >= self.num_experts \
-                or len(set(self.held_experts)) != len(self.held_experts):
-            raise ValueError(
-                f"held_experts must be distinct ids below {num_experts}")
+        self.held_experts = held_ids(held_experts, self.num_experts)
         self.expert_dim = int(expert_dim)
         self.rms_eps = float(rms_eps)
         self.dtype = str(dtype)
@@ -118,11 +112,7 @@ class WindowMoELM:
         nf = len(self.held_experts) * f
         keys = iter(jax.random.split(key, 4 + 12 * self.num_layers))
 
-        def dense(shape, scale=None, dtype=dt):
-            scale = 1.0 / math.sqrt(shape[0]) if scale is None else scale
-            return (jax.random.normal(next(keys), shape, jnp.float32)
-                    * scale).astype(dtype)
-
+        dense = dense_from(keys, dt)
         ones = lambda n: jnp.ones((n,), jnp.float32)  # noqa: E731
         w = {"tok_emb": dense((v, dm), 1.0), "lm_head": dense((dm, v)),
              "norm_f": ones(dm), "layers": []}
@@ -168,10 +158,11 @@ class WindowMoELM:
             k = _mm(h, lw["wk"]).reshape(*lead, hkv, self.head_dim)
             v = _mm(h, lw["wv"]).reshape(*lead, hkv, self.v_head_dim)
             with jax.named_scope(ROPE_SCOPE):
-                turn = self._rotary(
+                turn = half_split_angles(
                     positions, self.window_rope_theta if kind == "window"
-                    else self.rope_theta)
-                q, k = self._rotate(q, *turn), self._rotate(k, *turn)
+                    else self.rope_theta, self.rotary_dim)
+                q, k = (half_split_rotate(q, *turn),
+                        half_split_rotate(k, *turn))
             if kind == "window":
                 ctx, cache = attend(l, q, k, v, cache, sinks=lw["sink"])
             else:
@@ -189,25 +180,3 @@ class WindowMoELM:
                 x = x + share_ffn(self, h, lw, local, attend)
         return _mm(rms_norm(x, w["norm_f"], self.rms_eps),
                    w["lm_head"]), cache
-
-    def _rotary(self, positions, theta):
-        """(cos, sin) ``[..., 1, rotary_dim / 2]`` of the rotary angles
-        at ``positions [...]`` and base ``theta``."""
-        import jax.numpy as jnp
-
-        half = self.rotary_dim // 2
-        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-        angle = positions.astype(jnp.float32)[..., None, None] * freq
-        return jnp.cos(angle), jnp.sin(angle)
-
-    def _rotate(self, x, cos, sin):
-        """The rotary term on the first ``rotary_dim`` lanes of every
-        head of ``x [..., heads, D]``: lane j pairs with lane ``j +
-        rotary_dim / 2``; the other lanes pass."""
-        import jax.numpy as jnp
-
-        half = self.rotary_dim // 2
-        a, b = x[..., :half], x[..., half:2 * half]
-        return jnp.concatenate(
-            [a * cos - b * sin, b * cos + a * sin, x[..., 2 * half:]],
-            axis=-1)

@@ -462,7 +462,7 @@ def test_the_flash_kernel_declines_heads_of_64_lanes():
 
 
 @pytest.mark.parametrize("cfg, names", [
-    (dict(prefill_chunk_pages=1), "chunked/ragged prefill"),
+    (dict(prefill_chunk_pages=1), "chunked prefill"),
     (dict(spec_k=2), "speculative decoding"),
     (dict(kv_quant=True), "kv_quant"),
 ], ids=["chunked", "speculative", "kv_quant"])
@@ -539,3 +539,34 @@ def test_the_reference_at_a_stated_precision_rounds_operands_and_no_more():
     below = rms_off(operands="bfloat16", results="bfloat16")
     assert 1e-3 < stated < 3e-2 and stated < below < 6e-2
     assert float(jnp.abs(want - followed).max()) > 0
+
+
+# sha256 of the lowered text of ``make_model()``'s joint step and 8-row
+# whole-prompt prefill (the smallest bucket) behind ``engine()``, as
+# PR 58's tree lowers them (taken on that commit, before the blocks this
+# model shares with others moved out of the others' files: PR 59)
+PROGRAMS_AS_LOWERED = {
+    "step": "a2ddc6fa9abb5c9b476ef1b9f7c36fb7f940780a46365d10c4989612caa2735c",
+    "prefill":
+        "b4a11d14c88d72f2f1352cc7e17226689a6a962b6fb80600ff0749f54ae90a40"}
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_the_programs_are_still_the_ones_lowered_before_the_block_library(
+        program):
+    """The matmul feed, the norm, the half-split rotary pairing and the
+    routed share are ``serving/blocks.py``'s, functions of what they
+    read; this model borrows no other model's methods any more (PR 59).
+    Where they are written moves no line of what they lower to: the
+    joint step and the whole-prompt prefill are the text they were.  A
+    change MEANT to move these programs replaces the digests; one that
+    was not has found out here."""
+    import hashlib
+
+    model = make_model()
+    weights = model.init_weights(jax.random.PRNGKey(1))
+    eng = engine(model, weights)
+    text = (eng.lower_step() if program == "step"
+            else eng.lower_prefill(8)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PROGRAMS_AS_LOWERED[program]

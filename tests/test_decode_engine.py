@@ -1352,39 +1352,6 @@ def test_decode_server_least_loaded_dispatch_and_stats(
         srv.stop()
 
 
-def test_one_shot_mode_vs_continuous_admission(model_and_weights):
-    """continuous=False degrades to group admission (the static-batching
-    baseline): a follow-up request cannot start until the WHOLE group
-    finishes, while the continuous engine admits it mid-flight."""
-    model, weights = model_and_weights
-    cfg = dict(slots=2, max_seq_len=128, max_new_tokens=64)
-    eng = DecodeEngine(model, weights, DecodeConfig(**cfg),
-                       continuous=False).start()
-    try:
-        long_r = eng.submit([1, 2], max_new_tokens=50)
-        short_r = eng.submit([3, 4], max_new_tokens=2)
-        short_r.result(timeout=120)
-        third = eng.submit([5, 6], max_new_tokens=2)
-        third.result(timeout=120)
-        # group mode: the third request could only start after the
-        # long request's group fully drained
-        assert long_r.done()
-    finally:
-        eng.stop()
-    eng = DecodeEngine(model, weights, DecodeConfig(**cfg),
-                       continuous=True).start()
-    try:
-        long_r = eng.submit([1, 2], max_new_tokens=50)
-        for _ in long_r.tokens(timeout=60):
-            break
-        third = eng.submit([5, 6], max_new_tokens=2)
-        third.result(timeout=120)
-        assert not long_r.done()  # joined mid-flight, left early
-        long_r.result(timeout=120)
-    finally:
-        eng.stop()
-
-
 # sha256 of the lowered text of the module fixture's model's joint step
 # and 64-row whole-prompt prefill behind ``make_engine``, as PR 48's tree
 # lowers them (taken before PR 50 touched the engine)

@@ -569,80 +569,12 @@ def test_decode_server_aggregates_tentpole_stats(model_and_weights):
     assert all("cache_hit_rate" in p for p in st["replicas"])
 
 
-# -- ragged prefill packing (ISSUE 17) ------------------------------------
-
-
-def _run_prompts(eng, prompts, new=4):
-    """Submit concurrently, return ([outputs], [logits traces])."""
-    try:
-        reqs = [eng.submit(p, max_new_tokens=new, record_logits=True)
-                for p in prompts]
-        outs = [r.result(timeout=120) for r in reqs]
-    finally:
-        eng.stop()
-    return outs, [r.logits_trace for r in reqs]
-
-
-def test_ragged_prefill_bitwise_and_waste_drop(model_and_weights):
-    """FLAGS_decode_ragged_prefill packs several prompts' chunk tails
-    into one multi-lane dispatch (per-lane (page, offset) coords).
-    Contract: decoded tokens AND per-step logits stay bitwise equal to
-    the padded chunk path, while the measured prefill pad waste
-    (record_pad_waste counters) strictly drops — padding rounded 27/13/5
-    up to 8-row chunks (56 rows), packing shares 3x16 lanes (48 rows)."""
-    prompts = [list(range(1, 28)), [7, 3, 9, 2, 11, 5, 4, 8, 6, 1, 2, 3,
-                                    4], [5, 1, 2, 4, 3]]
-
-    def waste_fraction(run):
-        p0 = stat_get("prefill_padded_tokens_total")
-        l0 = stat_get("prefill_live_tokens_total")
-        result = run()
-        pad = stat_get("prefill_padded_tokens_total") - p0
-        live = stat_get("prefill_live_tokens_total") - l0
-        assert pad + live > 0, "no prefill dispatches accounted"
-        return result, pad / (pad + live)
-
-    (pad_outs, pad_logits), frac_padded = waste_fraction(
-        lambda: _run_prompts(make_engine(
-            model_and_weights, slots=4, prefill_chunk_pages=1,
-            prefix_cache=False).start(), prompts))
-    r0 = stat_get("decode_ragged_dispatches")
-    (rag_outs, rag_logits), frac_ragged = waste_fraction(
-        lambda: _run_prompts(make_engine(
-            model_and_weights, slots=4, prefill_chunk_pages=1,
-            prefix_cache=False, ragged_prefill_rows=16).start(),
-            prompts))
-
-    assert stat_get("decode_ragged_dispatches") - r0 >= 1
-    assert rag_outs == pad_outs, "ragged packing changed decoded tokens"
-    for pt_, rt in zip(pad_logits, rag_logits):
-        assert len(pt_) == len(rt)
-        for a, b in zip(pt_, rt):
-            assert np.array_equal(a, b), \
-                "ragged packing changed a recorded logits row"
-    assert frac_ragged < frac_padded, (
-        f"ragged packing did not reduce prefill pad waste "
-        f"({frac_ragged:.4f} vs {frac_padded:.4f})")
-
-
-def test_ragged_prefill_single_prompt_bitwise(model_and_weights):
-    """Degenerate packing (one request, dead lanes to the trash page)
-    must still be bitwise vs the full-recompute oracle."""
-    eng = make_engine(model_and_weights, slots=2, prefill_chunk_pages=1,
-                      prefix_cache=False, ragged_prefill_rows=16).start()
-    prompt = list(range(1, 28))
-    try:
-        r = eng.submit(prompt, max_new_tokens=5, record_logits=True)
-        out = r.result(timeout=120)
-    finally:
-        eng.stop()
-    assert_oracle_bitwise(eng, prompt, r, out)
+# -- the padded paths' waste ------------------------------------------------
 
 
 def test_pad_waste_gauge_accounts_padded_path(model_and_weights):
-    """Satellite bugfix: the pad-waste gauge must move on the PADDED
-    paths too (full prefill and chunked rows), not only under ragged
-    packing — otherwise the A/B has no baseline."""
+    """The pad-waste gauge moves on the padded paths (full prefill
+    and chunked rows): what a bucket costs in dead rows is counted."""
     from paddle_tpu.serving.buckets import record_pad_waste
 
     w0 = stat_get("prefill_padded_tokens_total")

@@ -400,7 +400,6 @@ def test_a_prefix_hit_reads_what_was_registered_under_a_step_in_flight(lm):
 @pytest.mark.parametrize("path,over", [
     ("speculative", {"spec_k": 2, "draft": True, "prefix_cache": True}),
     ("chunked", {"prefill_chunk_pages": 1}),
-    ("ragged", {"prefill_chunk_pages": 1, "ragged_prefill_rows": 16}),
     ("suffix", {"prefix_cache": True}),
 ])
 def test_the_other_paths_give_the_tokens_they_gave(lm, path, over):

@@ -290,12 +290,10 @@ def test_the_unit_lower_inverse_inverts():
 # -- the engine's side ------------------------------------------------------
 
 @pytest.mark.parametrize("cfg, names", [
-    (dict(prefill_chunk_pages=1), "chunked/ragged prefill"),
-    (dict(prefill_chunk_pages=1, ragged_prefill_rows=8),
-     "chunked/ragged prefill"),
+    (dict(prefill_chunk_pages=1), "chunked prefill"),
     (dict(spec_k=2), "speculative decoding"),
     (dict(kv_quant=True), "kv_quant"),
-], ids=["chunked", "ragged", "speculative", "kv_quant"])
+], ids=["chunked", "speculative", "kv_quant"])
 def test_what_cannot_carry_recurrent_state_refuses_by_name(cfg, names):
     model = make_model(PERIOD)
     weights = model.init_weights(jax.random.PRNGKey(14))

@@ -15,7 +15,7 @@ from paddle_tpu.ops import moe_ops
 from paddle_tpu.ops import pallas_decode_attention as pda
 from paddle_tpu.serving import DecodeConfig, DecodeEngine
 from paddle_tpu.serving import decode as decode_mod
-from paddle_tpu.serving import hybrid_moe_lm as hybrid
+from paddle_tpu.serving import mixers
 from paddle_tpu.serving.hybrid_moe_lm import HybridMoELM
 
 from benchmark.reference import hybrid_moe_lm as ref
@@ -108,7 +108,7 @@ widths = pytest.mark.parametrize("widths", list(WIDTHS))
 @pytest.fixture
 def short_chunks(monkeypatch):
     """Prefill chunks of 16 tokens: a test's prompts span several."""
-    monkeypatch.setattr(hybrid, "PREFILL_CHUNK", 16)
+    monkeypatch.setattr(mixers, "PREFILL_CHUNK", 16)
 
 
 @pytest.mark.parametrize("kinds", [("recurrent",), PERIOD],
@@ -425,12 +425,10 @@ def test_a_long_prompts_prefill_groups_its_pairs_and_counts_them():
 
 
 @pytest.mark.parametrize("cfg, names", [
-    (dict(prefill_chunk_pages=1), "chunked/ragged prefill"),
-    (dict(prefill_chunk_pages=1, ragged_prefill_rows=8),
-     "chunked/ragged prefill"),
+    (dict(prefill_chunk_pages=1), "chunked prefill"),
     (dict(spec_k=2), "speculative decoding"),
     (dict(kv_quant=True), "kv_quant"),
-], ids=["chunked", "ragged", "speculative", "kv_quant"])
+], ids=["chunked", "speculative", "kv_quant"])
 def test_what_cannot_carry_recurrent_state_refuses_by_name(cfg, names):
     model = make_model(PERIOD)
     weights = model.init_weights(jax.random.PRNGKey(14))
@@ -599,7 +597,7 @@ def test_a_prefills_calls_cover_the_buckets_chunks(widths):
     if widths == "toy":
         assert model.prefill_chunks_per_call(4096) == 0
         return
-    assert hybrid.PREFILL_CHUNK == chunked.CHUNK == 64
+    assert mixers.PREFILL_CHUNK == chunked.CHUNK == 64
     # 8 heads of 128: 2 MiB of temporaries a chunk, 16 chunks under the cap
     assert [model.prefill_chunks_per_call(r)
             for r in (8, 64, 72, 128, 256, 2048, 4096)] \
@@ -671,7 +669,7 @@ def chunk_form(model, lw, rows, state, length, group):
     hands them to ``_kda_chunk``: ``group`` chunks a call, the last call
     told how many of its rows are real -> (``o`` of all rows, the state
     after token ``length - 1``)."""
-    per_call = group * hybrid.PREFILL_CHUNK
+    per_call = group * mixers.PREFILL_CHUNK
     call = _jitted(model, "chunk")
     outs = []
     for lo in range(0, max(length, 1), per_call):
@@ -684,7 +682,7 @@ def chunk_form(model, lw, rows, state, length, group):
 def token_form(model, lw, rows, state, length, monkeypatch):
     """The same rows one after another through the one-token update's
     XLA lines (``_kda_rule_xla``: the decay as a FACTOR, no chunk)."""
-    monkeypatch.setattr(hybrid.kda, "kda_rule", lambda *a: False)
+    monkeypatch.setattr(mixers.kda, "kda_rule", lambda *a: False)
     step = _jitted(model, "token")
     outs = []
     for t in range(length):
@@ -699,7 +697,7 @@ def assert_the_chunk_form_is_the_token_form(model, case, length, group,
     ``length`` zero."""
     lw, rows, state = case
     rows_run = rows_run or -(-max(length, 1) // (
-        group * hybrid.PREFILL_CHUNK)) * group * hybrid.PREFILL_CHUNK
+        group * mixers.PREFILL_CHUNK)) * group * mixers.PREFILL_CHUNK
     rows = {k: v[:rows_run] for k, v in rows.items()}
     o, new = chunk_form(model, lw, rows, state, length, group)
     want_o, want = token_form(model, lw, rows, state, length, monkeypatch)

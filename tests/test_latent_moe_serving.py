@@ -277,12 +277,10 @@ def test_flash_rule_takes_ungrouped_heads_of_192_and_nothing_else_moves():
 
 
 @pytest.mark.parametrize("cfg, names", [
-    (dict(prefill_chunk_pages=1), "latent page.*chunked/ragged prefill"),
-    (dict(prefill_chunk_pages=1, ragged_prefill_rows=8),
-     "latent page.*chunked/ragged prefill"),
+    (dict(prefill_chunk_pages=1), "latent page.*chunked prefill"),
     (dict(spec_k=2), "latent page.*speculative decoding"),
     (dict(kv_quant=True), "latent page.*kv_quant"),
-], ids=["chunked", "ragged", "speculative", "kv_quant"])
+], ids=["chunked", "speculative", "kv_quant"])
 def test_what_is_not_built_for_a_latent_page_refuses_by_name(cfg, names):
     model = make_model()
     weights = model.init_weights(jax.random.PRNGKey(18))

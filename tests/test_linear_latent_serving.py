@@ -17,7 +17,7 @@ from paddle_tpu.ops import pallas_prompt_attention as ppa
 from paddle_tpu.serving import (CacheConfig, DecodeConfig, DecodeEngine,
                                 PagedKVCache)
 from paddle_tpu.serving import decode as decode_mod
-from paddle_tpu.serving import hybrid_moe_lm as hybrid
+from paddle_tpu.serving import HybridMoELM, mixers
 from paddle_tpu.serving.kv_cache import RecurrentSpec
 from paddle_tpu.serving.linear_latent_lm import LinearLatentLM
 
@@ -101,7 +101,7 @@ def blocks_of_128(monkeypatch):
 @pytest.fixture
 def short_chunks(monkeypatch):
     """Prefill chunks of 16 tokens: a test's prompts span several."""
-    monkeypatch.setattr(hybrid, "PREFILL_CHUNK", 16)
+    monkeypatch.setattr(mixers, "PREFILL_CHUNK", 16)
 
 
 # linear heads of whole lane tiles in whole sublane tiles (``kda_rule``):
@@ -288,12 +288,10 @@ def test_a_latent_cache_with_a_recurrent_spec_holds_both_and_splits():
 
 
 @pytest.mark.parametrize("cfg, names", [
-    (dict(prefill_chunk_pages=1), "chunked/ragged prefill"),
-    (dict(prefill_chunk_pages=1, ragged_prefill_rows=8),
-     "chunked/ragged prefill"),
+    (dict(prefill_chunk_pages=1), "chunked prefill"),
     (dict(spec_k=2), "speculative decoding"),
     (dict(kv_quant=True), "kv_quant"),
-], ids=["chunked", "ragged", "speculative", "kv_quant"])
+], ids=["chunked", "speculative", "kv_quant"])
 def test_each_refusal_names_its_mechanism_for_both_of_the_models_kinds(
         cfg, names):
     """What cannot carry a state a slot cannot carry a latent page
@@ -343,7 +341,7 @@ def test_the_tallies_are_both_siblings_and_no_rotary_work_is_traced():
     assert model.tallies == eng._tallies + moe_ops.HIT_TALLIES
     assert eng._prefill_tallies == decode_mod._SCAN_TALLIES \
         + moe_ops.GROUPED_TALLIES
-    assert model.beta_scale == 1.0 and hybrid.HybridMoELM.beta_scale == 2.0
+    assert model.beta_scale == 1.0 and HybridMoELM.beta_scale == 2.0
     for text in (eng.lower_step().as_text(),
                  eng.lower_prefill(64).as_text()):
         assert "sine" not in text and "cosine" not in text

@@ -264,3 +264,34 @@ def test_the_lowered_step_holds_one_loop_over_the_passes_and_one_kernel():
     whiles = [ln for ln in text.splitlines()
               if "stablehlo.while" in ln and pool in ln]
     assert len(whiles) == 2, whiles
+
+
+# sha256 of the lowered text of ``make_model()``'s joint step and 8-row
+# whole-prompt prefill (the smallest bucket) behind ``engine()``, as
+# PR 58's tree lowers them (taken on that commit, before the blocks this
+# model shares with others moved out of the others' files: PR 59)
+PROGRAMS_AS_LOWERED = {
+    "step": "fd488ac01ad625e3a27745e473d86e05d5cc671a41a31b5782d579409bc131fc",
+    "prefill":
+        "d4866e965376e1d5c86721f42d8b9a881f00c1a0129d8df874531890bf4eca9d"}
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_the_programs_are_still_the_ones_lowered_before_the_block_library(
+        program):
+    """The two matmul feeds, the norm and the half-split rotary pairing
+    are ``serving/blocks.py``'s, functions of what they read; this model
+    borrows no other model's methods any more (PR 59).  Where they are
+    written moves no line of what they lower to: the joint step and the
+    whole-prompt prefill are the text they were.  A change MEANT to move
+    these programs replaces the digests; one that was not has found out
+    here."""
+    import hashlib
+
+    model = make_model()
+    weights = model.init_weights(jax.random.PRNGKey(1))
+    eng = engine(model, weights)
+    text = (eng.lower_step() if program == "step"
+            else eng.lower_prefill(8)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PROGRAMS_AS_LOWERED[program]

@@ -153,20 +153,24 @@ class TestTracer:
             pass
         assert observe.snapshot() == []
 
-    def test_disabled_overhead_near_zero(self):
-        """ISSUE acceptance: tracer off => near-zero per-span cost.  10k
-        disabled spans must stay far under a millisecond each (generous
-        CI bound; typical is <1us)."""
-        import time
+    def test_disabled_overhead_near_zero(self, monkeypatch):
+        """ISSUE acceptance: tracer off => near-zero per-span cost, as
+        counts no loaded CPU moves: of 10k disabled spans NONE reaches
+        the ring buffer's ``begin`` or ``end`` (its two clock reads and
+        its record), none is recorded and none is left open."""
+        from paddle_tpu.observe import tracer
 
         observe.disable()
-        n = 10_000
-        t0 = time.perf_counter()
-        for _ in range(n):
-            with observe.span("off"):
+        observe.clear()
+        ring_calls = []
+        for name in ("begin", "end", "record"):
+            monkeypatch.setattr(tracer._TRACER, name,
+                                lambda *a, _n=name: ring_calls.append(_n))
+        for _ in range(10_000):
+            with observe.span("off", bytes=1):
                 pass
-        per_span = (time.perf_counter() - t0) / n
-        assert per_span < 50e-6, f"disabled span cost {per_span * 1e6:.1f}us"
+        assert ring_calls == [] and observe.snapshot() == []
+        assert tracer.open_spans() == [] and not tracer.recording()
 
     def test_nesting_and_args(self, tracer_on):
         with observe.span("outer", phase="x"):

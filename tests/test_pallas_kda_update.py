@@ -1,6 +1,6 @@
 """``ops/pallas_kda_update.py`` (the gated delta rule's TOKEN form with
 a head's state held in VMEM) interpreted on the CPU, at lanes of 128,
-against the XLA form it stands in for (``serving/hybrid_moe_lm.py``
+against the XLA form it stands in for (``serving/mixers.py``
 ``_kda_rule_xla`` and the one-token update built on it).  Its grid over
 one row's ``T > 1`` tokens served a prompt until PR 58 and serves no
 model since (the prompt's form is ``ops/pallas_kda_chunk.py``, tested in
@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.ops import pallas_kda_update as kda
-from paddle_tpu.serving import hybrid_moe_lm as hybrid
+from paddle_tpu.serving import HybridMoELM, mixers
 
 D = 128
 
@@ -36,7 +36,7 @@ def _kernel(x, state, n):
 
 
 def _xla_token(x, t, state):
-    return hybrid._kda_rule_xla(
+    return mixers._kda_rule_xla(
         x["q"][:, t], x["k"][:, t], x["v"][:, t], x["decay"][:, t],
         x["beta"][:, t], state)
 
@@ -89,7 +89,7 @@ def _model(**kw):
                  held_experts=(0, 1, 2), expert_dim=16, shared_dim=16,
                  dtype="float32")
     sizes.update(kw)
-    model = hybrid.HybridMoELM(**sizes)
+    model = HybridMoELM(**sizes)
     return model, model.init_weights(jax.random.PRNGKey(3))["layers"][1]
 
 
@@ -120,7 +120,7 @@ def test_a_chunk_is_its_real_tokens_one_by_one(n_real, monkeypatch):
     rows, state = _projections(model, rng, chunk), _state(model, rng, 1)
     o, new = model._kda_chunk(lw, rows, jnp.int32(n_real), state,
                               interpret=True)
-    monkeypatch.setattr(hybrid.kda, "kda_rule", lambda *a: False)
+    monkeypatch.setattr(mixers.kda, "kda_rule", lambda *a: False)
     want = state
     for t in range(n_real):
         want_o, want = model._kda_token(
@@ -140,7 +140,7 @@ def test_the_token_update_owns_its_dead_rows_in_both_forms(monkeypatch):
     rows, state = _projections(model, rng, 4), _state(model, rng, 4)
     live = jnp.asarray([True, False, True, True])
     o, new = model._kda_token(lw, rows, state, live=live, interpret=True)
-    monkeypatch.setattr(hybrid.kda, "kda_rule", lambda *a: False)
+    monkeypatch.setattr(mixers.kda, "kda_rule", lambda *a: False)
     want_o, want = model._kda_token(lw, rows, state, live=live)
     np.testing.assert_allclose(o[np.asarray(live)],
                                want_o[np.asarray(live)], atol=1e-5)

@@ -14,7 +14,7 @@ from paddle_tpu.monitor import stat_get
 from paddle_tpu.ops import moe_ops
 from paddle_tpu.ops import pallas_decode_attention as pda
 from paddle_tpu.serving import DecodeConfig, DecodeEngine
-from paddle_tpu.serving.hybrid_moe_lm import rms_norm
+from paddle_tpu.serving.blocks import rms_norm
 from paddle_tpu.serving.parallel_moe_lm import ParallelMoELM
 
 from benchmark.reference import parallel_moe_lm as ref

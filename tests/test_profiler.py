@@ -201,20 +201,35 @@ def test_exported_timeline_is_schema_valid_chrome_trace(tmp_path):
 
 def test_tracer_disabled_run_overhead_is_negligible():
     """ISSUE acceptance: tracer off => the instrumented Executor.run
-    path costs ~nothing extra.  Microbench the actual disabled span
-    call (the only added per-run work) rather than racing two full
-    runs against CI noise."""
-    import time
-
+    path costs ~nothing extra, as counts no loaded CPU moves: a warm
+    run with the tracer off leaves NO span in the ring buffer and none
+    open, and what the same run opens with the tracer on (each a flag
+    read and a null profiler annotation when it is off) stays a
+    handful."""
     from paddle_tpu import observe
+    from paddle_tpu.observe import tracer
+
+    scope = pt.framework.Scope()
+    main, startup = Program(), Program()
+    with program_guard(main, startup):
+        y = layers.fc(layers.data("x", [4]), size=2)
+    exe = pt.Executor(pt.CPUPlace())
+    exe.run(startup, scope=scope)
+
+    def warm_runs(n):
+        observe.clear()
+        for _ in range(n):
+            exe.run(main, feed={"x": np.ones((3, 4), "float32")},
+                    fetch_list=[y], scope=scope)
+        return len(observe.snapshot())
 
     observe.disable()
-    n = 20_000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        with observe.span("executor/run"):
-            pass
-    per_call = (time.perf_counter() - t0) / n
-    # ~7 disabled spans per Executor.run; even a 100us run budget keeps
-    # this under 1% — assert an order of magnitude of headroom
-    assert per_call < 20e-6, f"{per_call * 1e6:.2f}us per disabled span"
+    warm_runs(1)                        # compiled: the runs below are warm
+    assert warm_runs(20) == 0 and tracer.open_spans() == []
+    observe.enable()
+    try:
+        a_run = warm_runs(20) / 20
+    finally:
+        observe.disable()
+        observe.clear()
+    assert 1 <= a_run <= 8, a_run

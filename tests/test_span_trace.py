@@ -656,7 +656,6 @@ class _SlowToken:
 @pytest.mark.parametrize("path,cfg", [
     ("full", {}),
     ("rows", {"prefill_chunk_pages": 1}),
-    ("ragged", {"prefill_chunk_pages": 1, "ragged_prefill_rows": 16}),
 ])
 def test_prefill_seconds_run_through_the_sync(model_and_weights,
                                               monkeypatch, path, cfg):

@@ -345,3 +345,43 @@ def test_the_kda_sweep_rehearses_on_the_cpu_and_times_nothing_there(
     monkeypatch.setattr(sys, "argv", ["sweep"])
     with pytest.raises(SystemExit, match="a TPU or nothing"):
         sweep.main()
+
+
+SERVING = os.path.join(ROOT, "paddle_tpu", "serving")
+# what a file under ``serving/`` may import of ``serving/``: the arrows
+# of ``ops/`` <- ``blocks.py`` <- ``mixers.py`` <- ``*_lm.py``
+MODEL_FILES = sorted(f for f in os.listdir(SERVING) if f.endswith("_lm.py"))
+
+
+def _serving_imports(name):
+    """The modules of ``paddle_tpu.serving`` that ``serving/<name>``
+    imports, anywhere in the file (the files import each other by
+    ``from .x import`` alone: an absolute spelling fails here too)."""
+    import ast
+
+    with open(os.path.join(SERVING, name)) as f:
+        source = f.read()
+    assert "paddle_tpu.serving" not in source.split('"""', 2)[2]
+    found = set()
+    for node in ast.walk(ast.parse(source)):
+        if isinstance(node, ast.ImportFrom) and node.level == 1:
+            found.update([node.module.split(".")[0]] if node.module
+                         else [a.name for a in node.names])
+    return found
+
+
+@pytest.mark.parametrize("name", MODEL_FILES + ["blocks.py", "mixers.py"])
+def test_the_served_models_library_has_its_arrows_one_way(name):
+    """No model file imports another model file (a model's block changes
+    in that model's file and moves that model's cell alone: PR 57 and
+    PR 58, claimed on Kimi-Linear, moved Solar's by 8.5 % through a class
+    that lived in Solar's file); what two models share is in
+    ``blocks.py`` (stateless, nothing of ``serving/`` imported) or
+    ``mixers.py`` (the stateful mixins, ``blocks`` alone), and no class
+    borrows another model class's methods."""
+    assert len(MODEL_FILES) == 9
+    allowed = {"blocks.py": set(), "mixers.py": {"blocks"}}.get(
+        name, {"blocks", "mixers"})
+    assert _serving_imports(name) <= allowed, name
+    with open(os.path.join(SERVING, name)) as f:
+        assert not re.search(r"= \w+LM\._\w+", f.read())

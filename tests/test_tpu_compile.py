@@ -589,15 +589,15 @@ def test_kda_chunk_form_compiles_at_the_cells_calls(one_chip, heads, bucket):
     and its second result's buffer, the blocks fit the VMEM budget and
     the compiled call's own temporaries the cap."""
     from paddle_tpu.ops import pallas_kda_chunk as chunked
-    from paddle_tpu.serving import hybrid_moe_lm as hybrid
+    from paddle_tpu.serving import mixers
     from tools.sweep_kda_chunk import mixer_of
 
     d, f32 = 128, jnp.float32
     group = mixer_of(dict(heads=heads, beta=1.0)).prefill_chunks_per_call(
         bucket)
     assert group == {64: 2, 32: 4}[heads] and bucket % (group * 64) == 0
-    tokens = group * hybrid.PREFILL_CHUNK
-    assert 4 * tokens * 8 * heads * d <= hybrid.GROUP_BYTES
+    tokens = group * mixers.PREFILL_CHUNK
+    assert 4 * tokens * 8 * heads * d <= mixers.GROUP_BYTES
     vec = (1, tokens, heads, d)
     args = [jax.ShapeDtypeStruct(s_, t, sharding=one_chip) for s_, t in (
         (vec, f32), (vec, f32), (vec, f32), (vec, f32), (vec[:3], f32),
@@ -612,7 +612,7 @@ def test_kda_chunk_form_compiles_at_the_cells_calls(one_chip, heads, bucket):
                      call[0])
     assert call[0].count(f"f32[{tokens},{heads * d}]") == 5
     assert compiled.memory_analysis().temp_size_in_bytes \
-        <= hybrid.GROUP_BYTES
+        <= mixers.GROUP_BYTES
 
 
 def _assert_one_kernel_passes_over_the_slab(text, slabs):

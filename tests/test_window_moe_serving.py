@@ -426,12 +426,10 @@ def test_the_prefill_leaves_only_the_windows_tail_in_the_ring():
 
 
 @pytest.mark.parametrize("cfg, names", [
-    (dict(prefill_chunk_pages=1), "window layers.*chunked/ragged prefill"),
-    (dict(prefill_chunk_pages=1, ragged_prefill_rows=8),
-     "window layers.*chunked/ragged prefill"),
+    (dict(prefill_chunk_pages=1), "window layers.*chunked prefill"),
     (dict(spec_k=2), "window layers.*speculative decoding"),
     (dict(kv_quant=True), "window layers.*kv_quant"),
-], ids=["chunked", "ragged", "speculative", "kv_quant"])
+], ids=["chunked", "speculative", "kv_quant"])
 def test_what_cannot_hold_over_a_ring_refuses_by_kind_and_name(cfg, names):
     model = make_model(PERIOD)
     weights = model.init_weights(jax.random.PRNGKey(18))

@@ -52,7 +52,7 @@ from jax import lax
 
 from paddle_tpu.ops import pallas_kda_chunk as chunked
 from paddle_tpu.ops import pallas_kda_update as kda
-from paddle_tpu.serving import hybrid_moe_lm as hybrid
+from paddle_tpu.serving import mixers
 
 D = 128
 SHAPES = {      # heads, the bucket's rows, the prompt's real tokens, beta's top
@@ -72,7 +72,7 @@ def token_form(q, k, g, v, beta, state, n_real, interpret=False):
 
 def mixer_of(sizes):
     """A `KDAMixer` of the shape's heads, nothing else of a model."""
-    mixer = hybrid.KDAMixer()
+    mixer = mixers.KDAMixer()
     mixer.lin_heads, mixer.lin_head_dim, mixer.conv_kernel = \
         sizes["heads"], D, 4
     mixer.beta_scale = sizes["beta"]
@@ -84,7 +84,7 @@ def served_group(sizes):
     """Tokens a call `KDAMixer.prefill_chunks_per_call` gives the
     shape's bucket."""
     return mixer_of(sizes).prefill_chunks_per_call(sizes["bucket"]) \
-        * hybrid.PREFILL_CHUNK
+        * mixers.PREFILL_CHUNK
 
 
 def layer_form(sizes, seed):
