@@ -45,7 +45,12 @@ the first with a bfloat16 query and the second at default precision, one
 bfloat16 pass over the float32 probabilities); products accumulate in
 float32 and the softmax is float32.  Float32 K/V meet float32 operands.
 ``Dv != Dk`` is the shapes' business: scores follow K's width, the
-accumulator and the output V's.
+accumulator and the output V's.  Heads of 64 lanes ride in whole groups
+of an even number of heads (two heads' lanes fill one lane tile, so a
+group's lanes of a row are whole tiles on both sides): a program slices
+head ``h``'s 64 lanes of the query block and stores its 64 lanes of the
+result at half-tile offsets, and the accumulator is the head's own 64
+lanes wide.
 
 ``flash_rule`` is the engagement rule, a function of the call's static
 shape alone and the same on every backend; ``keys_visited`` is the
@@ -96,10 +101,14 @@ def flash_rule(t, num_heads, kv_heads, d, dv, window=None):
     prompt bucket of ``t`` rows, ``num_heads`` query heads on
     ``kv_heads`` K/V heads of ``d`` (K) and ``dv`` (V) lanes, or None
     where the kernel does not take the shape and the plain form stays:
-    heads that are not whole groups, a group of several heads whose K
-    lanes or a head whose V lanes are not whole lane tiles (K heads of
-    192 in even groups are, and so is ONE head a group of K lanes that
-    fill whole sublanes: its query is laid out head-major), a bucket
+    heads that are not whole groups; a group of several heads whose K
+    lanes are not whole lane tiles (K heads of 192, or of 64, in even
+    groups are; so is ONE head a group of K lanes that fill whole
+    sublanes: its query is laid out head-major); V heads that are
+    neither whole lane tiles nor 64 lanes in a group of an even number
+    of heads (two heads a tile; every other width that 128 does not
+    divide, 96 among them, stays refused: no served model has one); a
+    bucket
     that is not a whole number of blocks of at least 128 rows.  A key
     block is the widest of 1,024 / 512 / 256 /
     128 positions that divides the bucket and does not pass the window
@@ -107,9 +116,11 @@ def flash_rule(t, num_heads, kv_heads, d, dv, window=None):
     the group's heads, at most ``_MAX_ROWS`` of one head's and, under a
     window, no more than a key block (what the window's edge wastes
     grows with both)."""
-    if num_heads % kv_heads or dv % _LANES or t % _LANES:
+    if num_heads % kv_heads or t % _LANES:
         return None
     group = num_heads // kv_heads
+    if dv % _LANES and (dv != 64 or group % 2):
+        return None
     head_major = group * d % _LANES != 0
     if head_major and (group != 1 or d % 8 or d < _LANES):
         return None

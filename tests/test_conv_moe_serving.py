@@ -419,8 +419,9 @@ def test_four_pairs_a_row_take_one_pass_where_two_a_row_took_two():
 
 def test_a_prompt_over_all_held_experts_groups_its_pairs_in_one_pass():
     """A 300-token prompt through a model that holds all 16 experts of a
-    top-4 router, at widths of whole lanes (``interpret`` lets the two
-    kernels run without a chip): the served logits are the reference's;
+    top-4 router, at widths of whole lanes and heads of 64 (``interpret``
+    lets the grouped two, the prompt's flash kernel and the paged one
+    run without a chip): the served logits are the reference's;
     the prefill counted 4 pairs a real row an expert layer and NO extra
     pass; every joint step's live row made 4 local pairs a layer."""
     model = make_model(("recurrent", "attention"), held=range(16),
@@ -433,7 +434,8 @@ def test_a_prompt_over_all_held_experts_groups_its_pairs_in_one_pass():
         "moe_local_assignments", "decode_tokens_total", "decode_prefills",
         "decode_prefill_attn_flash", "decode_prefill_attn_blocks")
     before = {n: stat_get(n) for n in names}
-    with engine(model, weights, max_seq_len=512, interpret=True) as eng:
+    with engine(model, weights, max_seq_len=512, use_pallas="always",
+                interpret=True) as eng:
         assert eng._prefill_tallies[2:] == moe_ops.GROUPED_TALLIES + (
             "decode_prefill_conv_rows",)
         req = eng.submit(prompt, max_new_tokens=4, record_logits=True)
@@ -449,16 +451,23 @@ def test_a_prompt_over_all_held_experts_groups_its_pairs_in_one_pass():
     assert d["moe_grouped_extra_passes"] == 0
     stepped = d["decode_tokens_total"] - d["decode_prefills"]
     assert stepped == 3 and d["moe_local_assignments"] == 4 * stepped * 2
-    # V heads of 64 lanes: the flash kernel declines, the blocked form
-    # serves the prompt's attention
-    assert ppa.flash_rule(512, 2, 1, 64, 64, None) is None
-    assert d["decode_prefill_attn_flash"] == 0
-    assert d["decode_prefill_attn_blocks"] >= 1
+    # V heads of 64 lanes in a whole group of two: the flash kernel
+    # serves the prompt's attention, interpreted here
+    assert ppa.flash_rule(512, 2, 1, 64, 64, None) == (256, 512)
+    assert eng._prefill_walks(512) == [(1, None, ("flash", 256, 512))]
+    assert d["decode_prefill_attn_flash"] >= 1
+    assert d["decode_prefill_attn_blocks"] == 0
 
 
-def test_the_flash_kernel_declines_heads_of_64_lanes():
-    assert ppa.flash_rule(2048, 32, 8, 64, 64, None) is None
-    assert ppa.flash_rule(2048, 32, 8, 128, 128, None) is not None
+def test_the_flash_kernel_takes_heads_of_64_lanes_in_whole_groups():
+    """The cell's shape gets the tiles every grouped model's prompt gets;
+    what no 128 divides but 64 stays refused, and so does a group whose
+    V lanes end inside a tile."""
+    assert ppa.flash_rule(2048, 32, 8, 64, 64, None) == (256, 1024)
+    assert ppa.flash_rule(2048, 32, 8, 128, 128, None) == (256, 1024)
+    assert ppa.flash_rule(2048, 32, 8, 64, 96, None) is None
+    assert ppa.flash_rule(2048, 24, 8, 128, 64, None) is None
+    assert ppa.flash_rule(2048, 16, 16, 64, 64, None) is None
 
 
 @pytest.mark.parametrize("cfg, names", [

@@ -37,6 +37,10 @@ def _both(q, k, v, sinks, window, length=None):
     return np.asarray(got), np.asarray(want)
 
 
+def _rms_rel(got, want):
+    return np.sqrt(((got - want) ** 2).mean() / (want ** 2).mean())
+
+
 @pytest.fixture
 def small_tiles(monkeypatch):
     """Blocks of 128 rows at most and 128 keys: 512 rows are 4 x 4 of
@@ -58,6 +62,10 @@ CASES = {
     "window_is_the_bucket": (512, 4, 2, 128, 128, 512, False),
     "keys_wider_than_values": (512, 8, 2, 192, 128, None, True),
     "window_keys_wider_than_values": (512, 8, 1, 192, 128, 128, True),
+    # V heads of 64 lanes in whole groups: two heads' values a lane tile
+    "heads_of_64": (512, 8, 2, 64, 64, None, False),
+    "heads_of_64_window_200_sinks": (512, 8, 2, 64, 64, 200, True),
+    "keys_of_128_values_of_64": (512, 4, 2, 128, 64, None, True),
 }
 
 
@@ -78,14 +86,16 @@ def test_the_kernel_is_the_plain_form(small_tiles, case):
             assert np.abs(got - off).max() > 1e-2
 
 
+@pytest.mark.parametrize("lanes", [128, 64])
 @pytest.mark.parametrize("length", [1, 130, 257, 512])
-def test_row_blocks_past_the_length_are_not_computed(small_tiles, length):
+def test_row_blocks_past_the_length_are_not_computed(small_tiles, length,
+                                                     lanes):
     """A prompt short of its bucket by more than a row block: the real
     rows are the plain form's, a block of padding rows alone comes back
-    zero."""
+    zero; at heads of whole lane tiles and of 64 lanes."""
     t, h, hkv = 512, 16, 1
-    assert ppa.flash_rule(t, h, hkv, 128, 128) == (128, 128)
-    q, k, v, _ = _operands(t, h, hkv, 128, 128, False)
+    assert ppa.flash_rule(t, h, hkv, lanes, lanes) == (128, 128)
+    q, k, v, _ = _operands(t, h, hkv, lanes, lanes, False)
     got, want = _both(q, k, v, None, None, length=jnp.int32(length))
     live = -(-length // 128) * 128
     np.testing.assert_allclose(got[:live], want[:live], atol=1e-5)
@@ -100,15 +110,36 @@ def test_bfloat16_keys_meet_one_bfloat16_term():
     gives the plain form's float32 einsums."""
     q, k, v, s = _operands(256, 8, 2, 128, 128, True, jnp.bfloat16)
     got, want = _both(q, k, v, s, None)
-    err = np.sqrt(((got - want) ** 2).mean() / (want ** 2).mean())
+    err = _rms_rel(got, want)
     assert 1e-4 < err < 6e-3, err
+
+
+def test_bfloat16_keys_of_64_lanes_meet_one_bfloat16_term():
+    """The same feed at heads of 64 lanes: the error against the plain
+    form's float32 is what 128 lanes read (1.91e-3 here, 1.87e-3 there:
+    one bfloat16 term for the query and one for the probabilities).
+    ``sm_scale`` at 64 lanes is 0.125, a power of two, so rounding the
+    scaled query is rounding the query: a query on bfloat16's grid
+    meets the plain form's operands bit for bit and leaves the
+    probabilities' term alone (at 128 lanes it leaves both)."""
+    q, k, v, s = _operands(256, 8, 2, 64, 64, True, jnp.bfloat16)
+    got, want = _both(q, k, v, s, None)
+    err = _rms_rel(got, want)
+    assert 1.4e-3 < err < 2.6e-3, err       # 1.91e-3 at this seed
+    # with the query on bfloat16's grid already, the scaled query's
+    # rounding is exact and only the probabilities' term is left
+    q = q.astype(jnp.bfloat16).astype(jnp.float32)
+    got, want = _both(q, k, v, s, None)
+    exact_q = _rms_rel(got, want)
+    assert 8e-4 < exact_q < 0.8 * err, (exact_q, err)   # 1.26e-3
 
 
 @pytest.mark.parametrize("shape", [
     (512, 8, 2, 8, 4), (512, 8, 3, 128, 128), (72, 8, 2, 128, 128),
-    (512, 8, 2, 128, 96)],
+    (512, 8, 2, 128, 96), (512, 2, 2, 64, 64), (512, 6, 2, 128, 64)],
     ids=["narrow_heads", "no_whole_groups", "no_whole_blocks",
-         "narrow_values"])
+         "narrow_values", "ungrouped_heads_of_64",
+         "odd_groups_of_64_lane_values"])
 def test_a_shape_the_rule_refuses_keeps_the_plain_form(shape):
     t, h, hkv, d, dv = shape
     assert ppa.flash_rule(t, h, hkv, d, dv) is None
@@ -134,6 +165,9 @@ def test_the_rule_at_the_cells_shapes():
     for t in (128, 256, 512, 1024):
         assert ppa.flash_rule(t, 64, 8, 128, 128) == (
             min(t, 256), min(t, 1024))
+    # LFM2's 32 heads on 8 of K 64 / V 64: four heads' lanes are two
+    # whole tiles on both sides
+    assert ppa.flash_rule(2048, 32, 8, 64, 64) == (256, 1024)
     assert pda.prefill_walk(4096, 128, 8, 128, 128, 4096, "auto") == (
         "blocks", 256, 4096)
     assert pda.prefill_walk(4096, 128, 8, 128, 128, 4096, "always") == (
@@ -145,6 +179,46 @@ def test_the_rule_at_the_cells_shapes():
     # each up to its diagonal's block of 1,024
     assert ppa.keys_visited(4096, 3600, 128, 1024) == 128 * 1024 * (
         8 + 8 * 2 + 8 * 3 + 5 * 4)
+
+
+BUCKETS = (128, 256, 512, 1024, 2048, 4096, 8192)
+# (query heads, K/V heads, K lanes, V lanes, window) of a cell's layers
+# and ``flash_rule``'s answer a bucket of ``BUCKETS`` as it was before
+# the rule took V heads of 64 lanes (read off the parent commit, PR 59)
+WAS = {
+    "mimo_v2_5": {
+        (64, 4, 192, 128, None): [(128, 128), (128, 256), (128, 512)]
+        + [(128, 1024)] * 4,
+        (64, 8, 192, 128, 128): [(128, 128)] * 7},
+    "command_a_plus": {
+        (128, 8, 128, 128, None): [(128, 128), (128, 256), (128, 512)]
+        + [(128, 1024)] * 4,
+        (128, 8, 128, 128, 4096): [(128, 128), (128, 256), (128, 512)]
+        + [(128, 1024)] * 4},
+    "olmo_hybrid_7b": {
+        (30, 30, 128, 128, None): [(128, 128), (256, 256), (256, 512)]
+        + [(256, 1024)] * 4},
+    "kimi_k2_5": {
+        (64, 64, 192, 128, None): [(128, 128), (256, 256), (512, 512)]
+        + [(1024, 1024)] * 4},
+    "kimi_linear_48b": {
+        (32, 32, 192, 128, None): [(128, 128), (256, 256), (512, 512)]
+        + [(1024, 1024)] * 4},
+    "solar_open2_250b": {
+        (64, 8, 128, 128, None): [(128, 128), (256, 256), (256, 512)]
+        + [(256, 1024)] * 4},
+}
+
+
+@pytest.mark.parametrize("cell", list(WAS))
+def test_the_other_cells_keep_the_tiles_they_had(cell):
+    """The six cells whose prompts ran the kernel before it took V heads
+    of 64 lanes: the rule's answer at every bucket is the parent's, so
+    their prefills trace the lines they traced."""
+    for (h, hkv, d, dv, window), tiles in WAS[cell].items():
+        assert dv % 128 == 0
+        assert [ppa.flash_rule(t, h, hkv, d, dv, window)
+                for t in BUCKETS] == tiles, (cell, h, hkv, d, dv, window)
 
 
 def test_the_engine_counts_the_walk_of_the_form_that_runs(monkeypatch):

@@ -279,6 +279,53 @@ def test_the_block_sweep_rehearses_on_the_cpu_and_times_nothing_there(
         sweep.main()
 
 
+def test_the_prompt_flash_sweep_rehearses_on_the_cpu_and_times_nothing_there(
+        tmp_path, monkeypatch, capsys):
+    """``tools/sweep_prompt_flash.py`` (the numbers behind ``flash_rule``'s
+    tiles at heads of 64 lanes, PR 60): at ``--tiny`` sizes the kernel in
+    the interpreter at the tiles the rule gives and at another pair, V as
+    it lies and padded to whole lanes, each against the plain blocks,
+    with the keys its walk covers; the table's header is printed and no
+    line holds a time; without ``--tiny`` it wants a TPU."""
+    import json
+
+    from paddle_tpu.ops import pallas_prompt_attention as ppa
+    from tools import sweep_prompt_flash as sweep
+
+    out = tmp_path / "sweep.json"
+    monkeypatch.setattr(sys, "argv", [
+        "sweep", "--tiny", "--shapes", "heads_of_64", "--rows", "64",
+        "--keys", "128", "--out", str(out)])
+    sweep.main()
+    assert capsys.readouterr().out.startswith(
+        "shape length form rows keys rule ms_a_call kernel_ms "
+        "live_over_walked\n")
+    with open(out) as f:
+        lines = json.load(f)
+    ruled = ppa.flash_rule(*sweep.TINY["heads_of_64"][:6])
+    assert ruled == (256, 256)
+    assert [(x["form"], x["length"], x["rows"], x["keys"], x["rule"])
+            for x in lines] == [
+        ("blocks", 256, 256, 256, False),
+        ("flash", 130, 64, 128, False), ("flash", 256, 64, 128, False),
+        ("flash", 130, 256, 256, True), ("flash", 256, 256, 256, True),
+        ("flash_v128", 130, 64, 128, False),
+        ("flash_v128", 256, 64, 128, False),
+        ("flash_v128", 130, 256, 256, False),
+        ("flash_v128", 256, 256, 256, False)]
+    # bfloat16 K/V: the kernel's one bfloat16 term against the plain
+    # form's float32 products
+    assert all(x["rms_err"] < 4e-3 and "ms_a_call" not in x
+               and "error" not in x for x in lines)
+    # 130 tokens are three row blocks of 64: one, one and two key blocks
+    assert lines[1]["keys_walked"] == 64 * 128 * 4
+    assert lines[1]["live_over_walked"] == round(
+        130 * 131 // 2 / (64 * 128 * 4), 4)
+    monkeypatch.setattr(sys, "argv", ["sweep"])
+    with pytest.raises(SystemExit, match="a TPU or nothing"):
+        sweep.main()
+
+
 def test_the_layout_sweep_rehearses_on_the_cpu_and_times_nothing_there(
         tmp_path, monkeypatch):
     """``tools/sweep_moe_layout.py`` (the numbers behind the grouped

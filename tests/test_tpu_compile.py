@@ -1305,10 +1305,11 @@ def test_lfm2_width_programs_compile(one_chip, program):
     """The 128-row joint step (the paged kernel at 8 K/V heads of 64
     lanes, the experts in the dense form: 128 rows hit every expert) and
     the 2,048-row whole-prompt prefill (the grouped experts' two kernels
-    at four pairs a row, the attention in the blocked form: the flash
-    kernel declines V heads of 64 lanes; the head over one row): each
-    needs under 1.5 GB beside its operands, updates pools and tails in
-    place, and copies or transposes nothing an expert matrix's size."""
+    at four pairs a row, the attention in the flash kernel at four heads
+    of 64 lanes a group, so no plane of float32 scores anywhere; the
+    head over one row): each needs under 1.5 GB beside its operands,
+    updates pools and tails in place, and copies or transposes nothing
+    an expert matrix's size."""
     from paddle_tpu.ops import moe_ops, pallas_moe_grouped as grouped
     from paddle_tpu.ops import pallas_prompt_attention as ppa
 
@@ -1316,7 +1317,8 @@ def test_lfm2_width_programs_compile(one_chip, program):
     m = eng.model
     assert eng._cache.config.pool_shape() == (1, 20609, 16, 512)
     assert eng._cache.state_bytes() == 2 * 128 * 2 * 2048 * 4
-    assert ppa.flash_rule(2048, 32, 8, 64, 64, None) is None
+    assert ppa.flash_rule(2048, 32, 8, 64, 64, None) == (256, 1024)
+    assert eng._prefill_walks(2048) == [(1, None, ("flash", 256, 1024))]
     shape = (len(m.held_experts), m.expert_dim, m.d_model, m.top_k,
              m.num_experts)
     assert not moe_ops.grouped_rule(128, *shape)
@@ -1334,9 +1336,16 @@ def test_lfm2_width_programs_compile(one_chip, program):
         assert "paged_attention" in text
         assert not re.search(r"f32\[2048,65536\]", text)
     else:
-        assert text.count("tpu_custom_call") == 2       # the grouped two
+        # the grouped two and the prompt's flash kernel
+        assert text.count("tpu_custom_call") == 3
         assert grouped.GATE_UP_KERNEL_NAME in text
         assert grouped.DOWN_KERNEL_NAME in text
+        assert re.search(r"%" + ppa.KERNEL_NAME + r"[.\d]* = .*custom-call\(",
+                         text)
+        # no plane of scores, whole (f32[8,4,2048,2048]) or in blocks of
+        # rows (the tails are f32[128,2,2048]: d_model is the bucket)
+        assert not re.search(r"f32\[(\d+,)+\d+,2048,2048\]", text)
+        assert not re.search(r"f32\[8,4,\d+,2048\]", text)
         # the head over the read row alone, no plane of every row
         assert re.search(r"f32\[1,65536\]", text)
         assert not re.search(r"f32\[2048,65536\]", text)
@@ -1352,6 +1361,42 @@ def test_lfm2_width_programs_compile(one_chip, program):
     assert ma.temp_size_in_bytes < 1536 << 20
     pools_and_tails = 2 * 20609 * 16 * 512 * 2 + 2 * 128 * 2 * 2048 * 4
     assert ma.alias_size_in_bytes >= pools_and_tails
+
+
+# t, query heads, K/V heads, K lanes, V lanes, window, sinks
+_FLASH_64 = {
+    "lfm2": (2048, 32, 8, 64, 64, None, False),
+    "window_and_sinks": (2048, 32, 8, 64, 64, 128, True),
+    "keys_of_128": (1024, 16, 8, 128, 64, None, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_FLASH_64))
+def test_prompt_flash_kernel_compiles_at_values_of_64_lanes(one_chip, case):
+    """The prompt's flash kernel ALONE at every kind of shape
+    ``flash_rule`` takes V heads of 64 lanes in (the cell's four heads a
+    group, a window layer with sinks, K heads of whole tiles over V
+    heads of half a tile): the chip's compiler lowers the half-tile
+    slices of the query block and the half-tile stores of the result,
+    which the interpreter cannot refuse."""
+    from paddle_tpu.ops import pallas_prompt_attention as ppa
+
+    t, h, hkv, d, dv, window, sinks = _FLASH_64[case]
+    tiles = ppa.flash_rule(t, h, hkv, d, dv, window)
+    assert tiles is not None
+
+    def call(q, k, v, n, *sink):
+        return ppa.prompt_flash_attention(
+            q, k, v, n, *sink, sm_scale=d ** -0.5, window=window,
+            tiles=tiles)
+
+    shapes = [((t, h, d), jnp.float32), ((t, hkv, d), jnp.bfloat16),
+              ((t, hkv, dv), jnp.bfloat16), ((), jnp.int32)]
+    if sinks:
+        shapes.append(((h,), jnp.float32))
+    text = _compile(one_chip, call, *shapes)
+    assert text.count("tpu_custom_call") == 1 and ppa.KERNEL_NAME in text
+    assert f"f32[{t},{h},{dv}]" in text
 
 
 # -- Kimi-Linear: state slabs BESIDE one pool of latent rows ----------------
