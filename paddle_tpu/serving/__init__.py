@@ -64,6 +64,7 @@ from .kv_cache import (  # noqa: F401
 from .latent_moe_lm import LatentMoELM  # noqa: F401
 from .linear_latent_lm import LinearLatentLM  # noqa: F401
 from .looped_lm import LoopedLM  # noqa: F401
+from .mamba_lm import MambaLM  # noqa: F401
 from .parallel_moe_lm import ParallelMoELM  # noqa: F401
 from .window_moe_lm import WindowMoELM  # noqa: F401
 from .server import (  # noqa: F401
@@ -81,7 +82,7 @@ __all__ = [
     "DisaggRequest", "DisaggServer", "GatedDeltaLM", "HybridMoELM",
     "InferenceRequest",
     "KVPageExport", "LatentMoELM", "LinearLatentLM", "LoopedLM",
-    "PageAllocator",
+    "MambaLM", "PageAllocator",
     "PagedKVCache",
     "ParallelMoELM",
     "PrefixIndex",

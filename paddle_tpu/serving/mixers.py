@@ -1,4 +1,4 @@
-"""The two stateful mixers a served model composes, as mixins: what a
+"""The three stateful mixers a served model composes, as mixins: what a
 layer keeps of a request lives with the engine (``attend``), what the
 layer computes of it is here, once, for every model that has such a
 layer.
@@ -19,6 +19,16 @@ row is ``[c | rot(k_r)]``, no V pool) in its two forms, expanded over a
 whole prompt and absorbed in the step.  ``LatentMoELM``'s and
 ``LinearLatentLM``'s.
 
+``SSMMixer``: the selective state-space (Mamba-1) layer
+(``"recurrent"`` layers: a float32 state of ``[d_state, d_inner]`` a
+slot in which every entry decays by itself, and the convolution's last
+inputs; no heads, no keys).  ONE rule in two forms, chosen by
+``ops/pallas_ssm.py`` ``ssm_rule`` from the state's static shape: the
+step's kernel over every live slot's state in place, and a prompt's scan
+with the state in fast memory, a group of ``SCAN_CHUNK``-token tiles a
+call; anything else (toy widths) runs ``ssm_token_xla`` a token, which
+is also the tests' oracle.  ``MambaLM``'s.
+
 A mixin reads the model's declared widths off ``self`` and imports no
 model file: ``blocks.py`` alone of ``serving/``
 (``tests/test_tooling.py`` holds the arrows).
@@ -32,6 +42,7 @@ import numpy as np
 
 from ..ops import pallas_kda_chunk as kda_chunk
 from ..ops import pallas_kda_update as kda
+from ..ops import pallas_ssm as ssm
 from .blocks import ROPE_SCOPE, _mm, rms_norm
 
 KDA_SCOPE = "kda_update"
@@ -45,6 +56,19 @@ PREFILL_CHUNK = kda_chunk.CHUNK
 # chip: they differ by 2-3 %, a call of a whole 256-row bucket at 64
 # heads by 18 %
 GROUP_BYTES = 32 << 20
+
+# the state-space layer's operations, the step's and a prompt's apart
+SSM_STEP_SCOPE = "ssm_update"           # the state kernel of the step
+SSM_SCAN_SCOPE = "ssm_scan"             # the state kernel of a prompt
+SSM_CONV_SCOPE = "ssm_conv"             # the taps, the bias, SiLU, the tail
+SSM_IN_SCOPE = "ssm_in_proj"            # W_in: u | z
+SSM_X_SCOPE = "ssm_x_proj"              # W_x, the three norms, W_dt
+SSM_OUT_SCOPE = "ssm_out_proj"          # the gate and W_out
+# tokens of one tile of the prompt's scan, what the whole-prompt prefill
+# counts as a scan step; tokens ONE call of the scan takes at most (its
+# vectors, nine float32 rows of all channels a token, are formed at once)
+SCAN_CHUNK = ssm.SCAN_TILE
+SCAN_CALL_TOKENS = 1024
 
 Q_PROJ_SCOPE = "latent_q_proj"          # q_a, its norm, q_b
 KV_PROJ_SCOPE = "latent_kv_proj"        # kv_a and the latent's norm
@@ -276,6 +300,180 @@ def _kda_rule_xla(q, k, v, decay, beta, s):
     delta = beta[..., None] * (v - ks)
     s = s + k[..., None] * delta[..., None, :]
     return qs + jnp.sum(q * k, -1, keepdims=True) * delta, s
+
+
+class SSMMixer:
+    """The selective state-space mixer of a model with ``d_inner``
+    channels, ``d_state`` state rows a channel, a convolution of
+    ``d_conv`` taps, a step projection of ``dt_rank`` and ``rms_eps``:
+    its weights, one slot's state, the mixer's residual term and the
+    rule's two forms.  ``MambaLM``'s."""
+
+    def ssm_state(self):
+        """One slot's state of ONE recurrent layer, laid out for the
+        chip: ``ssm`` the ``[d_state, d_inner]`` state (the channels on
+        the lanes: ``[d_inner, d_state]`` would pad 16 lanes to 128),
+        ``conv`` the ``d_conv - 1`` rows the convolution looks back on,
+        oldest first, side by side in one lane-dense row (three rows of
+        their own would pad to eight sublanes)."""
+        return {"ssm": ((self.d_state, self.d_inner), np.float32),
+                "conv": (((self.d_conv - 1) * self.d_inner,), np.float32)}
+
+    def ssm_weights(self, dense, keys, ones):
+        """A recurrent layer's mixer weights.  The diagonal's as the
+        state-space families set them: ``A_log[n, c] = log(n + 1)``
+        (rates 1..d_state, S4D-real), ``b_dt`` the inverse softplus of
+        steps log-uniform in 1e-3..1e-1, ``D`` ones; ``A_log`` lies as
+        the state does, ``[d_state, d_inner]``."""
+        import jax
+        import jax.numpy as jnp
+
+        dm, d, n, r = self.d_model, self.d_inner, self.d_state, self.dt_rank
+        step = jnp.exp(jax.random.uniform(
+            next(keys), (d,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        return dict(
+            ssm_w_in=dense((dm, 2 * d)),
+            ssm_conv=dense((self.d_conv, d), 1.0 / math.sqrt(self.d_conv),
+                           jnp.float32),
+            ssm_conv_b=dense((d,), 0.1, jnp.float32),
+            ssm_w_x=dense((d, r + 2 * n)),
+            ssm_dt_norm=ones(r), ssm_b_norm=ones(n), ssm_c_norm=ones(n),
+            ssm_w_dt=dense((r, d)),
+            # softplus^-1(step)
+            ssm_dt_b=step + jnp.log(-jnp.expm1(-step)),
+            ssm_a_log=jnp.broadcast_to(jnp.log(jnp.arange(
+                1, n + 1, dtype=jnp.float32))[:, None], (n, d)),
+            ssm_d=ones(d),
+            ssm_w_out=dense((d, dm)))
+
+    def ssm_mixer(self, l, lw, h, cache, attend):
+        """Recurrent layer ``l``'s residual term of the normed rows
+        ``h`` -> (``y``, cache).  Where the kernels take the state's
+        shape a whole-prompt prefill runs ``prefill_chunks_per_call``
+        tiles of the scan a call (``_ssm_chunk``), and a step, which
+        runs the token rule through ``_ssm_token`` whatever else is
+        handed over, counts the rows it updated."""
+        import jax
+        import jax.numpy as jnp
+
+        with jax.named_scope(SSM_IN_SCOPE):
+            uz = _mm(h, lw["ssm_w_in"])
+        u, z = uz[..., :self.d_inner], uz[..., self.d_inner:]
+        group = self.prefill_chunks_per_call(u.shape[0])
+        if group and not attend.prompt:
+            attend.tally("ssm_kernel_rows",
+                         jnp.sum(attend.live, dtype=jnp.int32))
+        o, cache = attend.recur(
+            l, functools.partial(self._ssm_token, lw,
+                                 interpret=attend.interpret),
+            {"u": u}, cache, chunk=group * SCAN_CHUNK,
+            chunk_fn=functools.partial(
+                self._ssm_chunk, lw, interpret=attend.interpret)
+            if group else None, chunks_per_call=max(group, 1))
+        with jax.named_scope(SSM_OUT_SCOPE):
+            return _mm(o * jax.nn.silu(z), lw["ssm_w_out"]), cache
+
+    def prefill_chunks_per_call(self, rows):
+        """Tiles of the scan (``SCAN_CHUNK`` tokens) ONE call of
+        ``_ssm_chunk`` takes of a prompt bucket of ``rows`` rows, where
+        the kernels take the state: all of them, up to
+        ``SCAN_CALL_TOKENS``; else 0, no chunk form.  A function of the
+        bucket and the model's widths alone."""
+        shape, dtype = self.recurrent_state["ssm"]
+        if not ssm.ssm_rule(*shape, dtype):
+            return 0
+        return max(1, min(-(-int(rows) // SCAN_CHUNK),
+                          SCAN_CALL_TOKENS // SCAN_CHUNK))
+
+    def _ssm_vectors(self, lw, u):
+        """What the rule takes of ``N`` tokens beside their convolved,
+        activated rows ``u [N, d_inner]`` -> (dt ``[N, d_inner]``, b, c
+        ``[N, d_state]``, a ``[d_state, d_inner]``): the step, ``b`` and
+        ``c`` each through an RMSNorm of its own, the step's bias inside
+        the softplus, ``a`` below zero."""
+        import jax
+        import jax.numpy as jnp
+
+        r, n = self.dt_rank, self.d_state
+        with jax.named_scope(SSM_X_SCOPE):
+            x = _mm(u, lw["ssm_w_x"])
+            dt = rms_norm(x[..., :r], lw["ssm_dt_norm"], self.rms_eps)
+            b = rms_norm(x[..., r:r + n], lw["ssm_b_norm"], self.rms_eps)
+            c = rms_norm(x[..., r + n:], lw["ssm_c_norm"], self.rms_eps)
+            dt = jax.nn.softplus(_mm(dt, lw["ssm_w_dt"]) + lw["ssm_dt_b"])
+        return dt, b, c, -jnp.exp(lw["ssm_a_log"])
+
+    def _ssm_token(self, lw, rows, state, live=None, interpret=False):
+        """One token a row through a recurrent layer: ``rows`` the
+        token's in-projection (``u [R, d_inner]`` before the
+        convolution), ``state`` the rows' state BEFORE it (``ssm [R,
+        d_state, d_inner]``, ``conv [R, (K-1) d_inner]``) -> (``y + D u
+        [R, d_inner]``, the state after it).  All float32.
+
+        It takes ``live`` (bool ``[R]``; None: every row) and OWNS the
+        dead rows: a row that is not live comes back with the state it
+        had.  Where ``ssm_rule`` takes the state's shape both arrays go
+        through kernels, each read once and written once where it lies
+        (a dead row's blocks written back as read); else through
+        ``ssm_conv_xla`` and ``ssm_token_xla`` and a mask."""
+        import jax
+        import jax.numpy as jnp
+
+        s0 = state["ssm"]
+        kernels = ssm.ssm_rule(*s0.shape[1:], s0.dtype)
+        if kernels and live is None:
+            live = jnp.ones(s0.shape[:1], bool)
+        with jax.named_scope(SSM_CONV_SCOPE):
+            if kernels:
+                u, tail = ssm.ssm_conv_update(
+                    state["conv"], rows["u"], lw["ssm_conv"],
+                    lw["ssm_conv_b"], live, interpret=interpret)
+            else:
+                u, tail = ssm.ssm_conv_xla(
+                    state["conv"], rows["u"], lw["ssm_conv"],
+                    lw["ssm_conv_b"])
+                if live is not None:
+                    tail = jnp.where(live[:, None], tail, state["conv"])
+        dt, b, c, a = self._ssm_vectors(lw, u)
+        with jax.named_scope(SSM_STEP_SCOPE):
+            if kernels:
+                y, s = ssm.ssm_update(dt, u, b, c, a, s0, live,
+                                      interpret=interpret)
+            else:
+                y, s = ssm.ssm_token_xla(dt, u, b, c, a, s0)
+                if live is not None:
+                    s = jnp.where(live[:, None, None], s, s0)
+        return y + lw["ssm_d"] * u, {"ssm": s, "conv": tail}
+
+    def _ssm_chunk(self, lw, rows, n_real, state, interpret=False):
+        """A GROUP of whole ``SCAN_CHUNK``-token tiles, consecutive
+        tokens of ONE request, through a recurrent layer in one call of
+        the scan kernel (``ops/pallas_ssm.py`` ``ssm_scan``: the token
+        rule itself, the state in fast memory from the call's first
+        token to its last): ``rows`` their in-projection (``u [N,
+        d_inner]``), of which the first ``n_real`` are the request's
+        (the kernel steps the rest by 0 and skips tiles of nothing else:
+        padding touches neither the state nor the tail), ``state`` the
+        request's before them (leading dimension 1) -> (``y + D u [N,
+        d_inner]``, the state after token ``n_real - 1``).  The
+        convolution, the projections and the norms are the token form's,
+        over all the call's rows at once."""
+        import jax
+        import jax.numpy as jnp
+
+        k, d = self.d_conv, self.d_inner
+        with jax.named_scope(SSM_CONV_SCOPE):
+            n = rows["u"].shape[0]
+            window = jnp.concatenate(
+                [state["conv"].reshape(k - 1, d), rows["u"]])
+            u = jax.nn.silu(lw["ssm_conv_b"] + sum(
+                window[j:j + n] * lw["ssm_conv"][j] for j in range(k)))
+            tail = jax.lax.dynamic_slice_in_dim(window, n_real, k - 1)
+        dt, b, c, a = self._ssm_vectors(lw, u)
+        with jax.named_scope(SSM_SCAN_SCOPE):
+            y, s = ssm.ssm_scan(dt, u, b, c, a, state["ssm"], n_real,
+                                interpret=interpret)
+        return y + lw["ssm_d"] * u, {"ssm": s, "conv": tail.reshape(1, -1)}
 
 
 class LatentMixer:

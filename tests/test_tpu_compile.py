@@ -1538,3 +1538,95 @@ def test_kimi_linear_width_programs_compile(one_chip, program):
                     for a in _ARRAY.findall(m["type"])}
             assert not dims & held, line[:160]
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+# -- Jamba2-3B: the selective scan's kernels, 256 slots, ONE K/V head -------
+
+def _mamba_engine():
+    """Jamba2-3B's published widths behind the engine at the cell's
+    serving sizes (256 slots, 4,096 positions, bf16 pages, the whole
+    vocabulary tied to the head), one state-space layer on either side
+    of one attention layer: only shapes matter to a compile, and these
+    are the ones the chip's compiler could refuse (slabs of ``[256, 16,
+    5120]`` and ``[256, 15360]``, 20 stacked query rows on ONE K/V head,
+    logits of ``[256, 65536]``); 2,049 pages make the pools 16 MB."""
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine, MambaLM
+
+    model = MambaLM(
+        vocab_size=65536, d_model=2560,
+        layer_kinds=("recurrent", "attention", "recurrent"), d_inner=5120,
+        d_state=16, d_conv=4, dt_rank=160, num_heads=20, num_kv_heads=1,
+        head_dim=128, ffn_dim=8192)
+    weights = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
+    return DecodeEngine(model, weights, DecodeConfig(
+        slots=256, max_seq_len=4096, num_pages=256 * 8 + 1,
+        use_pallas="always", cache_dtype="bfloat16"))
+
+
+@pytest.mark.parametrize("program", ["step", "prefill_512"])
+def test_jamba2_width_programs_compile(one_chip, program):
+    """The joint step at 256 slots (the state kernel and the
+    convolution's over the slabs IN PLACE: no copy of a slab anywhere,
+    both by the names the benchmark's pattern finds; the paged kernel at
+    20 rows of the one head; the head over the embedding where it lies)
+    and the 512-row whole-prompt prefill (one call of the scan kernel a
+    recurrent layer, eight tiles of 64; the attention in plain blocks).
+    The slabs' device layout holds no padding: the memory analysis reads
+    the logical bytes."""
+    from paddle_tpu.ops import pallas_ssm as ps
+
+    eng = _mamba_engine()
+    state = [tuple(eng._scope.get_var(n).shape) for n in eng._state_vars]
+    assert state == [(1, 2049, 16, 128)] * 2 \
+        + [(256, 16, 5120), (256, 15360)] * 2
+    slabs = 2 * 256 * (16 * 5120 + 15360) * 4
+    pools = 2 * 2049 * 16 * 128 * 2
+    if program == "step":
+        compiled = eng.lower_step(sharding=one_chip).compile()
+        text = compiled.as_text()
+        entry = _computation(text, "ENTRY ")
+        step = _metric_pattern("ssm_step_ms.serve")
+        assert _metric_pattern("ssm_step_roofline").pattern == step.pattern
+        calls = [ln.strip() for ln in entry if step.search(ln.strip())]
+        assert sorted(c.split(".")[0] for c in calls) == [
+            "%" + ps.CONV_KERNEL] * 2 + ["%" + ps.STEP_KERNEL] * 2
+        assert all("f32[256,16,5120]" in c or "f32[256,15360]" in c
+                   for c in calls)
+        paged = [ln for ln in entry if _metric_pattern(
+            "full_attn_ms_per_step.serve").search(ln.strip())]
+        assert len(paged) == 1 and text.count("tpu_custom_call") == 5
+        # no slab is copied: the kernels' results ARE the program's
+        assert not re.search(
+            r"= f32\[256,(16,5120|15360)\][^ ]* copy\(", text)
+        proj = _metric_pattern("ssm_proj_ms_per_step.serve")
+        assert {m for ln in entry for m in proj.findall(ln)} >= {"in", "out"}
+        ffn = _metric_pattern("dense_ffn_ms_per_step.serve")
+        assert {m for ln in entry for m in ffn.findall(ln)} == {
+            "gate", "up", "down"}
+        # the tied head contracts the embedding where it lies
+        assert "bf16[2560,65536]" not in text
+    else:
+        assert eng._prefill_walks(512) == [(1, None, ("blocks", 512, 512))]
+        assert eng.model.prefill_chunks_per_call(512) == 8
+        compiled = eng.lower_prefill(512, sharding=one_chip).compile()
+        text = compiled.as_text()
+        scan = _metric_pattern("ssm_prefill_ms.serve")
+        assert _metric_pattern("ssm_prefill_roofline").pattern \
+            == scan.pattern
+        calls = [ln.strip() for ln in text.splitlines()
+                 if scan.search(ln.strip())]
+        assert len(calls) == 2 == text.count("tpu_custom_call")
+        assert all(c.count("f32[512,5120]") >= 3
+                   and "f32[1,16,5120]" in c for c in calls)
+        assert f"%{ps.STEP_KERNEL}" not in text
+    mem = compiled.memory_analysis()
+    # arguments: the weights (1.05 GB of three layers and the embedding),
+    # the pools and the slabs at their LOGICAL bytes, a packed row
+    weights = sum(x.size * x.dtype.itemsize for x in
+                  jax.tree_util.tree_leaves(eng.weights))
+    assert weights + pools + slabs <= mem.argument_size_in_bytes \
+        < weights + pools + slabs + (4 << 20)
+    assert mem.alias_size_in_bytes == pools + slabs
+    assert mem.temp_size_in_bytes < 1 << 30
