@@ -257,16 +257,24 @@ def feed_forward(model, l, lw, x, attend):
         return x + model.routed_scale * routed + _swiglu(h, lw, "shared")
 
 
-def head_logits(model, w, x, attend):
-    """The logits of ``x``'s rows, or of a prompt's read row alone."""
+def read_rows(x, attend):
+    """The rows of ``x`` whose logits the program reads: of a prompt
+    whose ``attend`` names its ``read_row``, that row alone ``[1, D]``
+    (the final norm and the head over a whole bucket are up to a
+    seventh of a prefill's matmuls and gigabytes of float32 nobody
+    reads); else ``x``."""
     import jax
 
-    if attend.prompt and attend.read_row is not None:
-        # the one row of a prompt whose logits are read: the head
-        # over every row would be a seventh of a prefill's matmuls
-        # and 0.67 GB of float32 nobody reads
+    if getattr(attend, "prompt", False) \
+            and getattr(attend, "read_row", None) is not None:
         x = jax.lax.dynamic_slice_in_dim(x, attend.read_row, 1, axis=0)
-    return _mm(rms_norm(x, w["norm_f"], model.rms_eps), w["lm_head"])
+    return x
+
+
+def head_logits(model, w, x, attend):
+    """The logits of ``x``'s rows, or of a prompt's read row alone."""
+    return _mm(rms_norm(read_rows(x, attend), w["norm_f"], model.rms_eps),
+               w["lm_head"])
 
 
 def dense_from(keys, dt):

@@ -44,7 +44,8 @@ import numpy as np
 from ..ops import moe_ops
 from .blocks import (DENSE_SCOPE, ROPE_SCOPE, _mm, dense_from,
                      half_split_angles, half_split_rotate, held_ids,
-                     rms_norm, route_share, share_ffn, step_tallies)
+                     read_rows, rms_norm, route_share, share_ffn,
+                     step_tallies)
 
 CONV_SCOPE = "short_conv"
 CONV_PROMPT_SCOPE = "short_conv_prompt"
@@ -198,12 +199,8 @@ class ConvMoELM:
                 local = route_share(h, lw, attend, self.top_k,
                                     self.held_experts)
                 x = x + share_ffn(self, h, lw, local, attend)
-        if attend.prompt and attend.read_row is not None:
-            # the one row of a prompt whose logits are read: the head
-            # over a bucket of 2,048 rows would be 0.54 GB of float32
-            # nobody reads
-            x = jax.lax.dynamic_slice_in_dim(x, attend.read_row, 1, axis=0)
-        return self._head(w, rms_norm(x, w["norm_f"], self.rms_eps)), cache
+        return self._head(w, rms_norm(read_rows(x, attend), w["norm_f"],
+                                      self.rms_eps)), cache
 
     def _head(self, w, x):
         """``x [..., D]`` over the vocabulary: the tied head reads the

@@ -45,7 +45,7 @@ from typing import Sequence
 
 import numpy as np
 
-from .blocks import DENSE_SCOPE, _mm, dense_from, rms_norm
+from .blocks import DENSE_SCOPE, _mm, dense_from, head_logits, rms_norm
 
 GDN_SCOPE = "gdn_update"        # the one-token update's operations
 GDN_CHUNK_SCOPE = "gdn_chunk"   # the chunk form's
@@ -160,8 +160,8 @@ class GatedDeltaLM:
                 y = _mm(jax.nn.silu(_mm(x, lw["ffn_w_gate"]))
                         * _mm(x, lw["ffn_w_up"]), lw["ffn_w_down"])
             x = x + rms_norm(y, lw["norm_ffn"], self.rms_eps)
-        return _mm(rms_norm(x, weights["norm_f"], self.rms_eps),
-                   weights["lm_head"]), cache
+        # every row's logits, or a prompt's ``attend.read_row`` alone
+        return head_logits(self, weights, x, attend), cache
 
     def _attention(self, l, lw, x, cache, attend):
         """Layer ``l``'s softmax attention of the rows ``x`` -> (its

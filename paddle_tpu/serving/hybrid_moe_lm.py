@@ -35,8 +35,8 @@ import math
 from typing import Sequence
 
 from ..ops import moe_ops
-from .blocks import (MOE_SHARED_SCOPE, _mm, dense_from, held_ids, rms_norm,
-                     route_share, share_ffn, step_tallies)
+from .blocks import (MOE_SHARED_SCOPE, _mm, dense_from, head_logits,
+                     held_ids, rms_norm, route_share, share_ffn, step_tallies)
 from .mixers import KDAMixer
 
 
@@ -159,5 +159,5 @@ class HybridMoELM(KDAMixer):
                              * _mm(h, lw["shared_w_up"]),
                              lw["shared_w_down"])
             x = x + share_ffn(self, h, lw, local, attend) + shared
-        return _mm(rms_norm(x, w["norm_f"], self.rms_eps),
-                   w["lm_head"]), cache
+        # every row's logits, or a prompt's ``attend.read_row`` alone
+        return head_logits(self, w, x, attend), cache

@@ -34,7 +34,7 @@ from typing import Sequence
 from ..ops import moe_ops
 from .blocks import (MOE_SHARED_SCOPE, ROPE_SCOPE, _mm, adjacent_angles,
                      adjacent_rotate_negated_partner, dense_from, held_ids,
-                     route_share, share_ffn, step_tallies)
+                     read_rows, route_share, share_ffn, step_tallies)
 
 
 class ParallelMoELM:
@@ -136,7 +136,7 @@ class ParallelMoELM:
             a, cache = self._attention(l, lw, h, positions, cache, attend)
             # ONE add: attention and the feed-forward read the same h
             x = x + a + self._feed_forward(lw, h, attend)
-        return self._head(weights, x), cache
+        return self._head(weights, read_rows(x, attend)), cache
 
     def _attention(self, l, lw, h, positions, cache, attend):
         """Layer ``l``'s attention of the normed rows ``h`` -> (its

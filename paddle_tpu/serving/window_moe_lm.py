@@ -32,8 +32,8 @@ from typing import Sequence
 
 from ..ops import moe_ops
 from .blocks import (DENSE_SCOPE, ROPE_SCOPE, _mm, dense_from,
-                     half_split_angles, half_split_rotate, held_ids,
-                     rms_norm, route_share, share_ffn, step_tallies)
+                     half_split_angles, half_split_rotate, head_logits,
+                     held_ids, rms_norm, route_share, share_ffn, step_tallies)
 
 
 class WindowMoELM:
@@ -178,5 +178,5 @@ class WindowMoELM:
                 local = route_share(h, lw, attend, self.top_k,
                                     self.held_experts)
                 x = x + share_ffn(self, h, lw, local, attend)
-        return _mm(rms_norm(x, w["norm_f"], self.rms_eps),
-                   w["lm_head"]), cache
+        # every row's logits, or a prompt's ``attend.read_row`` alone
+        return head_logits(self, w, x, attend), cache

@@ -528,12 +528,13 @@ def test_a_recurrent_layer_of_the_prompt_is_one_loop_that_holds_the_group():
 
 # sha256 of the lowered text of ``make_model()``'s joint step and
 # 128-row whole-prompt prefill behind ``engine()``: the step as PR 46's
-# tree lowers it, the prefill since its chunk form takes a group of
-# chunks a call (PR 53)
+# tree lowers it, the prefill since its head forms the one row the
+# engine reads (PR 62; until then as PR 53 left it, its chunk form a
+# group of chunks a call and the head over all 128 rows)
 PROGRAMS_AS_LOWERED = {
     "step": "93ad8ee9930b9c80b7f7980246e89a35d1b3df4b9a69cbc6688e0aa3435f8573",
     "prefill":
-        "b758e87029ce950ed99f262402691584891dc1706d5a42db29eb81b0797789f0"}
+        "25ed6649e15f0b32d6dbe973519ca6649c114cebb8d8fa9994728c140de1a604"}
 
 
 @pytest.mark.parametrize("program", ["step", "prefill"])
@@ -543,7 +544,8 @@ def test_the_programs_are_still_the_ones_lowered_before_the_kernel(program):
     update nothing new, so the joint step lowers to the text it had
     before ``HybridMoELM``'s update moved into a kernel (PR 47), and the
     whole-prompt prefill to the text PR 53 gave it (its chunk form a
-    group of chunks a call).  A change MEANT to move these programs
+    group of chunks a call) but for the head, which PR 62 cut to the row
+    the engine reads.  A change MEANT to move these programs
     replaces the digests; one that was not has found out here."""
     import hashlib
 
@@ -560,3 +562,20 @@ def test_the_programs_are_still_the_ones_lowered_before_the_kernel(program):
     assert "tpu_custom_call" not in text
     assert hashlib.sha256(text.encode()).hexdigest() \
         == PROGRAMS_AS_LOWERED[program]
+
+
+@pytest.mark.parametrize("length, bucket", [(11, 16), (37, 64)])
+def test_a_prompts_head_forms_the_one_row_the_engine_reads(
+        length, bucket, monkeypatch):
+    """The whole-prompt prefill names the row it reads and the model
+    hands back ``[1, V]`` (``blocks.head_logits``, PR 62): the head over
+    a 4,096-row bucket of the cell was 22.8 of a prefill's 145 ms.  Rows
+    inside a short and a longer bucket: tokens and recorded logits are
+    those of the form that made every row's; the joint step makes every
+    slot's as ever."""
+    import sys
+
+    from prompt_head_forms import the_read_row_is_the_every_row_forms
+
+    the_read_row_is_the_every_row_forms(
+        sys.modules[__name__], length, bucket, monkeypatch)

@@ -556,16 +556,17 @@ def test_a_step_that_keeps_the_dense_form_is_the_program_it_was(which):
 
 
 # sha256 of the 64-row whole-prompt prefill's lowered text (``make_model()``,
-# 3 slots) and what the gauge says of the model.  The toy widths': taken
-# on the commit before the engine's ``recur`` learned how many of a
-# rule's chunks a call covers (PR 53): no chunk form then or now.  The
-# kernel widths': the chunk form interpreted (PR 58; until then a call
-# walked ``PREFILL_CHUNK`` tokens through the token rule's kernel)
+# 3 slots) and what the gauge says of the model, both since the head
+# forms the one row the engine reads (PR 62).  The toy widths': no chunk
+# form (until PR 62 the text was that of the commit before the engine's
+# ``recur`` learned how many of a rule's chunks a call covers, PR 53).
+# The kernel widths': the chunk form interpreted (PR 58; until then a
+# call walked ``PREFILL_CHUNK`` tokens through the token rule's kernel)
 PREFILLS_AS_LOWERED = {
-    "toy": ("c434fdc64b206e6b06a262e37f7741e65d1b9e0e74b9f7c4ab155db008902a55",
+    "toy": ("961f1ad842cfeffdc79f2643f9633be6383cd9bad625081248b885bcb1b8a778",
             0),
     "kernel": (
-        "03d9892b13bcd66b93172ca0caa8e0e707e18c62095e4cf70b9944d4d097ec95", 1),
+        "9cd6327c9827a110228455eb938a26e66de9ebdf8be92c6386d383b26f0f2e2e", 1),
 }
 
 
@@ -574,8 +575,9 @@ def test_a_prefills_calls_cover_the_buckets_chunks(widths):
     """``GatedDeltaLM`` hands ``attend.recur`` a group of its rule's
     chunks a call (PR 53).  At toy widths this model hands no chunk
     form at all, says so (gauge ``decode_prefill_chunks_per_call``), and
-    its prefill lowers to the text it had.  Where the kernels take the
-    state a call covers the bucket's chunks of the rule's WY form
+    its prefill lowers to the text it had (but for the head's one row,
+    PR 62).  Where the kernels take the state a call covers the
+    bucket's chunks of the rule's WY form
     (``PREFILL_CHUNK`` tokens) up to what ``GROUP_BYTES`` of its
     temporaries allow (PR 58): a function of the bucket and the widths
     alone, the same number on the gauge; lowered for the chip the
@@ -831,3 +833,19 @@ def test_the_steps_slabs_go_through_the_kernel_and_nothing_else():
     # the convolution's tail is the model's to mask: one select a layer
     assert len([ln for ln in main if "call @_where" in ln
                 and "tensor<3x9216xf32>" in ln.split("->")[-1]]) == 2
+
+
+@pytest.mark.parametrize("length, bucket", [(11, 16), (37, 64)])
+def test_a_prompts_head_forms_the_one_row_the_engine_reads(
+        length, bucket, monkeypatch):
+    """The whole-prompt prefill names the row it reads and the model
+    hands back ``[1, V]`` (``blocks.head_logits``, PR 62).  Rows inside
+    a short and a longer bucket: tokens and recorded logits are those
+    of the form that made every row's; the joint step makes every slot's
+    as ever."""
+    import sys
+
+    from prompt_head_forms import the_read_row_is_the_every_row_forms
+
+    the_read_row_is_the_every_row_forms(
+        sys.modules[__name__], length, bucket, monkeypatch)

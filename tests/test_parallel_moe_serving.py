@@ -413,3 +413,55 @@ def test_what_cannot_hold_over_a_ring_refuses():
         engine(model, weights, prefill_chunk_pages=1)
     with pytest.raises(ValueError, match="window layers.*speculative"):
         engine(model, weights, spec_k=2)
+
+
+@pytest.mark.parametrize("length, bucket", [(11, 16), (37, 64)])
+def test_a_prompts_head_forms_the_one_row_the_engine_reads(
+        length, bucket, monkeypatch):
+    """The whole-prompt prefill names the row it reads and the model
+    cuts the residual to it in front of ``_head`` (``blocks.read_rows``,
+    PR 62): ``[1, V]`` through the LayerNorm, the transposed embedding
+    and ``logit_scale``.  Rows inside a short and a longer bucket:
+    tokens and recorded logits are those of the form that made every
+    row's; the joint step makes every slot's as ever."""
+    import sys
+
+    from prompt_head_forms import the_read_row_is_the_every_row_forms
+
+    the_read_row_is_the_every_row_forms(
+        sys.modules[__name__], length, bucket, monkeypatch)
+
+
+@pytest.mark.parametrize("says, rows", [
+    ({}, 8), (dict(prompt=False, read_row=5), 8),
+    (dict(prompt=True, read_row=None), 8), (dict(prompt=True, read_row=5), 1),
+], ids=["a_plain_callable", "rows_that_are_no_prompt",
+        "a_prompt_that_names_no_row", "a_prompt_that_names_its_row"])
+def test_only_a_prompt_that_names_its_row_is_cut_to_it(says, rows):
+    """``blocks.read_rows`` is the one place a served model's head asks
+    which rows are read: a test's plain callable says nothing, the joint
+    step and the multi-row program of chunks and verification
+    (``prompt`` False) read every row, and so does a prompt whose
+    engine names none."""
+    import types
+
+    from paddle_tpu.serving.blocks import head_logits, read_rows
+
+    def attend(*a):
+        raise AssertionError("the head attends nothing")
+
+    vars(attend).update(says)
+    x = jax.random.normal(jax.random.PRNGKey(0), (8, 16), jnp.float32)
+    got = read_rows(x, attend)
+    if rows == 8:
+        assert got is x
+    else:
+        np.testing.assert_array_equal(got, x[5:6])
+    w = dict(norm_f=jnp.full((16,), 1.5),
+             lm_head=jax.random.normal(jax.random.PRNGKey(1), (16, 24)))
+    model = types.SimpleNamespace(rms_eps=1e-6)
+    every = head_logits(model, w, x, lambda *a: None)
+    assert every.shape == (8, 24)
+    np.testing.assert_allclose(
+        head_logits(model, w, x, attend),
+        every if rows == 8 else every[5:6], rtol=1e-6, atol=1e-6)

@@ -495,3 +495,19 @@ def test_every_request_is_admitted_fresh_and_the_ring_costs_no_upload():
     assert d["decode_window_blocks_walked"] == 10
     assert 0 < d["moe_experts_hit"] <= 10 * 4 * 5
     assert stat_get("decode_window_bytes") == eng._cache.window_bytes()
+
+
+@pytest.mark.parametrize("length, bucket", [(11, 16), (37, 64)])
+def test_a_prompts_head_forms_the_one_row_the_engine_reads(
+        length, bucket, monkeypatch):
+    """The whole-prompt prefill names the row it reads and the model
+    hands back ``[1, V]`` (``blocks.head_logits``, PR 62).  Rows inside
+    a short and a longer bucket: tokens and recorded logits are those
+    of the form that made every row's; the joint step makes every slot's
+    as ever."""
+    import sys
+
+    from prompt_head_forms import the_read_row_is_the_every_row_forms
+
+    the_read_row_is_the_every_row_forms(
+        sys.modules[__name__], length, bucket, monkeypatch)
