@@ -409,6 +409,14 @@ RULES = (
          "once a joint decode step; `decode_latent_positions_live` over "
          "it x a block's positions is the share of what it copies that "
          "is attended"),
+    Rule("decode_kv_joint_rows", "gauge", "serving",
+         "1 where a position's K and V lie side by side in ONE pool row "
+         "(`serving/kv_cache.py` `CacheConfig.joint`: one unquantized "
+         "K/V head whose keys and values are whole lane tiles wide; the "
+         "paged kernel then starts one copy a page, not two), 0 where "
+         "the cache keeps two pools or a latent row; read from the "
+         "cache's shape when the engine is built.  "
+         "`decode_kv_pool_row_lanes` then reads keys + values"),
     Rule("decode_attn_feed_bits", "gauge", "serving",
          "Width of the K and V operands the paged attention kernel's "
          "matmuls take, read from the pools' dtype when the engine "

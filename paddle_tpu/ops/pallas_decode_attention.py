@@ -154,6 +154,23 @@ against 1.44 at the cell's rows), and a block is ``_LATENT_BLOCK``
 positions and not one lane tile of scores.  The call has its own name
 (``LATENT_KERNEL_NAME``).
 
+**One pool, K and V side by side** (``v_pages=None``, ``value_lanes``
+AND ``value_offset``).  ``v_pages=None`` has two meanings, and
+``value_offset`` tells them apart: None is the latent pool above; a lane
+is a JOINT pool (`serving/kv_cache.py` ``CacheConfig.joint``), whose row
+is ONE K/V head's keys (its first D lanes) and, from lane
+``value_offset`` on, its ``value_lanes`` values.  The kernel then starts
+ONE copy a page where two pools cost two, the same bytes in half the
+descriptors and half the waits, which is what paces a call whose pages
+are 4 KB a pool (the table by ``_STACKED_BLOCK``: Jamba2-3B's call 1.83
+-> 1.33 ms).  Nothing else is the latent body's: the K-and-V body runs
+on the one buffer (the scores read its first D lanes, the values the
+lanes after them), with its three bfloat16 terms, its block
+(``pages_per_block`` as for two pools of those widths), its guard on the
+values' lanes alone and its name (``KERNEL_NAME``), the arithmetic and
+its order unchanged: the output is the two-pool call's on the same K and
+V, bit for bit.  The plain path slices K and V out of the gathered row.
+
 ``paged_chunk_attention`` is the kernel itself: R query rows per slot
 with per-row causal lengths over one shared page table — the attention
 shape of chunked/suffix prefill and speculative verification
@@ -363,7 +380,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            layer=0, sm_scale=None, use_pallas="auto",
                            interpret=False, k_scales=None,
                            v_scales=None, window=None, sinks=None,
-                           value_lanes=None):
+                           value_lanes=None, value_offset=None):
     """Decode attention straight off the page pool.
 
     q [S,H,D]; k/v_pages [L,P,page,H*D] (the stacked pools; ``layer``
@@ -375,7 +392,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     [L,P,page,H] arm the quantized path (FLAGS_decode_kv_quant): pages
     are int8 and BOTH paths dequantize them inline — the Pallas kernel
     per tile in VMEM, the reference during the gather — before the one
-    shared masked-softmax formulation.  ``window`` / ``sinks`` [H]: see
+    shared masked-softmax formulation.  ``window`` / ``sinks`` [H],
+    ``value_lanes`` / ``value_offset`` (``v_pages=None``): see
     ``paged_chunk_attention``.
     """
     # one query row per slot IS the chunk kernel at R=1: Mosaic has no
@@ -385,7 +403,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         q[:, None], k_pages, v_pages, page_table, lengths[:, None],
         layer=layer, sm_scale=sm_scale, use_pallas=use_pallas,
         interpret=interpret, k_scales=k_scales, v_scales=v_scales,
-        window=window, sinks=sinks, value_lanes=value_lanes)[:, 0]
+        window=window, sinks=sinks, value_lanes=value_lanes,
+        value_offset=value_offset)[:, 0]
 
 
 # -- the kernel: R query rows per slot (decode is R=1) --------------------
@@ -432,6 +451,16 @@ _LATENT_BLOCK = 1024
 #       2.91  2.90                     [2.90 2.89; 2.63]
 #   MiMo window   8 x (192 + 128), 8 rows, rings of 9 pages: 0.35 [0.18]
 #       at 128, the only block its table holds.
+#   Jamba2-3B   ONE head of 128 + 128, 20 rows on it, 256 slots of
+#   0.3-3.6k (my chip runs, PR 63), at 128 / 256 / 512 / 1,024:
+#       3.25  2.31  1.83  1.69         [1.33 1.20 1.16 1.19; 0.31]
+#     a page is 4 KB a pool and the 64 copies of a block of 512 issue in
+#     3.7x their bytes' time: the call is paced by their COUNT.  The same
+#     K and V in ONE pool of 256-lane rows (a joint pool, the module
+#     header: 32 copies of 8 KB a block), the call and [its copies]:
+#       -     1.82  1.33  1.21         [1.11 0.75 0.65 0.68; 0.31]
+#     what is left over the copies (0.68 at 512) is the one stack's
+#     chain, which nothing overlaps at one K/V head.
 # 512 takes all but 2 % of what any block gives and both its buffers are
 # 2.6 MB at MiMo's rows and ``_BLOCK_VMEM_BYTES`` at Command A+'s; past it
 # the dead positions of a slot's last block and the page-by-page copies
@@ -551,8 +580,8 @@ def _join_terms(x, rows, n=_SPLIT_TERMS):
 
 def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
                   page, pps, ppb, n_slots, n_rows, head_dim, v_dim=None,
-                  quantized=False, window=None, sinks=False, latent=False,
-                  terms=_SPLIT_TERMS):
+                  quantized=False, window=None, sinks=False, one_pool=False,
+                  v_off=0, terms=_SPLIT_TERMS):
     """One grid step is one SLOT: R query rows (a prefill chunk, a
     speculative t0+draft window, or decode's one) over the slot's live
     blocks of ``ppb`` page-table entries.  Row r of slot s attends
@@ -577,15 +606,17 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
     whole packed tiles, hb*Dk) in bfloat16, and two more scratch follow:
     the float32 rows the query is stacked in before it is split, and
     each stack's probabilities as groups of rows, (n_stacks, 3 * hb*R
-    ..., ppb*page) in bfloat16.  ``latent``: there is no V pool and no V
-    buffer, the values are the leading ``v_dim`` lanes of the K buffer's
-    rows; ``terms``: how many bfloat16 terms the float32 side rides as."""
+    ..., ppb*page) in bfloat16.  ``one_pool``: there is no V pool and no V
+    buffer, the values are ``v_dim`` lanes of the K buffer's rows from
+    lane ``v_off`` on (0: a latent row's leading lanes, which the scores
+    read too; past the keys: a joint row's second half); ``terms``: how
+    many bfloat16 terms the float32 side rides as."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if window is not None:
         slot_lo_ref, *rest = rest
-    if latent:
+    if one_pool:
         q_ref, k_hbm, *rest = rest
     else:
         q_ref, k_hbm, v_hbm, *rest = rest
@@ -594,7 +625,7 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
     if sinks:
         sink_ref, *rest = rest
     o_ref, qbd_scr, m_scr, l_scr, acc_scr, k_buf, *rest = rest
-    if latent:
+    if one_pool:
         # one copy of a block serves the scores and the values
         v_buf, pools = k_buf, ((k_hbm, k_buf),)
     else:
@@ -615,6 +646,10 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
     lane_head = lax.broadcasted_iota(jnp.int32, (1, width), 1) // head_dim
     v_lane_head = lane_head if v_width == width else lax.broadcasted_iota(
         jnp.int32, (1, v_width), 1) // v_dim
+    # the values' lanes of a V buffer's row: all of it, but of a joint
+    # row the half after the keys
+    v_row = slice(v_off, v_off + (n_stacks * v_width if v_off
+                                  else v_buf.shape[3]))
 
     def first_block(s):
         """The block a slot's walk starts at."""
@@ -724,8 +759,8 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
         first, lo, hi = live_entries(s_idx, b)
 
         def _zero(entry, carry):
-            v_buf[buf, entry - first] = jnp.zeros(
-                v_buf.shape[2:], v_buf.dtype)
+            v_buf[buf, entry - first, :, v_row] = jnp.zeros(
+                (page, v_row.stop - v_row.start), v_buf.dtype)
             return carry
 
         @pl.when(hi - lo < ppb)
@@ -739,8 +774,8 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
         def _tail():
             at = max_len // page - first
             row = lax.broadcasted_iota(jnp.int32, (page, 1), 0)
-            v_buf[buf, at] = jnp.where(
-                row < tail, v_buf[buf, at].astype(jnp.float32),
+            v_buf[buf, at, :, v_row] = jnp.where(
+                row < tail, v_buf[buf, at, :, v_row].astype(jnp.float32),
                 0.0).astype(v_buf.dtype)
 
     def update_from_bf16(buf, live):
@@ -755,7 +790,7 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
         stacks = range(n_stacks)
         k = [k_buf[buf, :, :, j * width:(j + 1) * width]
              .reshape(block, width) for j in stacks]
-        v = [v_buf[buf, :, :, j * v_width:(j + 1) * v_width]
+        v = [v_buf[buf, :, :, v_off + j * v_width:v_off + (j + 1) * v_width]
              .reshape(block, v_width) for j in stacks]
         m_prev = [m_scr[j, :, :1] for j in stacks]
         l_prev = [l_scr[j, :, :1] for j in stacks]
@@ -816,7 +851,7 @@ def _chunk_kernel(layer_ref, pt_ref, len_ref, slot_len_ref, *rest, sm_scale,
             v_live = v_live & (col >= slot_lo_ref[s_idx] // page * page)
         for j in range(n_stacks):
             lanes = slice(j * width, (j + 1) * width)
-            v_lanes = slice(j * v_width, (j + 1) * v_width)
+            v_lanes = slice(v_off + j * v_width, v_off + (j + 1) * v_width)
             k = k_buf[buf, :, :, lanes].astype(jnp.float32) \
                 .reshape(block, width)
             v = v_buf[buf, :, :, v_lanes].astype(jnp.float32) \
@@ -871,24 +906,37 @@ def _scale_lanes(scale_ref, at, stack, hb, lane_head):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "sm_scale", "interpret", "window", "value_lanes"))
+    "sm_scale", "interpret", "window", "value_lanes", "value_offset"))
 def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
                 k_scales=None, v_scales=None, sinks=None, *, sm_scale,
-                interpret, window=None, value_lanes=None):
+                interpret, window=None, value_lanes=None,
+                value_offset=None):
     """The ``pallas_call``.  ``layer`` is an OPERAND (int32 scalar), so
     a model's layers share one traced and lowered kernel: a program
     pays for the body once, not once a layer.  ``sinks`` [R, H]: the
     logit of each query row and head.  ``v_pages=None``: the values are
-    the first ``value_lanes`` lanes of K's rows (one head; the module
-    header)."""
+    ``value_lanes`` lanes of K's rows (one head; the module header), the
+    row's first where ``value_offset`` is None (a latent pool) and those
+    from ``value_offset`` on where it is given (a joint pool: the
+    K-and-V body on one buffer)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n_slots, n_rows, h, d = q.shape
     pps = page_table.shape[1]
     page, hd = k_pages.shape[2:]
-    latent = v_pages is None
-    if latent:
+    one_pool = v_pages is None
+    joint = one_pool and value_offset is not None
+    latent = one_pool and not joint
+    if joint:
+        if k_scales is not None or h != 1 or not value_lanes \
+                or not d <= value_offset <= hd - value_lanes:
+            raise ValueError(
+                f"a joint pool's rows ({hd} lanes) are one unquantized "
+                f"head's keys, then its values: q has {h} heads of {d}, "
+                f"the values {value_lanes} lanes from {value_offset}")
+        v_hd = value_lanes
+    elif latent:
         if h != 1 or d > hd or not value_lanes or value_lanes > hd:
             raise ValueError(
                 f"a latent pool's rows ({hd} lanes) are one head's: q has "
@@ -899,7 +947,7 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
         d, v_hd = hd, value_lanes
     else:
         v_hd = v_pages.shape[3]
-    if hd != h * d or v_hd % h:
+    if (hd != h * d and not joint) or v_hd % h:
         raise ValueError(
             f"pool rows are {hd} (K) and {v_hd} (V) lanes wide but q has "
             f"{h} heads of {d}")
@@ -907,12 +955,13 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
     # bfloat16 blocks go to the matmuls as they lie in the pool, and the
     # float32 side rides as three groups of bfloat16 rows
     split = feed_bits(k_pages.dtype) == 16 and (
-        latent or feed_bits(v_pages.dtype) == 16)
+        one_pool or feed_bits(v_pages.dtype) == 16)
     terms = _LATENT_TERMS if latent else _SPLIT_TERMS
     hb = _stack_heads(h, d, n_rows, dv,
                       _MAX_SPLIT_ROWS if split else _MAX_STACK_ROWS)
     n_stacks, rows, width, v_width = h // hb, hb * n_rows, hb * d, hb * dv
-    ppb = pages_per_block(page, pps, hd, k_pages.dtype,
+    # a joint row's halves are two pools' widths to the rule
+    ppb = pages_per_block(page, pps, h * d, k_pages.dtype,
                           0 if latent else v_hd, h, n_rows)
     quantized = k_scales is not None
     row_lengths = row_lengths.astype(jnp.int32)
@@ -928,10 +977,10 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
     # the stacked pools stay in HBM; the kernel copies the live pages
     # of one layer itself, so nothing else of a pool ever moves
     pool_spec = pl.BlockSpec(memory_space=pltpu.HBM)
-    in_specs = [slot_block(n_rows, hd), pool_spec] \
-        + [pool_spec] * (not latent)
-    operands = [q.reshape(n_slots, n_rows, hd), k_pages] \
-        + [v_pages] * (not latent)
+    in_specs = [slot_block(n_rows, h * d), pool_spec] \
+        + [pool_spec] * (not one_pool)
+    operands = [q.reshape(n_slots, n_rows, h * d), k_pages] \
+        + [v_pages] * (not one_pool)
     if quantized:
         # the chip's compiler cuts no page out of a plane H lanes wide
         # in HBM, so a slot's scales are gathered by its table out here
@@ -965,7 +1014,7 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
             pltpu.VMEM((n_stacks, rows, _LANES), jnp.float32),  # denom
             pltpu.VMEM((n_stacks, rows, v_width), jnp.float32),  # acc
             pltpu.VMEM((2, ppb, page, hd), k_pages.dtype),      # K blocks
-        ] + ([] if latent else [
+        ] + ([] if one_pool else [
             pltpu.VMEM((2, ppb, page, v_hd), v_pages.dtype),    # V blocks
         ]) + [
             pltpu.SemaphoreType.DMA((2,)),     # one a buffer, K and V
@@ -980,8 +1029,8 @@ def _chunk_call(q, k_pages, v_pages, layer, page_table, row_lengths,
                              pps=pps, ppb=ppb, n_slots=n_slots,
                              n_rows=n_rows, head_dim=d, v_dim=dv,
                              quantized=quantized, window=window,
-                             sinks=sinks is not None, latent=latent,
-                             terms=terms)
+                             sinks=sinks is not None, one_pool=one_pool,
+                             v_off=value_offset or 0, terms=terms)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
@@ -1000,7 +1049,7 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
                           *, layer=0, sm_scale=None, use_pallas="auto",
                           interpret=False, k_scales=None,
                           v_scales=None, window=None, sinks=None,
-                          value_lanes=None):
+                          value_lanes=None, value_offset=None):
     """Multi-row attention off the page pool — R query rows per slot.
 
     q [S,R,H,D]; k_pages [L,P,page,Hkv*D] and v_pages [L,P,page,Hkv*Dv]
@@ -1030,20 +1079,25 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
     ``paged_decode_attention``.  ``v_pages=None`` with ``value_lanes``:
     a latent pool (the module header), ONE row a position of at least
     D lanes that every query head reads, whose first ``value_lanes``
-    lanes are the values (the output is [S,R,H,value_lanes]).
+    lanes are the values (the output is [S,R,H,value_lanes]).  With
+    ``value_offset`` too: a JOINT pool, ONE K/V head's keys in a row's
+    first D lanes and its values in the ``value_lanes`` from
+    ``value_offset`` on, what `serving/kv_cache.py` keeps where a cache
+    has one K/V head of whole lane tiles; every answer is the two-pool
+    call's on the same K and V, bit for bit.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     s, r, h, d = q.shape
-    latent = v_pages is None
-    kv_heads = 1 if latent else k_pages.shape[-1] // d
+    one_pool = v_pages is None      # a latent pool, or a joint one
+    kv_heads = 1 if one_pool else k_pages.shape[-1] // d
     if sinks is not None and sinks.ndim == 1:
         sinks = jnp.broadcast_to(sinks, (r, h))
     if kv_heads != h:
         # grouped-query heads: the G query heads that share a K/V head
         # ride as G more ROWS of that head's slot, so the kernel (and
         # the reference) read a slot's pages once for all of them
-        if h % kv_heads or (k_pages.shape[-1] % d and not latent):
+        if h % kv_heads or (k_pages.shape[-1] % d and not one_pool):
             raise ValueError(
                 f"q has {h} heads of {d} but the pool rows hold "
                 f"{k_pages.shape[-1]} lanes: not a whole group a K/V head")
@@ -1058,7 +1112,8 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
             jnp.repeat(row_lengths, g, axis=1), layer=layer,
             sm_scale=sm_scale, use_pallas=use_pallas, interpret=interpret,
             k_scales=k_scales, v_scales=v_scales, window=window,
-            sinks=sinks, value_lanes=value_lanes)
+            sinks=sinks, value_lanes=value_lanes,
+            value_offset=value_offset)
         return out.reshape(s, r, g, kv_heads, -1).transpose(0, 1, 3, 2, 4) \
             .reshape(s, r, h, -1)
     if _kernel_asked(use_pallas):
@@ -1066,7 +1121,8 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
                            page_table, row_lengths, k_scales, v_scales,
                            sinks, sm_scale=float(sm_scale),
                            interpret=interpret, window=window,
-                           value_lanes=value_lanes)
+                           value_lanes=value_lanes,
+                           value_offset=value_offset)
     offset = None
     if window is not None:
         # the ring read in logical order: its pps entries hold the
@@ -1078,8 +1134,9 @@ def paged_chunk_attention(q, k_pages, v_pages, page_table, row_lengths,
             page_table, (first[:, None] + jnp.arange(pps)) % pps, axis=1)
         offset = jnp.repeat(first * page, r)
     k = _gather_dequant(k_pages, k_scales, layer, page_table, h)
-    if latent:
-        k, v = k[..., :d], k[..., :value_lanes]
+    if one_pool:
+        first = value_offset or 0
+        k, v = k[..., :d], k[..., first:first + value_lanes]
     else:
         v = _gather_dequant(v_pages, v_scales, layer, page_table, h)
     kr = jnp.broadcast_to(k[:, None], (s, r) + k.shape[1:]) \

@@ -290,8 +290,8 @@ _DONE = object()  # stream sentinel
 def _split_state(state):
     """Persistent-state tuple -> (k_pages, v_pages, k_scales,
     v_scales); the scale pools exist only under FLAGS_decode_kv_quant,
-    and a latent cache's one pool comes alone (``v_pages`` None: the
-    values are lanes of K's rows)."""
+    and a latent or a joint cache's one pool comes alone (``v_pages``
+    None: the values are lanes of K's rows)."""
     kp, *rest = state
     vp, *scales = rest or (None,)
     return (kp, vp, *(scales or (None, None)))
@@ -971,16 +971,19 @@ class DecodeEngine:
         stat_set("decode_kv_lane_dense",
                  1 if self._cache.config.lane_dense else 0)
         stat_set("decode_kv_pool_row_lanes", self._cache.config.row_lanes)
+        # ... and whether a position's K and V share one pool row (one
+        # K/V head of whole lane tiles: ``CacheConfig.joint``)
+        stat_set("decode_kv_joint_rows", 1 if self._cache.config.joint else 0)
         # positions one block of the paged-attention kernel covers at
         # this shape (the op reads the same rule from the same shapes),
         # and the blocks of all the slots' tables
         from ..ops.pallas_decode_attention import pages_per_block
 
         cc = self._cache.config
+        k_lanes, v_lanes = cc.attended_lanes()
         self._attn_block = cc.page_size * pages_per_block(
-            cc.page_size, cc.pages_per_slot, cc.row_lanes,
-            cc.store_dtype, cc.v_row_lanes, kv_heads,
-            model.num_heads // kv_heads)
+            cc.page_size, cc.pages_per_slot, k_lanes, cc.store_dtype,
+            v_lanes, kv_heads, model.num_heads // kv_heads)
         stat_set("decode_attn_block_positions", self._attn_block)
         # the rule's own chunks ONE call of the model's chunk form takes
         # of a recurrent layer's prompt, at the largest bucket (0: the
@@ -1229,11 +1232,20 @@ class DecodeEngine:
         ``[S, R]`` (the multi-row step).  Quantized pools (scales not
         None) write int8 + scales; attention dequantizes inline."""
         import jax
+        import jax.numpy as jnp
 
         from ..ops.pallas_decode_attention import KERNEL_NAME
 
+        cc = self._cache.config
+
         def attend(l, q, k, v, pools):
             k_pages, v_pages, k_scales, v_scales = pools
+            # a joint cache's one pool (``v_pages`` None, as a latent
+            # one's; the draft model's pools are always two): a row is
+            # the head's keys, then its values, landed by ONE scatter
+            joint = v_pages is None and cc.joint
+            if joint:
+                k = jnp.concatenate([k, v], axis=-1)
             flat = (-1,) + k.shape[-2:]                     # [rows, H, D]
             k_pages, k_scales = kv_cache.write_token_layer(
                 k_pages, k_scales, l, k.reshape(flat),
@@ -1246,17 +1258,19 @@ class DecodeEngine:
             # gather+mask reference) lives in ONE place: the op itself —
             # including the quantized dequant-inline paths.  The scope
             # is metadata only: it names the call's device ops in a trace
-            # a latent cache's one pool (``v_pages`` None): the values
-            # are the leading lanes of the rows just written
+            # one pool: the values are lanes of the rows just written,
+            # a latent row's leading ones, a joint row's after the keys
             with jax.named_scope(
-                    KERNEL_NAME if v_pages is not None else LATENT_SCOPE):
+                    KERNEL_NAME if v_pages is not None or joint
+                    else LATENT_SCOPE):
                 ctx = attention(
                     q, k_pages, v_pages, page_table, lengths, layer=l,
                     use_pallas=self.config.use_pallas,
                     interpret=self.config.interpret,
                     k_scales=k_scales, v_scales=v_scales,
                     value_lanes=None if v_pages is not None
-                    else self._cache.config.v_head_dim)
+                    else cc.v_head_dim,
+                    value_offset=cc.head_dim if joint else None)
             return ctx, (k_pages, v_pages, k_scales, v_scales)
 
         return attend
@@ -1482,7 +1496,10 @@ class DecodeEngine:
             def attend(l, q, k, v, pools, keep=None):       # [T_pad, H, D]
                 k_pages, v_pages, k_scales, v_scales = pools
                 # ``keep``: the rows the cache holds where they are not
-                # the K the prompt attends (a latent cache's one pool)
+                # the K the prompt attends (a latent cache's one pool);
+                # a joint cache's are the keys, then the values
+                if keep is None and v_pages is None and cc.joint:
+                    keep = jnp.concatenate([k, v], axis=-1)
                 k_pages, k_scales = kv_cache.write_prompt_layer(
                     k_pages, k_scales, l, k if keep is None else keep,
                     pages[:n_bp])
@@ -3094,7 +3111,7 @@ class DecodeEngine:
                                 cc.scale_dtype),
                        jnp.full(sshape, kv_cache.SCALE_EPS,
                                 cc.scale_dtype))
-        elif cc.latent:
+        elif not cc.v_row_lanes:        # a latent or a joint cache
             scratch = (jnp.zeros(shape, cc.dtype),)
         else:
             scratch = (jnp.zeros(shape, cc.dtype),

@@ -2,7 +2,8 @@
 (serving/decode.py).
 
 Layout (the vLLM PagedAttention idea, TPU-native): all keys/values for
-every serving slot live in TWO device arrays of fixed-size pages
+every serving slot live in TWO device arrays of fixed-size pages (ONE
+where a latent or a joint cache keeps a position in one row, below)
 
     k_pages : [cache_layers, num_pages, page_size, heads * head_dim]
     v_pages : [cache_layers, num_pages, page_size, heads * v_head_dim]
@@ -131,6 +132,34 @@ prefix index (``prefix_bypassed``), no int8 form, no export: the
 programs that would read such rows R at a time (a prefix hit's suffix,
 a chunk, a speculative window) are not built for them.
 
+**One row for keys AND values of one head** (``CacheConfig.joint``).
+A cache whose layers have ONE K/V head, unquantized, with keys and values
+each a whole number of lane tiles wide (``head_dim % 128 == 0`` and
+``v_head_dim % 128 == 0``: AI21-Jamba2-3B's 128 + 128) keeps a position's
+K and V side by side in one row of ``head_dim + v_head_dim`` lanes, in
+the K pool ALONE.  The rule is read from the shape: no flag, no model's
+name; a toy width, a second head, int8 pages and a latent cache keep
+what they had, and the bytes are the two pools' (``cache_bytes`` does
+not change).  Why: such a page is 16 rows of 128 bfloat16 lanes = 4 KB
+a pool, the paged kernel starts one copy a page a pool, and at that size
+a call is bound by the COUNT of its copies, not their bytes (measured on
+the v5e at 256 slots of 0.3-3.6k positions: 64 copies a block of 512
+positions issue in 3.7x the time the rows' bytes take; one 8 KB copy a
+page halves it, `ops/pallas_decode_attention.py`'s table by
+``_STACKED_BLOCK``).  The writers lay ``k`` and ``v`` of a row side by
+side and land them with ONE scatter a layer; the kernel and the plain
+path read the values from the lanes after the keys
+(``value_offset=head_dim``), bit for bit what two pools give.  Everything
+indexed by (layer, page id, offset) sees one array where it saw two:
+the free list, tables, refcounts, the prefix index, copy-on-write,
+``export_pages`` / ``install_pages`` (both engines derive the same rule
+from the same shape, and the payload's names and shapes are checked),
+``debug_check``.  Nothing refuses a joint pool: unlike the latent row it
+holds what two pools hold, a head's K and V of every position, so chunks,
+a prefix hit's suffix and a verify window read it R rows at a time as
+they read two.  A draft model's pools are always two (they follow the
+draft's shape through their own names).
+
 **Quantized storage** (``FLAGS_decode_kv_quant``): pages are stored
 int8 (same folded rows) beside parallel scale pools ``[layers, pages,
 page_size, heads]``
@@ -213,7 +242,9 @@ class CacheConfig:
     ``row_lanes = num_heads * head_dim`` (``v_row_lanes = num_heads *
     v_head_dim`` for the V pool where ``v_head_dim`` is given): one
     position's heads folded
-    into one row (the module header's tile rule).  ``lane_dense`` says
+    into one row (the module header's tile rule).  ``joint`` (derived,
+    the module header): one K/V head of whole lane tiles has the K pool
+    alone, ``row_lanes = head_dim + v_head_dim`` and ``v_row_lanes = 0``.  ``lane_dense`` says
     whether that row and the page fill the chip's (8, 128) tiles
     exactly, i.e. whether the pool keeps one unpadded layout through
     every program.
@@ -277,18 +308,40 @@ class CacheConfig:
         self.scale_dtype = np.dtype(np.float32)
 
     @property
+    def joint(self) -> bool:
+        """Whether a position's K and V lie side by side in ONE pool row
+        (the module header): one unquantized K/V head whose keys and
+        values are each whole lane tiles wide.  Read from the shape
+        alone; a toy width keeps two pools."""
+        return self.num_heads == 1 and not self.latent \
+            and not self.quantized and self.head_dim % 128 == 0 \
+            and self.v_head_dim % 128 == 0
+
+    @property
     def row_lanes(self) -> int:
         """Width of one stored position: every head's ``head_dim``
-        values side by side; a latent row up to whole lane tiles."""
+        values side by side; a latent row up to whole lane tiles; a
+        joint row the one head's keys, then its values."""
         if self.latent:
             return -(-self.head_dim // 128) * 128
+        if self.joint:
+            return self.head_dim + self.v_head_dim
         return self.num_heads * self.head_dim
 
     @property
     def v_row_lanes(self) -> int:
-        """Width of one stored position of V (a latent cache stores
-        none: its values are lanes of the K row)."""
-        return 0 if self.latent else self.num_heads * self.v_head_dim
+        """Width of one stored position of V (a latent or a joint cache
+        has no V pool: its values are lanes of the K pool's row)."""
+        return 0 if self.latent or self.joint \
+            else self.num_heads * self.v_head_dim
+
+    def attended_lanes(self) -> Tuple[int, int]:
+        """(K lanes, V lanes) of a position as the paged kernel's block
+        rule counts them (``pages_per_block``): the two pools' rows, a
+        joint row's two halves, a latent row and 0."""
+        if self.joint:
+            return self.head_dim, self.v_head_dim
+        return self.row_lanes, self.v_row_lanes
 
     @property
     def lane_dense(self) -> bool:
@@ -299,8 +352,9 @@ class CacheConfig:
 
     def pool_shape(self, num_layers: Optional[int] = None,
                    row_lanes: Optional[int] = None) -> Tuple[int, ...]:
-        """Shape of one page pool; the draft model's pools share the
-        page ids and differ in depth and row width only."""
+        """Shape of one page pool (a joint cache's ONE pool: rows of
+        keys + values); the draft model's pools share the page ids and
+        differ in depth and row width only."""
         return (self.num_layers if num_layers is None else num_layers,
                 self.num_pages, self.page_size,
                 self.row_lanes if row_lanes is None else row_lanes)
@@ -310,7 +364,8 @@ class CacheConfig:
 
     def page_bytes(self, v: bool = False) -> int:
         """Device bytes ONE page costs in one pool (K's, or with ``v``
-        V's) — including its
+        V's: 0 where a latent or a joint cache has none, the K pool's
+        page then holding the values too) — including its
         scale plane when quantized, so capacity math can't hide the
         scale overhead."""
         data = (self.page_size
@@ -323,14 +378,16 @@ class CacheConfig:
 
     def per_page_pool_bytes(self) -> int:
         """Total device bytes one page costs across EVERY pool (k + v,
-        all cache layers, scale planes included) — the unit a fixed byte
-        budget is divided by to size ``num_pages``."""
+        or the one pool that holds both; all cache layers, scale planes
+        included) — the unit a fixed byte budget is divided by to size
+        ``num_pages``."""
         return self.num_layers * (self.page_bytes()
                                   + self.page_bytes(v=True))
 
     def cache_bytes(self) -> int:
         """Total device bytes of the page arrays (k + v, scale pools
-        included when quantized)."""
+        included when quantized; a joint cache's one pool costs what
+        the two it replaces would)."""
         return self.num_pages * self.per_page_pool_bytes()
 
 
@@ -654,7 +711,7 @@ class PagedKVCache:
         self._cow_spare: List[List[int]] = [[] for _ in range(c.num_slots)]
         self._refs = [0] * c.num_pages
         scope.set_var(K_PAGES_VAR, jnp.zeros(c.pool_shape(), c.store_dtype))
-        if not c.latent:
+        if c.v_row_lanes:
             scope.set_var(V_PAGES_VAR, jnp.zeros(
                 c.pool_shape(row_lanes=c.v_row_lanes), c.store_dtype))
         if self.window is not None:
@@ -694,9 +751,9 @@ class PagedKVCache:
         """Scope names a persistent step must thread (in order): the
         two page pools, plus the scale pools when quantized, then the
         window layers' two pools, then the recurrent layers' slabs.  A
-        latent cache has the K pool alone."""
-        names = (K_PAGES_VAR,) if self.config.latent \
-            else (K_PAGES_VAR, V_PAGES_VAR)
+        latent cache and a joint one have the K pool alone."""
+        names = (K_PAGES_VAR, V_PAGES_VAR) if self.config.v_row_lanes \
+            else (K_PAGES_VAR,)
         if self.config.quantized:
             names += (K_SCALES_VAR, V_SCALES_VAR)
         return names + self.window_var_names() + self.recurrent_var_names()
@@ -1046,8 +1103,8 @@ class PagedKVCache:
 
     def arrays(self):
         return (self.scope.get_var(K_PAGES_VAR),
-                None if self.config.latent
-                else self.scope.get_var(V_PAGES_VAR))
+                self.scope.get_var(V_PAGES_VAR)
+                if self.config.v_row_lanes else None)
 
     # -- integrity audit (chaos tests / debugging) ------------------------
     def debug_check(self) -> None:

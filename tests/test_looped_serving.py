@@ -250,7 +250,7 @@ def test_without_cache_layers_the_pools_are_todays():
 def test_the_lowered_step_holds_one_loop_over_the_passes_and_one_kernel():
     """At heads of whole lanes, lowered for the chip: the step's text
     has the loop over the passes and the loop over the layers inside it
-    (the only ``while``s that carry the pools) and ONE paged-attention
+    (the only ``while``s that carry the pool) and ONE paged-attention
     kernel call for all 12 cache layers, its layer a traced scalar."""
     model = make_model(d_model=128, num_heads=1, head_dim=128, ffn_dim=128)
     weights = model.init_weights(jax.random.PRNGKey(13))
@@ -260,7 +260,10 @@ def test_the_lowered_step_holds_one_loop_over_the_passes_and_one_kernel():
     text = eng._step_fn.trace(*args).lower(
         lowering_platforms=("tpu",)).as_text()
     assert 1 <= text.count("@tpu_custom_call") <= model.num_layers
-    pool = "tensor<12x25x8x128xf32>"
+    # ONE K/V head of 128 lanes: K and V of a position share a row of the
+    # one pool the loops carry (``CacheConfig.joint``)
+    assert eng._cache.config.joint and len(eng._state_vars) == 1
+    pool = "tensor<12x25x8x256xf32>"
     whiles = [ln for ln in text.splitlines()
               if "stablehlo.while" in ln and pool in ln]
     assert len(whiles) == 2, whiles

@@ -1570,7 +1570,9 @@ def test_jamba2_width_programs_compile(one_chip, program):
     """The joint step at 256 slots (the state kernel and the
     convolution's over the slabs IN PLACE: no copy of a slab anywhere,
     both by the names the benchmark's pattern finds; the paged kernel at
-    20 rows of the one head; the head over the embedding where it lies)
+    20 rows of the one head, reading K and V out of the ONE joint pool
+    under the name the accepted metric finds; the head over the embedding
+    where it lies)
     and the 512-row whole-prompt prefill (one call of the scan kernel a
     recurrent layer, eight tiles of 64; the attention in plain blocks).
     The slabs' device layout holds no padding: the memory analysis reads
@@ -1579,7 +1581,9 @@ def test_jamba2_width_programs_compile(one_chip, program):
 
     eng = _mamba_engine()
     state = [tuple(eng._scope.get_var(n).shape) for n in eng._state_vars]
-    assert state == [(1, 2049, 16, 128)] * 2 \
+    # ONE pool: a position's keys and values side by side in a row
+    assert eng._cache.config.joint
+    assert state == [(1, 2049, 16, 256)] \
         + [(256, 16, 5120), (256, 15360)] * 2
     slabs = 2 * 256 * (16 * 5120 + 15360) * 4
     pools = 2 * 2049 * 16 * 128 * 2

@@ -12,7 +12,12 @@ arithmetic.  The numbers behind the rule in `pages_per_block` (PR 51).
 
 Prints one JSON line a (shape, block), ``rule`` true on the block the
 rule gives that shape, and writes them all to
-``chiprun_out/sweep_decode_block.json``.  A TPU or nothing: a time from
+``chiprun_out/sweep_decode_block.json``.  A shape of ONE K/V head of whole
+lane tiles (Jamba2-3B's; `serving/kv_cache.py` then keeps K and V of a
+position in one pool row) is also timed from that joint pool: the
+copy-only kernel with ONE copy a page (``joint_copies_ms``: the same
+bytes in half the descriptors) and the call itself (``joint_kernel_ms``,
+its output held bitwise to the two-pool call's).  A TPU or nothing: a time from
 the CPU is not a time (``--tiny`` rehearses the walk in the interpreter
 at small sizes and compares each block's output with the reference).
 """
@@ -30,6 +35,7 @@ import numpy as np
 from jax import lax
 
 from paddle_tpu.ops import pallas_decode_attention as pda
+from paddle_tpu.serving.kv_cache import CacheConfig
 
 PAGE = 16
 PEAKS = os.path.join(os.path.dirname(os.path.dirname(
@@ -45,6 +51,7 @@ SHAPES = {
     "solar": (64, 8, 128, 128, 128, 128, None, (200, 1500)),
     "ouro": (16, 16, 128, 128, 16, 20, None, (64, 320)),
     "olmo_hybrid": (30, 30, 128, 128, 32, 352, None, (3072, 5600)),
+    "jamba2": (20, 1, 128, 128, 256, 256, None, (330, 3584)),
 }
 BLOCKS = (128, 256, 512, 768, 1024)
 # a rehearsal's sizes (--tiny: the interpreter on the CPU, no time)
@@ -52,6 +59,7 @@ TINY = {
     "mimo_global": (8, 2, 192, 128, 3, 40, None, (100, 600)),
     "mimo_window": (8, 2, 192, 128, 3, 9, 128, (100, 600)),
     "command_window": (4, 2, 128, 128, 2, 41, 640, (500, 900)),
+    "jamba2": (4, 1, 128, 128, 3, 40, None, (100, 600)),
 }
 
 
@@ -72,9 +80,21 @@ def make_case(shape, seed):
                                 jnp.bfloat16)
     q = jax.random.normal(kq, (slots, hq, d), jnp.float32)
     sinks = jnp.zeros((hq,), jnp.float32) if window == 128 else None
-    return dict(q=q, k_pages=k_pages, v_pages=v_pages,
+    case = dict(q=q, k_pages=k_pages, v_pages=v_pages,
                 table=jnp.asarray(table), lengths=jnp.asarray(lengths),
                 sinks=sinks, layers=layers)
+    if joint_shape(shape):
+        # the same K and V, a position's side by side in one pool row
+        case["kv_pages"] = jnp.concatenate([k_pages, v_pages], axis=-1)
+    return case
+
+
+def joint_shape(shape):
+    """Whether `serving/kv_cache.py` keeps this shape's K and V in one
+    pool row (its own rule, asked of a cache of these widths)."""
+    _, h, d, dv, slots, pps, *_ = shape
+    return CacheConfig(1, h, d, slots, pps * PAGE, PAGE, dtype="bfloat16",
+                       v_head_dim=dv).joint
 
 
 def attended_bytes(shape, lengths):
@@ -85,18 +105,21 @@ def attended_bytes(shape, lengths):
     return int(pos.sum()) * h * (d + dv) * 2
 
 
-def _copy_kernel(layer_ref, pt_ref, len_ref, lo_ref, k_hbm, v_hbm, o_ref,
-                 k_buf, v_buf, sem, cur, *, page, pps, ppb, n_slots, ring):
+def _copy_kernel(layer_ref, pt_ref, len_ref, lo_ref, *rest, page, pps, ppb,
+                 n_slots, ring, n_pools):
     """The kernel's walk and copies with no arithmetic: same blocks, the
     next in flight while this one is waited for, a whole block's copies
-    started unrolled and waited for once a pool."""
+    started unrolled and waited for once a pool.  ``rest``: the pools in
+    HBM (K and V, or the one joint pool), the output, a buffer a pool,
+    the semaphores and the buffer's turn."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     s_idx = pl.program_id(0)
     layer = layer_ref[0]
     block = ppb * page
-    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+    hbm, (o_ref, *bufs, sem, cur) = rest[:n_pools], rest[n_pools:]
+    pools = tuple(zip(hbm, bufs))
 
     def first_block(s):
         return lo_ref[s] // block
@@ -155,46 +178,46 @@ def _copy_kernel(layer_ref, pt_ref, len_ref, lo_ref, k_hbm, v_hbm, o_ref,
     o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def copy_only(k_pages, v_pages, layer, table, lengths, window, ppb,
-              interpret=False):
+def copy_only(pools, layer, table, lengths, window, ppb, interpret=False):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n_slots, pps = table.shape
-    page, hd = k_pages.shape[2:]
-    v_hd = v_pages.shape[3]
+    page = pools[0].shape[2]
     lo = jnp.maximum(lengths - window, 0) if window \
         else jnp.zeros_like(lengths)
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4, grid=(n_slots,), in_specs=[hbm, hbm],
+        num_scalar_prefetch=4, grid=(n_slots,), in_specs=[hbm] * len(pools),
         out_specs=pl.BlockSpec((1, 8, 128), lambda s, *_: (s, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, ppb, page, hd), k_pages.dtype),
-            pltpu.VMEM((2, ppb, page, v_hd), v_pages.dtype),
+            pltpu.VMEM((2, ppb, page, pool.shape[3]), pool.dtype)
+            for pool in pools] + [
             pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)])
     return pl.pallas_call(
         functools.partial(_copy_kernel, page=page, pps=pps, ppb=ppb,
-                          n_slots=n_slots, ring=window is not None),
+                          n_slots=n_slots, ring=window is not None,
+                          n_pools=len(pools)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_slots, 8, 128), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="paged_copies_only", interpret=interpret,
-    )(layer.reshape(1), table.reshape(-1), lengths, lo, k_pages, v_pages)
+    )(layer.reshape(1), table.reshape(-1), lengths, lo, *pools)
 
 
-def time_loop(fn, case, iters, reps=3):
+def time_loop(fn, case, iters, reps=3, pools=("k_pages", "v_pages")):
     """ms a call: ``iters`` calls inside one program, the layer an
-    operand that changes from call to call."""
-    def run(q, k_pages, v_pages, table, lengths):
+    operand that changes from call to call.  ``pools``: the case's pools
+    the call reads (``fn(q, pools, layer, table, lengths)``)."""
+    def run(q, table, lengths, *pools):
         def body(i, acc):
-            return acc + fn(q, k_pages, v_pages, jnp.int32(i) % case["layers"],
+            return acc + fn(q, pools, jnp.int32(i) % case["layers"],
                             table, lengths).astype(jnp.float32).sum()
         return lax.fori_loop(0, iters, body, jnp.float32(0))
 
-    args = (case["q"], case["k_pages"], case["v_pages"], case["table"],
-            case["lengths"])
+    args = (case["q"], case["table"], case["lengths"],
+            *(case[name] for name in pools))
     run = jax.jit(run)
     t0 = time.perf_counter()
     run(*args).block_until_ready()
@@ -241,33 +264,47 @@ def main():
                 pda.pages_per_block = lambda *_, **__: ppb
                 pda._chunk_call.clear_cache()
 
-                def kernel(q, kp, vp, layer, table, lengths):
+                def kernel(q, pools, layer, table, lengths):
+                    # one pool: the joint row, the values after the keys
+                    kp, vp = pools if len(pools) == 2 else (pools[0], None)
                     return pda.paged_decode_attention(
                         q, kp, vp, table, lengths, layer=layer,
                         use_pallas="always", interpret=interpret,
-                        window=window, sinks=case["sinks"])
+                        window=window, sinks=case["sinks"],
+                        **({} if vp is not None else dict(
+                            value_lanes=dv, value_offset=d)))
 
-                def copies(q, kp, vp, layer, table, lengths):
-                    return copy_only(kp, vp, layer, table, lengths, window,
+                def copies(q, pools, layer, table, lengths):
+                    return copy_only(pools, layer, table, lengths, window,
                                      ppb, interpret)
 
                 line = dict(shape=name, block=block, rule=block == ruled,
                             buffers_bytes=both, attended_bytes=need,
                             bytes_floor_ms=round(floor_ms, 4))
                 try:
+                    two = (case["k_pages"], case["v_pages"])
+                    rest = (case["table"], case["lengths"])
                     if a.tiny:
-                        args = (case["q"], case["k_pages"], case["v_pages"])
-                        rest = (case["table"], case["lengths"])
                         want = pda.paged_decode_attention(
-                            *args, *rest, layer=1, use_pallas="never",
-                            window=window, sinks=case["sinks"])
+                            case["q"], *two, *rest, layer=1,
+                            use_pallas="never", window=window,
+                            sinks=case["sinks"])
                         line["max_err"] = float(jnp.abs(
-                            kernel(*args, 1, *rest) - want).max())
+                            kernel(case["q"], two, 1, *rest) - want).max())
                     reps = 1 if a.tiny else 3
                     line["kernel_ms"], line["kernel_compile_s"] = time_loop(
                         kernel, case, a.iters, reps)
                     line["copies_ms"], _ = time_loop(copies, case, a.iters,
                                                      reps)
+                    if "kv_pages" in case:
+                        one = ("kv_pages",)
+                        line["joint_copies_ms"], _ = time_loop(
+                            copies, case, a.iters, reps, one)
+                        line["joint_bitwise"] = bool(jnp.array_equal(
+                            kernel(case["q"], (case["kv_pages"],), 1, *rest),
+                            kernel(case["q"], two, 1, *rest)))
+                        line["joint_kernel_ms"], _ = time_loop(
+                            kernel, case, a.iters, reps, one)
                 except Exception as e:  # a block the chip's compiler refuses
                     line["error"] = f"{type(e).__name__}: {e}"[:400]
                 print(json.dumps(line), flush=True)
