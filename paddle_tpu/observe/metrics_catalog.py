@@ -409,6 +409,23 @@ RULES = (
          "once a joint decode step; `decode_latent_positions_live` over "
          "it x a block's positions is the share of what it copies that "
          "is attended"),
+    Rule("decode_index_bytes", "gauge", "serving",
+         "Device bytes of the index keys' pool of a model whose attention "
+         "reads the positions a learned indexer selects: ONE key a "
+         "position a layer in a third pool behind the K/V pools' page "
+         "ids, at whole lane tiles, every page of every layer; 0 for a "
+         "model that keeps none"),
+    Rule("decode_index_positions_scored", "gauge", "serving",
+         "Cached index keys the live slots' queries score a layer (a "
+         "slot's length, the token itself counted), added once a joint "
+         "decode step from the lengths the engine holds: times the key's "
+         "published bytes and the layers it is what the indexer has to "
+         "read"),
+    Rule("decode_index_positions_selected", "gauge", "serving",
+         "Positions the live slots attend a layer after the indexer's "
+         "selection (min(a slot's length, `index_topk`)), added once a "
+         "joint decode step: over `decode_index_positions_scored` it is "
+         "the share of a context a step reads K and V of"),
     Rule("decode_kv_joint_rows", "gauge", "serving",
          "1 where a position's K and V lie side by side in ONE pool row "
          "(`serving/kv_cache.py` `CacheConfig.joint`: one unquantized "

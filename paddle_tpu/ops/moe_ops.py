@@ -309,12 +309,14 @@ EXPERTS_SCOPE = "moe_experts"
 
 
 def moe_share_route(h, router_w, router_bias, *, top_k, held_ids,
-                    live=None):
-    """Sigmoid routing of rows ``h [..., D]`` over ALL experts
+                    live=None, scoring="sigmoid"):
+    """Routing of rows ``h [..., D]`` over ALL experts
     (``router_w [D, E]``, float32 at ``highest``: a score's rounding
     decides the top-k), for a chip that holds ``held_ids`` ([n_held]
-    expert ids).  The top-k is taken by score + ``router_bias`` [E] (the
-    load-balance correction: the model's own buffer, zero in four of
+    expert ids).  A score is the logit's ``scoring``: ``"sigmoid"``
+    (an expert's own) or ``"softmax"`` (over all the experts).  The
+    top-k is taken by score + ``router_bias`` [E] (the load-balance
+    correction: the model's own buffer, zero in four of
     the served configurations and drawn from the seed in one); the
     weights are the plain scores, WITHOUT the bias, normalised over the
     chosen k.  ``live`` (rows' shape, bool)
@@ -325,7 +327,8 @@ def moe_share_route(h, router_w, router_bias, *, top_k, held_ids,
     [..., n_held] f32)``: ``local[r, j]`` is row r's weight for held
     expert j, zero where it did not choose it."""
     with jax.named_scope(ROUTE_SCOPE):
-        scores = jax.nn.sigmoid(jnp.einsum(
+        score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}
+        scores = score[scoring](jnp.einsum(
             "...d,de->...e", h.astype(jnp.float32),
             router_w.astype(jnp.float32),
             precision=lax.Precision.HIGHEST))

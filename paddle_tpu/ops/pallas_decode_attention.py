@@ -294,13 +294,16 @@ def prefill_keys_walked(t, n, walk, window=None):
 
 def grouped_causal_attention(q, k, v, *, sm_scale=None, window=None,
                              sinks=None, length=None, use_pallas="auto",
-                             interpret=False):
+                             interpret=False, select=None):
     """Causal attention of one sequence whose query heads outnumber its
     K/V heads: q [T, H, D], k [T, Hkv, D], v [T, Hkv, Dv], query head i
     reading K/V head ``i // (H // Hkv)``; row t attends positions
     ``<= t`` (with ``window``: the last ``window`` of them, itself
     counted), and ``sinks`` [H] joins each head's softmax as a logit
-    with no value.  The whole-prompt prefill of a grouped-query model
+    with no value; ``select`` [T, T] (nonzero where row t attends key
+    s, beside the causal rule: a learned indexer's selection, one a row
+    for all heads; every row has such a key).  The whole-prompt prefill
+    of a grouped-query model
     (serving/decode.py), in one of two forms (``prefill_walk``).  Where
     ``use_pallas`` asks for it and the shape is one the kernel takes,
     the flash kernel of ``pallas_prompt_attention``: scores in fast
@@ -322,7 +325,7 @@ def grouped_causal_attention(q, k, v, *, sm_scale=None, window=None,
         from .pallas_prompt_attention import prompt_flash_attention
 
         return prompt_flash_attention(
-            q, k, v, t if length is None else length, sinks,
+            q, k, v, t if length is None else length, sinks, select,
             sm_scale=float(sm_scale), window=window, tiles=(rows, span),
             interpret=interpret)
     qg = q.astype(jnp.float32).reshape(t // rows, rows, kv_heads,
@@ -345,6 +348,9 @@ def grouped_causal_attention(q, k, v, *, sm_scale=None, window=None,
         mask = col <= row
         if window is not None:
             mask = mask & (col > row - window)
+        if select is not None:
+            mask = mask & (lax.dynamic_slice(
+                select, (first, lo), (rows, span)) != 0)
         s = jnp.where(mask, s, _NEG_INF)
         if sinks is not None:
             s = jnp.concatenate([s, sink], axis=-1)

@@ -167,20 +167,24 @@ def keys_visited(t, n, bq, bk, window=None):
 
 
 def _flash_kernel(len_ref, q_ref, k_ref, v_ref, *rest, bq, bk, window,
-                  sinks):
+                  sinks, select=False):
     """One grid step: the ``G * bq`` stacked query rows of K/V head
     ``program_id(0)``'s group, row block ``program_id(1)``, against the
     ``program_id(2)``-th key block that row block needs.
 
     Refs: q (bq, G*D) and o (bq, G*Dv), the group's lanes of the rows
     as the caller holds them; k (1, bk, D) and v (1, bk, Dv);
-    ``sinks``: (1, G, 8, 128), head g's logit all over its tile.
+    ``sinks``: (1, G, 8, 128), head g's logit all over its tile;
+    ``select``: (bq, bk) int8, nonzero where the row attends the key (a
+    selection a ROW, shared by the heads; every row has one key).
     Scratch: the stacked query (G*bq, D), running
     max and denominator (G*bq, 128), the accumulator (G*bq, Dv)."""
     import jax.experimental.pallas as pl
 
     if sinks:
         sink_ref, *rest = rest
+    if select:
+        sel_ref, *rest = rest
     o_ref, q_scr, m_scr, l_scr, acc_scr = rest
     i, j = pl.program_id(1), pl.program_id(2)
     rows, d = q_scr.shape
@@ -219,6 +223,13 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, *rest, bq, bk, window,
             seen = col <= row
             if window is not None:
                 seen = seen & (col > row - window)
+            if select:
+                # a block in which a row chose nothing leaves it p = 1
+                # a key while its max is still the floor; the first
+                # chosen key's alpha = exp(floor - m) = 0 wipes that
+                seen = seen & (jnp.broadcast_to(
+                    sel_ref[...].astype(jnp.int32)[None],
+                    (g, bq, bk)).reshape(rows, bk) != 0)
             s = jnp.where(seen, s, _NEG_INF)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -238,8 +249,11 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, *rest, bq, bk, window,
     crossed = kb * bk + bk - 1 > first
     if window is not None:
         crossed = crossed | (kb * bk <= first + bq - 1 - window)
-    pl.when(live & crossed)(lambda: update(True))
-    pl.when(live & jnp.logical_not(crossed))(lambda: update(False))
+    if select:
+        pl.when(live)(lambda: update(True))
+    else:
+        pl.when(live & crossed)(lambda: update(True))
+        pl.when(live & jnp.logical_not(crossed))(lambda: update(False))
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _flush():
@@ -253,12 +267,14 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, *rest, bq, bk, window,
 
 @functools.partial(jax.jit, static_argnames=(
     "sm_scale", "window", "tiles", "interpret"))
-def prompt_flash_attention(q, k, v, length, sinks=None, *, sm_scale,
-                           window=None, tiles, interpret=False):
+def prompt_flash_attention(q, k, v, length, sinks=None, select=None, *,
+                           sm_scale, window=None, tiles, interpret=False):
     """The kernel at ``tiles = flash_rule(...)``: q [T, H, D], k [T,
     Hkv, D], v [T, Hkv, Dv], ``length`` an int32 scalar (rows past it
     are padding: whole row blocks of them come back zero), ``sinks``
-    [H] or None; returns [T, H, Dv] in q's dtype.  The query and the
+    [H] or None, ``select`` [T, T] int8 or None (nonzero where row t
+    attends key s, beside the causal rule; every row has one such key);
+    returns [T, H, Dv] in q's dtype.  The query and the
     result stay where they lie, a row's heads folded into its lanes (a
     program takes its group's lanes of a row block); only K and V, an
     H / Hkv-th of them, are laid out head-major."""
@@ -308,9 +324,15 @@ def prompt_flash_attention(q, k, v, length, sinks=None, *, sm_scale,
         operands.append(jnp.broadcast_to(
             sinks.astype(jnp.float32).reshape(kv_heads, g, 1, 1),
             (kv_heads, g, 8, _LANES)))
+    if select is not None:
+        in_specs.append(pl.BlockSpec(
+            (bq, bk), lambda kh, i, j, len_ref: (
+                q_map(kh, i, j, len_ref)[0], kv_map(kh, i, j, len_ref)[1])))
+        operands.append(select.astype(jnp.int8))
     out = pl.pallas_call(
         functools.partial(_flash_kernel, bq=bq, bk=bk, window=window,
-                          sinks=sinks is not None),
+                          sinks=sinks is not None,
+                          select=select is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(kv_heads, t // bq, _steps(t, bq, bk, window)),

@@ -53,6 +53,7 @@ from .disagg import (  # noqa: F401
 from .conv_moe_lm import ConvMoELM  # noqa: F401
 from .gated_delta_lm import GatedDeltaLM  # noqa: F401
 from .hybrid_moe_lm import HybridMoELM  # noqa: F401
+from .indexed_moe_lm import IndexedMoELM  # noqa: F401
 from .kv_cache import (  # noqa: F401
     CacheConfig,
     CacheExhaustedError,
@@ -80,7 +81,7 @@ __all__ = [
     "DecodeConfig",
     "DecodeEngine", "DecodeRequest", "DecodeServer", "DisaggConfig",
     "DisaggRequest", "DisaggServer", "GatedDeltaLM", "HybridMoELM",
-    "InferenceRequest",
+    "IndexedMoELM", "InferenceRequest",
     "KVPageExport", "LatentMoELM", "LinearLatentLM", "LoopedLM",
     "MambaLM", "PageAllocator",
     "PagedKVCache",

@@ -186,14 +186,17 @@ def step_tallies(model, rows):
     return tuple(n for n in model.tallies if n not in moe_ops.HIT_TALLIES)
 
 
-def route_share(h, lw, attend, top_k, held_experts):
+def route_share(h, lw, attend, top_k, held_experts, **how):
     """Rows ``h`` routed over all of the layer's experts: the weights
     of the held ones a row (``moe_ops.moe_share_route``'s ``local``),
     with the counts tallied and the chosen ids recorded through
-    ``attend``."""
+    ``attend``.  ``how``: a model's own ``scoring`` of a logit
+    (``moe_ops.moe_share_route``'s), handed on only where a model names
+    one: the benchmark's controls stand in for that function with the
+    signature it had."""
     ids, _, local = moe_ops.moe_share_route(
         h, lw["moe_router"], lw["moe_router_bias"], top_k=top_k,
-        held_ids=held_experts, live=attend.live)
+        held_ids=held_experts, live=attend.live, **how)
     assigned, hit = moe_ops.moe_share_counts(local)
     attend.tally("moe_local_assignments", assigned)
     attend.tally("moe_experts_hit", hit)

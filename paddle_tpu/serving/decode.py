@@ -131,7 +131,7 @@ from .buckets import (BucketSpec, DeadlineExceededError, QueueFullError,
                       RequestTooLargeError, ServerClosedError,
                       prefill_bucket_grid, record_pad_waste)
 from . import kv_cache
-from .kv_cache import (CacheConfig, PagedKVCache, RecurrentSpec,
+from .kv_cache import (CacheConfig, IndexSpec, PagedKVCache, RecurrentSpec,
                        WindowSpec)
 # the reference model lives beside the engine; its names stay importable here
 from .transformer_lm import (TransformerLM, quantize_moe_weights,  # noqa: F401
@@ -312,12 +312,14 @@ class _Mixed:
     declares ``layer_kinds``): which pool layer an attention layer's K/V
     live in, which layer of the window pools a window layer's, which
     slabs a recurrent layer's state, and how the persistent-state tuple
-    (pools, then the window pools, then slabs layer-major) splits into
-    the ``(pools, window pools, recurrent)`` cache that ``forward``
-    threads."""
+    (pools, then the index pool, then the window pools, then slabs
+    layer-major) splits into the ``(pools, window pools, recurrent)``
+    cache that ``forward`` threads; an index pool rides behind the four
+    ``pools`` as a fifth."""
 
     def __init__(self, model, spec: Optional[RecurrentSpec],
-                 window: Optional[WindowSpec], slots: int):
+                 window: Optional[WindowSpec], slots: int,
+                 index: Optional[IndexSpec] = None):
         self.kinds = tuple(model.layer_kinds)
         self.layer = {kind: {l: i for i, l in enumerate(
             l for l, k in enumerate(self.kinds) if k == kind)}
@@ -325,6 +327,7 @@ class _Mixed:
         self.names = tuple(spec.arrays) if spec is not None else ()
         self.n_arrays = len(self.layer["recurrent"]) * len(self.names)
         self.n_window = 2 if window is not None else 0
+        self.n_index = 1 if index is not None else 0
         # every counter ``forward`` may add to, and those of them a
         # joint step of ``slots`` rows reads back (all, unless the model
         # says which: a form chosen by the step's shape brings its own)
@@ -341,7 +344,9 @@ class _Mixed:
     def split(self, state):
         n = len(state) - self.n_window - self.n_arrays
         flat, k = state[n + self.n_window:], len(self.names)
-        return (_split_state(state[:n]), tuple(state[n:n + self.n_window]),
+        pools = _split_state(state[:n - self.n_index]) \
+            + tuple(state[n - self.n_index:n])
+        return (pools, tuple(state[n:n + self.n_window]),
                 tuple(dict(zip(self.names, flat[i * k:(i + 1) * k]))
                       for i in range(len(self.layer["recurrent"]))))
 
@@ -380,13 +385,20 @@ class _Mixers:
     that is not the ``k`` and ``v`` they attend.  ``read_row`` (with
     ``prompt``; an int32 scalar) is the ONE row whose logits the program
     reads, the prompt's last real token: a model may form that row's
-    logits alone and hand back ``[1, V]``."""
+    logits alone and hand back ``[1, V]``.  A layer whose attention
+    reads the positions an indexer selects hands the call ``index=``
+    (``mixers.IndexCall``: the rows' index key for the third pool and
+    the indexer's two forms; ``attend_indexed``), and its prompt form
+    runs the prompt's causal attention through ``causal(q, k, v, length,
+    select=None)``, the form every prompt's takes here."""
 
     def __init__(self, mixed: _Mixed, recur, live, attend=None,
                  attend_window=None, own_tallies=(), interpret=False,
-                 prompt=False, read_row=None):
+                 prompt=False, read_row=None, attend_indexed=None,
+                 causal=None):
         self._mixed, self._recur, self.attend = mixed, recur, attend
         self.attend_window = attend_window
+        self.attend_indexed, self.causal = attend_indexed, causal
         self.live = live
         self.interpret = bool(interpret)
         self.prompt, self.read_row = bool(prompt), read_row
@@ -395,11 +407,16 @@ class _Mixers:
         self.counts = dict.fromkeys(mixed.tallies + tuple(own_tallies), 0)
         self.records = {}
 
-    def __call__(self, layer, q, k, v, cache, sinks=None, keep=None):
+    def __call__(self, layer, q, k, v, cache, sinks=None, keep=None,
+                 index=None):
         pools, window, rec = cache
         if self._mixed.kinds[layer] == "window":
             ctx, window = self.attend_window(
                 self._mixed.layer["window"][layer], q, k, v, window, sinks)
+        elif index is not None:
+            ctx, pools = self.attend_indexed(
+                self._mixed.layer["attention"][layer], q, k, v, pools,
+                index)
         else:
             ctx, pools = self.attend(
                 self._mixed.layer["attention"][layer], q, k, v, pools,
@@ -884,6 +901,25 @@ class DecodeEngine:
     the latent page: the programs that extend a sequence R rows at a time
     through the pages are not built for its rows.
 
+    **A third pool of index keys** (``serving/indexed_moe_lm.py`` is the
+    reference).  A model whose attention layers read only the positions
+    a learned indexer selects declares ``index_dim`` (lanes of the one
+    index key a position a layer keeps beside K and V) and
+    ``index_topk``; the cache then has a third pool behind the K/V
+    pools' page ids (``kv_cache.IndexSpec``: one table, one free list).
+    Its layers are ``"attention"`` layers and hand every call
+    ``index=`` (``mixers.IndexCall``).  The step writes the token's
+    key, hands the indexer each slot's cached keys with the slot's
+    length (it masks before it selects: a recycled page's stale rows are
+    positions past the length) and attends the rows at the positions it
+    gets back, gathered from the pools; the whole-prompt prefill writes
+    the prompt's keys and hands the indexer the prompt's own rows.
+    Every request is admitted fresh (``decode_prefix_bypassed``), and
+    chunked prefill, speculation, ``kv_quant`` and the hand-over refuse,
+    naming the index pool.  Counters ``decode_index_positions_scored`` /
+    ``decode_index_positions_selected`` (a layer's, over the live slots
+    of a step), gauge ``decode_index_bytes``.
+
     ``draft_model``/``draft_weights`` arm speculative decoding (with
     ``spec_k > 0``): the draft's page pools are indexed by the SAME
     page ids as the target's, so prefix sharing, reservation
@@ -945,7 +981,13 @@ class DecodeEngine:
         self._window = WindowSpec(
             n_win, getattr(model, "window_kv_heads", kv_heads),
             model.head_dim, v_dim, model.window) if n_win else None
-        self._mixed = _Mixed(model, spec, self._window, c.slots) \
+        # ... and the index keys of layers that select what they attend:
+        # a third pool behind the same page ids
+        n_idx = layers_of_kind(model, "attention") \
+            if getattr(model, "index_dim", 0) else 0
+        self._index = IndexSpec(n_idx, model.index_dim) if n_idx else None
+        self._mixed = _Mixed(model, spec, self._window, c.slots,
+                             self._index) \
             if getattr(model, "layer_kinds", None) else None
         # the model's counters behind a step's tokens, as it declares them
         self._tallies = self._mixed.tallies if self._mixed \
@@ -954,7 +996,8 @@ class DecodeEngine:
         self._prefill_tallies = self._mixed.prefill_tallies \
             if self._mixed else tuple(getattr(model, "prefill_tallies", ()))
         latent = bool(getattr(model, "values_in_keys", False))
-        self._refuse(model, c, draft_model, latent)
+        self._refuse(model, c, draft_model, latent,
+                     indexed=self._index is not None)
         with jax.default_device(self._device):
             self._cache = PagedKVCache(
                 CacheConfig(max(cache_layers(model) - n_rec - n_win, 1),
@@ -964,7 +1007,7 @@ class DecodeEngine:
                             quantized=c.kv_quant, v_head_dim=v_dim,
                             latent=latent),
                 self._scope, prefix_cache=c.prefix_cache, recurrent=spec,
-                window=self._window)
+                window=self._window, index=self._index)
         # whether the pools' one layout is also an unpadded one (the
         # tile rule in serving/kv_cache.py): the counter that says the
         # lane-dense representation engaged for this model's shape
@@ -1010,6 +1053,7 @@ class DecodeEngine:
         # pools join them under FLAGS_decode_kv_quant)
         self._state_vars = self._cache.state_var_names()
         n_pools = len(self._state_vars) \
+            - len(self._cache.index_var_names()) \
             - len(self._cache.window_var_names()) \
             - len(self._cache.recurrent_var_names())
         self._draft_state_vars = ()
@@ -1091,7 +1135,8 @@ class DecodeEngine:
         self._step_ran = False
 
     @classmethod
-    def _refuse(cls, model, c: "DecodeConfig", draft_model, latent) -> None:
+    def _refuse(cls, model, c: "DecodeConfig", draft_model, latent,
+                indexed=False) -> None:
         """What the configuration asks for that the model's layers or
         its page cannot carry.  A model may keep state a slot in some
         layers AND a latent page in the others: the refusal then names
@@ -1099,6 +1144,8 @@ class DecodeEngine:
         refusals = [(cls._refuse_for_kinds, (model, c, draft_model))]
         if latent:
             refusals.append((cls._refuse_for_latent, (c, draft_model)))
+        if indexed:
+            refusals.append((cls._refuse_for_index, (c, draft_model)))
         said = []
         for refuse, args in refusals:
             try:
@@ -1158,6 +1205,32 @@ class DecodeEngine:
             raise ValueError(
                 why + "kv_quant (int8 K/V pages) scales a row a head, "
                 "and the latent row has none")
+
+    @staticmethod
+    def _refuse_for_index(c: "DecodeConfig", draft_model) -> None:
+        """A model that keeps an index pool is served by the
+        whole-prompt prefill and the joint step: what extends a
+        sequence R rows at a time THROUGH the pages would have to score
+        and select for each of its rows against the pool, and an int8
+        page would be gathered row by row with its scales."""
+        why = ("the model keeps an index pool (a third array a position: "
+               "the keys its attention selects by): ")
+        if c.prefill_chunk_pages > 0:
+            raise ValueError(
+                why + "chunked prefill (prefill_chunk_pages="
+                f"{c.prefill_chunk_pages}) attends its rows through the "
+                "pages, where the multi-row step neither scores the "
+                "cached keys nor selects")
+        if draft_model is not None or c.spec_k > 0:
+            raise ValueError(
+                why + "speculative decoding (a draft model, spec_k="
+                f"{c.spec_k}) verifies its window through the pages with "
+                "the multi-row step, which selects nothing")
+        if c.kv_quant:
+            raise ValueError(
+                why + "kv_quant (int8 K/V pages) is not wired for rows "
+                "gathered by position, and the index keys are scored as "
+                "they lie")
 
     def _commit(self, tree):
         """Device arrays for ``tree``; on a pinned replica every leaf
@@ -1304,6 +1377,58 @@ class DecodeEngine:
 
         return attend
 
+    def _indexed_attend(self, page_table, lengths, write_page, write_off):
+        """The joint step's ``attend`` of a layer that selects what it
+        attends: the token's K, V and index key written at (page,
+        offset); each slot's cached keys, gathered by its table, handed
+        to the indexer with the slot's length and every table position's
+        flat row; the rows it selects gathered from the K and V pools and
+        attended.  Every gather reads the WHOLE pool by a flat row (a
+        layer's slice of a pool is a copy of it)."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.indexed_attention import attend_rows
+        from .mixers import SPARSE_ATTN_SCOPE
+
+        cc = self._cache.config
+        kv_heads = cc.num_heads
+        # a table position's row inside ONE layer of a pool
+        within = (page_table[:, :, None] * cc.page_size + jnp.arange(
+            cc.page_size, dtype=jnp.int32)).reshape(page_table.shape[0], -1)
+
+        def attend(l, q, k, v, pools, index):
+            k_pages, v_pages, k_scales, v_scales, i_pages = pools
+            flat = (-1,) + k.shape[-2:]
+            k_pages = kv_cache.scatter_token_layer(
+                k_pages, l, k.reshape(flat), write_page, write_off)
+            v_pages = kv_cache.scatter_token_layer(
+                v_pages, l, v.reshape((-1,) + v.shape[-2:]), write_page,
+                write_off)
+            i_pages = kv_cache.scatter_token_layer(
+                i_pages, l, index.key, write_page, write_off)
+            n_pages, page = i_pages.shape[1:3]
+            # every index is in bounds (a dead table entry is page 0):
+            # "clip" spares the pass that would fill what is not
+            keys = jnp.take(
+                i_pages.reshape((-1,) + i_pages.shape[2:]),
+                l * n_pages + page_table, axis=0,
+                mode="clip")                            # [S, P, page, lanes]
+            # where each table position's K and V lie, as a flat row of
+            # the pools: it rides the selection's sort (a gather of the
+            # table by the selected positions would cost as much)
+            _, ok, at = index.step(
+                keys.reshape(keys.shape[0], -1, keys.shape[-1]), lengths,
+                l * n_pages * page + within)
+            with jax.named_scope(SPARSE_ATTN_SCOPE):
+                rows = [jnp.take(p.reshape(-1, p.shape[-1]), at, axis=0,
+                                 mode="clip")
+                        for p in (k_pages, v_pages)]
+                ctx = attend_rows(q, *rows, ok, kv_heads)
+            return ctx, (k_pages, v_pages, k_scales, v_scales, i_pages)
+
+        return attend
+
     def _token_step_body(self, model, weights, pools, tokens, positions,
                          page_table, write_page, write_off, mix=None):
         """One single-token step of ``model`` over ``pools``, the token
@@ -1393,6 +1518,9 @@ class DecodeEngine:
             else:
                 mix = _Mixers(mixed, recur, live,
                               interpret=self.config.interpret)
+                if self._index is not None:
+                    mix.attend_indexed = self._indexed_attend(
+                        page_table, positions + 1, write_page, write_off)
                 if self._window is not None:
                     # the position's page of the slot's own ring
                     # (kv_cache.WindowSpec.ring_table), trash for a dead
@@ -1539,6 +1667,31 @@ class DecodeEngine:
                     row_lengths)
                 return ctx, (k_pages, v_pages, k_scales, v_scales)
 
+            def causal(q, k, v, length, select=None):
+                """A prompt's own rows attended causally, in the form
+                ``attend`` runs a grouped model's (under ``select``
+                where an indexer chose a row's keys)."""
+                return grouped_causal_attention(
+                    q, k, v, length=length, select=select,
+                    use_pallas=self.config.use_pallas,
+                    interpret=self.config.interpret)
+
+            def attend_indexed(l, q, k, v, pools, index):
+                """A layer that selects what it attends: the prompt's K,
+                V and index keys into the slot's pages, then the
+                indexer's prompt form over the rows as the pools keep
+                them."""
+                *kv, i_pages = pools
+                kv[0], _ = kv_cache.write_prompt_layer(
+                    kv[0], None, l, k, pages[:n_bp])
+                kv[1], _ = kv_cache.write_prompt_layer(
+                    kv[1], None, l, v, pages[:n_bp])
+                i_pages = kv_cache.scatter_prompt_layer(
+                    i_pages, l, index.key, pages[:n_bp])
+                ctx = index.prompt(q, k.astype(cdt), v.astype(cdt),
+                                   index.key[..., 0, :].astype(cdt), length)
+                return ctx, (*kv, i_pages)
+
             def attend_window(l, q, k, v, pools, sinks):
                 """A window layer of the prompt: masked to the window,
                 with the sink, at the prompt's bucket; of its K/V only
@@ -1567,7 +1720,8 @@ class DecodeEngine:
                 mix = _Mixers(mixed, recur, positions < length, attend,
                               attend_window, own_tallies=counted,
                               interpret=self.config.interpret, prompt=True,
-                              read_row=length - 1)
+                              read_row=length - 1,
+                              attend_indexed=attend_indexed, causal=causal)
                 logits, cache = model.forward(
                     weights, tokens, positions, mixed.split(state), mix)
                 new_state = mixed.join(cache)
@@ -1785,6 +1939,12 @@ class DecodeEngine:
                 "pages (extract_kv / kv_import); this model keeps a latent "
                 "page (one row a position for keys and values), which the "
                 "export and the install are not built for")
+        if (extract_kv or kv_import is not None) and self._index is not None:
+            raise ValueError(
+                "disaggregated serving hands a prompt over as its K/V "
+                "pages (extract_kv / kv_import); this model keeps an "
+                "index pool (a third array a position), which the export "
+                "and the install are not built to carry")
         if kv_import is not None:
             # migrated admission (serving/disagg.py): validate the
             # payload against THIS engine's pool geometry at submit
@@ -1917,6 +2077,7 @@ class DecodeEngine:
         stat_set("decode_state_bytes", self._cache.state_bytes())
         stat_set("decode_window_bytes", self._cache.window_bytes())
         stat_set("decode_latent_bytes", self._cache.latent_bytes())
+        stat_set("decode_index_bytes", self._cache.index_bytes())
         stat_set("decode_cache_layers", self._cache.config.num_layers)
         stat_set("decode_kv_pool_bytes", self._cache.config.cache_bytes())
         from ..ops.pallas_decode_attention import feed_bits
@@ -2693,6 +2854,13 @@ class DecodeEngine:
                     stat_add("decode_steps_filtered")
             if self._window is not None:
                 self._count_window(positions[list(live_idx)])
+            if self._index is not None:
+                # a layer's: the cached keys the live slots' queries
+                # score, and the positions they attend of them
+                n = positions[list(live_idx)] + 1
+                stat_add("decode_index_positions_scored", int(n.sum()))
+                stat_add("decode_index_positions_selected", int(
+                    np.minimum(n, self.model.index_topk).sum()))
         return _words(rows)
 
     def _prefill_walks(self, t_pad: int):
@@ -2736,7 +2904,11 @@ class DecodeEngine:
         forms = {}
         for layers, window, walk in self._prefill_walks(t_pad):
             attended += layers * prefill_keys_walked(t_pad, n, walk, window)
-            w = n if window is None else min(window, n)
+            # what caps the keys a row sees: a window, or the count an
+            # indexer selects (the kernel still walks the triangle)
+            cap = window if window is not None or self._index is None \
+                else self.model.index_topk
+            w = n if cap is None else min(cap, n)
             live += layers * (w * (w + 1) // 2 + (n - w) * w)
             forms[walk[0]] = forms.get(walk[0], 0) + layers
         return attended, live, forms

@@ -260,6 +260,12 @@ class DisaggServer:
                 "keeps a latent page (one row a position for keys and "
                 "values), which the export and the install are not built "
                 "for")
+        if getattr(model, "index_dim", 0):
+            raise ValueError(
+                "disaggregated serving hands a prompt from a prefill "
+                "replica to a decode replica as its K/V pages; this model "
+                "keeps an index pool (a third array a position), which "
+                "the export and the install are not built to carry")
         self.config = config or DecodeConfig()
         self.disagg = disagg or DisaggConfig()
         d = self.disagg

@@ -160,6 +160,26 @@ a prefix hit's suffix and a verify window read it R rows at a time as
 they read two.  A draft model's pools are always two (they follow the
 draft's shape through their own names).
 
+**A third array a position** (``IndexSpec``).  A model whose attention
+reads only the positions a learned INDEXER picks caches, beside K and V,
+one small index key a position a layer; a query scores every live
+position's key and attends the ``topk`` best.  The keys live in a THIRD
+pool behind the SAME page ids
+
+    index_pages : [cache_layers, num_pages, page_size, index row lanes]
+
+so one page table, one free list and one ``claim``/``release`` cover all
+three: a page's index rows are claimed, released and recycled with its
+K/V rows, and nothing is cleared on release.  That is sound because the
+readers mask by the slot's LENGTH before they select: a recycled page's
+stale rows lie at offsets the new owner has not written yet, which are
+positions past its length.  A row is stored at whole lane tiles (64
+lanes take 128, the rest zeros that a zero-padded query meets), said by
+the shape and by ``index_bytes`` rather than left to the chip's padding.
+No prefix index (``prefix_bypassed``: a shared page would need its index
+rows shared and the suffix's rows selected R at a time), no int8 form,
+no export.
+
 **Quantized storage** (``FLAGS_decode_kv_quant``): pages are stored
 int8 (same folded rows) beside parallel scale pools ``[layers, pages,
 page_size, heads]``
@@ -194,6 +214,7 @@ K_SCALES_VAR = "__decode_k_scales__"
 V_SCALES_VAR = "__decode_v_scales__"
 WINDOW_K_VAR = "__decode_window_k_pages__"
 WINDOW_V_VAR = "__decode_window_v_pages__"
+INDEX_PAGES_VAR = "__decode_index_pages__"
 
 KV_QMAX = 127.0  # symmetric int8 grid for quantized pages
 
@@ -457,6 +478,32 @@ class WindowSpec:
                    for shape in self.pool_shapes(num_slots, page_size))
 
 
+class IndexSpec:
+    """The index keys of a model whose attention layers select the
+    positions they attend (the module header: a third pool behind the
+    K/V pools' page ids): ``num_layers`` such layers, ``key_dim`` lanes a
+    position."""
+
+    def __init__(self, num_layers: int, key_dim: int):
+        self.num_layers, self.key_dim = int(num_layers), int(key_dim)
+        if self.key_dim < 1:
+            raise ValueError(f"key_dim must be positive, got {key_dim}")
+
+    @property
+    def row_lanes(self) -> int:
+        """Width of one stored key: up to whole lane tiles."""
+        return -(-self.key_dim // 128) * 128
+
+    def pool_shape(self, num_pages: int, page_size: int):
+        return (self.num_layers, int(num_pages), int(page_size),
+                self.row_lanes)
+
+    def bytes(self, num_pages: int, page_size: int, itemsize: int) -> int:
+        """Device bytes of the pool, every page and layer."""
+        return int(np.prod(self.pool_shape(num_pages, page_size))) \
+            * int(itemsize)
+
+
 class PageAllocator:
     """Host-side free list over page ids 1..num_pages-1 (0 is trash).
 
@@ -670,7 +717,8 @@ class PagedKVCache:
 
     def __init__(self, config: CacheConfig, scope, prefix_cache=True,
                  recurrent: Optional[RecurrentSpec] = None,
-                 window: Optional[WindowSpec] = None):
+                 window: Optional[WindowSpec] = None,
+                 index: Optional[IndexSpec] = None):
         import jax.numpy as jnp
 
         self.config = config
@@ -683,8 +731,15 @@ class PagedKVCache:
         self.window = window if window is not None \
             and window.num_layers else None
         per_slot = self.recurrent is not None or self.window is not None
-        # ... and none over latent rows (module header)
-        fresh_only = per_slot or config.latent
+        # the index keys of layers that select what they attend: a
+        # third pool behind the K/V pools' page ids (module header)
+        self.index = index if index is not None and index.num_layers \
+            else None
+        if self.index is not None and config.quantized:
+            raise ValueError(
+                "an index pool's keys are scored as they lie: no kv_quant")
+        # ... and none over latent rows or an index pool (module header)
+        fresh_only = per_slot or config.latent or self.index is not None
         self.prefix_bypassed = bool(prefix_cache) and fresh_only
         prefix_cache = bool(prefix_cache) and not fresh_only
         # optional per-request tracing hook: ``on_event(slot, name,
@@ -714,6 +769,10 @@ class PagedKVCache:
         if c.v_row_lanes:
             scope.set_var(V_PAGES_VAR, jnp.zeros(
                 c.pool_shape(row_lanes=c.v_row_lanes), c.store_dtype))
+        if self.index is not None:
+            scope.set_var(INDEX_PAGES_VAR, jnp.zeros(
+                self.index.pool_shape(c.num_pages, c.page_size),
+                c.store_dtype))
         if self.window is not None:
             for var, shape in zip((WINDOW_K_VAR, WINDOW_V_VAR),
                                   self.window.pool_shapes(c.num_slots,
@@ -750,13 +809,26 @@ class PagedKVCache:
     def state_var_names(self) -> Tuple[str, ...]:
         """Scope names a persistent step must thread (in order): the
         two page pools, plus the scale pools when quantized, then the
-        window layers' two pools, then the recurrent layers' slabs.  A
-        latent cache and a joint one have the K pool alone."""
+        index pool, then the window layers' two pools, then the
+        recurrent layers' slabs.  A latent cache and a joint one have
+        the K pool alone."""
         names = (K_PAGES_VAR, V_PAGES_VAR) if self.config.v_row_lanes \
             else (K_PAGES_VAR,)
         if self.config.quantized:
             names += (K_SCALES_VAR, V_SCALES_VAR)
-        return names + self.window_var_names() + self.recurrent_var_names()
+        return names + self.index_var_names() + self.window_var_names() \
+            + self.recurrent_var_names()
+
+    def index_var_names(self) -> Tuple[str, ...]:
+        return (INDEX_PAGES_VAR,) if self.index is not None else ()
+
+    def index_bytes(self) -> int:
+        """Device bytes of the index keys' pool, all layers and pages
+        (0 for a cache that keeps none)."""
+        c = self.config
+        return self.index.bytes(c.num_pages, c.page_size,
+                                c.store_dtype.itemsize) \
+            if self.index is not None else 0
 
     def window_var_names(self) -> Tuple[str, ...]:
         return (WINDOW_K_VAR, WINDOW_V_VAR) if self.window is not None \
@@ -991,6 +1063,10 @@ class PagedKVCache:
             raise ValueError(
                 "a cache of latent pages exports none: the hand-over is "
                 "not built for a pool of one row for keys and values")
+        if self.index is not None:
+            raise ValueError(
+                "a cache with an index pool exports no pages: the "
+                "hand-over is not built to carry a third pool's rows")
         idx = np.asarray([int(p) for p in pages], np.int32)
         return {name: self.scope.get_var(name)[:, idx]
                 for name in self.state_var_names()}

@@ -1,4 +1,4 @@
-"""The three stateful mixers a served model composes, as mixins: what a
+"""The four stateful mixers a served model composes, as mixins: what a
 layer keeps of a request lives with the engine (``attend``), what the
 layer computes of it is here, once, for every model that has such a
 layer.
@@ -29,6 +29,14 @@ with the state in fast memory, a group of ``SCAN_CHUNK``-token tiles a
 call; anything else (toy widths) runs ``ssm_token_xla`` a token, which
 is also the tests' oracle.  ``MambaLM``'s.
 
+``IndexedMixer``: grouped-query attention over the positions a learned
+INDEXER selects (``"attention"`` layers that cache, beside K and V, one
+small index key a position in a third pool).  The indexer is here ONCE
+in two forms that choose the same positions: the step's (every live
+key of a slot scored, the ``index_topk`` best gathered and attended) and
+a prompt's (blocks of query rows: a mask a pair).  The operations are
+``ops/indexed_attention.py``'s.  ``IndexedMoELM``'s.
+
 A mixin reads the model's declared widths off ``self`` and imports no
 model file: ``blocks.py`` alone of ``serving/``
 (``tests/test_tooling.py`` holds the arrows).
@@ -40,10 +48,12 @@ import math
 
 import numpy as np
 
+from ..ops import indexed_attention as ixa
 from ..ops import pallas_kda_chunk as kda_chunk
 from ..ops import pallas_kda_update as kda
 from ..ops import pallas_ssm as ssm
-from .blocks import ROPE_SCOPE, _mm, rms_norm
+from .blocks import (ROPE_SCOPE, _mm, half_split_angles, half_split_rotate,
+                     rms_norm)
 
 KDA_SCOPE = "kda_update"
 # tokens of one chunk of the rule's WY form, what the whole-prompt
@@ -69,6 +79,20 @@ SSM_OUT_SCOPE = "ssm_out_proj"          # the gate and W_out
 # vectors, nine float32 rows of all channels a token, are formed at once)
 SCAN_CHUNK = ssm.SCAN_TILE
 SCAN_CALL_TOKENS = 1024
+
+# the indexed attention's operations, the step's and a prompt's apart
+INDEX_PROJ_SCOPE = "index_proj"                     # W_Iq, W_Ik, W_Iw
+INDEX_SCORE_SCOPE = "index_scores"                  # I[t, s], a step's
+INDEX_SELECT_SCOPE = "index_select"                 # the topk of it
+SPARSE_ATTN_SCOPE = "sparse_attention"              # over the selected rows
+INDEX_SCORE_PROMPT_SCOPE = "index_scores_prompt"
+INDEX_SELECT_PROMPT_SCOPE = "index_select_prompt"
+SPARSE_ATTN_PROMPT_SCOPE = "sparse_attention_prompt"
+# what a request that records its logits keeps of every layer's selection:
+# a step's row the positions (-1 beyond the live ones), a prompt's row a
+# bit a key (``ixa.pack_bits``; all zeros for a row under ``index_topk``,
+# which attends every live position)
+INDEX_RECORD = "index_selected"
 
 Q_PROJ_SCOPE = "latent_q_proj"          # q_a, its norm, q_b
 KV_PROJ_SCOPE = "latent_kv_proj"        # kv_a and the latent's norm
@@ -564,3 +588,182 @@ class LatentMixer:
         the query's width; what the softmax's scale holds beyond that
         (the heads' own width, YaRN's ``mscale^2``) rides the query."""
         return q * (self.softmax_scale * math.sqrt(q.shape[-1]))
+
+
+class IndexCall:
+    """What an indexed layer hands ``attend`` as ``index=``: the rows'
+    index ``key`` (``[..., 1, index_dim]``: what the third pool keeps of
+    them), and the indexer's two forms with the call's queries bound.
+    ``step(keys, lengths, rows) -> (positions, ok, rows)`` over each
+    slot's cached keys ``[S, N, lanes]`` as the pool lays them out,
+    ``rows [S, N]`` whatever the caller wants back at the selected
+    positions (where a position's K and V lie); ``prompt(q, k, v,
+    keys, length) -> ctx`` over one prompt's own rows."""
+
+    def __init__(self, key, step, prompt):
+        self.key, self.step, self.prompt = key, step, prompt
+
+
+class IndexedMixer:
+    """Grouped-query attention (``num_heads`` on ``num_kv_heads`` of
+    ``head_dim``, an RMSNorm on every q and k head, half-split rotary on
+    all lanes at ``rope_theta``) whose softmax runs over the
+    ``index_topk`` positions an indexer of ``index_heads`` heads of
+    ``index_dim`` lanes on ONE key head selects, a layer its own.  A
+    prompt's rows are taken ``index_block`` at a time.  ``IndexedMoELM``'s."""
+
+    def indexed_weights(self, dense, ones, keys):
+        """An indexed layer's mixer weights.  The index key's LayerNorm
+        has a bias, seeded small and not zero so that leaving it out
+        shows."""
+        import jax
+        import jax.numpy as jnp
+
+        dm = self.d_model
+        hq = self.num_heads * self.head_dim
+        hkv = self.num_kv_heads * self.head_dim
+        return dict(
+            wq=dense((dm, hq)), wk=dense((dm, hkv)), wv=dense((dm, hkv)),
+            wo=dense((hq, dm)), q_norm=ones(self.head_dim),
+            k_norm=ones(self.head_dim),
+            index_wq=dense((dm, self.index_heads * self.index_dim)),
+            index_wk=dense((dm, self.index_dim)),
+            index_ww=dense((dm, self.index_heads)),
+            index_k_gain=ones(self.index_dim),
+            index_k_bias=0.1 * jax.random.normal(
+                next(keys), (self.index_dim,), jnp.float32))
+
+    def _attention(self, lw, l, h, positions, cache, attend):
+        """One layer's output ``[..., D]`` of rows ``h``: q and k normed
+        a head and turned, the indexer's queries, key and head weights
+        formed from the same rows, attended through the engine."""
+        import jax
+        import jax.numpy as jnp
+
+        lead = h.shape[:-1]
+        q = _mm(h, lw["wq"]).reshape(*lead, self.num_heads, self.head_dim)
+        k = _mm(h, lw["wk"]).reshape(*lead, self.num_kv_heads,
+                                     self.head_dim)
+        v = _mm(h, lw["wv"]).reshape(*lead, self.num_kv_heads,
+                                     self.head_dim)
+        q, k = self._qk_norm(lw, q, k)
+        with jax.named_scope(INDEX_PROJ_SCOPE):
+            qi = _mm(h, lw["index_wq"]).reshape(
+                *lead, self.index_heads, self.index_dim)
+            ki = self._index_key(lw, _mm(h, lw["index_wk"]))[..., None, :]
+            w = self._index_weights(lw, h)
+        with jax.named_scope(ROPE_SCOPE):
+            turn = self._rotary(positions, self.head_dim)
+            q, k = self._rotate(q, *turn), self._rotate(k, *turn)
+            qi, ki = self._index_rotary(qi, ki, positions)
+        ctx, cache = attend(l, q, k, v, cache, index=IndexCall(
+            ki, functools.partial(self._index_step, attend, qi, w),
+            functools.partial(self._index_prompt, attend, qi, w)))
+        return _mm(ctx.reshape(*lead, -1).astype(jnp.float32),
+                   lw["wo"]), cache
+
+    # -- the seams a departure is made at -----------------------------------
+    def _qk_norm(self, lw, q, k):
+        return rms_norm(q, lw["q_norm"], self.rms_eps), \
+            rms_norm(k, lw["k_norm"], self.rms_eps)
+
+    def _rotary(self, positions, width):
+        return half_split_angles(positions, self.rope_theta, width)
+
+    _rotate = staticmethod(half_split_rotate)
+
+    def _index_rotary(self, qi, ki, positions):
+        """The indexer's queries and key carry the same rotary term on
+        their ``index_dim`` lanes."""
+        turn = self._rotary(positions, self.index_dim)
+        return self._rotate(qi, *turn), self._rotate(ki, *turn)
+
+    def _index_key(self, lw, x):
+        """LayerNorm (mean removed, a gain and a bias) of the one index
+        key, float32."""
+        import jax
+        import jax.numpy as jnp
+
+        mu = jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
+        return (x - mu) * jax.lax.rsqrt(var + self.rms_eps) \
+            * lw["index_k_gain"] + lw["index_k_bias"]
+
+    def _index_weights(self, lw, h):
+        """A row's weight of each indexer head."""
+        return _mm(h, lw["index_ww"])
+
+    def _index_relu(self, s):
+        import jax
+
+        return jax.nn.relu(s)
+
+    def _index_scores(self, qi, w, keys):
+        """``I`` of queries ``qi [..., Hi, Di]`` weighted ``w`` over
+        ``keys [..., N, lanes]`` (``lanes >= Di``: a pool's row is a key
+        and zeros, which a zero-padded query meets)."""
+        import jax.numpy as jnp
+
+        short = keys.shape[-1] - qi.shape[-1]
+        if short:
+            qi = jnp.pad(qi, ((0, 0),) * (qi.ndim - 1) + ((0, short),))
+        return ixa.index_scores(qi, w, keys, relu=self._index_relu)
+
+    # -- the indexer's two forms --------------------------------------------
+    def _index_step(self, attend, qi, w, keys, lengths, rows):
+        """The step: slot s's query against its cached keys ``keys [S,
+        N, lanes]`` of which the first ``lengths[s]`` are live ->
+        (positions ``[S, topk]``, ok, ``rows [S, N]`` at them)."""
+        import jax
+        import jax.numpy as jnp
+
+        with jax.named_scope(INDEX_SCORE_SCOPE):
+            scores = self._index_scores(qi, w, keys)
+        with jax.named_scope(INDEX_SELECT_SCOPE):
+            pos, ok, rows = ixa.select_top(
+                scores, lengths, self.index_topk, carry=(rows,))
+        attend.record(INDEX_RECORD, jnp.where(ok, pos, -1))
+        return pos, ok, rows
+
+    def _index_prompt(self, attend, qi, w, q, k, v, keys, length):
+        """One prompt's rows ``q [T, H, D]`` over its own ``k`` / ``v
+        [T, Hkv, D]`` and index ``keys [T, Di]`` (each as the pools keep
+        them) -> ``ctx [T, H, D]``: causal attention in whatever form
+        the engine runs a prompt's (``attend.causal``), under a
+        selection a pair where some row has more than ``index_topk``
+        positions to choose from.  Those rows go ``index_block`` at a
+        time (the block's scores over the whole bucket, each row's mask);
+        the whole blocks of rows under ``index_topk`` attend every
+        earlier position."""
+        import jax
+        import jax.numpy as jnp
+
+        t, blk = q.shape[0], min(self.index_block, q.shape[0])
+        plain = min(self.index_topk // blk * blk, t)
+        bits = jnp.zeros((plain, t // 8), jnp.uint8)
+        if plain == t:
+            attend.record(INDEX_RECORD, bits)
+            return attend.causal(q, k, v, length)
+        if (t - plain) % blk:
+            raise ValueError(f"a prompt bucket of {t} rows is not whole "
+                             f"blocks of {blk} past row {plain}")
+        col = jnp.arange(t, dtype=jnp.int32)[None, :]
+
+        def block(first):
+            sl = lambda a: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+                a, first, blk, axis=0)
+            row = first + jnp.arange(blk, dtype=jnp.int32)[:, None]
+            with jax.named_scope(INDEX_SCORE_PROMPT_SCOPE):
+                scores = self._index_scores(sl(qi), sl(w), keys)
+            with jax.named_scope(INDEX_SELECT_PROMPT_SCOPE):
+                mask = ixa.select_mask(scores, col <= row, self.index_topk)
+                return mask.astype(jnp.int8), ixa.pack_bits(mask)
+
+        chosen, packed = jax.lax.map(block, jnp.arange(
+            plain, t, blk, dtype=jnp.int32))
+        attend.record(INDEX_RECORD, jnp.concatenate(
+            [bits, packed.reshape(t - plain, t // 8)]))
+        with jax.named_scope(SPARSE_ATTN_PROMPT_SCOPE):
+            return attend.causal(q, k, v, length, select=jnp.concatenate(
+                [jnp.ones((plain, t), jnp.int8),
+                 chosen.reshape(t - plain, t)]))

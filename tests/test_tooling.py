@@ -426,7 +426,7 @@ def test_the_served_models_library_has_its_arrows_one_way(name):
     ``blocks.py`` (stateless, nothing of ``serving/`` imported) or
     ``mixers.py`` (the stateful mixins, ``blocks`` alone), and no class
     borrows another model class's methods."""
-    assert len(MODEL_FILES) == 10
+    assert len(MODEL_FILES) == 11
     allowed = {"blocks.py": set(), "mixers.py": {"blocks"}}.get(
         name, {"blocks", "mixers"})
     assert _serving_imports(name) <= allowed, name

@@ -1634,3 +1634,91 @@ def test_jamba2_width_programs_compile(one_chip, program):
         < weights + pools + slabs + (4 << 20)
     assert mem.alias_size_in_bytes == pools + slabs
     assert mem.temp_size_in_bytes < 1 << 30
+
+
+def _indexed_engine(layers=2, **cfg):
+    """Keye-VL-2.0-30B-A3B's language block behind the engine at the
+    cell's serving sizes (16 slots, tables of 1,536 pages and the spare:
+    24,576 positions, bf16 pages and index keys), two layers, 16 held
+    experts of the published width and a short vocabulary: only shapes
+    matter to a compile, and these are the ones the chip's compiler
+    could refuse (a third pool of 64-lane keys at 128 lanes, every
+    slot's 24,576 keys scored and the 2,048 best of them taken, rows of
+    512 lanes gathered by position, the 8,192-row prompt's flash kernel
+    under a selection a pair).  The pools hold half the cell's pages: a
+    compile reads the table's width, not the pool's depth, but a pool
+    has to stay too large for the compiler to move it through fast
+    memory whole (it does that with an index pool of 78 MB; the cell's
+    is 805 MB)."""
+    from paddle_tpu.serving import DecodeConfig, DecodeEngine
+    from paddle_tpu.serving.indexed_moe_lm import IndexedMoELM
+
+    model = IndexedMoELM(
+        vocab_size=1536, d_model=2048, num_layers=layers, num_heads=32,
+        num_kv_heads=4, head_dim=128, index_heads=16, index_dim=64,
+        index_topk=2048, num_experts=128, top_k=8, held_experts=range(16),
+        expert_dim=768)
+    weights = jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init_weights, jax.random.PRNGKey(0)))
+    return DecodeEngine(model, weights, DecodeConfig(**dict(dict(
+        slots=16, max_seq_len=24576, num_pages=16 * 768 + 1,
+        use_pallas="always", cache_dtype="bfloat16"), **cfg)))
+
+
+@pytest.mark.parametrize("program", ["step", "prefill_8192"])
+def test_keye_vl2_width_programs_compile(one_chip, program):
+    """The joint step (16 slots x 1,536 pages: every slot's 24,576 index
+    keys gathered by its table and scored, the 2,048 best positions
+    taken, their K and V rows gathered from the pools by flat row; no
+    kernel but the experts' hit form: the three pools go in and come out
+    in place and no layer of a pool is copied) and the 8,192-row
+    whole-prompt prefill (the flash kernel a layer under the selection, an int8 plane of 8,192 x 8,192
+    its operand; the two grouped-expert kernels; the head over the one
+    row that is read)."""
+    from paddle_tpu.ops import pallas_moe_grouped as grouped
+
+    eng = _indexed_engine()
+    cc = eng._cache.config
+    assert (cc.num_heads, cc.row_lanes, cc.v_row_lanes, cc.pages_per_slot,
+            cc.lane_dense) == (4, 512, 512, 1536, True)
+    assert eng._state_vars == (
+        "__decode_k_pages__", "__decode_v_pages__",
+        "__decode_index_pages__")
+    pools = [(2, 12289, 16, 512), (2, 12289, 16, 512),
+             (2, 12289, 16, 128)]
+    if program == "step":
+        compiled = eng.lower_step(sharding=one_chip).compile()
+        text = compiled.as_text()
+        # the one kernel of a step is the experts' hit form (16 rows
+        # over 16 held of 128, top-8: 64 % of them hit), a layer
+        from paddle_tpu.ops import pallas_moe_hit as hit
+
+        calls = [ln for ln in text.splitlines() if re.match(
+            r"\s*%" + hit.HIT_KERNEL_NAME + r"[.\d]* = ", ln)]
+        assert len(calls) == text.count("tpu_custom_call") == 2
+        # each slot's keys as the pool lays them out, its scores, and the
+        # rows of the positions it selected
+        assert "bf16[16,1536,16,128]" in text or "bf16[16,24576,128]" in text
+        assert "f32[16,24576]" in text and "bf16[16,2048,512]" in text
+    else:
+        assert eng._prefill_walks(8192) == [(2, None, ("flash", 256, 1024))]
+        compiled = eng.lower_prefill(8192, sharding=one_chip).compile()
+        text = compiled.as_text()
+        assert grouped.GATE_UP_KERNEL_NAME in text
+        assert grouped.DOWN_KERNEL_NAME in text
+        from paddle_tpu.ops.pallas_prompt_attention import KERNEL_NAME
+
+        calls = [line.strip() for line in text.splitlines()
+                 if re.match(r"\s*%" + KERNEL_NAME + r"[.\d]* = ", line)]
+        assert len(calls) == 2
+        assert all("s8[8192,8192]" in c and "bf16[4,8192,128]" in c
+                   for c in calls), calls
+        # the float32 planes over the bucket are a block's index products
+        # (512 rows x 16 heads) and scores; no plane of attention scores
+        planes = set(re.findall(r"f32\[(?:\d+,){2,}8192\]", text))
+        assert planes <= {"f32[512,16,8192]", "f32[12,512,8192]"}, planes
+        # the head runs over the one row that is read, not the bucket
+        assert "f32[1,1536]" in text and "f32[8192,1536]" not in text
+    _assert_pools_stay_put(compiled, pools)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
